@@ -11,6 +11,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
 import threading
 from typing import Optional
 
@@ -34,6 +35,17 @@ RTS_ERR_MISSING = -4
 RTS_ERR_STATE = -5
 
 
+def _say_fallback(why: str) -> None:
+    # Once per process (load_library latches _load_failed): the
+    # Python per-segment store is a correct but slower stand-in, and
+    # which one a run used must be readable from its log.
+    print(
+        f"[ray_tpu] native object store unavailable ({why}); "
+        "using the Python store",
+        file=sys.stderr,
+    )
+
+
 def load_library() -> Optional[ctypes.CDLL]:
     """Load (building if needed) the native store; None on failure."""
     global _lib, _load_failed
@@ -55,14 +67,16 @@ def load_library() -> Optional[ctypes.CDLL]:
                     capture_output=True,
                     timeout=120,
                 )
-            except Exception:
+            except Exception as e:
                 if not os.path.exists(_SO):
                     _load_failed = True
+                    _say_fallback(f"`make -C {_DIR}` failed: {e!r}")
                     return None
         try:
             lib = ctypes.CDLL(_SO)
-        except OSError:
+        except OSError as e:
             _load_failed = True
+            _say_fallback(f"{_SO} did not load: {e!r}")
             return None
         lib.rts_open.restype = ctypes.c_void_p
         lib.rts_open.argtypes = [

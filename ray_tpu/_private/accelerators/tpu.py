@@ -7,12 +7,13 @@ or GKE env vars (:198-271), and the slice-scheduling auto-resources
 `TPU-{pod_type}-head` + pod-name (:334-397) that make SPMD gang
 scheduling expressible as ordinary resource requests.
 
-TPU-first deviation: a TPU worker owns the host's *entire* chip set.
-libtpu wants one process per chip-set, and SPMD programs address whole
-hosts of a slice — so chips are not sub-divided across concurrent
-workers the way GPUs are (SURVEY.md §7 hard part 1: "the worker pool
-must pin TPU workers"). Sub-host granularity is expressed by starting
-the node with explicit `num_tpus` instead.
+One process per chip set: libtpu gives a chip to one process at a
+time, so a TPU worker is scoped at spawn to exactly the chips its
+lease holds (`chip_scope_env`, the reference's TPU_VISIBLE_CHIPS +
+bounds recipe). A lease for every chip on the node leaves libtpu's
+defaults alone, which is what an SPMD program spanning the host wants;
+two `num_tpus=1` actors on a four-chip host get two different chips
+(SURVEY.md §7 hard part 1: "the worker pool must pin TPU workers").
 
 Cloud metadata is read from env vars only (GCE metadata-server lookups
 are gated out: zero-egress environments hang on them). The overrides
@@ -25,7 +26,7 @@ import glob
 import os
 import re
 from functools import lru_cache
-from typing import Dict, Optional, Tuple
+from typing import Dict, Iterable, Optional, Sequence, Tuple
 
 from .base import AcceleratorManager
 
@@ -43,6 +44,48 @@ _DEFAULT_CHIPS_PER_HOST = {
 }
 
 _POD_TYPE_RE = re.compile(r"^(v\d+[a-z]*)-(\d+)$")
+
+#: Chips one process may hold short of the whole host -> the chip
+#: grid libtpu is told to expect (reference: tpu.py:155-195
+#: TPU_CHIPS_PER_HOST_BOUNDS_{1,2}_CHIP_CONFIG; 4-of-8 is the same
+#: recipe one size up).
+_CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1"}
+
+
+def pick_chips(
+    free: Iterable[int], n: int, total: int
+) -> Optional[Tuple[int, ...]]:
+    """The lowest aligned block of `n` chip ids that is entirely in
+    `free`, or None. Aligned (0-1, 2-3; 0-3, 4-7) because a
+    multi-chip process needs chips that are neighbours on the host's
+    grid, not any `n` that happen to be idle."""
+    free = set(free)
+    for start in range(0, total - n + 1, n):
+        block = tuple(range(start, start + n))
+        if free.issuperset(block):
+            return block
+    return None
+
+
+def chip_scope_env(
+    chips: Sequence[int], chips_on_node: int
+) -> Dict[str, str]:
+    """Environment that scopes one worker process to `chips`. Empty
+    when the worker holds every chip on the node (libtpu's defaults
+    already describe the host)."""
+    if len(chips) >= chips_on_node:
+        return {}
+    bounds = _CHIP_BOUNDS.get(len(chips))
+    if bounds is None:
+        raise ValueError(
+            f"no host topology for a {len(chips)}-chip worker; ask for "
+            f"{sorted(_CHIP_BOUNDS)} chips or all {chips_on_node}"
+        )
+    return {
+        TPU_VISIBLE_CHIPS_ENV: ",".join(str(c) for c in chips),
+        "TPU_CHIPS_PER_HOST_BOUNDS": bounds,
+        "TPU_HOST_BOUNDS": "1,1,1",
+    }
 
 
 def _env(*names: str) -> Optional[str]:

@@ -13,9 +13,11 @@ into first-class observability:
   already seen — a tuple build + one set lookup, microseconds against
   a multi-ms step (the <1%-of-step bar is enforced by a unit test).
   A digest MISS means XLA is about to trace+compile: the call is
-  timed, ``jax.monitoring`` event-duration hooks (registered lazily;
-  available on jax 0.4.x) attribute the exact backend-compile seconds
-  to the active program, and the compilation is recorded as
+  timed, ``jax.monitoring`` event-duration hooks (registered lazily)
+  attribute the exact backend-compile seconds to the active program
+  (a persistent-cache hit fires the same event with the retrieval
+  time, so a warm cache does not hide a program from the watch), and
+  the compilation is recorded as
   (program name, shape digest, duration).
 * Every recorded compile (a) bills ``compile_ms`` as a first-class
   stall phase into `step_telemetry` — cold-compile steps stop
@@ -678,6 +680,12 @@ class WatchedFunction:
         # EXECUTION as compile_ms and mint a phantom compile count;
         # the digest is marked seen and nothing is recorded.
         return out
+
+    @property
+    def wrapped(self) -> Callable:
+        """The instrumented callable itself, for what the watch does
+        not forward (AOT inspection: `.wrapped.lower(...)`)."""
+        return self._fn
 
     def stats(self) -> Dict[str, Any]:
         """This program's compile counts from the process registry

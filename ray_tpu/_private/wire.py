@@ -397,7 +397,7 @@ _num = (int, float)
 SCHEMAS: Dict[str, Dict[str, Any]] = {
     # registration / lifecycle
     "register_client": {
-        "role": str, "pid": int, "?is_tpu": bool,
+        "role": str, "pid": int, "?chips": list,
         "?direct_address": (str, type(None)), "?entrypoint": str,
     },
     "register_node": {

@@ -462,7 +462,11 @@ class CoreWorker:
             "register_client",
             role=role,
             pid=os.getpid(),
-            is_tpu=os.environ.get("RT_WORKER_TPU") == "1",
+            chips=[
+                int(c)
+                for c in os.environ.get("RT_WORKER_CHIPS", "").split(",")
+                if c
+            ],
             direct_address=direct_address,
         )
         self.node_id = NodeID(reply["node_id"])

@@ -78,16 +78,23 @@ def _run_child(log_path: str, env: dict) -> None:
         os._exit(0)
 
 
-def _proc_starttime(pid: int):
-    """Kernel start time (clock ticks since boot) of `pid`, or None if
-    the process is gone. Field 22 of /proc/<pid>/stat; parse after the
-    last ')' — the comm field may itself contain spaces or parens."""
+def _proc_stat(pid: int):
+    """(state, start time in clock ticks since boot) of `pid` from
+    /proc/<pid>/stat, or None if the process is gone. Fields 3 and 22;
+    parse after the last ')' — the comm field may itself contain
+    spaces or parens."""
     try:
         with open(f"/proc/{pid}/stat", "rb") as f:
             stat = f.read().decode("ascii", "replace")
-        return int(stat.rsplit(")", 1)[1].split()[19])
+        fields = stat.rsplit(")", 1)[1].split()
+        return fields[0], int(fields[19])
     except (OSError, IndexError, ValueError):
         return None
+
+
+def _proc_starttime(pid: int):
+    stat = _proc_stat(pid)
+    return None if stat is None else stat[1]
 
 
 class ForkedProc:
@@ -122,10 +129,18 @@ class ForkedProc:
             # pid reused by another user's process: ours is gone.
             self._returncode = 0
             return 0
-        now = _proc_starttime(self.pid)
-        if self._starttime is None or now != self._starttime:
-            # Same pid, different (or vanished) start time: the pid
-            # was recycled after our child exited.
+        stat = _proc_stat(self.pid)
+        if (
+            self._starttime is None
+            or stat is None
+            or stat[1] != self._starttime
+            # Exited but not yet reaped by the template (its reaper
+            # naps between children): everything the process held —
+            # sockets, arena pins, chips — is already released.
+            or stat[0] == "Z"
+        ):
+            # Otherwise: same pid, different (or vanished) start time
+            # means the pid was recycled after our child exited.
             self._returncode = 0
             return 0
         return None

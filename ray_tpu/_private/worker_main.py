@@ -10,6 +10,12 @@ import sys
 
 def main() -> None:
     socket_path = os.environ["RT_SOCKET"]
+    if os.environ.get("RT_WORKER_CHIPS"):
+        # This process owns chips: place XLA's persistent cache before
+        # anything it runs can compile.
+        from .compile_cache import ensure_compile_cache
+
+        ensure_compile_cache()
     profile_dir = os.environ.get("RT_WORKER_PROFILE")
     prof = None
     if profile_dir:

@@ -292,12 +292,12 @@ class RuntimeContext:
         return actor_id.hex() if actor_id is not None else None
 
     def get_accelerator_ids(self) -> Dict[str, List[str]]:
-        """Accelerator ids visible to THIS worker (reference:
-        RuntimeContext.get_accelerator_ids; TPU chip visibility rides
-        TPU_VISIBLE_CHIPS, accelerators/tpu.py)."""
+        """Accelerator ids THIS worker's lease holds (reference:
+        RuntimeContext.get_accelerator_ids): the chips the daemon
+        scoped the process to at spawn (daemon._worker_env)."""
         import os as _os
 
-        chips = _os.environ.get("TPU_VISIBLE_CHIPS", "")
+        chips = _os.environ.get("RT_WORKER_CHIPS", "")
         return {"TPU": [c for c in chips.split(",") if c]}
 
 

@@ -399,6 +399,15 @@ class InferenceEngine:
                 (ec.slots, cfg.vocab_size), jnp.float32
             )
         self._base_key = jax.random.PRNGKey(ec.seed)
+        # Where this engine's programs run, as JAX reports it from
+        # inside this process: every serving number is read against
+        # it (engine_stats, /api/serve).
+        device = jax.devices()[0]
+        self._device = {
+            "platform": device.platform,
+            "device_kind": device.device_kind,
+            "devices": len(jax.devices()),
+        }
         # Compile-watch registration (ISSUE 15 satellite): the
         # engine's jitted entry points are named programs, so "the
         # engine compiles ONCE per geometry" (PR 11) is a tested
@@ -449,6 +458,7 @@ class InferenceEngine:
             name=f"llm-engine:{family or 'default'}",
         )
         self._thread.start()
+        self._observe_device()
 
     # -- public --------------------------------------------------------
     def submit(
@@ -636,6 +646,7 @@ class InferenceEngine:
                 policy_steps=self._policy_steps,
                 policy_rows_served=self._policy_rows_served,
                 dead=self._dead is not None,
+                **self._device,
             )
             # Per-family compile counts (compile-watch): prefill /
             # decode / policy programs, each {compiles,
@@ -1206,6 +1217,14 @@ class InferenceEngine:
                 stats["slots_total"], stats["waiting"],
                 **self._block_stats(),
             )
+        except Exception:
+            pass
+
+    def _observe_device(self) -> None:
+        try:
+            from ..serve.observability import observe_engine_device
+
+            observe_engine_device(self._tags, **self._device)
         except Exception:
             pass
 

@@ -82,7 +82,6 @@ def build_model(spec: Dict[str, Any]):
     kwargs = dict(spec.get("config") or {})
     if "dtype" in kwargs:
         kwargs["dtype"] = _resolve_dtype(kwargs["dtype"])
-    kwargs.setdefault("attention", "reference")
     cfg = LlamaConfig(**kwargs)
     params = init_params(
         jax.random.PRNGKey(int(spec.get("seed", 0))), cfg
@@ -337,9 +336,15 @@ def build_llm_app(
     """Bind the engine deployment. `engine_enabled=None` resolves the
     RT_serve_engine_enabled kill switch HERE (driver-side) so the
     decision ships in the replica init args instead of depending on
-    worker-process environments."""
+    worker-process environments.
+
+    Each replica hosts one single-device `InferenceEngine`, so it
+    leases one chip when the cluster advertises any: the daemon then
+    scopes its worker to that chip (two replicas, two chips). With no
+    chips in the cluster the replica is a CPU worker, as in tests."""
     from .._private.config import Config
     from ..serve.deployment import deployment as serve_deployment
+    from ..util.accelerators.tpu import cluster_tpu_chips
 
     runtime_cfg = Config.from_env()
     if engine_enabled is None:
@@ -362,6 +367,9 @@ def build_llm_app(
         name=name,
         num_replicas=num_replicas,
         max_ongoing_requests=max_ongoing_requests,
+        ray_actor_options=(
+            {"num_tpus": 1} if cluster_tpu_chips() else None
+        ),
     )(LLMServer)
     return dep.bind(
         dict(families),

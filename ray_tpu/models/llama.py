@@ -24,7 +24,12 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import flash_attention, mha_reference, repeat_kv
+from ..ops.attention import (
+    flash_attention,
+    flash_attention_sharded,
+    mha_reference,
+    repeat_kv,
+)
 from ..ops.moe import moe_ffn_dense, moe_ffn_ep
 from ..ops.norms import apply_rotary, rms_norm, rotary_embedding, swiglu
 from ..ops.ring_attention import ring_attention
@@ -317,18 +322,21 @@ def project_qkv(cfg: LlamaConfig, h, layer):
     return q, k, v
 
 
-def _attention(cfg: LlamaConfig, q, k, v, sp_axis: Optional[str]):
+def _attention(cfg: LlamaConfig, q, k, v, sp_axis: Optional[str],
+               mesh=None):
     k = repeat_kv(k, cfg.n_heads // cfg.n_kv_heads)
     v = repeat_kv(v, cfg.n_heads // cfg.n_kv_heads)
     if cfg.attention == "ring" and sp_axis is not None:
         return ring_attention(q, k, v, sp_axis, causal=True)
     if cfg.attention == "flash":
+        if mesh is not None and mesh.size > 1:
+            return flash_attention_sharded(q, k, v, mesh, causal=True)
         return flash_attention(q, k, v, causal=True)
     return mha_reference(q, k, v, causal=True)
 
 
 def _layer(cfg: LlamaConfig, x, layer, cos, sin, sp_axis=None,
-           ep_axis=None):
+           ep_axis=None, mesh=None):
     """One decoder block. x: [batch, seq, dim]. Returns (x, aux) where
     aux is the MoE load-balancing loss (0 for dense layers)."""
     b, t, _ = x.shape
@@ -337,7 +345,7 @@ def _layer(cfg: LlamaConfig, x, layer, cos, sin, sp_axis=None,
     q, k, v = project_qkv(cfg, h, layer)
     q = apply_rotary(q, cos, sin)
     k = apply_rotary(k, cos, sin)
-    attn = _attention(cfg, q, k, v, sp_axis)
+    attn = _attention(cfg, q, k, v, sp_axis, mesh)
     attn = attn.transpose(0, 2, 1, 3).reshape(b, t, cfg.n_heads * hd)
     x = x + attn @ layer["wo"]
     h = model_norm(cfg, x, layer["mlp_norm"])
@@ -371,12 +379,18 @@ def forward_and_aux(
     positions: Optional[jax.Array] = None,
     sp_axis: Optional[str] = None,
     ep_axis: Optional[str] = None,
+    mesh=None,
 ) -> tuple:
     """Token ids [batch, seq] → (logits [batch, seq, vocab] f32,
     aux: summed MoE load-balancing loss, 0 for dense models).
 
     With sequence parallelism, `tokens` is the local seq shard and
-    `positions` carries its global positions.
+    `positions` carries its global positions. `mesh` is the mesh of
+    the GSPMD jit this is traced under (what `make_train_step` was
+    given): with more than one device the flash kernel must run per
+    shard (ops.attention.flash_attention_sharded; without it JAX
+    refuses to lower the kernel). Leave it None inside a `shard_map`,
+    where the call is already per shard.
     """
     b, t = tokens.shape
     if positions is None:
@@ -387,7 +401,7 @@ def forward_and_aux(
     )
 
     def body(x, layer):
-        return _layer(cfg, x, layer, cos, sin, sp_axis, ep_axis)
+        return _layer(cfg, x, layer, cos, sin, sp_axis, ep_axis, mesh)
 
     if cfg.remat:
         if cfg.remat_policy == "dots":
@@ -423,11 +437,12 @@ def forward(
     positions: Optional[jax.Array] = None,
     sp_axis: Optional[str] = None,
     ep_axis: Optional[str] = None,
+    mesh=None,
 ) -> jax.Array:
     """Token ids [batch, seq] → logits [batch, seq, vocab] (f32)."""
     return forward_and_aux(
         params, tokens, cfg, positions=positions, sp_axis=sp_axis,
-        ep_axis=ep_axis,
+        ep_axis=ep_axis, mesh=mesh,
     )[0]
 
 
@@ -457,12 +472,13 @@ def loss_fn(
     positions: Optional[jax.Array] = None,
     sp_axis: Optional[str] = None,
     ep_axis: Optional[str] = None,
+    mesh=None,
 ) -> jax.Array:
     """Mean next-token cross-entropy (+ weighted MoE aux loss).
     `targets` < 0 are masked out."""
     logits, aux = forward_and_aux(
         params, tokens, cfg, positions=positions, sp_axis=sp_axis,
-        ep_axis=ep_axis,
+        ep_axis=ep_axis, mesh=mesh,
     )
     nll_sum, count = masked_xent(logits, targets)
     xent = nll_sum / jnp.maximum(count, 1.0)

@@ -23,19 +23,27 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh
 
-try:  # TPU-only module; import lazily so CPU tests work.
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from ..parallel.sharding import ACT_RULES, checked_shard_map, spec_for
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
 def _interpret() -> bool:
-    # Off-TPU the kernels run in Pallas interpreter mode, which is how
-    # CI validates them numerically without hardware.
-    return jax.default_backend() in ("cpu",)
+    # The Pallas interpreter is how CPU tests validate the kernels
+    # numerically. It is chosen from the platform and is unreachable
+    # on a TPU: there Mosaic compiles the kernel or the call raises.
+    return jax.default_backend() == "cpu"
+
+
+def _out_struct(shape, dtype, like) -> jax.ShapeDtypeStruct:
+    """Kernel output type that varies over the mesh axes `like` does:
+    inside a `shard_map` (flash_attention_sharded) every output is
+    per-shard like its inputs, and the replication check wants that
+    said; outside one the set is empty."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _mask_logits(s, qi, ki, block_q, block_k, causal, kv_len):
@@ -275,8 +283,8 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, kv_len):
             pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, 8, t), jnp.float32),
+            _out_struct((bh, t, d), q.dtype, q),
+            _out_struct((bh, 8, t), jnp.float32, q),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
@@ -483,7 +491,7 @@ def _flash_backward_fused(
     # (including the consecutive-revisit nq==1 case) is validated on
     # hardware by the cross-attention grad shapes in the verify
     # recipe — interpret mode cannot model it (see _bwd_fused_kernel).
-    dq_seed = jnp.zeros((bh, t, d), jnp.float32)
+    dq_seed = jnp.zeros_like(q, jnp.float32)
 
     interp = _interpret()
     dq, dk, dv = pl.pallas_call(
@@ -510,9 +518,9 @@ def _flash_backward_fused(
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk, d), v.dtype),
+            _out_struct((bh, t, d), jnp.float32, q),
+            _out_struct((bh, tk, d), k.dtype, q),
+            _out_struct((bh, tk, d), v.dtype, q),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
@@ -533,13 +541,6 @@ def _flash_backward_fused(
 # ---------------------------------------------------------------------------
 # public op with custom VJP
 # ---------------------------------------------------------------------------
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() not in ("cpu", "gpu") and pltpu is not None
-    except Exception:
-        return False
-
 
 @functools.partial(
     jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8)
@@ -589,17 +590,21 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: int = 1024,
     block_k: int = 1024,
-    force_pallas: Optional[bool] = None,
+    force_pallas: bool = False,
 ) -> jax.Array:
-    """Fused attention: Pallas kernel on TPU, reference math elsewhere.
+    """Fused attention. On a TPU this is always the Pallas kernel,
+    compiled by Mosaic: a shape the compiler refuses raises, it never
+    degrades to the reference. Off-TPU the default is `mha_reference`
+    (the interpreter is a test vehicle, far too slow to stand in for
+    a backend); `force_pallas=True` runs the kernel there interpreted,
+    which is how CPU tests validate it.
 
     q/k/v: [batch, heads, seq, head_dim]. head_dim should be a
     multiple of 128 for MXU efficiency (callers pad).
     """
     b, h, t, d = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    use_pallas = _on_tpu() if force_pallas is None else force_pallas
-    if not use_pallas:
+    if jax.default_backend() != "tpu" and not force_pallas:
         return mha_reference(q, k, v, causal=causal, scale=scale)
     tk = k.shape[2]
     block_q = min(block_q, t)
@@ -621,6 +626,30 @@ def flash_attention(
         qf, kf, vf, scale, causal, block_q, block_k, tk, t
     )
     return out[:, :t, :].reshape(b, h, t, d)
+
+
+def flash_attention_sharded(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    mesh: Mesh,
+    **kwargs,
+) -> jax.Array:
+    """`flash_attention` inside a GSPMD `jax.jit` over `mesh`. XLA
+    cannot partition a Mosaic custom call, and JAX refuses to lower
+    one from a multi-device jit ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map" —
+    measured on 4 chips, PR 21). So the kernel runs per shard: batch
+    over the data axes and heads over `tp`, as ACT_RULES lays
+    activations out; sequence and head_dim stay whole (a softmax row
+    needs all of its keys)."""
+    spec = spec_for(("batch", "heads", None, None), ACT_RULES)
+    return checked_shard_map(
+        functools.partial(flash_attention, **kwargs),
+        mesh,
+        (spec, spec, spec),
+        spec,
+    )(q, k, v)
 
 
 def repeat_kv(k: jax.Array, num_rep: int) -> jax.Array:

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..parallel.collective import axis_size as _axis_size
+from ..parallel.collective import axis_size as _axis_size, pcast_varying
 
 from .attention import DEFAULT_MASK_VALUE
 
@@ -87,21 +87,14 @@ def ring_attention(
         return acc_new, m_new, l_new, k_next, v_next
 
     # Initializers are device-varying over the ring axis (each rank
-    # accumulates different data) — mark them so scan's
-    # varying-manual-axes type check agrees (jax >= 0.7).
-    def _varying(x):
-        try:
-            return lax.pcast(x, (axis_name,), to="varying")
-        except (AttributeError, TypeError, ValueError):
-            # Already varying over axis_name (the *_like inits inherit
-            # it from q), or an older jax without pcast.
-            return x
-
-    # *_like inherits every OTHER varying axis q already carries (pp/ep
-    # when ring attention runs inside the pipeline/MoE composition).
-    acc = _varying(jnp.zeros_like(qf))
-    m = _varying(jnp.full_like(qf[..., :1], -jnp.inf))
-    l = _varying(jnp.zeros_like(qf[..., :1]))
+    # accumulates different data) — mark them so the loop carry's
+    # varying-manual-axes type matches its body. *_like inherits every
+    # varying axis q already carries (the ring axis among them when q
+    # is sharded over it; pp/ep when ring attention runs inside the
+    # pipeline/MoE composition).
+    acc = pcast_varying(jnp.zeros_like(qf), axis_name)
+    m = pcast_varying(jnp.full_like(qf[..., :1], -jnp.inf), axis_name)
+    l = pcast_varying(jnp.zeros_like(qf[..., :1]), axis_name)
     acc, m, l, _, _ = lax.fori_loop(0, n, step, (acc, m, l, k, v))
     l_safe = jnp.where(l == 0.0, 1.0, l)
     return (acc / l_safe).astype(q.dtype)

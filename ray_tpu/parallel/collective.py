@@ -111,25 +111,18 @@ def axis_index(axis: Axis):
 
 
 def axis_size(axis: Axis):
-    """Static size of a named mesh axis, on any jax this repo meets:
-    `lax.axis_size` where it exists (>= 0.6), else `psum(1, axis)` —
-    which constant-folds to the same Python int at trace time."""
-    try:
-        return lax.axis_size(axis)
-    except AttributeError:
-        return lax.psum(1, axis)
+    """Static size of a named mesh axis (a Python int at trace
+    time)."""
+    return lax.axis_size(axis)
 
 
 def pcast_varying(x, axes):
-    """Mark `x` varying over `axes` for jax >= 0.7's
-    varying-manual-axes type check; a no-op per axis when the axis is
-    already varying or the jax predates `lax.pcast` (same guard idiom
-    as ops/ring_attention._varying)."""
+    """Mark `x` varying over `axes` for the varying-manual-axes type
+    check. Axes `x` already varies over are left alone (`lax.pcast`
+    rejects a repeat)."""
     if isinstance(axes, str):
         axes = (axes,)
-    for ax in axes:
-        try:
-            x = lax.pcast(x, (ax,), to="varying")
-        except (AttributeError, TypeError, ValueError):
-            pass
-    return x
+    missing = tuple(
+        ax for ax in axes if ax not in jax.typeof(x).vma
+    )
+    return lax.pcast(x, missing, to="varying") if missing else x

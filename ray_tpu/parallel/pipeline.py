@@ -94,11 +94,10 @@ def spmd_pipeline(
         return state, outputs, aux_acc
 
     # The carry is device-varying over pp (each rank holds different
-    # activations); mark the zero initializers so scan's type check
-    # agrees (jax >= 0.7 varying-manual-axes; a no-op on older jax
-    # without pcast). zeros_like inherits any OTHER varying axes
-    # (sp/ep) the activations already carry when the pipeline composes
-    # with sequence/expert parallelism.
+    # activations); mark the zero initializers so the loop carry's
+    # varying-manual-axes type matches. zeros_like inherits any OTHER
+    # varying axes (sp/ep) the activations already carry when the
+    # pipeline composes with sequence/expert parallelism.
     state = pcast_varying(
         jnp.zeros_like(jnp.take(microbatches, 0, axis=0)),
         axis_name,

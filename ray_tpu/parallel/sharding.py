@@ -116,18 +116,11 @@ def with_constraint(x, mesh: Mesh, logical_axes, rules: Rules):
 
 
 def checked_shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """shard_map with a version-adaptive replication check: jax >= 0.6
-    proves psum-derived scalars replicated and keeps the check ON; the
-    0.4-era checker cannot follow the pipeline's ppermute/psum chains
-    and would reject correct programs (out_specs=P() _SpecError), so
-    it is disabled there. Gate: `lax.pcast` existing is the same
-    varying-manual-axes generation whose checker works."""
-    try:
-        from jax import shard_map as _shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if not hasattr(jax.lax, "pcast"):
-        kwargs["check_rep"] = False
-    return _shard_map(f, **kwargs)
+    """`jax.shard_map` over an explicit mesh with the replication
+    (varying-manual-axes) check ON: psum-derived scalars are proven
+    replicated, so `out_specs=P()` is a checked claim. One spelling of
+    the call for the pipeline, MoE, ring-attention and sharded flash
+    paths."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs
+    )

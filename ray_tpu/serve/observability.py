@@ -520,6 +520,31 @@ def observe_engine_finish(tags: Dict[str, str], reason: str) -> None:
         pass
 
 
+def observe_engine_device(
+    tags: Dict[str, str], platform: str, device_kind: str, devices: int
+) -> None:
+    """Engine: the device its programs run on, as JAX reported it
+    inside the replica process — an info-style gauge (value = device
+    count; platform and kind ride as labels) so `/api/serve` can name
+    what every engine number was taken on."""
+    if not _ENABLED:
+        return
+    try:
+        _gauge(
+            "serve_engine_devices",
+            "Devices visible to the engine process, labeled with the "
+            "platform and device kind JAX reports",
+            ENGINE_TAGS + ("platform", "device_kind"),
+        ).set(
+            float(devices),
+            tags={
+                **tags, "platform": platform, "device_kind": device_kind,
+            },
+        )
+    except Exception:
+        pass
+
+
 def observe_engine_weights(
     tags: Dict[str, str], version: int
 ) -> None:
@@ -809,6 +834,16 @@ def _fold_engine(summary: Dict[str, dict], row, out) -> None:
             "prefix_misses", float(s.get("total", 0.0) or 0.0)
         ),
     )
+
+    for flat, series in (
+        summary.get("serve_engine_devices", {}).get("by_tags") or {}
+    ).items():
+        tags = _tag_dict(flat)
+        target = family_row(tags)
+        if target is not None:
+            target["platform"] = tags.get("platform", "")
+            target["device_kind"] = tags.get("device_kind", "")
+            target["devices"] = float(series.get("value", 0.0) or 0.0)
 
     def histo(target: dict, series: dict, prefix: str) -> None:
         if not series.get("count"):

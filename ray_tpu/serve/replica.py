@@ -304,9 +304,13 @@ class Replica:
             reset_request_context(ctx_token)
 
     def stats(self) -> dict:
+        import ray_tpu as rt
+
         return {
             "replica_id": self.replica_id,
             "pid": os.getpid(),
+            # Chip ids this replica's lease holds (empty: CPU worker).
+            "chips": rt.get_runtime_context().get_accelerator_ids()["TPU"],
             "served": self._served,
             "executing": self._executing,
             "uptime_s": time.time() - self._started,

@@ -56,12 +56,9 @@ def _host_snapshot(state: Any) -> Any:
     caller's next train step: with donate_argnums the step reuses the
     state's HBM in place, so a lazy read from the writer thread would
     see garbage. Non-jax pytrees (numpy/python) pass through."""
-    try:
-        import jax
+    import jax
 
-        return jax.device_get(state)
-    except ImportError:
-        return state
+    return jax.device_get(state)
 
 
 def _fully_addressable(state: Any) -> bool:
@@ -70,10 +67,8 @@ def _fully_addressable(state: Any) -> bool:
     non-addressable devices (multi-host meshes), so async_save falls
     back to the sync orbax path — which gathers per-host — for such
     state."""
-    try:
-        import jax
-    except ImportError:
-        return True
+    import jax
+
     return all(
         getattr(leaf, "is_fully_addressable", True)
         for leaf in jax.tree.leaves(state)

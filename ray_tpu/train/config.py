@@ -18,11 +18,12 @@ class ScalingConfig:
     scale is a device mesh. `num_workers` is the number of host
     processes in the gang (1 = single-controller); `mesh` is the
     per-gang parallelism layout; `resources_per_worker` feeds the
-    placement-group request when the gang is scheduled on a cluster.
+    placement-group request when the gang is scheduled on a cluster
+    (without a "TPU" entry each worker leases an equal share of the
+    chips the cluster advertises — see WorkerGroup).
     """
 
     num_workers: int = 1
-    use_tpu: bool = True
     mesh: Optional[MeshSpec] = None
     resources_per_worker: Optional[Dict[str, float]] = None
 

@@ -36,18 +36,10 @@ from ..models.llama import (
     param_annotations,
 )
 from ..ops.norms import rotary_embedding
+from ..parallel.collective import pcast_varying
 from ..parallel.pipeline import broadcast_from_last_stage, spmd_pipeline
 from ..parallel.sharding import Annotated, checked_shard_map
 from .train_step import TrainState, infer_opt_shardings
-
-
-def _promote(x, axes):
-    """Mark x varying over `axes` (no-op per axis when already so, or
-    on a jax predating pcast) — required before psum/pmean under
-    jax >= 0.7's varying-manual-axes check."""
-    from ..parallel.collective import pcast_varying
-
-    return pcast_varying(x, axes)
 
 
 def to_pipeline_params(params: Any, pp: int) -> Any:
@@ -160,7 +152,9 @@ def make_pp_train_step(
             # MoE aux loss rides spmd_pipeline's rank-local accumulator
             # instead; it must vary over at most pp — average the data
             # axes here.
-            aux = _promote(jnp.sum(auxs), ("sp", "ep"))
+            # Varying over sp/ep before the psum/pmean below (the
+            # varying-manual-axes check requires it).
+            aux = pcast_varying(jnp.sum(auxs), ("sp", "ep"))
             return h, lax.pmean(aux, ("sp", "ep"))
 
         outs, aux_local = spmd_pipeline(
@@ -180,7 +174,9 @@ def make_pp_train_step(
         # Reduce over BOTH data axes unconditionally (even size-1 axes
         # carry a formal varying mark from the batch in_spec, and
         # out_specs=P() demands a fully unvarying scalar).
-        local = _promote(jnp.stack([nll_sum, count]), ("sp", "ep"))
+        local = pcast_varying(
+            jnp.stack([nll_sum, count]), ("sp", "ep")
+        )
         local = lax.psum(local, ("sp", "ep"))
         xent = local[0] / jnp.maximum(local[1], 1.0)
         return xent + cfg.moe_aux_weight * aux

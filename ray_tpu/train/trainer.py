@@ -8,9 +8,17 @@ TrainingIterator gathers results; failures restart from the latest
 checkpoint up to FailureConfig.max_failures (backend_executor.py:759).
 
 TPU-native shape: `num_workers=1` is the single-controller JAX mode —
-the loop runs in-process and pjit spans every device the process sees
-(a whole slice on real pods). `num_workers>1` builds an actor gang via
+one process runs the loop and pjit spans every device it sees (a
+whole slice on real pods). `num_workers>1` builds an actor gang via
 WorkerGroup + JaxBackend rendezvous for multi-host DCN setups.
+
+Who owns the chips: a chip belongs to one process at a time. When the
+runtime advertises chips, the single-controller loop runs in a
+one-member gang whose worker leases them and exits when `fit` returns,
+so a driver that trains and then serves never holds the chip its
+replica needs. With no chips advertised (or no runtime) the loop runs
+in the calling process, as it does inside a worker that already holds
+its chips.
 """
 
 from __future__ import annotations
@@ -23,6 +31,7 @@ from typing import Any, Callable, Dict, Optional
 from .backend import Backend, JaxBackend
 from .checkpoint import wait_for_checkpoints
 from .config import Result, RunConfig, ScalingConfig
+from ..util.accelerators.tpu import cluster_tpu_chips
 from .session import TrainContext, clear_session, init_session
 from .worker_group import WorkerGroup
 
@@ -71,7 +80,9 @@ class JaxTrainer:
 
     # ------------------------------------------------------------------
     def _fit_once(self, name: str, storage: str) -> Result:
-        if self.scaling_config.num_workers <= 1:
+        if self.scaling_config.num_workers <= 1 and (
+            os.environ.get("RT_WORKER_CHIPS") or not cluster_tpu_chips()
+        ):
             return self._fit_local(name, storage)
         return self._fit_gang(name, storage)
 
@@ -153,7 +164,7 @@ class JaxTrainer:
         """Multi-worker gang over the actor runtime (reference:
         BackendExecutor.start + start_training)."""
         group = WorkerGroup(
-            self.scaling_config.num_workers,
+            max(1, self.scaling_config.num_workers),
             self.scaling_config.resources_per_worker,
         )
         try:
@@ -164,7 +175,7 @@ class JaxTrainer:
                 self._loop_args(),
                 trial_dir=storage,
                 dataset_shards_per_rank=self._make_gang_shards(
-                    self.scaling_config.num_workers
+                    group.size
                 ),
             )
         finally:

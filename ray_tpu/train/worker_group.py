@@ -14,6 +14,7 @@ from typing import Any, Callable, List, Optional
 
 from .. import api as rt
 from ..actor import ActorHandle
+from ..util.accelerators.tpu import cluster_tpu_chips
 
 
 class _TrainWorker:
@@ -64,6 +65,11 @@ class _TrainWorker:
 
 
 class WorkerGroup:
+    """Each member hosts a JAX program, so unless the caller says
+    otherwise it leases an equal share of the chips the cluster
+    advertises (one host's chip set per worker on a pod; none on a
+    CPU cluster) and the daemon scopes its process to them."""
+
     def __init__(
         self,
         num_workers: int,
@@ -71,9 +77,11 @@ class WorkerGroup:
     ):
         self.size = num_workers
         options = dict(resources_per_worker or {})
+        if "TPU" not in options:
+            options["TPU"] = cluster_tpu_chips() // num_workers
         actor_cls = rt.remote(
             num_cpus=options.pop("CPU", 1),
-            num_tpus=options.pop("TPU", 0),
+            num_tpus=options.pop("TPU"),
             resources=options or None,
         )(_TrainWorker)
         self.workers: List[ActorHandle] = [

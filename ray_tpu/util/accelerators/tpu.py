@@ -37,6 +37,20 @@ def get_num_tpu_chips_on_node() -> int:
     return TPUAcceleratorManager.get_current_node_num_accelerators()
 
 
+def cluster_tpu_chips() -> int:
+    """Chips the connected cluster advertises (0 with no runtime, or
+    none detected). Libraries whose actors host a JAX program size
+    their `num_tpus` request from this, so the same call lands on the
+    chip where there is one and on the CPU where there is not."""
+    from ..._private.worker import global_worker
+
+    worker = global_worker()
+    if worker is None:
+        return 0
+    resources = worker.call("cluster_resources")["resources"]
+    return int(resources.get("TPU", 0))
+
+
 def slice_placement_group(
     pod_type: str,
     pod_name: Optional[str] = None,
@@ -63,6 +77,7 @@ def slice_placement_group(
 
 
 __all__ = [
+    "cluster_tpu_chips",
     "get_current_pod_name",
     "get_current_pod_worker_count",
     "get_num_tpu_chips_on_node",

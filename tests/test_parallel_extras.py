@@ -95,9 +95,6 @@ def test_spmd_pipeline_differentiable():
             h = jnp.tanh(h @ p["w"])
         return jnp.mean(h**2)
 
-    # checked_shard_map: jax 0.4's replication checker rejects the
-    # (correct) ppermute-transpose grad program; the helper disables
-    # the check only there.
     from ray_tpu.parallel.sharding import checked_shard_map
 
     sharded_loss = jax.jit(

@@ -20,27 +20,6 @@ def _mesh(pp, sp, ep):
     return Mesh(devs, ("pp", "sp", "ep"))
 
 
-def _jax_version() -> tuple:
-    return tuple(
-        int(part) for part in jax.__version__.split(".")[:2]
-    )
-
-
-#: jax 0.4.x shard_map mis-transposes the pp x ep MoE compose (the
-#: grad of the ppermute/all-to-all sandwich; CHANGES.md PR 12 — the
-#: 2 tests below are the documented known-failing pair on 0.4.37).
-#: Version-gated, NOT xfailed: on jax >= 0.6 the checker is back on
-#: and a regression here must fail loudly.
-_SHARD_MAP_TRANSPOSE_BUG = pytest.mark.skipif(
-    _jax_version() < (0, 6),
-    reason=(
-        "jax < 0.6 shard_map transpose bug breaks the pp x ep MoE "
-        "compose (documented known-failing on 0.4.37; see "
-        "CHANGES.md PR 12)"
-    ),
-)
-
-
 def _run_steps(cfg, mesh, batch, seq, steps=3, num_mb=2):
     init_fn, step_fn = make_pp_train_step(
         cfg, mesh, default_optimizer(learning_rate=1e-2, total_steps=10),
@@ -73,7 +52,6 @@ def test_pp_sp_dense_loss_decreases():
     assert losses[-1] < losses[0], losses
 
 
-@_SHARD_MAP_TRANSPOSE_BUG
 def test_pp_ep_moe_loss_decreases():
     if len(jax.devices()) < 4:
         pytest.skip("needs 4 devices")
@@ -87,7 +65,6 @@ def test_pp_ep_moe_loss_decreases():
     assert losses[-1] < losses[0], losses
 
 
-@_SHARD_MAP_TRANSPOSE_BUG
 def test_pp_sp_ep_full_compose():
     """The full pp x sp x ep stack in one program (8 devices)."""
     if len(jax.devices()) < 8:

@@ -258,3 +258,37 @@ def test_spawn_watcher_judgment():
             daemon._registered_pids_ever.discard(reg_pid)
     finally:
         rt.shutdown()
+
+
+def test_chips_pass_from_a_dead_actor_to_its_successor():
+    """One process per chip set, on a fake 4-chip node: a killed
+    actor's chips go to whoever queued for them at once (its death
+    must wake the scheduler — train-then-serve hands the chip over
+    this way), an idle pooled TPU worker of another shape makes way,
+    and one-chip actors land on distinct chips."""
+    rt.init(num_cpus=4, num_tpus=4)
+    try:
+        @rt.remote(num_cpus=0)
+        class Holder:
+            def chips(self):
+                return rt.get_runtime_context().get_accelerator_ids()["TPU"]
+
+        whole = Holder.options(num_tpus=4)
+        first = whole.remote()
+        assert rt.get(first.chips.remote(), timeout=30) == list("0123")
+        rt.kill(first)
+        second = whole.remote()  # queued while `first` still holds them
+        assert rt.get(second.chips.remote(), timeout=30) == list("0123")
+        rt.kill(second)
+
+        @rt.remote(num_tpus=4, num_cpus=0)
+        def task_chips():
+            return rt.get_runtime_context().get_accelerator_ids()["TPU"]
+
+        # Leaves an idle 4-chip worker in the pool, holding every chip.
+        assert rt.get(task_chips.remote(), timeout=30) == list("0123")
+        singles = [Holder.options(num_tpus=1).remote() for _ in range(3)]
+        held = rt.get([a.chips.remote() for a in singles], timeout=30)
+        assert sorted(held) == [["0"], ["1"], ["2"]]
+    finally:
+        rt.shutdown()
