@@ -1,0 +1,14 @@
+"""90th percentile of due-to-first-token over the window's requests.
+With some twenty requests in a window it lies between the second and
+third largest sample and swings with the arrival draw. Recorded, not
+judged, like the median beside it."""
+
+from benchmark.stats import percentile, ttfts_ms
+
+LAYER, UNIT, SOURCE = "client", "ms", "host_clock"
+
+
+def reduce(run: dict):
+    if run.get("loop") != "open":
+        return None
+    return percentile(ttfts_ms(run["requests"]), 90.0)

@@ -1,0 +1,523 @@
+"""Tests of the yardstick itself (BENCHMARK.json + benchmark/), on the
+CPU at tiny sizes: the manifest is legal, every cell's files are found
+by name, the generators repeat, the arithmetic is right, and a fifth
+cell can be added as new files plus one entry."""
+
+import importlib.util
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import flops, harness, stats  # noqa: E402
+from benchmark.traffic import lengths, serve_closed, serve_open, train_stream  # noqa: E402
+from benchmark.trace import xplane  # noqa: E402
+
+MANIFEST = harness.load_manifest()
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+CELLS = [c["name"] for c in MANIFEST["workloads"]]
+E2E = [m["name"] for m in MANIFEST["end_to_end"]]
+LAYER = [m["name"] for m in MANIFEST["per_layer"]]
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+
+
+# -- the manifest -----------------------------------------------------
+
+def test_manifest_has_exactly_the_contract_keys():
+    assert set(MANIFEST) == {
+        "command", "paths", "run_seconds", "configs", "workloads",
+        "end_to_end", "per_layer",
+    }
+    assert isinstance(MANIFEST["run_seconds"], int)
+    assert 1 <= MANIFEST["run_seconds"] <= 51
+    assert len(json.dumps(MANIFEST)) < 64 * 1024
+    assert all(
+        not w.startswith("/") and ".." not in w for w in MANIFEST["command"]
+    )
+
+
+def test_manifest_run_budget_fits_with_24_cells():
+    s = MANIFEST["run_seconds"]
+    assert (2 + 14 * 24) * (s + 60) + 24 * 180 + 1200 <= 43200
+
+
+def test_four_chip_cells_are_at_most_a_quarter_or_one():
+    four = [c for c in MANIFEST["workloads"] if c["chips"] == 4]
+    assert all(c["chips"] in (1, 4) for c in MANIFEST["workloads"])
+    assert len(four) <= max(1, len(CELLS) // 4)
+
+
+@pytest.mark.parametrize("section", ["configs", "workloads", "end_to_end", "per_layer"])
+def test_names_units_and_lines_are_legal(section):
+    entries = MANIFEST[section]
+    names = [e["name"] for e in entries]
+    assert len(names) == len(set(names))
+    for e in entries:
+        assert NAME.match(e["name"]), e["name"]
+        for key in ("why", "layer"):
+            if key in e:
+                assert 1 <= len(e[key]) <= 200
+                assert "\n" not in e[key] and "\t" not in e[key]
+        if "unit" in e:
+            assert UNIT.match(e["unit"]), e["unit"]
+            assert e["better"] in ("lower", "higher")
+            assert e["source"] in SOURCES
+    if section == "end_to_end":
+        assert "setup_s" in names
+        for e in entries:
+            assert set(e) <= {"name", "unit", "better", "bound", "source", "workloads"}
+            assert 0.01 <= e["bound"] <= 0.1
+            assert e["source"] in ("host_clock", "device_trace")
+    if section == "per_layer":
+        for e in entries:
+            assert set(e) <= {"name", "unit", "better", "source", "layer", "moves", "workloads"}
+            assert e["moves"] in E2E
+    if section == "workloads":
+        pairs = [(e["config"], e["traffic"]) for e in entries]
+        assert len(pairs) == len(set(pairs))
+        for e in entries:
+            assert set(e) == {"name", "config", "traffic", "chips", "why"}
+            assert NAME.match(e["traffic"]) and NAME.match(e["config"])
+    if section == "configs":
+        used = {c["config"] for c in MANIFEST["workloads"]}
+        files = [e["file"] for e in entries]
+        assert len(files) == len(set(files))
+        for e in entries:
+            assert set(e) == {"name", "source", "file", "reduced", "why"}
+            assert 1 <= len(e["source"]) <= 200
+            assert e["name"] in used
+            assert any(e["file"].startswith(p + "/") for p in MANIFEST["paths"])
+            assert all(NAME.match(k) for k in e["reduced"])
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_cell_reports_setup_another_end_to_end_and_a_layer_metric(cell):
+    e2e = [m["name"] for m in harness.metrics_of_cell(MANIFEST, "end_to_end", cell)]
+    assert "setup_s" in e2e and len(e2e) >= 2
+    per_layer = harness.metrics_of_cell(MANIFEST, "per_layer", cell)
+    assert per_layer
+    # a per-layer metric is reported only where the metric it moves is
+    assert all(m["moves"] in e2e for m in per_layer)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_cell_finds_its_files_by_name(cell):
+    entry = harness.find_cell(MANIFEST, cell)
+    config = harness.load_config(MANIFEST, entry["config"])
+    traffic = harness.load_traffic(entry["traffic"])
+    generator = harness.load_module("traffic", traffic["kind"])
+    driver = harness.load_module("drivers", generator.DRIVER)
+    assert callable(generator.generate) and callable(driver.run)
+    assert config["name"] == entry["config"]
+    assert ("trainer" in config) == (generator.DRIVER == "train")
+    assert ("engine" in config) == (generator.DRIVER == "serve")
+
+
+@pytest.mark.parametrize("metric", E2E)
+def test_end_to_end_reader_exists(metric):
+    assert callable(harness.load_module("end_to_end", metric).reduce)
+
+
+@pytest.mark.parametrize("metric", LAYER)
+def test_layer_reader_agrees_with_the_manifest(metric):
+    entry = next(m for m in MANIFEST["per_layer"] if m["name"] == metric)
+    module = harness.load_module("layer_metrics", harness.reader_name(metric))
+    assert callable(module.reduce)
+    assert (module.LAYER, module.UNIT, module.SOURCE) == (
+        entry["layer"], entry["unit"], entry["source"]
+    )
+    assert set(entry["workloads"]) <= set(CELLS)
+
+
+def test_layer_names_are_the_ones_perf_md_lists():
+    with open(os.path.join(ROOT, "PERF.md")) as f:
+        text = f.read()
+    for layer in {m["layer"] for m in MANIFEST["per_layer"]}:
+        assert f"| {layer} |" in text, layer
+
+
+# -- configurations ---------------------------------------------------
+
+PUBLISHED = {
+    "qwen2.5-3b": dict(
+        hidden_size=2048, num_hidden_layers=36, num_attention_heads=16,
+        num_key_value_heads=2, intermediate_size=11008, vocab_size=151936,
+        rope_theta=1000000.0, rms_norm_eps=1e-06,
+    ),
+    "mistral-7b-v0.3-l4": dict(
+        hidden_size=4096, num_hidden_layers=32, num_attention_heads=32,
+        num_key_value_heads=8, intermediate_size=14336, vocab_size=32768,
+        rope_theta=1000000.0, rms_norm_eps=1e-05,
+    ),
+}
+PUBLISHED["mistral-7b-v0.3-l8"] = PUBLISHED["mistral-7b-v0.3-l4"]
+
+
+@pytest.mark.parametrize("name", sorted(PUBLISHED))
+def test_config_keeps_published_widths_and_lists_every_cut(name):
+    entry = next(c for c in MANIFEST["configs"] if c["name"] == name)
+    config = harness.load_config(MANIFEST, name)
+    assert config["source"] == entry["source"]
+    assert sorted(config["reduced"]) == sorted(entry["reduced"])
+    changed = [k for k, v in PUBLISHED[name].items() if config[k] != v]
+    assert changed == entry["reduced"]
+    assert all(k == "num_hidden_layers" for k in changed)  # never a width
+    for key in changed:
+        assert config["reduced"][key]["published"] == PUBLISHED[name][key]
+        assert config["reduced"][key]["here"] == config[key]
+    model = config["model"]
+    assert (
+        model["dim"], model["n_layers"], model["n_heads"], model["n_kv_heads"],
+        model["intermediate"], model["vocab_size"], model["rope_theta"],
+        model["norm_eps"],
+    ) == tuple(config[k] for k in (
+        "hidden_size", "num_hidden_layers", "num_attention_heads",
+        "num_key_value_heads", "intermediate_size", "vocab_size",
+        "rope_theta", "rms_norm_eps",
+    ))
+    assert config["assumed"] and config["deployment"]
+
+
+# -- traffic ----------------------------------------------------------
+
+def _rehearsal(name):
+    return harness.apply_rehearsal(harness.load_traffic(name))
+
+
+def test_quantile_lengths_are_a_fixed_sorted_multiset_within_bounds():
+    spec = {"dist": "lognormal", "median": 256, "sigma": 1.0, "min": 32, "max": 2048}
+    a = lengths.quantile_lengths(spec, 101)
+    assert a == sorted(a) and a[0] >= 32 and a[-1] <= 2048
+    assert a[50] == 256  # the median is the median
+    assert lengths.quantile_lengths({"dist": "uniform", "min": 0, "max": 100}, 4) == [12, 38, 62, 88]
+
+
+@pytest.mark.parametrize("traffic", ["chat_open"])
+def test_open_loop_repeats_from_a_seed_and_keeps_the_multiset(traffic):
+    params = harness.load_traffic(traffic)
+    a = serve_open.generate(params, 7, 20.0, 1000)
+    b = serve_open.generate(params, 7, 20.0, 1000)
+    c = serve_open.generate(params, 8, 20.0, 1000)
+    assert a == b and a != c
+    n = round(params["rate_per_s"] * 20.0)
+    assert len(a["requests"]) == len(c["requests"]) == n
+    due = [r["due_s"] for r in a["requests"]]
+    assert due == sorted(due) and 0 <= due[0] and due[-1] < 20.0
+    for key in (lambda r: len(r["prompt"]), lambda r: r["max_new_tokens"]):
+        assert sorted(map(key, a["requests"])) == sorted(map(key, c["requests"]))
+    assert all(
+        len(r["prompt"]) + r["max_new_tokens"] <= params["max_total_tokens"]
+        and all(1 <= t < 1000 for t in r["prompt"])
+        for r in a["requests"]
+    )
+
+
+def test_closed_loop_shares_documents_group_docs_apart():
+    params = harness.load_traffic("docqa_closed")
+    a = serve_closed.generate(params, 3, 10.0, 5000)
+    b = serve_closed.generate(params, 3, 10.0, 5000)
+    n, docs = 2 * params["group_docs"] * params["questions_per_doc"], params["group_docs"]
+    ra = [next(a["requests"]) for _ in range(n)]
+    rb = [next(b["requests"]) for _ in range(n)]
+    assert ra == rb and a["clients"] == params["clients"]
+    q = params["question_tokens"]
+    for i, r in enumerate(ra[: n // 2]):
+        doc = r["prompt"][:-q]
+        assert params["document_tokens"]["min"] <= len(doc) <= params["document_tokens"]["max"]
+        ask = i // docs
+        assert r["shared_tokens"] == (len(doc) if ask else 0)
+        if ask:
+            assert ra[i - docs]["prompt"][:-q] == doc  # same document
+            assert ra[i - docs]["prompt"] != r["prompt"]  # another question
+    first, second = ra[: n // 2], ra[n // 2:]
+    assert sorted(len(r["prompt"]) for r in first) == sorted(len(r["prompt"]) for r in second)
+    assert first[0]["prompt"] != second[0]["prompt"]
+    warm = {tuple(r["prompt"]) for r in a["warmup"]}
+    assert not warm & {tuple(r["prompt"]) for r in ra}
+
+
+def test_train_stream_repeats_from_a_seed():
+    params = _rehearsal("stream_8k")
+    a = train_stream.generate(params, 5, 4, 512)
+    b = train_stream.generate(params, 5, 4, 512)
+    c = train_stream.generate(params, 6, 4, 512)
+    assert np.array_equal(a["tokens"], b["tokens"])
+    assert not np.array_equal(a["tokens"], c["tokens"])
+    assert a["batch"] == 4 * params["sequences_per_chip"]
+    assert a["tokens"].shape == (a["batch"] * params["max_steps"], params["seq_len"] + 1)
+    assert a["tokens"].dtype == np.int32 and a["tokens"].max() < 512
+
+
+# -- arithmetic -------------------------------------------------------
+
+def test_a_cut_request_counts_what_it_streamed_and_a_failed_one_nothing():
+    cut = {"ok": False, "cut": True, "due_s": 1.0, "sent_s": 1.0, "first_s": 1.5,
+           "done_s": 2.6, "token_s": [1.5, 1.7, 2.3], "n_prompt": 30, "status": 200}
+    waiting = {"ok": False, "cut": True, "due_s": 1.8, "sent_s": 1.8, "done_s": 2.6,
+               "token_s": [], "n_prompt": 50, "status": 0}
+    failed = {"ok": False, "cut": False, "due_s": 0.2, "sent_s": 0.2, "first_s": 0.3,
+              "done_s": 0.4, "token_s": [0.3, 0.35], "n_prompt": 70, "status": 200}
+    shed = {"ok": False, "cut": False, "due_s": 0.5, "sent_s": 0.5, "done_s": 0.6,
+            "token_s": [], "n_prompt": 90, "status": 503}
+    rows = [cut, waiting, failed, shed]
+    assert [stats.served(r) for r in rows] == [True, True, False, False]
+    # the one cut before its first token had waited 0.8 s by then
+    assert stats.ttfts_ms(rows) == pytest.approx([500.0, 800.0])
+    # only gaps that ended inside the window, only of served requests
+    assert stats.pooled_gaps_ms(rows, 2.0) == pytest.approx([200.0])
+    assert stats.pooled_gaps_ms(rows) == pytest.approx([200.0, 600.0])
+    run = {"requests": rows, "window_s": 2.0, "loop": "open",
+           "engine": {"before": {"prefix_tokens_saved": 5},
+                      "after": {"prefix_tokens_saved": 20}}}
+    read = lambda d, m: harness.load_module(d, m).reduce(run)
+    assert read("end_to_end", "serve_tokens_per_s") == pytest.approx((30 + 2) / 2.0)
+    assert read("end_to_end", "itl_mean_ms") == pytest.approx(200.0)
+    assert read("layer_metrics", "shed_share") == pytest.approx(25.0)
+    # saved tokens over the prompts whose first token had come, the
+    # failed stream's too (the engine did prefill it): 15 of 30 + 70
+    assert read("layer_metrics", "prefix_hit_token_share") == pytest.approx(15.0)
+
+
+@pytest.mark.parametrize("q", [0, 25, 50, 90, 99, 100])
+def test_percentile_is_numpys(q):
+    values = list(np.random.default_rng(q).normal(size=57))
+    assert stats.percentile(values, q) == pytest.approx(np.percentile(values, q))
+    assert stats.percentile([], q) is None
+    assert stats.percentile([3.0], q) == 3.0
+
+
+def test_spread_lateness_and_gaps():
+    assert stats.spread([1, 2, 3, 4, 5]) == pytest.approx((4 - 2) / 3)
+    assert stats.lateness_ms([1.0, 2.0], [1.004, 1.9]) == pytest.approx([4.0, 0.0])
+    assert stats.token_gaps_ms([0.0, 0.01, 0.04]) == pytest.approx([10.0, 30.0])
+
+
+def test_end_to_end_readers_on_a_hand_made_run():
+    requests = [
+        {"ok": True, "due_s": 0.0, "sent_s": 0.0, "first_s": 0.1, "done_s": 0.5,
+         "token_s": [0.1, 0.2, 0.5], "n_prompt": 10, "n_out": 3, "status": 200},
+        {"ok": True, "due_s": 1.0, "sent_s": 1.1, "first_s": 1.4, "done_s": 2.5,
+         "token_s": [1.4, 1.5], "n_prompt": 20, "n_out": 2, "status": 200},
+    ]
+    run = {"requests": requests, "window_s": 2.0, "loop": "open"}
+    read = lambda d, m: harness.load_module(d, m).reduce(run)
+    assert read("layer_metrics", "ttft_p50_ms") == pytest.approx(250.0)
+    assert read("end_to_end", "itl_mean_ms") == pytest.approx(500 / 3)
+    assert read("layer_metrics", "ttft_p90_ms") == pytest.approx(100 + 0.9 * 300)
+    assert read("end_to_end", "itl_p95_ms") == pytest.approx(
+        np.percentile([100, 300, 100], 95)
+    )
+    assert read("layer_metrics", "itl_p99_ms") == pytest.approx(
+        np.percentile([100, 300, 100], 99)
+    )
+    # request 1 whole (10 + 3), request 2's prompt and its tokens by 2.0 s
+    assert read("end_to_end", "serve_tokens_per_s") == pytest.approx((13 + 22) / 2.0)
+    assert read("layer_metrics", "gen_late_p99_ms") == pytest.approx(99.0)
+    assert read("layer_metrics", "shed_share") == 0.0
+    run = {"steps": [[0.5, 500, 5, 5], [1.0, 500, 0, 10]], "tokens_per_step": 8192,
+           "cell": {"chips": 1}}
+    assert read("end_to_end", "train_tokens_per_s_chip") == pytest.approx(16384.0)
+    assert read("layer_metrics", "data_wait_share") == pytest.approx(2.0)
+
+
+def test_flops_against_a_hand_count():
+    model = {"dim": 8, "n_layers": 2, "n_heads": 2, "n_kv_heads": 1,
+             "intermediate": 16, "vocab_size": 100}
+    # per layer: wq 8*8 + wk,wv 2*8*4 + wo 8*8 + mlp 3*8*16 = 576; head 800
+    assert flops.matmul_params(model) == 2 * 576 + 800 == 1952
+    # attention forward per token: layers * 2 matmuls * 2 * width 8 * (4+1)/2 keys
+    assert flops.attention_flops_per_token_fwd(model, 4) == 2 * 2 * 2 * 8 * 2.5 == 160
+    assert flops.train_flops_per_token(model, 4) == 3 * (2 * 1952 + 160)
+    mistral = harness.load_config(MANIFEST, "mistral-7b-v0.3-l4")["model"]
+    assert flops.matmul_params(mistral) == 4 * 218103808 + 4096 * 32768
+    # the program's count has the embedding lookup in it; ours does not
+    from ray_tpu.models.llama import LlamaConfig
+    program = LlamaConfig(**mistral).num_params()
+    assert program - flops.matmul_params(mistral) == 4096 * 32768 + 4 * 2 * 4096 + 4096
+
+
+def test_peaks_name_their_source_and_refuse_an_unknown_chip():
+    v5e = flops.peaks_for("TPU v5 lite")
+    assert v5e["bf16_flops_per_s"] == 197e12 and v5e["hbm_bytes_per_s"] == 819e9
+    with pytest.raises(flops.UnknownDevice):
+        flops.peaks_for("cpu")
+    with pytest.raises(flops.UnknownDevice):
+        flops.peaks_for("_source")
+
+
+# -- the load generator's senders -------------------------------------
+
+@pytest.fixture
+def token_server():
+    """Streams `max_new_tokens` tokens, one every 10 ms, as the
+    replica does: ASCII decimal and a trailing space."""
+    import http.server
+    import threading
+    import time
+
+    class Handler(http.server.BaseHTTPRequestHandler):
+        def do_POST(self):
+            body = json.loads(self.rfile.read(int(self.headers["Content-Length"])))
+            self.send_response(200)
+            self.end_headers()
+            try:
+                for i in range(body["max_new_tokens"]):
+                    self.wfile.write(f"{100 + i} ".encode())
+                    self.wfile.flush()
+                    time.sleep(0.01)
+            except OSError:
+                pass
+
+        def log_message(self, *args):
+            pass
+
+    server = http.server.ThreadingHTTPServer(("127.0.0.1", 0), Handler)
+    server.daemon_threads = True
+    threading.Thread(target=server.serve_forever, daemon=True).start()
+    yield server.server_address[1]
+    server.shutdown()
+    server.server_close()
+
+
+def _clock():
+    import time
+
+    t0 = time.perf_counter()
+    return lambda: time.perf_counter() - t0
+
+
+def test_open_loop_sender_cuts_what_is_in_flight_after_the_drain(token_server):
+    import threading
+    from concurrent.futures import ThreadPoolExecutor
+
+    from benchmark.drivers import serve
+
+    requests = [
+        {"due_s": 0.0, "prompt": [1, 2, 3], "max_new_tokens": 5},
+        {"due_s": 0.1, "prompt": [4, 5], "max_new_tokens": 10_000},
+    ]
+    clock, pool, stop = _clock(), ThreadPoolExecutor(4), threading.Event()
+    rows = serve.offer_open(pool, token_server, requests, clock, stop)
+    serve.finish(pool, rows, clock, 0.5, stop)
+    done, endless = rows
+    assert done["ok"] and not done["cut"] and done["tokens"] == [100, 101, 102, 103, 104]
+    assert not endless["ok"] and endless["cut"] and endless["status"] == 200
+    assert 0.5 <= endless["done_s"] < 1.5 and clock() < 2.0  # cut, not waited for
+    n = endless["n_out"]
+    assert 10 <= n < 10_000 and endless["tokens"] == list(range(100, 100 + n))
+    assert len(endless["token_s"]) == n and endless["first_s"] >= 0.1
+    assert all("sock" not in r for r in rows)
+
+
+def test_closed_loop_callers_stop_at_the_windows_edge(token_server):
+    import itertools
+    import threading
+    from concurrent.futures import ThreadPoolExecutor
+
+    from benchmark.drivers import serve
+
+    stream = (
+        {"prompt": [i], "max_new_tokens": 8, "shared_tokens": 0}
+        for i in itertools.count()
+    )
+    clock, pool, stop = _clock(), ThreadPoolExecutor(8), threading.Event()
+    rows = serve.offer_closed(pool, token_server, stream, 3, 0.6, clock, stop)
+    import time
+
+    time.sleep(max(0.0, 0.6 - clock()))
+    serve.finish(pool, rows, clock, 0.6, stop)
+    assert clock() < 1.5 and len(rows) >= 6
+    assert all(r["ok"] or r["cut"] for r in rows) and sum(r["cut"] for r in rows) <= 3
+    assert all(r["due_s"] == r["sent_s"] < 0.6 for r in rows)
+    assert next(stream)["prompt"] == [len(rows)]  # nothing taken after the edge
+
+
+# -- the trace reduction ----------------------------------------------
+
+def test_xplane_summary_of_a_hand_made_stream():
+    ms = 1e6
+    ops = [
+        ["%while.1 = while(...)", 0 * ms, 10 * ms],       # container
+        ["%fusion.1 = fusion(...)", 0 * ms, 4 * ms],
+        ["%all-gather.2 = all-gather(...)", 4 * ms, 2 * ms],
+        ["%fusion.2 = fusion(...)", 6 * ms, 4 * ms],
+        ["%custom-call.7 = custom-call(...)", 15 * ms, 5 * ms],  # after a 5 ms gap
+    ]
+    host = [["python", "engine.step", 9 * ms, 7 * ms], ["python", "whole", 0, 100 * ms]]
+    out = xplane.summarize({"device": {"/device:TPU:0": ops}, "host": host})
+    assert out["planes"] == 1
+    assert out["window_s"] == pytest.approx(0.020)
+    assert out["busy_s"] == pytest.approx(0.015)
+    assert out["collective_exposed_s"] == pytest.approx(0.002)
+    assert dict(out["device_ops"])["fusion"] == pytest.approx(0.008)
+    assert "while" not in dict(out["device_ops"])
+    assert out["idle_gaps"][0] == ["engine.step", pytest.approx(0.005)]
+
+
+def test_xplane_reduction_of_the_recorded_trace():
+    path = os.path.join(ROOT, "benchmark", "trace", "recorded_v5e.json")
+    with open(path) as f:
+        recorded = json.load(f)
+    out = xplane.summarize(recorded["events"])
+    want = recorded["summary"]
+    assert out["planes"] == want["planes"] >= 1
+    for key in ("window_s", "busy_s", "collective_exposed_s"):
+        assert out[key] == pytest.approx(want[key])
+    assert 0 < out["busy_s"] <= out["window_s"]
+    assert [n for n, _ in out["device_ops"]] == [n for n, _ in want["device_ops"]]
+
+
+def test_xplane_reads_a_profile_written_here(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    f = jax.jit(lambda x: (x @ x).sum())
+    x = jnp.ones((64, 64))
+    f(x).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation("bench.step"):
+        f(x).block_until_ready()
+    jax.profiler.stop_trace()
+    events = xplane.read(xplane.find_xplane(str(tmp_path)))
+    assert events["device"] == {}  # a CPU has no device plane
+    assert any(name == "bench.step" for _, name, _, _ in events["host"])
+    assert xplane.summarize(events) == {"planes": 0}
+
+
+# -- the reference ----------------------------------------------------
+
+def test_reference_agrees_with_the_program_on_a_tiny_model():
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.reference import llama_ref
+    from ray_tpu.models.llama import LlamaConfig, forward, init_params
+
+    model = dict(vocab_size=97, dim=32, n_layers=3, n_heads=4, n_kv_heads=2,
+                 intermediate=48, rope_theta=1e6, max_seq_len=64,
+                 norm_eps=1e-5, attn_bias=True)
+    cfg = LlamaConfig(**model, dtype=jnp.float32, attention="reference")
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    # biases are zero at init: make them count
+    params["layers"]["bq"] = params["layers"]["bq"] + 0.3
+    params["layers"]["bk"] = params["layers"]["bk"] - 0.2
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (40,), 0, 97)
+    got = forward(params, tokens[None], cfg)[0]
+    want = llama_ref.forward(params, tokens, model, q_block=16)
+    assert llama_ref.relative_rms_error(got, want) < 1e-5
+    # a path in lower precision must fail a bf16-sized tolerance's tenth
+    low = forward(
+        jax.tree.map(lambda x: x.astype(jnp.bfloat16), params), tokens[None],
+        LlamaConfig(**model, dtype=jnp.bfloat16, attention="reference"),
+    )[0]
+    assert llama_ref.relative_rms_error(low, want) > 1e-3
