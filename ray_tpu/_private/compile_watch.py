@@ -688,17 +688,23 @@ class WatchedFunction:
         return self._fn
 
     def stats(self) -> Dict[str, Any]:
-        """This program's compile counts from the process registry
-        (the `engine_stats` surface: a mid-traffic recompile is an
-        engine bug — now a visible counter)."""
-        with _lock:
-            row = _programs.get(self.name)
-            if row is None:
-                return {"compiles": 0, "distinct_shapes": 0}
-            return {
-                "compiles": row["compiles"],
-                "distinct_shapes": len(row["digests"]),
-            }
+        """This program's compile counts (`program_stats`)."""
+        return program_stats(self.name)
+
+
+def program_stats(name: str) -> Dict[str, Any]:
+    """One registered program's compile counts from the process
+    registry, by the name it was instrumented under (the
+    `engine_stats` surface: a mid-traffic recompile is an engine bug,
+    and a visible counter)."""
+    with _lock:
+        row = _programs.get(name)
+        if row is None:
+            return {"compiles": 0, "distinct_shapes": 0}
+        return {
+            "compiles": row["compiles"],
+            "distinct_shapes": len(row["digests"]),
+        }
 
 
 def instrument(name: str, fn: Callable) -> WatchedFunction:

@@ -2593,6 +2593,15 @@ class NodeDaemon:
         num_returns = msg["num_returns"]
         timeout = msg.get("wait_timeout")
         state = {"done": False}
+        # Cancelled as soon as the wait is answered: a Timer is a
+        # thread that otherwise lives out its whole timeout, and a
+        # token stream waits once per chunk with a 60 s bound — some
+        # thousands of parked threads in the head's process within a
+        # minute of serving (PERF.md, PR 23).
+        timer = (
+            threading.Timer(timeout, lambda: check_and_reply(force=True))
+            if timeout is not None else None
+        )
 
         def check_and_reply(force: bool = False):
             with self._lock:
@@ -2612,6 +2621,8 @@ class NodeDaemon:
                     conn.reply(
                         msg["_mid"], {"ready": ready, "remaining": remaining}
                     )
+                    if timer is not None:
+                        timer.cancel()
 
         with self._lock:
             for o in oids:
@@ -2620,8 +2631,10 @@ class NodeDaemon:
                     entry.waiters.append(
                         (_CallbackConn(check_and_reply), None)
                     )
-        if timeout is not None:
-            threading.Timer(timeout, lambda: check_and_reply(force=True)).start()
+        if timer is not None:
+            # Already cancelled if a waiter answered meanwhile: the
+            # thread then starts and ends at once.
+            timer.start()
         check_and_reply()
         return DEFERRED
 

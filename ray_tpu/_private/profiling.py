@@ -249,28 +249,27 @@ def capture_gang(
     hz: float = 100.0,
     start_at: Optional[float] = None,
 ) -> dict:
-    """One rank's share of a coordinated gang-profile window. On TPU
-    (and other accelerator) backends the window additionally runs
-    under a `jax.profiler` trace whose artifact directory rides back
-    in the result; everywhere else — and alongside it — the
-    in-process timeline sampler provides the chrome-trace slices the
-    head merges. jax is only touched when the process already
-    imported it; failures degrade to sampler-only, never fail the
-    capture."""
-    import sys as _sys
-
+    """One rank's share of a coordinated gang-profile window. In a
+    process that has imported jax — on any backend, the CPU included,
+    so the path can be rehearsed without a chip — the window also
+    runs under a `jax.profiler` trace whose artifact directory rides
+    back in the result: device operations, and the host phases
+    `step_telemetry.phase_timer` names (the engine loop's, the input
+    path's). Alongside it the in-process timeline sampler provides
+    the chrome-trace slices the head merges. jax is only touched when
+    the process already imported it; failures degrade to sampler-only,
+    never fail the capture."""
     trace_dir = None
     profiler = None
-    if "jax" in _sys.modules:
+    if "jax" in sys.modules:
         try:
+            import tempfile
+
             import jax
 
-            if jax.default_backend() != "cpu":
-                import tempfile
-
-                trace_dir = tempfile.mkdtemp(prefix="rt_gang_trace_")
-                jax.profiler.start_trace(trace_dir)
-                profiler = jax
+            trace_dir = tempfile.mkdtemp(prefix="rt_gang_trace_")
+            jax.profiler.start_trace(trace_dir)
+            profiler = jax
         except Exception:  # noqa: BLE001 — sampler-only fallback
             trace_dir = None
             profiler = None

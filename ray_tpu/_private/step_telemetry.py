@@ -27,6 +27,7 @@ the user-facing surface.
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, Iterator, Optional
@@ -72,38 +73,78 @@ def take_phases() -> Dict[str, float]:
     return phases or {}
 
 
+def _trace_annotation(name: str):
+    """A `jax.profiler.TraceAnnotation` for `name`, or None when this
+    process has not imported jax (telemetry must never be what drags
+    it in). With no profiler session an annotation is one inactive
+    TraceMe; inside one, the phase lands on this thread's line of the
+    profiler's own trace, on the clock the device events use."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return None
+    return profiler.TraceAnnotation(name)
+
+
 class phase_timer:
-    """Context manager billing a consumer-visible stall into `phase`.
+    """Context manager billing a consumer-visible stall into `phase`,
+    and naming it in a running `jax.profiler` trace.
 
     Reentrancy-safe per (thread, phase): only the OUTERMOST active
     timer records. An inner timed region — e.g. a telemetry-wrapped
     iterator pulled through a user's generator transform into
     prefetch_to_device — is already inside the outer timer's wall,
     and billing both would double-count the same stall (driving the
-    derived step_ms = wall - waits negative)."""
+    derived step_ms = wall - waits negative).
 
-    __slots__ = ("_phase", "_outer", "_t0")
+    A loop that is always in one phase or another (the engine's)
+    opens one timer around itself and calls `switch(name)` at every
+    boundary: the old phase ends and the new one starts at ONE clock
+    reading, so the phases partition the loop's wall time exactly,
+    and whatever falls between two statements (a device array freed
+    at a function's return, the interpreter lock handed to another
+    thread) is billed to the phase that was open."""
+
+    __slots__ = ("_phase", "_outer", "_t0", "_annotation")
 
     def __init__(self, phase: str):
         self._phase = phase
 
-    def __enter__(self) -> "phase_timer":
+    def _begin(self, now: float) -> None:
         depths = getattr(_tl, "depths", None)
         if depths is None:
             depths = _tl.depths = {}
         self._outer = not depths.get(self._phase)
         depths[self._phase] = depths.get(self._phase, 0) + 1
-        self._t0 = time.monotonic()
+        self._t0 = now
+        self._annotation = (
+            _trace_annotation(self._phase) if self._outer else None
+        )
+        if self._annotation is not None:
+            self._annotation.__enter__()
+
+    def _end(self, now: float, billed: bool) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        _tl.depths[self._phase] -= 1
+        if self._outer and billed:
+            add_phase(self._phase, (now - self._t0) * 1e3)
+
+    def __enter__(self) -> "phase_timer":
+        self._begin(time.monotonic())
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        _tl.depths[self._phase] -= 1
         # Exhaustion (StopIteration) and errors don't bill the phase.
-        if self._outer and exc_type is None:
-            add_phase(
-                self._phase, (time.monotonic() - self._t0) * 1e3
-            )
+        self._end(time.monotonic(), exc_type is None)
         return False
+
+    def switch(self, phase: str) -> None:
+        """End the open phase and begin `phase` at one clock reading."""
+        now = time.monotonic()
+        self._end(now, True)
+        self._phase = phase
+        self._begin(now)
 
 
 def stalls_active() -> bool:

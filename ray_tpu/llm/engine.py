@@ -77,6 +77,9 @@ from typing import Any, Dict, Iterator, List, Optional
 
 import numpy as np
 
+from .._private import compile_watch
+from .._private.step_telemetry import phase_timer, take_phases
+from ..util import tracing
 from .kv_slots import NULL_BLOCK, PagedKVCache, default_block_len
 from .scheduler import EngineDead, EngineOverloaded, SlotScheduler
 
@@ -148,7 +151,8 @@ class _Request:
         "out", "cancelled", "submitted_ts", "first_token_ts",
         "emitted", "slot", "bucket", "offset", "padded",
         "prefix_keys", "total_blocks", "block_ids", "n_shared",
-        "skip", "gen",
+        "skip", "gen", "submitted_ns", "admitted_ts", "decoding_ts",
+        "trace_parent", "serve_request_id",
     )
 
     def __init__(
@@ -171,6 +175,15 @@ class _Request:
         self.submitted_ts = time.perf_counter()
         self.first_token_ts: Optional[float] = None
         self.emitted = 0
+        # What the `engine.request` span is made of when the request
+        # finishes: epoch start, slot granted, prefill done (both
+        # perf_counter, None if never reached), and the caller's span
+        # and serve request id as they stood at submit().
+        self.submitted_ns = time.time_ns()
+        self.admitted_ts: Optional[float] = None
+        self.decoding_ts: Optional[float] = None
+        self.trace_parent: Optional[dict] = None
+        self.serve_request_id = ""
         # prefill progress (engine thread only)
         self.slot: Optional[int] = None
         self.bucket = 0
@@ -408,36 +421,14 @@ class InferenceEngine:
             "device_kind": device.device_kind,
             "devices": len(jax.devices()),
         }
-        # Compile-watch registration (ISSUE 15 satellite): the
-        # engine's jitted entry points are named programs, so "the
-        # engine compiles ONCE per geometry" (PR 11) is a tested
-        # counter instead of a comment — a mid-traffic recompile is
-        # an engine bug, and now it is a visible one (engine_stats /
-        # /api/serve / verdict.compile). Family rides in the program
-        # NAME (bounded: model families), never a free-form label.
-        from .._private import compile_watch
-
-        fam = family or "default"
-        if cfg is not None:
-            from ..models.generate import (
-                paged_decode_step,
-                paged_prefill,
-            )
-
-            self._paged_prefill = compile_watch.instrument(
-                f"engine.paged_prefill[{fam}]", paged_prefill
-            )
-            self._paged_decode = compile_watch.instrument(
-                f"engine.paged_decode_step[{fam}]", paged_decode_step
-            )
-        if program is not None:
-            # Late-bound through self._program so a swapped/patched
-            # program (tests, hot program replacement) takes effect —
-            # the watcher wraps the CALL, not one captured function.
-            self._program_run = compile_watch.instrument(
-                f"engine.policy[{fam}]",
-                lambda *a, **k: self._program.run(*a, **k),
-            )
+        # The loop's own clock: milliseconds per phase and iterations,
+        # cumulative, written by the loop's thread once an iteration
+        # and read under the lock by stats(); and two exact counters
+        # taken at admission.
+        self._loop_ms: Dict[str, float] = {}
+        self._loop_iterations = 0
+        self._admitted = 0
+        self._admit_wait_ms_total = 0.0
         self._prefilling: Optional[_Request] = None
         self._by_id: Dict[str, _Request] = {}
         self._policy_pending: "deque[_PolicyRequest]" = deque()
@@ -510,6 +501,10 @@ class InferenceEngine:
         )
         req.bucket = bucket
         req.total_blocks = total_blocks
+        from ..serve.observability import get_request_id
+
+        req.trace_parent = tracing.inject_context()
+        req.serve_request_id = get_request_id()  # "" outside serve
         if ec.prefix_cache:
             req.prefix_keys = self._kv.prefix_keys(prompt)
         with self._lock:
@@ -646,21 +641,32 @@ class InferenceEngine:
                 policy_steps=self._policy_steps,
                 policy_rows_served=self._policy_rows_served,
                 dead=self._dead is not None,
+                # Where the loop's thread spent its time, by phase
+                # (cumulative ms; the phases partition the loop's wall
+                # time, so deltas over a window are shares of it), and
+                # how long admitted requests waited for their slot.
+                loop_ms=dict(self._loop_ms),
+                loop_iterations=self._loop_iterations,
+                admitted=self._admitted,
+                admit_wait_ms_total=self._admit_wait_ms_total,
                 **self._device,
             )
-            # Per-family compile counts (compile-watch): prefill /
-            # decode / policy programs, each {compiles,
-            # distinct_shapes}. Steady state after warmup is a FIXED
-            # number — movement under traffic is a recompile bug.
-            compiles: Dict[str, Any] = {}
             if self._kv is not None:
-                compiles["prefill"] = self._paged_prefill.stats()
-                compiles["decode"] = self._paged_decode.stats()
-            if self._program is not None:
-                compiles["policy"] = self._program_run.stats()
-            if compiles:
-                out["compiles"] = compiles
-            if self._kv is not None:
+                # Compile counts of the two programs the LLM path
+                # runs, as the compile watch credits them: under the
+                # names models/generate.py registers (one wrapper a
+                # program; a second one here was credited nothing).
+                # Process-wide, like the jitted programs themselves.
+                # Steady state after warmup is a FIXED number —
+                # movement under traffic is a recompile bug.
+                out["compiles"] = {
+                    "prefill": compile_watch.program_stats(
+                        "generate.paged_prefill"
+                    ),
+                    "decode": compile_watch.program_stats(
+                        "generate.paged_decode_step"
+                    ),
+                }
                 out.update(
                     kv_bytes=self._kv.nbytes(),
                     kv_block_len=self._kv.block_len,
@@ -688,15 +694,35 @@ class InferenceEngine:
 
     # -- engine loop ---------------------------------------------------
     def _run(self) -> None:
+        """The loop is always in one `engine.*` phase or another: one
+        `phase_timer` is open for the thread's whole life and every
+        boundary is a `switch`, so the phases partition the loop's
+        wall time (a bucket each, and an annotation in a running
+        `jax.profiler` trace). The buckets are drained once an
+        iteration into the totals `stats()` returns as `loop_ms`."""
         try:
-            while True:
-                with self._lock:
-                    if self._stopping:
-                        return
-                did_work = self._step()
-                if not did_work:
-                    self._wake.wait(self.config.idle_wait_s)
-                    self._wake.clear()
+            with phase_timer("engine.reap") as phase:
+                self._phase = phase
+                while True:
+                    phase.switch("engine.reap")
+                    self._drain_phases()
+                    with self._lock:
+                        if self._stopping:
+                            return
+                    worked = self._reap_cancelled()
+                    if self._program is not None:
+                        phase.switch("engine.policy")
+                        worked = self._policy_step() or worked
+                    if self._sched is not None:
+                        # Prefill before decode: an admitted request
+                        # advances by ONE chunk, then the whole batch
+                        # decodes one step (Sarathi-style interleave).
+                        worked = self._advance_prefill() or worked
+                        worked = self._decode() or worked
+                    if not worked:
+                        phase.switch("engine.idle")
+                        self._wake.wait(self.config.idle_wait_s)
+                        self._wake.clear()
         except BaseException as e:  # noqa: BLE001 — forwarded to
             # every consumer; the loop must never die silently.
             failure = EngineDead(f"engine loop died: {e!r}")
@@ -705,25 +731,26 @@ class InferenceEngine:
                 self._dead = e
                 self._fail_all_locked(failure)
 
-    def _step(self) -> bool:
-        """One engine iteration; returns whether any work happened.
-        Policy batches go first: their callers are blocked env-runner
-        threads, and one batched forward is cheap next to a decode
-        step over the full slot batch."""
-        worked = self._reap_cancelled()
-        worked = self._policy_step() or worked
-        if self._sched is not None:
-            worked = self._advance_prefill() or worked
-            worked = self._decode() or worked
-        return worked
+    def _drain_phases(self) -> None:
+        phases = take_phases()
+        with self._lock:
+            self._loop_iterations += 1
+            for name, ms in phases.items():
+                # Only the loop's own: this thread's bucket also
+                # collects `compile_ms` from the compile watch.
+                if name.startswith("engine."):
+                    self._loop_ms[name] = (
+                        self._loop_ms.get(name, 0.0) + ms
+                    )
 
     # -- policy path ---------------------------------------------------
     def _policy_step(self) -> bool:
         """Serve every pending policy request that fits the largest
         bucket in ONE padded batched forward on the LATEST weight
-        generation; scatter output rows back to their tickets."""
-        if self._program is None:
-            return False
+        generation; scatter output rows back to their tickets. Policy
+        batches go before the LLM path: their callers are blocked
+        env-runner threads, and one batched forward is cheap next to
+        a decode step over the full slot batch."""
         with self._lock:
             if not self._policy_pending:
                 return False
@@ -757,7 +784,7 @@ class InferenceEngine:
             self._policy_steps,
         )
         try:
-            outs = self._program_run(params, padded, key)
+            outs = self._program.run(params, padded, key)
             host = {k: np.asarray(v) for k, v in outs.items()}
         except BaseException as e:
             # A program failure fails THIS batch's tickets (the
@@ -780,9 +807,7 @@ class InferenceEngine:
             cursor += req.n
         self._policy_steps += 1
         self._policy_rows_served += rows
-        self._observe_policy(
-            (time.perf_counter() - t0) * 1e3, rows, bucket
-        )
+        self._observe_policy((time.perf_counter() - t0) * 1e3)
         return True
 
     # -- cancellation / completion ------------------------------------
@@ -839,12 +864,36 @@ class InferenceEngine:
         self._by_id.pop(req.request_id, None)
         self._requests_done += 1
         req.out.put(("end", reason))
-        self._observe_finish(reason)
+        self._record_request_span(req, reason)
         # Push occupancy from the retirement itself: cancellation/
         # drain may leave no alive rows, so no decode step would ever
         # publish the freed slots (the gauge throttle keeps this
         # cheap; a slots_used zero-crossing always goes out).
         self._observe_occupancy()
+
+    def _record_request_span(self, req: _Request, reason: str) -> None:
+        """One `engine.request` span per request, when it ends (never
+        per token): a child of the span that was current at submit()
+        (`serve.handle` under serve), so an HTTP request's spans share
+        one trace id from proxy to engine. Recording is one append to
+        the tracing ring; the metrics flusher ships it."""
+        now = time.perf_counter()
+        admitted = req.admitted_ts if req.admitted_ts is not None else now
+        decoding = req.decoding_ts if req.decoding_ts is not None else now
+        tracing.record_span(
+            "engine.request",
+            req.submitted_ns,
+            time.time_ns(),
+            req.trace_parent,
+            request_id=req.serve_request_id,
+            engine_request_id=req.request_id,
+            family=self._tags["family"],
+            queue_ms=round((admitted - req.submitted_ts) * 1e3, 3),
+            prefill_ms=round((decoding - admitted) * 1e3, 3),
+            decode_ms=round((now - decoding) * 1e3, 3),
+            tokens=req.emitted,
+            finish_reason=reason,
+        )
 
     def _fail_all_locked(self, error: BaseException) -> None:
         if self._prefilling is not None:
@@ -866,6 +915,7 @@ class InferenceEngine:
             req.gen = None
             self._by_id.pop(req.request_id, None)
             req.out.put(("err", error))
+            self._record_request_span(req, "error")
         # Pending policy tickets fail FAST too: their callers are
         # synchronously blocked env-runner threads — an engine death
         # must turn into EngineDead there, never a hang.
@@ -942,6 +992,10 @@ class InferenceEngine:
         whether prefill work happened."""
         import jax.numpy as jnp
 
+        from ..models.generate import paged_prefill
+
+        phase = self._phase
+        phase.switch("engine.admit")
         with self._lock:
             req = self._prefilling
             if req is None:
@@ -952,6 +1006,11 @@ class InferenceEngine:
                     return False
                 req, slot = admitted
                 req.slot = slot
+                req.admitted_ts = time.perf_counter()
+                self._admitted += 1
+                self._admit_wait_ms_total += (
+                    req.admitted_ts - req.submitted_ts
+                ) * 1e3
                 # Pin the weight generation at ADMISSION: everything
                 # this request computes — every prefill chunk and
                 # every decode step — uses these params, even if a
@@ -961,22 +1020,28 @@ class InferenceEngine:
                 self._gens[req.gen]["refs"] += 1
                 self._allocate_locked(req)
                 self._prefilling = req
+        phase.switch("engine.prefill.prepare")
         if req.padded is None:
             padded = np.zeros((1, req.bucket), np.int32)
             padded[0, : len(req.prompt)] = req.prompt
             req.padded = padded
         chunk = self.config.prefill_chunk
+        # `serve_engine_prefill_chunk_ms` starts here and ends after
+        # the wait: host arrays to device, dispatch, device.
         t0 = time.perf_counter()
         tokens = jnp.asarray(req.padded[:, req.offset:req.offset + chunk])
         table = jnp.asarray(self._tables[req.slot:req.slot + 1])
-        logits, pool = self._paged_prefill(
+        offset = jnp.int32(req.offset)
+        valid_len = jnp.int32(req.offset + chunk)
+        phase.switch("engine.prefill.dispatch")
+        logits, pool = paged_prefill(
             self._gens[req.gen]["params"],
             self.cfg,
             tokens,
             self._kv.pool,
             table,
-            jnp.int32(req.offset),
-            jnp.int32(req.offset + chunk),
+            offset,
+            valid_len,
         )
         self._kv.pool = pool
         req.offset += chunk
@@ -989,16 +1054,16 @@ class InferenceEngine:
             # reaches the final chunk, it is capped at
             # len(prompt) - 1).
             local = len(req.prompt) - 1 - (req.offset - chunk)
-            last_row = logits[0, local]
+            fence = logits[0, local]
             self._last_logits = self._last_logits.at[req.slot].set(
-                last_row
+                fence
             )
-            last_row.block_until_ready()
         else:
-            logits.block_until_ready()
-        self._observe_prefill(
-            (time.perf_counter() - t0) * 1e3, chunk
-        )
+            fence = logits
+        phase.switch("engine.prefill.wait")
+        fence.block_until_ready()
+        phase.switch("engine.emit")
+        self._observe_prefill((time.perf_counter() - t0) * 1e3)
         if last_chunk:
             req.padded = None
             with self._lock:
@@ -1020,6 +1085,7 @@ class InferenceEngine:
                         )
                 self._positions[req.slot] = len(req.prompt)
                 self._alive[req.slot] = True
+                req.decoding_ts = time.perf_counter()
         return True
 
     # -- decode --------------------------------------------------------
@@ -1027,11 +1093,17 @@ class InferenceEngine:
         import jax
         import jax.numpy as jnp
 
+        from ..models.generate import paged_decode_step
+
+        phase = self._phase
+        phase.switch("engine.decode.prepare")
         alive_idx = np.flatnonzero(self._alive)
         if alive_idx.size == 0:
             return False
         batch = int(alive_idx.size)
         ec = self.config
+        # `serve_engine_decode_step_ms` starts here and ends after the
+        # sync: the host's preparation, the dispatch and the device.
         t0 = time.perf_counter()
         key = jax.random.fold_in(self._base_key, self._steps)
         # Partition the alive batch by pinned weight generation. In
@@ -1056,20 +1128,23 @@ class InferenceEngine:
         positions = jnp.asarray(self._positions)
         if len(by_gen) == 1:
             gen = next(iter(by_gen))
-            token, pool, last_logits = self._paged_decode(
+            alive = jnp.asarray(self._alive)
+            phase.switch("engine.decode.dispatch")
+            token, pool, last_logits = paged_decode_step(
                 self._gens[gen]["params"],
                 self.cfg,
                 self._kv.pool,
                 tables,
                 self._last_logits,
                 positions,
-                jnp.asarray(self._alive),
+                alive,
                 key,
                 temperature=ec.temperature,
                 top_k=ec.top_k,
             )
             self._kv.pool = pool
             self._last_logits = last_logits
+            phase.switch("engine.decode.sync")
             tokens = np.asarray(token)  # device->host sync per step
         else:
             # Mixed-generation window: paged_decode_step donates
@@ -1079,6 +1154,7 @@ class InferenceEngine:
             # group must never read another group's freshly-written
             # junk rows, and the donated original must never be
             # reused.
+            phase.switch("engine.decode.dispatch")
             base_logits = self._last_logits
             merged = base_logits
             pool = self._kv.pool
@@ -1091,7 +1167,7 @@ class InferenceEngine:
                 mask = np.zeros(ec.slots, bool)
                 mask[by_gen[gen]] = True
                 gmask = jnp.asarray(mask)
-                token, pool, out_logits = self._paged_decode(
+                token, pool, out_logits = paged_decode_step(
                     self._gens[gen]["params"],
                     self.cfg,
                     pool,
@@ -1113,7 +1189,9 @@ class InferenceEngine:
                 )
             self._kv.pool = pool
             self._last_logits = merged
+            phase.switch("engine.decode.sync")
             tokens = np.asarray(merged_tokens)  # ONE sync for the window
+        phase.switch("engine.emit")
         step_ms = (time.perf_counter() - t0) * 1e3
         self._steps += 1
         now = time.perf_counter()
@@ -1148,12 +1226,11 @@ class InferenceEngine:
 
     def _block_stats(self) -> Dict[str, int]:
         if self._kv is None:
-            return {"kv_used": 0, "kv_total": 0, "kv_cached": 0}
+            return {"kv_used": 0, "kv_total": 0}
         alloc = self._kv.alloc
         return {
             "kv_used": alloc.used(),
             "kv_total": alloc.capacity(),
-            "kv_cached": alloc.cached(),
         }
 
     def _observe_step(
@@ -1171,11 +1248,11 @@ class InferenceEngine:
         except Exception:
             pass
 
-    def _observe_prefill(self, chunk_ms: float, tokens: int) -> None:
+    def _observe_prefill(self, chunk_ms: float) -> None:
         try:
             from ..serve.observability import observe_engine_prefill
 
-            observe_engine_prefill(self._tags, chunk_ms, tokens)
+            observe_engine_prefill(self._tags, chunk_ms)
         except Exception:
             pass
 
@@ -1192,14 +1269,6 @@ class InferenceEngine:
             from ..serve.observability import observe_engine_ttft
 
             observe_engine_ttft(self._tags, ttft_ms)
-        except Exception:
-            pass
-
-    def _observe_finish(self, reason: str) -> None:
-        try:
-            from ..serve.observability import observe_engine_finish
-
-            observe_engine_finish(self._tags, reason)
         except Exception:
             pass
 
@@ -1236,12 +1305,10 @@ class InferenceEngine:
         except Exception:
             pass
 
-    def _observe_policy(
-        self, batch_ms: float, rows: int, bucket: int
-    ) -> None:
+    def _observe_policy(self, batch_ms: float) -> None:
         try:
             from ..serve.observability import observe_engine_policy
 
-            observe_engine_policy(self._tags, batch_ms, rows, bucket)
+            observe_engine_policy(self._tags, batch_ms)
         except Exception:
             pass

@@ -162,14 +162,15 @@ def _forward_with_cache(
 
 def _sample(logits, key, temperature: float, top_k: int):
     """logits [b, vocab] -> token ids [b]."""
-    if temperature == 0.0:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    logits = logits / temperature
-    if top_k > 0:
-        top_vals, _ = jax.lax.top_k(logits, top_k)
-        cutoff = top_vals[:, -1][:, None]
-        logits = jnp.where(logits < cutoff, -1e30, logits)
-    return jax.random.categorical(key, logits).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        if temperature == 0.0:
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        logits = logits / temperature
+        if top_k > 0:
+            top_vals, _ = jax.lax.top_k(logits, top_k)
+            cutoff = top_vals[:, -1][:, None]
+            logits = jnp.where(logits < cutoff, -1e30, logits)
+        return jax.random.categorical(key, logits).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------
@@ -320,60 +321,77 @@ def _paged_layer(
     hd = cfg.head_dim
     bl = k_pool.shape[2]
     nb = tables.shape[1]
-    h = model_norm(cfg, x, layer["attn_norm"])
-    q, k, v = project_qkv(cfg, h, layer)
-    q = apply_rotary(q, cos, sin)
-    k = apply_rotary(k, cos, sin)
+    with jax.named_scope("layer/attn_qkv"):
+        h = model_norm(cfg, x, layer["attn_norm"])
+        q, k, v = project_qkv(cfg, h, layer)
+        q = apply_rotary(q, cos, sin)
+        k = apply_rotary(k, cos, sin)
     # Scatter this step's k/v: position p of row i lands in physical
     # block tables[i, p // bl] at offset p % bl. Rows never share a
     # writable block (the allocator hands a block to one sequence;
     # dead rows all point at the reserved null block 0, whose junk is
     # never gathered by a live row), so the flattened scatter indices
     # only collide harmlessly on the null block.
-    phys = jnp.take_along_axis(tables, q_pos // bl, axis=1)  # [b, t]
-    off = q_pos % bl
-    flat_phys = phys.reshape(-1)
-    flat_off = off.reshape(-1)
-    k_rows = k.transpose(0, 2, 1, 3).reshape(b * t, cfg.n_kv_heads, hd)
-    v_rows = v.transpose(0, 2, 1, 3).reshape(b * t, cfg.n_kv_heads, hd)
-    k_pool = k_pool.at[flat_phys, :, flat_off].set(
-        k_rows.astype(k_pool.dtype)
-    )
-    v_pool = v_pool.at[flat_phys, :, flat_off].set(
-        v_rows.astype(v_pool.dtype)
-    )
+    with jax.named_scope("paged/scatter_kv"):
+        phys = jnp.take_along_axis(tables, q_pos // bl, axis=1)  # [b, t]
+        off = q_pos % bl
+        flat_phys = phys.reshape(-1)
+        flat_off = off.reshape(-1)
+        k_rows = k.transpose(0, 2, 1, 3).reshape(
+            b * t, cfg.n_kv_heads, hd
+        )
+        v_rows = v.transpose(0, 2, 1, 3).reshape(
+            b * t, cfg.n_kv_heads, hd
+        )
+        k_pool = k_pool.at[flat_phys, :, flat_off].set(
+            k_rows.astype(k_pool.dtype)
+        )
+        v_pool = v_pool.at[flat_phys, :, flat_off].set(
+            v_rows.astype(v_pool.dtype)
+        )
     # Gather each row's cache back into logical order: [b, nb, kvH,
     # bl, hd] -> [b, kvH, nb*bl, hd]. Gather AFTER the scatter so the
     # chunk attends to its own tokens (prefill self-attention).
-    kf = k_pool[tables].transpose(0, 2, 1, 3, 4).reshape(
-        b, cfg.n_kv_heads, nb * bl, hd
-    )
-    vf = v_pool[tables].transpose(0, 2, 1, 3, 4).reshape(
-        b, cfg.n_kv_heads, nb * bl, hd
-    )
-    groups = cfg.n_heads // cfg.n_kv_heads
-    kf = jnp.repeat(kf, groups, axis=1)
-    vf = jnp.repeat(vf, groups, axis=1)
-    scale = 1.0 / jnp.sqrt(jnp.float32(hd))
-    logits = (
-        jnp.einsum(
-            "bhqd,bhkd->bhqk",
-            q.astype(jnp.float32),
-            kf.astype(jnp.float32),
+    with jax.named_scope("paged/gather_kv"):
+        kf = k_pool[tables].transpose(0, 2, 1, 3, 4).reshape(
+            b, cfg.n_kv_heads, nb * bl, hd
         )
-        * scale
-    )
-    k_pos = jnp.arange(nb * bl)
-    mask = (k_pos[None, None, :] <= q_pos[:, :, None]) & (
-        k_pos[None, None, :] < valid_len[:, None, None]
-    )  # [b, t, nb*bl]
-    logits = jnp.where(mask[:, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1)
-    attn = jnp.einsum("bhqk,bhkd->bhqd", probs, vf.astype(jnp.float32))
-    attn = attn.astype(cfg.dtype).transpose(0, 2, 1, 3).reshape(b, t, -1)
-    x = x + attn @ layer["wo"]
-    h = model_norm(cfg, x, layer["mlp_norm"])
-    x = x + model_glu(cfg, h @ layer["w1"], h @ layer["w3"]) @ layer["w2"]
+        vf = v_pool[tables].transpose(0, 2, 1, 3, 4).reshape(
+            b, cfg.n_kv_heads, nb * bl, hd
+        )
+        groups = cfg.n_heads // cfg.n_kv_heads
+        kf = jnp.repeat(kf, groups, axis=1)
+        vf = jnp.repeat(vf, groups, axis=1)
+    with jax.named_scope("paged/attention"):
+        scale = 1.0 / jnp.sqrt(jnp.float32(hd))
+        logits = (
+            jnp.einsum(
+                "bhqd,bhkd->bhqk",
+                q.astype(jnp.float32),
+                kf.astype(jnp.float32),
+            )
+            * scale
+        )
+        k_pos = jnp.arange(nb * bl)
+        mask = (k_pos[None, None, :] <= q_pos[:, :, None]) & (
+            k_pos[None, None, :] < valid_len[:, None, None]
+        )  # [b, t, nb*bl]
+        logits = jnp.where(mask[:, None], logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1)
+        attn = jnp.einsum(
+            "bhqk,bhkd->bhqd", probs, vf.astype(jnp.float32)
+        )
+    with jax.named_scope("layer/attn_out"):
+        attn = attn.astype(cfg.dtype).transpose(0, 2, 1, 3).reshape(
+            b, t, -1
+        )
+        x = x + attn @ layer["wo"]
+    with jax.named_scope("paged/mlp"):
+        h = model_norm(cfg, x, layer["mlp_norm"])
+        x = x + (
+            model_glu(cfg, h @ layer["w1"], h @ layer["w3"])
+            @ layer["w2"]
+        )
     return x, k_pool, v_pool
 
 
@@ -386,7 +404,8 @@ def _paged_forward(
     pool blocks and `valid_len` [b] bounds what attention may see."""
     q_pos = jnp.asarray(q_pos, jnp.int32)
     valid_len = jnp.asarray(valid_len, jnp.int32)
-    x = embed_tokens(cfg, params, tokens)
+    with jax.named_scope("embed"):
+        x = embed_tokens(cfg, params, tokens)
     cos, sin = rotary_embedding(
         q_pos, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
     )
@@ -403,8 +422,10 @@ def _paged_forward(
     x, (new_k, new_v) = jax.lax.scan(
         body, x, (params["layers"], pool["k"], pool["v"])
     )
-    x = model_norm(cfg, x, params["final_norm"])
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("final_norm"):
+        x = model_norm(cfg, x, params["final_norm"])
+    with jax.named_scope("lm_head"):
+        logits = (x @ params["lm_head"]).astype(jnp.float32)
     return logits, {"k": new_k, "v": new_v}
 
 

@@ -322,6 +322,7 @@ def project_qkv(cfg: LlamaConfig, h, layer):
     return q, k, v
 
 
+@jax.named_scope("layer/attention")
 def _attention(cfg: LlamaConfig, q, k, v, sp_axis: Optional[str],
                mesh=None):
     k = repeat_kv(k, cfg.n_heads // cfg.n_kv_heads)
@@ -341,13 +342,25 @@ def _layer(cfg: LlamaConfig, x, layer, cos, sin, sp_axis=None,
     aux is the MoE load-balancing loss (0 for dense layers)."""
     b, t, _ = x.shape
     hd = cfg.head_dim
-    h = model_norm(cfg, x, layer["attn_norm"])
-    q, k, v = project_qkv(cfg, h, layer)
-    q = apply_rotary(q, cos, sin)
-    k = apply_rotary(k, cos, sin)
+    with jax.named_scope("layer/attn_qkv"):
+        h = model_norm(cfg, x, layer["attn_norm"])
+        q, k, v = project_qkv(cfg, h, layer)
+        q = apply_rotary(q, cos, sin)
+        k = apply_rotary(k, cos, sin)
     attn = _attention(cfg, q, k, v, sp_axis, mesh)
-    attn = attn.transpose(0, 2, 1, 3).reshape(b, t, cfg.n_heads * hd)
-    x = x + attn @ layer["wo"]
+    with jax.named_scope("layer/attn_out"):
+        attn = attn.transpose(0, 2, 1, 3).reshape(
+            b, t, cfg.n_heads * hd
+        )
+        x = x + attn @ layer["wo"]
+    with jax.named_scope("layer/mlp"):
+        x, aux = _mlp(cfg, x, layer, ep_axis)
+    return x, aux
+
+
+def _mlp(cfg: LlamaConfig, x, layer, ep_axis):
+    """The block's second half: norm, dense GLU or MoE, residual."""
+    b, t, _ = x.shape
     h = model_norm(cfg, x, layer["mlp_norm"])
     if cfg.moe_experts:
         moe_params = {
@@ -395,7 +408,8 @@ def forward_and_aux(
     b, t = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(t), (b, t))
-    x = embed_tokens(cfg, params, tokens)
+    with jax.named_scope("embed"):
+        x = embed_tokens(cfg, params, tokens)
     cos, sin = rotary_embedding(
         positions, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
     )
@@ -424,8 +438,10 @@ def forward_and_aux(
         else:
             body = jax.checkpoint(body)
     x, auxs = jax.lax.scan(body, x, params["layers"])
-    x = model_norm(cfg, x, params["final_norm"])
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("final_norm"):
+        x = model_norm(cfg, x, params["final_norm"])
+    with jax.named_scope("lm_head"):
+        logits = (x @ params["lm_head"]).astype(jnp.float32)
     return logits, jnp.sum(auxs)
 
 
@@ -480,8 +496,9 @@ def loss_fn(
         params, tokens, cfg, positions=positions, sp_axis=sp_axis,
         ep_axis=ep_axis, mesh=mesh,
     )
-    nll_sum, count = masked_xent(logits, targets)
-    xent = nll_sum / jnp.maximum(count, 1.0)
+    with jax.named_scope("loss"):
+        nll_sum, count = masked_xent(logits, targets)
+        xent = nll_sum / jnp.maximum(count, 1.0)
     return xent + cfg.moe_aux_weight * aux
 
 

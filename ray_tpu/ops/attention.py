@@ -299,6 +299,9 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, kv_len):
             ),
         ],
         interpret=_interpret(),
+        # The kernel's name in the compiled program and in a device
+        # trace (`%flash_fwd.N`): what kernel time is summed by.
+        name="flash_fwd",
     )(q2, k, v)
     return out, lse
 
@@ -534,6 +537,7 @@ def _flash_backward_fused(
         ],
         input_output_aliases={6: 0},
         interpret=interp,
+        name="flash_bwd",
     )(q2, k, v, do, lse, delta, dq_seed)
     return dq.astype(q.dtype), dk, dv
 

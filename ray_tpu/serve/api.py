@@ -266,25 +266,24 @@ def status_detail() -> Dict[str, Any]:
                 "route_prefix": state.get("route_prefix"),
                 **dep,
             }
-    # Per-family engine compile counts from the head's compile-watch
-    # table (ISSUE 15): the engine registers its jitted programs as
-    # engine.<kind>[<family>], so the cluster-folded counts are
-    # already on the head — no per-replica RPC. A count that moves
-    # under steady traffic is a mid-traffic recompile, i.e. an
-    # engine bug, now visible next to the deployment rows.
+    # Compile counts of the engine's two programs from the head's
+    # compile-watch table (ISSUE 15), under the names
+    # models/generate.py registers them with (`generate.paged_*`), so
+    # the cluster-folded counts are already on the head — no
+    # per-replica RPC. A count that moves under steady traffic is a
+    # mid-traffic recompile, i.e. an engine bug, now visible next to
+    # the deployment rows.
     try:
         from ..util.state import compile_summary
 
+        prefix = "generate.paged_"
         for prog, row in sorted(
             compile_summary().get("programs", {}).items()
         ):
-            if not prog.startswith("engine."):
+            if not prog.startswith(prefix):
                 continue
-            kind, _, family = prog[len("engine."):].partition("[")
-            family = family.rstrip("]") or "default"
-            entry = out.setdefault(
-                f"engine:{family}", {"family": family}
-            )
+            kind = prog[len(prefix):]
+            entry = out.setdefault("engine:paged", {})
             entry[f"{kind}_compiles"] = row.get("compiles", 0)
             entry[f"{kind}_shapes"] = row.get("distinct_shapes", 0)
     except Exception:  # noqa: BLE001 — status must not need compiles

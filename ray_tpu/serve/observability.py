@@ -62,7 +62,6 @@ __all__ = [
     "observe_engine_prefill",
     "observe_engine_prefix",
     "observe_engine_ttft",
-    "observe_engine_finish",
     "observe_engine_weights",
     "observe_engine_policy",
     "deployment_snapshot",
@@ -412,7 +411,6 @@ def observe_engine_step(
     waiting: int,
     kv_used: Optional[int] = None,
     kv_total: Optional[int] = None,
-    kv_cached: Optional[int] = None,
 ) -> None:
     """Engine: one decode iteration over the slot batch."""
     if not _ENABLED:
@@ -435,15 +433,13 @@ def observe_engine_step(
             ).inc(float(tokens), tags=tags)
         _engine_gauges(
             tags, slots_used, slots_total, waiting,
-            kv_used, kv_total, kv_cached,
+            kv_used, kv_total,
         )
     except Exception:
         pass
 
 
-def observe_engine_prefill(
-    tags: Dict[str, str], chunk_ms: float, tokens: int
-) -> None:
+def observe_engine_prefill(tags: Dict[str, str], chunk_ms: float) -> None:
     """Engine: one prefill chunk (interleaved with decode steps)."""
     if not _ENABLED:
         return
@@ -452,11 +448,6 @@ def observe_engine_prefill(
             "serve_engine_prefill_chunk_ms",
             "One prefill chunk forward in the engine",
         ).observe(chunk_ms, tags=tags)
-        _counter(
-            "serve_engine_prefill_tokens_total",
-            "Prompt tokens prefilled by the engine",
-            ENGINE_TAGS,
-        ).inc(float(tokens), tags=tags)
     except Exception:
         pass
 
@@ -506,20 +497,6 @@ def observe_engine_ttft(tags: Dict[str, str], ttft_ms: float) -> None:
         pass
 
 
-def observe_engine_finish(tags: Dict[str, str], reason: str) -> None:
-    """Engine: one request retired (stop/length/cancelled)."""
-    if not _ENABLED:
-        return
-    try:
-        _counter(
-            "serve_engine_requests_total",
-            "Requests retired by the engine, by outcome",
-            ENGINE_TAGS + ("outcome",),
-        ).inc(1.0, tags={**tags, "outcome": reason})
-    except Exception:
-        pass
-
-
 def observe_engine_device(
     tags: Dict[str, str], platform: str, device_kind: str, devices: int
 ) -> None:
@@ -562,18 +539,11 @@ def observe_engine_weights(
             "Weight version served to new engine admissions",
             ENGINE_TAGS,
         ).set(float(version), tags=tags)
-        _counter(
-            "serve_engine_weight_updates_total",
-            "Drainless weight pushes installed by the engine",
-            ENGINE_TAGS,
-        ).inc(1.0, tags=tags)
     except Exception:
         pass
 
 
-def observe_engine_policy(
-    tags: Dict[str, str], batch_ms: float, rows: int, bucket: int
-) -> None:
+def observe_engine_policy(tags: Dict[str, str], batch_ms: float) -> None:
     """Engine: one policy-path batched forward (the non-LLM batch
     program serving RL action requests)."""
     if not _ENABLED:
@@ -583,16 +553,6 @@ def observe_engine_policy(
             "serve_engine_policy_batch_ms",
             "One policy batch-program forward in the engine",
         ).observe(batch_ms, tags=tags)
-        _engine_histogram(
-            "serve_engine_policy_batch_rows",
-            "Rows served per policy batch (before bucket padding)",
-            boundaries=BATCH_BUCKETS,
-        ).observe(float(rows), tags=tags)
-        _counter(
-            "serve_engine_policy_rows_total",
-            "Policy-path rows served by the engine",
-            ENGINE_TAGS,
-        ).inc(float(rows), tags=tags)
     except Exception:
         pass
 
@@ -604,7 +564,6 @@ def observe_engine_occupancy(
     waiting: int,
     kv_used: Optional[int] = None,
     kv_total: Optional[int] = None,
-    kv_cached: Optional[int] = None,
 ) -> None:
     """Engine: occupancy push OUTSIDE the decode step — cancellation,
     request retirement, and engine unload all free slots (and unpin
@@ -615,7 +574,7 @@ def observe_engine_occupancy(
     try:
         _engine_gauges(
             tags, slots_used, slots_total, waiting,
-            kv_used, kv_total, kv_cached,
+            kv_used, kv_total,
         )
     except Exception:
         pass
@@ -628,7 +587,6 @@ def _engine_gauges(
     waiting: int,
     kv_used: Optional[int] = None,
     kv_total: Optional[int] = None,
-    kv_cached: Optional[int] = None,
 ) -> None:
     """Slot-occupancy + KV-block gauges, throttled like
     replica_executing: zero-crossing edges always push, same-sign
@@ -669,12 +627,6 @@ def _engine_gauges(
             "serve_engine_kv_blocks_total",
             "Paged-KV blocks provisioned in the engine's pool",
             kv_total,
-        ))
-    if kv_cached is not None:
-        series.append((
-            "serve_engine_kv_blocks_cached",
-            "Refcount-0 paged-KV blocks retained for prefix reuse",
-            kv_cached,
         ))
     for name, desc, value in series:
         _gauge(name, desc, ENGINE_TAGS).set(

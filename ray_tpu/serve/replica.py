@@ -199,7 +199,10 @@ class Replica:
         handle_request_streaming + StreamingObjectRefGenerator).
         Called with num_returns='streaming' by the router. Latency is
         recorded over the WHOLE stream (first yield to exhaustion) —
-        the number a token-streaming client experiences."""
+        the number a token-streaming client experiences, and what its
+        `serve.handle` span covers."""
+        from ..util.tracing import remote_parent, span
+
         from .multiplex import _model_id_ctx, _set_request_model_id
         from .observability import (
             observe_handler,
@@ -220,7 +223,12 @@ class Replica:
         t0 = time.perf_counter()
         error = False
         try:
-            yield from target(*args, **kwargs)
+            with remote_parent(ctx.get("trace")), span(
+                "serve.handle",
+                request_id=request_id,
+                deployment=f"{self._app_name}/{self._deployment_name}",
+            ):
+                yield from target(*args, **kwargs)
         except BaseException:
             error = True
             raise

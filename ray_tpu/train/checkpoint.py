@@ -24,7 +24,6 @@ import json
 import os
 import shutil
 import threading
-import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -111,25 +110,21 @@ def save_checkpoint(
     # The step-blocking portion of a save (full write when sync, the
     # device->host snapshot when async) is a train-loop phase the step
     # telemetry attributes per step.
-    t0 = time.monotonic()
-    if not async_save or not _fully_addressable(state):
-        _write_payload(path, state, metadata)
-        step_telemetry.add_phase(
-            "ckpt_block_ms", (time.monotonic() - t0) * 1e3
-        )
-        return path
-    snapshot = _host_snapshot(state)
-    executor = _writer()
-    with _PENDING_LOCK:
-        # Submit under the lock: registration is atomic with the
-        # submit, so a concurrent barrier can never miss an in-flight
-        # write (and the single writer thread already serializes
-        # same-path saves in submission order).
-        future = executor.submit(_write_payload, path, snapshot, metadata)
-        _PENDING.setdefault(path, []).append(future)
-    step_telemetry.add_phase(
-        "ckpt_block_ms", (time.monotonic() - t0) * 1e3
-    )
+    with step_telemetry.phase_timer("ckpt_block_ms"):
+        if not async_save or not _fully_addressable(state):
+            _write_payload(path, state, metadata)
+            return path
+        snapshot = _host_snapshot(state)
+        executor = _writer()
+        with _PENDING_LOCK:
+            # Submit under the lock: registration is atomic with the
+            # submit, so a concurrent barrier can never miss an
+            # in-flight write (and the single writer thread already
+            # serializes same-path saves in submission order).
+            future = executor.submit(
+                _write_payload, path, snapshot, metadata
+            )
+            _PENDING.setdefault(path, []).append(future)
     return path
 
 

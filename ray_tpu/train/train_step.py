@@ -155,14 +155,16 @@ def make_train_step(
         )
 
     def _step(state: TrainState, tokens, targets):
-        loss, grads = jax.value_and_grad(loss_fn)(
-            state.params, tokens, targets
-        )
-        updates, new_opt = optimizer.update(
-            grads, state.opt_state, state.params
-        )
-        new_params = optax.apply_updates(state.params, updates)
-        gnorm = optax.global_norm(grads)
+        with jax.named_scope("grad"):
+            loss, grads = jax.value_and_grad(loss_fn)(
+                state.params, tokens, targets
+            )
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
+            gnorm = optax.global_norm(grads)
         metrics = {"loss": loss, "grad_norm": gnorm}
         return (
             TrainState(
@@ -217,7 +219,6 @@ def prefetch_to_device(
     shard_batch would use. buffer_size=2 is classic double buffering;
     1 degenerates to put-then-yield with no overlap.
     """
-    import time as _time
     from collections import deque
 
     from .._private import step_telemetry
@@ -230,14 +231,10 @@ def prefetch_to_device(
         # H2D dispatch time, attributed per step (device_put is an
         # async dispatch on TPU/GPU — what's measured is the stall the
         # loop pays, which is exactly the number the doctor wants).
-        t0 = _time.monotonic()
-        out = jax.tree.map(
-            lambda x: jax.device_put(x, sharding), batch
-        )
-        step_telemetry.add_phase(
-            "h2d_ms", (_time.monotonic() - t0) * 1e3
-        )
-        return out
+        with step_telemetry.phase_timer("h2d_ms"):
+            return jax.tree.map(
+                lambda x: jax.device_put(x, sharding), batch
+            )
 
     window: "deque" = deque()
     iterator = iter(batches)

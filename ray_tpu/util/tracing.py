@@ -136,6 +136,7 @@ def export_timeline(path: str) -> List[dict]:
 import contextvars
 import os as _os
 import time as _time
+from collections import deque
 from contextlib import contextmanager
 
 _current_span: contextvars.ContextVar = contextvars.ContextVar(
@@ -171,20 +172,82 @@ def _rand_hex(nbytes: int) -> str:
     return _os.urandom(nbytes).hex()
 
 
+#: Finished spans wait here for the metrics flusher. Bounded: with no
+#: head to take them the oldest fall off, and a span is recorded by
+#: one deque append, so closing one costs its thread no RPC (the
+#: engine's step loop records one per finished request).
+_SPAN_RING_MAX = 4096
+_ring: deque = deque(maxlen=_SPAN_RING_MAX)
+#: The `_Buffer` generation `_drain_spans` is registered on (fork and
+#: shutdown drop the singleton; re-registered lazily, as the worker's
+#: get-provenance drain is).
+_drain_buffer = None
+
+
 def _record_span(record: dict) -> None:
-    """Ship one finished span to the head's DEDICATED span ring (not
+    """Queue one finished span for the head's DEDICATED span ring (not
     the task-event ring: sharing one deque would let busy task streams
     evict spans — and vice versa — and force every event consumer to
-    filter foreign records)."""
+    filter foreign records). The metrics flusher ships what is queued,
+    one `span_event` per flush."""
+    from .._private.worker import global_worker
+
+    if global_worker() is None:
+        return
+    _ring.append(record)
+    from .metrics import _Buffer
+
+    global _drain_buffer
+    buf = _Buffer.get()
+    if _drain_buffer is not buf:
+        _drain_buffer = buf
+        buf.add_drain_hook(_drain_spans)
+
+
+def _drain_spans() -> None:
+    """`_Buffer` pre-flush hook, on the flusher's thread (or that of
+    an explicit flush)."""
     from .._private.worker import global_worker
 
     worker = global_worker()
-    if worker is None:
+    if worker is None or not _ring:
         return
+    spans = []
     try:
-        worker._client.notify("span_event", spans=[record])
+        while True:
+            spans.append(_ring.popleft())
+    except IndexError:
+        pass
+    try:
+        worker._client.notify("span_event", spans=spans)
     except Exception:
         pass
+
+
+_os.register_at_fork(after_in_child=_ring.clear)
+
+
+def record_span(
+    name: str,
+    start_ns: int,
+    end_ns: int,
+    parent: "dict | None" = None,
+    **attributes,
+) -> None:
+    """Record a span that already ended, with its own epoch-ns times:
+    for work whose start and end are seen by different threads (the
+    engine finishes on its loop a request that a handler submitted).
+    `parent` is what `inject_context()` returned where the work was
+    submitted; None starts a trace."""
+    _record_span({
+        "name": name,
+        "trace_id": parent["trace_id"] if parent else _rand_hex(16),
+        "span_id": _rand_hex(8),
+        "parent_span_id": parent["span_id"] if parent else "",
+        "start_ns": int(start_ns),
+        "end_ns": int(end_ns),
+        "attributes": {str(k): str(v) for k, v in attributes.items()},
+    })
 
 
 @contextmanager
@@ -337,6 +400,11 @@ def export_otlp(path: "str | None" = None) -> dict:
             "export_otlp() requires an initialized session "
             "(call ray_tpu.init() first)"
         )
+    from .metrics import flush_best_effort
+
+    # Ship this process's own queued spans first (best-effort, as the
+    # metrics readers do before they read).
+    flush_best_effort()
     records = worker.call("list_spans", limit=10000)["spans"]
     otlp = spans_to_otlp(records)
     if path:

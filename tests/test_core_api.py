@@ -137,6 +137,29 @@ def test_wait():
     assert len(remaining) == 1
 
 
+def test_an_answered_wait_leaves_no_timer_thread_behind():
+    """A wait with a timeout parks a `threading.Timer` in the head's
+    process; it must end with the wait, not live out its timeout (a
+    token stream waits once per chunk, each with a 60 s bound)."""
+    import threading
+    import time
+
+    def timers():
+        return sum(
+            isinstance(t, threading.Timer) for t in threading.enumerate()
+        )
+
+    before = timers()
+    for i in range(12):
+        # A stored object: the wait is the head's to answer.
+        ready, _ = rt.wait([rt.put(i)], timeout=300)
+        assert len(ready) == 1
+    deadline = time.monotonic() + 5
+    while timers() > before and time.monotonic() < deadline:
+        time.sleep(0.05)
+    assert timers() <= before
+
+
 def test_nested_tasks():
     @rt.remote
     def inner(x):

@@ -260,3 +260,36 @@ class TestFlashAttentionPadding:
                 np.asarray(a), np.asarray(b), atol=1e-3, rtol=1e-3,
                 err_msg=f"d{name}",
             )
+
+
+def test_every_pallas_call_is_named():
+    """A device trace names a kernel by its HLO instruction, which is
+    the `name=` of its `pl.pallas_call`: one without it shows up as
+    whatever JAX wrapper it sits in (`checkpoint`, `closed_call`,
+    `shard_map`), and the benchmark's kernel metrics cannot find it."""
+    import ast
+    import glob
+    import os
+
+    import ray_tpu.ops
+
+    unnamed, seen = [], 0
+    root = os.path.dirname(ray_tpu.ops.__file__)
+    for path in sorted(glob.glob(os.path.join(root, "**", "*.py"), recursive=True)):
+        with open(path) as f:
+            tree = ast.parse(f.read(), path)
+        for node in ast.walk(tree):
+            if not (
+                isinstance(node, ast.Call)
+                and isinstance(node.func, ast.Attribute)
+                and node.func.attr == "pallas_call"
+            ):
+                continue
+            seen += 1
+            name = next(
+                (k.value for k in node.keywords if k.arg == "name"), None
+            )
+            if not (isinstance(name, ast.Constant) and name.value):
+                unnamed.append(f"{path}:{node.lineno}")
+    assert seen >= 2  # the flash forward and the fused backward
+    assert not unnamed, unnamed
