@@ -18,8 +18,10 @@ loop, every iteration:
      starting AFTER the shared prefix);
   3. runs ONE jitted paged decode step over the FULL slot batch
      (static shapes: full-width block tables, dead rows masked and
-     parked on the null block) — `models/generate.paged_decode_step`,
-     buffer-donated on accelerator backends — streaming each live
+     parked on the null block; attention walks the tables only as far
+     as the longest alive row reaches) —
+     `models/generate.paged_decode_step`, the pool donated and
+     written in place on accelerator backends — streaming each live
      row's token to its consumer queue;
   4. retires EOS/budget rows, releasing slots and unpinning blocks in
      the same iteration (full prompt blocks stay cached for future
@@ -394,6 +396,13 @@ class InferenceEngine:
                 cfg, n_blocks, block_len, ec.max_len, ec.prefill_chunk
             )
             self._sched = SlotScheduler(ec.slots, ec.max_waiting)
+            # Keys a decode step's attention walks per trip (for the
+            # `kv_keys_read` counter).
+            from ..models.generate import paged_tile_keys
+
+            self._kv_tile_keys = paged_tile_keys(
+                block_len, self._kv.max_blocks, q_len=1
+            )
         else:
             self._kv = None
             self._sched = None
@@ -429,6 +438,12 @@ class InferenceEngine:
         self._loop_iterations = 0
         self._admitted = 0
         self._admit_wait_ms_total = 0.0
+        # What decode's attention touches, per step, from the lengths
+        # the host holds: keys inside alive rows' `valid_len`, and
+        # keys the step's program attends over all rows (whole tiles
+        # to the longest alive row, the rule the program itself runs).
+        self._kv_keys_live = 0
+        self._kv_keys_read = 0
         self._prefilling: Optional[_Request] = None
         self._by_id: Dict[str, _Request] = {}
         self._policy_pending: "deque[_PolicyRequest]" = deque()
@@ -649,6 +664,8 @@ class InferenceEngine:
                 loop_iterations=self._loop_iterations,
                 admitted=self._admitted,
                 admit_wait_ms_total=self._admit_wait_ms_total,
+                kv_keys_live=self._kv_keys_live,
+                kv_keys_read=self._kv_keys_read,
                 **self._device,
             )
             if self._kv is not None:
@@ -1124,6 +1141,7 @@ class InferenceEngine:
                 ).append(int(slot))
         if not by_gen:
             return False
+        self._count_kv_keys(by_gen)
         tables = jnp.asarray(self._tables)
         positions = jnp.asarray(self._positions)
         if len(by_gen) == 1:
@@ -1218,6 +1236,26 @@ class InferenceEngine:
             self._tokens_emitted += emitted
         self._observe_step(step_ms, batch, emitted)
         return True
+
+    def _count_kv_keys(self, by_gen: Dict[int, List[int]]) -> None:
+        """Add this step's `kv_keys_live` / `kv_keys_read`: one
+        program per weight generation, each over every slot as far as
+        its own rows' longest `valid_len` asks."""
+        from ..models.generate import paged_tiles_read
+
+        tile = self._kv_tile_keys
+        valid_len = self._positions + 1
+        live = read = 0
+        for slots in by_gen.values():
+            mask = np.zeros_like(self._alive)
+            mask[slots] = True
+            live += int(valid_len[mask].sum())
+            read += self.config.slots * tile * int(
+                paged_tiles_read(valid_len, mask, tile)
+            )
+        with self._lock:
+            self._kv_keys_live += live
+            self._kv_keys_read += read
 
     # -- metrics -------------------------------------------------------
     # All hooks are guarded no-ops on failure: observability must

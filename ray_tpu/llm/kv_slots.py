@@ -13,9 +13,12 @@ item 1c):
 
   (models/generate.init_block_pool);
 * a per-request PAGE TABLE mapping logical block j -> physical block
-  id; attention gathers blocks back into logical order per step
-  (models/generate._paged_layer), so the math — and the greedy token
-  stream — is identical to the contiguous cache;
+  id; a forward writes its new k/v into the pool in place and
+  attention walks each row's table a tile of entries at a time, only
+  as far as the longest alive row reaches
+  (models/generate._paged_attention), so the math — and the greedy
+  token stream — is that of the contiguous cache over the keys inside
+  `valid_len`;
 * a refcounted `BlockAllocator` (the plasma-style ownership model of
   the reference object plane: pin/refcount, free-list reuse, nothing
   zeroed) with PREFIX CACHING: full prompt blocks register under the

@@ -279,12 +279,29 @@ def prefill(params, cfg: LlamaConfig, tokens, cache, cache_pos, valid_len):
 # Paged KV: one shared block pool instead of per-row [max_len] arenas.
 # A sequence's cache lives in `block_len`-sized blocks scattered across
 # the pool; a per-row BLOCK TABLE maps logical block j -> physical
-# block id. Attention gathers each row's blocks back into logical
-# order, so the math is identical to the contiguous cache above with
-# max_len == n_logical_blocks * block_len — the paged engine stays
-# token-for-token equal to `generate()` (llm/kv_slots.py owns the
-# allocator/refcounting; this module owns the compute).
+# block id. A forward touches the pool IN PLACE: the layer loop
+# carries both arrays (all layers), each layer scatters its new k/v
+# at [layer, block, :, offset], and attention walks the row's table a
+# TILE of entries at a time — gathering that tile's pages at the
+# pool's own dtype, once per kv head (GQA queries grouped onto their
+# kv head, nothing repeated) — with the online-softmax recurrence
+# (float32 running max, sum and accumulator, as ops/attention.py).
+# The walk stops after the longest live row's last tile (a traced
+# trip count: one compiled program per shape, whatever the lengths),
+# so the math equals the contiguous cache above over the keys inside
+# `valid_len` and the paged engine stays token-for-token equal to
+# `generate()` (llm/kv_slots.py owns the allocator/refcounting; this
+# module owns the compute).
 # ---------------------------------------------------------------------
+
+#: Keys of one attention tile in a single-token step: the page
+#: gathers and the two products run over this many keys of every row
+#: per trip of the walk. Measured on the v5e at the benchmark's
+#: geometry (PERF.md, PR 24): such a step is bound by the gathers'
+#: bytes, so a shorter tile wastes less past the longest row's end
+#: (128 pays more launches than it saves); a chunk's trip is bound by
+#: its launches and takes twice as many keys.
+PAGED_TILE_KEYS = 256
 
 
 def init_block_pool(
@@ -305,82 +322,195 @@ def init_block_pool(
     }
 
 
+def paged_tile_keys(block_len: int, table_width: int, q_len: int) -> int:
+    """Keys in one attention tile for a pool geometry and `q_len`
+    query tokens a row: whole blocks, `PAGED_TILE_KEYS` for a
+    single-token step and twice that for a chunk, never more than a
+    row's table holds."""
+    keys = PAGED_TILE_KEYS if q_len == 1 else 2 * PAGED_TILE_KEYS
+    return max(1, min(keys // block_len, table_width)) * block_len
+
+
+def paged_tiles_read(valid_len, alive, tile_keys: int):
+    """Tiles of `tile_keys` keys a paged forward attends over, per
+    row: up to the end of the longest ALIVE row, whole tiles. The one
+    rule behind the program's trip count (traced arrays) and the
+    engine's `kv_keys_read` counter (numpy arrays of the same
+    lengths), so the two cannot drift. A dead row's stale length
+    holds nothing open; all rows dead reads nothing."""
+    longest = (valid_len * alive).max()
+    return (longest + tile_keys - 1) // tile_keys
+
+
+def _paged_attention(
+    q: jax.Array,  # [b, heads, t, hd], rotated
+    k_pool,  # [layers, n_blocks, kv_heads, block_len, hd]
+    v_pool,
+    layer_idx,  # [] which layer's pages
+    tables: jax.Array,  # [b, whole tiles of entries] physical block ids
+    q_pos: jax.Array,  # [b, t]
+    valid_len: jax.Array,  # [b]
+    n_tiles,  # [] traced trip count (paged_tiles_read)
+    tile_blocks: int,
+) -> jax.Array:
+    """Attention of `q` over the pages `tables` names, read where they
+    lie: -> [b, heads, t, hd] float32. Keys past a query's position or
+    the row's `valid_len` are masked per tile; tiles past `n_tiles`
+    are never read."""
+    b, n_heads, t, hd = q.shape
+    n_blocks, kv_heads, bl = k_pool.shape[1:4]
+    groups = n_heads // kv_heads
+    tile = tile_blocks * bl
+    # The queries of one kv head side by side: row g * t + i of the
+    # grouped axis is head (kv, g) at chunk position i, so both
+    # products are plain matmuls against that head's keys.
+    qg = q.reshape(b, kv_heads, groups * t, hd)
+    pos_g = jnp.tile(q_pos, (1, groups))[:, None, :, None]
+    len_g = valid_len[:, None, None, None]
+    scale = 1.0 / jnp.sqrt(jnp.float32(hd))
+    key_offsets = jnp.arange(tile)
+    # Entries wholly past a row's `valid_len` (every entry of a dead
+    # row) name no key the row may see, and on the host they all name
+    # the null block: gathered as they are, every row's copies hit one
+    # address and the chip serialises them (a step with 15 dead rows
+    # took 23.5 ms against 19.5). Each is pointed at a block of its
+    # own instead; whatever it holds is masked like the null block's
+    # junk.
+    elsewhere = jnp.arange(b * tile_blocks).reshape(b, -1) % n_blocks
+
+    def one_tile(j, carry):
+        m, l, acc = carry
+        with jax.named_scope("paged/gather_kv"):
+            ids = jax.lax.dynamic_slice_in_dim(
+                tables, j * tile_blocks, tile_blocks, axis=1
+            )
+            starts = j * tile + jnp.arange(tile_blocks) * bl
+            ids = jnp.where(
+                starts < valid_len[:, None], ids, elsewhere
+            )
+            # The layer rides inside the gather's indices: one gather
+            # of [kv_heads, block_len, hd] pages, no per-layer slice
+            # of the pool materialised.
+            kt = k_pool[layer_idx, ids]  # [b, tile_blocks, kvH, bl, hd]
+            vt = v_pool[layer_idx, ids]
+        with jax.named_scope("paged/attention"):
+            s = jnp.einsum(
+                "bhqd,bnhkd->bhqnk", qg, kt,
+                preferred_element_type=jnp.float32,
+            ).reshape(b, kv_heads, groups * t, tile) * scale
+            k_pos = j * tile + key_offsets
+            s = jnp.where((k_pos <= pos_g) & (k_pos < len_g), s, -1e30)
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[..., None])
+            l = l * alpha + p.sum(axis=-1)
+            pv = jnp.einsum(
+                "bhqnk,bnhkd->bhqd",
+                p.astype(vt.dtype).reshape(
+                    b, kv_heads, groups * t, tile_blocks, bl
+                ),
+                vt,
+                preferred_element_type=jnp.float32,
+            )
+            return m_new, l, acc * alpha[..., None] + pv
+
+    rows = (b, kv_heads, groups * t)
+    _, l, acc = jax.lax.fori_loop(
+        0,
+        n_tiles,
+        one_tile,
+        (
+            jnp.full(rows, -1e30, jnp.float32),
+            jnp.zeros(rows, jnp.float32),
+            jnp.zeros(rows + (hd,), jnp.float32),
+        ),
+    )
+    # A row no tile was read for (every row dead) has l == 0: its
+    # output is junk nobody reads, but keep it finite.
+    out = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
+    return out.reshape(b, n_heads, t, hd)
+
+
+def _paged_write(pool, layer_idx, tables, q_pos, new):
+    """`new` [b, kv_heads, t, hd], the k or v of tokens at positions
+    `q_pos` [b, t], written into `pool` at this layer: position p of
+    row i lands in physical block tables[i, p // bl] at offset p % bl.
+    Rows never share a writable block (the allocator hands a block to
+    one sequence; dead rows all point at the reserved null block 0,
+    whose junk no live row reads unmasked), so writes only collide,
+    harmlessly, on the null block."""
+    _, n_blocks, kv_heads, bl, hd = pool.shape
+    b, _, t, _ = new.shape
+    new = new.astype(pool.dtype)
+    if t < bl:
+        # A token or a few: one row of head_dim per (token, kv head),
+        # scattered into the pool seen as rows (a view: only major
+        # dimensions merge). A scatter over [kv_heads, head_dim]
+        # windows made the compiler re-lay the whole pool around
+        # every layer; rows leave it in the layout the page gather
+        # reads.
+        phys = jnp.take_along_axis(tables, q_pos // bl, axis=1)
+        rows = (
+            ((layer_idx * n_blocks + phys) * kv_heads)[..., None]
+            + jnp.arange(kv_heads)
+        ) * bl + (q_pos % bl)[..., None]  # [b, t, kv_heads]
+        flat = pool.reshape(-1, hd).at[rows.reshape(-1)].set(
+            new.transpose(0, 2, 1, 3).reshape(-1, hd)
+        )
+        return flat.reshape(pool.shape)
+    # A chunk (consecutive positions, as both callers make them):
+    # row by row the scatter above took 75 us a layer for 512 tokens.
+    # Instead read the blocks the chunk touches — one more than it
+    # fills, in case it starts inside a block — lay the new rows over
+    # them and write whole blocks back (4.3 ms a chunk saved).
+    first, shift = q_pos[:, 0] // bl, q_pos[:, 0] % bl
+    span = (t + bl - 2) // bl + 1
+    phys = jnp.take_along_axis(
+        tables, first[:, None] + jnp.arange(span), axis=1
+    )
+    held = pool[layer_idx, phys].transpose(0, 2, 1, 3, 4).reshape(
+        b, kv_heads, span * bl, hd
+    )
+    held = jax.vmap(
+        lambda old, rows, at: jax.lax.dynamic_update_slice(
+            old, rows, (0, at, 0)
+        )
+    )(held, new, shift)
+    return pool.at[layer_idx, phys].set(
+        held.reshape(b, kv_heads, span, bl, hd).transpose(0, 2, 1, 3, 4)
+    )
+
+
 def _paged_layer(
     cfg: LlamaConfig,
     x: jax.Array,  # [b, t, dim]
     layer: Dict[str, jax.Array],
+    layer_idx,  # [] this layer's index into the pool
     cos,
     sin,
-    k_pool,  # [n_blocks, kv_heads, block_len, hd] (one layer's slice)
+    k_pool,  # [layers, n_blocks, kv_heads, block_len, hd]: the pool
     v_pool,
-    tables: jax.Array,  # [b, n_logical_blocks] physical block ids
+    tables: jax.Array,  # [b, whole tiles of entries] physical block ids
     q_pos: jax.Array,  # [b, t] absolute positions of x's tokens
     valid_len: jax.Array,  # [b] valid cache length incl. x
+    n_tiles,  # [] attention's trip count
+    tile_blocks: int,
 ):
     b, t, _ = x.shape
-    hd = cfg.head_dim
-    bl = k_pool.shape[2]
-    nb = tables.shape[1]
     with jax.named_scope("layer/attn_qkv"):
         h = model_norm(cfg, x, layer["attn_norm"])
         q, k, v = project_qkv(cfg, h, layer)
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
-    # Scatter this step's k/v: position p of row i lands in physical
-    # block tables[i, p // bl] at offset p % bl. Rows never share a
-    # writable block (the allocator hands a block to one sequence;
-    # dead rows all point at the reserved null block 0, whose junk is
-    # never gathered by a live row), so the flattened scatter indices
-    # only collide harmlessly on the null block.
+    # Write BEFORE attention so the chunk attends to its own tokens
+    # (prefill self-attention).
     with jax.named_scope("paged/scatter_kv"):
-        phys = jnp.take_along_axis(tables, q_pos // bl, axis=1)  # [b, t]
-        off = q_pos % bl
-        flat_phys = phys.reshape(-1)
-        flat_off = off.reshape(-1)
-        k_rows = k.transpose(0, 2, 1, 3).reshape(
-            b * t, cfg.n_kv_heads, hd
-        )
-        v_rows = v.transpose(0, 2, 1, 3).reshape(
-            b * t, cfg.n_kv_heads, hd
-        )
-        k_pool = k_pool.at[flat_phys, :, flat_off].set(
-            k_rows.astype(k_pool.dtype)
-        )
-        v_pool = v_pool.at[flat_phys, :, flat_off].set(
-            v_rows.astype(v_pool.dtype)
-        )
-    # Gather each row's cache back into logical order: [b, nb, kvH,
-    # bl, hd] -> [b, kvH, nb*bl, hd]. Gather AFTER the scatter so the
-    # chunk attends to its own tokens (prefill self-attention).
-    with jax.named_scope("paged/gather_kv"):
-        kf = k_pool[tables].transpose(0, 2, 1, 3, 4).reshape(
-            b, cfg.n_kv_heads, nb * bl, hd
-        )
-        vf = v_pool[tables].transpose(0, 2, 1, 3, 4).reshape(
-            b, cfg.n_kv_heads, nb * bl, hd
-        )
-        groups = cfg.n_heads // cfg.n_kv_heads
-        kf = jnp.repeat(kf, groups, axis=1)
-        vf = jnp.repeat(vf, groups, axis=1)
-    with jax.named_scope("paged/attention"):
-        scale = 1.0 / jnp.sqrt(jnp.float32(hd))
-        logits = (
-            jnp.einsum(
-                "bhqd,bhkd->bhqk",
-                q.astype(jnp.float32),
-                kf.astype(jnp.float32),
-            )
-            * scale
-        )
-        k_pos = jnp.arange(nb * bl)
-        mask = (k_pos[None, None, :] <= q_pos[:, :, None]) & (
-            k_pos[None, None, :] < valid_len[:, None, None]
-        )  # [b, t, nb*bl]
-        logits = jnp.where(mask[:, None], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1)
-        attn = jnp.einsum(
-            "bhqk,bhkd->bhqd", probs, vf.astype(jnp.float32)
-        )
+        k_pool = _paged_write(k_pool, layer_idx, tables, q_pos, k)
+        v_pool = _paged_write(v_pool, layer_idx, tables, q_pos, v)
+    attn = _paged_attention(
+        q, k_pool, v_pool, layer_idx, tables, q_pos, valid_len,
+        n_tiles, tile_blocks,
+    )
     with jax.named_scope("layer/attn_out"):
         attn = attn.astype(cfg.dtype).transpose(0, 2, 1, 3).reshape(
             b, t, -1
@@ -396,14 +526,34 @@ def _paged_layer(
 
 
 def _paged_forward(
-    params, cfg: LlamaConfig, tokens, pool, tables, q_pos, valid_len
+    params, cfg: LlamaConfig, tokens, pool, tables, q_pos, valid_len,
+    alive=True,
 ):
-    """tokens [b, t] at absolute positions q_pos [b, t] -> (logits
-    [b, t, vocab], new pool). The paged analog of
-    `_forward_with_cache`; `tables` maps each row's logical blocks to
-    pool blocks and `valid_len` [b] bounds what attention may see."""
+    """tokens [b, t] at absolute positions q_pos [b, t] (consecutive
+    along a row) -> (logits [b, t, vocab], new pool). The paged analog
+    of `_forward_with_cache`; `tables` maps each row's logical blocks
+    to pool blocks and `valid_len` [b] bounds what attention may see.
+    `alive` [b] names the rows whose length bounds the walk over key
+    tiles (a dead row still computes, over whatever tiles the live
+    ones need, and sees none of their keys). The pool is carried
+    through the layer loop and written in place."""
     q_pos = jnp.asarray(q_pos, jnp.int32)
     valid_len = jnp.asarray(valid_len, jnp.int32)
+    t = tokens.shape[1]
+    bl, width = pool["k"].shape[3], tables.shape[1]
+    tile = paged_tile_keys(bl, width, t)
+    tile_blocks = tile // bl
+    n_tiles = paged_tiles_read(valid_len, alive, tile)
+    # A dead row sees no key: its stale length must not keep its table
+    # entries (all the null block) in the gathers either.
+    valid_len = valid_len * alive
+    # Whole tiles of table entries, and room for the one block past
+    # its last that a chunk's write reads: the padding names the null
+    # block, at key positions no `valid_len` reaches.
+    spare = (t + bl - 2) // bl
+    tables = jnp.pad(
+        tables, ((0, 0), (0, -(width + spare) % tile_blocks + spare))
+    )
     with jax.named_scope("embed"):
         x = embed_tokens(cfg, params, tokens)
     cos, sin = rotary_embedding(
@@ -411,16 +561,17 @@ def _paged_forward(
     )
 
     def body(carry, inputs):
-        x = carry
-        layer, k_pool, v_pool = inputs
-        x, k_pool, v_pool = _paged_layer(
-            cfg, x, layer, cos, sin, k_pool, v_pool, tables, q_pos,
-            valid_len,
-        )
-        return x, (k_pool, v_pool)
+        x, k_pool, v_pool = carry
+        layer, layer_idx = inputs
+        return _paged_layer(
+            cfg, x, layer, layer_idx, cos, sin, k_pool, v_pool,
+            tables, q_pos, valid_len, n_tiles, tile_blocks,
+        ), None
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["layers"], pool["k"], pool["v"])
+    (x, new_k, new_v), _ = jax.lax.scan(
+        body,
+        (x, pool["k"], pool["v"]),
+        (params["layers"], jnp.arange(cfg.n_layers)),
     )
     with jax.named_scope("final_norm"):
         x = model_norm(cfg, x, params["final_norm"])
@@ -496,7 +647,7 @@ def _paged_decode_step_impl(
     tables = jnp.where(alive[:, None], tables, 0)
     logits, pool = _paged_forward(
         params, cfg, token[:, None], pool, tables,
-        positions[:, None], positions + 1,
+        positions[:, None], positions + 1, alive,
     )
     return token, pool, logits[:, 0]
 
@@ -520,7 +671,8 @@ def paged_decode_step(
     """Jitted single-step decode over the FULL slot batch against the
     block pool (the paged analog of `decode_step`): sample one token
     per row from `last_logits`, scatter its k/v into each row's
-    current block, and gather-attend over the row's block table.
+    current block in place, and attend over the tiles of block-table
+    entries the longest alive row reaches.
     Compiles once per (batch, pool, table) shape. `pool` and
     `last_logits` are donated on accelerator backends — treat them as
     consumed."""

@@ -1,0 +1,200 @@
+"""The paged forward against the contiguous one (ISSUE 24): logits of
+`_paged_forward` (pool written in place, attention over live key
+tiles, GQA queries grouped, bf16 read once) equal `_forward_with_cache`
+on the same weights and tokens. One parametrised test: dtype x GQA
+grouping x scenario.
+
+Geometry: blocks of 8 keys, rows to 128 keys (16 table entries), a
+decode tile of 32 keys and a chunk tile of 64, so a handful of tokens
+reach every tile edge."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import generate as g
+from ray_tpu.models.llama import LlamaConfig, init_params
+
+BL, MAX_LEN, CHUNK, VOCAB = 8, 128, 32, 128
+WIDTH = MAX_LEN // BL
+TILE = 32
+
+
+@pytest.fixture(autouse=True)
+def small_tiles(monkeypatch):
+    monkeypatch.setattr(g, "PAGED_TILE_KEYS", TILE)
+
+
+def build(dtype, groups):
+    cfg = LlamaConfig(
+        vocab_size=VOCAB, dim=64, n_layers=2, n_heads=8,
+        n_kv_heads=8 // groups, intermediate=128, max_seq_len=MAX_LEN,
+        dtype=dtype, attention="reference",
+    )
+    return cfg, init_params(jax.random.PRNGKey(groups), cfg)
+
+
+def rel_rms(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.sqrt(((got - want) ** 2).mean() / (want ** 2).mean()))
+
+
+def paged_prefill_rows(cfg, params, pool, tables, tokens, lengths):
+    """Every row's tokens into the pool, chunk by chunk as the engine
+    does (offset traced, `valid_len` the chunk's end); -> (logits
+    [rows, MAX_LEN, vocab], pool)."""
+    out = []
+    for row, n in enumerate(lengths):
+        chunks = []
+        for off in range(0, -(-n // CHUNK) * CHUNK, CHUNK):
+            logits, pool = g._paged_prefill_impl(
+                params, cfg, jnp.asarray(tokens[row:row + 1, off:off + CHUNK]),
+                pool, jnp.asarray(tables[row:row + 1]), off, off + CHUNK,
+            )
+            chunks.append(logits[0])
+        chunks.append(jnp.zeros((MAX_LEN - len(chunks) * CHUNK, VOCAB)))
+        out.append(jnp.concatenate(chunks))
+    return jnp.stack(out), pool
+
+
+def decode_ragged(cfg, params, tol):
+    """A decode step over rows whose `valid_len` ends inside, exactly
+    on and one past a tile edge, one at full `max_len`, and a dead row
+    mid-admission (real table, stale position); physical blocks out of
+    order."""
+    rng = np.random.default_rng(3)
+    # valid_len = position + 1: 31 (inside), 32 (on the edge), 33 (one
+    # past), 128 (full max_len); the dead row sits at a stale 100.
+    positions = np.array([30, 31, 32, MAX_LEN - 1, 100], np.int32)
+    alive = np.array([True, True, True, True, False])
+    rows = len(positions)
+    n_blocks = rows * WIDTH + 1
+    tables = (
+        1 + rng.permutation(rows * WIDTH).astype(np.int32)
+    ).reshape(rows, WIDTH)
+    tokens = rng.integers(1, VOCAB, size=(rows, MAX_LEN)).astype(np.int32)
+    pool = g.init_block_pool(cfg, n_blocks, BL)
+    prefill_logits, pool = paged_prefill_rows(
+        cfg, params, pool, tables, tokens, positions
+    )
+    cache = g.init_kv_cache(cfg, rows, MAX_LEN)
+    want_prefill, cache = g._forward_with_cache(
+        params, cfg, jnp.asarray(tokens), cache, 0, jnp.asarray(positions)
+    )
+    for row, n in enumerate(positions):
+        assert rel_rms(prefill_logits[row, :n], want_prefill[row, :n]) < tol
+
+    step_tokens = tokens[np.arange(rows), positions]
+    before = jax.tree.map(np.asarray, pool)
+    # `_paged_decode_step_impl` samples from `last_logits`: make it
+    # sample the tokens the reference is fed.
+    last_logits = 50.0 * jax.nn.one_hot(step_tokens, VOCAB)
+    token, pool, logits = g._paged_decode_step_impl(
+        params, cfg, pool, jnp.asarray(tables), last_logits,
+        jnp.asarray(positions), jnp.asarray(alive), jax.random.PRNGKey(0),
+        0.0, 0,
+    )
+    assert np.asarray(token)[alive].tolist() == step_tokens[alive].tolist()
+    want, _ = g._forward_with_cache(
+        params, cfg, jnp.asarray(step_tokens)[:, None], cache,
+        jnp.asarray(positions), jnp.asarray(positions) + 1,
+    )
+    for row in np.flatnonzero(alive):
+        assert rel_rms(logits[row], want[row, 0]) < tol, row
+    assert np.isfinite(np.asarray(logits)).all()
+
+    # What the step may write: one row of each alive sequence's
+    # current block (the row at position 32 had none before: its
+    # logits above prove the write), and junk in the null block; every
+    # other key of every real block, the dead row's above all, is as
+    # it was.
+    for name in ("k", "v"):
+        changed = np.asarray(pool[name]) != before[name]
+        expect = np.zeros_like(changed)
+        expect[:, 0] = changed[:, 0]
+        for row in np.flatnonzero(alive):
+            p = positions[row]
+            expect[:, tables[row, p // BL], :, p % BL] = True
+        assert (changed <= expect).all(), name
+
+
+def prefill_shared_prefix(cfg, params, tol):
+    """A second sequence shares the first one's prefix blocks and
+    prefills only its own chunk, at `offset > 0`, over them."""
+    rng = np.random.default_rng(5)
+    prefix, own = 2 * CHUNK, CHUNK - 5
+    first = rng.integers(1, VOCAB, size=MAX_LEN).astype(np.int32)
+    second = first.copy()
+    second[prefix:] = rng.integers(1, VOCAB, size=MAX_LEN - prefix)
+    tokens = np.stack([first, second])
+    n_blocks = 2 * WIDTH + 1
+    order = 1 + rng.permutation(2 * WIDTH).astype(np.int32)
+    tables = order.reshape(2, WIDTH).copy()
+    tables[1, : prefix // BL] = tables[0, : prefix // BL]
+    pool = g.init_block_pool(cfg, n_blocks, BL)
+    _, pool = paged_prefill_rows(
+        cfg, params, pool, tables[:1], tokens[:1], [prefix + own]
+    )
+    shared = [np.asarray(pool[n])[:, tables[0, : prefix // BL]] for n in "kv"]
+    logits, pool = g._paged_prefill_impl(
+        params, cfg, jnp.asarray(tokens[1:, prefix:prefix + CHUNK]), pool,
+        jnp.asarray(tables[1:]), prefix, prefix + CHUNK,
+    )
+    cache = g.init_kv_cache(cfg, 1, MAX_LEN)
+    want, _ = g._forward_with_cache(
+        params, cfg, jnp.asarray(tokens[1:]), cache, 0,
+        jnp.asarray([prefix + own]),
+    )
+    assert rel_rms(logits[0, :own], want[0, prefix:prefix + own]) < tol
+    # The shared prefix blocks are read, never written.
+    for name, was in zip("kv", shared):
+        now = np.asarray(pool[name])[:, tables[0, : prefix // BL]]
+        assert (now == was).all(), name
+
+
+def chunk_from_inside_a_block(cfg, params, tol):
+    """A chunk that starts three keys into a block (the engine's are
+    aligned; the program does not count on it): its whole-block write
+    keeps the keys before it and reaches one block further."""
+    rng = np.random.default_rng(9)
+    start = CHUNK + 3
+    tokens = rng.integers(1, VOCAB, size=(1, MAX_LEN)).astype(np.int32)
+    tables = (1 + rng.permutation(WIDTH).astype(np.int32))[None]
+    pool = g.init_block_pool(cfg, WIDTH + 1, BL)
+    _, pool = paged_prefill_rows(cfg, params, pool, tables, tokens, [start])
+    # Junk where the chunk will write, so a row it misses shows.
+    junk = jnp.full((1, CHUNK), VOCAB - 1, jnp.int32)
+    _, pool = g._paged_prefill_impl(
+        params, cfg, junk, pool, jnp.asarray(tables), start, start + CHUNK
+    )
+    logits, pool = g._paged_prefill_impl(
+        params, cfg, jnp.asarray(tokens[:, start:start + CHUNK]), pool,
+        jnp.asarray(tables), start, start + CHUNK,
+    )
+    cache = g.init_kv_cache(cfg, 1, MAX_LEN)
+    want, _ = g._forward_with_cache(
+        params, cfg, jnp.asarray(tokens), cache, 0,
+        jnp.asarray([start + CHUNK]),
+    )
+    assert rel_rms(logits[0], want[0, start:start + CHUNK]) < tol
+
+
+SCENARIOS = {
+    "chunk_from_inside_a_block": chunk_from_inside_a_block,
+    "decode_ragged": decode_ragged,
+    "prefill_shared_prefix": prefill_shared_prefix,
+}
+
+
+@pytest.mark.parametrize("scenario", sorted(SCENARIOS))
+@pytest.mark.parametrize("groups", [1, 4, 8])
+@pytest.mark.parametrize(
+    "dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 3e-2)],
+    ids=["float32", "bfloat16"],
+)
+def test_paged_forward_matches_the_contiguous_cache(
+    dtype, tol, groups, scenario
+):
+    cfg, params = build(dtype, groups)
+    SCENARIOS[scenario](cfg, params, tol)

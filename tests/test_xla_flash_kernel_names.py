@@ -4,6 +4,10 @@ names a kernel by its HLO instruction, so the names the benchmark's
 `flash_kernel_share` sums by are pinned here, with the `named_scope`
 the model puts around attention.
 
+With them, because this is the one file that may describe a topology,
+the serve forwards compiled for the same described chip: the paged
+decode step and prefill chunk hold ONE block pool (ISSUE 24).
+
 The topology is described inside a module-scoped fixture of this file
 and nowhere else (on-chip-measurement guide, section 2): only one
 process may load the TPU's library, and only a test that has started
@@ -99,3 +103,70 @@ def test_kernel_is_named_and_scoped(kernel_calls, kernel):
     assert re.fullmatch(rf"{kernel}(\.\d+)?", name)
     assert "layer/attention" in op_name
     assert f"/{kernel}/" in op_name
+
+
+@pytest.mark.parametrize("program", ["paged_decode_step", "paged_prefill"])
+def test_paged_programs_update_the_pool_in_place(one_chip, program):
+    """With the pool donated, a compiled serve forward's temporaries
+    are smaller than ONE of the pool's two arrays: no second pool, no
+    re-laid copy of it around the layers."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from ray_tpu.models import generate
+
+    # The benchmark's pool geometry (16 slots x 4,096 keys in blocks of
+    # 16, 2 kv heads x 128) under a shallow model: each array is 201 MB,
+    # more than the chip's fast memory could hide a copy of.
+    cfg = llama.LlamaConfig(
+        vocab_size=512, dim=HEAD_DIM * 8, n_layers=6, n_heads=8, n_kv_heads=2,
+        intermediate=512, max_seq_len=4096, dtype=jnp.bfloat16,
+    )
+    slots, block, chunk = 16, 16, 128
+    width = cfg.max_seq_len // block
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda s: spec(s.shape, s.dtype),
+        jax.eval_shape(
+            lambda k: llama.init_params(k, cfg), jax.random.PRNGKey(0)
+        ),
+    )
+    pool_shape = (
+        cfg.n_layers, slots * width + 1, cfg.n_kv_heads, block, cfg.head_dim
+    )
+    pool = {
+        "k": spec(pool_shape, cfg.dtype), "v": spec(pool_shape, cfg.dtype)
+    }
+    if program == "paged_decode_step":
+        lowered = jax.jit(
+            generate._paged_decode_step_impl,
+            static_argnames=("temperature", "top_k", "cfg"),
+            donate_argnums=(2, 4),
+        ).lower(
+            params, cfg, pool, spec((slots, width), jnp.int32),
+            spec((slots, cfg.vocab_size), jnp.float32),
+            spec((slots,), jnp.int32), spec((slots,), jnp.bool_),
+            spec((2,), jnp.uint32), temperature=0.0, top_k=0,
+        )
+    else:
+        lowered = jax.jit(
+            generate._paged_prefill_impl, static_argnames=("cfg",),
+            donate_argnums=(3,),
+        ).lower(
+            params, cfg, spec((1, chunk), jnp.int32), pool,
+            spec((1, width), jnp.int32), spec((), jnp.int32),
+            spec((), jnp.int32),
+        )
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        memory = lowered.compile().memory_analysis()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    one_array = 2 * int(jnp.prod(jnp.asarray(pool_shape)))
+    assert memory.alias_size_in_bytes >= 2 * one_array  # donated, reused
+    assert memory.temp_size_in_bytes < one_array, memory
