@@ -4,115 +4,44 @@ client of `serve.run(build_llm_app(..))` takes.
 Order of a run: the correctness probe (a child that holds the chip and
 exits), then the cluster and one replica that leases the chip, warm-up
 requests until the engine has loaded its weights and compiled its two
-programs, then the window. This process sends the load (one scheduler,
-a bounded pool of sender threads) and never touches JAX.
+programs, then the lead-in (where the traffic file has one) and the
+window. This process hosts the head daemon and never touches JAX. It
+sends the warm-up itself and stamps nothing that is judged: the load
+of a window comes from `serve_client.py`, a process of its own, which
+hands its records back in a file (`ClientWindow`).
 
-Open loop: each request is sent when due and timed from when it was
-due; how late the sender ran is recorded. Closed loop: `clients`
-callers take the next request of one shared list as soon as their
-last reply ended.
-
-The window ends at its edge: the engine's counters and timers are read
-there, closed-loop callers stop there, and what is still in flight is
-cut (the client closes the connection) after `DRAIN_S`, which only
-lets a request that was due late in the window deliver its first
-token. A cut request is neither failed nor complete: what it streamed
-inside the window counts.
+The window opens `lead_in_s` after the client's first request is due,
+on an engine that already carries its standing population; `setup_s`
+ends there. It ends at its edge: the engine's counters and timers are
+read at both ends, closed-loop callers stop at the edge, and what is
+still in flight is cut by the client `DRAIN_S` later (open loop) or at
+once (closed loop). A cut request is neither failed nor complete: what
+it streamed inside the window counts.
 """
 
 from __future__ import annotations
 
-import http.client
 import json
 import os
-import socket
 import subprocess
 import sys
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
 
-from ..harness import ROOT, BenchmarkError
+from ..harness import ROOT, BenchmarkError, load_module
+from . import serve_client
+from .serve_client import DRAIN_S, monotonic, stream_request
 
-APP, ROUTE = "llm", "/llm"
-REQUEST_TIMEOUT_S = 120.0
-DRAIN_S = 5.0
-NEVER = threading.Event()
-
-
-def stream_request(
-    port: int, request: dict, clock, record: dict, stop=NEVER
-) -> dict:
-    """POST one prompt and read the token stream to its end, or until
-    `cut` closes the connection once `stop` is set. Times are `clock()`
-    seconds; every streamed token (digits and a space) gets the time of
-    the read that completed it."""
-    body = json.dumps({
-        "prompt": request["prompt"],
-        "max_new_tokens": request["max_new_tokens"],
-    })
-    record.update(
-        n_prompt=len(request["prompt"]), want=request["max_new_tokens"],
-        token_s=[], status=0,
-    )
-    data = b""
-    conn = http.client.HTTPConnection(
-        "127.0.0.1", port, timeout=REQUEST_TIMEOUT_S
-    )
-    try:
-        record["sent_s"] = clock()
-        conn.connect()
-        record["sock"] = conn.sock  # for `cut`
-        if stop.is_set():
-            raise OSError("cut before it was sent")
-        conn.request(
-            "POST", ROUTE, body=body,
-            headers={"Content-Type": "application/json"},
-        )
-        resp = conn.getresponse()
-        record["status"] = resp.status
-        while True:
-            chunk = resp.read1(65536)
-            if not chunk:
-                break
-            now = clock()
-            record["token_s"].extend([now] * chunk.count(b" "))
-            data += chunk
-    except (OSError, http.client.HTTPException) as e:
-        record["error"] = repr(e)
-    finally:
-        conn.close()
-        record.pop("sock", None)
-    record["done_s"] = clock()
-    whole = data[: data.rfind(b" ") + 1] if record["status"] == 200 else b""
-    record["tokens"] = [int(t) for t in whole.split()]
-    record["n_out"] = len(record["tokens"])
-    record["ok"] = (
-        record["status"] == 200 and record["n_out"] == record["want"]
-    )
-    record["cut"] = (
-        stop.is_set() and not record["ok"] and record["status"] in (0, 200)
-    )
-    if record["token_s"]:
-        record["first_s"] = record["token_s"][0]
-    return record
-
-
-def cut(record: dict) -> None:
-    """Close a request's connection under its reader (`stop` is set
-    first, so a sender that has not connected yet gives up itself)."""
-    sock = record.get("sock")
-    if sock is not None:
-        try:
-            sock.shutdown(socket.SHUT_RDWR)
-        except OSError:
-            pass
+APP, ROUTE = "llm", serve_client.ROUTE
+#: From telling the client the clock's origin to its first request.
+CLIENT_START_S = 0.25
 
 
 def run_probe(ctx: dict) -> dict:
     config = ctx["config"]
     spec = {
         "model": config["model"], "dtype": config["dtype"],
+        "reference": config.get("reference"),
         "engine": config["engine"], "tolerance": config["tolerance"],
         "probe_lengths": config["probe_lengths"], "seed": ctx["seed"],
         "chips": ctx["cell"]["chips"], "rehearse": ctx["rehearse"],
@@ -194,60 +123,85 @@ class Replica:
             time.sleep(0.1)
 
 
-def offer_open(pool, port: int, requests: list, clock, stop) -> list:
-    """Send each request when it is due; returns once the last is on
-    its way, with the records the sender threads fill."""
-    records = [{"due_s": r["due_s"]} for r in requests]
-    for request, record in zip(requests, records):
-        delay = request["due_s"] - clock()
-        if delay > 0:
-            time.sleep(delay)
-        pool.submit(stream_request, port, request, clock, record, stop)
-    return records
+class ClientWindow:
+    """One window of load from a client process of its own.
 
+    Made early: the client draws its load while the engine drains.
+    `open()` fixes the clock's origin (the time the window opens, the
+    traffic's `lead_in_s` after the first request is due) and tells the
+    client; `clock()` is seconds after it, negative in the lead-in;
+    `records()` waits for the client and reads what it wrote."""
 
-def offer_closed(
-    pool, port, requests, clients: int, seconds: float, clock, stop
-) -> list:
-    """Start `clients` callers that take requests until the window's
-    edge; returns the shared list of records, which grows."""
-    records, lock = [], threading.Lock()
+    def __init__(
+        self, port: int, traffic: dict, seed: int, seconds: float,
+        vocab_size: int, scratch: str,
+    ):
+        self.seconds = seconds
+        self.lead_in_s = float(traffic.get("lead_in_s", 0.0))
+        stem = os.path.join(scratch, f"client.{seed}.{time.time_ns()}")
+        self._out = stem + ".records.json"
+        with open(stem + ".json", "w") as f:
+            json.dump({
+                "port": port, "traffic": traffic, "seed": seed,
+                "seconds": seconds, "vocab_size": vocab_size,
+                "out": self._out,
+            }, f)
+        self._proc = subprocess.Popen(
+            [sys.executable, serve_client.__file__, stem + ".json"],
+            cwd=ROOT, stdin=subprocess.PIPE, stdout=subprocess.PIPE,
+            text=True,
+        )
+        self.origin = self.epoch = None
 
-    def client() -> None:
-        while True:
-            with lock:
-                if stop.is_set() or clock() >= seconds:
-                    return
-                request = next(requests)
-                record = {"shared_tokens": request["shared_tokens"]}
-                records.append(record)
-            stream_request(port, request, clock, record, stop)
-            record["due_s"] = record["sent_s"]
+    def __enter__(self) -> "ClientWindow":
+        return self
 
-    for _ in range(clients):
-        pool.submit(client)
-    return records
+    def __exit__(self, *exc) -> None:
+        if self._proc.poll() is None:
+            self._proc.kill()
+        self._proc.wait()
+        for pipe in (self._proc.stdin, self._proc.stdout):
+            pipe.close()
 
+    def open(self) -> None:
+        if self._proc.stdout.readline().strip() != "ready":
+            raise BenchmarkError("the load generator's process did not start")
+        self.origin = monotonic() + CLIENT_START_S + self.lead_in_s
+        self.epoch = time.time() + (self.origin - monotonic())
+        self._proc.stdin.write(f"{self.origin!r}\n")
+        self._proc.stdin.flush()
 
-def finish(pool, records: list, clock, deadline_s: float, stop) -> None:
-    """Wait for what is in flight until `deadline_s`, then cut it."""
-    while clock() < deadline_s and any("done_s" not in r for r in records):
-        time.sleep(0.05)
-    stop.set()
-    for record in list(records):
-        cut(record)
-    pool.shutdown(wait=True)
+    def clock(self) -> float:
+        return monotonic() - self.origin
+
+    def sleep_until(self, t_s: float) -> None:
+        time.sleep(max(0.0, t_s - self.clock()))
+
+    def records(self) -> list:
+        left = self.seconds + DRAIN_S + 60.0 - self.clock()
+        try:
+            code = self._proc.wait(timeout=max(left, 1.0))
+        except subprocess.TimeoutExpired:
+            raise BenchmarkError("the load generator outlived its window")
+        if code != 0:
+            raise BenchmarkError(f"the load generator exited {code}")
+        with open(self._out) as f:
+            return json.load(f)
 
 
 def sweep_point(rate: float, rows: list, seconds: float) -> dict:
-    """One window of an open-loop cell, or one offered rate of the knee
-    sweep: did completions keep up? Holds the judged statistics and
-    their neighbours, so a reading can be checked against them."""
+    """One window of an open-loop cell, at the file's rate or at
+    `run.py --rate` for a point of the knee: did completions keep up?
+    Holds the judged statistics and their neighbours, so a reading can
+    be checked against them. The lead-in's requests count among those
+    in flight and stream gaps into the window; the per-request samples
+    are the window's own."""
     from ..stats import (
-        lateness_ms, percentile, pooled_gaps_ms, served, ttfts_ms,
+        in_window, lateness_ms, percentile, pooled_gaps_ms, served, ttfts_ms,
     )
 
-    ttft = ttfts_ms(rows)
+    own = in_window(rows)
+    ttft = ttfts_ms(own)
     gaps = pooled_gaps_ms(rows, seconds)
     step = percentile(gaps, 50) or 0.0
 
@@ -255,19 +209,21 @@ def sweep_point(rate: float, rows: list, seconds: float) -> dict:
         return sum(1 for r in rows if r["due_s"] <= t < r["done_s"])
 
     point = {
-        "rate_per_s": rate, "requests": len(rows),
+        "rate_per_s": rate, "requests": len(own),
+        "lead_in_requests": len(rows) - len(own),
         "failed": sum(1 for r in rows if not served(r)),
         "shed": sum(1 for r in rows if r["status"] == 503),
         "cut": sum(1 for r in rows if r["cut"]),
         "ttft_mean_ms": sum(ttft) / max(len(ttft), 1),
         "ttft_p50_second_half_ms": percentile(
-            ttfts_ms([r for r in rows if r["due_s"] >= seconds / 2]), 50
+            ttfts_ms([r for r in own if r["due_s"] >= seconds / 2]), 50
         ),
+        "in_flight_start": in_flight(0.0),
         "in_flight_mid": in_flight(seconds / 2),
         "in_flight_end": in_flight(seconds - 1e-3),
         "drain_s": max(r["done_s"] for r in rows) - seconds,
         "late_p99_ms": percentile(lateness_ms(
-            [r["due_s"] for r in rows], [r["sent_s"] for r in rows]
+            [r["due_s"] for r in own], [r["sent_s"] for r in own]
         ), 99),
         "gaps": len(gaps),
         "itl_mean_ms": sum(gaps) / max(len(gaps), 1),
@@ -283,15 +239,54 @@ def sweep_point(rate: float, rows: list, seconds: float) -> dict:
 
 
 def cluster_metrics() -> dict:
-    """{name: (sum, count)} of the engine's fenced timers, from the
-    head's metrics table (replicas flush every 0.5 s)."""
+    """{name: (sum, count)} of the serve path's timers (`serve_*`:
+    proxy, router, replica and the engine's fenced ones), from the
+    head's metrics table (processes flush every 0.5 s)."""
     from ray_tpu.util.metrics import metrics_summary
 
     summary = metrics_summary()
     return {
         name: [float(row.get("sum", 0.0)), float(row.get("count", 0.0))]
         for name, row in summary.items()
-        if name.startswith("serve_engine_") and "count" in row
+        if name.startswith("serve_") and "count" in row
+    }
+
+
+def timer_means(before: dict, after: dict) -> dict:
+    """Mean of every timer of two `cluster_metrics` tables over the
+    time between them."""
+    out = {}
+    for name, (total, count) in after.items():
+        total0, count0 = before.get(name, (0.0, 0.0))
+        if count > count0:
+            out[name] = (total - total0) / (count - count0)
+    return out
+
+
+def window_ends(replica: Replica, window: ClientWindow) -> tuple:
+    """The engine's counters and the program's timers at the window's
+    two ends. The timers reach the head's table up to half a second
+    late: a mean over 48 s does not see it."""
+    ends = []
+    for at_s in (0.0, window.seconds):
+        window.sleep_until(at_s)
+        ends.append({
+            "engine": replica.engine(), "metrics": cluster_metrics()
+        })
+    return tuple(ends)
+
+
+def engine_point(before: dict, edge: dict) -> dict:
+    """What the engine and the serve path's own timers say of one
+    window of an open-loop cell: how full the batch ran (the per-layer
+    reader's number) and the mean of every `serve_*` timer."""
+    run = {"engine": {"before": before["engine"], "after": edge["engine"]}}
+    return {
+        "steps": edge["engine"]["steps"] - before["engine"]["steps"],
+        "tokens_per_step": load_module(
+            "layer_metrics", "engine_tokens_per_step"
+        ).reduce(run),
+        "timer_means_ms": timer_means(before["metrics"], edge["metrics"]),
     }
 
 
@@ -310,9 +305,11 @@ def run(ctx: dict) -> dict:
 
     config, traffic = ctx["config"], ctx["traffic"]
     seconds = ctx["seconds"]
-    load = ctx["generator"].generate(
-        traffic, ctx["seed"], seconds, config["model"]["vocab_size"]
-    )
+    vocab = config["model"]["vocab_size"]
+    # The warm-up alone: the window's requests are drawn in one place,
+    # the client's process.
+    warm_requests = ctx["generator"].warmup(traffic, ctx["seed"], vocab)
+    loop = ctx["generator"].LOOP
     marks = {"start": time.time()}
     probe = run_probe(ctx)
     marks["probe_done"] = time.time()
@@ -333,7 +330,7 @@ def run(ctx: dict) -> dict:
         while True:
             warm = [
                 stream_request(port, r, time.perf_counter, {})
-                for r in load["warmup"]
+                for r in warm_requests
             ]
             if all(r["ok"] for r in warm):
                 break
@@ -343,71 +340,37 @@ def run(ctx: dict) -> dict:
         # prompt in the prefix cache (a hit and a miss may round
         # differently and are never compared token for token).
         replays = [
-            stream_request(port, load["warmup"][0], time.perf_counter, {})
+            stream_request(port, warm_requests[0], time.perf_counter, {})
             for _ in range(2)
         ]
         replay_equal = (
             all(r["ok"] for r in replays)
             and replays[0]["tokens"] == replays[1]["tokens"]
         )
-        for i, rate in enumerate(ctx.get("sweep") or ()):
-            # Finding the knee: one window per offered rate in this
-            # one process (one set-up), each with another seed and cut
-            # like the measured one; the engine drops what was cut, so
-            # the next window starts on an idle engine.
-            point = ctx["generator"].generate(
-                dict(traffic, rate_per_s=rate), ctx["seed"] + 1000 + i,
-                seconds, config["model"]["vocab_size"],
-            )
-            t_point = time.perf_counter()
-            point_clock = lambda: time.perf_counter() - t_point  # noqa: E731
-            pool, stop = ThreadPoolExecutor(max_workers=128), threading.Event()
-            rows = offer_open(pool, port, point["requests"], point_clock, stop)
-            finish(pool, rows, point_clock, seconds + DRAIN_S, stop)
-            print("[benchmark] sweep " + json.dumps(
-                sweep_point(rate, rows, seconds)), flush=True)
-
-        replica.wait_idle()
-        time.sleep(1.0)  # let the replica's metric buffer flush
-        before = {
-            "engine": replica.engine(), "probe": replica.probe(),
-            "metrics": cluster_metrics(),
-        }
-
-        window_start_epoch = marks["window"] = time.time()
-        t0 = time.perf_counter()
-
-        def clock() -> float:
-            return time.perf_counter() - t0
-
-        tracer = None
-        trace_dir = os.path.join(ctx["scratch"], "trace")
-        if ctx["trace"]:
-            tracer = threading.Thread(
-                target=trace_window, daemon=True, args=(
-                    replica, trace_dir, 0.4 * seconds,
-                    min(float(traffic["trace_seconds"]), 0.5 * seconds),
-                    clock,
-                ),
-            )
-            tracer.start()
-        is_open = load["loop"] == "open"
-        pool, stop = ThreadPoolExecutor(max_workers=128), threading.Event()
-        if is_open:
-            records = offer_open(pool, port, load["requests"], clock, stop)
-        else:
-            records = offer_closed(
-                pool, port, load["requests"], load["clients"], seconds,
-                clock, stop,
-            )
-        time.sleep(max(0.0, seconds - clock()))
-        # The window's edge. The timers reach the head's table up to
-        # half a second late: a mean over 48 s does not see it.
-        edge = {"engine": replica.engine(), "metrics": cluster_metrics()}
-        finish(
-            pool, records, clock, seconds + (DRAIN_S if is_open else 0.0),
-            stop,
-        )
+        with ClientWindow(
+            port, traffic, ctx["seed"], seconds, vocab, ctx["scratch"]
+        ) as window:
+            replica.wait_idle()
+            time.sleep(1.0)  # let the replica's metric buffer flush
+            # Compiles are counted from before the lead-in: a shape the
+            # warm-up missed makes the run incorrect there too.
+            before = {"probe": replica.probe()}
+            window.open()
+            marks["window"] = window.epoch
+            tracer = None
+            trace_dir = os.path.join(ctx["scratch"], "trace")
+            if ctx["trace"]:
+                tracer = threading.Thread(
+                    target=trace_window, daemon=True, args=(
+                        replica, trace_dir, 0.4 * seconds,
+                        min(float(traffic["trace_seconds"]), 0.5 * seconds),
+                        window.clock,
+                    ),
+                )
+                tracer.start()
+            at_open, edge = window_ends(replica, window)
+            before.update(at_open)
+            records = window.records()
         if tracer is not None:
             tracer.join(timeout=120)
         after = {"engine": replica.engine(), "probe": replica.probe()}
@@ -427,7 +390,6 @@ def run(ctx: dict) -> dict:
             raise BenchmarkError("could not reduce the replica's trace")
         trace = json.loads(out.stdout.strip().splitlines()[-1])
 
-    vocab = config["model"]["vocab_size"]
     in_range = all(
         0 <= t < vocab for r in records + warm for t in r["tokens"]
     )
@@ -452,8 +414,8 @@ def run(ctx: dict) -> dict:
         ),
         "attempted": len(records),
         "failed": failed,
-        "setup_s": window_start_epoch - ctx["started_epoch"],
-        "loop": load["loop"],
+        "setup_s": marks["window"] - ctx["started_epoch"],
+        "loop": loop,
         "requests": records,
         "window_s": seconds,
         "engine": {"before": before["engine"], "after": edge["engine"]},
@@ -471,9 +433,12 @@ def run(ctx: dict) -> dict:
                 "cluster_and_deploy": marks["deployed"] - marks["probe_done"],
                 "load_and_warm": marks["window"] - marks["deployed"],
             },
+            "lead_in_s": window.lead_in_s,
             "window": (
-                sweep_point(traffic["rate_per_s"], records, seconds)
-                if load["loop"] == "open" else None
+                dict(
+                    sweep_point(traffic["rate_per_s"], records, seconds),
+                    **engine_point(before, edge)
+                ) if loop == "open" else None
             ),
             "cut": sum(1 for r in records if r["cut"]),
         },

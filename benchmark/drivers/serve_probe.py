@@ -41,7 +41,7 @@ def main() -> int:
     from ray_tpu.models.llama import LlamaConfig, init_params
 
     from benchmark.harness import describe
-    from benchmark.reference import llama_ref
+    from benchmark.reference import compare
 
     ensure_compile_cache()
     device = describe(jax.devices())
@@ -91,25 +91,27 @@ def main() -> int:
     token = np.asarray(token)
     del pool, last_logits
 
+    reference = compare.load(spec.get("reference"))
     pad_to = max(spec["probe_lengths"]) + 1
     errors = []
     for row, prompt in enumerate(prompts):
         n = len(prompt)
         seq = np.zeros(pad_to, np.int32)
         seq[:n], seq[n] = prompt, token[row]
-        want = llama_ref.forward(params, jnp.asarray(seq), model)
+        want = reference.forward(params, jnp.asarray(seq), model)
         errors.append({
             "tokens": n,
-            "prefill": llama_ref.relative_rms_error(
+            "prefill": compare.relative_rms_error(
                 prefill_logits[row], want[:n]
             ),
-            "decode": llama_ref.relative_rms_error(
+            "decode": compare.relative_rms_error(
                 decode_logits[row], want[n]
             ),
         })
     worst = max(max(e["prefill"], e["decode"]) for e in errors)
     print(json.dumps({
         "device": device,
+        "reference": reference.__name__,
         "errors": errors,
         "worst": worst,
         "correct": bool(worst <= spec["tolerance"]["logits_rel_rms"]),

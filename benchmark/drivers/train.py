@@ -18,6 +18,7 @@ import os
 import time
 
 from ..harness import BenchmarkError
+from ..stats import median
 
 
 def train_loop(spec: dict) -> None:
@@ -38,7 +39,7 @@ def train_loop(spec: dict) -> None:
     )
 
     from benchmark import harness
-    from benchmark.reference import llama_ref
+    from benchmark.reference import compare
     from benchmark.trace import xplane
 
     devices = jax.devices()
@@ -88,9 +89,10 @@ def train_loop(spec: dict) -> None:
     on_one = jax.tree.map(
         lambda x: jax.device_put(x, devices[0]), state.params
     ) if len(devices) > 1 else state.params
-    want = llama_ref.forward(on_one, jnp.asarray(probe[:-1]), model)
-    want_loss = float(llama_ref.mean_xent(want, jnp.asarray(probe[1:])))
-    logit_err = llama_ref.relative_rms_error(got_last, want[-1])
+    reference = compare.load(spec.get("reference"))
+    want = reference.forward(on_one, jnp.asarray(probe[:-1]), model)
+    want_loss = float(compare.mean_xent(want, jnp.asarray(probe[1:])))
+    logit_err = compare.relative_rms_error(got_last, want[-1])
     loss_err = abs(float(got_loss) - want_loss)
     del want, on_one, rows
     tolerance = spec["tolerance"]
@@ -163,6 +165,7 @@ def train_loop(spec: dict) -> None:
     report({
         "device": device,
         "correct": bool(correct),
+        "reference": reference.__name__,
         "logit_err": logit_err,
         "loss_err": loss_err,
         "probe_loss": float(got_loss),
@@ -196,6 +199,7 @@ def run(ctx: dict) -> dict:
     )
     spec = {
         "model": model, "dtype": config["dtype"],
+        "reference": config.get("reference"),
         "trainer": config["trainer"], "tolerance": config["tolerance"],
         "chips": chips, "seed": ctx["seed"], "seconds": ctx["seconds"],
         "batch": stream["batch"], "seq_len": stream["seq_len"],
@@ -244,10 +248,13 @@ def run(ctx: dict) -> dict:
         "tokens_per_step": stream["batch"] * stream["seq_len"],
         "seq_len": stream["seq_len"],
         "trace": m["trace"],
-        "notes": {
-            k: m[k] for k in (
-                "logit_err", "loss_err", "probe_loss", "losses",
+        "notes": dict(
+            {k: m[k] for k in (
+                "reference", "logit_err", "loss_err", "probe_loss", "losses",
                 "steady_compiles",
-            )
-        },
+            )},
+            # a stall shows here: the rate is over all the window's time
+            step_ms_median=median([s[1] for s in steps]),
+            step_ms_max=max(s[1] for s in steps),
+        ),
     }

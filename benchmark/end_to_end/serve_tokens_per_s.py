@@ -1,4 +1,5 @@
-"""Tokens the service delivered inside the window, over the window:
+"""Tokens the service delivered inside the window (after it opened at
+0 and by its edge), over the window:
 the prompt tokens of each request whose first token arrived in the
 window (its prefill, or its prefix-cache hit, was done by then) plus
 every generated token streamed in the window. Requests that completed
@@ -19,7 +20,7 @@ def reduce(run: dict):
     for r in requests:
         if not served(r):
             continue
-        if r["token_s"] and r["token_s"][0] <= window:
+        if r["token_s"] and 0.0 < r["token_s"][0] <= window:
             tokens += r["n_prompt"]
-        tokens += sum(1 for t in r["token_s"] if t <= window)
+        tokens += sum(1 for t in r["token_s"] if 0.0 < t <= window)
     return tokens / window

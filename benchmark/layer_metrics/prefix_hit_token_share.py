@@ -1,6 +1,6 @@
-"""Prompt tokens whose prefill the prefix cache saved by the window's
-edge, as a share of the prompt tokens of the requests whose first
-token had arrived by then (their prefill was done)."""
+"""Prompt tokens whose prefill the prefix cache saved inside the
+window, as a share of the prompt tokens of the requests whose first
+token arrived in it (their prefill was done by then)."""
 
 LAYER, UNIT, SOURCE = "engine", "%", "program_counter"
 
@@ -12,7 +12,7 @@ def reduce(run: dict):
     window = run["window_s"]
     prefilled = sum(
         r["n_prompt"] for r in run["requests"]
-        if r["token_s"] and r["token_s"][0] <= window
+        if r["token_s"] and 0.0 < r["token_s"][0] <= window
     )
     if not prefilled:
         return None

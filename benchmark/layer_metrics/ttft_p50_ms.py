@@ -1,9 +1,10 @@
 """Median of the time from when a request was due to its first
-streamed token, over the requests due in the window. Recorded, not
-judged: over the twenty requests a window holds at real lengths it
-spread by 3 to 9 % between runs of one code (PERF.md section 6), more
-than any bound the contract allows can admit. One the client cut
-before its first token counts as the wait it had had by then."""
+streamed token, over the requests due in the window (not the
+lead-in's). Recorded, not judged: over the 58 requests a window of
+`chat_loaded` holds it spread by 1.8 to 4.3 % between seeds (PERF.md
+section 2), which only the contract's largest bound would admit, and
+not safely. One the client cut before its first token counts as the
+wait it had had by then."""
 
 from benchmark.stats import percentile, ttfts_ms
 

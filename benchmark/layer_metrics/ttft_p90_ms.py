@@ -1,7 +1,7 @@
 """90th percentile of due-to-first-token over the window's requests.
-With some twenty requests in a window it lies between the second and
-third largest sample and swings with the arrival draw. Recorded, not
-judged, like the median beside it."""
+With 58 requests in a window it lies between the sixth and seventh
+largest sample and spread by 12 to 19 % between seeds (PERF.md section
+2). Recorded, not judged, like the median beside it."""
 
 from benchmark.stats import percentile, ttfts_ms
 

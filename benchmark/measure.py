@@ -4,7 +4,8 @@ each set's median and spread (quartile distance over the median).
     python benchmark/measure.py --workload <cell> [--workload ...] \
         --sets 2 --runs 6 [--seconds S] [--traced 1] [--seed0 100]
 
-Every run is a new process of `benchmark/run.py` with another seed;
+Every run is a new process of `benchmark/run.py`; the runs of a set
+have seeds `seed0`, `seed0 + 1`, ..., the same in every set;
 result lines and timings go to `chiprun_out/measure.<cell>.jsonl`.
 """
 
@@ -58,12 +59,12 @@ def main() -> int:
     for cell in args.workload:
         path = os.path.join(ROOT, "chiprun_out", f"measure.{cell}.jsonl")
         with open(path, "a") as log:
-            seed = args.seed0
             for s in range(args.sets):
-                rows = []
-                for _ in range(args.runs):
-                    rows.append(one_run(cell, seed, args.seconds, 0, log))
-                    seed += 1
+                # the same seeds in every set, as the driver's sets have
+                rows = [
+                    one_run(cell, args.seed0 + i, args.seconds, 0, log)
+                    for i in range(args.runs)
+                ]
                 good = [r["result"] for r in rows if "result" in r]
                 bad += len(rows) - len(good)
                 names = sorted({n for g in good for n in g["metrics"]})
@@ -76,9 +77,10 @@ def main() -> int:
                         "values": values,
                         "correct": all(g["correct"] for g in good),
                     }), flush=True)
-            for _ in range(args.traced):
-                row = one_run(cell, seed, args.seconds, 1, log)
-                seed += 1
+            for i in range(args.traced):
+                row = one_run(
+                    cell, args.seed0 + args.runs + i, args.seconds, 1, log
+                )
                 print(json.dumps({"cell": cell, "traced": row.get(
                     "result", row.get("stderr"))}), flush=True)
     return 1 if bad else 0

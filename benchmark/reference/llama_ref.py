@@ -99,23 +99,3 @@ def forward(params, tokens, model: dict, q_block: int = 512):
             float(model.get("norm_eps", 1e-6)),
         )
         return x @ params["lm_head"].astype(jnp.float32)
-
-
-def mean_xent(logits, targets):
-    """Mean next-token cross-entropy of logits [t, vocab] float32."""
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    picked = jnp.take_along_axis(logits, targets[:, None], axis=-1)[:, 0]
-    return jnp.mean(lse - picked)
-
-
-def relative_rms_error(got, want) -> float:
-    """rms(got - want) / rms(want), on the host in float32 (the two
-    may live on different devices)."""
-    import numpy as np
-
-    got = np.asarray(got, np.float32)
-    want = np.asarray(want, np.float32)
-    return float(
-        np.sqrt(np.mean((got - want) ** 2))
-        / max(float(np.sqrt(np.mean(want ** 2))), 1e-30)
-    )

@@ -39,9 +39,10 @@ def main() -> int:
     parser.add_argument("--trace", type=int, choices=[0, 1], default=0)
     parser.add_argument("--rehearse", action="store_true")
     parser.add_argument(
-        "--sweep", default=None,
-        help="open-loop cells: offered rates (a,b,c) to run one window "
-        "each before the measured one, to find the knee",
+        "--rate", type=float, default=None,
+        help="open-loop cells: offer this rate instead of the file's "
+        "(one point of the knee; each rate in a process of its own, since "
+        "windows above the knee leave a serve process slow)",
     )
     args = parser.parse_args()
 
@@ -59,6 +60,8 @@ def main() -> int:
             if not f.startswith(flag)
         ]
         os.environ["XLA_FLAGS"] = " ".join(kept + [f"{flag}{cell['chips']}"])
+    if args.rate is not None:
+        traffic = dict(traffic, rate_per_s=args.rate)
     seconds = args.seconds
     if seconds is None:
         seconds = traffic.get("rehearsal_seconds", 3.0) if args.rehearse \
@@ -83,7 +86,6 @@ def main() -> int:
             "generator": generator, "seed": args.seed, "seconds": seconds,
             "trace": bool(args.trace), "rehearse": args.rehearse,
             "started_epoch": STARTED, "scratch": scratch,
-            "sweep": [float(r) for r in (args.sweep or "").split(",") if r],
         })
     finally:
         shutil.rmtree(scratch, ignore_errors=True)

@@ -2,17 +2,18 @@
 
     python3 benchmark/serve_long.py [--rates 0.8,1.0,1.2,1.4,1.6] [--seconds 96] [--seed 7]
 
-Not a cell and not judged. It deploys `chat_steady`'s configuration as
+Not a cell and not judged. It deploys `chat_loaded`'s configuration as
 the serve driver does, then offers one open-loop window per rate in
-one process, as the knee sweep of PR 22 that slowed down did (answers
+one process, as the knee sweeps of PR 22 and PR 25 that slowed down did (answers
 cut short, median 48 and at most 128 tokens, so that a window holds a
 hundred requests; each window another seed, cut 5 s after its edge
 like a measured one) and prints one line per window: the engine loop's
 phases per iteration (`engine.stats()["loop_ms"]` deltas), the client's
 lateness and gaps, the means of the serve path's own timers and the
-live threads of this process, which is load generator, driver and head
-daemon in one. A phase that grows names the engine; flat phases beside
-a growing lateness name what the client's process hosts. At the end it
+live threads of this process, which is driver and head daemon (the load
+comes from `drivers/serve_client.py`, a process of its own, as in a
+cell). A phase that grows names the engine; flat phases beside a
+growing lateness name the path between the client and the engine. At the end it
 asks the runtime's own profiler for a `jax.profiler` trace of the
 replica (`profile_worker(kind="gang")`), under a little load, and
 prints what came back.
@@ -23,11 +24,11 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import shutil
 import subprocess
 import sys
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
@@ -38,36 +39,15 @@ SHORT_ANSWERS = {
 }
 
 
-def timer_means(before: dict, after: dict) -> dict:
-    """Mean of every serve timer over a window, from the head's
-    [sum, count] table."""
-    out = {}
-    for name, (total, count) in after.items():
-        total0, count0 = before.get(name, (0.0, 0.0))
-        if count > count0:
-            out[name] = (total - total0) / (count - count0)
-    return out
-
-
 def thread_families() -> dict:
-    """Live threads of this process (load generator, driver and the
-    head daemon it hosts), counted by what they run."""
+    """Live threads of this process (the driver and the head daemon it
+    hosts), counted by what they run."""
     out: dict = {}
     for thread in threading.enumerate():
         target = getattr(thread, "_target", None)
         family = getattr(target, "__qualname__", None) or type(thread).__name__
         out[family] = out.get(family, 0) + 1
     return out
-
-
-def serve_timers() -> dict:
-    from ray_tpu.util.metrics import metrics_summary
-
-    return {
-        name: [float(row.get("sum", 0.0)), float(row.get("count", 0.0))]
-        for name, row in metrics_summary().items()
-        if name.startswith("serve_") and "count" in row
-    }
 
 
 def gang_profile(serve, replica, port: int, request: dict) -> dict:
@@ -138,7 +118,7 @@ def main() -> int:
     from benchmark.drivers import serve
 
     manifest = harness.load_manifest()
-    cell = harness.find_cell(manifest, "chat_steady")
+    cell = harness.find_cell(manifest, "chat_loaded")
     config = harness.load_config(manifest, cell["config"])
     traffic = harness.load_traffic(cell["traffic"])
     if args.rehearse:
@@ -160,11 +140,13 @@ def main() -> int:
     import ray_tpu as rt
     import ray_tpu.serve as rt_serve
 
+    scratch = os.path.join(ROOT, ".scratch", f"serve_long.{os.getpid()}")
+    os.makedirs(scratch, exist_ok=True)
     rt.init(num_tpus=1 if args.rehearse else None)
     try:
         port = serve.deploy(config, args.seed)
         replica = serve.Replica(config["name"])
-        warmup = generator.generate(traffic, args.seed, 1.0, vocab)["warmup"]
+        warmup = generator.warmup(traffic, args.seed, vocab)
         deadline = time.monotonic() + 1000
         while not all(
             serve.stream_request(port, r, time.perf_counter, {})["ok"]
@@ -175,20 +157,15 @@ def main() -> int:
         served = 0
         rates = [float(r) for r in args.rates.split(",")]
         for window, rate in enumerate(rates):
-            load = generator.generate(
-                dict(traffic, rate_per_s=rate), args.seed + 1 + window,
-                args.seconds, vocab,
-            )
-            replica.wait_idle()
-            time.sleep(1.0)  # let the replica's metric buffer flush
-            before = {"engine": replica.engine(), "timers": serve_timers()}
-            t0 = time.perf_counter()
-            clock = lambda: time.perf_counter() - t0  # noqa: E731
-            pool, stop = ThreadPoolExecutor(max_workers=128), threading.Event()
-            rows = serve.offer_open(pool, port, load["requests"], clock, stop)
-            time.sleep(max(0.0, args.seconds - clock()))
-            edge = {"engine": replica.engine(), "timers": serve_timers()}
-            serve.finish(pool, rows, clock, args.seconds + serve.DRAIN_S, stop)
+            with serve.ClientWindow(
+                port, dict(traffic, rate_per_s=rate), args.seed + 1 + window,
+                args.seconds, vocab, scratch,
+            ) as client:
+                replica.wait_idle()
+                time.sleep(1.0)  # let the replica's metric buffer flush
+                client.open()
+                before, edge = serve.window_ends(replica, client)
+                rows = client.records()
             served += len(rows)
             point = serve.sweep_point(rate, rows, args.seconds)
             b, a = before["engine"], edge["engine"]
@@ -200,7 +177,7 @@ def main() -> int:
                     "itl_p50_ms", "itl_mean_ms", "itl_p95_ms", "ttft_p50_ms",
                     "in_flight_end",
                 )},
-                "client_threads": thread_families(),
+                "driver_threads": thread_families(),
                 "engine": {
                     "iterations": iterations,
                     "steps": a["steps"] - b["steps"],
@@ -211,8 +188,8 @@ def main() -> int:
                         for phase, ms in sorted(a["loop_ms"].items())
                     },
                 },
-                "serve_timer_means_ms": timer_means(
-                    before["timers"], edge["timers"]
+                "serve_timer_means_ms": serve.timer_means(
+                    before["metrics"], edge["metrics"]
                 ),
             }), flush=True)
         profile = gang_profile(serve, replica, port, warmup[0])
@@ -220,6 +197,7 @@ def main() -> int:
         rt_serve.shutdown()
     finally:
         rt.shutdown()
+        shutil.rmtree(scratch, ignore_errors=True)
     return 0
 
 

@@ -6,6 +6,7 @@ later PR can change how a tail is computed.
 
 from __future__ import annotations
 
+import statistics
 from typing import Optional, Sequence
 
 
@@ -29,12 +30,15 @@ def median(values: Sequence[float]) -> Optional[float]:
 
 
 def spread(values: Sequence[float]) -> Optional[float]:
-    """Distance between the quartiles over the median: the driver's
-    measure of run-to-run noise."""
+    """Distance between the first and third quartile over the median:
+    the driver's measure of run-to-run noise. The quartiles are those
+    of `statistics.quantiles(values, n=4)`, as the driver takes them
+    (numpy's lie closer together and would flatter a set of six)."""
     mid = median(values)
-    if mid is None or mid == 0:
+    if mid is None or mid == 0 or len(values) < 2:
         return None
-    return (percentile(values, 75.0) - percentile(values, 25.0)) / abs(mid)
+    low, _, high = statistics.quantiles(values, n=4)
+    return (high - low) / abs(mid)
 
 
 def lateness_ms(due_s: Sequence[float], sent_s: Sequence[float]) -> list:
@@ -56,13 +60,20 @@ def served(request: dict) -> bool:
     return bool(request["ok"] or request.get("cut"))
 
 
+def in_window(requests: Sequence[dict]) -> list:
+    """The window's own requests: those not due in the lead-in, which
+    only loads the engine before the window opens."""
+    return [r for r in requests if not r.get("lead_in")]
+
+
 def ttfts_ms(requests: Sequence[dict]) -> list:
-    """Due-to-first-token of each served request. One cut before its
-    first token counts as the wait it had had by then (a lower bound).
-    A failed request is left out: the run reports it as failed."""
+    """Due-to-first-token of each served request of the window. One cut
+    before its first token counts as the wait it had had by then (a
+    lower bound). A failed request is left out: the run reports it as
+    failed."""
     return [
         (r.get("first_s", r["done_s"]) - r["due_s"]) * 1e3
-        for r in requests if served(r)
+        for r in in_window(requests) if served(r)
     ]
 
 
@@ -70,15 +81,18 @@ def pooled_gaps_ms(
     requests: Sequence[dict], until_s: Optional[float] = None
 ) -> list:
     """Gaps between streamed tokens, all served requests pooled; with
-    `until_s`, only gaps that ended inside the window."""
+    `until_s`, only gaps that ended inside the window, after 0 and by
+    `until_s`, whoever streamed them (a request of the lead-in streams
+    into the window like any other)."""
     out = []
     for r in requests:
         if not served(r):
             continue
-        times = r["token_s"]
-        if until_s is not None:
-            times = [t for t in times if t <= until_s]
-        out.extend(token_gaps_ms(times))
+        ends = r["token_s"][1:]
+        out.extend(
+            gap for gap, end in zip(token_gaps_ms(r["token_s"]), ends)
+            if until_s is None or 0.0 < end <= until_s
+        )
     return out
 
 

@@ -18,6 +18,7 @@ import numpy as np
 from .lengths import quantile_lengths
 
 DRIVER = "serve"
+LOOP = "closed"
 
 
 def _group(params: dict, seed: int, index: int, vocab_size: int) -> list:
@@ -54,19 +55,22 @@ def _stream(params: dict, seed: int, vocab_size: int) -> Iterator[dict]:
         index += 1
 
 
-def generate(params: dict, seed: int, seconds: float, vocab_size: int) -> dict:
-    """-> {"loop": "closed", "clients", "requests": endless iterator,
-    "warmup": [...]}. Warm-up requests come from a group of their own
-    that the window never asks about."""
-    del seconds  # the list is endless; the window decides how far it gets
+def warmup(params: dict, seed: int, vocab_size: int) -> list:
+    """The warm-up requests, from a group of their own that the window
+    never asks about; the serve driver sends them itself."""
     warm = _group(params, seed, 1 << 20, vocab_size)
-    warmup = [
+    return [
         dict(r, max_new_tokens=int(params["warmup_new_tokens"]))
         for r in warm[: int(params["warmup_requests"])]
     ]
+
+
+def generate(params: dict, seed: int, seconds: float, vocab_size: int) -> dict:
+    """-> {"loop": "closed", "clients", "requests": endless iterator}:
+    the window's load, which only the client process draws."""
+    del seconds  # the list is endless; the window decides how far it gets
     return {
-        "loop": "closed",
+        "loop": LOOP,
         "clients": int(params["clients"]),
         "requests": _stream(params, seed, vocab_size),
-        "warmup": warmup,
     }

@@ -57,7 +57,7 @@ def test_manifest_reports_it_in_both_serve_cells():
         if m["name"].startswith("kv_read_amplification")
     }
     assert {n: (m["moves"], m["workloads"]) for n, m in entries.items()} == {
-        "kv_read_amplification.itl": ("itl_mean_ms", ["chat_steady"]),
+        "kv_read_amplification.itl": ("itl_mean_ms", ["chat_loaded"]),
         "kv_read_amplification.tput": ("serve_tokens_per_s", ["docqa_closed"]),
     }
     for m in entries.values():

@@ -108,6 +108,54 @@ def test_a_fifth_cell_is_three_new_files_and_one_entry(tmp_path):
     assert line["metric_names"] == ["setup_s", "train_tokens_per_s_chip"]
 
 
+@pytest.mark.timeout(400)
+@pytest.mark.parametrize("base, cell, seed", [
+    ("qwen2.5-3b", "docqa_closed", 5), ("mistral-7b-v0.3-l4", "pretrain_8k", 0),
+], ids=["serve-probe", "train-check"])
+def test_a_configuration_names_its_reference_and_the_driver_calls_it(base, cell, seed, tmp_path):
+    """A configuration whose equations differ is new files and entries:
+    its file names `reference/<module>.py`, and the probe (serve) or
+    the train check decides `correct` against that module."""
+    root = _checkout(tmp_path)
+    bench = os.path.join(root, "benchmark")
+    mark = str(tmp_path / "stub_ref.called")
+    with open(os.path.join(bench, "reference", "stub_ref.py"), "w") as f:
+        f.write(
+            "from benchmark.reference import llama_ref\n"
+            "def forward(params, tokens, model):\n"
+            f"    with open({mark!r}, 'a') as f:\n"
+            "        f.write(f'{tokens.shape[0]}\\n')\n"
+            "    return llama_ref.forward(params, tokens, model)\n"
+        )
+    config = dict(harness.load_config(MANIFEST, base), name="stub-model", reference="stub_ref")
+    with open(os.path.join(bench, "configs", "stub-model.json"), "w") as f:
+        json.dump(config, f)
+    manifest = json.loads(json.dumps(MANIFEST))
+    entry = next(c for c in manifest["configs"] if c["name"] == base)
+    manifest["configs"].append(dict(entry, name="stub-model", file="benchmark/configs/stub-model.json"))
+    traffic = harness.find_cell(MANIFEST, cell)["traffic"]
+    manifest["workloads"].append({
+        "name": "stub_cell", "config": "stub-model", "traffic": traffic,
+        "chips": 1, "why": "stub",
+    })
+    for section in ("end_to_end", "per_layer"):
+        for metric in manifest[section]:
+            if cell in metric.get("workloads", ()):
+                metric["workloads"].append("stub_cell")
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(manifest, f)
+    proc = _run(root, "--workload", "stub_cell", "--seed", str(seed), "--rehearse")
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    lines = proc.stdout.strip().splitlines()
+    assert json.loads(lines[-1])["correct"] is True
+    notes = json.loads(next(x for x in lines if x.startswith("[benchmark] notes "))[18:])
+    assert (notes.get("probe") or notes)["reference"] == "benchmark.reference.stub_ref"
+    with open(mark) as f:
+        calls = f.read().split()
+    # the probe compares one sequence per probe length, the train check one
+    assert len(calls) == (len(harness.apply_rehearsal(config)["probe_lengths"]) if "engine" in config else 1)
+
+
 def test_without_a_tpu_the_command_fails_and_prints_no_result(tmp_path):
     root = _checkout(tmp_path)
     proc = _run(root, "--workload", "pretrain_8k", "--seconds", "1")

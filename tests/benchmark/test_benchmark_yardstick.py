@@ -202,24 +202,67 @@ def test_quantile_lengths_are_a_fixed_sorted_multiset_within_bounds():
     assert lengths.quantile_lengths({"dist": "uniform", "min": 0, "max": 100}, 4) == [12, 38, 62, 88]
 
 
-@pytest.mark.parametrize("traffic", ["chat_open"])
+@pytest.mark.parametrize("traffic", ["chat_open_loaded"])
 def test_open_loop_repeats_from_a_seed_and_keeps_the_multiset(traffic):
     params = harness.load_traffic(traffic)
     a = serve_open.generate(params, 7, 20.0, 1000)
     b = serve_open.generate(params, 7, 20.0, 1000)
     c = serve_open.generate(params, 8, 20.0, 1000)
     assert a == b and a != c
-    n = round(params["rate_per_s"] * 20.0)
-    assert len(a["requests"]) == len(c["requests"]) == n
+    lead_in = params["lead_in_s"]
+    n, n_lead = round(params["rate_per_s"] * 20.0), round(params["rate_per_s"] * lead_in)
+    assert lead_in > 0 and len(a["requests"]) == len(c["requests"]) == n + n_lead
     due = [r["due_s"] for r in a["requests"]]
-    assert due == sorted(due) and 0 <= due[0] and due[-1] < 20.0
-    for key in (lambda r: len(r["prompt"]), lambda r: r["max_new_tokens"]):
-        assert sorted(map(key, a["requests"])) == sorted(map(key, c["requests"]))
+    assert due == sorted(due) and -lead_in <= due[0] and due[-1] < 20.0
+    # the window's own count and lengths do not depend on the lead-in,
+    # and each part keeps its multiset from seed to seed
+    assert sum(1 for t in due if t < 0) == n_lead
+    plain = serve_open.generate(dict(params, lead_in_s=0), 7, 20.0, 1000)
+    assert plain["requests"] == a["requests"][n_lead:]
+    for part in (slice(0, n_lead), slice(n_lead, None)):
+        for key in (lambda r: len(r["prompt"]), lambda r: r["max_new_tokens"]):
+            assert sorted(map(key, a["requests"][part])) == sorted(map(key, c["requests"][part]))
+    # the seed draws the arrivals and deals the lengths
+    assert due != [r["due_s"] for r in c["requests"]]
+    assert [len(r["prompt"]) for r in a["requests"]] != [len(r["prompt"]) for r in c["requests"]]
     assert all(
         len(r["prompt"]) + r["max_new_tokens"] <= params["max_total_tokens"]
         and all(1 <= t < 1000 for t in r["prompt"])
         for r in a["requests"]
     )
+
+
+def test_open_loop_arrivals_are_the_seeds_and_a_quarter_holds_what_the_draw_gives():
+    # Poisson given the count: nothing evens out the quarters of a window,
+    # and nothing keeps the long answers apart
+    params = dict(harness.load_traffic("chat_open_loaded"), rate_per_s=1.0, lead_in_s=0)
+    assert "stratum_s" not in params
+    counts, tails = set(), set()
+    for seed in range(8):
+        requests = serve_open.generate(params, seed, 40.0, 1000)["requests"]
+        assert len(requests) == 40
+        quarters = [sum(1 for r in requests if 10 * j <= r["due_s"] < 10 * (j + 1)) for j in range(4)]
+        assert sum(quarters) == 40
+        counts.add(tuple(quarters))
+        longest = max(requests, key=lambda r: r["max_new_tokens"])
+        tails.add(int(longest["due_s"] // 10))
+    assert len(counts) == 8 and any(max(q) - min(q) >= 4 for q in counts)
+    assert len(tails) > 1  # where the longest answer falls is the seed's
+
+
+@pytest.mark.parametrize("name,generator", [("chat_open_loaded", serve_open), ("docqa_closed", serve_closed)])
+def test_the_drivers_warm_up_is_drawn_without_the_windows_requests(name, generator, monkeypatch):
+    params = harness.load_traffic(name)
+    made = generator.generate(params, 3, 10.0, 5000)
+    assert set(made) == {"loop", "requests"} | ({"clients"} if generator.LOOP == "closed" else set())
+    assert made["loop"] == generator.LOOP
+    monkeypatch.setattr(generator, "generate", None)  # the driver's half never calls it
+    warm = generator.warmup(params, 3, 5000)
+    assert warm == generator.warmup(params, 3, 5000) != generator.warmup(params, 4, 5000)
+    assert len(warm) == params["warmup_requests"]
+    assert all(r["max_new_tokens"] == params["warmup_new_tokens"] for r in warm)
+    window = made["requests"] if generator.LOOP == "open" else [next(made["requests"]) for _ in range(64)]
+    assert not {tuple(r["prompt"]) for r in warm} & {tuple(r["prompt"]) for r in window}
 
 
 def test_closed_loop_shares_documents_group_docs_apart():
@@ -242,7 +285,7 @@ def test_closed_loop_shares_documents_group_docs_apart():
     first, second = ra[: n // 2], ra[n // 2:]
     assert sorted(len(r["prompt"]) for r in first) == sorted(len(r["prompt"]) for r in second)
     assert first[0]["prompt"] != second[0]["prompt"]
-    warm = {tuple(r["prompt"]) for r in a["warmup"]}
+    warm = {tuple(r["prompt"]) for r in serve_closed.warmup(params, 3, 5000)}
     assert not warm & {tuple(r["prompt"]) for r in ra}
 
 
@@ -262,7 +305,8 @@ def test_train_stream_repeats_from_a_seed():
 
 def test_a_cut_request_counts_what_it_streamed_and_a_failed_one_nothing():
     cut = {"ok": False, "cut": True, "due_s": 1.0, "sent_s": 1.0, "first_s": 1.5,
-           "done_s": 2.6, "token_s": [1.5, 1.7, 2.3], "n_prompt": 30, "status": 200}
+           "done_s": 2.6, "token_s": [1.5, 1.7, 2.3], "n_prompt": 30, "status": 200,
+           "lead_in": False}
     waiting = {"ok": False, "cut": True, "due_s": 1.8, "sent_s": 1.8, "done_s": 2.6,
                "token_s": [], "n_prompt": 50, "status": 0}
     failed = {"ok": False, "cut": False, "due_s": 0.2, "sent_s": 0.2, "first_s": 0.3,
@@ -297,7 +341,11 @@ def test_percentile_is_numpys(q):
 
 
 def test_spread_lateness_and_gaps():
-    assert stats.spread([1, 2, 3, 4, 5]) == pytest.approx((4 - 2) / 3)
+    # quartiles as `statistics.quantiles(values, n=4)` gives them
+    assert stats.spread([1, 2, 3, 4, 5]) == pytest.approx((4.5 - 1.5) / 3)
+    six = [19.66, 20.29, 20.33, 20.49, 20.80, 21.04]
+    assert stats.spread(six) == pytest.approx((20.86 - 20.1325) / 20.41)
+    assert stats.spread([7.0]) is None and stats.spread([]) is None
     assert stats.lateness_ms([1.0, 2.0], [1.004, 1.9]) == pytest.approx([4.0, 0.0])
     assert stats.token_gaps_ms([0.0, 0.01, 0.04]) == pytest.approx([10.0, 30.0])
 
@@ -400,15 +448,15 @@ def test_open_loop_sender_cuts_what_is_in_flight_after_the_drain(token_server):
     import threading
     from concurrent.futures import ThreadPoolExecutor
 
-    from benchmark.drivers import serve
+    from benchmark.drivers import serve_client
 
     requests = [
         {"due_s": 0.0, "prompt": [1, 2, 3], "max_new_tokens": 5},
         {"due_s": 0.1, "prompt": [4, 5], "max_new_tokens": 10_000},
     ]
     clock, pool, stop = _clock(), ThreadPoolExecutor(4), threading.Event()
-    rows = serve.offer_open(pool, token_server, requests, clock, stop)
-    serve.finish(pool, rows, clock, 0.5, stop)
+    rows = serve_client.offer_open(pool, token_server, requests, clock, stop)
+    serve_client.finish(pool, rows, clock, 0.5, stop)
     done, endless = rows
     assert done["ok"] and not done["cut"] and done["tokens"] == [100, 101, 102, 103, 104]
     assert not endless["ok"] and endless["cut"] and endless["status"] == 200
@@ -424,22 +472,139 @@ def test_closed_loop_callers_stop_at_the_windows_edge(token_server):
     import threading
     from concurrent.futures import ThreadPoolExecutor
 
-    from benchmark.drivers import serve
+    from benchmark.drivers import serve_client
 
     stream = (
         {"prompt": [i], "max_new_tokens": 8, "shared_tokens": 0}
         for i in itertools.count()
     )
     clock, pool, stop = _clock(), ThreadPoolExecutor(8), threading.Event()
-    rows = serve.offer_closed(pool, token_server, stream, 3, 0.6, clock, stop)
+    rows = serve_client.offer_closed(pool, token_server, stream, 3, 0.6, clock, stop)
     import time
 
     time.sleep(max(0.0, 0.6 - clock()))
-    serve.finish(pool, rows, clock, 0.6, stop)
+    serve_client.finish(pool, rows, clock, 0.6, stop)
     assert clock() < 1.5 and len(rows) >= 6
     assert all(r["ok"] or r["cut"] for r in rows) and sum(r["cut"] for r in rows) <= 3
     assert all(r["due_s"] == r["sent_s"] < 0.6 for r in rows)
     assert next(stream)["prompt"] == [len(rows)]  # nothing taken after the edge
+
+
+# -- the load generator's process -------------------------------------
+
+RECORD_KEYS = {
+    "due_s", "sent_s", "done_s", "token_s", "tokens", "n_prompt", "n_out",
+    "want", "status", "ok", "cut",
+}
+
+
+def _client_window(port, traffic, seconds, tmp_path):
+    """A window as the serve driver makes one: its records, when it
+    opened, and how long the client's process took to hand them back."""
+    import time
+
+    from benchmark.drivers import serve
+
+    with serve.ClientWindow(port, traffic, 11, seconds, 1000, str(tmp_path)) as window:
+        window.open()
+        assert abs(window.epoch - (time.time() - window.clock())) < 0.05
+        rows = window.records()
+        return rows, window.clock()
+
+
+def test_client_process_offers_an_open_list_with_its_lead_in_and_cuts(token_server, tmp_path):
+    from benchmark.drivers import serve, serve_client
+
+    traffic = {
+        "kind": "serve_open", "rate_per_s": 8.0, "lead_in_s": 0.5,
+        "prompt_tokens": {"dist": "uniform", "min": 2, "max": 6},
+        # 10 ms a token: the 8 quantile points run from 0.6 s to 19 s,
+        # so some end inside the window, some in the drain, some are cut
+        "output_tokens": {"dist": "uniform", "min": 2, "max": 2000},
+        "max_total_tokens": 4096, "warmup_requests": 1, "warmup_new_tokens": 1,
+    }
+    seconds = 1.0
+    rows, took = _client_window(token_server, traffic, seconds, tmp_path)
+    assert took < seconds + serve_client.DRAIN_S + 2.0  # cut, not waited for
+    want = serve_open.generate(traffic, 11, seconds, 1000)["requests"]
+    assert len(rows) == len(want) == 12
+    assert [r["due_s"] for r in rows] == [r["due_s"] for r in want]
+    assert [(r["n_prompt"], r["want"]) for r in rows] == [
+        (len(r["prompt"]), r["max_new_tokens"]) for r in want
+    ]
+    for r in rows:
+        assert set(r) - {"first_s", "error"} == RECORD_KEYS | {"lead_in"}
+        assert r["lead_in"] == (r["due_s"] < 0)
+        assert r["status"] == 200 and (r["ok"] or r["cut"]) and r["ok"] != r["cut"]
+        assert 0 <= r["sent_s"] - r["due_s"] < 0.25
+        assert r["tokens"] == list(range(100, 100 + r["n_out"]))
+        assert len(r["token_s"]) == r["n_out"] and r["first_s"] >= r["sent_s"]
+    assert sum(r["lead_in"] for r in rows) == 4
+    assert rows[0]["sent_s"] < 0 and any(r["ok"] for r in rows)
+    cut = [r for r in rows if r["cut"]]
+    assert cut and all(r["want"] > 400 and r["n_out"] < r["want"] for r in cut)
+    assert all(r["done_s"] >= seconds + serve_client.DRAIN_S - 0.1 for r in cut)
+    # the window's samples: its own requests, and gaps that ended in it
+    assert len(stats.ttfts_ms(rows)) == len(stats.in_window(rows)) == 8
+    gaps = stats.pooled_gaps_ms(rows, seconds)
+    inside = sum(
+        1 for r in rows for a, b in zip(r["token_s"], r["token_s"][1:])
+        if 0 < b <= seconds
+    )
+    assert len(gaps) == inside > 100 and 9.0 < sum(gaps) / len(gaps) < 30.0
+    point = serve.sweep_point(8.0, rows, seconds)
+    assert (point["requests"], point["lead_in_requests"]) == (8, 4)
+    assert point["in_flight_start"] >= 1 and point["late_p99_ms"] < 250.0
+
+
+def test_client_process_drives_a_closed_list_to_the_edge(token_server, tmp_path):
+    traffic = {
+        "kind": "serve_closed", "clients": 3, "group_docs": 2, "questions_per_doc": 2,
+        "document_tokens": {"dist": "uniform", "min": 4, "max": 8},
+        "question_tokens": 2, "answer_tokens": {"dist": "uniform", "min": 4, "max": 8},
+        "warmup_requests": 1, "warmup_new_tokens": 1,
+    }
+    seconds = 0.6
+    rows, took = _client_window(token_server, traffic, seconds, tmp_path)
+    assert took < seconds + 2.0 and len(rows) >= 6  # cut at the edge, no drain
+    stream = serve_closed.generate(traffic, 11, seconds, 1000)["requests"]
+    for r, request in zip(rows, stream):  # taken in the list's order
+        assert set(r) - {"first_s", "error"} == RECORD_KEYS | {"shared_tokens"}
+        assert (r["n_prompt"], r["want"], r["shared_tokens"]) == (
+            len(request["prompt"]), request["max_new_tokens"], request["shared_tokens"]
+        )
+        assert r["ok"] or r["cut"]
+        assert 0 <= r["due_s"] == r["sent_s"] < seconds
+    assert sum(r["cut"] for r in rows) <= 3
+    run = {"requests": rows, "window_s": seconds, "loop": "closed"}
+    rate = harness.load_module("end_to_end", "serve_tokens_per_s").reduce(run)
+    assert rate == pytest.approx(sum(
+        r["n_prompt"] * (0 < r.get("first_s", 9) <= seconds)
+        + sum(1 for t in r["token_s"] if 0 < t <= seconds) for r in rows
+    ) / seconds)
+
+
+def test_a_lead_in_request_streams_into_the_window_and_is_no_sample_of_it():
+    lead = {"ok": True, "cut": False, "lead_in": True, "due_s": -2.0, "sent_s": -1.9,
+            "first_s": -1.5, "done_s": 0.6, "token_s": [-1.5, -0.1, 0.1, 0.5],
+            "n_prompt": 40, "status": 200}
+    own = {"ok": True, "cut": False, "lead_in": False, "due_s": 0.2, "sent_s": 0.21,
+           "first_s": 0.5, "done_s": 0.9, "token_s": [0.5, 0.8], "n_prompt": 10,
+           "status": 200}
+    rows = [lead, own]
+    assert stats.in_window(rows) == [own]
+    assert stats.ttfts_ms(rows) == pytest.approx([300.0])
+    # the gap that ended at -0.1 s is the lead-in's own; the one that
+    # began before 0 and ended after it is the window's
+    assert sorted(stats.pooled_gaps_ms(rows, 2.0)) == pytest.approx([200.0, 300.0, 400.0])
+    run = {"requests": rows, "window_s": 2.0, "loop": "open"}
+    read = lambda d, m: harness.load_module(d, m).reduce(run)
+    assert read("layer_metrics", "gen_late_p99_ms") == pytest.approx(10.0)
+    assert read("layer_metrics", "ttft_p50_ms") == pytest.approx(300.0)
+    assert read("end_to_end", "itl_mean_ms") == pytest.approx(300.0)
+    # the lead-in's prompt was prefilled before the window: 2 tokens of
+    # it streamed inside, then the window's own 10 + 2
+    assert read("end_to_end", "serve_tokens_per_s") == pytest.approx(14 / 2.0)
 
 
 # -- the trace reduction ----------------------------------------------
@@ -496,11 +661,31 @@ def test_xplane_reads_a_profile_written_here(tmp_path):
 
 # -- the reference ----------------------------------------------------
 
+@pytest.mark.parametrize("name", [c["name"] for c in MANIFEST["configs"]])
+def test_every_configuration_resolves_to_a_reference_with_forward(name):
+    from benchmark.reference import compare
+
+    config = harness.load_config(MANIFEST, name)
+    module = compare.load(config.get("reference"))
+    assert callable(module.forward)
+    stem = config.get("reference", compare.DEFAULT)
+    assert module.__file__ == os.path.join(ROOT, "benchmark", "reference", f"{stem}.py")
+
+
+def test_a_reference_that_is_not_there_or_has_no_forward_is_refused():
+    from benchmark.reference import compare
+
+    with pytest.raises(harness.BenchmarkError):
+        compare.load("no_such_reference")
+    with pytest.raises(harness.BenchmarkError):
+        compare.load("compare")  # a module of the directory, but no reference
+
+
 def test_reference_agrees_with_the_program_on_a_tiny_model():
     import jax
     import jax.numpy as jnp
 
-    from benchmark.reference import llama_ref
+    from benchmark.reference import compare, llama_ref
     from ray_tpu.models.llama import LlamaConfig, forward, init_params
 
     model = dict(vocab_size=97, dim=32, n_layers=3, n_heads=4, n_kv_heads=2,
@@ -514,10 +699,53 @@ def test_reference_agrees_with_the_program_on_a_tiny_model():
     tokens = jax.random.randint(jax.random.PRNGKey(1), (40,), 0, 97)
     got = forward(params, tokens[None], cfg)[0]
     want = llama_ref.forward(params, tokens, model, q_block=16)
-    assert llama_ref.relative_rms_error(got, want) < 1e-5
+    assert compare.relative_rms_error(got, want) < 1e-5
     # a path in lower precision must fail a bf16-sized tolerance's tenth
     low = forward(
         jax.tree.map(lambda x: x.astype(jnp.bfloat16), params), tokens[None],
         LlamaConfig(**model, dtype=jnp.bfloat16, attention="reference"),
     )[0]
-    assert llama_ref.relative_rms_error(low, want) > 1e-3
+    assert compare.relative_rms_error(low, want) > 1e-3
+
+
+def test_the_int8_control_reads_above_the_programs_bf16_path_on_a_tiny_model():
+    """`benchmark/control.py` at a size a test run can hold: the plain
+    reference with int8 weights in the program's place. At the cells'
+    own sizes it is run on the chip (PERF.md section 2 has the readings
+    beside the limits); here it has to round to 8 bits and no further,
+    and read further from the reference than the program's own bf16
+    path does."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import control
+    from benchmark.reference import compare, llama_ref
+    from ray_tpu.models.llama import LlamaConfig, forward, init_params
+
+    w = jax.random.normal(jax.random.PRNGKey(3), (64, 16), jnp.float32)
+    q = control.int8_matrix(w)
+    scale = jnp.max(jnp.abs(w), axis=0) / 127.0
+    levels = jnp.round(q / scale)
+    assert float(jnp.max(jnp.abs(levels * scale - q))) < 1e-6  # on the int8 grid
+    assert float(jnp.max(jnp.abs(levels))) == 127.0
+    assert float(jnp.max(jnp.abs(q - w) / scale)) <= 0.5 + 1e-4  # rounded, not cut
+
+    model = dict(vocab_size=97, dim=32, n_layers=3, n_heads=4, n_kv_heads=2,
+                 intermediate=48, rope_theta=1e6, max_seq_len=64,
+                 norm_eps=1e-5, attn_bias=True)
+    cfg = LlamaConfig(**model, dtype=jnp.bfloat16, attention="reference")
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (40,), 0, 97)
+    want = llama_ref.forward(params, tokens, model, q_block=16)
+    program = compare.relative_rms_error(forward(params, tokens[None], cfg)[0], want)
+    untouched = {k: params[k] for k in ("embed", "final_norm")}
+    quantized = control.int8_weights(jax.tree.map(jnp.copy, params))
+    assert all(bool(jnp.all(quantized[k] == v)) for k, v in untouched.items())
+    assert not bool(jnp.all(quantized["layers"]["wq"] == params["layers"]["wq"]))
+    got = compare.relative_rms_error(llama_ref.forward(quantized, tokens, model, q_block=16), want)
+    assert 1.5 * program < got < 0.2, (program, got)
+    row = control.control_errors(
+        {"model": model, "dtype": "bfloat16", "tolerance": {"logits_rel_rms": 0.035}}, [0], 40
+    )[0]
+    assert row["limit"] == 0.035 and 0 < row["all_positions"] < 0.2
+

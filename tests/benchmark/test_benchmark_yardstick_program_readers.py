@@ -78,6 +78,38 @@ def test_engine_readers_give_nothing_where_there_is_nothing(reader, run):
     assert read(reader, run) is None
 
 
+def timers_run(before, after):
+    return {"engine_timers": {"before": before, "after": after}}
+
+
+def test_ingress_overhead_is_proxy_time_less_replica_time_over_the_window():
+    # 20 requests ended in the window: 6.0 s each at the proxy, 4.5 s
+    # each in the replica's handler (PR 25: 1.5 requests/s, at the knee).
+    run = timers_run(
+        {"serve_http_request_latency_ms": [9000.0, 3.0],
+         "serve_request_latency_ms": [8700.0, 3.0]},
+        {"serve_http_request_latency_ms": [9000.0 + 20 * 6000.0, 23.0],
+         "serve_request_latency_ms": [8700.0 + 20 * 4500.0, 23.0],
+         "serve_engine_decode_step_ms": [100.0, 5.0]},
+    )
+    assert read("ingress_overhead_mean_ms", run) == pytest.approx(1500.0)
+
+
+@pytest.mark.parametrize("run", [
+    {},
+    {"engine_timers": None},
+    # a program with the engine's timers only
+    timers_run({}, {"serve_engine_decode_step_ms": [100.0, 5.0]}),
+    # no request ended in the window
+    timers_run(
+        {"serve_http_request_latency_ms": [50.0, 2.0], "serve_request_latency_ms": [40.0, 2.0]},
+        {"serve_http_request_latency_ms": [50.0, 2.0], "serve_request_latency_ms": [40.0, 2.0]},
+    ),
+], ids=["train", "no-timers", "engine-only", "idle"])
+def test_ingress_overhead_gives_nothing_where_there_is_nothing(run):
+    assert read("ingress_overhead_mean_ms", run) is None
+
+
 MODEL = {"dim": 4096, "n_layers": 4, "n_heads": 32, "n_kv_heads": 8,
          "intermediate": 14336, "vocab_size": 32768}
 
