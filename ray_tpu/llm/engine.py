@@ -444,6 +444,18 @@ class InferenceEngine:
         # to the longest alive row, the rule the program itself runs).
         self._kv_keys_live = 0
         self._kv_keys_read = 0
+        # What the expert layers did, from the picks per layer and
+        # expert each paged forward leaves in the pool (MoE configs
+        # only; a dense engine has none of these keys in stats()).
+        self._moe: Dict[str, int] = dict.fromkeys(
+            (
+                "moe_picks_prefill", "moe_chunk_layers",
+                "moe_chunk_max_load", "moe_chunk_experts",
+                "moe_picks_decode", "moe_step_layers",
+                "moe_experts_touched",
+            ) if cfg is not None and cfg.moe_experts else (),
+            0,
+        )
         self._prefilling: Optional[_Request] = None
         self._by_id: Dict[str, _Request] = {}
         self._policy_pending: "deque[_PolicyRequest]" = deque()
@@ -666,6 +678,7 @@ class InferenceEngine:
                 admit_wait_ms_total=self._admit_wait_ms_total,
                 kv_keys_live=self._kv_keys_live,
                 kv_keys_read=self._kv_keys_read,
+                **self._moe,
                 **self._device,
             )
             if self._kv is not None:
@@ -1081,6 +1094,10 @@ class InferenceEngine:
         fence.block_until_ready()
         phase.switch("engine.emit")
         self._observe_prefill((time.perf_counter() - t0) * 1e3)
+        if self._moe:
+            # The program that made the fence made these: a copy of
+            # [layers, E] integers, no second wait.
+            self._count_moe("prefill", np.asarray(pool["moe_counts"]))
         if last_chunk:
             req.padded = None
             with self._lock:
@@ -1163,7 +1180,11 @@ class InferenceEngine:
             self._kv.pool = pool
             self._last_logits = last_logits
             phase.switch("engine.decode.sync")
-            tokens = np.asarray(token)  # device->host sync per step
+            # device->host sync per step: the tokens and, for a MoE
+            # config, the step's picks in the same transfer.
+            tokens, moe_counts = jax.device_get(
+                (token, pool.get("moe_counts"))
+            )
         else:
             # Mixed-generation window: paged_decode_step donates
             # last_logits on accelerator backends, so each group gets
@@ -1180,7 +1201,7 @@ class InferenceEngine:
             # would block the host once per generation inside the hot
             # step loop (static analyzer rule RT303); one sync after
             # the loop costs the same D2H as the single-gen path.
-            merged_tokens = None
+            merged_tokens = moe_counts = None
             for gen in sorted(by_gen):
                 mask = np.zeros(ec.slots, bool)
                 mask[by_gen[gen]] = True
@@ -1205,13 +1226,21 @@ class InferenceEngine:
                     token,
                     0 if merged_tokens is None else merged_tokens,
                 )
+                if self._moe:
+                    # Each group's program counts its own rows' picks.
+                    moe_counts = pool["moe_counts"] + (
+                        0 if moe_counts is None else moe_counts
+                    )
             self._kv.pool = pool
             self._last_logits = merged
             phase.switch("engine.decode.sync")
-            tokens = np.asarray(merged_tokens)  # ONE sync for the window
+            # ONE sync for the window
+            tokens, moe_counts = jax.device_get((merged_tokens, moe_counts))
         phase.switch("engine.emit")
         step_ms = (time.perf_counter() - t0) * 1e3
         self._steps += 1
+        if moe_counts is not None:
+            self._count_moe("decode", moe_counts)
         now = time.perf_counter()
         emitted = 0
         with self._lock:
@@ -1256,6 +1285,26 @@ class InferenceEngine:
         with self._lock:
             self._kv_keys_live += live
             self._kv_keys_read += read
+
+    def _count_moe(self, program: str, counts: np.ndarray) -> None:
+        """Add one paged forward's picks per layer and expert,
+        `counts` [layers, E]: the picks; per layer the experts that
+        got any token (the expert weights the forward had to read);
+        and, of a chunk, per layer the fullest expert's tokens (how
+        uneven the grouped matmuls ran)."""
+        picks, layers = int(counts.sum()), counts.shape[0]
+        touched = int((counts > 0).sum())
+        with self._lock:
+            moe = self._moe
+            if program == "prefill":
+                moe["moe_picks_prefill"] += picks
+                moe["moe_chunk_layers"] += layers
+                moe["moe_chunk_max_load"] += int(counts.max(axis=1).sum())
+                moe["moe_chunk_experts"] += touched
+            else:
+                moe["moe_picks_decode"] += picks
+                moe["moe_step_layers"] += layers
+                moe["moe_experts_touched"] += touched
 
     # -- metrics -------------------------------------------------------
     # All hooks are guarded no-ops on failure: observability must

@@ -300,7 +300,9 @@ class PagedKVCache:
     @property
     def pool(self) -> Dict[str, object]:
         """The {"k", "v"} block pool the jitted paged kernels consume
-        and (on accelerator backends, via donation) update in place."""
+        and (on accelerator backends, via donation) update in place;
+        for a MoE config it also carries `moe_counts`, the last
+        forward's picks per layer and expert."""
         return self._pool
 
     @pool.setter

@@ -27,8 +27,8 @@ import jax.numpy as jnp
 
 from .._private import compile_watch
 from ..ops.norms import apply_rotary, rotary_embedding
-from .llama import embed_tokens, model_glu, model_norm
-from .llama import LlamaConfig, project_qkv
+from .llama import embed_tokens, model_norm
+from .llama import EXPERT_LEAVES, LlamaConfig, _mlp, project_qkv
 
 
 def init_kv_cache(
@@ -118,8 +118,7 @@ def _layer_with_cache(
     attn = jnp.einsum("bhqk,bhkd->bhqd", probs, vf.astype(jnp.float32))
     attn = attn.astype(cfg.dtype).transpose(0, 2, 1, 3).reshape(b, t, -1)
     x = x + attn @ layer["wo"]
-    h = model_norm(cfg, x, layer["mlp_norm"])
-    x = x + model_glu(cfg, h @ layer["w1"], h @ layer["w3"]) @ layer["w2"]
+    x, _, _ = _mlp(cfg, x, layer)
     return x, k_cache, v_cache
 
 
@@ -308,7 +307,9 @@ def init_block_pool(
     cfg: LlamaConfig, n_blocks: int, block_len: int
 ) -> Dict[str, jax.Array]:
     """The shared pool: k/v of shape
-    [layers, n_blocks, kv_heads, block_len, head_dim]."""
+    [layers, n_blocks, kv_heads, block_len, head_dim]; for a MoE
+    config also `moe_counts` [layers, E], where each paged forward
+    leaves its picks per expert (`_paged_forward`)."""
     shape = (
         cfg.n_layers,
         n_blocks,
@@ -316,10 +317,15 @@ def init_block_pool(
         block_len,
         cfg.head_dim,
     )
-    return {
+    pool = {
         "k": jnp.zeros(shape, cfg.dtype),
         "v": jnp.zeros(shape, cfg.dtype),
     }
+    if cfg.moe_experts:
+        pool["moe_counts"] = jnp.zeros(
+            (cfg.n_layers, cfg.moe_experts), jnp.int32
+        )
+    return pool
 
 
 def paged_tile_keys(block_len: int, table_width: int, q_len: int) -> int:
@@ -495,7 +501,10 @@ def _paged_layer(
     valid_len: jax.Array,  # [b] valid cache length incl. x
     n_tiles,  # [] attention's trip count
     tile_blocks: int,
+    live=None,  # [b] rows that are real (None: all)
 ):
+    """-> (x, k_pool, v_pool, counts): counts is the layer's picks
+    per expert [E] for a MoE config, None for a dense one."""
     b, t, _ = x.shape
     with jax.named_scope("layer/attn_qkv"):
         h = model_norm(cfg, x, layer["attn_norm"])
@@ -517,12 +526,12 @@ def _paged_layer(
         )
         x = x + attn @ layer["wo"]
     with jax.named_scope("paged/mlp"):
-        h = model_norm(cfg, x, layer["mlp_norm"])
-        x = x + (
-            model_glu(cfg, h @ layer["w1"], h @ layer["w3"])
-            @ layer["w2"]
+        # A MoE layer's experts arrive as whole stacks (below).
+        x, _, counts = _mlp(
+            cfg, x, layer, live=live,
+            layer_idx=layer_idx if cfg.moe_experts else None,
         )
-    return x, k_pool, v_pool
+    return x, k_pool, v_pool, counts
 
 
 def _paged_forward(
@@ -536,7 +545,10 @@ def _paged_forward(
     `alive` [b] names the rows whose length bounds the walk over key
     tiles (a dead row still computes, over whatever tiles the live
     ones need, and sees none of their keys). The pool is carried
-    through the layer loop and written in place."""
+    through the layer loop and written in place. For a MoE config the
+    new pool also holds this forward's picks per layer and expert,
+    `moe_counts` [layers, E] int32 (overwritten, not summed: the
+    engine adds them up); a dead row picks no expert."""
     q_pos = jnp.asarray(q_pos, jnp.int32)
     valid_len = jnp.asarray(valid_len, jnp.int32)
     t = tokens.shape[1]
@@ -560,24 +572,39 @@ def _paged_forward(
         q_pos, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
     )
 
+    live = None if alive is True else alive
+    # The loop slices each layer's weights out of their stacks, all
+    # but a MoE layer's experts: a slice of those would be copied
+    # before the grouped-matmul kernel (805 MB a layer at OLMoE's
+    # widths), so they stay whole and the expert layer finds its own
+    # in them (ops/moe.py). A dense model has none: its loop is as it
+    # was.
+    layers = params["layers"]
+    experts = {n: layers[n] for n in EXPERT_LEAVES if n in layers}
+    sliced = {n: w for n, w in layers.items() if n not in experts}
+
     def body(carry, inputs):
         x, k_pool, v_pool = carry
         layer, layer_idx = inputs
-        return _paged_layer(
-            cfg, x, layer, layer_idx, cos, sin, k_pool, v_pool,
-            tables, q_pos, valid_len, n_tiles, tile_blocks,
-        ), None
+        *carry, counts = _paged_layer(
+            cfg, x, {**layer, **experts}, layer_idx, cos, sin, k_pool,
+            v_pool, tables, q_pos, valid_len, n_tiles, tile_blocks, live,
+        )
+        return tuple(carry), counts
 
-    (x, new_k, new_v), _ = jax.lax.scan(
+    (x, new_k, new_v), counts = jax.lax.scan(
         body,
         (x, pool["k"], pool["v"]),
-        (params["layers"], jnp.arange(cfg.n_layers)),
+        (sliced, jnp.arange(cfg.n_layers)),
     )
     with jax.named_scope("final_norm"):
         x = model_norm(cfg, x, params["final_norm"])
     with jax.named_scope("lm_head"):
         logits = (x @ params["lm_head"]).astype(jnp.float32)
-    return logits, {"k": new_k, "v": new_v}
+    new_pool = {"k": new_k, "v": new_v}
+    if counts is not None:
+        new_pool["moe_counts"] = counts
+    return logits, new_pool
 
 
 def _paged_prefill_impl(

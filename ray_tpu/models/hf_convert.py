@@ -1,5 +1,5 @@
 """HuggingFace checkpoint conversion (Llama + Qwen2 + Qwen3 +
-Mistral + Gemma + Phi-3 families).
+Mistral + Gemma + Phi-3 + OLMoE families).
 
 The integration-parity role of the reference's framework adapters
 (reference: python/ray/train/huggingface/ — Ray Train wraps HF
@@ -17,7 +17,12 @@ RMSNorms, a sqrt(dim) embedding scale and a head_dim decoupled from
 dim/n_heads (gemma-2's soft-capping stays loudly unsupported);
 Phi-3 fuses qkv_proj and gate_up_proj, which the converter splits by
 output-row ranges; Qwen3 adds per-head RMSNorm on q and k before
-RoPE (cfg.qk_norm) with a decoupled head_dim.
+RoPE (cfg.qk_norm) with a decoupled head_dim. OLMoE
+(`OlmoeForCausalLM`) swaps the dense MLP for top-k of gated experts
+(per-expert gate/up/down_proj stacked on an expert axis -> w_gate /
+w_up / w_down, `mlp.gate` -> router, gates renormalised or not from
+norm_topk_prob) and norms q and k over the whole projection
+(cfg.qk_norm "proj").
 tests/test_hf_parity.py proves numerical parity of the full forward
 (logits) against transformers' reference implementation for all six.
 
@@ -83,11 +88,11 @@ def config_from_hf(hf_config) -> LlamaConfig:
             )
     model_type = getattr(hf_config, "model_type", "llama")
     if model_type not in (
-        "llama", "qwen2", "mistral", "gemma", "phi3", "qwen3"
+        "llama", "qwen2", "mistral", "gemma", "phi3", "qwen3", "olmoe"
     ):
         raise NotImplementedError(
             f"model_type={model_type!r}: only the llama, qwen2, "
-            "qwen3, mistral, gemma and phi3 families convert; "
+            "qwen3, mistral, gemma, phi3 and olmoe families convert; "
             "anything else would need its own numerics audit "
             "(gemma2's logit soft-capping and alternating sliding "
             "windows are NOT implemented — converting one would "
@@ -156,6 +161,29 @@ def config_from_hf(hf_config) -> LlamaConfig:
                 "hidden_act only"
             )
         act = mapping[hidden_act]
+    moe = {}
+    if model_type == "olmoe":
+        if getattr(hf_config, "clip_qkv", None) is not None:
+            raise NotImplementedError(
+                "olmoe clip_qkv is not implemented (the published "
+                "OLMoE-1B-7B configs carry null)"
+            )
+        if getattr(hf_config, "attention_bias", False):
+            raise NotImplementedError(
+                "olmoe attention_bias=True (o_proj bias too) has no "
+                "slot here"
+            )
+        if getattr(hf_config, "hidden_act", "silu") != "silu":
+            raise NotImplementedError(
+                f"olmoe hidden_act={hf_config.hidden_act!r} unsupported"
+            )
+        moe = dict(
+            moe_experts=hf_config.num_experts,
+            moe_top_k=hf_config.num_experts_per_tok,
+            moe_router=(
+                "softmax_renorm" if hf_config.norm_topk_prob else "softmax"
+            ),
+        )
     head_dim = getattr(hf_config, "head_dim", 0) or 0
     if head_dim and head_dim * hf_config.num_attention_heads == (
         hf_config.hidden_size
@@ -163,7 +191,8 @@ def config_from_hf(hf_config) -> LlamaConfig:
         head_dim = 0  # derived — keep the config canonical
     return LlamaConfig(
         attn_bias=model_type == "qwen2",
-        qk_norm=model_type == "qwen3",
+        qk_norm={"qwen3": "head", "olmoe": "proj"}.get(model_type, False),
+        **moe,
         custom_head_dim=head_dim,
         act=act,
         norm_offset=model_type == "gemma",
@@ -257,15 +286,32 @@ def convert_hf_llama(state_dict: Dict[str, Any], cfg: LlamaConfig):
             "wq": stack("self_attn.q_proj.weight"),
             "wk": stack("self_attn.k_proj.weight"),
             "wv": stack("self_attn.v_proj.weight"),
-            # Our swiglu(x, gate) gates its SECOND argument; the
-            # forward computes swiglu(h @ w1, h @ w3), so gate_proj
-            # lands in w3.
-            "w3": stack("mlp.gate_proj.weight"),
-            "w1": stack("mlp.up_proj.weight"),
         }
+        if cfg.moe_experts:  # OLMoE: [L, E, in, out] per projection
+            def experts(name: str):
+                return jnp.stack([
+                    stack(f"mlp.experts.{e}.{name}.weight")
+                    for e in range(cfg.moe_experts)
+                ], axis=1)
+
+            layers.update({
+                "router": stack("mlp.gate.weight"),
+                "w_gate": experts("gate_proj"),
+                "w_up": experts("up_proj"),
+                "w_down": experts("down_proj"),
+            })
+        else:
+            layers.update({
+                # Our swiglu(x, gate) gates its SECOND argument; the
+                # forward computes swiglu(h @ w1, h @ w3), so
+                # gate_proj lands in w3.
+                "w3": stack("mlp.gate_proj.weight"),
+                "w1": stack("mlp.up_proj.weight"),
+            })
+    if not cfg.moe_experts:
+        layers["w2"] = stack("mlp.down_proj.weight")
     layers.update({
         "wo": stack("self_attn.o_proj.weight"),
-        "w2": stack("mlp.down_proj.weight"),
         "attn_norm": stack("input_layernorm.weight", transpose=False),
         "mlp_norm": stack(
             "post_attention_layernorm.weight", transpose=False
@@ -277,7 +323,7 @@ def convert_hf_llama(state_dict: Dict[str, Any], cfg: LlamaConfig):
             "bk": stack("self_attn.k_proj.bias", transpose=False),
             "bv": stack("self_attn.v_proj.bias", transpose=False),
         })
-    if cfg.qk_norm:  # Qwen3 per-head q/k RMSNorm weights
+    if cfg.qk_norm:  # Qwen3 per-head / OLMoE projection-wide weights
         layers.update({
             "q_norm": stack("self_attn.q_norm.weight", transpose=False),
             "k_norm": stack("self_attn.k_norm.weight", transpose=False),

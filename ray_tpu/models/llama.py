@@ -30,7 +30,7 @@ from ..ops.attention import (
     mha_reference,
     repeat_kv,
 )
-from ..ops.moe import moe_ffn_dense, moe_ffn_ep
+from ..ops.moe import moe_ffn_dropless, moe_ffn_ep
 from ..ops.norms import apply_rotary, rms_norm, rotary_embedding, swiglu
 from ..ops.ring_attention import ring_attention
 from ..parallel.sharding import Annotated, annotate
@@ -61,10 +61,18 @@ class LlamaConfig:
     remat_policy: str = "full"  # full | dots | dots_flash
     # ---- mixture of experts ----
     #: >0 turns every FFN into a top-k-routed MoE with this many
-    #: experts (0 = dense SwiGLU). Experts shard over the `ep` mesh
-    #: axis when an ep_axis is passed (shard_map) — SURVEY §2.4 EP row.
+    #: gated experts of width `intermediate`, activation `act` (0 =
+    #: dense GLU). Every expert on one device runs dropless
+    #: (ops/moe.py); experts shard over the `ep` mesh axis when an
+    #: ep_axis is passed (shard_map) — SURVEY §2.4 EP row.
     moe_experts: int = 0
     moe_top_k: int = 2
+    #: What the top-k gates are: "softmax_renorm" (the k largest
+    #: softmax probabilities, rescaled to sum to one: Mixtral, Switch)
+    #: or "softmax" (left as they are: OLMoE, `norm_topk_prob: false`).
+    moe_router: str = "softmax_renorm"
+    #: Buffer size of the expert-parallel exchange only (picks past
+    #: it drop); the single-device path has no capacity.
     moe_capacity_factor: float = 2.0
     #: Weight of the Switch/GShard load-balancing auxiliary loss.
     moe_aux_weight: float = 0.01
@@ -93,8 +101,19 @@ class LlamaConfig:
     norm_offset: bool = False
     #: Multiply embedding output by sqrt(dim) (Gemma normalizer).
     embed_scale: bool = False
-    #: Per-head RMSNorm on q and k before RoPE (Qwen3 family).
-    qk_norm: bool = False
+    #: RMSNorm on q and k before RoPE: False (none), "head" (over
+    #: each head's head_dim, one weight of head_dim shared by the
+    #: heads: Qwen3; True means this) or "proj" (over the whole
+    #: projection before the split into heads: OLMoE).
+    qk_norm: Any = False
+
+    def __post_init__(self):
+        if self.qk_norm is True:
+            object.__setattr__(self, "qk_norm", "head")
+        if self.qk_norm not in (False, "head", "proj"):
+            raise ValueError(f"unknown qk_norm {self.qk_norm!r}")
+        if self.moe_router not in ("softmax", "softmax_renorm"):
+            raise ValueError(f"unknown moe_router {self.moe_router!r}")
 
     @property
     def head_dim(self) -> int:
@@ -104,8 +123,8 @@ class LlamaConfig:
         embed = self.vocab_size * self.dim
         if self.moe_experts:
             ffn = self.dim * self.moe_experts + (
-                2 * self.moe_experts * self.dim * self.intermediate
-            )  # router + per-expert in/out
+                3 * self.moe_experts * self.dim * self.intermediate
+            )  # router + per-expert gate/up/down
         else:
             ffn = 3 * self.dim * self.intermediate  # w1, w2, w3
         per_layer = (
@@ -119,9 +138,17 @@ class LlamaConfig:
             per_layer += (
                 self.n_heads + 2 * self.n_kv_heads
             ) * self.head_dim
-        if self.qk_norm:
-            per_layer += 2 * self.head_dim
+        per_layer += sum(self.qk_norm_widths())
         return embed * 2 + self.n_layers * per_layer + self.dim
+
+    def qk_norm_widths(self) -> tuple:
+        """Lengths of the q and k norm weights: () with no q/k norm."""
+        if self.qk_norm == "proj":
+            return (
+                self.n_heads * self.head_dim,
+                self.n_kv_heads * self.head_dim,
+            )
+        return (self.head_dim, self.head_dim) if self.qk_norm else ()
 
     # ---- presets ----
     @staticmethod
@@ -210,7 +237,7 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
             * (1.0 / math.sqrt(fan_in))
         ).astype(dt)
 
-    keys = jax.random.split(k_layers, 7)
+    keys = jax.random.split(k_layers, 8)
     L = cfg.n_layers
     layers = {
         "wq": norm_init(keys[0], cfg.dim, (L, cfg.dim, cfg.n_heads * hd)),
@@ -227,18 +254,22 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
             "bv": jnp.zeros((L, cfg.n_kv_heads * hd), dt),
         })
     if cfg.qk_norm:
+        q_width, k_width = cfg.qk_norm_widths()
         layers.update({
-            "q_norm": jnp.ones((L, hd), dt),
-            "k_norm": jnp.ones((L, hd), dt),
+            "q_norm": jnp.ones((L, q_width), dt),
+            "k_norm": jnp.ones((L, k_width), dt),
         })
     if cfg.moe_experts:
         E = cfg.moe_experts
         layers.update({
             "router": norm_init(keys[4], cfg.dim, (L, cfg.dim, E)),
-            "w_in": norm_init(
+            "w_gate": norm_init(
                 keys[5], cfg.dim, (L, E, cfg.dim, cfg.intermediate)
             ),
-            "w_out": norm_init(
+            "w_up": norm_init(
+                keys[7], cfg.dim, (L, E, cfg.dim, cfg.intermediate)
+            ),
+            "w_down": norm_init(
                 keys[6], cfg.intermediate,
                 (L, E, cfg.intermediate, cfg.dim),
             ),
@@ -283,8 +314,9 @@ def param_annotations(cfg: LlamaConfig) -> Dict[str, Any]:
     if cfg.moe_experts:
         layers.update({
             "router": annotate("layers", "embed", None),
-            "w_in": annotate("layers", "expert", "embed", "mlp"),
-            "w_out": annotate("layers", "expert", "mlp", "embed"),
+            "w_gate": annotate("layers", "expert", "embed", "mlp"),
+            "w_up": annotate("layers", "expert", "embed", "mlp"),
+            "w_down": annotate("layers", "expert", "mlp", "embed"),
         })
     else:
         layers.update({
@@ -310,10 +342,15 @@ def project_qkv(cfg: LlamaConfig, h, layer):
     q, k, v = h @ layer["wq"], h @ layer["wk"], h @ layer["wv"]
     if cfg.attn_bias:
         q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
+    if cfg.qk_norm == "proj":
+        # OLMoE: one RMSNorm over the whole projection, heads not yet
+        # split, BEFORE RoPE (transformers' OlmoeAttention).
+        q = rms_norm(q, layer["q_norm"], eps=cfg.norm_eps)
+        k = rms_norm(k, layer["k_norm"], eps=cfg.norm_eps)
     q = q.reshape(b, t, cfg.n_heads, hd).transpose(0, 2, 1, 3)
     k = k.reshape(b, t, cfg.n_kv_heads, hd).transpose(0, 2, 1, 3)
     v = v.reshape(b, t, cfg.n_kv_heads, hd).transpose(0, 2, 1, 3)
-    if cfg.qk_norm:
+    if cfg.qk_norm == "head":
         # Qwen3: per-head RMSNorm over head_dim, BEFORE RoPE (callers
         # apply rope to whatever this returns, matching transformers'
         # q_norm/k_norm placement).
@@ -354,34 +391,48 @@ def _layer(cfg: LlamaConfig, x, layer, cos, sin, sp_axis=None,
         )
         x = x + attn @ layer["wo"]
     with jax.named_scope("layer/mlp"):
-        x, aux = _mlp(cfg, x, layer, ep_axis)
+        x, aux, _ = _mlp(cfg, x, layer, ep_axis)
     return x, aux
 
 
-def _mlp(cfg: LlamaConfig, x, layer, ep_axis):
-    """The block's second half: norm, dense GLU or MoE, residual."""
+#: The experts' matrices of a MoE layer, stacked `[L, E, ., .]`.
+EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+def _mlp(cfg: LlamaConfig, x, layer, ep_axis=None, live=None,
+         layer_idx=None):
+    """The second half of every block — training, the contiguous-cache
+    forwards and the paged forwards call this one: norm, dense GLU or
+    MoE, residual. x: [b, t, dim] -> (x, aux, counts): the MoE
+    load-balancing loss (0 for a dense layer) and the picks each
+    expert got, [E] int32 (None for a dense layer). `live` [b] marks
+    the rows a serve step computes for real; with `layer_idx` the
+    `EXPERT_LEAVES` of `layer` are the whole stacks and that is the
+    layer to use (ops/moe.py `moe_ffn_dropless` has both)."""
     b, t, _ = x.shape
     h = model_norm(cfg, x, layer["mlp_norm"])
-    if cfg.moe_experts:
-        moe_params = {
-            "router": layer["router"],
-            "w_in": layer["w_in"],
-            "w_out": layer["w_out"],
-        }
-        flat = h.reshape(b * t, -1)
-        if ep_axis is not None:
-            out, aux = moe_ffn_ep(
-                moe_params, flat, axis_name=ep_axis,
-                k=cfg.moe_top_k,
-                capacity_factor=cfg.moe_capacity_factor,
-            )
-        else:
-            out, aux = moe_ffn_dense(moe_params, flat, k=cfg.moe_top_k)
-        x = x + out.reshape(b, t, -1)
-    else:
+    if not cfg.moe_experts:
         x = x + model_glu(cfg, h @ layer["w1"], h @ layer["w3"]) @ layer["w2"]
-        aux = jnp.zeros((), jnp.float32)
-    return x, aux
+        return x, jnp.zeros((), jnp.float32), None
+    moe = dict(
+        k=cfg.moe_top_k,
+        renormalise=cfg.moe_router == "softmax_renorm",
+        glu=partial(model_glu, cfg),
+    )
+    flat = h.reshape(b * t, -1)
+    if ep_axis is not None:
+        out, aux = moe_ffn_ep(
+            layer, flat, axis_name=ep_axis,
+            capacity_factor=cfg.moe_capacity_factor, **moe,
+        )
+        counts = None
+    else:
+        out, aux, counts = moe_ffn_dropless(
+            layer, flat,
+            live=None if live is None else jnp.repeat(live, t),
+            layer=layer_idx, **moe,
+        )
+    return x + out.reshape(b, t, -1), aux, counts
 
 
 def forward_and_aux(
@@ -508,7 +559,7 @@ def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:
     parameters a token activates (top-k experts, not all E)."""
     n = cfg.num_params()
     if cfg.moe_experts:
-        inactive = (cfg.moe_experts - cfg.moe_top_k) * 2 * (
+        inactive = (cfg.moe_experts - cfg.moe_top_k) * 3 * (
             cfg.dim * cfg.intermediate
         )
         n -= cfg.n_layers * max(inactive, 0)

@@ -1,24 +1,41 @@
-"""Mixture-of-experts with expert parallelism.
+"""Mixture-of-experts: one router, one gated expert function, two ways
+of bringing tokens to experts.
 
 Absent from the reference (SURVEY.md §2.4 EP row — no MoE sharding
-anywhere in Ray core or its libraries); built TPU-first: experts shard
-over the `ep` mesh axis, token dispatch/return are `lax.all_to_all`
-hops over ICI, and the per-expert FFN is a dense batched matmul that
-lands on the MXU (GShard/Switch capacity-based dispatch — fixed
-capacity keeps every shape static for XLA; overflow tokens drop to the
-residual path, the standard trade).
+anywhere in Ray core or its libraries); built TPU-first.
+
+  * `route`: float32 softmax over all experts, the `k` largest
+    probabilities and their experts, renormalised to sum to one or
+    left as they are (OLMoE's `norm_topk_prob: false`).
+  * `gated_experts`: `down(act(gate(x)) * up(x))` for rows already
+    grouped by expert; how a row finds its expert's matrices is the
+    caller's matmul.
+  * `moe_ffn_dropless` — every expert on this device (training on one
+    device, the serve forwards): the `t * k` picks are sorted by
+    expert and the three matmuls run as grouped matmuls over the
+    sorted rows (`lax.ragged_dot`, which XLA compiles to its own
+    grouped-matmul kernel on the TPU), so each token meets only its
+    `k` experts and NOTHING is dropped at any skew: all tokens to one
+    expert is one group of `t` rows.
+  * `moe_ffn_ep` — experts sharded over the `ep` mesh axis inside a
+    `shard_map`: dispatch/return are `lax.all_to_all` hops over ICI
+    with GShard/Switch fixed-capacity buffers (static shapes for the
+    exchange; picks past an expert's capacity drop to the residual
+    path). Its dropless rewrite belongs to the four-chip training
+    cell (ROADMAP.md Reach 1).
 """
 
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ..parallel.collective import axis_size as _axis_size
+from .norms import swiglu
 
 
 def init_moe_params(
@@ -28,40 +45,125 @@ def init_moe_params(
     d_ff: int,
     dtype=jnp.float32,
 ) -> Dict[str, jax.Array]:
-    k_router, k1, k2 = jax.random.split(key, 3)
+    k_router, k_gate, k_up, k_down = jax.random.split(key, 4)
     scale_in = 1.0 / math.sqrt(d_model)
     scale_out = 1.0 / math.sqrt(d_ff)
+
+    def normal(k, shape, scale):
+        return (jax.random.normal(k, shape) * scale).astype(dtype)
+
     return {
-        "router": (
-            jax.random.normal(k_router, (d_model, num_experts)) * scale_in
-        ).astype(dtype),
-        "w_in": (
-            jax.random.normal(k1, (num_experts, d_model, d_ff)) * scale_in
-        ).astype(dtype),
-        "w_out": (
-            jax.random.normal(k2, (num_experts, d_ff, d_model)) * scale_out
-        ).astype(dtype),
+        "router": normal(k_router, (d_model, num_experts), scale_in),
+        "w_gate": normal(k_gate, (num_experts, d_model, d_ff), scale_in),
+        "w_up": normal(k_up, (num_experts, d_model, d_ff), scale_in),
+        "w_down": normal(k_down, (num_experts, d_ff, d_model), scale_out),
     }
 
 
-def top_k_router(
-    logits: jax.Array, k: int
+def route(
+    x: jax.Array, router: jax.Array, k: int, renormalise: bool = True
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """[tokens, experts] -> (gates [t, k], indices [t, k], aux_loss).
+    """x [t, d], router [d, E] -> (gates [t, k] float32, experts
+    [t, k], aux_loss). Logits and softmax are float32 whatever the
+    model's dtype: which expert comes 8th is decided here.
 
     aux_loss is the Switch/GShard load-balancing loss: mean expert
     probability x mean assignment fraction, scaled by num_experts.
     """
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gates, indices = lax.top_k(probs, k)
-    gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    logits = jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    )
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, experts = lax.top_k(probs, k)
+    if renormalise:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
     num_experts = logits.shape[-1]
     assign = jnp.sum(
-        jax.nn.one_hot(indices[:, 0], num_experts), axis=0
+        jax.nn.one_hot(experts[:, 0], num_experts), axis=0
     ) / logits.shape[0]
     importance = jnp.mean(probs, axis=0)
     aux_loss = num_experts * jnp.sum(assign * importance)
-    return gates, indices, aux_loss
+    return gates, experts, aux_loss
+
+
+def gated_experts(
+    params: Dict, rows: jax.Array, matmul: Callable, glu: Callable
+) -> jax.Array:
+    """`down(glu(up(rows), gate(rows)))` with the caller's grouped
+    `matmul(rows, expert_weights)`; `glu(x, gate) = act(gate) * x`."""
+    hidden = glu(
+        matmul(rows, params["w_up"]), matmul(rows, params["w_gate"])
+    )
+    return matmul(hidden, params["w_down"])
+
+
+def moe_ffn_dropless(
+    params: Dict,
+    x: jax.Array,
+    *,
+    k: int = 2,
+    renormalise: bool = True,
+    glu: Callable = swiglu,
+    live: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
+):
+    """Every expert local, no capacity. x: [tokens, d] ->
+    (out [tokens, d], aux_loss, counts [E] int32: picks per expert).
+
+    `layer`: the experts' matrices are whole stacks `[layers, E, ., .]`
+    and this is the layer to use. A loop over layers that slices its
+    layer's experts out of the stack makes the compiler COPY them
+    before the grouped-matmul kernel (a custom call cannot read a
+    slice in place): 805 MB a layer at OLMoE's widths, 22 ms of a
+    39 ms forward on the v5e (PERF.md, PR 26). With the stack seen as
+    `layers x E` groups of which only this layer's hold rows, the
+    kernel reads the experts where they lie and visits no empty group.
+    The serve forwards pass it; training slices (its gradient would
+    otherwise be the whole stack's, once a layer).
+
+    `live` [tokens] bool marks the rows that are real (a decode step
+    carries dead slots): a row that is not live picks no expert, so it
+    is in no group, reads no expert's weights, counts nowhere and
+    comes out zero.
+    """
+    t, _ = x.shape
+    num_experts = params["router"].shape[-1]
+    with jax.named_scope("moe/route"):
+        gates, experts, aux = route(x, params["router"], k, renormalise)
+        picked = experts.reshape(-1)  # [t*k], token-major
+        if live is not None:
+            # Past the last expert: sorted behind every group.
+            picked = jnp.where(jnp.repeat(live, k), picked, num_experts)
+        order = jnp.argsort(picked)  # stable: pick j of sorted row i
+        counts = jnp.bincount(picked, length=num_experts).astype(jnp.int32)
+        groups = counts
+        if layer is not None:
+            n_layers = params["w_gate"].shape[0]
+            groups = lax.dynamic_update_slice(
+                jnp.zeros(n_layers * num_experts, jnp.int32),
+                counts, (layer * num_experts,),
+            )
+    with jax.named_scope("moe/experts"):
+        out = gated_experts(
+            params,
+            x[order // k],
+            # (a stack's two leading axes merge for free)
+            lambda rows, w: lax.ragged_dot(
+                rows, w.reshape((-1,) + w.shape[-2:]), groups
+            ),
+            glu,
+        )
+    with jax.named_scope("moe/combine"):
+        # Back to token order by the inverse permutation (a gather; a
+        # scatter-add of t*k rows serialises on the TPU), then the
+        # weighted sum of each token's k rows in float32.
+        out = out[jnp.argsort(order)].reshape(t, k, -1)
+        if live is not None:
+            # Rows behind the last group are whatever the kernel left.
+            out = jnp.where(live[:, None, None], out, 0)
+        out = jnp.sum(out * gates[:, :, None], axis=1)
+    return out.astype(x.dtype), aux, counts
 
 
 def _dispatch_tensors(
@@ -70,9 +172,10 @@ def _dispatch_tensors(
     num_experts: int,
     capacity: int,
 ):
-    """Capacity-based dispatch (Switch-style): per (token, choice),
-    its position in the target expert's buffer; tokens past capacity
-    drop. Returns dispatch one-hot [t, E, C] and combine [t, E, C]."""
+    """Capacity-based dispatch (Switch-style) for the all_to_all
+    exchange: per (token, choice), its position in the target expert's
+    buffer; picks past capacity drop. Returns dispatch one-hot
+    [t, E, C] and combine [t, E, C]."""
     t, k = indices.shape
     flat_expert = indices.reshape(-1)  # [t*k], choice-major rows
     onehot = jax.nn.one_hot(flat_expert, num_experts, dtype=jnp.int32)
@@ -81,38 +184,19 @@ def _dispatch_tensors(
     pos_in_expert = jnp.sum(position * onehot, axis=-1)  # [t*k]
     keep = pos_in_expert < capacity
     pos_clipped = jnp.clip(pos_in_expert, 0, capacity - 1)
-    dispatch = (
+    slot = (
         jax.nn.one_hot(flat_expert, num_experts)[:, :, None]
         * jax.nn.one_hot(pos_clipped, capacity)[:, None, :]
-        * keep[:, None, None]
     )  # [t*k, E, C]
-    dispatch = dispatch.reshape(t, k, num_experts, capacity).sum(axis=1)
-    combine = (
-        (
-            jax.nn.one_hot(flat_expert, num_experts)[:, :, None]
-            * jax.nn.one_hot(pos_clipped, capacity)[:, None, :]
-            * (keep * gates.reshape(-1))[:, None, None]
+
+    def per_token(weight):
+        return (
+            (slot * weight[:, None, None])
+            .reshape(t, k, num_experts, capacity)
+            .sum(axis=1)
         )
-        .reshape(t, k, num_experts, capacity)
-        .sum(axis=1)
-    )
-    return dispatch, combine
 
-
-def moe_ffn_dense(params: Dict, x: jax.Array, k: int = 2):
-    """Single-device reference: every expert local. x: [tokens, d]."""
-    logits = x @ params["router"]
-    gates, indices, aux = top_k_router(logits, k)
-    outs = jnp.einsum("td,edf->tef", x, params["w_in"])
-    outs = jax.nn.gelu(outs)
-    outs = jnp.einsum("tef,efd->ted", outs, params["w_out"])
-    picked = jnp.take_along_axis(
-        outs, indices[:, :, None], axis=1
-    )  # [t, k, d]
-    return (
-        jnp.sum(picked * gates[:, :, None].astype(x.dtype), axis=1),
-        aux,
-    )
+    return per_token(keep), per_token(keep * gates.reshape(-1))
 
 
 def moe_ffn_ep(
@@ -122,16 +206,18 @@ def moe_ffn_ep(
     axis_name: str = "ep",
     k: int = 2,
     capacity_factor: float = 2.0,
+    renormalise: bool = True,
+    glu: Callable = swiglu,
 ):
     """Expert-parallel MoE inside shard_map.
 
     Each rank holds E_local = E/ep experts (params sharded on the
     expert axis) and a token shard x: [t_local, d]. Dispatch:
     one all_to_all sends each rank's per-expert buffers to the expert's
-    owner; experts run dense; a second all_to_all returns outputs.
+    owner; experts run batched; a second all_to_all returns outputs.
     """
     ep = _axis_size(axis_name)
-    e_local = params["w_in"].shape[0]
+    e_local = params["w_gate"].shape[0]
     num_experts = e_local * ep
     t_local, d = x.shape
     capacity = int(
@@ -141,8 +227,7 @@ def moe_ffn_ep(
 
     # The router is tiny ([d, E]) and replicated on every rank; only
     # the expert FFN weights shard over ep.
-    logits = x @ params["router"]
-    gates, indices, aux = top_k_router(logits, k)
+    gates, indices, aux = route(x, params["router"], k, renormalise)
     dispatch, combine = _dispatch_tensors(
         indices, gates, num_experts, capacity
     )
@@ -161,9 +246,9 @@ def moe_ffn_ep(
     h = expert_inputs.transpose(1, 0, 2, 3).reshape(
         e_local, ep * capacity, d
     )
-    h = jnp.einsum("ecd,edf->ecf", h, params["w_in"])
-    h = jax.nn.gelu(h)
-    h = jnp.einsum("ecf,efd->ecd", h, params["w_out"])
+    h = gated_experts(
+        params, h, lambda rows, w: jnp.einsum("ecd,edf->ecf", rows, w), glu
+    )
     # Return trip: back to source ranks.
     h = h.reshape(e_local, ep, capacity, d).transpose(1, 0, 2, 3)
     h = lax.all_to_all(

@@ -618,3 +618,78 @@ def test_qwen3_greedy_decode_matches_transformers_generate():
         temperature=0.0,
     )
     assert np.asarray(ours).tolist() == ref.tolist()
+
+
+# ---------------------------------------------------------------------
+# OLMoE (top-k of gated experts, projection-wide q/k norm)
+# ---------------------------------------------------------------------
+
+def _tiny_hf_olmoe(norm_topk_prob=False, seed=0):
+    from transformers import OlmoeConfig, OlmoeForCausalLM
+
+    torch.manual_seed(seed)
+    hf_cfg = OlmoeConfig(
+        vocab_size=128,
+        hidden_size=64,
+        intermediate_size=32,
+        num_hidden_layers=2,
+        num_attention_heads=4,
+        num_key_value_heads=4,
+        num_experts=8,
+        num_experts_per_tok=2,
+        norm_topk_prob=norm_topk_prob,
+        max_position_embeddings=64,
+        rope_theta=10000.0,
+        tie_word_embeddings=False,
+        attn_implementation="eager",
+    )
+    model = OlmoeForCausalLM(hf_cfg)
+    # RMSNorm weights are ones at init: a q/k norm over the wrong
+    # axis, or left out of a path, must show.
+    with torch.no_grad():
+        for name, p in model.named_parameters():
+            if name.endswith(("q_norm.weight", "k_norm.weight")):
+                p.add_(0.3 * torch.randn_like(p))
+    model.eval()
+    return model
+
+
+@pytest.mark.parametrize("norm_topk_prob", [False, True])
+def test_olmoe_logits_match_transformers(norm_topk_prob):
+    """Three independent copies of OLMoE's equations agree in float32:
+    transformers' `OlmoeForCausalLM`, the program, and the benchmark's
+    plain reference. The reference against transformers is what
+    catches a q/k norm in the wrong place or a renormalised gate in
+    BOTH of this repo's copies."""
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.reference import olmoe_ref
+
+    model = _tiny_hf_olmoe(norm_topk_prob, seed=31)
+    cfg = config_from_hf(model.config)
+    assert cfg.qk_norm == "proj" and cfg.moe_experts == 8
+    assert cfg.moe_top_k == 2 and cfg.intermediate == 32
+    assert cfg.moe_router == (
+        "softmax_renorm" if norm_topk_prob else "softmax"
+    )
+    rng = np.random.default_rng(31)
+    tokens = rng.integers(0, 128, (2, 33), dtype=np.int64)
+    _compare(model, tokens)
+    with torch.no_grad():
+        ref = model(torch.from_numpy(tokens)).logits.numpy()
+    params = convert_hf_llama(model.state_dict(), cfg)
+    keys = dict(
+        dim=cfg.dim, n_layers=cfg.n_layers, n_heads=cfg.n_heads,
+        n_kv_heads=cfg.n_kv_heads, norm_eps=cfg.norm_eps,
+        rope_theta=cfg.rope_theta, moe_top_k=cfg.moe_top_k,
+        moe_router=cfg.moe_router,
+    )
+    for row in range(tokens.shape[0]):
+        plain = np.asarray(olmoe_ref.forward(
+            params, jax.numpy.asarray(tokens[row]), keys, q_block=16
+        ))
+        assert np.max(np.abs(plain - ref[row])) < 2e-4

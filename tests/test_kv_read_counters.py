@@ -82,3 +82,66 @@ def test_engine_counts_keys_live_and_keys_read():
         assert twice["kv_keys_read"] == 2 * once["kv_keys_read"]
     finally:
         engine.close()
+
+
+def _engine(**model):
+    from ray_tpu.llm import EngineConfig, InferenceEngine
+    from ray_tpu.models.llama import LlamaConfig, init_params
+
+    cfg = LlamaConfig(
+        vocab_size=128, dim=64, n_layers=2, n_heads=4, n_kv_heads=4,
+        intermediate=32, max_seq_len=128, dtype=jnp.float32,
+        attention="reference", **model,
+    )
+    return cfg, InferenceEngine(
+        init_params(jax.random.PRNGKey(0), cfg), cfg,
+        EngineConfig(slots=3, max_len=48, prefill_chunk=8, max_new_tokens=6),
+        family="tiny",
+    )
+
+
+def test_engine_adds_up_the_expert_counts_of_a_moe_config():
+    cfg, engine = _engine(
+        moe_experts=8, moe_top_k=2, moe_router="softmax", qk_norm="proj"
+    )
+    try:
+        assert engine.stats()["moe_picks_prefill"] == 0
+        # 11 and 5 tokens: two chunks of 8 and one; 6 steps each, the
+        # two rows alive together for as many steps as they overlap.
+        streams = [
+            engine.submit(list(range(1, n + 1)), max_new_tokens=6)
+            for n in (11, 5)
+        ]
+        assert [len(list(s)) for s in streams] == [6, 6]
+        stats = engine.stats()
+        chunks, k, layers, experts = 3, cfg.moe_top_k, cfg.n_layers, 8
+        # picks = tokens x k x layers: a chunk computes all 8 of its
+        # tokens, a step only its live rows (dead slots pick nothing).
+        assert stats["moe_picks_prefill"] == chunks * 8 * k * layers
+        assert stats["moe_picks_decode"] == stats["tokens_emitted"] * k * layers
+        assert stats["tokens_emitted"] == 12
+        assert stats["moe_chunk_layers"] == chunks * layers
+        assert stats["moe_step_layers"] == stats["steps"] * layers
+        # the fullest expert of a chunk holds its even share or more,
+        # and never more than the chunk's tokens
+        even = 8 * k / experts
+        assert even * stats["moe_chunk_layers"] <= stats["moe_chunk_max_load"]
+        assert stats["moe_chunk_max_load"] <= 8 * stats["moe_chunk_layers"]
+        # 16 picks of a chunk-layer touch 2 experts at least, 8 at most
+        assert k * stats["moe_chunk_layers"] <= stats["moe_chunk_experts"]
+        assert stats["moe_chunk_experts"] <= experts * stats["moe_chunk_layers"]
+        # a step touches at least k experts a layer, at most one a pick
+        assert k * stats["moe_step_layers"] <= stats["moe_experts_touched"]
+        assert stats["moe_experts_touched"] <= stats["moe_picks_decode"]
+    finally:
+        engine.close()
+
+
+def test_a_dense_engine_reports_no_expert_counter():
+    _, engine = _engine()
+    try:
+        assert len(list(engine.submit([1, 2, 3], max_new_tokens=2))) == 2
+        assert not [k for k in engine.stats() if k.startswith("moe_")]
+        assert "moe_counts" not in engine._kv.pool
+    finally:
+        engine.close()

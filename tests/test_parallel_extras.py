@@ -111,24 +111,30 @@ def test_spmd_pipeline_differentiable():
         )
 
 
+#: The expert-parallel layout of `init_moe_params`' tree: the router
+#: replicated, the experts' matrices sharded on the expert axis.
+_EP_SPECS = {
+    "router": P(), "w_gate": P("ep"), "w_up": P("ep"), "w_down": P("ep"),
+}
+
+
 def test_moe_dense_routes_topk():
-    from ray_tpu.ops.moe import init_moe_params, moe_ffn_dense
+    from ray_tpu.ops.moe import init_moe_params, moe_ffn_dropless
 
     params = init_moe_params(jax.random.PRNGKey(0), 4, 16, 32)
     x = jax.random.normal(jax.random.PRNGKey(1), (10, 16))
-    out, aux = moe_ffn_dense(params, x, k=2)
+    out, aux, counts = moe_ffn_dropless(params, x, k=2)
     assert out.shape == (10, 16)
     assert np.isfinite(np.asarray(out)).all()
     assert float(aux) > 0
+    assert int(counts.sum()) == 10 * 2
 
 
 def test_moe_expert_parallel_matches_dense():
     """EP sharded MoE == dense MoE when capacity never overflows."""
-    from ray_tpu.ops.moe import (
-        init_moe_params,
-        moe_ffn_dense,
-        moe_ffn_ep,
-    )
+    from ray_tpu.ops.moe import init_moe_params, moe_ffn_ep
+
+    from moe_oracle import all_experts_ffn
 
     ep, e_local, d, ff = 4, 2, 16, 32
     num_experts = ep * e_local
@@ -139,9 +145,9 @@ def test_moe_expert_parallel_matches_dense():
     )
     x = jax.random.normal(jax.random.PRNGKey(1), (ep * t_local, d))
 
-    def ep_fn(router, w_in, w_out, tokens):
+    def ep_fn(params, tokens):
         out, aux = moe_ffn_ep(
-            {"router": router, "w_in": w_in, "w_out": w_out},
+            params,
             tokens,
             k=2,
             capacity_factor=float(num_experts),  # no drops
@@ -152,15 +158,15 @@ def test_moe_expert_parallel_matches_dense():
         shard_map(
             ep_fn,
             mesh=mesh,
-            in_specs=(P(), P("ep"), P("ep"), P("ep")),
+            in_specs=(_EP_SPECS, P("ep")),
             out_specs=P("ep"),
         )
     )
-    got = run(params["router"], params["w_in"], params["w_out"], x)
+    got = run(params, x)
 
-    # Dense reference per token shard (routing is per-token, so the
-    # shard split doesn't change assignments).
-    want, _ = moe_ffn_dense(params, x, k=2)
+    # The all-experts oracle over every token (routing is per-token,
+    # so the shard split doesn't change assignments).
+    want = all_experts_ffn(params, x, 2)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4
     )
@@ -174,12 +180,8 @@ def test_moe_ep_sharded_gradients_finite():
     params = init_moe_params(jax.random.PRNGKey(0), 8, d, ff)
     x = jax.random.normal(jax.random.PRNGKey(1), (32, d))
 
-    def loss(router, w_in, w_out, tokens):
-        out, aux = moe_ffn_ep(
-            {"router": router, "w_in": w_in, "w_out": w_out},
-            tokens,
-            k=2,
-        )
+    def loss(params, tokens):
+        out, aux = moe_ffn_ep(params, tokens, k=2)
         from jax import lax
 
         return lax.pmean(jnp.mean(out**2) + 0.01 * aux, "ep")
@@ -187,14 +189,10 @@ def test_moe_ep_sharded_gradients_finite():
     run = shard_map(
         loss,
         mesh=mesh,
-        in_specs=(P(), P("ep"), P("ep"), P("ep")),
+        in_specs=(_EP_SPECS, P("ep")),
         out_specs=P(),
     )
-    grads = jax.jit(
-        jax.grad(
-            lambda r, wi, wo: run(r, wi, wo, x), argnums=(0, 1, 2)
-        )
-    )(params["router"], params["w_in"], params["w_out"])
-    for g in grads:
+    grads = jax.jit(jax.grad(lambda p: run(p, x)))(params)
+    for g in jax.tree.leaves(grads):
         assert np.isfinite(np.asarray(g)).all()
         assert float(jnp.abs(g).sum()) > 0

@@ -107,8 +107,9 @@ def test_pp_loss_matches_nonpp():
 
 
 def test_moe_dense_matches_shapes_single_device():
-    """MoE Llama runs single-device (dense fallback path) through the
-    standard loss_fn, aux loss included."""
+    """MoE Llama runs single-device (the dropless path: every expert
+    local, ops/moe.py) through the standard loss_fn, aux loss
+    included."""
     cfg = LlamaConfig(
         vocab_size=64, dim=32, n_layers=2, n_heads=2, n_kv_heads=2,
         intermediate=64, max_seq_len=32, dtype=jnp.float32,

@@ -170,3 +170,63 @@ def test_paged_programs_update_the_pool_in_place(one_chip, program):
     one_array = 2 * int(jnp.prod(jnp.asarray(pool_shape)))
     assert memory.alias_size_in_bytes >= 2 * one_array  # donated, reused
     assert memory.temp_size_in_bytes < one_array, memory
+
+
+def test_expert_matmuls_are_named_ragged_dot_and_scoped(one_chip):
+    """The dropless expert layer at OLMoE's widths, compiled for the
+    described chip: XLA turns `lax.ragged_dot` into a grouped-matmul
+    kernel of its own whose instructions are named `ragged-dot-*`
+    (what `benchmark/layer_metrics/moe_kernel_share.py` sums by), one
+    per projection, and one `ragged-dot-metadata` that lays out the
+    groups for all three. XLA renames the kernels' `op_name` too, so
+    the instruction name is what finds them; the `moe/route`,
+    `moe/experts` (the gather of the sorted rows, the activation) and
+    `moe/combine` scopes are on the operations around them. Nothing
+    runs every expert on every token: the compiler's own count of the
+    program's operations is the picks', not 8 times that."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from ray_tpu.ops.moe import moe_ffn_dropless
+
+    experts, dim, width, tokens, k = 64, 2048, 1024, 512, 8
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {
+        "router": spec((dim, experts)),
+        "w_gate": spec((experts, dim, width)),
+        "w_up": spec((experts, dim, width)),
+        "w_down": spec((experts, width, dim)),
+    }
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(
+            lambda p, x: moe_ffn_dropless(p, x, k=k, renormalise=False)
+        ).lower(params, spec((tokens, dim))).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    kernels = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and " = " in line
+    ]
+    names = [
+        line.strip().split(" = ")[0].removeprefix("ROOT ").lstrip("%")
+        for line in kernels
+    ]
+    assert names and all(n.startswith("ragged-dot") for n in names), names
+    matmuls = [
+        line for line, n in zip(kernels, names) if "metadata" not in n
+    ]
+    assert len(matmuls) == 3  # gate, up, down
+    assert len(names) == 4  # and one layout of the groups for all three
+    for scope in ("moe/route", "moe/experts", "moe/combine"):
+        assert scope in text, scope
+    # 512 tokens x 8 picks x 3 matrices x 2 x 2048 x 1024, not 8 times
+    # that: the compiler's own count of the program's operations.
+    flops = compiled.cost_analysis()["flops"]
+    assert flops < 1.5 * tokens * k * 3 * 2 * dim * width, flops
