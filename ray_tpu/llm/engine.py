@@ -13,24 +13,46 @@ loop, every iteration:
   2. admits the FIFO head of the waiting queue — gated on KV-block
      availability (not enough blocks: the head WAITS, no skip-ahead,
      no crash) — pinning any prefix-cache hit and reserving the rest
-     of its pages, then advances its prefill by ONE fixed-size chunk
-     written straight into its pages (Sarathi-style interleave, now
-     starting AFTER the shared prefix);
-  3. runs ONE jitted paged decode step over the FULL slot batch
+     of its pages, then DISPATCHES its prefill's next fixed-size
+     chunk, written straight into its pages (Sarathi-style
+     interleave, starting AFTER the shared prefix);
+  3. DISPATCHES one jitted paged decode step over the FULL slot batch
      (static shapes: full-width block tables, dead rows masked and
      parked on the null block; attention walks the tables only as far
      as the longest alive row reaches) —
-     `models/generate.paged_decode_step`, the pool donated and
-     written in place on accelerator backends — streaming each live
-     row's token to its consumer queue;
-  4. retires EOS/budget rows, releasing slots and unpinning blocks in
-     the same iteration (full prompt blocks stay cached for future
-     prefix hits until memory pressure evicts them).
+     `models/generate.paged_engine_step`, the pool donated and
+     written in place on accelerator backends;
+  4. only then RETIRES the programs of the iteration before: waits
+     for its chunk, fetches its step's tokens, streams each live
+     row's token to its consumer queue, and releases EOS/budget rows
+     (full prompt blocks stay cached for future prefix hits until
+     memory pressure evicts them).
+
+Dispatch ahead, retire behind (ISSUE 27). The device always has the
+next iteration's programs queued while the host emits, because a
+step needs nothing from the host that the step before it decides:
+block tables, positions, the alive mask, each row's EOS id and token
+budget and the step counter are device arrays (`_state`, laid out in
+models/generate.py) which the step program advances itself, ending a
+row by the two rules the host releases it by. The host keeps numpy
+mirrors (`_tables`, `_positions`, `_alive`, `_eos`, `_budget`: the
+allocator and the counters read them) and patches the device's copy
+one slot at a time where it decides something: a table row at
+admission, a row's start after its prompt's last chunk, a
+cancellation. What makes this safe is ORDER: one device stream runs
+programs in the order the loop dispatched them. So a block may go
+back to the allocator, or into the prefix cache, while a program that
+writes it is still queued: whoever gets the block next reads or
+writes it in a program dispatched later. And a row that ended in
+step N is dead on the device in step N+1, dispatched before the host
+saw N's tokens, and writes nothing there. The price: a finished
+row's slot and blocks come back one iteration after its last step.
+Depth is one iteration, a constant of this loop.
 
 Requests are host-side objects; per-request device state is the pages
-its table points at + one row of `last_logits`. Sampling parameters
-stay engine-level statics (jit statics in the shared kernel; greedy
-is the serving default).
+its table points at, one row of `last_logits` and one row of the step
+state. Sampling parameters stay engine-level statics (jit statics in
+the shared kernel; greedy is the serving default).
 
 Threading: submit()/cancel() may be called from any thread; all
 scheduler/allocator/request state is guarded by one lock, JAX work
@@ -52,7 +74,8 @@ RL dataflow:
   During the transient mixed window the decode batch partitions by
   generation and runs one masked decode step per generation (disjoint
   alive masks over the same pool; `last_logits` rows merge back), so
-  nothing is drained, shed or errored on account of the push. Old
+  no REQUEST is drained, shed or errored on account of the push (the
+  window's steps run one at a time, nothing dispatched ahead). Old
   generations are dropped the moment their last pinned request
   retires.
 * **Pluggable batch program** (`program=`, `submit_policy`): ragged
@@ -154,7 +177,7 @@ class _Request:
         "emitted", "slot", "bucket", "offset", "padded",
         "prefix_keys", "total_blocks", "block_ids", "n_shared",
         "skip", "gen", "submitted_ns", "admitted_ts", "decoding_ts",
-        "trace_parent", "serve_request_id",
+        "trace_parent", "serve_request_id", "table", "dispatched",
     )
 
     def __init__(
@@ -197,10 +220,49 @@ class _Request:
         self.block_ids: List[int] = []
         self.n_shared = 0
         self.skip = 0
+        #: The request's table row on the device, [1, width], uploaded
+        #: once at admission: what every chunk of its prompt takes.
+        self.table = None
+        #: Decode steps dispatched with this row alive, retired or
+        #: not: the host knows a budget's end without seeing a token.
+        self.dispatched = 0
         #: Weight generation pinned at ADMISSION (None until then):
         #: the request prefils and decodes on this generation to
         #: completion even if update_weights lands mid-stream.
         self.gen: Optional[int] = None
+
+
+class _ChunkInFlight:
+    """A prefill chunk the loop dispatched and has not retired."""
+
+    __slots__ = ("started", "fence", "t0")
+
+    def __init__(self, started: Optional[_Request], fence, t0: float):
+        #: The request whose row began to decode behind this chunk, its
+        #: prompt's last; None for any other chunk.
+        self.started = started
+        #: Ready when the chunk is (`generate.finish_chunk`); a MoE
+        #: config's picks of the chunk.
+        self.fence = fence
+        self.t0 = t0
+
+
+class _StepInFlight:
+    """A decode step the loop dispatched and has not retired."""
+
+    __slots__ = ("rows", "fetch", "state", "t0")
+
+    def __init__(self, rows, fetch, state, t0: float):
+        #: (slot, request) of the rows the HOST held alive at dispatch.
+        #: The device may know better: a row that met its EOS in the
+        #: step before was dead in this one, and is skipped at
+        #: retirement because its request has been released by then.
+        self.rows = rows
+        #: The step's tokens, the step counter, a MoE config's picks.
+        self.fetch = fetch
+        #: The device's step state as this step left it.
+        self.state = state
+        self.t0 = t0
 
 
 class TokenStream:
@@ -406,20 +468,37 @@ class InferenceEngine:
         else:
             self._kv = None
             self._sched = None
-        # Per-slot decode state. positions/alive/tables live host-side
-        # (the engine mutates them per admission/step); last_logits
-        # stays on device.
+        # Per-slot decode state: on the device (`_state`, advanced by
+        # the step program; `_last_logits`) and mirrored here in numpy
+        # for the allocator, the counters and `stats()`. The mirrors
+        # follow the device one retirement behind.
         import jax.numpy as jnp
 
+        self._steps = 0
         if cfg is not None:
             self._positions = np.zeros(ec.slots, np.int32)
             self._alive = np.zeros(ec.slots, bool)
+            self._eos = np.full(ec.slots, -1, np.int32)
+            self._budget = np.zeros(ec.slots, np.int32)
             self._tables = np.full(
                 (ec.slots, self._kv.max_blocks), NULL_BLOCK, np.int32
             )
             self._last_logits = jnp.zeros(
                 (ec.slots, cfg.vocab_size), jnp.float32
             )
+            self._null_row = jnp.full(
+                (1, self._kv.max_blocks), NULL_BLOCK, jnp.int32
+            )
+            self._push_state()
+        # Programs dispatched and not retired, oldest first, and the
+        # clock at the last retirement (where the next program's
+        # fenced timer starts if it was dispatched before that).
+        self._inflight: "deque" = deque()
+        self._retired_ts = 0.0
+        self._programs = 0
+        self._programs_ahead = 0
+        self._state_patches = 0
+        self._pipeline_drains = 0
         self._base_key = jax.random.PRNGKey(ec.seed)
         # Where this engine's programs run, as JAX reports it from
         # inside this process: every serving number is read against
@@ -462,7 +541,6 @@ class InferenceEngine:
         self._policy_rows_pending = 0
         self._policy_steps = 0
         self._policy_rows_served = 0
-        self._steps = 0
         self._tokens_emitted = 0
         self._requests_done = 0
         self._prefix_hits = 0
@@ -678,24 +756,38 @@ class InferenceEngine:
                 admit_wait_ms_total=self._admit_wait_ms_total,
                 kv_keys_live=self._kv_keys_live,
                 kv_keys_read=self._kv_keys_read,
+                # The pipeline: chunks and steps dispatched, those
+                # dispatched while an earlier one was not retired yet,
+                # patches of the device's step state, and the times
+                # the loop retired everything before going on (a
+                # mixed-generation window, shutdown).
+                programs=self._programs,
+                programs_ahead=self._programs_ahead,
+                state_patches=self._state_patches,
+                pipeline_drains=self._pipeline_drains,
                 **self._moe,
                 **self._device,
             )
             if self._kv is not None:
-                # Compile counts of the two programs the LLM path
-                # runs, as the compile watch credits them: under the
-                # names models/generate.py registers (one wrapper a
+                # Compile counts of the programs the LLM path runs,
+                # as the compile watch credits them: under the names
+                # models/generate.py registers (one wrapper a
                 # program; a second one here was credited nothing).
                 # Process-wide, like the jitted programs themselves.
                 # Steady state after warmup is a FIXED number —
-                # movement under traffic is a recompile bug.
+                # movement under traffic is a recompile bug. (A
+                # mixed-generation window runs a fifth,
+                # `generate.paged_decode_step`.)
                 out["compiles"] = {
-                    "prefill": compile_watch.program_stats(
-                        "generate.paged_prefill"
-                    ),
-                    "decode": compile_watch.program_stats(
-                        "generate.paged_decode_step"
-                    ),
+                    kind: compile_watch.program_stats(
+                        f"generate.{program}"
+                    )
+                    for kind, program in (
+                        ("prefill", "paged_prefill"),
+                        ("decode", "paged_engine_step"),
+                        ("patch", "patch_step_slot"),
+                        ("finish_chunk", "finish_chunk"),
+                    )
                 }
                 out.update(
                     kv_bytes=self._kv.nbytes(),
@@ -737,8 +829,10 @@ class InferenceEngine:
                     phase.switch("engine.reap")
                     self._drain_phases()
                     with self._lock:
-                        if self._stopping:
-                            return
+                        stopping = self._stopping
+                    if stopping:
+                        self._drain()
+                        return
                     worked = self._reap_cancelled()
                     if self._program is not None:
                         phase.switch("engine.policy")
@@ -747,8 +841,19 @@ class InferenceEngine:
                         # Prefill before decode: an admitted request
                         # advances by ONE chunk, then the whole batch
                         # decodes one step (Sarathi-style interleave).
-                        worked = self._advance_prefill() or worked
+                        # Both are only dispatched; what is retired
+                        # after them is what the iteration before
+                        # dispatched, so the device has this
+                        # iteration's programs queued while the host
+                        # emits that one's tokens.
+                        held = len(self._inflight)
+                        self._advance_prefill()
                         worked = self._decode() or worked
+                        ahead = max(len(self._inflight) - held, 0)
+                        worked = (
+                            self._retire(keep=ahead) or ahead > 0
+                            or worked
+                        )
                     if not worked:
                         phase.switch("engine.idle")
                         self._wake.wait(self.config.idle_wait_s)
@@ -844,30 +949,38 @@ class InferenceEngine:
     def _reap_cancelled(self) -> bool:
         if self._sched is None:
             return False
-        worked = False
+        reaped = []
         with self._lock:
             # The prefilling request is ALSO in sched.running (its
-            # slot was claimed at admission) — release it through this
-            # branch first so the loop below can't double-release the
-            # slot (release() on an already-freed slot raises and
+            # slot was claimed at admission), so one pass releases it
+            # too, once (release() on an already-freed slot raises and
             # would kill the whole loop).
             if (
                 self._prefilling is not None
                 and self._prefilling.cancelled.is_set()
             ):
-                req = self._prefilling
                 self._prefilling = None
-                self._release_locked(req.slot, req, "cancelled")
-                worked = True
             for slot, req in list(self._sched.running.items()):
                 if req.cancelled.is_set():
                     self._release_locked(slot, req, "cancelled")
-                    worked = True
-        return worked
+                    reaped.append(slot)
+        # The device cannot know of a cancellation: tell it, before
+        # the next step is dispatched. A step in flight still decodes
+        # the row; its token is dropped at retirement, and what it
+        # writes into the released blocks lands before anything their
+        # next owner dispatches.
+        for slot in reaped:
+            self._patch_slot(slot, self._null_row)
+        return bool(reaped)
 
     def _release_locked(
         self, slot: int, req: _Request, reason: str
     ) -> None:
+        """Give back a request's slot and blocks, in the mirrors. An
+        EOS or a spent budget the device has applied itself already
+        (its table row there goes stale, which a dead row's never
+        matters: the step program reads the null block for it); a
+        cancellation the caller patches in (`_patch_slot`)."""
         self._sched.release(slot)
         self._alive[slot] = False
         self._tables[slot, :] = NULL_BLOCK
@@ -876,6 +989,9 @@ class InferenceEngine:
             # (refcount 0, LRU-evictable); private blocks go back to
             # the free list. block_ids cleared so no path can double-
             # free (the allocator would raise and kill the loop).
+            # Programs still queued may write these blocks (the step
+            # in flight when a row is cancelled): safe, by dispatch
+            # order (module docstring).
             self._kv.alloc.release(req.block_ids)
             req.block_ids = []
         self._unpin_gen_locked(req)
@@ -1015,25 +1131,69 @@ class InferenceEngine:
             self._prefix_misses += 1
         self._observe_prefix(skip)
 
-    # -- prefill -------------------------------------------------------
-    def _advance_prefill(self) -> bool:
-        """Admit (if idle) and advance the current prefill by ONE
-        chunk, written straight into the request's pages. Returns
-        whether prefill work happened."""
+    # -- the device's step state --------------------------------------
+    def _push_state(self) -> None:
+        """Make the device's step state from the mirrors, whole: at
+        start, and after a mixed-generation window's serial steps."""
         import jax.numpy as jnp
 
-        from ..models.generate import paged_prefill
+        self._state = {
+            "tables": jnp.asarray(self._tables),
+            "positions": jnp.asarray(self._positions),
+            "alive": jnp.asarray(self._alive),
+            "eos": jnp.asarray(self._eos),
+            "budget": jnp.asarray(self._budget),
+            "step": jnp.asarray(np.int32(self._steps)),
+        }
+
+    def _patch_slot(self, slot: int, table_row) -> None:
+        """The device's row of `slot`: this table row, and dead."""
+        from ..models.generate import patch_step_slot
+
+        self._state = patch_step_slot(
+            self._state, np.int32(slot), table_row
+        )
+        self._state_patches += 1
+
+    def _dispatching(self) -> float:
+        """Count a chunk or step about to be dispatched; -> the clock
+        its fenced timer starts at."""
+        self._programs += 1
+        if self._inflight:
+            self._programs_ahead += 1
+        return time.perf_counter()
+
+    def _owed_ms(self, t0: float) -> float:
+        """A program's fenced time at its retirement (now): from the
+        later of its dispatch and the retirement of the program before
+        it. Programs run in dispatch order, so that is what the device
+        owed THIS program, its wait behind the one before not counted
+        twice."""
+        now = time.perf_counter()
+        ms = (now - max(t0, self._retired_ts)) * 1e3
+        self._retired_ts = now
+        return ms
+
+    # -- prefill -------------------------------------------------------
+    def _advance_prefill(self) -> None:
+        """Admit (if no prompt is prefilling) and dispatch the current
+        prefill's next chunk, written straight into the request's
+        pages, with `finish_chunk` behind it; neither is waited for."""
+        import jax.numpy as jnp
+
+        from ..models.generate import finish_chunk, paged_prefill
 
         phase = self._phase
         phase.switch("engine.admit")
         with self._lock:
             req = self._prefilling
-            if req is None:
+            admitting = req is None
+            if admitting:
                 admitted = self._sched.admit_next(
                     gate=self._gate_locked
                 )
                 if admitted is None:
-                    return False
+                    return
                 req, slot = admitted
                 req.slot = slot
                 req.admitted_ts = time.perf_counter()
@@ -1050,204 +1210,303 @@ class InferenceEngine:
                 self._gens[req.gen]["refs"] += 1
                 self._allocate_locked(req)
                 self._prefilling = req
+        slot = req.slot
+        if admitting:
+            req.table = jnp.asarray(self._tables[slot:slot + 1])
+            self._patch_slot(slot, req.table)
         phase.switch("engine.prefill.prepare")
         if req.padded is None:
             padded = np.zeros((1, req.bucket), np.int32)
             padded[0, : len(req.prompt)] = req.prompt
             req.padded = padded
         chunk = self.config.prefill_chunk
-        # `serve_engine_prefill_chunk_ms` starts here and ends after
-        # the wait: host arrays to device, dispatch, device.
-        t0 = time.perf_counter()
-        tokens = jnp.asarray(req.padded[:, req.offset:req.offset + chunk])
-        table = jnp.asarray(self._tables[req.slot:req.slot + 1])
-        offset = jnp.int32(req.offset)
-        valid_len = jnp.int32(req.offset + chunk)
+        start = req.offset
+        tokens = req.padded[:, start:start + chunk]
+        req.offset += chunk
+        last_chunk = req.offset >= req.bucket
         phase.switch("engine.prefill.dispatch")
+        t0 = self._dispatching()
         logits, pool = paged_prefill(
             self._gens[req.gen]["params"],
             self.cfg,
             tokens,
             self._kv.pool,
-            table,
-            offset,
-            valid_len,
+            req.table,
+            np.int32(start),
+            np.int32(start + chunk),
         )
         self._kv.pool = pool
-        req.offset += chunk
-        last_chunk = req.offset >= req.bucket
+        started = cancelled = False
         if last_chunk:
-            # Next-token logits come from the prompt's LAST REAL
-            # position (inside this chunk by bucket construction:
-            # the final chunk covers [bucket - chunk, bucket) and
-            # len(prompt) > bucket - chunk — prefix skip never
-            # reaches the final chunk, it is capped at
-            # len(prompt) - 1).
-            local = len(req.prompt) - 1 - (req.offset - chunk)
-            fence = logits[0, local]
-            self._last_logits = self._last_logits.at[req.slot].set(
-                fence
-            )
-        else:
-            fence = logits
-        phase.switch("engine.prefill.wait")
-        fence.block_until_ready()
-        phase.switch("engine.emit")
-        self._observe_prefill((time.perf_counter() - t0) * 1e3)
-        if self._moe:
-            # The program that made the fence made these: a copy of
-            # [layers, E] integers, no second wait.
-            self._count_moe("prefill", np.asarray(pool["moe_counts"]))
-        if last_chunk:
+            # The host's side of a row's start happens HERE, at
+            # dispatch, so that this iteration's decode step takes the
+            # row along as it always did, and the next iteration
+            # admits the next prompt.
             req.padded = None
             with self._lock:
                 self._prefilling = None
-                # Cancelled during the final chunk: reap now rather
-                # than decoding a dead row for one step.
-                if req.cancelled.is_set():
-                    self._release_locked(req.slot, req, "cancelled")
-                    return True
-                if self.config.prefix_cache:
-                    # Publish the full prompt blocks this request
-                    # computed (not the ones it shared) for future
-                    # prefix hits; first writer wins on races.
-                    for i in range(
-                        req.n_shared, len(req.prefix_keys)
-                    ):
-                        self._kv.alloc.register(
-                            req.block_ids[i], req.prefix_keys[i]
-                        )
-                self._positions[req.slot] = len(req.prompt)
-                self._alive[req.slot] = True
-                req.decoding_ts = time.perf_counter()
-        return True
+                # Cancelled during the prompt: reap now rather than
+                # decoding a dead row for one step.
+                cancelled = req.cancelled.is_set()
+                if cancelled:
+                    self._release_locked(slot, req, "cancelled")
+                else:
+                    started = True
+                    if self.config.prefix_cache:
+                        # Publish the full prompt blocks this request
+                        # computed (not the ones it shared) for future
+                        # prefix hits; first writer wins on races.
+                        # The chunk that fills the last of them is
+                        # only queued: a request that hits them reads
+                        # them in a program dispatched after it.
+                        for i in range(
+                            req.n_shared, len(req.prefix_keys)
+                        ):
+                            self._kv.alloc.register(
+                                req.block_ids[i], req.prefix_keys[i]
+                            )
+                    self._positions[slot] = len(req.prompt)
+                    self._alive[slot] = True
+                    self._budget[slot] = req.max_new_tokens
+                    # An id no token can be ends no row, here as in
+                    # `_emit`.
+                    self._eos[slot] = (
+                        req.eos_token
+                        if 0 <= req.eos_token < self.cfg.vocab_size
+                        else -1
+                    )
+        # Next-token logits come from the prompt's LAST REAL position
+        # (inside the last chunk by bucket construction: it covers
+        # [bucket - chunk, bucket) and len(prompt) > bucket - chunk —
+        # prefix skip never reaches the final chunk, it is capped at
+        # len(prompt) - 1). `finish_chunk` keeps that one row and the
+        # chunk's logits of every position (311 MB at qwen's chunk)
+        # are dropped here, at dispatch.
+        self._state, self._last_logits, fence = finish_chunk(
+            self._state,
+            self._last_logits,
+            logits,
+            pool.get("moe_counts"),
+            np.int32(slot),
+            np.int32(len(req.prompt) - 1 - start if last_chunk else 0),
+            np.bool_(started),
+            self._positions[slot],
+            self._budget[slot],
+            self._eos[slot],
+        )
+        del logits
+        if started:
+            self._state_patches += 1
+        if cancelled:
+            self._patch_slot(slot, self._null_row)
+        self._inflight.append(
+            _ChunkInFlight(req if started else None, fence, t0)
+        )
 
     # -- decode --------------------------------------------------------
+    def _live_rows(self) -> List[tuple]:
+        """(slot, request) of the rows a step dispatched now would
+        decode, as far as the host knows: alive in the mirror (so not
+        seen to end yet) and with budget left after the steps already
+        dispatched."""
+        with self._lock:
+            return [
+                (slot, req)
+                for slot, req in sorted(self._sched.running.items())
+                if self._alive[slot]
+                and req.dispatched < req.max_new_tokens
+            ]
+
     def _decode(self) -> bool:
+        """Dispatch one decode step if a row is alive; not waited for.
+        -> whether there was anything to decode."""
+        from ..models.generate import paged_engine_step
+
+        phase = self._phase
+        phase.switch("engine.decode.prepare")
+        def mixed(rows):
+            return len({req.gen for _, req in rows}) > 1
+
+        rows = self._live_rows()
+        if mixed(rows):
+            # Rows of two weight generations (the transient window
+            # after an update_weights: old streams finishing, new
+            # admissions starting): retire what is in flight (a row
+            # that ended there may end the window) and, if the mix
+            # stands, run this step the serial way.
+            self._drain()
+            rows = self._live_rows()
+            if mixed(rows):
+                self._decode_mixed(rows)
+                return True
+        if not rows:
+            return False
+        ec = self.config
+        phase.switch("engine.decode.dispatch")
+        t0 = self._dispatching()
+        fetch, pool, self._last_logits, self._state = paged_engine_step(
+            self._gens[rows[0][1].gen]["params"],
+            self.cfg,
+            self._kv.pool,
+            self._last_logits,
+            self._state,
+            self._base_key,
+            temperature=ec.temperature,
+            top_k=ec.top_k,
+        )
+        self._kv.pool = pool
+        for _, req in rows:
+            req.dispatched += 1
+        self._inflight.append(_StepInFlight(rows, fetch, self._state, t0))
+        return True
+
+    def _decode_mixed(self, rows: List[tuple]) -> None:
+        """One decode step over rows of several weight generations,
+        with nothing in flight: a masked `paged_decode_step` per
+        generation over the SAME pool (masks are disjoint and dead
+        rows scatter to the null block, so the groups can't
+        cross-talk), the host's mirrors for state, one sync, and the
+        device's state made anew from the mirrors after it."""
         import jax
         import jax.numpy as jnp
 
         from ..models.generate import paged_decode_step
 
         phase = self._phase
-        phase.switch("engine.decode.prepare")
-        alive_idx = np.flatnonzero(self._alive)
-        if alive_idx.size == 0:
-            return False
-        batch = int(alive_idx.size)
         ec = self.config
-        # `serve_engine_decode_step_ms` starts here and ends after the
-        # sync: the host's preparation, the dispatch and the device.
         t0 = time.perf_counter()
+        by_gen: Dict[int, List[int]] = {}
+        for slot, req in rows:
+            by_gen.setdefault(req.gen, []).append(slot)
+            req.dispatched += 1
+        self._count_kv_keys(by_gen.values())
         key = jax.random.fold_in(self._base_key, self._steps)
-        # Partition the alive batch by pinned weight generation. In
-        # steady state there is exactly one group and this is the
-        # PR 11 fast path verbatim; in the transient window after an
-        # update_weights there are two (old streams finishing, new
-        # admissions starting) and each runs its own masked decode
-        # step over the SAME pool — masks are disjoint and dead rows
-        # scatter to the null block, so the groups can't cross-talk.
-        with self._lock:
-            by_gen: Dict[int, List[int]] = {}
-            for slot in alive_idx:
-                req = self._sched.running.get(int(slot))
-                if req is None:
-                    continue
-                by_gen.setdefault(
-                    req.gen if req.gen is not None else 0, []
-                ).append(int(slot))
-        if not by_gen:
-            return False
-        self._count_kv_keys(by_gen)
         tables = jnp.asarray(self._tables)
         positions = jnp.asarray(self._positions)
-        if len(by_gen) == 1:
-            gen = next(iter(by_gen))
-            alive = jnp.asarray(self._alive)
-            phase.switch("engine.decode.dispatch")
-            token, pool, last_logits = paged_decode_step(
+        # paged_decode_step donates last_logits on accelerator
+        # backends, so each group gets a PRIVATE copy of the pre-step
+        # logits (`+ 0` forces a fresh buffer) and the surviving rows
+        # merge back — a group must never read another group's
+        # freshly-written junk rows, and the donated original must
+        # never be reused.
+        phase.switch("engine.decode.dispatch")
+        base_logits = self._last_logits
+        merged = base_logits
+        pool = self._kv.pool
+        # Tokens merge on-device too: a per-group np.asarray here
+        # would block the host once per generation inside the hot
+        # step loop (static analyzer rule RT303); one sync after the
+        # loop costs the same D2H as a single step's.
+        merged_tokens = moe_counts = None
+        for gen in sorted(by_gen):
+            self._dispatching()
+            mask = np.zeros(ec.slots, bool)
+            mask[by_gen[gen]] = True
+            gmask = jnp.asarray(mask)
+            token, pool, out_logits = paged_decode_step(
                 self._gens[gen]["params"],
                 self.cfg,
-                self._kv.pool,
+                pool,
                 tables,
-                self._last_logits,
+                base_logits + 0,
                 positions,
-                alive,
+                gmask,
                 key,
                 temperature=ec.temperature,
                 top_k=ec.top_k,
             )
-            self._kv.pool = pool
-            self._last_logits = last_logits
-            phase.switch("engine.decode.sync")
-            # device->host sync per step: the tokens and, for a MoE
-            # config, the step's picks in the same transfer.
-            tokens, moe_counts = jax.device_get(
-                (token, pool.get("moe_counts"))
+            merged = jnp.where(gmask[:, None], out_logits, merged)
+            merged_tokens = jnp.where(
+                gmask,
+                token,
+                0 if merged_tokens is None else merged_tokens,
             )
-        else:
-            # Mixed-generation window: paged_decode_step donates
-            # last_logits on accelerator backends, so each group gets
-            # a PRIVATE copy of the pre-step logits (`+ 0` forces a
-            # fresh buffer) and the surviving rows merge back — a
-            # group must never read another group's freshly-written
-            # junk rows, and the donated original must never be
-            # reused.
-            phase.switch("engine.decode.dispatch")
-            base_logits = self._last_logits
-            merged = base_logits
-            pool = self._kv.pool
-            # Tokens merge on-device too: a per-group np.asarray here
-            # would block the host once per generation inside the hot
-            # step loop (static analyzer rule RT303); one sync after
-            # the loop costs the same D2H as the single-gen path.
-            merged_tokens = moe_counts = None
-            for gen in sorted(by_gen):
-                mask = np.zeros(ec.slots, bool)
-                mask[by_gen[gen]] = True
-                gmask = jnp.asarray(mask)
-                token, pool, out_logits = paged_decode_step(
-                    self._gens[gen]["params"],
-                    self.cfg,
-                    pool,
-                    tables,
-                    base_logits + 0,
-                    positions,
-                    gmask,
-                    key,
-                    temperature=ec.temperature,
-                    top_k=ec.top_k,
+            if self._moe:
+                # Each group's program counts its own rows' picks.
+                moe_counts = pool["moe_counts"] + (
+                    0 if moe_counts is None else moe_counts
                 )
-                merged = jnp.where(
-                    gmask[:, None], out_logits, merged
-                )
-                merged_tokens = jnp.where(
-                    gmask,
-                    token,
-                    0 if merged_tokens is None else merged_tokens,
-                )
-                if self._moe:
-                    # Each group's program counts its own rows' picks.
-                    moe_counts = pool["moe_counts"] + (
-                        0 if moe_counts is None else moe_counts
-                    )
-            self._kv.pool = pool
-            self._last_logits = merged
-            phase.switch("engine.decode.sync")
-            # ONE sync for the window
-            tokens, moe_counts = jax.device_get((merged_tokens, moe_counts))
+        self._kv.pool = pool
+        self._last_logits = merged
+        phase.switch("engine.decode.sync")
+        # ONE sync for the window
+        tokens, moe_counts = jax.device_get((merged_tokens, moe_counts))
         phase.switch("engine.emit")
-        step_ms = (time.perf_counter() - t0) * 1e3
+        step_ms = self._owed_ms(t0)
         self._steps += 1
         if moe_counts is not None:
             self._count_moe("decode", moe_counts)
+        self._emit(rows, tokens)
+        self._observe_step(step_ms, len(rows), len(rows))
+        self._push_state()
+
+    # -- retirement ----------------------------------------------------
+    def _retire(self, keep: int) -> bool:
+        """Retire the oldest programs in flight until `keep` are left
+        (the ones this iteration dispatched). -> whether any was."""
+        worked = len(self._inflight) > keep
+        while len(self._inflight) > keep:
+            program = self._inflight.popleft()
+            if isinstance(program, _StepInFlight):
+                self._retire_step(program)
+            else:
+                self._retire_chunk(program)
+        return worked
+
+    def _drain(self) -> None:
+        """Retire everything in flight: afterwards the mirrors say
+        what the device's state says."""
+        if self._retire(keep=0):
+            self._pipeline_drains += 1
+
+    def _retire_chunk(self, chunk: _ChunkInFlight) -> None:
+        import jax
+
+        phase = self._phase
+        phase.switch("engine.prefill.wait")
+        fence = jax.device_get(chunk.fence)
+        phase.switch("engine.emit")
+        self._observe_prefill(self._owed_ms(chunk.t0))
+        if self._moe:
+            self._count_moe("prefill", fence)
+        if chunk.started is not None:
+            chunk.started.decoding_ts = time.perf_counter()
+
+    def _retire_step(self, step: _StepInFlight) -> None:
+        import jax
+
+        phase = self._phase
+        phase.switch("engine.decode.sync")
+        # device->host sync per step: the tokens and, for a MoE
+        # config, the step's picks in the same transfer.
+        fetch = jax.device_get(step.fetch)
+        phase.switch("engine.emit")
+        step_ms = self._owed_ms(step.t0)
+        # Rows released since the dispatch (they ended in the step
+        # before, or were cancelled) get no token: on the device the
+        # first kind was dead in this step anyway.
+        running = self._sched.running
+        rows = [
+            (slot, req) for slot, req in step.rows
+            if running.get(slot) is req
+        ]
+        # The device counts a step if a row was alive in it.
+        steps, self._steps = self._steps, int(fetch["step"])
+        if self._steps > steps:
+            # The mirrors still stand where this step found the rows.
+            self._count_kv_keys([[slot for slot, _ in rows]])
+            if self._moe:
+                self._count_moe("decode", fetch["moe_counts"])
+        self._emit(rows, fetch["token"])
+        self._observe_step(step_ms, len(rows), len(rows))
+
+    def _emit(self, rows: List[tuple], tokens: np.ndarray) -> None:
+        """Stream a retired step's token to each of `rows`, advance
+        the mirrors as the step program advanced the device's state,
+        and release the rows that ended: the same two rules."""
         now = time.perf_counter()
-        emitted = 0
         with self._lock:
-            for slot in alive_idx:
-                req = self._sched.running.get(int(slot))
-                if req is None:  # freed this iteration
-                    continue
+            for slot, req in rows:
                 tok = int(tokens[slot])
                 if req.first_token_ts is None:
                     req.first_token_ts = now
@@ -1256,26 +1515,25 @@ class InferenceEngine:
                     )
                 req.out.put(("tok", tok))
                 req.emitted += 1
-                emitted += 1
                 self._positions[slot] += 1
+                self._budget[slot] -= 1
                 if tok == req.eos_token:
-                    self._release_locked(int(slot), req, "stop")
+                    self._release_locked(slot, req, "stop")
                 elif req.emitted >= req.max_new_tokens:
-                    self._release_locked(int(slot), req, "length")
-            self._tokens_emitted += emitted
-        self._observe_step(step_ms, batch, emitted)
-        return True
+                    self._release_locked(slot, req, "length")
+            self._tokens_emitted += len(rows)
 
-    def _count_kv_keys(self, by_gen: Dict[int, List[int]]) -> None:
-        """Add this step's `kv_keys_live` / `kv_keys_read`: one
-        program per weight generation, each over every slot as far as
-        its own rows' longest `valid_len` asks."""
+    def _count_kv_keys(self, groups) -> None:
+        """Add a step's `kv_keys_live` / `kv_keys_read`: one program
+        per group of slots (one, or one a weight generation), each
+        over every slot as far as its own rows' longest `valid_len`
+        asks."""
         from ..models.generate import paged_tiles_read
 
         tile = self._kv_tile_keys
         valid_len = self._positions + 1
         live = read = 0
-        for slots in by_gen.values():
+        for slots in groups:
             mask = np.zeros_like(self._alive)
             mask[slots] = True
             live += int(valid_len[mask].sum())

@@ -719,6 +719,177 @@ def paged_decode_step(
     )
 
 
+# -- the engine's step state ------------------------------------------
+# What a decode step needs besides the pool and `last_logits` lives on
+# the device too, in one dict the engine owns (llm/engine.py keeps
+# numpy mirrors and says when each entry changes):
+#
+#   tables    [slots, width] int32  each row's physical block ids
+#   positions [slots] int32         where a row's next token goes
+#   alive     [slots] bool          rows that decode
+#   eos       [slots] int32         a row's EOS id (-1: none)
+#   budget    [slots] int32         tokens a row may still emit
+#   step      [] int32              steps that had a row alive
+#
+# The step program advances it, so step N+1 can be dispatched before
+# the host has seen step N's tokens; the host only PATCHES it, one
+# slot at a time with a traced index, where it changes something the
+# device cannot know: a table row at admission or release
+# (`patch_step_slot`) and a row's start after its prompt's last chunk
+# (`finish_chunk`).
+
+
+def _paged_engine_step_impl(
+    params,
+    cfg: LlamaConfig,
+    pool,
+    last_logits,
+    state,
+    base_key,
+    temperature: float,
+    top_k: int,
+):
+    alive = state["alive"]
+    token, pool, last_logits = _paged_decode_step_impl(
+        params, cfg, pool, state["tables"], last_logits,
+        state["positions"], alive,
+        jax.random.fold_in(base_key, state["step"]),
+        temperature, top_k,
+    )
+    # The engine's two release rules, `stop` and `length`: a row that
+    # ends here is dead in the next step and writes nothing there.
+    budget = state["budget"] - alive
+    state = {
+        **state,
+        "positions": state["positions"] + alive,
+        "alive": alive & (token != state["eos"]) & (budget > 0),
+        "budget": budget,
+        "step": state["step"] + jnp.any(alive),
+    }
+    # What the host fetches when it retires the step, in buffers of
+    # their own: the pool's `moe_counts` is donated to the next program
+    # before the host gets to read it.
+    fetch = {"token": token, "step": state["step"]}
+    if "moe_counts" in pool:
+        fetch["moe_counts"] = pool["moe_counts"] + 0
+    return fetch, pool, last_logits, state
+
+
+_paged_engine_step_jit = None
+
+
+def paged_engine_step(
+    params,
+    cfg: LlamaConfig,
+    pool,
+    last_logits,
+    state,
+    base_key,
+    *,
+    temperature: float = 0.0,
+    top_k: int = 0,
+):
+    """`paged_decode_step` with the step state on the device (layout
+    above): the key is `fold_in(base_key, state["step"])`, made inside
+    the program, and the state comes back advanced. -> (fetch, pool,
+    last_logits, state): `fetch` holds the step's `token` [slots] (0
+    in a dead row), the advanced `step` and, for a MoE config,
+    `moe_counts`. `pool` and `last_logits` are donated on accelerator
+    backends; the state's few kilobytes are not, so whoever holds an
+    older state may still read it."""
+    global _paged_engine_step_jit
+    if _paged_engine_step_jit is None:
+        _paged_engine_step_jit = compile_watch.instrument(
+            "generate.paged_engine_step",
+            partial(
+                jax.jit,
+                static_argnames=("temperature", "top_k", "cfg"),
+                donate_argnums=accel_donate(2, 3),
+            )(_paged_engine_step_impl),
+        )
+    return _paged_engine_step_jit(
+        params, cfg, pool, last_logits, state, base_key,
+        temperature=temperature, top_k=top_k,
+    )
+
+
+def _patch_step_slot_impl(state, slot, table_row):
+    return {
+        **state,
+        "tables": state["tables"].at[slot].set(table_row[0]),
+        "alive": state["alive"].at[slot].set(False),
+    }
+
+
+_patch_step_slot_jit = compile_watch.instrument(
+    "generate.patch_step_slot", jax.jit(_patch_step_slot_impl)
+)
+
+
+def patch_step_slot(state, slot, table_row):
+    """-> the state with `slot`'s table row replaced by `table_row`
+    [1, width] (as `paged_prefill` takes it) and the row dead: an admission (the request's blocks; the
+    row starts at its prompt's last chunk) or a release the device
+    cannot know of (a cancellation: the null row). `slot` is traced:
+    one program for every slot."""
+    return _patch_step_slot_jit(state, slot, table_row)
+
+
+def _finish_chunk_impl(
+    state, last_logits, logits, moe_counts, slot, local, last,
+    position, budget, eos,
+):
+    row = logits[0, local]
+    last_logits = last_logits.at[slot].set(
+        jnp.where(last, row, last_logits[slot])
+    )
+
+    def start(values, value):
+        return values.at[slot].set(jnp.where(last, value, values[slot]))
+
+    state = {
+        **state,
+        "positions": start(state["positions"], position),
+        "alive": start(state["alive"], True),
+        "budget": start(state["budget"], budget),
+        "eos": start(state["eos"], eos),
+    }
+    fence = row[:1] if moe_counts is None else moe_counts + 0
+    return state, last_logits, fence
+
+
+_finish_chunk_jit = None
+
+
+def finish_chunk(
+    state, last_logits, logits, moe_counts, slot, local, last,
+    position, budget, eos,
+):
+    """What follows every `paged_prefill` chunk of the engine, in one
+    small program whose scalars are all traced. If the chunk was the
+    prompt's `last`, the row starts: `last_logits[slot]` becomes the
+    chunk's logits at its `local` position (the prompt's last token)
+    and the state's row gets its `position`, `budget` and `eos` and is
+    alive. Otherwise nothing changes. -> (state, last_logits, fence):
+    the fence is ready when the chunk is, and small enough to keep
+    while the chunk's logits of every position are dropped; for a MoE
+    config it is a copy of the chunk's `moe_counts` (the pool's own is
+    donated to the next program). `last_logits` is donated on
+    accelerator backends."""
+    global _finish_chunk_jit
+    if _finish_chunk_jit is None:
+        _finish_chunk_jit = compile_watch.instrument(
+            "generate.finish_chunk",
+            jax.jit(
+                _finish_chunk_impl, donate_argnums=accel_donate(1)
+            ),
+        )
+    return _finish_chunk_jit(
+        state, last_logits, logits, moe_counts, slot, local, last,
+        position, budget, eos,
+    )
+
+
 @partial(
     jax.jit,
     static_argnames=(

@@ -446,6 +446,405 @@ def test_fallback_padding_is_exact(tiny_model):
     assert out == np.asarray(ref)[0].tolist()
 
 
+# ---------------------------------------------------------------------
+# dispatch ahead, retire behind (ISSUE 27): the pipelined loop streams
+# what a plain serial loop would, token for token
+# ---------------------------------------------------------------------
+
+PIPE_KW = dict(slots=3, max_len=64, prefill_chunk=8)
+
+
+def serial_streams(params, cfg, ec, jobs):
+    """The plain reference: the engine's policy (FIFO, one prompt
+    prefilling at a time, one chunk an iteration and then one decode
+    step over the rows alive, `fold_in(base_key, step)` keys) run one
+    program at a time with the state on the host. `jobs` are
+    (prompt, max_new_tokens, eos), all queued at the start and no more
+    of them than slots. -> one token list a job."""
+    from ray_tpu.llm.kv_slots import default_block_len
+    from ray_tpu.models.generate import (
+        init_block_pool, paged_decode_step, paged_prefill,
+    )
+
+    assert len(jobs) <= ec.slots
+    chunk = ec.prefill_chunk
+    bl = ec.kv_block_len or default_block_len(chunk)
+    width = ec.max_len // bl
+    pool = init_block_pool(cfg, ec.slots * width + 1, bl)
+    # slot s owns blocks 1 + s * width ...: which ones is not the
+    # mathematics' business.
+    tables = 1 + np.arange(ec.slots * width, dtype=np.int32).reshape(
+        ec.slots, width
+    )
+    positions = np.zeros(ec.slots, np.int32)
+    alive = np.zeros(ec.slots, bool)
+    last_logits = jnp.zeros((ec.slots, cfg.vocab_size), jnp.float32)
+    base_key = jax.random.PRNGKey(ec.seed)
+    outs = [[] for _ in jobs]
+    waiting = list(range(len(jobs)))
+    prefilling = None  # (slot, padded prompt, offset)
+    step = 0
+    while waiting or prefilling or alive.any():
+        if prefilling is None and waiting:
+            slot = waiting.pop(0)
+            prompt = jobs[slot][0]
+            padded = np.zeros((1, -(-len(prompt) // chunk) * chunk), np.int32)
+            padded[0, : len(prompt)] = prompt
+            prefilling = (slot, padded, 0)
+        if prefilling:
+            slot, padded, offset = prefilling
+            logits, pool = paged_prefill(
+                params, cfg, jnp.asarray(padded[:, offset:offset + chunk]),
+                pool, jnp.asarray(tables[slot:slot + 1]),
+                jnp.int32(offset), jnp.int32(offset + chunk),
+            )
+            prefilling = (slot, padded, offset + chunk)
+            if offset + chunk >= padded.shape[1]:
+                n = len(jobs[slot][0])
+                last_logits = last_logits.at[slot].set(
+                    logits[0, n - 1 - offset]
+                )
+                positions[slot], alive[slot] = n, True
+                prefilling = None
+        if alive.any():
+            token, pool, last_logits = paged_decode_step(
+                params, cfg, pool, jnp.asarray(tables), last_logits,
+                jnp.asarray(positions), jnp.asarray(alive),
+                jax.random.fold_in(base_key, step),
+                temperature=ec.temperature, top_k=ec.top_k,
+            )
+            step += 1
+            token = np.asarray(token)
+            for slot in np.flatnonzero(alive):
+                _, max_new, eos = jobs[slot]
+                outs[slot].append(int(token[slot]))
+                positions[slot] += 1
+                if token[slot] == eos or len(outs[slot]) >= max_new:
+                    alive[slot] = False
+    return outs
+
+
+class held_engine:
+    """An engine whose admissions wait until `open()`: what is
+    submitted before that is all queued when the loop first looks, so
+    its schedule is the policy's and no race's."""
+
+    def __init__(self, model, **config):
+        from ray_tpu.llm import EngineConfig, InferenceEngine
+
+        cfg, params = model
+        self.config = EngineConfig(**{**PIPE_KW, **config})
+        self.engine = InferenceEngine(
+            params, cfg, self.config, family="tiny"
+        )
+        self._opened = threading.Event()
+        admit = self.engine._sched.admit_next
+        self.engine._sched.admit_next = lambda gate=None: (
+            admit(gate=gate) if self._opened.is_set() else None
+        )
+
+    def open(self):
+        self._opened.set()
+        self.engine._wake.set()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.engine.close()
+
+
+def run_jobs(model, jobs, **config):
+    with held_engine(model, **config) as held:
+        streams = [
+            held.engine.submit(prompt, max_new_tokens=n, eos_token=eos)
+            for prompt, n, eos in jobs
+        ]
+        held.open()
+        outs = [list(s) for s in streams]
+        return outs, [s.finish_reason for s in streams], held.engine.stats()
+
+
+def pipe_jobs(kind):
+    rng = np.random.default_rng(11)
+    if kind == "ends_mid_batch":
+        # Three rows alive together; budgets of 5, 14 and 9 tokens end
+        # two of them in the middle of the batch (an EOS ends another
+        # below).
+        lengths, budgets = (5, 7, 6), (5, 14, 9)
+    else:
+        # Prompts of 1, 3 and 2 chunks: the second and third are
+        # admitted, and their last chunks land, while the rows before
+        # them have a step in flight.
+        lengths, budgets = (6, 21, 12), (12, 6, 8)
+    return [
+        (rng.integers(1, 128, size=n).tolist(), budget, -1)
+        for n, budget in zip(lengths, budgets)
+    ]
+
+
+SAMPLING = {
+    "greedy": dict(temperature=0.0),
+    "sampled": dict(temperature=0.8, seed=5),
+    "sampled_top_k": dict(temperature=0.8, top_k=8, seed=6),
+}
+
+
+@pytest.mark.parametrize("sampling", list(SAMPLING))
+@pytest.mark.parametrize("kind", ["ends_mid_batch", "chunks_land_mid_step"])
+def test_streams_equal_the_serial_reference(tiny_model, kind, sampling):
+    from ray_tpu.llm import EngineConfig
+
+    cfg, params = tiny_model
+    config = SAMPLING[sampling]
+    ec = EngineConfig(**{**PIPE_KW, **config})
+    jobs = pipe_jobs(kind)
+    # The longest stream's fourth token becomes that row's EOS: it
+    # ends by `stop` with other rows alive around it.
+    longest = max(range(len(jobs)), key=lambda i: jobs[i][1])
+    free = serial_streams(params, cfg, ec, jobs)
+    eos = free[longest][3]
+    jobs[longest] = (*jobs[longest][:2], eos)
+    want = serial_streams(params, cfg, ec, jobs)
+    assert want[longest] == free[longest][: free[longest].index(eos) + 1]
+    outs, reasons, stats = run_jobs(tiny_model, jobs, **config)
+    assert outs == want
+    assert reasons == [
+        "stop" if i == longest else "length" for i in range(len(jobs))
+    ]
+    # Every step but the first few was dispatched with one in flight.
+    assert stats["programs_ahead"] >= stats["programs"] - 2
+    assert stats["pipeline_drains"] == 0
+
+
+def test_a_few_hundred_steps_run_ahead(tiny_model):
+    jobs = [([3, 1, 4, 1, 5], 300, -1), ([9, 2, 6], 250, -1)]
+    outs, _, stats = run_jobs(tiny_model, jobs, max_len=512)
+    assert [len(o) for o in outs] == [300, 250]
+    assert stats["steps"] == 300  # no step without a row alive
+    assert stats["programs"] == 302  # two chunks and the steps
+    assert stats["programs_ahead"] / stats["programs"] > 0.9
+    # An admission and a start a request; nothing else is patched.
+    assert stats["state_patches"] == 4
+    assert stats["pipeline_drains"] == 0
+
+
+def device_state(eng):
+    return {k: np.asarray(v) for k, v in eng._state.items()}
+
+
+def test_mirrors_follow_the_device_state(tiny_model, monkeypatch):
+    """After every retirement the host's mirrors say, of the rows the
+    step decoded, what the device's state said when the step ended;
+    and with nothing in flight the two are equal row for row."""
+    from ray_tpu.llm import EngineConfig
+
+    cfg, params = tiny_model
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(1, 128, size=4 + 3 * i).tolist() for i in range(5)]
+    # The second request ends by an EOS its stream really meets.
+    free = serial_streams(
+        params, cfg, EngineConfig(**PIPE_KW), [(prompts[1], 6, -1)]
+    )[0]
+    eos = next(t for i, t in enumerate(free[:5]) if i and t not in free[:i])
+    checked = []
+    with held_engine(tiny_model, slots=2) as held:
+        eng = held.engine
+        retire = eng._retire_step
+
+        def check(step):
+            rows = [
+                (slot, req) for slot, req in step.rows
+                if eng._sched.running.get(slot) is req
+            ]
+            retire(step)
+            state = {k: np.asarray(v) for k, v in step.state.items()}
+            for slot, _ in rows:
+                assert eng._positions[slot] == state["positions"][slot]
+                assert eng._alive[slot] == state["alive"][slot]
+                assert eng._budget[slot] == state["budget"][slot]
+                assert eng._eos[slot] == state["eos"][slot]
+                if eng._alive[slot]:
+                    assert (
+                        eng._tables[slot] == state["tables"][slot]
+                    ).all()
+            assert eng._steps == state["step"]
+            checked.append(len(rows))
+
+        monkeypatch.setattr(eng, "_retire_step", check)
+        # Five requests through two slots, one of them ending by EOS:
+        # slots are reused while steps are in flight.
+        streams = [
+            eng.submit(
+                prompt, max_new_tokens=5 + i,
+                eos_token=eos if i == 1 else None,
+            )
+            for i, prompt in enumerate(prompts)
+        ]
+        held.open()
+        assert [len(list(s)) > 0 for s in streams] == [True] * 5
+        assert streams[1].finish_reason == "stop"
+        deadline = time.time() + 10
+        while eng._inflight and time.time() < deadline:
+            time.sleep(0.01)
+        assert not eng._inflight
+        state = device_state(eng)
+        assert (state["alive"] == eng._alive).all()
+        assert not state["alive"].any()
+        assert (state["positions"] == eng._positions).all()
+        assert (state["budget"] == eng._budget).all()
+        assert (state["eos"] == eng._eos).all()
+        assert state["step"] == eng._steps == eng.stats()["steps"]
+    assert sum(checked) == eng.stats()["tokens_emitted"]
+    assert max(checked) == 2
+
+
+def test_a_dead_row_writes_nothing(tiny_model):
+    """A row that met its EOS in step N is dead in step N+1, which
+    was dispatched before the host saw N's tokens: past its last
+    token its pages stay as the pool was made, whatever the rows
+    around it go on to write."""
+    prompt = [3, 14, 15, 9, 2, 6, 5, 35]  # a whole chunk, no padding
+    with held_engine(tiny_model) as held:
+        eng = held.engine
+        probe = eng.submit(prompt, max_new_tokens=8)
+        held.open()
+        free = list(probe)
+    # The EOS: a token the stream meets after its first, and not before.
+    ends = next(i for i in range(1, 8) if free[i] not in free[:i])
+    eos = free[ends]
+    with held_engine(tiny_model, prefix_cache=False) as held:
+        eng = held.engine
+        short = eng.submit(prompt, max_new_tokens=8, eos_token=eos)
+        other = eng.submit([7, 7, 7], max_new_tokens=40)
+        held.open()
+        assert isinstance(next(short), int)
+        blocks = eng._tables[short._req.slot]
+        blocks = blocks[blocks != 0]  # its own, not the null block
+        assert len(list(short)) == ends and short.finish_reason == "stop"
+        assert len(list(other)) == 40  # dozens of steps after the EOS
+        written = len(prompt) + ends + 1  # the prompt and its tokens
+        for name in ("k", "v"):
+            pages = np.asarray(eng._kv.pool[name])[:, blocks]
+            # [layers, blocks, kv_heads, block_len, hd] -> positions
+            keys = pages.transpose(0, 2, 1, 3, 4).reshape(
+                pages.shape[0], pages.shape[2], -1, pages.shape[4]
+            )
+            assert np.abs(keys[:, :, :written]).sum(axis=(0, 1, 3)).all()
+            assert not keys[:, :, written:].any()
+
+
+def test_cancel_with_a_step_in_flight(tiny_model, monkeypatch):
+    """A cancellation reaped while the row's step is in flight: that
+    step's token is dropped, the blocks go back once, the row beside
+    it streams on untouched and the slot serves the next request."""
+    from ray_tpu.llm import EngineConfig
+
+    cfg, params = tiny_model
+    ec = EngineConfig(**PIPE_KW)
+    jobs = [([5, 6, 7, 8, 9], 58, -1), ([2, 4, 6], 40, -1)]
+    want = serial_streams(params, cfg, ec, jobs)
+    seen = {}
+    with held_engine(tiny_model) as held:
+        eng = held.engine
+        keeper, doomed = (
+            eng.submit(p, max_new_tokens=n) for p, n, _ in jobs
+        )
+        patch = eng._patch_slot
+
+        def spy(slot, row):
+            if row is eng._null_row:
+                seen.update(
+                    inflight=len(eng._inflight),
+                    emitted=doomed._req.emitted,
+                )
+            patch(slot, row)
+
+        monkeypatch.setattr(eng, "_patch_slot", spy)
+        held.open()
+        got = [next(doomed) for _ in range(3)]
+        doomed.cancel()
+        got.extend(doomed)
+        assert doomed.finish_reason == "cancelled"
+        # The slot serves the next request, beside the row that never
+        # stopped.
+        again = eng.submit(jobs[1][0], max_new_tokens=20)
+        assert list(again) == want[1][:20]
+        assert again._req.slot == doomed._req.slot
+        assert list(keeper) == want[0]
+        # No token after the cancellation was reaped, though a step
+        # that held the row was in flight then.
+        assert seen["inflight"] >= 1
+        assert len(got) == seen["emitted"] < 40
+        assert got == want[1][: len(got)]
+        stats = eng.stats()
+        assert stats["slots_used"] == 0 and not stats["dead"]
+        assert eng._kv.alloc.used() == 0  # returned, and only once
+
+
+@pytest.mark.parametrize("shared", ["whole_prompt", "prefix_only"])
+def test_prefix_hit_replays_the_serial_reference(tiny_model, shared):
+    from ray_tpu.llm import EngineConfig
+
+    cfg, params = tiny_model
+    ec = EngineConfig(**PIPE_KW)
+    rng = np.random.default_rng(21)
+    first = rng.integers(1, 128, size=20).tolist()
+    second = first if shared == "whole_prompt" else first[:16] + [5, 9, 2]
+    with held_engine(tiny_model, prefix_cache=True) as held:
+        eng = held.engine
+        held.open()
+        outs = [
+            list(eng.submit(p, max_new_tokens=8)) for p in (first, second)
+        ]
+        stats = eng.stats()
+    # The second prompt's first two chunks were never computed ...
+    assert stats["prefix_hits"] == 1 and stats["prefix_tokens_saved"] == 16
+    assert stats["programs"] == 3 + 1 + 16
+    # ... and it streams what a prompt computed whole does.
+    assert outs == [
+        serial_streams(params, cfg, ec, [(p, 8, -1)])[0]
+        for p in (first, second)
+    ]
+
+
+def test_update_weights_window_drains_and_resumes(tiny_model):
+    """Old and new streams decode together through the mixed window
+    (serial steps, the pipeline drained), each exact on its weights;
+    when the old ones are gone the loop runs ahead again."""
+    from ray_tpu.llm import EngineConfig
+    from ray_tpu.models.llama import init_params
+
+    cfg, p_old = tiny_model
+    p_new = init_params(jax.random.PRNGKey(99), cfg)
+    ec = EngineConfig(**PIPE_KW)
+    prompt = [8, 6, 7, 5, 3, 9]
+
+    def ref(params, n):
+        return serial_streams(params, cfg, ec, [(prompt, n, -1)])[0]
+
+    with held_engine(tiny_model) as held:
+        eng = held.engine
+        held.open()
+        old = eng.submit(prompt, max_new_tokens=24)
+        head = [next(old), next(old)]  # provably mid-decode
+        assert eng.update_weights(p_new) == 1
+        new = eng.submit(prompt, max_new_tokens=40)
+        assert head + list(old) == ref(p_old, 24)
+        assert list(new) == ref(p_new, 40)
+        stats = eng.stats()
+        assert stats["pipeline_drains"] >= 1
+        assert stats["weight_gens"] == 1
+        # Both ended alone on the new weights, dispatched ahead again.
+        before = stats
+        assert list(eng.submit(prompt, max_new_tokens=30)) == ref(p_new, 30)
+        after = eng.stats()
+        assert after["pipeline_drains"] == before["pipeline_drains"]
+        ahead = after["programs_ahead"] - before["programs_ahead"]
+        assert ahead >= after["programs"] - before["programs"] - 2
+        assert (device_state(eng)["alive"] == eng._alive).all()
+
+
 def test_multiplex_swap_blocks_only_affected_family(
     tiny_model, monkeypatch
 ):

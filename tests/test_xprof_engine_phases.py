@@ -107,6 +107,20 @@ def test_phases_partition_the_loops_wall_time(engine):
     )
     # Work was done in every phase the LLM path has.
     assert all(grown[p] > 0.0 for p in LOOP_PHASES)
+    # The two phases in which the loop waits for the device keep their
+    # names with the new order (the blocking fetch of a retired step's
+    # tokens, the wait for a retired chunk): they are what the
+    # benchmark's `engine_host_share` leaves out of the host's side.
+    from benchmark.layer_metrics.engine_host_share import on_device
+
+    assert {p for p in LOOP_PHASES if on_device(p)} == {
+        "engine.prefill.wait", "engine.decode.sync",
+    }
+    # And the loop ran ahead: nearly every program was dispatched with
+    # an earlier one in flight.
+    programs = after["programs"] - before["programs"]
+    ahead = after["programs_ahead"] - before["programs_ahead"]
+    assert programs > 0 and ahead > 0.5 * programs
 
 
 def test_admission_counters_are_exact(engine):
@@ -143,22 +157,47 @@ def test_policy_engine_bills_its_batches_to_a_phase():
         eng.close()
 
 
+#: What `stats()["compiles"]` calls the loop's four programs, and the
+#: names the compile watch knows them by.
+ENGINE_PROGRAMS = {
+    "prefill": "generate.paged_prefill",
+    "decode": "generate.paged_engine_step",
+    "patch": "generate.patch_step_slot",
+    "finish_chunk": "generate.finish_chunk",
+}
+
+
 def test_compile_counts_are_what_the_watch_credits(engine):
-    run_requests(engine, n=2)  # warm-up: both programs have compiled
+    run_requests(engine, n=2)  # warm-up: every program has compiled
     compiles = engine.stats()["compiles"]
     snapshot = compile_watch.snapshot()
-    assert compiles["decode"]["compiles"] >= 1
-    assert compiles["prefill"]["compiles"] >= 1
-    assert compiles["decode"]["compiles"] == (
-        snapshot["generate.paged_decode_step"]["compiles"]
-    )
-    assert compiles["prefill"]["compiles"] == (
-        snapshot["generate.paged_prefill"]["compiles"]
-    )
+    assert set(compiles) == set(ENGINE_PROGRAMS)
+    for kind, name in ENGINE_PROGRAMS.items():
+        assert compiles[kind]["compiles"] >= 1, kind
+        assert compiles[kind]["compiles"] == snapshot[name]["compiles"]
     # One wrapper a program: nothing is registered under a second name.
     assert not [name for name in snapshot if name.startswith("engine.")]
+    before = sum(row["compiles"] for row in snapshot.values())
     run_requests(engine, n=3)
+    # A cancellation's patch is the admission's program: any request
+    # at all has warmed it up.
+    stream = engine.submit([5, 4, 3, 2, 1], max_new_tokens=40)
+    next(stream)
+    stream.cancel()
+    list(stream)
+    # Two patches a request, and the cancellation's, which the loop
+    # dispatches right after it ends the stream.
+    deadline = time.monotonic() + 10
+    while (
+        engine.stats()["state_patches"] < 2 * 6 + 1
+        and time.monotonic() < deadline
+    ):
+        time.sleep(0.01)
+    assert engine.stats()["state_patches"] == 2 * 6 + 1
     assert engine.stats()["compiles"] == compiles  # steady state
+    assert sum(
+        row["compiles"] for row in compile_watch.snapshot().values()
+    ) == before  # and no eager operation compiled beside them
 
 
 # -- the profiler's trace ---------------------------------------------
