@@ -660,7 +660,7 @@ int rts_futex_wake(void* p, int n) {
 // single FFI call matters because the Python path pays ~6 ctypes
 // round-trips + interpreter bytecode per hop: measured 39us/hop
 // two-process ping-pong vs a 6.9us OS-pipe floor on the 1-core CI
-// box; this path closes most of that gap (MICROBENCH dag_hop_per_s).
+// box; this path closes most of that gap.
 //
 // Returns: 0 / payload size on success; -EPIPE closed; -ETIMEDOUT
 // deadline passed; -EMSGSIZE record exceeds capacity; -E2BIG caller

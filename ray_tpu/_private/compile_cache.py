@@ -1,8 +1,8 @@
 """Where XLA's persistent compilation cache lives.
 
 A process that owns a chip calls `ensure_compile_cache()` before its
-first compile (TPU workers in `worker_main`, `chip_smoke.py`,
-`bench.py --mode tpu*`). The directory is part of the cache key's
+first compile (TPU workers in `worker_main`, `chip_smoke.py`). The
+directory is part of the cache key's
 lookup, so it must not move between runs:
 
 * `JAX_COMPILATION_CACHE_DIR` set — JAX reads it itself; nothing here

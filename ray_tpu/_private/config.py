@@ -250,16 +250,11 @@ class Config:
     #: samples for the whole window and the head holds one RPC pool
     #: thread per rank for it.
     profile_gang_max_duration_s: float = 60.0
-    #: Kill switch for the continuous-batching LLM serving engine
-    #: (ray_tpu/llm): RT_serve_engine_enabled=0 makes `build_llm_app`
-    #: deployments fall back to per-request `generate_stream()` — the
-    #: serialize-per-request baseline servebench.py compares against.
-    serve_engine_enabled: bool = True
     #: Kill switch for paged-KV prefix caching (ray_tpu/llm/kv_slots):
     #: RT_serve_prefix_cache_enabled=0 makes every `build_llm_app`
     #: engine prefill every prompt from scratch (blocks stay private,
     #: nothing registers in the prefix table). Resolved driver-side by
-    #: build_llm_app, like serve_engine_enabled.
+    #: build_llm_app.
     serve_prefix_cache_enabled: bool = True
     #: Serve request routing policy (serve/router.py):
     #: "least_tokens" routes each request to the candidate replica

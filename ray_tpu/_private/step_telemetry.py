@@ -1,8 +1,8 @@
 """Per-step, per-worker train-loop telemetry (runtime core).
 
-PR 4 proved the input pipeline and checkpointing can be driven off the
-step's critical path — but only bench.py could SHOW it. This module
-moves that attribution into the runtime, always on: the data plane
+The input pipeline and checkpointing can be driven off the step's
+critical path; this module keeps the attribution that shows it in
+the runtime, always on: the data plane
 (data/dataset.py), the H2D prefetcher (train/train_step.py), and the
 checkpoint writer (train/checkpoint.py) accumulate per-phase wall time
 into a thread-local, and the session's per-step report() folds them

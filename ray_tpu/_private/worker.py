@@ -806,8 +806,8 @@ class CoreWorker:
     ) -> None:
         """Classify ONE rt.get resolution at the source and fold it
         into this process's aggregate table. O(one dict update) — this
-        is the per-get cost the `get_provenance_overhead_us` bench
-        bars; the wire cost is one record per distinct (provenance,
+        is the per-get cost tests/test_data_plane.py bars; the wire
+        cost is one record per distinct (provenance,
         src, task) per drain, riding the metrics flush tick. Never a
         per-get RPC."""
         if self.config.transfer_report_interval_s <= 0:

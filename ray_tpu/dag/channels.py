@@ -29,8 +29,7 @@ _LEN = 8  # per-record length prefix
 #: the release/acquire pattern below; elsewhere publication goes
 #: through the native atomics. Gating on machine() keeps the hot path
 #: at ~0.1us/counter-op (memoryview index) instead of ~1.5us (lock +
-#: ctypes FFI round trip) — the difference is 2x on whole-hop latency
-#: (MICROBENCH dag_hop_per_s).
+#: ctypes FFI round trip) — the difference is 2x on whole-hop latency.
 import platform as _platform
 
 _TSO = _platform.machine() in ("x86_64", "AMD64", "i686", "i386")

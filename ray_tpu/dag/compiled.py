@@ -242,8 +242,8 @@ class CompiledDAG:
                     # Driver IO edges are counters-only (timed=False):
                     # their blocked time is the caller's own
                     # execute()/get() latency, and the ~2 us timed
-                    # path would tax the ~25 us hop (MICROBENCH
-                    # dag_hop_per_s). Actor->actor edges keep full
+                    # path would tax the ~25 us hop. Actor->actor
+                    # edges keep full
                     # wait timing — that's where a straggler stage
                     # shows.
                     edge = Edge(

@@ -14,8 +14,8 @@ labeled by edge), which the head folds into `doctor --json` under
 ``verdict["dag"]``.
 
 Export is BATCHED off the hot path: a compiled-DAG hop is ~25-45 us
-(MICROBENCH dag_hop_per_s) and per-op metric pushes would tax exactly
-the number this instrumentation exists to defend — so counters flush
+on a CPU host and per-op metric pushes would tax exactly the number
+this instrumentation exists to defend — so counters flush
 as accumulated deltas (every `_FLUSH_OPS` ops or `_FLUSH_S`), and
 wait histograms sample 1-in-`_WAIT_SAMPLE` of sub-millisecond waits
 while recording every wait >= 1 ms unconditionally (the bubble tail

@@ -23,8 +23,8 @@ under what program name — f-string names become ``fnmatch`` patterns,
 billed by ``step_telemetry.phase_timer``, ``@rt.remote`` actor
 methods, and any function whose loop dispatches a known-jitted
 callable.  Module-level forwarders (a function whose return is a
-1:1 positional call of a jit binding — the ``decode_step`` ->
-``_decode_step_jit`` idiom in models/generate.py) inherit the inner
+1:1 positional call of a jit binding — the ``paged_decode_step`` ->
+``_paged_decode_jit`` idiom in models/generate.py) inherit the inner
 wrapper's donate/static signature, so call sites in *other* modules
 are judged too.
 

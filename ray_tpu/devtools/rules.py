@@ -1,8 +1,8 @@
 """The RT001–RT010 distributed-correctness passes.
 
 Each rule is one bug class ray_tpu has actually shipped (or nearly
-shipped — see ADVICE.md for the originals) generalized into a
-syntactic pattern plus a path scope. Rules are deliberately
+shipped; tests/test_lint.py keeps the regressions) generalized into
+a syntactic pattern plus a path scope. Rules are deliberately
 high-precision: a pass that cries wolf on idiomatic code gets noqa'd
 into silence, so each one matches the narrow framework idiom and
 leaves the rest of Python alone.

@@ -3,9 +3,9 @@
 The engine (engine.py) owns a PAGED KV cache — a refcounted block
 pool with prefix reuse (kv_slots.py) — fed by a FIFO slot scheduler
 gated on block availability (scheduler.py); serving.py wires it
-behind `ray_tpu.serve` as a multiplexed streaming deployment, and
-`servebench.py` at the repo root drives it with open-loop Poisson
-traffic, single- and multi-replica (results in SERVEBENCH.json).
+behind `ray_tpu.serve` as a multiplexed streaming deployment, which
+`benchmark/run.py` drives with open- and closed-loop traffic on the
+chip (BENCHMARK.json's serve cells; readings in PERF.md).
 """
 
 from .engine import (

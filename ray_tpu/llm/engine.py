@@ -676,7 +676,6 @@ class InferenceEngine:
             self._weight_version = v
             self.params = params
             self._prune_gens_locked()
-        self._observe_weights()
         self._wake.set()
         return v
 
@@ -904,7 +903,6 @@ class InferenceEngine:
             params, version = entry["params"], entry["version"]
         import jax
 
-        t0 = time.perf_counter()
         bucket = self._program.bucket_for(rows)
         sample = batch[0].inputs
         padded = np.zeros(
@@ -942,7 +940,6 @@ class InferenceEngine:
             cursor += req.n
         self._policy_steps += 1
         self._policy_rows_served += rows
-        self._observe_policy((time.perf_counter() - t0) * 1e3)
         return True
 
     # -- cancellation / completion ------------------------------------
@@ -1642,18 +1639,3 @@ class InferenceEngine:
         except Exception:
             pass
 
-    def _observe_weights(self) -> None:
-        try:
-            from ..serve.observability import observe_engine_weights
-
-            observe_engine_weights(self._tags, self._weight_version)
-        except Exception:
-            pass
-
-    def _observe_policy(self, batch_ms: float) -> None:
-        try:
-            from ..serve.observability import observe_engine_policy
-
-            observe_engine_policy(self._tags, batch_ms)
-        except Exception:
-            pass

@@ -17,8 +17,8 @@ item 1c):
   attention walks each row's table a tile of entries at a time, only
   as far as the longest alive row reaches
   (models/generate._paged_attention), so the math — and the greedy
-  token stream — is that of the contiguous cache over the keys inside
-  `valid_len`;
+  token stream — is that of plain causal attention over the keys
+  inside `valid_len`;
 * a refcounted `BlockAllocator` (the plasma-style ownership model of
   the reference object plane: pin/refcount, free-list reuse, nothing
   zeroed) with PREFIX CACHING: full prompt blocks register under the

@@ -18,11 +18,7 @@ Wiring (ISSUE 10 tentpole):
   (`DeploymentResponseGenerator.close()`, proxy client disconnect)
   triggers `Replica.cancel_stream` -> ``__serve_cancel_stream__``
   here -> `engine.cancel` — the slot frees mid-decode instead of
-  decoding to the token budget for nobody;
-* kill switch: ``RT_serve_engine_enabled=0`` (or
-  ``engine_enabled=False``) serves every request with a per-request
-  `generate_stream()` — the serialize-per-request baseline, same
-  response format.
+  decoding to the token budget for nobody.
 
 Request payload (HTTP body JSON or a plain dict via handle):
 
@@ -31,7 +27,7 @@ Request payload (HTTP body JSON or a plain dict via handle):
       header / handle option wins), "eos_token": optional}
 
 Response stream: one chunk per token, ASCII decimal + trailing space
-(client sums/parses trivially; servebench.py times chunk arrivals).
+(a client sums and parses it trivially, and times chunk arrivals).
 """
 
 from __future__ import annotations
@@ -63,8 +59,8 @@ def _resolve_dtype(name: Any):
 def build_model(spec: Dict[str, Any]):
     """Model-family spec -> (params, LlamaConfig).
 
-    kind "init": randomly initialized from a config dict (tests,
-    servebench — every HF family shares the Llama compute graph, so a
+    kind "init": randomly initialized from a config dict (tests, the
+    benchmark — every HF family shares the Llama compute graph, so a
     family here is a (config, seed) point);
     kind "hf": a converted HF checkpoint directory
     (models/hf_convert.load_hf_llama — the six parity-proven
@@ -107,10 +103,18 @@ class LLMServer:
     ):
         if not families:
             raise ValueError("families must name at least one model")
+        if not engine_enabled:
+            # The keyword outlives its switch only because
+            # benchmark/drivers/serve.py still binds
+            # `engine_enabled=True`; it goes with that line.
+            raise ValueError(
+                "engine_enabled=False: the per-request fallback it "
+                "selected was deleted in PR 29; the engine is the "
+                "only serve path"
+            )
         self._families = dict(families)
         self._default = default_family or next(iter(self._families))
         self._engine_cfg = EngineConfig(**(engine or {}))
-        self._engine_enabled = bool(engine_enabled)
         # serve request_id -> [(engine, engine_request_id), ...] for
         # cancel_stream propagation. A LIST per id: the serve id is
         # CLIENT-controlled (x-request-id), so concurrent requests may
@@ -123,13 +127,6 @@ class LLMServer:
         # submit. Entries expire; the map stays tiny.
         self._early_cancels: Dict[str, float] = {}
         self._streams_lock = threading.Lock()
-        # Fallback (params, cfg) per family, behind the SAME LRU
-        # machinery as engines: bounded to MAX_FAMILIES_PER_REPLICA
-        # (not an ever-growing dict) and per-family load
-        # serialization, so a cold family's load never blocks warm
-        # families' requests.
-        self._fallback_lock = threading.Lock()
-        self._fallback_wrapper = None
 
     # -- engines -------------------------------------------------------
     @multiplexed(max_num_models_per_replica=MAX_FAMILIES_PER_REPLICA)
@@ -178,11 +175,6 @@ class LLMServer:
         max_new = None if max_new is None else int(max_new)
         eos = payload.get("eos_token")
         eos = None if eos is None else int(eos)
-        if not self._engine_enabled:
-            yield from self._serve_fallback(
-                family, prompt, max_new, eos
-            )
-            return
         from ..serve.observability import get_request_id
 
         engine = self.get_engine(family)
@@ -247,74 +239,6 @@ class LLMServer:
             cancelled = engine.cancel(engine_request_id) or cancelled
         return cancelled
 
-    # -- fallback (kill switch) ---------------------------------------
-    def _fallback_model(self, family: str):
-        """(params, cfg) through the multiplex LRU wrapper — same
-        bound and same per-family load serialization as the engine
-        path (a hand-rolled dict would grow unboundedly and a single
-        load lock would stall warm families behind a cold load)."""
-        wrapper = self._fallback_wrapper
-        if wrapper is None:
-            from ..serve.multiplex import _ModelMultiplexWrapper
-
-            with self._fallback_lock:
-                if self._fallback_wrapper is None:
-                    self._fallback_wrapper = _ModelMultiplexWrapper(
-                        lambda owner, fam: build_model(
-                            owner._spec(fam)
-                        ),
-                        self,
-                        MAX_FAMILIES_PER_REPLICA,
-                    )
-                wrapper = self._fallback_wrapper
-        return wrapper.load(family)
-
-    def _serve_fallback(self, family, prompt, max_new, eos):
-        """Per-request `generate_stream` — no shared cache, no
-        batching: what serving looked like before the engine, kept as
-        the RT_serve_engine_enabled=0 escape hatch and the servebench
-        baseline."""
-        import jax.numpy as jnp
-
-        from ..models.generate import generate_stream
-        from .kv_slots import bucket_for
-
-        params, cfg = self._fallback_model(family)
-        ec = self._engine_cfg
-        max_new = int(
-            ec.max_new_tokens if max_new is None else max_new
-        )
-        # Same length-bucket padding as the engine, so the baseline
-        # pays the same bounded compile set, not one compile per
-        # distinct prompt length.
-        prompt = [int(t) for t in prompt]
-        bucket = bucket_for(
-            len(prompt), ec.prefill_chunk, ec.max_len
-        )
-        if len(prompt) + max_new > ec.max_len:
-            # Same admission contract as the engine path: the kill
-            # switch changes throughput, not validation semantics.
-            raise ValueError(
-                f"prompt ({len(prompt)}) + max_new_tokens "
-                f"({max_new}) exceeds slot capacity "
-                f"max_len={ec.max_len}"
-            )
-        padded = prompt + [0] * (bucket - len(prompt))
-        for step_tokens in generate_stream(
-            params,
-            jnp.asarray([padded], jnp.int32),
-            jnp.asarray([len(prompt)], jnp.int32),
-            cfg,
-            max_new_tokens=max_new,
-            temperature=ec.temperature,
-            top_k=ec.top_k,
-            eos_token=ec.eos_token if eos is None else eos,
-            # Fixed cache size: one compile per prompt bucket, same
-            # as the engine, instead of one per (bucket, budget).
-            cache_len=ec.max_len,
-        ):
-            yield f"{int(step_tokens[0])} ".encode()
-
     # -- introspection -------------------------------------------------
     def engine_stats(self) -> Dict[str, Any]:
         """Per-loaded-family engine stats for this replica (also the
@@ -333,15 +257,11 @@ def build_llm_app(
     *,
     default_family: Optional[str] = None,
     engine: Optional[Dict[str, Any]] = None,
-    engine_enabled: Optional[bool] = None,
     num_replicas: int = 1,
     max_ongoing_requests: Optional[int] = None,
     name: str = "llm",
 ):
-    """Bind the engine deployment. `engine_enabled=None` resolves the
-    RT_serve_engine_enabled kill switch HERE (driver-side) so the
-    decision ships in the replica init args instead of depending on
-    worker-process environments.
+    """Bind the engine deployment.
 
     Each replica hosts one single-device `InferenceEngine`, so it
     leases one chip when the cluster advertises any: the daemon then
@@ -351,16 +271,13 @@ def build_llm_app(
     from ..serve.deployment import deployment as serve_deployment
     from ..util.accelerators.tpu import cluster_tpu_chips
 
-    runtime_cfg = Config.from_env()
-    if engine_enabled is None:
-        engine_enabled = runtime_cfg.serve_engine_enabled
     engine = dict(engine or {})
     if "prefix_cache" not in engine:
-        # Same driver-side resolution as the engine kill switch: the
-        # decision ships in the replica init args instead of depending
-        # on worker-process environments.
+        # Resolved HERE (driver-side) so the decision ships in the
+        # replica init args instead of depending on worker-process
+        # environments.
         engine["prefix_cache"] = bool(
-            runtime_cfg.serve_prefix_cache_enabled
+            Config.from_env().serve_prefix_cache_enabled
         )
     engine_cfg = EngineConfig(**engine)
     if max_ongoing_requests is None:
@@ -379,6 +296,5 @@ def build_llm_app(
     return dep.bind(
         dict(families),
         default_family=default_family,
-        engine=dict(engine or {}),
-        engine_enabled=bool(engine_enabled),
+        engine=engine,
     )

@@ -1,18 +1,9 @@
 """Model families. Flagship: Llama (BASELINE.md north star).
 
-Serving-side decode lives in the `generate` submodule; `prefill`/
-`decode_step` are the single jitted kernels shared by
-`generate.generate`, `generate_stream`, and the continuous-batching
-engine (ray_tpu/llm). The `generate()` FUNCTION is deliberately not
-re-exported here — it would shadow the `ray_tpu.models.generate`
-submodule attribute; import it from the submodule."""
+Serving-side decode lives in the `generate` submodule: the jitted
+paged-KV programs the continuous-batching engine (ray_tpu/llm)
+drives. Import them from the submodule."""
 
-from .generate import (
-    decode_step,
-    generate_stream,
-    init_kv_cache,
-    prefill,
-)
 from .llama import (
     LlamaConfig,
     flops_per_token,
@@ -29,8 +20,4 @@ __all__ = [
     "init_params",
     "param_annotations",
     "flops_per_token",
-    "generate_stream",
-    "decode_step",
-    "prefill",
-    "init_kv_cache",
 ]

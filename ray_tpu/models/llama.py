@@ -401,8 +401,8 @@ EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 
 def _mlp(cfg: LlamaConfig, x, layer, ep_axis=None, live=None,
          layer_idx=None):
-    """The second half of every block — training, the contiguous-cache
-    forwards and the paged forwards call this one: norm, dense GLU or
+    """The second half of every block — training and the paged
+    forwards call this one: norm, dense GLU or
     MoE, residual. x: [b, t, dim] -> (x, aux, counts): the MoE
     load-balancing loss (0 for a dense layer) and the picks each
     expert got, [E] int32 (None for a dense layer). `live` [b] marks
@@ -554,8 +554,10 @@ def loss_fn(
 
 
 def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:
-    """Training FLOPs/token (fwd+bwd), standard 6N + attention term —
-    used for MFU accounting in bench.py. For MoE, N counts only the
+    """Training FLOPs/token (fwd+bwd), standard 6N + attention term.
+    N is `num_params()`, embedding lookup included; the benchmark
+    counts required operations without it (benchmark/flops.py), so
+    measured MFU comes from there. For MoE, N counts only the
     parameters a token activates (top-k experts, not all E)."""
     n = cfg.num_params()
     if cfg.moe_experts:

@@ -10,8 +10,8 @@ per-stage op ORDER comes from a schedule built here ahead of time.
 Everything in this module is pure Python over op tuples — no jax, no
 runtime — so schedules are unit-testable (stash bounds, deadlock
 freedom) and replayable against measured per-op costs
-(`simulate_schedule`), which is how pipebench turns a 1-core CPU run
-into a defensible pipeline-efficiency number.
+(`simulate_schedule`): measured per-op times from a run whose stages
+time-share a core give what the schedule would cost if they did not.
 
 An op is a tuple ``(kind, chunk, mb)``:
   kind   "F" (forward) or "B" (backward)
@@ -315,8 +315,8 @@ def simulate_schedule(
     """Replay per-stage op lists as a discrete-event simulation with
     each stage on its own executor: op start = max(stage free, inputs
     ready + hop), strictly in list order. `op_cost_s(kind, chunk, mb)`
-    supplies each op's duration (pipebench feeds MEASURED per-op times
-    from the real multi-stage run, so the result is a measurement-
+    supplies each op's duration (feed it MEASURED per-op times
+    from the real multi-stage run and the result is a measurement-
     driven account of what the schedule costs when stages do not
     time-share a core — the honest pipeline-efficiency number a
     1-core CI box can produce, committed alongside the raw wall
@@ -384,7 +384,7 @@ def partition_layers(
 ) -> List[Tuple[int, int]]:
     """Contiguous [start, end) layer ranges per chunk minimizing the
     bottleneck chunk cost. `layer_ms` is per-layer cost (uniform when
-    omitted — e.g. bench.py's measured `layer_ms` applies to every
+    omitted — one measured `layer_ms` applies to every
     layer of a homogeneous stack); `embed_ms` loads chunk 0 and
     `head_ms` the last chunk — the asymmetric ends the
     `fixed_ms_breakdown` numbers name (embed + lm_head/loss), so a

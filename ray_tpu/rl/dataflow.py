@@ -20,8 +20,8 @@ Policy inference during rollout runs in one of two modes:
 
 * ``policy="local"`` — classic Sebulba: each runner holds the policy
   params and samples on-CPU, refreshing from the WeightStore between
-  fragments. Identical per-step work to the synchronous baseline, so
-  rlbench's comparison isolates pure dataflow overlap.
+  fragments. Identical per-step work to the synchronous loop, so
+  a comparison of the two isolates pure dataflow overlap.
 * ``policy="engine"`` — the RLHF shape: runners hold NO weights and
   call a continuous-batching `InferenceEngine` (llm/engine.py policy
   path) whose step loop coalesces all runners' ragged per-env

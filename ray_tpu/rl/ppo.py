@@ -40,8 +40,7 @@ class PPOConfig:
         self.seed = 0
         self.num_learners = 1
         # Decoupled dataflow (ISSUE 13): off = the synchronous
-        # sample -> update -> broadcast loop below (kept as the
-        # rlbench baseline).
+        # sample -> update -> broadcast loop below.
         self.dataflow_enabled = False
         self.dataflow_policy = "local"
         self.queue_capacity: Optional[int] = None
@@ -122,7 +121,7 @@ class PPOConfig:
         drainless versioned weight sync. ``policy="engine"`` serves
         rollout inference from a continuous-batching policy engine
         (the RLHF shape); ``"local"`` keeps inference in the runners
-        (classic Sebulba, the apples-to-apples rlbench comparison).
+        (classic Sebulba, like for like with the synchronous loop).
         Unset knobs fall back to the ``rl_*`` runtime config keys."""
         self.dataflow_enabled = bool(enabled)
         if policy is not None:
@@ -238,8 +237,8 @@ class DecoupledPPO:
     over the rollout queue instead of alternating behind a gather
     barrier. One `train()` = `updates_per_iteration` learner updates,
     each consuming the same row count the synchronous path samples
-    per iteration — updates-per-env-step parity is what keeps the
-    rlbench comparison honest."""
+    per iteration — updates-per-env-step parity is what keeps a
+    comparison with the synchronous path honest."""
 
     def __init__(self, config: PPOConfig):
         from .dataflow import DataflowConfig, RLDataflow

@@ -83,8 +83,8 @@ class RolloutQueue:
         self._dropped_stale = 0
         self._empty_gets = 0
         self._env_steps_in = 0
-        # Occupancy integral for mean-depth reporting (rlbench's
-        # queue-occupancy series): sum of depth x dwell-time.
+        # Occupancy integral for mean-depth reporting: sum of
+        # depth x dwell-time.
         self._occ_t0 = time.monotonic()
         self._occ_area = 0.0
         self._tags = {"queue": name}

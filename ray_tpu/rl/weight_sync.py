@@ -15,7 +15,7 @@ Two halves, one version counter:
   serves the new one — the engine is never drained), plus the store
   publish and the rollout queue's ``set_learner_version`` (which
   arms the staleness gates). Returns the end-to-end latency — the
-  ``weight_sync_ms`` series rlbench commits and the learner bills as
+  ``weight_sync_ms`` series the learner bills as
   a first-class stall phase next to data_wait.
 
 The version counter is owned by the caller (the learner loop): it

@@ -62,8 +62,6 @@ __all__ = [
     "observe_engine_prefill",
     "observe_engine_prefix",
     "observe_engine_ttft",
-    "observe_engine_weights",
-    "observe_engine_policy",
     "deployment_snapshot",
 ]
 
@@ -473,13 +471,6 @@ def observe_engine_prefix(
             + " the paged KV prefix cache",
             ENGINE_TAGS,
         ).inc(1.0, tags=tags)
-        if skip_tokens:
-            _counter(
-                "serve_engine_prefix_tokens_saved_total",
-                "Prompt tokens whose prefill was skipped via "
-                "prefix-cache hits",
-                ENGINE_TAGS,
-            ).inc(float(skip_tokens), tags=tags)
     except Exception:
         pass
 
@@ -518,41 +509,6 @@ def observe_engine_device(
                 **tags, "platform": platform, "device_kind": device_kind,
             },
         )
-    except Exception:
-        pass
-
-
-def observe_engine_weights(
-    tags: Dict[str, str], version: int
-) -> None:
-    """Engine: a drainless weight push installed a new generation —
-    the version now served to NEW admissions and policy batches
-    (in-flight streams finish on the generation they pinned). The RL
-    dataflow pairs this with `rl_weight_version`/`rl_weight_lag` from
-    the learner side; the acceptance surface for weight-sync
-    visibility on /metrics."""
-    if not _ENABLED:
-        return
-    try:
-        _gauge(
-            "serve_engine_weight_version",
-            "Weight version served to new engine admissions",
-            ENGINE_TAGS,
-        ).set(float(version), tags=tags)
-    except Exception:
-        pass
-
-
-def observe_engine_policy(tags: Dict[str, str], batch_ms: float) -> None:
-    """Engine: one policy-path batched forward (the non-LLM batch
-    program serving RL action requests)."""
-    if not _ENABLED:
-        return
-    try:
-        _engine_histogram(
-            "serve_engine_policy_batch_ms",
-            "One policy batch-program forward in the engine",
-        ).observe(batch_ms, tags=tags)
     except Exception:
         pass
 

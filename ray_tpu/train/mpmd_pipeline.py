@@ -316,7 +316,7 @@ class _PipelineStage:
         """Execute this stage's 1F1B op list once: recv/compute/send
         per op, accumulate grads, then apply the local optimizer
         shard. Returns loss pieces + the per-op timing and edge-wait
-        numbers pipebench's efficiency accounting reads."""
+        numbers a pipeline-efficiency account reads (`simulate_schedule`)."""
         import jax
         import jax.numpy as jnp
 
@@ -552,8 +552,8 @@ class MPMDPipeline:
     params), then call `step(tokens, targets)` per global batch.
     Geometry: ``global batch = num_microbatches * microbatch_size``,
     layer partition from `partition_layers` (pass `layer_ms` /
-    `embed_ms` / `head_ms` from bench.py's `fixed_ms_breakdown` to
-    balance the asymmetric ends; uniform otherwise).
+    `embed_ms` / `head_ms` as measured for one layer, the embedding
+    and the head + loss to balance the asymmetric ends; uniform otherwise).
     """
 
     def __init__(
@@ -596,7 +596,7 @@ class MPMDPipeline:
             step_timeout_s or config.pipeline_step_timeout_s
         )
         if isinstance(layer_ms, (int, float)):
-            # bench.py's measured `layer_ms` is one number for a
+            # One measured `layer_ms` stands for every layer of a
             # homogeneous stack — broadcast it.
             layer_ms = [float(layer_ms)] * cfg.n_layers
         self.bounds = partition_layers(

@@ -20,7 +20,7 @@ import signal
 
 import pytest
 
-#: Hard per-test wall-clock cap (VERDICT r2 weak #8: a wedged session
+#: Hard per-test wall-clock cap (review r2 weak #8: a wedged session
 #: must FAIL the test, not hang the suite; faulthandler_timeout only
 #: dumps). SIGALRM raises in the main thread, which interrupts Python
 #: code and most blocking socket/lock waits. Slow-marked tests get 4x.
@@ -64,7 +64,7 @@ def rt_session():
     # Workers crashing BEFORE registering are never a legitimate test
     # outcome (tests that kill workers kill REGISTERED ones): a
     # nonzero startup-failure count is the crash-loop-under-load bug
-    # class (VERDICT r4 weak #7) and must fail the test that hit it,
+    # class (review r4 weak #7) and must fail the test that hit it,
     # with a pointer at the worker logs carrying the traceback.
     try:
         daemon = rt.api._session.daemon

@@ -1,6 +1,6 @@
 """Liveness smoke test — MUST stay first in collection order.
 
-VERDICT r2 item 1: round 2 snapshotted a repo whose ``rt.init()`` never
+review r2 item 1: round 2 snapshotted a repo whose ``rt.init()`` never
 completed (half-landed RPC nonce handshake), wedging the whole suite
 and the bench. This file is the guardrail: it collects first
 (``test_00_``), has a tight hard timeout, and fails fast if the

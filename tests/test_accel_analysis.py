@@ -3,8 +3,8 @@ devtools/accel.py rules RT301-RT306) and the static<->runtime bridge
 into the compile watch (`compile_watch.load_inventory`/`static_hint`).
 
 Every rule has a seeded-bug fixture (must fire) and a corrected twin
-(must stay quiet); the repo analyzes itself clean — package, tests AND
-bench.py — so every jit wrap site is either registered with
+(must stay quiet); the repo analyzes itself clean — package and
+tests — so every jit wrap site is either registered with
 `compile_watch.instrument` or carries an explicit, reviewed
 `# rt: noqa[RT3xx]`. Also here: the noqa-hygiene contract shared by
 all four passes (RT090/RT190/RT290/RT390 — a suppression naming a
@@ -37,7 +37,6 @@ from ray_tpu.devtools.accel import (
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PKG = os.path.join(REPO, "ray_tpu")
 TESTS = os.path.dirname(os.path.abspath(__file__))
-BENCH = os.path.join(REPO, "bench.py")
 
 
 def fired(source: str, path: str = "mod.py"):
@@ -547,11 +546,8 @@ def test_package_inventory_has_no_unregistered_programs():
     names = {p["program"] for p in inv["programs"] if p["program"]}
     # The convictions fixed in this PR, by name.
     for prog in (
-        "generate.decode_step",
-        "generate.prefill",
         "generate.paged_prefill",
         "generate.paged_decode_step",
-        "generate.generate",
         "rl.sample_actions",
         "rl.dqn.td_update",
         "rl.ppo.minibatch_update",
@@ -568,7 +564,7 @@ def test_package_inventory_has_no_unregistered_programs():
 
 
 def test_repo_analyzes_clean():
-    findings = accel_paths([PKG, TESTS, BENCH])
+    findings = accel_paths([PKG, TESTS])
     assert findings == [], "\n".join(f.render() for f in findings)
 
 
@@ -591,33 +587,29 @@ def test_devtools_all_includes_accel(tmp_path):
 
 
 def test_generate_wraps_registered_and_callable():
-    """The five generate.py jits register by name and still work; the
-    module-level `generate` rebind survives pickling by reference."""
+    """The generate.py jits register by name and still work; the
+    module-level forwarders pickle by reference."""
     import pickle
 
-    import jax.numpy as jnp
-    import numpy as np
-
+    from decode_oracle import paged_greedy
+    from ray_tpu._private import compile_watch
     from ray_tpu._private.compile_watch import WatchedFunction
     from ray_tpu.models import generate as g
     from ray_tpu.models.llama import LlamaConfig, init_params
 
-    assert isinstance(g.generate, WatchedFunction)
-    assert g.generate.name == "generate.generate"
+    assert isinstance(g._patch_step_slot_jit, WatchedFunction)
+    assert g._patch_step_slot_jit.name == "generate.patch_step_slot"
     # Importable call sites pickle the NAME, not the wrapper.
-    assert pickle.loads(pickle.dumps(g.decode_step)) is not None
+    assert pickle.loads(pickle.dumps(g.paged_decode_step)) is not None
 
     cfg = LlamaConfig.tiny()
     import jax
 
     params = init_params(jax.random.PRNGKey(0), cfg)
-    prompts = jnp.asarray(np.full((2, 4), 3, np.int32))
-    lengths = jnp.asarray(np.array([4, 4], np.int32))
-    tokens, out_lengths = g.generate(
-        params, prompts, lengths, cfg, max_new_tokens=3
-    )
-    assert tokens.shape == (2, 3)
-    assert g.generate.stats()["compiles"] >= 1
+    tokens = paged_greedy(params, cfg, [[3] * 4, [3] * 4], 3)
+    assert [len(row) for row in tokens] == [3, 3]
+    for name in ("generate.paged_prefill", "generate.paged_decode_step"):
+        assert compile_watch.snapshot()[name]["compiles"] >= 1, name
 
 
 def test_engine_mixed_generation_merge_stays_on_device():
@@ -678,7 +670,7 @@ def test_stale_noqa_hygiene_keeps_repo_clean():
             lint_paths([PKG])
             + check_paths([PKG, TESTS])
             + race_paths([PKG, TESTS])
-            + accel_paths([PKG, TESTS, BENCH])
+            + accel_paths([PKG, TESTS])
         )
         if f.rule in hygiene
     ]

@@ -13,6 +13,7 @@ transformers = pytest.importorskip("transformers")
 
 import jax  # noqa: E402
 
+from decode_oracle import paged_greedy  # noqa: E402
 from ray_tpu.models.hf_convert import config_from_hf, convert_hf_llama  # noqa: E402
 from ray_tpu.models.llama import forward  # noqa: E402
 
@@ -115,7 +116,7 @@ def test_unsupported_checkpoint_features_fail_loudly():
 
 def _tiny_hf_qwen2(n_heads=4, n_kv_heads=4, seed=0, tied=False):
     """Qwen2: same skeleton as Llama plus QKV projection biases — the
-    second HF architecture (VERDICT r3 item 10), proving the converter
+    second HF architecture (review r3 item 10), proving the converter
     isn't Llama-shape-hardcoded."""
     from transformers import Qwen2Config, Qwen2ForCausalLM
 
@@ -156,9 +157,7 @@ def test_qwen2_logits_match_transformers_gqa_tied():
 
 
 def test_qwen2_greedy_decode_matches_transformers_generate():
-    """The KV-cache serving path applies the biases too."""
-    from ray_tpu.models.generate import generate
-
+    """The paged serving forwards apply the biases too."""
     model = _tiny_hf_qwen2(n_heads=4, n_kv_heads=2, seed=9)
     rng = np.random.default_rng(9)
     prompt = rng.integers(1, 128, (2, 12), dtype=np.int64)
@@ -172,15 +171,8 @@ def test_qwen2_greedy_decode_matches_transformers_generate():
         )[:, prompt.shape[1]:].numpy()
     cfg = config_from_hf(model.config)
     params = convert_hf_llama(model.state_dict(), cfg)
-    ours, _lengths = generate(
-        params,
-        jax.numpy.asarray(prompt),
-        jax.numpy.asarray(np.full(2, prompt.shape[1], np.int32)),
-        cfg,
-        max_new_tokens=10,
-        temperature=0.0,
-    )
-    assert np.asarray(ours).tolist() == ref.tolist()
+    ours = paged_greedy(params, cfg, prompt, 10)
+    assert ours == ref.tolist()
 
 
 def test_biased_llama_rejected_loudly():
@@ -220,12 +212,10 @@ def test_flash_attention_matches_hf_reference():
 
 
 def test_greedy_decode_matches_transformers_generate():
-    """Greedy decode through OUR KV-cache prefill+step loop produces
-    the same continuation transformers.generate does — pins the cache
-    write indices, rotary offsets, and last-position logit selection of
-    the serving path, not just the training forward."""
-    from ray_tpu.models.generate import generate
-
+    """Greedy decode through OUR paged prefill + decode-step programs
+    produces the same continuation transformers.generate does — pins
+    the pool's write indices, rotary offsets, and last-position logit
+    selection of the serving path, not just the training forward."""
     model = _tiny_hf_llama(n_heads=4, n_kv_heads=4, seed=5)
     rng = np.random.default_rng(5)
     prompt = rng.integers(1, 128, (2, 12), dtype=np.int64)
@@ -242,21 +232,14 @@ def test_greedy_decode_matches_transformers_generate():
         )[:, prompt.shape[1]:].numpy()
     cfg = config_from_hf(model.config)
     params = convert_hf_llama(model.state_dict(), cfg)
-    ours, lengths = generate(
-        params,
-        jax.numpy.asarray(prompt),
-        jax.numpy.asarray(np.full(2, prompt.shape[1], np.int32)),
-        cfg,
-        max_new_tokens=10,
-        temperature=0.0,
-    )
-    assert np.asarray(ours).tolist() == ref.tolist()
+    ours = paged_greedy(params, cfg, prompt, 10)
+    assert ours == ref.tolist()
 
 
 def test_llama31_rope_scaling_parity():
     """Llama-3.1 'llama3' rope_scaling converts and matches HF's
     piecewise frequency scaling bit-for-bit at the logit level
-    (VERDICT r4 weak #5: every Llama-3.1+ checkpoint used to be
+    (review r4 weak #5: every Llama-3.1+ checkpoint used to be
     rejected by the NotImplementedError guard)."""
     from transformers import LlamaConfig as HFConfig
     from transformers import LlamaForCausalLM
@@ -312,7 +295,7 @@ def test_linear_rope_scaling_parity():
 
 @pytest.mark.slow
 def test_parity_at_depth_gqa_bf16():
-    """Parity at realistic depth/width in bf16 (VERDICT r4 weak #5:
+    """Parity at realistic depth/width in bf16 (review r4 weak #5:
     tiny 2-layer configs never exercised the regime where 'subtly
     wrong logits' live): 24 layers, hidden 1024, GQA 16q/4kv heads,
     real Llama-3 rope theta, bf16 weights and activations on BOTH
@@ -464,8 +447,6 @@ def test_gemma_logits_match_transformers():
 def test_gemma_greedy_decode_matches_transformers_generate():
     """The KV-cache serving layer applies the Gemma conventions too
     (shared model_norm/model_glu/embed_tokens helpers)."""
-    from ray_tpu.models.generate import generate
-
     model = _tiny_hf_gemma(seed=14)
     rng = np.random.default_rng(14)
     prompt = rng.integers(1, 128, (2, 9), dtype=np.int64)
@@ -479,15 +460,8 @@ def test_gemma_greedy_decode_matches_transformers_generate():
         )[:, prompt.shape[1]:].numpy()
     cfg = config_from_hf(model.config)
     params = convert_hf_llama(model.state_dict(), cfg)
-    ours, _lengths = generate(
-        params,
-        jax.numpy.asarray(prompt),
-        jax.numpy.asarray(np.full(2, prompt.shape[1], np.int32)),
-        cfg,
-        max_new_tokens=10,
-        temperature=0.0,
-    )
-    assert np.asarray(ours).tolist() == ref.tolist()
+    ours = paged_greedy(params, cfg, prompt, 10)
+    assert ours == ref.tolist()
 
 
 def _tiny_hf_phi3(n_heads=4, n_kv_heads=2, seed=0):
@@ -528,8 +502,6 @@ def test_phi3_logits_match_transformers():
 def test_phi3_greedy_decode_matches_transformers_generate():
     """The split fused projections feed the KV-cache serving path
     identically."""
-    from ray_tpu.models.generate import generate
-
     model = _tiny_hf_phi3(seed=18)
     rng = np.random.default_rng(18)
     prompt = rng.integers(3, 128, (2, 11), dtype=np.int64)
@@ -543,15 +515,8 @@ def test_phi3_greedy_decode_matches_transformers_generate():
         )[:, prompt.shape[1]:].numpy()
     cfg = config_from_hf(model.config)
     params = convert_hf_llama(model.state_dict(), cfg)
-    ours, _lengths = generate(
-        params,
-        jax.numpy.asarray(prompt),
-        jax.numpy.asarray(np.full(2, prompt.shape[1], np.int32)),
-        cfg,
-        max_new_tokens=10,
-        temperature=0.0,
-    )
-    assert np.asarray(ours).tolist() == ref.tolist()
+    ours = paged_greedy(params, cfg, prompt, 10)
+    assert ours == ref.tolist()
 
 
 def _tiny_hf_qwen3(n_heads=4, n_kv_heads=2, head_dim=16, seed=0):
@@ -594,8 +559,6 @@ def test_qwen3_logits_match_transformers():
 def test_qwen3_greedy_decode_matches_transformers_generate():
     """QK-norm applies identically on the KV-cache serving path
     (shared project_qkv)."""
-    from ray_tpu.models.generate import generate
-
     model = _tiny_hf_qwen3(seed=22)
     rng = np.random.default_rng(22)
     prompt = rng.integers(1, 128, (2, 9), dtype=np.int64)
@@ -609,15 +572,8 @@ def test_qwen3_greedy_decode_matches_transformers_generate():
         )[:, prompt.shape[1]:].numpy()
     cfg = config_from_hf(model.config)
     params = convert_hf_llama(model.state_dict(), cfg)
-    ours, _lengths = generate(
-        params,
-        jax.numpy.asarray(prompt),
-        jax.numpy.asarray(np.full(2, prompt.shape[1], np.int32)),
-        cfg,
-        max_new_tokens=10,
-        temperature=0.0,
-    )
-    assert np.asarray(ours).tolist() == ref.tolist()
+    ours = paged_greedy(params, cfg, prompt, 10)
+    assert ours == ref.tolist()
 
 
 # ---------------------------------------------------------------------

@@ -1,8 +1,8 @@
 """Distributed-correctness linter tests (`ray_tpu lint`,
 devtools/lint.py + rules.py) and regression tests for the four bug
-classes that motivated it (ADVICE round 5: tcp_channel payload-dedup,
-autoscaler request packing, worker namespace pinning, sdk num_cpus
-truncation).
+classes that motivated it (found by an earlier round's review:
+tcp_channel payload-dedup, autoscaler request packing, worker
+namespace pinning, sdk num_cpus truncation).
 
 Every rule RT001-RT010 has a positive fixture (must fire) and a
 negative fixture (must stay quiet); the repo lints itself clean — so
@@ -500,7 +500,7 @@ def test_every_rule_has_id_title_and_doc():
 
 
 # ---------------------------------------------------------------------------
-# regression: tcp_channel sequence-number framing (ADVICE #1)
+# regression: tcp_channel sequence-number framing
 # ---------------------------------------------------------------------------
 
 
@@ -666,7 +666,7 @@ def test_execute_retry_resumes_torn_fanout():
 
 
 # ---------------------------------------------------------------------------
-# regression: request_resources packs against node TOTALS (ADVICE #2)
+# regression: request_resources packs against node TOTALS
 # ---------------------------------------------------------------------------
 
 
@@ -761,7 +761,7 @@ def test_task_demand_still_packs_against_available():
 
 
 # ---------------------------------------------------------------------------
-# regression: session namespace reaches workers (ADVICE #3)
+# regression: session namespace reaches workers
 # ---------------------------------------------------------------------------
 
 
@@ -815,7 +815,7 @@ def test_namespace_propagates_into_tasks_and_nested_actors():
 
 
 # ---------------------------------------------------------------------------
-# regression: request_resources(num_cpus=...) validation (ADVICE #4)
+# regression: request_resources(num_cpus=...) validation
 # ---------------------------------------------------------------------------
 
 

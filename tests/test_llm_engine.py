@@ -1,6 +1,6 @@
 """Continuous-batching engine tests (ISSUE 10): scheduler invariants,
-engine-vs-generate parity, cancellation, multiplex isolation, and
-chaos — in-flight requests get errors, never hangs."""
+engine-vs-uncached-forward parity, cancellation, multiplex isolation,
+and chaos — in-flight requests get errors, never hangs."""
 
 import threading
 import time
@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from decode_oracle import greedy_uncached, serial_streams
 from ray_tpu.llm.scheduler import (
     EngineOverloaded,
     SlotScheduler,
@@ -95,12 +96,10 @@ def engine(tiny_model):
     eng.close()
 
 
-def test_engine_matches_generate_greedy(tiny_model, engine):
-    """Satellite 1 parity: tokens decoded through the shared slot
+def test_engine_matches_uncached_greedy(tiny_model, engine):
+    """Satellite 1 parity: tokens decoded through the shared paged
     cache (concurrent requests, per-row positions, chunked prefill)
-    must equal `generate()`'s greedy output per prompt."""
-    from ray_tpu.models.generate import generate
-
+    must equal greedy decoding by the uncached forward per prompt."""
     cfg, params = tiny_model
     rng = np.random.default_rng(7)
     prompts = [rng.integers(1, 128, size=n).tolist() for n in (5, 8, 11)]
@@ -108,25 +107,16 @@ def test_engine_matches_generate_greedy(tiny_model, engine):
     outs = [list(s) for s in streams]
     assert [s.finish_reason for s in streams] == ["length"] * 3
     for prompt, out in zip(prompts, outs):
-        ref, _ = generate(
-            params,
-            jnp.asarray([prompt], jnp.int32),
-            jnp.asarray([len(prompt)], jnp.int32),
-            cfg,
-            max_new_tokens=8,
-            temperature=0.0,
-        )
-        assert out == np.asarray(ref)[0].tolist()
+        assert out == greedy_uncached(params, cfg, prompt, 8)
 
 
-def test_prefix_hit_parity_with_generate(tiny_model):
+def test_prefix_hit_parity_with_uncached_greedy(tiny_model):
     """ISSUE 11 satellite: with the paged cache AND prefix caching ON,
     a request whose prompt prefix hits the pool must skip prefill for
-    the shared blocks and STILL decode token-for-token what
-    `generate()` produces — including a request that shares only the
-    prefix, not the whole prompt."""
+    the shared blocks and STILL decode token-for-token what the
+    uncached forward produces — including a request that shares only
+    the prefix, not the whole prompt."""
     from ray_tpu.llm import EngineConfig, InferenceEngine
-    from ray_tpu.models.generate import generate
 
     cfg, params = tiny_model
     eng = InferenceEngine(
@@ -156,13 +146,7 @@ def test_prefix_hit_parity_with_generate(tiny_model):
         assert stats["prefix_hits"] == 2
         assert stats["prefix_tokens_saved"] == 32
         for prompt, out in zip(prompts, outs):
-            ref, _ = generate(
-                params,
-                jnp.asarray([prompt], jnp.int32),
-                jnp.asarray([len(prompt)], jnp.int32),
-                cfg, max_new_tokens=8, temperature=0.0,
-            )
-            assert out == np.asarray(ref)[0].tolist()
+            assert out == greedy_uncached(params, cfg, prompt, 8)
     finally:
         eng.close()
 
@@ -177,9 +161,8 @@ def test_midprefill_row_not_corrupted_by_interleaved_decode(
     request's real pages. Pre-fix, a slot whose previous occupant
     finished at a low position wrote junk INSIDE the new prompt's
     already-prefilled region (position 0 here), and the output
-    diverged from generate()."""
+    diverged from the uncached forward's."""
     from ray_tpu.llm import EngineConfig, InferenceEngine
-    from ray_tpu.models.generate import generate
 
     cfg, params = tiny_model
     eng = InferenceEngine(
@@ -199,34 +182,53 @@ def test_midprefill_row_not_corrupted_by_interleaved_decode(
         out = list(stream)
         busy.cancel()
         list(busy)
-        ref, _ = generate(
-            params,
-            jnp.asarray([prompt], jnp.int32),
-            jnp.asarray([len(prompt)], jnp.int32),
-            cfg, max_new_tokens=8, temperature=0.0,
-        )
-        assert out == np.asarray(ref)[0].tolist()
+        assert out == greedy_uncached(params, cfg, prompt, 8)
     finally:
         eng.close()
 
 
-def test_engine_eos_stops_row(tiny_model, engine):
-    from ray_tpu.models.generate import generate
-
+@pytest.mark.parametrize("nth", [3, 1])
+def test_engine_eos_stops_row(tiny_model, engine, nth):
+    """The row ends with the EOS it emitted: the EOS counts, nothing
+    after it does — also when it is the very first token."""
     cfg, params = tiny_model
     prompt = [3, 14, 15, 9]
-    ref, _ = generate(
-        params,
-        jnp.asarray([prompt], jnp.int32),
-        jnp.asarray([len(prompt)], jnp.int32),
-        cfg, max_new_tokens=8, temperature=0.0,
-    )
-    eos = int(np.asarray(ref)[0][2])  # declare the 3rd token EOS
+    ref = greedy_uncached(params, cfg, prompt, 8)
+    eos = ref[nth - 1]  # declare the nth token EOS
+    assert eos not in ref[: nth - 1]
     stream = engine.submit(prompt, max_new_tokens=8, eos_token=eos)
     out = list(stream)
     assert stream.finish_reason == "stop"
-    assert out == np.asarray(ref)[0][:3].tolist()
-    assert out[-1] == eos
+    assert out == ref[:nth]
+    assert out == greedy_uncached(params, cfg, prompt, 8, eos=eos)
+
+
+def test_engine_sampled_tokens_in_vocab(tiny_model):
+    """Temperature + top-k sampling through the engine: every row runs
+    its whole budget and every token is a vocabulary id."""
+    from ray_tpu.llm import EngineConfig, InferenceEngine
+
+    cfg, params = tiny_model
+    eng = InferenceEngine(
+        params, cfg,
+        EngineConfig(
+            max_new_tokens=8, temperature=0.8, top_k=20, seed=9,
+            **ENGINE_KW,
+        ),
+        family="tiny",
+    )
+    try:
+        rng = np.random.default_rng(5)
+        streams = [
+            eng.submit(rng.integers(0, 128, size=5).tolist())
+            for _ in range(3)
+        ]
+        outs = np.asarray([list(s) for s in streams])
+    finally:
+        eng.close()
+    assert outs.shape == (3, 8)
+    assert ((outs >= 0) & (outs < 128)).all()
+    assert [s.finish_reason for s in streams] == ["length"] * 3
 
 
 def test_slot_reuse_after_eviction(engine):
@@ -363,7 +365,7 @@ def test_engine_overload_rejects(tiny_model):
         busy = []
         for n in range(2):
             busy.append(
-                eng.submit([1 + n, 2, 3, 4], max_new_tokens=24)
+                eng.submit([1 + n, 2, 3, 4], max_new_tokens=40)
             )
             deadline = time.time() + 10
             while time.time() < deadline:
@@ -406,44 +408,16 @@ def test_engine_death_fails_inflight_not_hangs(tiny_model):
     eng.close()
 
 
-def test_fallback_padding_is_exact(tiny_model):
-    """Kill-switch fallback (per-request generate_stream over a
-    BUCKET-padded prompt) must emit the same greedy tokens as
-    generate() on the unpadded prompt: generate_stream decodes from
-    each row's TRUE length, so padding never enters attention."""
+def test_llm_server_refuses_engine_enabled_false():
+    """The keyword outlived its switch (the benchmark's driver still
+    binds `engine_enabled=True`): True or absent is the engine, False
+    names a path that no longer exists."""
     from ray_tpu.llm.serving import LLMServer
-    from ray_tpu.models.generate import generate
 
-    cfg, params = tiny_model
-    server = LLMServer(
-        {
-            "tiny": {
-                "kind": "init", "seed": 0,
-                "config": {
-                    "vocab_size": 128, "dim": 64, "n_layers": 2,
-                    "n_heads": 4, "n_kv_heads": 2,
-                    "intermediate": 128, "max_seq_len": 128,
-                    "dtype": "float32",
-                },
-            }
-        },
-        engine=dict(max_new_tokens=8, **ENGINE_KW),
-        engine_enabled=False,
-    )
-    prompt = [3, 99, 41, 7, 58]  # 5 tokens: NOT a bucket multiple
-    out = [
-        int(chunk)
-        for chunk in b"".join(
-            server({"prompt": prompt, "max_new_tokens": 8})
-        ).split()
-    ]
-    ref, _ = generate(
-        params,
-        jnp.asarray([prompt], jnp.int32),
-        jnp.asarray([len(prompt)], jnp.int32),
-        cfg, max_new_tokens=8, temperature=0.0,
-    )
-    assert out == np.asarray(ref)[0].tolist()
+    families = {"tiny": {"kind": "init", "config": {}}}
+    LLMServer(families, engine_enabled=True)
+    with pytest.raises(ValueError, match="PR 29"):
+        LLMServer(families, engine_enabled=False)
 
 
 # ---------------------------------------------------------------------
@@ -452,76 +426,6 @@ def test_fallback_padding_is_exact(tiny_model):
 # ---------------------------------------------------------------------
 
 PIPE_KW = dict(slots=3, max_len=64, prefill_chunk=8)
-
-
-def serial_streams(params, cfg, ec, jobs):
-    """The plain reference: the engine's policy (FIFO, one prompt
-    prefilling at a time, one chunk an iteration and then one decode
-    step over the rows alive, `fold_in(base_key, step)` keys) run one
-    program at a time with the state on the host. `jobs` are
-    (prompt, max_new_tokens, eos), all queued at the start and no more
-    of them than slots. -> one token list a job."""
-    from ray_tpu.llm.kv_slots import default_block_len
-    from ray_tpu.models.generate import (
-        init_block_pool, paged_decode_step, paged_prefill,
-    )
-
-    assert len(jobs) <= ec.slots
-    chunk = ec.prefill_chunk
-    bl = ec.kv_block_len or default_block_len(chunk)
-    width = ec.max_len // bl
-    pool = init_block_pool(cfg, ec.slots * width + 1, bl)
-    # slot s owns blocks 1 + s * width ...: which ones is not the
-    # mathematics' business.
-    tables = 1 + np.arange(ec.slots * width, dtype=np.int32).reshape(
-        ec.slots, width
-    )
-    positions = np.zeros(ec.slots, np.int32)
-    alive = np.zeros(ec.slots, bool)
-    last_logits = jnp.zeros((ec.slots, cfg.vocab_size), jnp.float32)
-    base_key = jax.random.PRNGKey(ec.seed)
-    outs = [[] for _ in jobs]
-    waiting = list(range(len(jobs)))
-    prefilling = None  # (slot, padded prompt, offset)
-    step = 0
-    while waiting or prefilling or alive.any():
-        if prefilling is None and waiting:
-            slot = waiting.pop(0)
-            prompt = jobs[slot][0]
-            padded = np.zeros((1, -(-len(prompt) // chunk) * chunk), np.int32)
-            padded[0, : len(prompt)] = prompt
-            prefilling = (slot, padded, 0)
-        if prefilling:
-            slot, padded, offset = prefilling
-            logits, pool = paged_prefill(
-                params, cfg, jnp.asarray(padded[:, offset:offset + chunk]),
-                pool, jnp.asarray(tables[slot:slot + 1]),
-                jnp.int32(offset), jnp.int32(offset + chunk),
-            )
-            prefilling = (slot, padded, offset + chunk)
-            if offset + chunk >= padded.shape[1]:
-                n = len(jobs[slot][0])
-                last_logits = last_logits.at[slot].set(
-                    logits[0, n - 1 - offset]
-                )
-                positions[slot], alive[slot] = n, True
-                prefilling = None
-        if alive.any():
-            token, pool, last_logits = paged_decode_step(
-                params, cfg, pool, jnp.asarray(tables), last_logits,
-                jnp.asarray(positions), jnp.asarray(alive),
-                jax.random.fold_in(base_key, step),
-                temperature=ec.temperature, top_k=ec.top_k,
-            )
-            step += 1
-            token = np.asarray(token)
-            for slot in np.flatnonzero(alive):
-                _, max_new, eos = jobs[slot]
-                outs[slot].append(int(token[slot]))
-                positions[slot] += 1
-                if token[slot] == eos or len(outs[slot]) >= max_new:
-                    alive[slot] = False
-    return outs
 
 
 class held_engine:

@@ -1,58 +1,47 @@
-"""End-to-end LLM serving: the Llama decode path behind a Serve
-deployment with request batching — the framework's pieces composed the
-way a user would (reference story: vLLM-on-Ray; here the in-tree
-decoder serves)."""
+"""End-to-end LLM serving: the paged decode path behind a Serve
+deployment — the framework's pieces composed the way a user would
+(reference story: vLLM-on-Ray; here the in-tree engine serves)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from decode_oracle import greedy_uncached
+
+MODEL = dict(
+    vocab_size=128, dim=64, n_layers=2, n_heads=4, n_kv_heads=4,
+    intermediate=128, max_seq_len=64, attention="reference",
+)
+ENGINE = dict(slots=4, max_len=32, prefill_chunk=8, max_new_tokens=6)
+
+
+def tokens_of(chunks):
+    return [int(t) for t in b"".join(chunks).split()]
+
 
 def test_llm_deployment_with_batching(rt_session):
-    rt = rt_session
+    """A user's own deployment around an `InferenceEngine`: six calls
+    in flight share four slots, and every one gets its completion."""
     import ray_tpu.serve as serve
 
-    @serve.deployment
+    @serve.deployment(max_ongoing_requests=8)
     class LlamaService:
         def __init__(self):
+            from ray_tpu.llm import EngineConfig, InferenceEngine
             from ray_tpu.models.llama import LlamaConfig, init_params
 
-            self.cfg = LlamaConfig(
-                vocab_size=128,
-                dim=64,
-                n_layers=2,
-                n_heads=4,
-                n_kv_heads=4,
-                intermediate=128,
-                max_seq_len=64,
-                dtype=jnp.float32,
-                attention="reference",
+            cfg = LlamaConfig(dtype=jnp.float32, **MODEL)
+            self.engine = InferenceEngine(
+                init_params(jax.random.PRNGKey(0), cfg), cfg,
+                EngineConfig(**ENGINE), family="tiny",
             )
-            self.params = init_params(jax.random.PRNGKey(0), self.cfg)
 
-        @serve.batch(max_batch_size=4, batch_wait_timeout_s=0.1)
-        def complete(self, prompts):
-            """prompts: list of token-id lists (equal length); one
-            jitted generate serves the whole batch."""
-            from ray_tpu.models.generate import generate
+        def complete(self, prompt):
+            return list(self.engine.submit(prompt))
 
-            batch = np.asarray(prompts, np.int32)
-            lengths = jnp.full((len(prompts),), batch.shape[1], jnp.int32)
-            out, out_lengths = generate(
-                self.params,
-                jnp.asarray(batch),
-                lengths,
-                self.cfg,
-                max_new_tokens=6,
-                temperature=0.0,
-            )
-            return [
-                row[:n].tolist()
-                for row, n in zip(
-                    np.asarray(out), np.asarray(out_lengths)
-                )
-            ]
+        def requests_done(self):
+            return self.engine.stats()["requests_done"]
 
     try:
         handle = serve.run(
@@ -68,74 +57,46 @@ def test_llm_deployment_with_batching(rt_session):
         # Determinism: same prompt, same greedy completion.
         again = handle.complete.remote(prompts[0]).result(timeout=120)
         assert again == results[0]
+        assert handle.requests_done.remote().result(timeout=60) == 7
     finally:
         serve.shutdown()
 
 
 def test_llm_token_streaming(rt_session):
-    """Token streaming: an engine actor decodes with generate_stream
-    and yields each step through a streaming generator — the consumer
-    receives tokens while decoding is still running (reference story:
-    streaming chat completions; transport:
-    num_returns='streaming' + models/generate.generate_stream)."""
-    rt = rt_session
-
-    @rt.remote
-    class Engine:
-        def __init__(self):
-            from ray_tpu.models.llama import LlamaConfig, init_params
-
-            self.cfg = LlamaConfig(
-                vocab_size=128, dim=64, n_layers=2, n_heads=4,
-                n_kv_heads=4, intermediate=128, max_seq_len=64,
-                dtype=jnp.float32, attention="reference",
-            )
-            self.params = init_params(jax.random.PRNGKey(0), self.cfg)
-
-        def stream(self, prompt, max_new_tokens):
-            from ray_tpu.models.generate import generate_stream
-
-            batch = jnp.asarray([prompt], jnp.int32)
-            lengths = jnp.asarray([len(prompt)], jnp.int32)
-            for step_tokens in generate_stream(
-                self.params, batch, lengths, self.cfg,
-                max_new_tokens=max_new_tokens, temperature=0.0,
-            ):
-                yield int(step_tokens[0])
-
-    engine = Engine.remote()
-    gen = engine.stream.options(num_returns="streaming").remote(
-        [1, 7, 12, 5], 6
-    )
-    tokens = [rt.get(r, timeout=60) for r in gen]
-    assert len(tokens) == 6
-    assert all(0 <= t < 128 for t in tokens)
-
-    # Greedy decode must match the batch (scan) path token-for-token.
-    from ray_tpu.models.generate import generate
+    """Token streaming through `build_llm_app`: the consumer receives
+    each token as its own chunk while decoding is still running
+    (reference story: streaming chat completions), and the greedy
+    stream equals the uncached forward's continuation."""
+    import ray_tpu.serve as serve
+    from ray_tpu.llm import build_llm_app
     from ray_tpu.models.llama import LlamaConfig, init_params
 
-    cfg = LlamaConfig(
-        vocab_size=128, dim=64, n_layers=2, n_heads=4, n_kv_heads=4,
-        intermediate=128, max_seq_len=64, dtype=jnp.float32,
-        attention="reference",
-    )
+    family = {
+        "kind": "init", "seed": 0,
+        "config": dict(MODEL, dtype="float32"),
+    }
+    try:
+        handle = serve.run(
+            build_llm_app({"tiny": family}, engine=ENGINE),
+            name="llm-stream", route_prefix=None,
+        )
+        stream = handle.options(stream=True).remote(
+            {"prompt": [1, 7, 12, 5], "max_new_tokens": 6}
+        )
+        chunks = list(stream)
+    finally:
+        serve.shutdown()
+    assert len(chunks) == 6  # one chunk a token
+    tokens = tokens_of(chunks)
+    cfg = LlamaConfig(dtype=jnp.float32, **MODEL)
     params = init_params(jax.random.PRNGKey(0), cfg)
-    out, _ = generate(
-        params,
-        jnp.asarray([[1, 7, 12, 5]], jnp.int32),
-        jnp.asarray([4], jnp.int32),
-        cfg,
-        max_new_tokens=6,
-        temperature=0.0,
-    )
-    assert tokens == np.asarray(out)[0].tolist()
+    assert tokens == greedy_uncached(params, cfg, [1, 7, 12, 5], 6)
 
 
 def test_serve_converted_hf_checkpoint(rt_session, tmp_path):
     """The full user story: an HF Llama checkpoint converts, deploys
-    behind Serve, and the served greedy tokens are IDENTICAL to
-    transformers.generate on the same weights."""
+    behind Serve on the engine, and the served greedy tokens are
+    IDENTICAL to transformers.generate on the same weights."""
     rt = rt_session
     torch = pytest.importorskip("torch")
     pytest.importorskip("transformers")
@@ -143,6 +104,7 @@ def test_serve_converted_hf_checkpoint(rt_session, tmp_path):
     from transformers import LlamaForCausalLM
 
     import ray_tpu.serve as serve
+    from ray_tpu.llm import build_llm_app
 
     torch.manual_seed(9)
     hf = LlamaForCausalLM(HFConfig(
@@ -164,31 +126,18 @@ def test_serve_converted_hf_checkpoint(rt_session, tmp_path):
             do_sample=False, pad_token_id=0, eos_token_id=None,
         )[:, prompt.shape[1]:].numpy().tolist()
 
-    @serve.deployment
-    class Checkpoint:
-        def __init__(self, path):
-            from ray_tpu.models.hf_convert import load_hf_llama
-
-            self.params, self.cfg = load_hf_llama(path)
-
-        def complete(self, tokens):
-            from ray_tpu.models.generate import generate
-
-            batch = np.asarray([tokens], np.int32)
-            out, _ = generate(
-                self.params, jnp.asarray(batch),
-                jnp.full((1,), batch.shape[1], jnp.int32),
-                self.cfg, max_new_tokens=6, temperature=0.0,
-            )
-            return np.asarray(out)[0].tolist()
-
     try:
         handle = serve.run(
-            Checkpoint.bind(ckpt), name="hf-llm", route_prefix=None
+            build_llm_app(
+                {"hf": {"kind": "hf", "path": ckpt}}, engine=ENGINE
+            ),
+            name="hf-llm", route_prefix=None,
         )
-        served = handle.complete.remote(
-            prompt[0].tolist()
-        ).result(timeout=120)
+        served = tokens_of(
+            handle.options(stream=True).remote(
+                {"prompt": prompt[0].tolist(), "max_new_tokens": 6}
+            )
+        )
         assert [served] == expected
     finally:
         serve.shutdown()

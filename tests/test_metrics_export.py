@@ -285,10 +285,9 @@ def test_goodput_keeps_jobs_apart():
 # ---------------------------------------------------------------------------
 
 
-def test_goodput_in_doctor_and_step_summary(rt_session):
-    """Acceptance: the doctor's per-job goodput fraction classifies
-    productive + stall to the reported step wall within 5%."""
-    rt = rt_session
+def _goodput_of_three_steps(rt):
+    """Three hand-rolled steps of 100 ms, 40 of them stalled -> the
+    job's row of the head's `step_summary` goodput."""
     from ray_tpu._private.step_telemetry import add_phase, report_step
     from ray_tpu.util import metrics
 
@@ -298,10 +297,22 @@ def test_goodput_in_doctor_and_step_summary(rt_session):
         report_step(step, rank=0, wall_ms=100.0)
     metrics.flush()
     summary = rt.api._worker().call("step_summary")["summary"]
-    goodput = summary["goodput"]
-    assert len(goodput) == 1
-    row = next(iter(goodput.values()))
+    (row,) = summary["goodput"].values()
     assert row["steps"] == 3
+    return row
+
+
+def test_goodput_in_doctor_and_step_summary(rt_session):
+    """Acceptance: the doctor's per-job goodput fraction classifies
+    productive + stall to the reported step wall within 5%."""
+    rt = rt_session
+    from ray_tpu._private.step_telemetry import take_phases
+
+    # A hand-rolled loop drains the thread's bucket before it starts
+    # (`report_step`): this thread is pytest's, and whatever compiled
+    # on it in an earlier test left its `compile_ms` there.
+    take_phases()
+    row = _goodput_of_three_steps(rt)
     assert row["goodput"] == pytest.approx(0.6, abs=0.01)
     total = row["productive_ms"] + row["stall_ms"] + row["idle_ms"]
     assert total == pytest.approx(row["wall_ms"], rel=0.05)
@@ -309,6 +320,30 @@ def test_goodput_in_doctor_and_step_summary(rt_session):
     verdict = rt.diagnose(capture_stacks=False)
     doctor_row = next(iter(verdict["steps"]["goodput"].values()))
     assert doctor_row["goodput"] == row["goodput"]
+
+
+@pytest.mark.parametrize("drained", [True, False])
+def test_phases_left_before_a_loop_bill_its_first_step(
+    rt_session, drained
+):
+    """What made the test above fail in every whole-suite run: a
+    phase billed on this thread BEFORE a hand-rolled loop (a cold
+    compile's `compile_ms`, seconds of it) waits in the thread's
+    bucket for the next `report_step`. Undrained it is capped at the
+    first step's wall and that step reads as all stall (2 productive
+    steps of 3: 0.4); after `take_phases()` the loop reads 0.6."""
+    from ray_tpu._private.step_telemetry import add_phase, take_phases
+
+    take_phases()
+    add_phase("compile_ms", 5000.0)
+    if drained:
+        assert take_phases() == {"compile_ms": 5000.0}
+    row = _goodput_of_three_steps(rt_session)
+    want = 0.6 if drained else 0.4
+    assert row["goodput"] == pytest.approx(want, abs=0.01)
+    assert row["stalls"]["compile_ms"] == pytest.approx(
+        0.0 if drained else 60.0
+    )
 
 
 def test_timeseries_live_ring_and_endpoint():

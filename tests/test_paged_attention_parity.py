@@ -1,8 +1,8 @@
-"""The paged forward against the contiguous one (ISSUE 24): logits of
+"""The paged forward against the uncached one (ISSUE 24): logits of
 `_paged_forward` (pool written in place, attention over live key
-tiles, GQA queries grouped, bf16 read once) equal `_forward_with_cache`
-on the same weights and tokens. One parametrised test: dtype x GQA
-grouping x scenario.
+tiles, GQA queries grouped, bf16 read once) equal `llama.forward`,
+which keeps no cache, on the same weights and tokens. One parametrised
+test: dtype x GQA grouping x scenario.
 
 Geometry: blocks of 8 keys, rows to 128 keys (16 table entries), a
 decode tile of 32 keys and a chunk tile of 64, so a handful of tokens
@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import generate as g
-from ray_tpu.models.llama import LlamaConfig, init_params
+from ray_tpu.models.llama import LlamaConfig, forward, init_params
 
 BL, MAX_LEN, CHUNK, VOCAB = 8, 128, 32, 128
 WIDTH = MAX_LEN // BL
@@ -85,12 +85,12 @@ def decode_ragged(cfg, params, tol):
     prefill_logits, pool = paged_prefill_rows(
         cfg, params, pool, tables, tokens, positions
     )
-    cache = g.init_kv_cache(cfg, rows, MAX_LEN)
-    want_prefill, cache = g._forward_with_cache(
-        params, cfg, jnp.asarray(tokens), cache, 0, jnp.asarray(positions)
-    )
+    # Row by row the whole sequence through the uncached forward: its
+    # logits before a row's position are the prefill's, the ones AT it
+    # the step's (the step is fed the token that stands there).
+    want = forward(params, jnp.asarray(tokens), cfg)
     for row, n in enumerate(positions):
-        assert rel_rms(prefill_logits[row, :n], want_prefill[row, :n]) < tol
+        assert rel_rms(prefill_logits[row, :n], want[row, :n]) < tol
 
     step_tokens = tokens[np.arange(rows), positions]
     before = jax.tree.map(np.asarray, pool)
@@ -103,12 +103,8 @@ def decode_ragged(cfg, params, tol):
         0.0, 0,
     )
     assert np.asarray(token)[alive].tolist() == step_tokens[alive].tolist()
-    want, _ = g._forward_with_cache(
-        params, cfg, jnp.asarray(step_tokens)[:, None], cache,
-        jnp.asarray(positions), jnp.asarray(positions) + 1,
-    )
     for row in np.flatnonzero(alive):
-        assert rel_rms(logits[row], want[row, 0]) < tol, row
+        assert rel_rms(logits[row], want[row, positions[row]]) < tol, row
     assert np.isfinite(np.asarray(logits)).all()
 
     # What the step may write: one row of each alive sequence's
@@ -148,11 +144,7 @@ def prefill_shared_prefix(cfg, params, tol):
         params, cfg, jnp.asarray(tokens[1:, prefix:prefix + CHUNK]), pool,
         jnp.asarray(tables[1:]), prefix, prefix + CHUNK,
     )
-    cache = g.init_kv_cache(cfg, 1, MAX_LEN)
-    want, _ = g._forward_with_cache(
-        params, cfg, jnp.asarray(tokens[1:]), cache, 0,
-        jnp.asarray([prefix + own]),
-    )
+    want = forward(params, jnp.asarray(tokens[1:]), cfg)
     assert rel_rms(logits[0, :own], want[0, prefix:prefix + own]) < tol
     # The shared prefix blocks are read, never written.
     for name, was in zip("kv", shared):
@@ -179,11 +171,7 @@ def chunk_from_inside_a_block(cfg, params, tol):
         params, cfg, jnp.asarray(tokens[:, start:start + CHUNK]), pool,
         jnp.asarray(tables), start, start + CHUNK,
     )
-    cache = g.init_kv_cache(cfg, 1, MAX_LEN)
-    want, _ = g._forward_with_cache(
-        params, cfg, jnp.asarray(tokens), cache, 0,
-        jnp.asarray([start + CHUNK]),
-    )
+    want = forward(params, jnp.asarray(tokens), cfg)
     assert rel_rms(logits[0], want[0, start:start + CHUNK]) < tol
 
 
@@ -211,7 +199,7 @@ MODELS = [
 
 @pytest.mark.parametrize("scenario", sorted(SCENARIOS))
 @pytest.mark.parametrize("dtype,tol,groups", MODELS)
-def test_paged_forward_matches_the_contiguous_cache(
+def test_paged_forward_matches_the_uncached_forward(
     dtype, tol, groups, scenario
 ):
     cfg, params = build(dtype, groups)

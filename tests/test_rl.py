@@ -155,7 +155,7 @@ def test_env_runner_death_mid_iteration(rt_session):
     """A runner killed between iterations must not fail training: the
     next sample() returns the surviving runners' shard, and the one
     after returns a full batch from a respawned, re-synced runner
-    (VERDICT r4 task 3 done-criterion)."""
+    (review r4 task 3 done-criterion)."""
     import jax
 
     import ray_tpu as rt
@@ -239,7 +239,7 @@ def jax_flat(tree):
 def test_two_learner_ppo_matches_single_learner(rt_session):
     """2-learner PPO reaches the same CartPole bar as the 1-learner
     regression above — same effective minibatch, averaged gradients
-    (VERDICT r4 task 3 done-criterion)."""
+    (review r4 task 3 done-criterion)."""
     from ray_tpu.rl import PPOConfig
 
     algo = (
@@ -306,7 +306,7 @@ def test_dqn_mechanics():
 
 @pytest.mark.slow
 def test_dqn_learns_cartpole():
-    """Second algorithm learning regression (VERDICT r4 task 3):
+    """Second algorithm learning regression (review r4 task 3):
     double-DQN clears the CartPole bar (measured: ~130 mean return by
     ~30k env steps, 6s on 8 virtual CPUs)."""
     from ray_tpu.rl import DQNConfig

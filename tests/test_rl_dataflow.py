@@ -264,8 +264,8 @@ def test_weight_push_mid_decode_token_exact(tiny_llm):
     on the new weights, both decode CONCURRENTLY through the mixed-
     generation window, and the old generation is dropped once its
     last request retires."""
+    from decode_oracle import greedy_uncached
     from ray_tpu.llm import EngineConfig, InferenceEngine
-    from ray_tpu.models.generate import generate
 
     cfg, p_old, p_new = tiny_llm
     eng = InferenceEngine(
@@ -288,15 +288,7 @@ def test_weight_push_mid_decode_token_exact(tiny_llm):
         assert stream_new.finish_reason == "length"
 
         def ref(params):
-            toks, _ = generate(
-                params,
-                jnp.asarray([prompt], jnp.int32),
-                jnp.asarray([len(prompt)], jnp.int32),
-                cfg,
-                max_new_tokens=16,
-                temperature=0.0,
-            )
-            return np.asarray(toks)[0].tolist()
+            return greedy_uncached(params, cfg, prompt, 16)
 
         assert out_old == ref(p_old)  # token-exact on OLD weights
         assert out_new == ref(p_new)  # next admission on NEW weights

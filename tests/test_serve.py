@@ -613,7 +613,7 @@ def test_multiplexed_lru_and_router_warmth(serve_session):
 
 
 def test_proxy_admission_control_and_keepalive():
-    """Ingress hardening (VERDICT r4 weak #6): the proxy bounds
+    """Ingress hardening (review r4 weak #6): the proxy bounds
     in-flight requests (immediate 503 + Retry-After past the cap, no
     unbounded thread stacking) and connections (raw 503 before a
     handler thread spawns); keep-alive connections serve multiple

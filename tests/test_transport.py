@@ -2,7 +2,7 @@
 src/ray/rpc/grpc_server.h; object transfer object_manager.h).
 
 Covers the TCP wire directly (framing, HMAC auth, address parsing),
-and the headline scenario of VERDICT round-1 item 1: head and worker
+and the headline scenario of review round-1 item 1: head and worker
 daemons in SEPARATE PROCESSES with SEPARATE SESSION DIRS joined over
 TCP loopback, where a multi-megabyte object produced on the worker
 node reaches the driver through chunked pulls over the socket — no
