@@ -215,8 +215,9 @@ def _generator_or_refs(refs, num_returns, worker):
     if num_returns == "streaming":
         from ..object_ref import ObjectRefGenerator
 
-        # The generator must keep the submit-returned primary ref
-        # alive: it holds the owner-side future __next__ waits on.
+        # The generator keeps the submit-returned primary ref alive:
+        # it holds the owner-side future that reports a lost producer.
+        worker.watch_stream_marker(refs[0])
         return ObjectRefGenerator(
             refs[0].id().task_id(), owner=worker, primary_ref=refs[0]
         )

@@ -66,6 +66,7 @@ from .ids import (
 )
 from .object_store import ObjectStoreFullError, make_store
 from .spilling import FileSpillStorage
+from .stream_runs import StreamRuns
 from .placement_groups import (
     PGEntry,
     STRATEGIES,
@@ -486,6 +487,9 @@ class NodeDaemon:
         # the head's lifetime); only a trim-induced seq gap parks
         # seqs in the set until the gap is passed.
         self._metrics_seen: Dict[str, list] = {}
+        #: Items of streaming-generator tasks on their way to their
+        #: consumers (head only; worker nodes forward).
+        self._streams = StreamRuns()
         #: Standing autoscaler capacity target (head only; sdk
         #: request_resources — REPLACE semantics, cleared by []).
         self._resource_requests: List[dict] = []
@@ -527,6 +531,10 @@ class NodeDaemon:
             "get_objects",
             "wait_objects",
             "put_inline",
+            "stream_append",
+            "stream_end",
+            "stream_fetch",
+            "stream_close",
             "object_sealed",
             "seal_error",
             "task_done",
@@ -1040,6 +1048,7 @@ class NodeDaemon:
                     if e[0].pid != winfo.pid
                 ]
         self._drop_log_subscriber(conn.conn_id)
+        self._streams.drop_consumer(conn.conn_id)
         if dead_node is not None:
             self._on_node_death(dead_node)
             return {}
@@ -1345,6 +1354,57 @@ class NodeDaemon:
             if k in msg
         }
 
+    # -- streaming-generator items (stream_runs.py) ---------------------
+    def _h_stream_append(self, conn, msg):
+        if not self.is_head:
+            self.head.notify(
+                "stream_append", task=msg["task"], index=msg["index"],
+                data=msg["data"],
+            )
+            return {}
+        self._streams.put(msg["task"], msg["index"], msg["data"])
+        return {}
+
+    def _h_stream_end(self, conn, msg):
+        if not self.is_head:
+            self.head.notify(
+                "stream_end", task=msg["task"], count=msg.get("count"),
+                error=msg.get("error"),
+            )
+            return {}
+        self._streams.end(msg["task"], msg.get("count"), msg.get("error"))
+        return {}
+
+    def _h_stream_fetch(self, conn, msg):
+        mid = msg["_mid"]
+        if not self.is_head:
+            self.head.call_async(
+                "stream_fetch",
+                lambda reply: conn.reply(mid, reply),
+                task=msg["task"], after=msg["after"],
+            )
+            return DEFERRED
+        task = msg["task"]
+        if self._streams.fetch(conn, mid, task, msg["after"]):
+            # A task that failed before this first request (never
+            # scheduled, its actor dead) left its error on the
+            # completion marker and found no run to end.
+            with self._lock:
+                marker = self.objects.get(
+                    ObjectID.for_return(TaskID(task), 1)
+                )
+                error = marker.error if marker is not None else None
+            if error is not None:
+                self._streams.end(task, None, error)
+        return DEFERRED
+
+    def _h_stream_close(self, conn, msg):
+        if not self.is_head:
+            self.head.notify("stream_close", task=msg["task"])
+            return {}
+        self._streams.close(msg["task"])
+        return {}
+
     def _h_object_sealed(self, conn, msg):
         """A shm object was sealed. From a local worker: record the
         local copy (and, on worker nodes, tell the head). From a node
@@ -1409,6 +1469,13 @@ class NodeDaemon:
             entry = self._ensure_entry(oid)
             entry.error = error
             entry.state = ERRORED
+        if self.is_head and len(self._streams) and oid.index() == 1:
+            # A streaming task's completion marker: whatever failed
+            # it, its consumer is parked on the run, not on this
+            # object.
+            self._streams.end(
+                oid.task_id().binary(), None, error, create=False
+            )
         self._wake(oid)
 
     def _object_reply_local(self, oid: ObjectID) -> Optional[dict]:
@@ -2006,6 +2073,7 @@ class NodeDaemon:
                 self._reap_idle_workers()
             except Exception:
                 pass
+            self._streams.sweep()
             time.sleep(self.config.object_eviction_check_interval_s)
 
     #: Idle workers beyond the pool cap live this long before exiting.

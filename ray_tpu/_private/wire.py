@@ -458,6 +458,18 @@ SCHEMAS: Dict[str, Dict[str, Any]] = {
         "?owner_job": str, "?owner": str, "?owner_pid": int,
     },
     "seal_error": {"oid": bytes, "error": bytes},
+    # Streaming-generator items (stream_runs.py): the producer
+    # appends, somebody who knows reports the end, the consumer parks
+    # one request per stream and says when it will ask no more.
+    "stream_append": {
+        "task": bytes, "index": int, "data": (bytes, type(None)),
+    },
+    "stream_end": {
+        "task": bytes, "?count": (int, type(None)),
+        "?error": (bytes, type(None)),
+    },
+    "stream_fetch": {"task": bytes, "after": int},
+    "stream_close": {"task": bytes},
     "get_object": {"oid": bytes},
     # Batched non-blocking get: one round trip resolves N refs (the
     # worker's arg-fetch path); unsealed oids come back as pending

@@ -20,6 +20,7 @@ import contextvars
 import inspect
 import itertools
 import os
+import pickle
 import queue
 import sys
 import threading
@@ -507,6 +508,11 @@ class CoreWorker:
         #: message ordered after it). Entries die with the local ref.
         #: (reference: CoreWorkerMemoryStore for small owned objects.)
         self._inline_cache: Dict[ObjectID, bytes] = {}
+        #: Items of streams consumed here whose value lives ONLY in
+        #: the inline cache: the daemon has never heard of them, and
+        #: hears nothing of their release either, unless their ref
+        #: leaves this process first (_publish_stream_item).
+        self._stream_local: set = set()
         #: Get-provenance aggregates: (provenance, src_node, task)
         #: -> [count, bytes, wait_ms]. Drained onto the metrics pipe
         #: once per flush tick (util.metrics._Buffer drain hook) —
@@ -582,7 +588,8 @@ class CoreWorker:
             if count <= 0:
                 self._ref_counts.pop(oid, None)
                 self._inline_cache.pop(oid, None)
-                notify = True
+                notify = oid not in self._stream_local
+                self._stream_local.discard(oid)
             else:
                 self._ref_counts[oid] = count
                 notify = False
@@ -630,6 +637,62 @@ class CoreWorker:
 
     def notify_borrowed_ref(self, oid: ObjectID) -> None:
         self._client.notify("add_ref", oids=[oid.binary()])
+
+    # ------------------------------------------------------------------
+    # consumer side of streaming generators (object_ref.py,
+    # stream_runs.py)
+    # ------------------------------------------------------------------
+    def adopt_stream_item(self, oid: ObjectID, data: bytes) -> None:
+        """Keep an item that arrived with its bytes where get() looks
+        first. The caller makes the ObjectRef whose release evicts
+        it."""
+        with self._ref_lock:
+            self._inline_cache[oid] = data
+            self._stream_local.add(oid)
+
+    def _local_stream_item(self, oid: ObjectID) -> Optional[bytes]:
+        with self._ref_lock:
+            if oid in self._stream_local:
+                return self._inline_cache.get(oid)
+        return None
+
+    def _publish_stream_item(self, oid: ObjectID) -> None:
+        """The ref of a streamed item is leaving this process (pickled
+        into a value) or the daemon is about to be asked about it:
+        make it an object of the directory first."""
+        with self._ref_lock:
+            if oid not in self._stream_local:
+                return
+            self._stream_local.discard(oid)
+            data = self._inline_cache.get(oid)
+        if data is not None:
+            self._client.notify(
+                "put_inline", oid=oid.binary(), data=data,
+                **self._owner_fields(oid),
+            )
+
+    def watch_stream_marker(self, marker: ObjectRef) -> None:
+        """Where the completion marker of a streaming task is a direct
+        future, an error reaches THIS process and nobody else: hand it
+        to the run, behind the items the producer said it had sealed,
+        so that the consumer's parked request is answered."""
+        entry = (
+            self._direct.lookup(marker.id())
+            if self._direct is not None else None
+        )
+        if entry is None:
+            return
+        task = marker.id().task_id().binary()
+
+        def report(fut):
+            if fut.daemon_fallback or fut.error is None:
+                return
+            self._client.notify(
+                "stream_end", task=task, error=fut.error,
+                count=pickle.loads(fut.error).get("items_emitted"),
+            )
+
+        entry[0].add_done_callback(report)
 
     # ------------------------------------------------------------------
     # ids
@@ -1007,24 +1070,6 @@ class CoreWorker:
                     ) from None
                 time.sleep(0.01)
 
-    def peek_object_error(self, oid: ObjectID) -> Optional[bytes]:
-        """Error payload of a KNOWN-READY object, or None if it holds a
-        value. Lets generator consumers inspect a failed completion
-        marker (e.g. for items_emitted) without raising."""
-        if self._direct is not None:
-            entry = self._direct.lookup(oid)
-            if entry is not None:
-                fut = entry[0]
-                if fut.done() and not fut.daemon_fallback:
-                    return fut.error
-        try:
-            reply = self._client.call(
-                "get_object", oid=oid.binary(), timeout=30.0
-            )
-        except RpcError:
-            return None
-        return reply.get("error")
-
     def _read_local_store(
         self, oid: ObjectID, size: int, timeout: Optional[float]
     ) -> Any:
@@ -1113,6 +1158,9 @@ class CoreWorker:
     ) -> Tuple[List[ObjectRef], List[ObjectRef]]:
         if not refs:
             return [], []
+        if self._stream_local:
+            for ref in refs:
+                self._publish_stream_item(ref.id())
         direct: Dict[ObjectRef, Any] = {}
         if self._direct is not None:
             for ref in refs:
@@ -1237,7 +1285,12 @@ class CoreWorker:
         """Owner-side dependency resolution for direct-call results
         (reference: normal_task_submitter.cc DependencyResolver —
         the owner waits for locally-owned results and inlines small
-        ones into the dependent spec). Non-direct refs pass through."""
+        ones into the dependent spec). So does the item of a stream
+        consumed here. Other refs pass through."""
+        if self._stream_local:
+            item = self._local_stream_item(arg.id())
+            if item is not None:
+                return ("inline", item)
         if self._direct is None:
             return ("ref", arg.binary())
         entry = self._direct.lookup(arg.id())
@@ -1268,9 +1321,10 @@ class CoreWorker:
 
     def ensure_globally_visible(self, oid: ObjectID) -> None:
         """Called when a ref escapes this process (pickled into a
-        value or borrowed): direct inline results must reach the
-        daemon's object table first or the borrower can never resolve
-        them."""
+        value or borrowed): direct inline results and streamed items
+        must reach the daemon's object table first or the borrower can
+        never resolve them."""
+        self._publish_stream_item(oid)
         if self._direct is not None:
             try:
                 self._direct.ensure_published(oid)
@@ -2052,11 +2106,13 @@ class CoreWorker:
     def _collect_returns(
         self, task_id: TaskID, spec: dict, value: Any
     ) -> List[Any]:
-        """Normal returns are split across the declared return ids;
-        generator tasks ("dynamic"/"streaming") seal each yielded item
-        under its deterministic id as produced, then return the
-        completion marker (an ObjectRefGenerator carrying the count)
-        as the single declared return (reference:
+        """Normal returns are split across the declared return ids.
+        A generator task has ONE declared return, its completion
+        marker: "dynamic" seals each yielded item as an object under
+        its deterministic id and returns an ObjectRefGenerator that
+        carries the count (its refs are handed out after the task
+        ends); "streaming" appends each item to the task's run as it
+        is produced (stream_runs.py) and returns the count (reference:
         python/ray/_raylet.pyx streaming generator protocol)."""
         mode = spec.get("num_returns_mode")
         if not mode:
@@ -2068,21 +2124,48 @@ class CoreWorker:
                 f"num_returns={mode!r} requires the task to return a "
                 f"generator or iterable, got {type(value).__name__}"
             )
-        from ..object_ref import ObjectRefGenerator
-
+        streaming = mode == "streaming"
+        task = task_id.binary()
         count = 0
         try:
             for item in value:
-                self.put_object(
-                    ObjectID.for_return(task_id, count + 2), item
-                )
+                oid = ObjectID.for_return(task_id, count + 2)
+                if streaming:
+                    self._client.notify(
+                        "stream_append", task=task, index=count,
+                        data=self._stream_item_bytes(oid, item),
+                    )
+                else:
+                    self.put_object(oid, item)
                 count += 1
         except BaseException as e:
             # Consumers must still receive the items sealed before the
             # failure; the error payload carries the emitted count.
+            # The error itself reaches a stream's run by the marker
+            # (daemon._seal_error_local, watch_stream_marker).
             e.__rt_items_emitted__ = count
             raise
+        if streaming:
+            self._client.notify("stream_end", task=task, count=count)
+            return [count]
+        from ..object_ref import ObjectRefGenerator
+
         return [ObjectRefGenerator(task_id, count=count)]
+
+    def _stream_item_bytes(
+        self, oid: ObjectID, item: Any
+    ) -> Optional[bytes]:
+        """What a stream's run carries for one item: its serialized
+        bytes, or None for one too large for a message, sealed in the
+        object store under its id."""
+        serialized = self.serialization.serialize(item)
+        size = serialized.total_size()
+        if size <= self.config.max_direct_call_object_size:
+            return serialized.to_bytes()
+        self.flush_pending_dels()
+        buf = self._store_create(oid, size)
+        self._seal_and_report(oid, serialized.write_to(buf))
+        return None
 
     @staticmethod
     def _split_returns(value: Any, num_returns: int) -> List[Any]:  # noqa: D102

@@ -9,6 +9,8 @@ core_worker/reference_count.h owner-based refcounting).
 
 from __future__ import annotations
 
+from collections import deque
+
 from ._private.ids import ObjectID
 
 
@@ -101,15 +103,20 @@ class ObjectRefGenerator:
 
     - Item object ids are **deterministic** — item *i* is
       ``ObjectID.for_return(task_id, i + 2)`` (index 1 is the task's
-      primary return, which doubles as the completion marker carrying
-      the final item count, stored by the worker AFTER every item is
-      sealed). No extra control traffic is needed to stream.
-    - Streaming mode: ``__next__`` blocks until item *i* is sealed or
-      the completion marker lands (count known -> StopIteration, task
-      error -> raised here).
+      primary return, the completion marker).
+    - Streaming mode (no count): the items travel as the task's
+      run of serialized values (_private/stream_runs.py). ``__next__``
+      keeps ONE request parked at the daemon, "everything after item
+      i", and is answered with every item appended since, bytes
+      included, and with the end of the stream once it has come: the
+      count, or the task's error behind the items sealed before it.
+      The ref it returns resolves from the owner's inline cache; the
+      item becomes an object of the directory only if the ref leaves
+      this process.
     - Dynamic mode: the completion marker's VALUE is this generator
-      (count pre-resolved), so ``get(ref)`` on a dynamic task returns
-      an ObjectRefGenerator, per the reference's API.
+      (count pre-resolved, every item a sealed object), so
+      ``get(ref)`` on a dynamic task returns an ObjectRefGenerator,
+      per the reference's API.
     """
 
     def __init__(self, task_id, owner=None, count=None, primary_ref=None):
@@ -117,11 +124,20 @@ class ObjectRefGenerator:
         self._owner = owner
         self._count = count
         self._index = 0
-        #: Held for the generator's lifetime while streaming: dropping
-        #: the last local ref to the completion marker would release
-        #: its owner-side future (and eventually the daemon entry)
-        #: while __next__ still needs it.
+        #: Held for the generator's lifetime while streaming: the
+        #: owner-side future of the completion marker is what reports
+        #: a lost producer to the run (CoreWorker.watch_stream_marker).
         self._primary_ref: ObjectRef | None = primary_ref
+        #: Serialized items fetched and not handed out yet.
+        self._fetched: deque = deque()
+        #: {"count", "error"} once the daemon has said so.
+        self._end: dict | None = None
+        self._closed = False
+        #: What the consumer counts of its transport: items received
+        #: and requests they took (1.0 item a fetch where it keeps up
+        #: with the producer, more where it does not).
+        self.stream_items = 0
+        self.stream_fetches = 0
 
     def _ref(self, object_id: ObjectID) -> ObjectRef:
         return ObjectRef(object_id, owner=self._owner)
@@ -131,8 +147,9 @@ class ObjectRefGenerator:
 
     @property
     def completed_ref(self) -> ObjectRef:
-        """Ref of the completion marker (resolves to the item count
-        once the whole generator has run; errors if the task failed)."""
+        """Ref of the completion marker (resolves once the whole
+        generator has run: to the item count of a streaming task;
+        errors if the task failed)."""
         if self._primary_ref is None:
             self._primary_ref = self._ref(
                 ObjectID.for_return(self._task_id, 1)
@@ -142,56 +159,72 @@ class ObjectRefGenerator:
     def __iter__(self):
         return self
 
-    def __next__(self) -> ObjectRef:
+    def _next_item(self) -> tuple:
+        """(id, serialized bytes) of the next streamed item, the bytes
+        None for an item that is a store object. Parks at the daemon
+        when nothing is at hand."""
         if self._owner is None:
             from ._private.worker import global_worker
 
             self._owner = global_worker()
+        while not self._fetched:
+            if self._end is not None:
+                if self._end["error"] is not None:
+                    from ._private.task_spec import raise_from_payload
+
+                    raise_from_payload(self._end["error"])
+                raise StopIteration
+            reply = self._owner.call(
+                "stream_fetch",
+                task=self._task_id.binary(),
+                after=self.stream_items,
+            )
+            self.stream_fetches += 1
+            self.stream_items += len(reply["items"])
+            self._fetched.extend(reply["items"])
+            self._end = reply["end"]
+        oid = self._item_id(self._index)
+        self._index += 1
+        return oid, self._fetched.popleft()
+
+    def __next__(self) -> ObjectRef:
         if self._count is not None:
             if self._index >= self._count:
                 raise StopIteration
             ref = self._ref(self._item_id(self._index))
             self._index += 1
             return ref
-        item = self._ref(self._item_id(self._index))
-        primary = self.completed_ref
-        while True:
-            ready, _ = self._owner.wait(
-                [item, primary], num_returns=1, timeout=30.0
-            )
-            if item in ready:
-                self._index += 1
-                return item
-            if primary in ready:
-                error = self._owner.peek_object_error(primary.id())
-                if error is not None:
-                    # Mid-stream failure: drain the items the worker
-                    # sealed before erroring (their count rides in the
-                    # payload), then re-raise the task's error.
-                    import pickle as _pickle
-
-                    emitted = _pickle.loads(error).get(
-                        "items_emitted", 0
-                    )
-                    if self._index < (emitted or 0):
-                        self._index += 1
-                        return item
-                    self._owner.get([primary])  # raises the error
-                # Worker seals the marker after the last item, so by
-                # now either index < count (item is sealed) or we are
-                # past the end.
-                marker = self._owner.get([primary])[0]
-                self._count = (
-                    marker._count
-                    if isinstance(marker, ObjectRefGenerator)
-                    else int(marker)
-                )
-                if self._index >= self._count:
-                    raise StopIteration
-                self._index += 1
-                return item
+        oid, data = self._next_item()
+        if data is not None:
+            self._owner.adopt_stream_item(oid, data)
+        return self._ref(oid)
 
     next = __next__
+
+    def next_value(self):
+        """The next item's VALUE (a streaming generator's): what
+        ``get(next(gen))`` returns, without the ref."""
+        oid, data = self._next_item()
+        if data is None:
+            return self._owner.get([self._ref(oid)])[0]
+        return self._owner.serialization.deserialize(data)
+
+    def close(self) -> None:
+        """Tell the daemon that nobody will ask for the rest of the
+        stream (a consumer that saw its end has no need to)."""
+        if self._count is not None or self._closed:
+            return
+        self._closed = True
+        if self._end is None and self._owner is not None:
+            self._owner.notify(
+                "stream_close", task=self._task_id.binary()
+            )
+
+    def __del__(self):
+        try:
+            self.close()
+        except Exception:
+            pass
 
     def __reduce__(self):
         return (

@@ -7,6 +7,8 @@ into per-deployment histograms:
 
   serve_http_request_latency_ms   proxy: end-to-end HTTP time
   serve_router_routing_ms         handle: replica selection time
+  serve_stream_items,             handle: per stream, the items its
+  serve_stream_fetches            consumer received and its requests
   serve_queue_wait_ms             replica: send -> execution start
   serve_request_latency_ms        replica: handler execution time
   serve_model_load_ms             multiplex: model swap (load) time
@@ -245,6 +247,31 @@ def observe_routing(app: str, deployment: str, dur_ms: float) -> None:
             "Replica selection time in the router",
             ("app", "deployment"),
         ).observe(dur_ms, tags={"app": app, "deployment": deployment})
+    except Exception:
+        pass
+
+
+def observe_stream(
+    app: str, deployment: str, items: int, fetches: int
+) -> None:
+    """Handle: one stream is over; what its consumer counted of the
+    transport. The sums of the two series are cumulative items and
+    fetches: their ratio is 1.0 where consumers keep up with their
+    producers and more where tokens wait for them."""
+    if not _ENABLED:
+        return
+    try:
+        tags = {"app": app, "deployment": deployment}
+        _histogram(
+            "serve_stream_items",
+            "Items a stream's consumer received, per stream",
+            ("app", "deployment"),
+        ).observe(float(items), tags=tags)
+        _histogram(
+            "serve_stream_fetches",
+            "Requests a stream's consumer made for its items, per stream",
+            ("app", "deployment"),
+        ).observe(float(fetches), tags=tags)
     except Exception:
         pass
 

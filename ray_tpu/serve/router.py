@@ -38,6 +38,7 @@ import uuid
 from typing import Any, Dict, List, Optional
 
 from .controller import CONTROLLER_NAME
+from .observability import observe_stream
 
 
 class DeploymentOverloaded(RuntimeError):
@@ -232,13 +233,10 @@ class DeploymentResponseGenerator:
         return self
 
     def __next__(self):
-        import ray_tpu as rt
-
         if self._finished:
             raise StopIteration
         try:
-            ref = next(self._gen)
-            value = rt.get(ref, timeout=60)
+            value = self._gen.next_value()
         except StopIteration:
             self._exhausted = True
             self.close()
@@ -273,6 +271,13 @@ class DeploymentResponseGenerator:
         self._router._ongoing_done(self._replica_id)
         self._router._tokens_done(self._replica_id, self._tokens_left)
         self._tokens_left = 0
+        observe_stream(
+            self._router.app_name,
+            self._router.deployment_name,
+            self._gen.stream_items,
+            self._gen.stream_fetches,
+        )
+        self._gen.close()
         if (
             not self._exhausted
             and self._actor is not None
@@ -392,10 +397,16 @@ class DeploymentHandle:
         #: SLO-admission signal; shared across method clones like
         #: _ongoing so one handle family sees one load picture).
         self._outstanding_tokens: Dict[str, int] = {}
-        self._sent = 0
-        self._done = 0
+        #: Requests sent and ended, and the thread that reports their
+        #: difference to the controller: ONE per handle family, in a
+        #: shared box like the listener's. A clone is made for every
+        #: request (`options()`), and a reporter of its own would
+        #: outlive it: a thread and four calls a second through the
+        #: head for every request ever served.
+        self._load: Dict[str, Any] = {
+            "sent": 0, "done": 0, "reporter": None,
+        }
         self._batchers: Dict[str, _BatchQueue] = {}
-        self._reporter: Optional[threading.Thread] = None
         # Mutable box shared across method clones (plain attributes
         # would be snapshotted at clone time): one listener per
         # handle family.
@@ -545,7 +556,7 @@ class DeploymentHandle:
         self, replica_id: Optional[str] = None, tokens: int = 0
     ) -> None:
         with self._lock:
-            self._sent += 1
+            self._load["sent"] += 1
             if replica_id:
                 self._ongoing[replica_id] = (
                     self._ongoing.get(replica_id, 0) + 1
@@ -559,7 +570,7 @@ class DeploymentHandle:
 
     def _ongoing_done(self, replica_id: Optional[str] = None) -> None:
         with self._lock:
-            self._done += 1
+            self._load["done"] += 1
             if replica_id and self._ongoing.get(replica_id, 0) > 0:
                 self._ongoing[replica_id] -= 1
 
@@ -593,12 +604,13 @@ class DeploymentHandle:
         """Push ongoing-load metrics to the controller for autoscaling
         (reference: autoscaling_state consumes handle metrics)."""
         with self._lock:
-            if self._reporter is not None:
+            if self._load["reporter"] is not None:
                 return
-            self._reporter = threading.Thread(
-                target=self._report_loop, daemon=True
+            reporter = self._load["reporter"] = threading.Thread(
+                target=self._report_loop, daemon=True,
+                name=f"serve-load-report:{self.deployment_name}",
             )
-            self._reporter.start()
+            reporter.start()
 
     def _report_loop(self) -> None:
         try:
@@ -607,7 +619,9 @@ class DeploymentHandle:
                 try:
                     controller = _controller()
                     with self._lock:
-                        ongoing = self._sent - self._done
+                        ongoing = (
+                            self._load["sent"] - self._load["done"]
+                        )
                     controller.report_metrics.remote(
                         self.app_name,
                         self.deployment_name,
@@ -622,7 +636,7 @@ class DeploymentHandle:
             # If the thread ever exits (interpreter teardown), allow a
             # later send to restart it.
             with self._lock:
-                self._reporter = None
+                self._load["reporter"] = None
 
     # -- calls ---------------------------------------------------------
     def _share_state_with(self, clone: "DeploymentHandle") -> None:
@@ -637,6 +651,7 @@ class DeploymentHandle:
                     "_state",
                     "_ongoing",
                     "_outstanding_tokens",
+                    "_load",
                     "_batchers",
                     "_listener_box",
                 )

@@ -361,3 +361,36 @@ def test_versioned_heartbeats_elide_unchanged_load(rt_cluster):
         assert info.available.get("special") == 1.0
     finally:
         head.server._handlers["node_heartbeat"] = orig
+
+
+def test_stream_crosses_nodes(rt_cluster):
+    """A stream's items reach a consumer wherever it runs: the
+    producer's node daemon forwards each append to the head, which
+    holds the run, and a consumer's node daemon forwards its one
+    parked request there (no thread to wait on it)."""
+    rt, cluster = rt_cluster
+    cluster.add_node(num_cpus=2, resources={"producer": 2.0})
+    cluster.add_node(num_cpus=2, resources={"consumer": 2.0})
+    cluster.wait_for_nodes(3)
+
+    @rt.remote(num_returns="streaming", resources={"producer": 1.0})
+    def words(n):
+        for i in range(n):
+            time.sleep(0.005)
+            yield f"w{i}"
+        raise ValueError("after the last word")
+
+    def consume(n):
+        got, gen = [], words.remote(n)
+        try:
+            for ref in gen:
+                got.append(rt.get(ref, timeout=30))
+        except ValueError as e:
+            got.append(str(e))
+        return got, gen.stream_items
+
+    expected = [f"w{i}" for i in range(50)] + ["after the last word"]
+    assert consume(50) == (expected, 50)  # the driver, at the head
+    there = rt.remote(consume).options(resources={"consumer": 1.0})
+    got, items = rt.get(there.remote(50), timeout=60)
+    assert (got, items) == (expected, 50)

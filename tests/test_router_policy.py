@@ -120,11 +120,20 @@ def _handle():
     return DeploymentHandle("app", "dep")
 
 
+def _no_stream():
+    """What the runtime hands the router for a stream, with nothing
+    behind it: no cluster, so close() has nobody to tell."""
+    from ray_tpu import ObjectRefGenerator
+    from ray_tpu._private.ids import TaskID
+
+    return ObjectRefGenerator(TaskID.from_random(), count=0)
+
+
 def test_stream_chunks_decay_outstanding_tokens():
     handle = _handle()
     handle._ongoing_sent("r1", 10)
     gen = DeploymentResponseGenerator(
-        iter(()), handle, "r1", tokens=10
+        _no_stream(), handle, "r1", tokens=10
     )
     # Simulate 4 delivered chunks' worth of decay.
     for _ in range(4):
@@ -144,7 +153,7 @@ def test_abandoned_stream_releases_full_estimate():
     handle = _handle()
     handle._ongoing_sent("r1", 464)
     gen = DeploymentResponseGenerator(
-        iter(()), handle, "r1", tokens=464
+        _no_stream(), handle, "r1", tokens=464
     )
     gen.close()  # client disconnected before any chunk
     assert handle._outstanding_tokens.get("r1", 0) == 0

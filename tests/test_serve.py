@@ -359,6 +359,95 @@ def test_interleaved_streams_not_serialized(serve_session):
     assert a_times[0] < b_times[-1], "stream a waited for stream b"
 
 
+def test_cut_streams_leave_no_residue(serve_session):
+    """A replica that served 32 streams at once, half of them cut by
+    their consumers mid-stream, serves the next stream as a fresh
+    session does: nothing of the cut streams outlives them in the
+    head (a parked request, a thread, a run that still takes items),
+    where a deployment overloaded once used to stay slow until it was
+    restarted (PERF.md section 7). The head daemon lives in this
+    process, so its threads are this process's; the pools of the RPC
+    plane keep the workers they grew and are counted out."""
+    rt, serve = serve_session
+
+    @serve.deployment(max_ongoing_requests=40)
+    class Paced:
+        def __serve_cancel_stream__(self, request_id):
+            return True
+
+        def __call__(self, request):
+            for i in range(request["chunks"]):
+                time.sleep(0.01)
+                yield f"t{i} "
+
+    handle = serve.run(Paced.bind(), name="residue", route_prefix=None)
+
+    def open_stream(n):
+        # The budget is what SLO admission counts: 32 streams of it
+        # stay under the default threshold of 1,024 tokens.
+        return handle.options(stream=True).remote(
+            {"chunks": n, "max_new_tokens": 16}
+        )
+
+    def mean_gap_s(n=40):
+        stamps = []
+        for _chunk in open_stream(n):
+            stamps.append(time.perf_counter())
+        assert len(stamps) == n
+        return (stamps[-1] - stamps[0]) / (n - 1)
+
+    def head_threads():
+        from concurrent.futures.thread import _worker as pool_worker
+
+        return {
+            t.ident: t.name for t in threading.enumerate()
+            if getattr(t, "_target", None) is not pool_worker
+        }
+
+    def new_threads():
+        return sorted(
+            name for ident, name in head_threads().items()
+            if ident not in threads_before
+        )
+
+    mean_gap_s(5)  # the first stream pays the routing set-up
+    fresh = mean_gap_s()
+    threads_before = head_threads()
+    runs = rt.api._session.daemon._streams
+
+    def consume(gen, take):
+        for i, _chunk in enumerate(gen):
+            if i + 1 == take:
+                break
+        gen.close()
+
+    gens = [open_stream(60) for _ in range(32)]
+    consumers = [
+        threading.Thread(target=consume, args=(g, 20 if i % 2 else 60))
+        for i, g in enumerate(gens)
+    ]
+    for t in consumers:
+        t.start()
+    for t in consumers:
+        t.join(timeout=120)
+    assert not any(t.is_alive() for t in consumers)
+    # The cut replicas' generators run to their end (this deployment
+    # ignores the cancel): let them, then look at the idle system.
+    deadline = time.time() + 30
+    while time.time() < deadline:
+        with runs._lock:
+            live = [r for r in runs._runs.values() if r.closed_at is None]
+        if not live and not new_threads():
+            break
+        time.sleep(0.2)
+    assert not live, f"{len(live)} runs outlive their streams"
+    with runs._lock:
+        assert not any(r.items or r.parked for r in runs._runs.values())
+    assert not new_threads()
+    after = mean_gap_s()
+    assert after < 2 * fresh, (fresh, after)
+
+
 def test_abandoned_stream_cancels_replica_side(serve_session):
     """Closing a DeploymentResponseGenerator mid-stream propagates a
     best-effort cancel to the replica (Replica.cancel_stream ->
