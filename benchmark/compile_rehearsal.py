@@ -113,7 +113,7 @@ def decode(config: dict, topo) -> dict:
 
     from ray_tpu.models import generate
 
-    from benchmark.drivers.serve_probe import pool_geometry
+    from ray_tpu.llm.kv_slots import default_block_len
     from ray_tpu.models.llama import LlamaConfig, init_params
 
     one = SingleDeviceSharding(topo.devices[0])
@@ -127,7 +127,11 @@ def decode(config: dict, topo) -> dict:
         lambda s: spec(s.shape, s.dtype),
         jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0)),
     )
-    block, width, n_blocks = pool_geometry(engine)
+    # (block length, table width, blocks in the pool) as
+    # `InferenceEngine` derives them from its config
+    block = engine["kv_block_len"] or default_block_len(engine["prefill_chunk"])
+    width = engine["max_len"] // block
+    n_blocks = engine["kv_blocks"] or engine["slots"] * width + 1
     pool_shape = (cfg.n_layers, n_blocks, cfg.n_kv_heads, block, cfg.head_dim)
     pool = {"k": spec(pool_shape, cfg.dtype), "v": spec(pool_shape, cfg.dtype)}
     slots, chunk = engine["slots"], engine["prefill_chunk"]

@@ -11,12 +11,22 @@ bytes of weights it reads): every matmul weight is rounded to int8,
 symmetric, one scale per output channel, and read back in the weights'
 own type. Activations, norms, biases and the embedding lookup stay as
 they are, so this is the mildest 8-bit path: one that also rounds
-activations reads more. The error printed per seed is the relative RMS
-distance of the control's logits from the reference's own, the number
-the probe and the train check compare, beside the configuration's
-limit: a limit holds where the smallest control reading is at least
-three times the largest sound reading of the program and the limit lies
-between them (PERF.md section 2 has both).
+activations reads more. A limit holds where the smallest control
+reading is at least three times the largest sound reading of the
+program and the limit lies between them (PERF.md section 2 has both).
+
+A train configuration's control (this command) reads one seeded
+sequence of the cell's length, all positions and the last: the
+relative RMS distance of the control's logits from the reference's
+own, the number the train check compares. A serve configuration's
+control reads what the run's own comparison reads, over the run's own
+sample of served requests, so it is a flag of the run:
+
+    python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --control
+
+(`drivers/serve_probe.py`: at each position of the same prompts and
+served tokens, the gap of the token this control puts first; the
+notes' `probe.control`, whose `correct` has to be false.)
 """
 
 from __future__ import annotations
@@ -44,22 +54,30 @@ def int8_matrix(w):
 
 def int8_weights(params: dict) -> dict:
     """The parameter tree with every matmul weight through
-    `int8_matrix`: leaves stacked `[layers, in, out]` under `layers`
-    (one layer at a time, so the float32 copy is one layer's) and
-    `lm_head`. The old leaves are donated: two trees of a model that
-    fills half a chip do not fit beside each other."""
+    `int8_matrix`: every leaf under `layers` whose last two axes are a
+    matrix behind one or more leading axes (`[layers, in, out]`, an
+    expert layer's `[layers, experts, in, out]`, the router `[layers,
+    d, E]`), one matrix at a time, so the float32 copy is one matrix's
+    and each expert gets scales of its own; and `lm_head`. The old
+    leaves are donated: two trees of a model that fills half a chip do
+    not fit beside each other."""
+    from functools import partial
+
     import jax
 
-    stacked = jax.jit(
-        lambda w: jax.lax.map(int8_matrix, w), donate_argnums=0
-    )
-    flat = jax.jit(int8_matrix, donate_argnums=0)
+    def mapped(w):
+        rounded = int8_matrix
+        for _ in range(w.ndim - 2):
+            rounded = partial(jax.lax.map, rounded)
+        return rounded(w)
+
+    stacked = jax.jit(mapped, donate_argnums=0)
     out = dict(params)
     out["layers"] = {
-        name: stacked(w) if w.ndim == 3 else w
+        name: stacked(w) if w.ndim >= 3 else w
         for name, w in params["layers"].items()
     }
-    out["lm_head"] = flat(params["lm_head"])
+    out["lm_head"] = stacked(params["lm_head"])
     return out
 
 
@@ -124,12 +142,14 @@ def main() -> int:
     if not args.rehearse and device["platform"] != "tpu":
         print(f"no TPU: JAX reports {device}", file=sys.stderr)
         return 1
-    # The length the cell's own comparison reads: the train check's
-    # sequence, or the probe's longest prompt and its decoded token.
-    seq_len = traffic.get("seq_len") or max(config["probe_lengths"]) + 1
-    for row in control_errors(
-        config, [int(s) for s in args.seeds.split(",")], int(seq_len)
-    ):
+    seeds = [int(x) for x in args.seeds.split(",")]
+    if "engine" in config:
+        print(
+            "a serve configuration's control is read by the run itself: "
+            "benchmark/run.py --workload <cell> --control", file=sys.stderr,
+        )
+        return 2
+    for row in control_errors(config, seeds, int(traffic["seq_len"])):
         print(json.dumps(dict(row, config=args.config, device=device)),
               flush=True)
     return 0

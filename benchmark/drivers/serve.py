@@ -1,14 +1,21 @@
 """Serve cells: HTTP -> proxy -> router -> replica -> engine, the path a
 client of `serve.run(build_llm_app(..))` takes.
 
-Order of a run: the correctness probe (a child that holds the chip and
-exits), then the cluster and one replica that leases the chip, warm-up
-requests until the engine has loaded its weights and compiled its two
-programs, then the lead-in (where the traffic file has one) and the
-window. This process hosts the head daemon and never touches JAX. It
-sends the warm-up itself and stamps nothing that is judged: the load
-of a window comes from `serve_client.py`, a process of its own, which
-hands its records back in a file (`ClientWindow`).
+Order of a run: the cluster and one replica that leases the chip and
+is handed the benchmark's weights (`serve_replica.py`), warm-up
+requests until the engine has loaded them and compiled its programs,
+then the lead-in (where the traffic file has one) and the window; and
+once the window has closed, the replica's peak memory has been read
+and the cluster is shut down (the chip free again), the comparison
+with the reference: a sample of the requests the window finished,
+their served tokens held against the reference's logits in a child
+that holds the chip and exits (`serve_probe.py`). Nothing of it is
+part of `setup_s` (until PR 36 a probe of the program's forwards ran
+first and its 18-26 s, the reference's pass included, were). This
+process hosts the head daemon and never touches JAX. It sends the
+warm-up itself and stamps nothing that is judged: the load of a window
+comes from `serve_client.py`, a process of its own, which hands its
+records back in a file (`ClientWindow`).
 
 The window opens `lead_in_s` after the client's first request is due,
 on an engine that already carries its standing population; `setup_s`
@@ -28,8 +35,8 @@ import sys
 import threading
 import time
 
-from ..harness import ROOT, BenchmarkError, load_module
-from . import serve_client
+from ..harness import ROOT, BenchmarkError, check, load_module
+from . import serve_client, serve_probe
 from .serve_client import DRAIN_S, monotonic, stream_request
 
 APP, ROUTE = "llm", serve_client.ROUTE
@@ -37,24 +44,37 @@ APP, ROUTE = "llm", serve_client.ROUTE
 CLIENT_START_S = 0.25
 
 
-def run_probe(ctx: dict) -> dict:
+def run_probe(ctx: dict, served: list) -> dict:
+    """The reference's pass over `served` (`serve_probe.sample`), in a
+    child that holds the chip; `ctx["control"]` asks for the control's
+    reading beside it (`run.py --control`)."""
     config = ctx["config"]
     spec = {
         "model": config["model"], "dtype": config["dtype"],
-        "reference": config.get("reference"),
-        "engine": config["engine"], "tolerance": config["tolerance"],
-        "probe_lengths": config["probe_lengths"], "seed": ctx["seed"],
+        "reference": config.get("reference"), "engine": config["engine"],
+        "tolerance": config["tolerance"], "served": served,
+        "probe_lengths": config["probe_lengths"],
+        "control": bool(ctx.get("control")), "seed": ctx["seed"],
         "chips": ctx["cell"]["chips"], "rehearse": ctx["rehearse"],
     }
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmark.drivers.serve_probe",
-         json.dumps(spec)],
-        cwd=ROOT, capture_output=True, text=True, timeout=1000,
-    )
-    if proc.returncode != 0:
+    path = os.path.join(ctx["scratch"], "served.json")
+    with open(path, "w") as f:
+        json.dump(spec, f)
+    # The replica that held the chip has just been shut down: where its
+    # process has not let go yet the child finds no chip, and is tried
+    # again.
+    for attempt in range(3):
+        proc = subprocess.run(
+            [sys.executable, "-m", "benchmark.drivers.serve_probe", path],
+            cwd=ROOT, capture_output=True, text=True, timeout=1000,
+        )
+        if proc.returncode == 0:
+            out = json.loads(proc.stdout.strip().splitlines()[-1])
+            return dict(out, attempts=attempt + 1)
         sys.stderr.write(proc.stderr[-4000:])
-        raise BenchmarkError(f"serve probe exited {proc.returncode}")
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+        if attempt < 2:
+            time.sleep(3.0)
+    raise BenchmarkError(f"serve probe exited {proc.returncode}")
 
 
 def deploy(config: dict, seed: int):
@@ -72,12 +92,11 @@ def deploy(config: dict, seed: int):
         ray_actor_options={"num_tpus": 1} if cluster_tpu_chips() else None,
     )(BenchLLMServer)
     family = {
-        "kind": "init", "seed": seed,
+        "kind": "benchmark", "seed": seed,
         "config": dict(config["model"], dtype=config["dtype"]),
     }
     app = dep.bind(
         {config["name"]: family}, default_family=None, engine=engine,
-        engine_enabled=True,
     )
     serve.run(app, name=APP, route_prefix=ROUTE)
     return serve.start(http_port=0)
@@ -311,8 +330,6 @@ def run(ctx: dict) -> dict:
     warm_requests = ctx["generator"].warmup(traffic, ctx["seed"], vocab)
     loop = ctx["generator"].LOOP
     marks = {"start": time.time()}
-    probe = run_probe(ctx)
-    marks["probe_done"] = time.time()
 
     rt.init(num_tpus=ctx["cell"]["chips"] if ctx["rehearse"] else None)
     try:
@@ -326,12 +343,16 @@ def run(ctx: dict) -> dict:
         # it may outlast the router's per-chunk bound, so failed
         # requests are sent again until the engine answers.
         deadline = time.monotonic() + 1000
-        warm = []
+        warm, warm_rounds = [], []
         while True:
             warm = [
                 stream_request(port, r, time.perf_counter, {})
                 for r in warm_requests
             ]
+            warm_rounds.append([
+                [round(r["done_s"] - r["sent_s"], 3), r["status"]]
+                for r in warm
+            ])
             if all(r["ok"] for r in warm):
                 break
             if time.monotonic() > deadline:
@@ -347,6 +368,7 @@ def run(ctx: dict) -> dict:
             all(r["ok"] for r in replays)
             and replays[0]["tokens"] == replays[1]["tokens"]
         )
+        marks["warmed"] = time.time()
         with ClientWindow(
             port, traffic, ctx["seed"], seconds, vocab, ctx["scratch"]
         ) as window:
@@ -355,6 +377,7 @@ def run(ctx: dict) -> dict:
             # Compiles are counted from before the lead-in: a shape the
             # warm-up missed makes the run incorrect there too.
             before = {"probe": replica.probe()}
+            marks["idle"] = time.time()
             window.open()
             marks["window"] = window.epoch
             tracer = None
@@ -390,28 +413,79 @@ def run(ctx: dict) -> dict:
             raise BenchmarkError("could not reduce the replica's trace")
         trace = json.loads(out.stdout.strip().splitlines()[-1])
 
-    in_range = all(
-        0 <= t < vocab for r in records + warm for t in r["tokens"]
+    # `correct`'s comparison with the reference: after the window, on
+    # the chip the replica has given back, outside `setup_s`.
+    marks["closed"] = time.time()
+    served = serve_probe.sample(records, ctx["seed"])
+    for r in records:
+        r.pop("prompt", None)  # the sample has its own
+    if not served:
+        raise BenchmarkError("the window finished no request to compare")
+    probe = run_probe(ctx, served)
+    marks["probe_done"] = time.time()
+
+    grew = {
+        name: count - before["probe"]["compiles"].get(name, 0)
+        for name, count in after["probe"]["compiles"].items()
+        if count > before["probe"]["compiles"].get(name, 0)
+    }
+    compiled = sum(grew.values())
+    lost = [r for r in records if not (r["ok"] or r["cut"])]
+    failed = len(lost)
+    out_of_range = sum(
+        1 for r in records + warm for t in r["tokens"] if not 0 <= t < vocab
     )
-    compiled = sum(after["probe"]["compiles"].values()) - sum(
-        before["probe"]["compiles"].values()
-    )
-    failed = sum(1 for r in records if not (r["ok"] or r["cut"]))
+    worst = probe["requests_rows"][probe["worst_request"]]
+    where = {
+        "served_gap_max": f"{probe['tokens']} served tokens of "
+        f"{probe['requests']} requests, the widest at token "
+        f"{probe['worst_token']} of a request of {worst['n_prompt']} + "
+        f"{worst['n_out']}",
+        "logits_rel_rms": f"prefill {probe['prefill']:.6g}, decode "
+        f"{probe['decode']:.6g}, {len(probe['rows'])} slots",
+        "logits_rel_rms_row": probe["worst_row"],
+    }
+    checks = [
+        check(name, probe[name], limit, where=where[name])
+        for name, limit in probe["limits"].items()
+    ] + [
+        check(
+            "compiles_in_window", compiled, 0,
+            **({"programs": " ".join(sorted(grew))} if grew else {}),
+        ),
+        check("replay_equal", replay_equal, 1, at_least=True),
+        check(
+            "tokens_in_range", out_of_range == 0, 1, at_least=True,
+            **({"out_of_range": out_of_range} if out_of_range else {}),
+        ),
+        check("engine_dead", bool(after["engine"].get("dead")), 0),
+        check(
+            "platform",
+            probe["device"]["platform"] == after["probe"]["platform"], 1,
+            at_least=True, probe=probe["device"]["platform"],
+            replica=after["probe"]["platform"],
+        ),
+    ]
+    correct = all(c["ok"] for c in checks)
+    # not part of `correct`: a failed request fails the run by itself
+    checks.append(check(
+        "failed", failed, 0, **({
+            "first_status": lost[0]["status"],
+            **({"first_error": str(lost[0]["error"])[:120]}
+               if lost[0].get("error") else {}),
+        } if lost else {}),
+    ))
     device = {
         "platform": after["probe"]["platform"],
         "kind": after["probe"]["kind"],
         "count": after["probe"]["count"],
         "memory_peak_bytes": after["probe"]["memory_peak_bytes"],
     }
-    return {
+    run = {
         "kind": "serve",
         "device": device,
-        "correct": bool(
-            probe["correct"] and in_range and compiled == 0
-            and replay_equal
-            and not after["engine"].get("dead")
-            and probe["device"]["platform"] == device["platform"]
-        ),
+        "correct": correct,
+        "checks": checks,
         "attempted": len(records),
         "failed": failed,
         "setup_s": marks["window"] - ctx["started_epoch"],
@@ -428,11 +502,26 @@ def run(ctx: dict) -> dict:
             "replay_equal": replay_equal,
             "statuses": sorted({r["status"] for r in records}),
             "setup_parts_s": {
-                "before_probe": marks["start"] - ctx["started_epoch"],
-                "probe": marks["probe_done"] - marks["start"],
-                "cluster_and_deploy": marks["deployed"] - marks["probe_done"],
+                "before_cluster": marks["start"] - ctx["started_epoch"],
+                "cluster_and_deploy": marks["deployed"] - marks["start"],
                 "load_and_warm": marks["window"] - marks["deployed"],
+                # `load_and_warm` again, by what the driver waited for:
+                # the warm-up's requests (the first loads the weights
+                # and the programs), the replays, the engine idle and
+                # its metrics flushed, the client told to start (with
+                # the lead-in, where the traffic has one)
+                "warm_up": marks["warmed"] - marks["deployed"],
+                "idle_and_flush": marks["idle"] - marks["warmed"],
+                "client_start": marks["window"] - marks["idle"],
             },
+            # seconds and status of each warm-up request, round by round
+            "warm_rounds": warm_rounds,
+            # what the first request spent in the replica before the
+            # engine was built (`serve_replica.build_model`)
+            "replica_load_s": before["probe"]["load_s"],
+            "replica_compile_ms": before["probe"]["compile_ms"],
+            # after the window, no part of `setup_s`
+            "probe_s": marks["probe_done"] - marks["closed"],
             "lead_in_s": window.lead_in_s,
             "window": (
                 dict(
@@ -443,3 +532,14 @@ def run(ctx: dict) -> dict:
             "cut": sum(1 for r in records if r["cut"]),
         },
     }
+    # An untraced run says too what the engine did in its window: where
+    # two runs' throughput differs, whether the hit share or the step did.
+    run["notes"]["engine_window"] = {
+        name: load_module("layer_metrics", name).reduce(run)
+        for name in (
+            "prefix_hit_token_share", "engine_tokens_per_step",
+            "decode_step_mean_ms", "prefill_chunk_mean_ms",
+            "engine_host_share",
+        )
+    }
+    return run

@@ -58,9 +58,11 @@ def stream_request(
         "prompt": request["prompt"],
         "max_new_tokens": request["max_new_tokens"],
     })
+    # `prompt` is the request's own list, kept for the comparison with
+    # the reference (`serve_probe.sample`)
     record.update(
-        n_prompt=len(request["prompt"]), want=request["max_new_tokens"],
-        token_s=[], status=0,
+        prompt=request["prompt"], n_prompt=len(request["prompt"]),
+        want=request["max_new_tokens"], token_s=[], status=0,
     )
     data = b""
     conn = http.client.HTTPConnection(

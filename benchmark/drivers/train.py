@@ -17,7 +17,7 @@ from __future__ import annotations
 import os
 import time
 
-from ..harness import BenchmarkError
+from ..harness import BenchmarkError, check
 from ..stats import median
 
 
@@ -95,11 +95,6 @@ def train_loop(spec: dict) -> None:
     logit_err = compare.relative_rms_error(got_last, want[-1])
     loss_err = abs(float(got_loss) - want_loss)
     del want, on_one, rows
-    tolerance = spec["tolerance"]
-    correct = (
-        logit_err <= tolerance["logits_rel_rms"]
-        and loss_err <= tolerance["loss_abs"]
-    )
 
     # -- warm the one step shape through the real input path
     batches = get_device_batches(
@@ -164,7 +159,6 @@ def train_loop(spec: dict) -> None:
         trace = xplane.summarize(xplane.read(xplane.find_xplane(trace_dir)))
     report({
         "device": device,
-        "correct": bool(correct),
         "reference": reference.__name__,
         "logit_err": logit_err,
         "loss_err": loss_err,
@@ -200,7 +194,7 @@ def run(ctx: dict) -> dict:
     spec = {
         "model": model, "dtype": config["dtype"],
         "reference": config.get("reference"),
-        "trainer": config["trainer"], "tolerance": config["tolerance"],
+        "trainer": config["trainer"],
         "chips": chips, "seed": ctx["seed"], "seconds": ctx["seconds"],
         "batch": stream["batch"], "seq_len": stream["seq_len"],
         "warmup_steps": int(traffic["warmup_steps"]),
@@ -235,12 +229,18 @@ def run(ctx: dict) -> dict:
     if m["pid"] == os.getpid():
         raise BenchmarkError("the train loop ran in the driver process")
     steps = m["steps"]
+    tolerance = config["tolerance"]
+    checks = [
+        check("logits_rel_rms", m["logit_err"], tolerance["logits_rel_rms"]),
+        check("loss_abs", m["loss_err"], tolerance["loss_abs"]),
+        check("finite", m["finite"], 1, at_least=True),
+        check("steady_compiles", m["steady_compiles"], 0),
+    ]
     return {
         "kind": "train",
         "device": dict(m["device"], memory_peak_bytes=m["memory_peak_bytes"]),
-        "correct": (
-            m["correct"] and m["finite"] and m["steady_compiles"] == 0
-        ),
+        "correct": all(c["ok"] for c in checks),
+        "checks": checks,
         "attempted": len(steps),
         "failed": 0 if m["finite"] else len(steps),
         "setup_s": m["window_start_epoch"] - ctx["started_epoch"],

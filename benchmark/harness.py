@@ -72,14 +72,16 @@ def load_config(manifest: dict, name: str, root: str = ROOT) -> dict:
     raise BenchmarkError(f"no config {name!r} in BENCHMARK.json")
 
 
-def load_traffic(name: str) -> dict:
-    return load_json(os.path.join(HERE, "traffic", f"{name}.json"))
+def load_traffic(name: str, root: str = ROOT) -> dict:
+    return load_json(
+        os.path.join(root, "benchmark", "traffic", f"{name}.json")
+    )
 
 
-def load_module(directory: str, name: str):
+def load_module(directory: str, name: str, root: str = ROOT):
     """Import benchmark/<directory>/<name>.py by path (names may hold
     characters an import statement cannot)."""
-    path = os.path.join(HERE, directory, f"{name}.py")
+    path = os.path.join(root, "benchmark", directory, f"{name}.py")
     if not os.path.exists(path):
         raise BenchmarkError(f"no file {path}")
     spec = importlib.util.spec_from_file_location(
@@ -142,8 +144,48 @@ def apply_rehearsal(settings: dict) -> dict:
     return out
 
 
+def check(name: str, value, limit, at_least: bool = False, **detail) -> dict:
+    """One comparison `correct` (or the exit code) rests on: a reading
+    beside its limit. Sound where `value <= limit`, or `>=` for a
+    reading that has to reach its limit (`at_least`). `detail` says
+    where the reading was taken (a row, a program's name, a status)."""
+    value, limit = float(value), float(limit)
+    ok = value >= limit if at_least else value <= limit
+    return dict(name=name, value=value, limit=limit, ok=bool(ok), **detail)
+
+
+def check_lines(checks: List[dict]) -> List[str]:
+    """What standard error ends on: every check with its reading and
+    its limit, then one `failed:` line for each that failed."""
+    def words(c: dict) -> str:
+        detail = ", ".join(
+            f"{k} {v}" for k, v in c.items()
+            if k not in ("name", "value", "limit", "ok")
+        )
+        return f"{c['name']} {c['value']:.6g} against {c['limit']:.6g}" + (
+            f" ({detail})" if detail else ""
+        )
+
+    lines = [
+        f"[benchmark] check: {words(c)}: {'ok' if c['ok'] else 'FAILED'}"
+        for c in checks
+    ]
+    return lines + [
+        f"[benchmark] failed: {words(c)}" for c in checks if not c["ok"]
+    ]
+
+
+def checks_of(run: dict) -> Dict[str, dict]:
+    """{name: reading, limit, ok, where} for the result line."""
+    return {
+        c["name"]: {k: v for k, v in c.items() if k != "name"}
+        for c in run.get("checks") or []
+    }
+
+
 def result_line(run: dict, metrics: Dict[str, dict]) -> str:
-    """The contract's last line of standard output."""
+    """The contract's last line of standard output; the numbers
+    `correct` compared, each beside its limit, come last in it."""
     line: Dict[str, Any] = {
         "correct": bool(run["correct"]),
         "attempted": int(run["attempted"]),
@@ -153,4 +195,5 @@ def result_line(run: dict, metrics: Dict[str, dict]) -> str:
     }
     if run.get("breakdown"):
         line["breakdown"] = run["breakdown"]
+    line["checks"] = checks_of(run)
     return json.dumps(line)

@@ -12,11 +12,13 @@ import jax.numpy as jnp
 DEFAULT = "llama_ref"
 
 
-def load(name=None):
-    """The reference module a configuration names, found by file."""
-    from benchmark.harness import BenchmarkError, load_module
+def load(name=None, root=None):
+    """The reference module a configuration names, found by file
+    (under `root`'s benchmark/ where a checkout other than this one is
+    looked at)."""
+    from benchmark.harness import ROOT, BenchmarkError, load_module
 
-    module = load_module("reference", name or DEFAULT)
+    module = load_module("reference", name or DEFAULT, root or ROOT)
     if not callable(getattr(module, "forward", None)):
         raise BenchmarkError(f"reference {name!r} has no forward()")
     return module
@@ -40,3 +42,26 @@ def relative_rms_error(got, want) -> float:
         np.sqrt(np.mean((got - want) ** 2))
         / max(float(np.sqrt(np.mean(want ** 2))), 1e-30)
     )
+
+
+def squared_sums(got, want, axis=None) -> tuple:
+    """(sum of (got - want)**2, sum of want**2) in float32, computed
+    where the arrays are: the parts a relative RMS error over several
+    blocks of rows is pooled from (`pooled`). Two floats, or with
+    `axis` two lists, one entry per row that is left."""
+    got = jnp.asarray(got, jnp.float32)
+    want = jnp.asarray(want, jnp.float32)
+    diff, ref = jax.device_get((
+        jnp.sum(jnp.square(got - want), axis=axis),
+        jnp.sum(jnp.square(want), axis=axis),
+    ))
+    return diff.tolist(), ref.tolist()
+
+
+def pooled(sums) -> float:
+    """ONE relative RMS error over everything `squared_sums` was taken
+    of: sqrt(sum of squared differences / sum of squared reference).
+    Of one pair it is `relative_rms_error`."""
+    diff = sum(d for d, _ in sums)
+    ref = sum(w for _, w in sums)
+    return float((diff / max(ref, 1e-60)) ** 0.5)

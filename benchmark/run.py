@@ -44,6 +44,13 @@ def main() -> int:
         "(one point of the knee; each rate in a process of its own, since "
         "windows above the knee leave a serve process slow)",
     )
+    parser.add_argument(
+        "--control", action="store_true",
+        help="serve cells: also read the control (benchmark/control.py's "
+        "int8 weights under the reference) over the run's own sample of "
+        "served requests; how a limit's upper reading is taken, no part "
+        "of a run",
+    )
     args = parser.parse_args()
 
     manifest = harness.load_manifest()
@@ -86,6 +93,7 @@ def main() -> int:
             "generator": generator, "seed": args.seed, "seconds": seconds,
             "trace": bool(args.trace), "rehearse": args.rehearse,
             "started_epoch": STARTED, "scratch": scratch,
+            "control": args.control,
         })
     finally:
         shutil.rmtree(scratch, ignore_errors=True)
@@ -104,6 +112,10 @@ def main() -> int:
         manifest, section, directory, run, rehearse=args.rehearse
     )
     print("[benchmark] notes " + json.dumps(run.get("notes", {})), flush=True)
+    # The exit code says only that something failed; these lines, the
+    # last on standard error, and `checks` in the last line of standard
+    # output say what, with each reading beside its limit.
+    verdict = "\n".join(harness.check_lines(run["checks"]))
     if args.rehearse:
         # A CPU walk-through proves paths and counts; it never prints a
         # number under the name of a device metric.
@@ -112,8 +124,10 @@ def main() -> int:
             "rehearsal": True, "correct": bool(run["correct"]),
             "attempted": run["attempted"], "failed": run["failed"],
             "metric_names": sorted(metrics), "device": run["device"],
+            "checks": harness.checks_of(run),
         }))
-        return 0 if run["correct"] else 1
+        print(verdict, file=sys.stderr, flush=True)
+        return 0 if run["correct"] and not run["failed"] else 1
     trace = run.get("trace") or {}
     if args.trace:
         if not trace.get("busy_s"):
@@ -130,7 +144,8 @@ def main() -> int:
         harness.metrics_of_cell(manifest, "end_to_end", cell["name"])
     ):
         raise harness.BenchmarkError(f"metrics missing: got {sorted(metrics)}")
-    print(harness.result_line(run, metrics))
+    print(harness.result_line(run, metrics), flush=True)
+    print(verdict, file=sys.stderr, flush=True)
     return 0 if run["correct"] and not run["failed"] else 1
 
 

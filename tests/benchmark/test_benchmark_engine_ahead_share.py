@@ -1,9 +1,10 @@
 """The reader of the engine's dispatch-ahead counters (PR 27), on a
 hand-made `run`; on the `run` of a program without them (the parent of
-PR 27) it gives nothing, and no exception. And its two entries in the
-manifest."""
+PR 27) it gives nothing, and no exception. (Its entries in the
+manifest are checked with every other per-layer entry,
+`test_benchmark_yardstick.py`
+`test_layer_reader_agrees_with_the_manifest`.)"""
 
-import json
 import os
 import sys
 
@@ -52,24 +53,3 @@ def test_ahead_share_is_programs_ahead_over_programs_in_the_window():
 ], ids=["train", "no-engine", "parent", "one-key", "idle"])
 def test_ahead_share_gives_nothing_where_there_is_nothing(run):
     assert read(run) is None
-
-
-@pytest.mark.parametrize("tag,moves,cells", [
-    ("itl", "itl_mean_ms", ["chat_loaded"]),
-    ("tput", "serve_tokens_per_s", ["docqa_closed", "doc_score_moe"]),
-])
-def test_the_manifest_lists_the_reader_in_the_serve_cells(tag, moves, cells):
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        manifest = json.load(f)
-    [entry] = [
-        m for m in manifest["per_layer"]
-        if m["name"] == f"engine_ahead_share.{tag}"
-    ]
-    module = harness.load_module(
-        "layer_metrics", harness.reader_name(entry["name"])
-    )
-    assert entry == {
-        "name": f"engine_ahead_share.{tag}", "unit": module.UNIT,
-        "better": "higher", "source": module.SOURCE,
-        "layer": module.LAYER, "moves": moves, "workloads": cells,
-    }

@@ -48,19 +48,3 @@ def test_kv_read_amplification_is_keys_read_over_keys_live():
 ], ids=["train", "no-engine", "parent", "idle"])
 def test_kv_read_amplification_gives_nothing_where_there_is_nothing(run):
     assert read(run) is None
-
-
-def test_manifest_reports_it_in_both_serve_cells():
-    manifest = harness.load_manifest()
-    entries = {
-        m["name"]: m for m in manifest["per_layer"]
-        if m["name"].startswith("kv_read_amplification")
-    }
-    assert {n: (m["moves"], m["workloads"]) for n, m in entries.items()} == {
-        "kv_read_amplification.itl": ("itl_mean_ms", ["chat_loaded"]),
-        "kv_read_amplification.tput": ("serve_tokens_per_s", ["docqa_closed"]),
-    }
-    for m in entries.values():
-        assert (m["layer"], m["unit"], m["better"], m["source"]) == (
-            "serve forwards", "x", "lower", "program_counter"
-        )

@@ -16,6 +16,9 @@ if ROOT not in sys.path:
 
 from benchmark import harness  # noqa: E402
 
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import manifest_checks as checks  # noqa: E402  (this directory)
+
 MANIFEST = harness.load_manifest()
 
 
@@ -34,18 +37,7 @@ def _run(root, *args, timeout=300):
     )
 
 
-def _checkout(tmp_path):
-    """A copy that holds what the benchmark owns plus a link to the
-    program: what a later PR's checkout looks like to run.py."""
-    root = str(tmp_path / "checkout")
-    os.makedirs(root)
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    shutil.copytree(
-        os.path.join(ROOT, "benchmark"), os.path.join(root, "benchmark"),
-        ignore=shutil.ignore_patterns("__pycache__"),
-    )
-    os.symlink(os.path.join(ROOT, "ray_tpu"), os.path.join(root, "ray_tpu"))
-    return root
+_checkout = checks.checkout
 
 
 @pytest.mark.timeout(400)
@@ -97,8 +89,7 @@ def test_a_fifth_cell_is_three_new_files_and_one_entry(tmp_path):
     for metric in manifest["end_to_end"]:
         if metric["name"] == "train_tokens_per_s_chip":
             metric["workloads"].append("dummy_cell")
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(manifest, f)
+    checks.write_manifest(root, manifest)
     proc = _run(root, "--workload", "dummy_cell", "--rehearse", "--trace", "1")
     assert proc.returncode == 0, proc.stderr[-3000:]
     line = json.loads(proc.stdout.strip().splitlines()[-1])
@@ -117,33 +108,9 @@ def test_a_configuration_names_its_reference_and_the_driver_calls_it(base, cell,
     its file names `reference/<module>.py`, and the probe (serve) or
     the train check decides `correct` against that module."""
     root = _checkout(tmp_path)
-    bench = os.path.join(root, "benchmark")
     mark = str(tmp_path / "stub_ref.called")
-    with open(os.path.join(bench, "reference", "stub_ref.py"), "w") as f:
-        f.write(
-            "from benchmark.reference import llama_ref\n"
-            "def forward(params, tokens, model):\n"
-            f"    with open({mark!r}, 'a') as f:\n"
-            "        f.write(f'{tokens.shape[0]}\\n')\n"
-            "    return llama_ref.forward(params, tokens, model)\n"
-        )
-    config = dict(harness.load_config(MANIFEST, base), name="stub-model", reference="stub_ref")
-    with open(os.path.join(bench, "configs", "stub-model.json"), "w") as f:
-        json.dump(config, f)
-    manifest = json.loads(json.dumps(MANIFEST))
-    entry = next(c for c in manifest["configs"] if c["name"] == base)
-    manifest["configs"].append(dict(entry, name="stub-model", file="benchmark/configs/stub-model.json"))
-    traffic = harness.find_cell(MANIFEST, cell)["traffic"]
-    manifest["workloads"].append({
-        "name": "stub_cell", "config": "stub-model", "traffic": traffic,
-        "chips": 1, "why": "stub",
-    })
-    for section in ("end_to_end", "per_layer"):
-        for metric in manifest[section]:
-            if cell in metric.get("workloads", ()):
-                metric["workloads"].append("stub_cell")
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(manifest, f)
+    checks.grow(root, base, cell, mark)
+    config = harness.load_json(os.path.join(root, "benchmark", "configs", "stub-model.json"))
     proc = _run(root, "--workload", "stub_cell", "--seed", str(seed), "--rehearse")
     assert proc.returncode == 0, proc.stderr[-3000:]
     lines = proc.stdout.strip().splitlines()
@@ -152,8 +119,99 @@ def test_a_configuration_names_its_reference_and_the_driver_calls_it(base, cell,
     assert (notes.get("probe") or notes)["reference"] == "benchmark.reference.stub_ref"
     with open(mark) as f:
         calls = f.read().split()
-    # the probe compares one sequence per probe length, the train check one
-    assert len(calls) == (len(harness.apply_rehearsal(config)["probe_lengths"]) if "engine" in config else 1)
+    # the serve comparison runs the reference once a sampled request and
+    # once a distinct row of the probe, each padded to a multiple of a
+    # quarter of max_len; the train check once
+    small = harness.apply_rehearsal(config)
+    if "engine" in config:
+        from benchmark.drivers.serve_probe import SAMPLE_REQUESTS
+
+        engine = small["engine"]
+        assert len(small["probe_lengths"]) < len(calls) <= SAMPLE_REQUESTS + engine["slots"]
+        assert all(int(c) % (engine["max_len"] // 4) == 0 for c in calls)
+    else:
+        assert len(calls) == 1
+
+
+def _last_lines(proc):
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert list(line)[-1] == "checks"
+    return line, proc.stderr.strip().splitlines()
+
+
+OTHER_CHECKS = [
+    "compiles_in_window", "replay_equal", "tokens_in_range", "engine_dead",
+    "platform", "failed",
+]
+
+
+@pytest.mark.timeout(400)
+def test_a_run_made_incorrect_names_the_check_on_its_last_line_and_on_standard_error(tmp_path):
+    """A limit under the reading in a scratch configuration: the run
+    exits 1, and both the last line of standard output (where the
+    driver's ledger looks) and the last lines of standard error say
+    which number of the comparison it was, with the reading beside the
+    limit; a sound run carries the same keys (the other tests of this
+    file read its last line)."""
+    root = _checkout(tmp_path)
+    path = os.path.join(root, "benchmark", "configs", "qwen2.5-3b.json")
+    config = harness.load_json(path)
+    config["rehearsal"]["tolerance"]["logits_rel_rms"] = 0.0
+    with open(path, "w") as f:
+        json.dump(config, f)
+    proc = _run(root, "--workload", "docqa_closed", "--seed", "3", "--rehearse")
+    assert proc.returncode == 1
+    line, errors = _last_lines(proc)
+    assert line["correct"] is False and line["failed"] == 0
+    worst = line["checks"]["logits_rel_rms"]
+    assert worst["ok"] is False and worst["limit"] == 0.0 and 0 < worst["value"] < 1e-4
+    assert worst["where"].startswith("prefill ") and "decode " in worst["where"]
+    others = {k: v["ok"] for k, v in line["checks"].items() if k != "logits_rel_rms"}
+    assert others == dict.fromkeys(
+        ["served_gap_max", "logits_rel_rms_row"] + OTHER_CHECKS, True
+    )
+    assert errors[-1].startswith("[benchmark] failed: logits_rel_rms ")
+    assert f"{worst['value']:.6g} against 0" in errors[-1]
+    assert sum(x.startswith("[benchmark] check: ") for x in errors[-10:]) == 9
+
+
+ALTERED_TOKEN = '''
+
+_sound_call = BenchLLMServer.__call__
+
+
+def _altered(self, request):
+    """The timed path broken underneath: every stream's second token is
+    altered where it is produced."""
+    for i, chunk in enumerate(_sound_call(self, request)):
+        yield b"%d " % ((int(chunk) + 1) % 512) if i == 1 else chunk
+
+
+BenchLLMServer.__call__ = _altered
+'''
+
+
+@pytest.mark.timeout(400)
+def test_a_token_altered_where_it_is_produced_makes_the_run_incorrect(tmp_path):
+    """The whole of a run but the look for a chip, with the replica of
+    a scratch checkout altering one token a stream: `correct` comes out
+    false by the comparison with the reference and by nothing else."""
+    root = _checkout(tmp_path)
+    with open(os.path.join(root, "benchmark", "drivers", "serve_replica.py"), "a") as f:
+        f.write(ALTERED_TOKEN)
+    proc = _run(root, "--workload", "docqa_closed", "--seed", "4", "--rehearse")
+    assert proc.returncode == 1
+    line, errors = _last_lines(proc)
+    assert line["correct"] is False and line["failed"] == 0
+    verdicts = {k: v["ok"] for k, v in line["checks"].items()}
+    assert verdicts == {
+        "served_gap_max": False,
+        **dict.fromkeys(["logits_rel_rms", "logits_rel_rms_row"] + OTHER_CHECKS, True),
+    }
+    # an altered token lies deviations under the reference's best
+    assert line["checks"]["served_gap_max"]["value"] > 0.5
+    assert "the widest at token 1 of" in line["checks"]["served_gap_max"]["where"]
+    assert errors[-1].startswith("[benchmark] failed: served_gap_max ")
 
 
 def test_without_a_tpu_the_command_fails_and_prints_no_result(tmp_path):

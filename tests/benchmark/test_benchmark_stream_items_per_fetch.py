@@ -1,9 +1,10 @@
 """The reader of the streaming transport's two counters (PR 34), on
 the `engine_timers` a run recorded; on the run of a program without
-them (the parent of PR 34) it gives nothing, and no exception. And its
-two entries in the manifest."""
+them (the parent of PR 34) it gives nothing, and no exception. (Its
+entries in the manifest are checked with every other per-layer entry,
+`test_benchmark_yardstick.py`
+`test_layer_reader_agrees_with_the_manifest`.)"""
 
-import json
 import os
 import sys
 
@@ -84,25 +85,3 @@ def test_items_per_fetch_of_the_recorded_run():
 ], ids=["train", "no-timers", "parent", "one-series", "idle"])
 def test_items_per_fetch_gives_nothing_where_there_is_nothing(run):
     assert read(run) is None
-
-
-@pytest.mark.parametrize("tag,moves,cells", [
-    ("itl", "itl_mean_ms", ["chat_loaded"]),
-    ("tput", "serve_tokens_per_s", ["docqa_closed", "doc_score_moe"]),
-])
-def test_the_manifest_lists_the_reader_in_the_serve_cells(tag, moves, cells):
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        manifest = json.load(f)
-    [entry] = [
-        m for m in manifest["per_layer"]
-        if m["name"] == f"stream_items_per_fetch.{tag}"
-    ]
-    module = harness.load_module(
-        "layer_metrics", harness.reader_name(entry["name"])
-    )
-    assert entry == {
-        "name": f"stream_items_per_fetch.{tag}", "unit": module.UNIT,
-        "better": "lower", "source": module.SOURCE,
-        "layer": module.LAYER, "moves": moves, "workloads": cells,
-    }
-    assert manifest["per_layer"][-2:][("itl", "tput").index(tag)] == entry

@@ -3,12 +3,8 @@ CPU at tiny sizes: the manifest is legal, every cell's files are found
 by name, the generators repeat, the arithmetic is right, and a fifth
 cell can be added as new files plus one entry."""
 
-import importlib.util
 import json
 import os
-import re
-import shutil
-import subprocess
 import sys
 
 import numpy as np
@@ -22,128 +18,110 @@ from benchmark import flops, harness, stats  # noqa: E402
 from benchmark.traffic import lengths, serve_closed, serve_open, train_stream  # noqa: E402
 from benchmark.trace import xplane  # noqa: E402
 
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import manifest_checks as checks  # noqa: E402  (this directory)
+
 MANIFEST = harness.load_manifest()
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-CELLS = [c["name"] for c in MANIFEST["workloads"]]
-E2E = [m["name"] for m in MANIFEST["end_to_end"]]
-LAYER = [m["name"] for m in MANIFEST["per_layer"]]
-SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+CELLS = checks.names(MANIFEST, "workloads")
+E2E = checks.names(MANIFEST, "end_to_end")
+LAYER = checks.names(MANIFEST, "per_layer")
 
 
 # -- the manifest -----------------------------------------------------
+# Each check is a function of a manifest in `manifest_checks.py`; here
+# the repo's own goes through them case by case, and further down a
+# grown copy goes through all of them.
 
 def test_manifest_has_exactly_the_contract_keys():
-    assert set(MANIFEST) == {
-        "command", "paths", "run_seconds", "configs", "workloads",
-        "end_to_end", "per_layer",
-    }
-    assert isinstance(MANIFEST["run_seconds"], int)
-    assert 1 <= MANIFEST["run_seconds"] <= 51
-    assert len(json.dumps(MANIFEST)) < 64 * 1024
-    assert all(
-        not w.startswith("/") and ".." not in w for w in MANIFEST["command"]
-    )
+    checks.contract_keys(MANIFEST)
 
 
 def test_manifest_run_budget_fits_with_24_cells():
-    s = MANIFEST["run_seconds"]
-    assert (2 + 14 * 24) * (s + 60) + 24 * 180 + 1200 <= 43200
+    checks.run_budget(MANIFEST)
 
 
 def test_four_chip_cells_are_at_most_a_quarter_or_one():
-    four = [c for c in MANIFEST["workloads"] if c["chips"] == 4]
-    assert all(c["chips"] in (1, 4) for c in MANIFEST["workloads"])
-    assert len(four) <= max(1, len(CELLS) // 4)
+    checks.four_chip_share(MANIFEST)
 
 
 @pytest.mark.parametrize("section", ["configs", "workloads", "end_to_end", "per_layer"])
 def test_names_units_and_lines_are_legal(section):
-    entries = MANIFEST[section]
-    names = [e["name"] for e in entries]
-    assert len(names) == len(set(names))
-    for e in entries:
-        assert NAME.match(e["name"]), e["name"]
-        for key in ("why", "layer"):
-            if key in e:
-                assert 1 <= len(e[key]) <= 200
-                assert "\n" not in e[key] and "\t" not in e[key]
-        if "unit" in e:
-            assert UNIT.match(e["unit"]), e["unit"]
-            assert e["better"] in ("lower", "higher")
-            assert e["source"] in SOURCES
-    if section == "end_to_end":
-        assert "setup_s" in names
-        for e in entries:
-            assert set(e) <= {"name", "unit", "better", "bound", "source", "workloads"}
-            assert 0.01 <= e["bound"] <= 0.1
-            assert e["source"] in ("host_clock", "device_trace")
-    if section == "per_layer":
-        for e in entries:
-            assert set(e) <= {"name", "unit", "better", "source", "layer", "moves", "workloads"}
-            assert e["moves"] in E2E
-    if section == "workloads":
-        pairs = [(e["config"], e["traffic"]) for e in entries]
-        assert len(pairs) == len(set(pairs))
-        for e in entries:
-            assert set(e) == {"name", "config", "traffic", "chips", "why"}
-            assert NAME.match(e["traffic"]) and NAME.match(e["config"])
-    if section == "configs":
-        used = {c["config"] for c in MANIFEST["workloads"]}
-        files = [e["file"] for e in entries]
-        assert len(files) == len(set(files))
-        for e in entries:
-            assert set(e) == {"name", "source", "file", "reduced", "why"}
-            assert 1 <= len(e["source"]) <= 200
-            assert e["name"] in used
-            assert any(e["file"].startswith(p + "/") for p in MANIFEST["paths"])
-            assert all(NAME.match(k) for k in e["reduced"])
+    checks.section_is_legal(MANIFEST, section)
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_every_cell_reports_setup_another_end_to_end_and_a_layer_metric(cell):
-    e2e = [m["name"] for m in harness.metrics_of_cell(MANIFEST, "end_to_end", cell)]
-    assert "setup_s" in e2e and len(e2e) >= 2
-    per_layer = harness.metrics_of_cell(MANIFEST, "per_layer", cell)
-    assert per_layer
-    # a per-layer metric is reported only where the metric it moves is
-    assert all(m["moves"] in e2e for m in per_layer)
+    checks.cell_reports(MANIFEST, cell)
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_every_cell_finds_its_files_by_name(cell):
-    entry = harness.find_cell(MANIFEST, cell)
-    config = harness.load_config(MANIFEST, entry["config"])
-    traffic = harness.load_traffic(entry["traffic"])
-    generator = harness.load_module("traffic", traffic["kind"])
-    driver = harness.load_module("drivers", generator.DRIVER)
-    assert callable(generator.generate) and callable(driver.run)
-    assert config["name"] == entry["config"]
-    assert ("trainer" in config) == (generator.DRIVER == "train")
-    assert ("engine" in config) == (generator.DRIVER == "serve")
+    checks.cell_finds_its_files(MANIFEST, cell)
 
 
 @pytest.mark.parametrize("metric", E2E)
 def test_end_to_end_reader_exists(metric):
-    assert callable(harness.load_module("end_to_end", metric).reduce)
+    checks.end_to_end_reader_exists(MANIFEST, metric)
 
 
 @pytest.mark.parametrize("metric", LAYER)
 def test_layer_reader_agrees_with_the_manifest(metric):
-    entry = next(m for m in MANIFEST["per_layer"] if m["name"] == metric)
-    module = harness.load_module("layer_metrics", harness.reader_name(metric))
-    assert callable(module.reduce)
-    assert (module.LAYER, module.UNIT, module.SOURCE) == (
-        entry["layer"], entry["unit"], entry["source"]
-    )
-    assert set(entry["workloads"]) <= set(CELLS)
+    """Every per-layer entry against its reader file and against what
+    it held before (`manifest_checks.HELD`): its `moves`, `better`,
+    `unit`, `source` and `layer`, and the cells it listed, which may
+    have others beside them now. No entry is held to a place."""
+    checks.layer_entry_agrees_with_its_reader(MANIFEST, metric)
+
+
+def test_every_entry_of_today_is_held_and_a_list_that_shrinks_is_caught():
+    assert set(checks.HELD) <= set(LAYER)
+    shrunk = json.loads(json.dumps(MANIFEST))
+    entry = next(m for m in shrunk["per_layer"] if m["name"] == "kv_read_amplification.tput")
+    entry["workloads"].remove("doc_score_moe")
+    with pytest.raises(AssertionError):
+        checks.layer_entry_agrees_with_its_reader(shrunk, entry["name"])
+    entry["workloads"] = ["doc_score_moe", "docqa_closed"]  # order is free
+    checks.layer_entry_agrees_with_its_reader(shrunk, entry["name"])
+    entry["moves"] = "itl_mean_ms"
+    with pytest.raises(AssertionError):
+        checks.layer_entry_agrees_with_its_reader(shrunk, entry["name"])
 
 
 def test_layer_names_are_the_ones_perf_md_lists():
-    with open(os.path.join(ROOT, "PERF.md")) as f:
-        text = f.read()
-    for layer in {m["layer"] for m in MANIFEST["per_layer"]}:
-        assert f"| {layer} |" in text, layer
+    checks.layer_names_are_in_perf_md(MANIFEST)
+
+
+@pytest.mark.parametrize("name", checks.names(MANIFEST, "configs"))
+def test_config_file_agrees_with_its_entry(name):
+    checks.config_agrees_with_its_entry(MANIFEST, name)
+
+
+def test_a_grown_copy_passes_every_manifest_check(tmp_path):
+    """`benchmark/README.md`, "Adding a cell (no edit to any file
+    here)": a copy grown as a `model_config` PR grows it (a
+    configuration, a cell, two per-layer entries at the END, the cell's
+    name at the end of every list it reports) goes through every check
+    this directory makes of a manifest, and `manifest_diff` finds
+    additions only."""
+    from benchmark import manifest_diff
+
+    root = checks.checkout(tmp_path)
+    grown = checks.grow(root, "qwen2.5-3b", "docqa_closed")
+    assert checks.walk(MANIFEST) == 4 + 4 + 2 * len(CELLS) + len(E2E) + len(LAYER) + 2 * 4
+    assert checks.walk(grown, root) == checks.walk(MANIFEST) + 2 + 2 + 2
+    for name in ("stream_items_per_fetch.tput", "engine_ahead_share.tput",
+                 "kv_read_amplification.tput", "serve_tokens_per_s"):
+        section = "end_to_end" if name == "serve_tokens_per_s" else "per_layer"
+        entry = next(m for m in grown[section] if m["name"] == name)
+        assert entry["workloads"][-1] == "stub_cell"
+    assert checks.names(grown, "per_layer")[-2:] == ["stub_requests", "stub_steps"]
+    appended, problems = manifest_diff.diff(MANIFEST, grown)
+    assert problems == [] and "workloads + stub_cell" in appended
+    # and a check fails where the grown copy is wrong: the new cell's
+    # entry names a reader that another layer owns
+    grown["per_layer"][-1]["layer"] = "client"
+    with pytest.raises(AssertionError):
+        checks.walk(grown, root)
 
 
 # -- configurations ---------------------------------------------------
@@ -493,8 +471,8 @@ def test_closed_loop_callers_stop_at_the_windows_edge(token_server):
 # -- the load generator's process -------------------------------------
 
 RECORD_KEYS = {
-    "due_s", "sent_s", "done_s", "token_s", "tokens", "n_prompt", "n_out",
-    "want", "status", "ok", "cut",
+    "due_s", "sent_s", "done_s", "token_s", "tokens", "prompt", "n_prompt",
+    "n_out", "want", "status", "ok", "cut",
 }
 
 
@@ -570,9 +548,10 @@ def test_client_process_drives_a_closed_list_to_the_edge(token_server, tmp_path)
     stream = serve_closed.generate(traffic, 11, seconds, 1000)["requests"]
     for r, request in zip(rows, stream):  # taken in the list's order
         assert set(r) - {"first_s", "error"} == RECORD_KEYS | {"shared_tokens"}
-        assert (r["n_prompt"], r["want"], r["shared_tokens"]) == (
-            len(request["prompt"]), request["max_new_tokens"], request["shared_tokens"]
-        )
+        assert (r["prompt"], r["n_prompt"], r["want"], r["shared_tokens"]) == (
+            request["prompt"], len(request["prompt"]), request["max_new_tokens"],
+            request["shared_tokens"],
+        )  # the prompt itself: the served tokens are compared over it
         assert r["ok"] or r["cut"]
         assert 0 <= r["due_s"] == r["sent_s"] < seconds
     assert sum(r["cut"] for r in rows) <= 3
@@ -663,13 +642,7 @@ def test_xplane_reads_a_profile_written_here(tmp_path):
 
 @pytest.mark.parametrize("name", [c["name"] for c in MANIFEST["configs"]])
 def test_every_configuration_resolves_to_a_reference_with_forward(name):
-    from benchmark.reference import compare
-
-    config = harness.load_config(MANIFEST, name)
-    module = compare.load(config.get("reference"))
-    assert callable(module.forward)
-    stem = config.get("reference", compare.DEFAULT)
-    assert module.__file__ == os.path.join(ROOT, "benchmark", "reference", f"{stem}.py")
+    checks.config_resolves_to_a_reference(MANIFEST, name)
 
 
 def test_a_reference_that_is_not_there_or_has_no_forward_is_refused():
