@@ -54,13 +54,16 @@ def int8_matrix(w):
 
 def int8_weights(params: dict) -> dict:
     """The parameter tree with every matmul weight through
-    `int8_matrix`: every leaf under `layers` whose last two axes are a
-    matrix behind one or more leading axes (`[layers, in, out]`, an
-    expert layer's `[layers, experts, in, out]`, the router `[layers,
-    d, E]`), one matrix at a time, so the float32 copy is one matrix's
-    and each expert gets scales of its own; and `lm_head`. The old
-    leaves are donated: two trees of a model that fills half a chip do
-    not fit beside each other."""
+    `int8_matrix`: every leaf under a parent (`layers`, and whatever
+    other stacks or groups a configuration's plan has, however deep)
+    whose last two axes are a matrix behind one or more leading axes
+    (`[layers, in, out]`, an expert layer's `[layers, experts, in,
+    out]`, the router `[layers, d, E]`), one matrix at a time, so the
+    float32 copy is one matrix's and each expert gets scales of its
+    own; and `lm_head`. A matrix that stands alone under a parent is
+    rounded where its plan stacks it (`[1, in, out]`). The old leaves
+    are donated: two trees of a model that fills half a chip do not fit
+    beside each other."""
     from functools import partial
 
     import jax
@@ -72,10 +75,10 @@ def int8_weights(params: dict) -> dict:
         return rounded(w)
 
     stacked = jax.jit(mapped, donate_argnums=0)
-    out = dict(params)
-    out["layers"] = {
-        name: stacked(w) if w.ndim >= 3 else w
-        for name, w in params["layers"].items()
+    out = {
+        name: jax.tree.map(lambda w: stacked(w) if w.ndim >= 3 else w, group)
+        if isinstance(group, dict) else group
+        for name, group in params.items()
     }
     out["lm_head"] = stacked(params["lm_head"])
     return out

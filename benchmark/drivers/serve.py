@@ -61,8 +61,9 @@ def run_probe(ctx: dict, served: list) -> dict:
     with open(path, "w") as f:
         json.dump(spec, f)
     # The replica that held the chip has just been shut down: where its
-    # process has not let go yet the child finds no chip, and is tried
-    # again.
+    # process has not let go yet the child finds no chip (exit code 1),
+    # and is tried again. Exit code 2 is a comparison that could not be
+    # made, and its last line says why.
     for attempt in range(3):
         proc = subprocess.run(
             [sys.executable, "-m", "benchmark.drivers.serve_probe", path],
@@ -72,9 +73,12 @@ def run_probe(ctx: dict, served: list) -> dict:
             out = json.loads(proc.stdout.strip().splitlines()[-1])
             return dict(out, attempts=attempt + 1)
         sys.stderr.write(proc.stderr[-4000:])
+        if proc.returncode == 2:
+            break
         if attempt < 2:
             time.sleep(3.0)
-    raise BenchmarkError(f"serve probe exited {proc.returncode}")
+    said = (proc.stderr.strip().splitlines() or ["nothing"])[-1]
+    raise BenchmarkError(f"serve probe exited {proc.returncode}: {said[:400]}")
 
 
 def deploy(config: dict, seed: int):
@@ -94,6 +98,7 @@ def deploy(config: dict, seed: int):
     family = {
         "kind": "benchmark", "seed": seed,
         "config": dict(config["model"], dtype=config["dtype"]),
+        "reference": config.get("reference"),
     }
     app = dep.bind(
         {config["name"]: family}, default_family=None, engine=engine,

@@ -5,10 +5,14 @@ so that neither it nor the reference's pass is part of `setup_s`.
 
 The weights are the benchmark's, made here again from the seed
 (`reference/weights.py`, as the replica was handed them): neither side
-of the comparison takes its weights from the other. Two comparisons
+of the comparison takes its weights from the other; which leaves the
+tree has is the reference module's to say (`shapes`). Two comparisons
 with the plain reference, which runs once over each sequence, one at a
 time, padded at its end to a multiple of a quarter of `max_len` (it is
-causal: what pads the end moves no position before it):
+causal: what pads the end moves no position before it), and is asked
+for the logits of the rows that are compared and no other
+(`forward(..., rows=(start, stop))`): a served request's served
+positions, a probe row's every position.
 
 1. THE WINDOW'S OWN SERVED TOKENS. `serve.py` draws from the seed a
    sample of the requests the window FINISHED (`sample`: the longest and
@@ -96,8 +100,11 @@ def gaps(logits, tokens):
     return jax.device_get(gap)
 
 
-def reference_logits(reference, params, tokens, model: dict, bucket: int):
-    """The reference's logits [t, vocab] over `tokens` [t], run at the
+def reference_logits(
+    reference, params, tokens, model: dict, bucket: int, rows=None,
+):
+    """The reference's logits over `tokens` [t], every position's
+    [t, vocab] or those of `rows` = (start, stop) alone, run at the
     next multiple of `bucket` so that a handful of shapes serve every
     run."""
     import jax.numpy as jnp
@@ -105,16 +112,20 @@ def reference_logits(reference, params, tokens, model: dict, bucket: int):
 
     fed = np.zeros(-(-len(tokens) // bucket) * bucket, np.int32)
     fed[:len(tokens)] = tokens
-    return reference.forward(params, jnp.asarray(fed), model)[:len(tokens)]
+    return reference.forward(
+        params, jnp.asarray(fed), model, rows=tuple(rows or (0, len(tokens)))
+    )
 
 
 def served_logits(forward, request: dict):
     """The logits [m, vocab] at the positions that produce a request's
     m served tokens (the last prompt position and all served tokens but
-    the last), in one pass of `forward(tokens)` over prompt + served."""
+    the last), in one pass of `forward(tokens, rows)` over prompt +
+    served that is asked for those m rows and no other: a request may
+    be as long as `max_len`."""
     n = len(request["prompt"])
     fed = list(request["prompt"]) + list(request["tokens"][:-1])
-    return forward(fed)[n - 1:]
+    return forward(fed, (n - 1, len(fed)))
 
 
 def served_summary(rows: list) -> dict:
@@ -288,6 +299,9 @@ def verdict(out: dict, limits: dict) -> bool:
 
 
 def probe(spec: dict, device: dict) -> dict:
+    from functools import partial
+
+    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -301,10 +315,12 @@ def probe(spec: dict, device: dict) -> dict:
     limits = {k: spec["tolerance"][k] for k in HELD if k in spec["tolerance"]}
     reference = compare.load(spec.get("reference"))
     control = bool(spec.get("control"))
-    params = weights.make(model, spec["dtype"], seed)
+    params = weights.make(model, spec["dtype"], seed, reference)
 
-    def logits_of(weights_, tokens):
-        return reference_logits(reference, weights_, tokens, model, bucket)
+    def logits_of(weights_, tokens, rows=None):
+        return reference_logits(
+            reference, weights_, tokens, model, bucket, rows
+        )
 
     # 2 first: the pool and the program's logits leave the chip before
     # the served requests' longer passes.
@@ -325,6 +341,7 @@ def probe(spec: dict, device: dict) -> dict:
 
     out = {
         "device": device, "seed": seed, "reference": reference.__name__,
+        "leaves": len(jax.tree.leaves(params)),
         "limits": limits, **compare_rows(
             lengths, sequences,
             lambda r: (
@@ -354,16 +371,16 @@ def probe(spec: dict, device: dict) -> dict:
         kept.clear()
         low_tokens = [
             np.asarray(jnp.argmax(
-                served_logits(lambda t: logits_of(low, t), r), axis=-1
+                served_logits(partial(logits_of, low), r), axis=-1
             )) for r in requests
         ]
         del low
-        params = weights.make(model, spec["dtype"], seed)
+        params = weights.make(model, spec["dtype"], seed, reference)
 
     rng, vocab = random.Random(int(seed)), model["vocab_size"]
     rows, low_rows, altered = [], [], []
     for i, request in enumerate(requests):
-        logits = served_logits(lambda t: logits_of(params, t), request)
+        logits = served_logits(partial(logits_of, params), request)
         n = len(request["prompt"])
         rows.append({"n_prompt": n, "gaps": gaps(logits, request["tokens"])})
         if control:
@@ -391,7 +408,7 @@ def main() -> int:
     # (`JAX_COMPILATION_CACHE_DIR`, inherited).
     import jax
 
-    from benchmark.harness import describe
+    from benchmark.harness import BenchmarkError, describe
 
     device = describe(jax.devices())
     if not spec["rehearse"] and device["platform"] != "tpu":
@@ -400,7 +417,21 @@ def main() -> int:
     if device["count"] < spec["chips"]:
         print(f"needs {spec['chips']} chip(s): {device}", file=sys.stderr)
         return 1
-    print(json.dumps(probe(spec, device)), flush=True)
+    try:
+        out = probe(spec, device)
+    except Exception as e:
+        # The chip was there and the comparison could not be made. What
+        # another try cannot mend (the reference or the plan refusing a
+        # leaf or a module, a row that does not fit the chip) ends the
+        # run on this line with no second try (exit code 2); anything
+        # else exits 1 as an uncaught error would, and is tried again.
+        import traceback
+
+        traceback.print_exc()
+        print(f"probe failed: {type(e).__name__}: {e}", file=sys.stderr)
+        final = isinstance(e, (ValueError, KeyError, TypeError, BenchmarkError))
+        return 2 if final or "RESOURCE_EXHAUSTED" in str(e) else 1
+    print(json.dumps(out), flush=True)
     return 0
 
 

@@ -7,9 +7,12 @@ The weights it serves are the benchmark's (`reference/weights.py`, one
 jitted call from the seed), not the program's `init_params`: a family
 of kind "benchmark" is built here, through the one seam the program
 has for it, `serving.build_model` (spec -> params, config), which this
-module wraps in the replica's process. The reference's pass makes the
-same tree from the same seed by itself, so neither side of `correct`
-takes its weights from the other.
+module wraps in the replica's process. Which leaves the tree has is
+said by the configuration's reference module where it defines `shapes`
+(the family carries its name; the module is the benchmark's file, not
+the program's). The reference's pass makes the same tree from the same
+seed by itself, so neither side of `correct` takes its weights from
+the other.
 
 `build_model` also times the two parts of the first request's load
 that happen before the engine is built, for the run's notes: the TPU
@@ -26,10 +29,11 @@ from ray_tpu.llm import serving
 from ray_tpu.llm.serving import LLMServer
 
 from ..harness import describe, peak_bytes
-from ..reference import weights
+from ..reference import compare, weights
 
 _build_model = serving.build_model
-#: Seconds of the first request's load, for the run's notes.
+#: Seconds of the first request's load and the leaves of the tree it
+#: made, for the run's notes.
 LOAD_S: dict = {}
 
 
@@ -45,11 +49,17 @@ def build_model(spec: dict):
     model = dict(spec["config"])
     dtype = model.pop("dtype")
     cfg = LlamaConfig(**model, dtype=jnp.dtype(dtype))
+    reference = compare.load(spec.get("reference"))
     t0 = time.monotonic()
     jax.devices()
     t1 = time.monotonic()
-    params = jax.block_until_ready(weights.make(model, dtype, spec["seed"]))
-    LOAD_S.update(backend=t1 - t0, weights=time.monotonic() - t1)
+    params = jax.block_until_ready(
+        weights.make(model, dtype, spec["seed"], reference)
+    )
+    LOAD_S.update(
+        backend=t1 - t0, weights=time.monotonic() - t1,
+        leaves=len(jax.tree.leaves(params)),
+    )
     return params, cfg
 
 

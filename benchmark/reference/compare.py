@@ -2,7 +2,18 @@
 equations: the loss of logits and the distance between two sets of
 logits. A configuration names its reference (`"reference": "<module>"`
 in its file, `llama_ref` when absent): `reference/<module>.py` with
-`forward(params, tokens, model) -> logits [t, vocab] float32`."""
+
+    forward(params, tokens, model, rows=None) -> logits float32
+
+over `tokens` [t]: every position's, [t, vocab], or with
+`rows=(start, stop)` those positions' alone, [stop - start, vocab]: the
+layers run over all t positions, the final norm and the head over the
+rows that are compared, so that a request as long as `max_len` fits
+beside the weights (at 24,576 positions and a vocabulary of 151,936
+every position's logits are 14.9 GB). `rows` is part of the contract:
+`load` refuses a module whose `forward` lacks it, by name. A module
+whose parameter tree is not `weights.shapes`' also defines
+`shapes(model)`, the plan `weights.make` draws (`weights.py`)."""
 
 from __future__ import annotations
 
@@ -18,9 +29,16 @@ def load(name=None, root=None):
     looked at)."""
     from benchmark.harness import ROOT, BenchmarkError, load_module
 
+    import inspect
+
     module = load_module("reference", name or DEFAULT, root or ROOT)
     if not callable(getattr(module, "forward", None)):
         raise BenchmarkError(f"reference {name!r} has no forward()")
+    if "rows" not in inspect.signature(module.forward).parameters:
+        raise BenchmarkError(
+            f"reference {name!r}: forward(params, tokens, model, rows=None) "
+            "has no `rows`"
+        )
     return module
 
 

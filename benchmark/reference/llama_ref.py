@@ -11,7 +11,8 @@ Matrix multiplications run at `highest` precision: on a TPU a float32
 matmul is otherwise done in bfloat16 passes.
 
 Departures from the papers: none in the mathematics. Attention is
-computed for `q_block` query rows at a time against all keys, which
+computed for `q_block` query rows at a time against all keys, and the
+final norm and the head only over the `rows` that are asked for, which
 changes memory and not the result.
 """
 
@@ -78,10 +79,40 @@ def _layer(x, layer, *, n_heads, n_kv_heads, head_dim, eps, theta, q_block):
     return x + (jax.nn.silu(h @ w["w3"]) * (h @ w["w1"])) @ w["w2"]
 
 
-def forward(params, tokens, model: dict, q_block: int = 512):
-    """tokens [t] int -> logits [t, vocab] float32. `model` holds
-    `LlamaConfig` keys (dim, n_layers, n_heads, n_kv_heads, norm_eps,
-    rope_theta)."""
+#: The head runs over a number of rows rounded up to a multiple of
+#: this, so that a handful of programs serve every request: a program
+#: for each number of served tokens cost a run 40-70 s of compiling
+#: (PERF.md section 6, PR 39).
+HEAD_BLOCK = 256
+
+
+@partial(jax.jit, static_argnames=("size", "eps"))
+def _head_block(x, final_norm, lm_head, first, *, size, eps):
+    x = jax.lax.dynamic_slice_in_dim(x, first, size, axis=0)
+    x = _rms_norm(x, final_norm.astype(jnp.float32), eps)
+    return x @ lm_head.astype(jnp.float32)
+
+
+def _head(x, params, eps, rows=None):
+    """Final norm and head of x [t, dim] -> logits [t, vocab], or of
+    `rows` = (start, stop) alone: the same product for those rows, and
+    every position's logits never exist (at most `HEAD_BLOCK` - 1
+    neighbouring rows are computed beside them and cut off)."""
+    t = x.shape[0]
+    start, stop = rows or (0, t)
+    size = min(-(-(stop - start) // HEAD_BLOCK) * HEAD_BLOCK, t)
+    first = min(start, t - size)
+    logits = _head_block(
+        x, params["final_norm"], params["lm_head"], first, size=size, eps=eps
+    )
+    return logits[start - first:stop - first]
+
+
+def forward(params, tokens, model: dict, rows=None, q_block: int = 512):
+    """tokens [t] int -> logits [t, vocab] float32, or with
+    `rows=(start, stop)` [stop - start, vocab], those positions' (the
+    layers still run over all t). `model` holds `LlamaConfig` keys
+    (dim, n_layers, n_heads, n_kv_heads, norm_eps, rope_theta)."""
     head_dim = model.get("custom_head_dim") or model["dim"] // model["n_heads"]
     with jax.default_matmul_precision("highest"):
         x = params["embed"][tokens].astype(jnp.float32)
@@ -94,8 +125,4 @@ def forward(params, tokens, model: dict, q_block: int = 512):
                 theta=float(model.get("rope_theta", 10000.0)),
                 q_block=q_block,
             )
-        x = _rms_norm(
-            x, params["final_norm"].astype(jnp.float32),
-            float(model.get("norm_eps", 1e-6)),
-        )
-        return x @ params["lm_head"].astype(jnp.float32)
+        return _head(x, params, float(model.get("norm_eps", 1e-6)), rows)

@@ -19,7 +19,7 @@ tree (layers stacked on axis 0; `router` [d, E]; `w_gate`, `w_up`
 and upcasts one layer at a time. Matrix multiplications run at
 `highest` precision. It reads `moe_top_k` and `moe_router` from the
 model's keys, so a configuration that renormalises is checked as
-such; rotary and the norm are `llama_ref`'s.
+such; rotary, the norm and the head are `llama_ref`'s.
 
 Departures from the published code: the router's matmul and softmax
 run in float32 here (and in the program); transformers multiplies by
@@ -37,7 +37,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from benchmark.reference.llama_ref import _rms_norm, _rotary
+from benchmark.reference.llama_ref import _head, _rms_norm, _rotary
 
 
 @partial(
@@ -96,8 +96,9 @@ def _layer(
     return x + y
 
 
-def forward(params, tokens, model: dict, q_block: int = 512):
-    """tokens [t] int -> logits [t, vocab] float32. `model` holds
+def forward(params, tokens, model: dict, rows=None, q_block: int = 512):
+    """tokens [t] int -> logits [t, vocab] float32, or with
+    `rows=(start, stop)` those positions' alone. `model` holds
     `LlamaConfig` keys (dim, n_layers, n_heads, n_kv_heads, norm_eps,
     rope_theta, moe_top_k, moe_router)."""
     head_dim = model.get("custom_head_dim") or model["dim"] // model["n_heads"]
@@ -117,5 +118,4 @@ def forward(params, tokens, model: dict, q_block: int = 512):
                 ) == "softmax_renorm",
                 q_block=q_block,
             )
-        x = _rms_norm(x, params["final_norm"].astype(jnp.float32), eps)
-        return x @ params["lm_head"].astype(jnp.float32)
+        return _head(x, params, eps, rows)

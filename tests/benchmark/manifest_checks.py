@@ -249,6 +249,18 @@ def config_resolves_to_a_reference(manifest, name, root=ROOT):
     assert module.__file__ == os.path.join(
         root, "benchmark", "reference", f"{stem}.py"
     )
+    if "engine" in config:
+        # the plan of leaves it names (or `weights.shapes`) draws: legal
+        # kinds, no leaf that is also a parent; nothing is computed
+        import jax
+
+        from benchmark.reference import weights
+
+        small = harness.apply_rehearsal(config)
+        tree = jax.eval_shape(
+            lambda: weights.make(small["model"], small["dtype"], 0, module)
+        )
+        assert {"embed", "lm_head"} <= set(tree)
 
 
 #: (check, the section whose names it takes one of, or None).
@@ -300,13 +312,70 @@ def write_manifest(root: str, manifest: dict) -> None:
         json.dump(manifest, f)
 
 
-STUB_REFERENCE = (
-    "from benchmark.reference import llama_ref\n"
-    "def forward(params, tokens, model):\n"
-    "    with open({mark!r}, 'a') as f:\n"
-    "        f.write(f'{{tokens.shape[0]}}\\n')\n"
-    "    return llama_ref.forward(params, tokens, model)\n"
-)
+#: The reference of a stub architecture that brings what a drawn row
+#: brings: leaves `weights.shapes` does not know (one more stacked leaf
+#: under `layers`, one under a parent of its own, one drawn around a
+#: mean of its own), sized by a `model` key, and a `forward` that takes
+#: `rows` and refuses a tree in which one of them is absent or
+#: misshapen. Its mathematics is `llama_ref`'s, which the program
+#: computes: the leaves are carried, not used. Each call appends
+#: [positions fed, rows asked for] to the file at MARK.
+STUB_REFERENCE = '''\
+import json
+
+from benchmark.reference import llama_ref, weights
+
+MARK = {mark!r}
+
+
+def own(model):
+    d, layers, k = model["dim"], model["n_layers"], model["moe_top_k"]
+    return {{
+        "layers/w_index": ((layers, d, 4 * k), "matrix", d),
+        "mtp/proj": ((d, d), "matrix", d),
+        "layers/decay_log": ((layers, k), (-2.0, 0.5), 0),
+    }}
+
+
+def shapes(model):
+    return {{**weights.shapes(model), **own(model)}}
+
+
+def forward(params, tokens, model, rows=None):
+    for path, (shape, _, _) in own(model).items():
+        leaf = params
+        for name in path.split("/"):
+            leaf = leaf.get(name) if isinstance(leaf, dict) else None
+        if leaf is None:
+            raise ValueError(f"stub_ref: the tree has no leaf {{path}}")
+        if tuple(leaf.shape) != shape:
+            raise ValueError(
+                f"stub_ref: leaf {{path}} is {{tuple(leaf.shape)}}, not {{shape}}"
+            )
+    with open(MARK, "a") as f:
+        f.write(json.dumps([int(tokens.shape[0]), rows]) + "\\n")
+    return llama_ref.forward(params, tokens, model, rows=rows)
+'''
+#: The key of `model` that sizes the stub's own leaves, and its value:
+#: one the program's config class takes and a dense forward ignores
+#: (a key the class lacks is the program change a `model_config` PR
+#: makes, which a stub cannot).
+STUB_MODEL_KEY = {"moe_top_k": 3}
+#: A train cell's stub: the train driver takes the program's
+#: `init_params` tree, so it names no leaves.
+STUB_REFERENCE_TRAIN = '''\
+import json
+
+from benchmark.reference import llama_ref
+
+MARK = {mark!r}
+
+
+def forward(params, tokens, model, rows=None):
+    with open(MARK, "a") as f:
+        f.write(json.dumps([int(tokens.shape[0]), rows]) + "\\n")
+    return llama_ref.forward(params, tokens, model, rows=rows)
+'''
 #: reader -> (layer, unit, source, the body of `reduce(run)`).
 STUB_READERS = {
     "stub_requests": (
@@ -324,19 +393,26 @@ STUB_READERS = {
 
 def grow(root: str, base: str, cell: str, mark: str = os.devnull) -> dict:
     """What a `model_config` PR adds to the checkout at `root`, and
-    nothing it may not: a reference module, a configuration that names
-    it (`base`'s sizes), two readers, and at the END of the manifest's
-    lists one configuration, one cell (`cell`'s traffic) and two
-    per-layer entries, with the new cell's name at the end of every
-    list `cell` is in. -> the grown manifest, written there."""
+    nothing it may not: a reference module (a serve cell's names leaves
+    of its own, `STUB_REFERENCE`), a configuration that names it
+    (`base`'s sizes and the key that sizes those leaves), two readers,
+    and at the END of the manifest's lists one configuration, one cell
+    (`cell`'s traffic) and two per-layer entries, with the new cell's
+    name at the end of every list `cell` is in. -> the grown manifest,
+    written there."""
     bench = os.path.join(root, "benchmark")
     manifest = harness.load_manifest(root)
-    with open(os.path.join(bench, "reference", "stub_ref.py"), "w") as f:
-        f.write(STUB_REFERENCE.format(mark=mark))
     config = dict(
         harness.load_config(manifest, base, root),
         name="stub-model", reference="stub_ref",
     )
+    serve = "engine" in config
+    if serve:
+        config["model"] = dict(config["model"], **STUB_MODEL_KEY)
+    with open(os.path.join(bench, "reference", "stub_ref.py"), "w") as f:
+        f.write(
+            (STUB_REFERENCE if serve else STUB_REFERENCE_TRAIN).format(mark=mark)
+        )
     with open(os.path.join(bench, "configs", "stub-model.json"), "w") as f:
         json.dump(config, f)
     for reader, (layer, unit, source, body) in STUB_READERS.items():

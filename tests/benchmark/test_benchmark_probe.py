@@ -144,27 +144,32 @@ def test_the_benchmarks_weights_have_the_tree_the_program_takes_and_are_not_its_
 
     config = harness.apply_rehearsal(harness.load_config(MANIFEST, name))
     model = config["model"]
-    made = weights.make(model, "bfloat16", 2 ** 31 + 7)
+    reference = compare.load(config.get("reference"))
+    made = weights.make(model, "bfloat16", 2 ** 31 + 7, reference)
     cfg = LlamaConfig(**model, dtype=jnp.bfloat16)
     theirs = jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0))
-    assert jax.tree.structure(made) == jax.tree.structure(theirs)
-    for mine, other in zip(jax.tree.leaves(made), jax.tree.leaves(theirs)):
-        assert (mine.shape, mine.dtype) == (other.shape, other.dtype)
-    again = weights.make(model, "bfloat16", 2 ** 31 + 7)
-    other = weights.make(model, "bfloat16", 2 ** 31 + 8)
-    drawn = init_params(jax.random.PRNGKey(2 ** 31 + 7), cfg)
 
-    def flat(tree):
+    def flat(tree, of=lambda v: np.asarray(v, np.float32)):
         return {
-            jax.tree_util.keystr(p): np.asarray(v, np.float32)
+            jax.tree_util.keystr(p): of(v)
             for p, v in jax.tree_util.tree_leaves_with_path(tree)
         }
 
-    made, again, other, drawn = map(flat, (made, again, other, drawn))
+    # every leaf the program's tree has, at its shape and in its type;
+    # where the reference names the leaves itself its plan may hold more
+    # (the layers that read them are the same PR's change to the program)
+    kinds = flat(made, lambda v: (v.shape, v.dtype))
+    assert flat(theirs, lambda v: (v.shape, v.dtype)).items() <= kinds.items()
+    if not hasattr(reference, "shapes"):
+        assert jax.tree.structure(made) == jax.tree.structure(theirs)
+    again = weights.make(model, "bfloat16", 2 ** 31 + 7, reference)
+    other = weights.make(model, "bfloat16", 2 ** 31 + 8, reference)
+    drawn = flat(init_params(jax.random.PRNGKey(2 ** 31 + 7), cfg))
+    made, again, other = map(flat, (made, again, other))
     for key, leaf in made.items():
         assert np.array_equal(leaf, again[key]), key      # the seed's
         assert not np.array_equal(leaf, other[key]), key  # and no other's
-        assert not np.array_equal(leaf, drawn[key]), key  # nor the program's
+        assert key not in drawn or not np.array_equal(leaf, drawn[key]), key  # nor the program's
         assert leaf.std() > 0.01, key  # no all-ones norm, no zero bias
     assert made["['layers']['attn_norm']"].mean() == pytest.approx(1.0, abs=0.05)
     fan_in = model["dim"]
@@ -248,7 +253,7 @@ def test_the_control_and_each_fault_come_out_not_correct(name, monkeypatch):
     model, seed, engine = config["model"], 11, config["engine"]
     assert max(config["probe_lengths"]) > 2 * engine["prefill_chunk"]  # three chunks
     reference = compare.load(config.get("reference"))
-    params = weights.make(model, config["dtype"], seed)
+    params = weights.make(model, config["dtype"], seed, reference)
     rng = np.random.default_rng(seed)
     served = []
     for n in (150, 33, 70, 120):
@@ -359,3 +364,38 @@ def test_checks_say_what_failed_and_come_last_in_the_result_line():
     assert line["checks"]["failed"]["first_status"] == 503
     # a NaN reading fails its check
     assert not harness.check("logits_rel_rms", float("nan"), 0.025)["ok"]
+
+
+@pytest.mark.parametrize("error, code", [
+    (ValueError("stub_ref: the tree has no leaf layers/w_index"), 2),
+    (KeyError("layers"), 2),
+    (TypeError("forward() got an unexpected keyword argument 'rows'"), 2),
+    (harness.BenchmarkError("reference 'old_ref': forward(...) has no `rows`"), 2),
+    (RuntimeError("RESOURCE_EXHAUSTED: Error allocating device buffer"), 2),
+    (RuntimeError("INTERNAL: the chip dropped out"), 1),
+    (OSError("Device or resource busy"), 1),
+], ids=lambda x: type(x).__name__ if isinstance(x, Exception) else None)
+def test_the_probes_child_says_whether_another_try_can_mend_it(
+    error, code, tmp_path, monkeypatch, capsys,
+):
+    """Exit code 2 ends the run with no second try (`serve.run_probe`):
+    only what a try cannot mend gets it, the reference or the plan
+    refusing and a row that does not fit; the rest exits 1 as an
+    uncaught error does, and is tried again."""
+    import json
+    import sys
+
+    path = tmp_path / "served.json"
+    path.write_text(json.dumps({"rehearse": True, "chips": 1}))
+
+    def raises(spec, device):
+        raise error
+
+    monkeypatch.setattr(serve_probe, "probe", raises)
+    monkeypatch.setattr(sys, "argv", ["serve_probe", str(path)])
+    assert serve_probe.main() == code
+    said = capsys.readouterr()
+    assert said.out == ""
+    assert said.err.strip().splitlines()[-1].startswith(
+        f"probe failed: {type(error).__name__}: "
+    )

@@ -4,6 +4,7 @@ runner can place these slower tests beside the quick ones."""
 
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -15,6 +16,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmark import harness  # noqa: E402
+from benchmark.reference import weights  # noqa: E402
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import manifest_checks as checks  # noqa: E402  (this directory)
@@ -118,19 +120,57 @@ def test_a_configuration_names_its_reference_and_the_driver_calls_it(base, cell,
     notes = json.loads(next(x for x in lines if x.startswith("[benchmark] notes "))[18:])
     assert (notes.get("probe") or notes)["reference"] == "benchmark.reference.stub_ref"
     with open(mark) as f:
-        calls = f.read().split()
+        calls = [json.loads(x) for x in f.read().splitlines()]
     # the serve comparison runs the reference once a sampled request and
     # once a distinct row of the probe, each padded to a multiple of a
     # quarter of max_len; the train check once
     small = harness.apply_rehearsal(config)
-    if "engine" in config:
-        from benchmark.drivers.serve_probe import SAMPLE_REQUESTS
+    if "engine" not in config:
+        assert len(calls) == 1 and calls[0][1] is None
+        return
+    from benchmark.drivers.serve_probe import DECODE_STEPS, SAMPLE_REQUESTS
 
-        engine = small["engine"]
-        assert len(small["probe_lengths"]) < len(calls) <= SAMPLE_REQUESTS + engine["slots"]
-        assert all(int(c) % (engine["max_len"] // 4) == 0 for c in calls)
-    else:
-        assert len(calls) == 1
+    engine, probe = small["engine"], notes["probe"]
+    assert len(small["probe_lengths"]) < len(calls) <= SAMPLE_REQUESTS + engine["slots"]
+    assert all(fed % (engine["max_len"] // 4) == 0 for fed, _ in calls)
+    # the stub's own leaves are there on both sides: in the tree the
+    # replica served and in the one the reference's pass made
+    leaves = len(weights.shapes(small["model"])) + 3
+    assert notes["replica_load_s"]["leaves"] == probe["leaves"] == leaves
+    # a served request is asked for the rows that are compared and no
+    # other (the last prompt position and every served token but the
+    # last), a probe row for every position it holds
+    served = [(a, b) for _, (a, b) in calls if a > 0]
+    assert sorted(b - a for a, b in served) == sorted(
+        r["n_out"] for r in probe["requests_rows"]
+    )
+    assert sorted(a + 1 for a, _ in served) == sorted(
+        r["n_prompt"] for r in probe["requests_rows"]
+    )
+    assert sum(b - a for a, b in served) == probe["tokens"]
+    rows = {b for _, (a, b) in calls if a == 0}
+    assert rows == {n + DECODE_STEPS for n in small["probe_lengths"]}
+
+
+@pytest.mark.timeout(400)
+def test_a_leaf_the_reference_names_and_the_tree_lacks_ends_the_run_on_its_name(tmp_path):
+    """The grown stub with its `shapes` taken away: both sides make
+    `weights.shapes`' tree, the program serves it, and the reference
+    refuses it. The run exits 1 on a last line that names the leaf, and
+    the probe is not tried again."""
+    root = _checkout(tmp_path)
+    mark = str(tmp_path / "stub_ref.called")
+    checks.grow(root, "qwen2.5-3b", "docqa_closed", mark)
+    with open(os.path.join(root, "benchmark", "reference", "stub_ref.py"), "a") as f:
+        f.write("\n\ndel shapes\n")
+    proc = _run(root, "--workload", "stub_cell", "--seed", "5", "--rehearse")
+    assert proc.returncode == 1
+    assert '"correct"' not in proc.stdout
+    last = proc.stderr.strip().splitlines()[-1]
+    assert "serve probe exited 2" in last
+    assert "stub_ref: the tree has no leaf layers/w_index" in last
+    assert proc.stderr.count("probe failed: ValueError") == 2  # the child's line, and the last
+    assert not os.path.exists(mark)
 
 
 def _last_lines(proc):
@@ -181,10 +221,16 @@ _sound_call = BenchLLMServer.__call__
 
 
 def _altered(self, request):
-    """The timed path broken underneath: every stream's second token is
-    altered where it is produced."""
-    for i, chunk in enumerate(_sound_call(self, request)):
-        yield b"%d " % ((int(chunk) + 1) % 512) if i == 1 else chunk
+    """The timed path broken underneath: every stream's last token is
+    altered where it is produced (nothing follows it, so no sound token
+    is judged in a context the reference was fed wrong)."""
+    held = None
+    for chunk in _sound_call(self, request):
+        if held is not None:
+            yield held
+        held = chunk
+    if held is not None:
+        yield b"%d " % ((int(held) + 1) % 512)
 
 
 BenchLLMServer.__call__ = _altered
@@ -194,8 +240,9 @@ BenchLLMServer.__call__ = _altered
 @pytest.mark.timeout(400)
 def test_a_token_altered_where_it_is_produced_makes_the_run_incorrect(tmp_path):
     """The whole of a run but the look for a chip, with the replica of
-    a scratch checkout altering one token a stream: `correct` comes out
-    false by the comparison with the reference and by nothing else."""
+    a scratch checkout altering the last token of every stream:
+    `correct` comes out false by the comparison with the reference and
+    by nothing else."""
     root = _checkout(tmp_path)
     with open(os.path.join(root, "benchmark", "drivers", "serve_replica.py"), "a") as f:
         f.write(ALTERED_TOKEN)
@@ -210,7 +257,12 @@ def test_a_token_altered_where_it_is_produced_makes_the_run_incorrect(tmp_path):
     }
     # an altered token lies deviations under the reference's best
     assert line["checks"]["served_gap_max"]["value"] > 0.5
-    assert "the widest at token 1 of" in line["checks"]["served_gap_max"]["where"]
+    # and the widest gap is read at the altered token, a request's last
+    where = line["checks"]["served_gap_max"]["where"]
+    token, served = re.search(
+        r"the widest at token (\d+) of a request of \d+ \+ (\d+)$", where
+    ).groups()
+    assert int(token) == int(served) - 1
     assert errors[-1].startswith("[benchmark] failed: served_gap_max ")
 
 

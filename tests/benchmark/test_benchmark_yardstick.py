@@ -107,7 +107,10 @@ def test_a_grown_copy_passes_every_manifest_check(tmp_path):
 
     root = checks.checkout(tmp_path)
     grown = checks.grow(root, "qwen2.5-3b", "docqa_closed")
-    assert checks.walk(MANIFEST) == 4 + 4 + 2 * len(CELLS) + len(E2E) + len(LAYER) + 2 * 4
+    configs = checks.names(MANIFEST, "configs")
+    assert checks.walk(MANIFEST) == (
+        4 + 4 + 2 * len(CELLS) + len(E2E) + len(LAYER) + 2 * len(configs)
+    )
     assert checks.walk(grown, root) == checks.walk(MANIFEST) + 2 + 2 + 2
     for name in ("stream_items_per_fetch.tput", "engine_ahead_share.tput",
                  "kv_read_amplification.tput", "serve_tokens_per_s"):
