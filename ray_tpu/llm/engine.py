@@ -458,7 +458,7 @@ class InferenceEngine:
                 cfg, n_blocks, block_len, ec.max_len, ec.prefill_chunk
             )
             self._sched = SlotScheduler(ec.slots, ec.max_waiting)
-            # Keys a decode step's attention walks per trip (for the
+            # Keys of one attention tile in a decode step (for the
             # `kv_keys_read` counter).
             from ..models.generate import paged_tile_keys
 
@@ -1523,8 +1523,8 @@ class InferenceEngine:
     def _count_kv_keys(self, groups) -> None:
         """Add a step's `kv_keys_live` / `kv_keys_read`: one program
         per group of slots (one, or one a weight generation), each
-        over every slot as far as its own rows' longest `valid_len`
-        asks."""
+        over the work list of its own rows' (row, tile) pairs, a
+        tile for every slot a trip."""
         from ..models.generate import paged_tiles_read
 
         tile = self._kv_tile_keys

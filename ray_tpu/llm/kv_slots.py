@@ -14,8 +14,8 @@ item 1c):
   (models/generate.init_block_pool);
 * a per-request PAGE TABLE mapping logical block j -> physical block
   id; a forward writes its new k/v into the pool in place and
-  attention walks each row's table a tile of entries at a time, only
-  as far as the longest alive row reaches
+  attention walks the live rows' tables a tile of entries at a time,
+  each row as far as its own length reaches and a dead row not at all
   (models/generate._paged_attention), so the math — and the greedy
   token stream — is that of plain causal attention over the keys
   inside `valid_len`;

@@ -62,25 +62,31 @@ def accel_donate(*argnums: int):
 # TABLE maps logical block j -> physical block id. A forward touches
 # the pool IN PLACE: the layer loop carries both arrays (all layers),
 # each layer scatters its new k/v
-# at [layer, block, :, offset], and attention walks the row's table a
-# TILE of entries at a time — gathering that tile's pages at the
-# pool's own dtype, once per kv head (GQA queries grouped onto their
-# kv head, nothing repeated) — with the online-softmax recurrence
-# (float32 running max, sum and accumulator, as ops/attention.py).
-# The walk stops after the longest live row's last tile (a traced
-# trip count: one compiled program per shape, whatever the lengths),
-# so the math equals plain causal attention over the keys inside
-# `valid_len` and the engine stays token-for-token equal to greedy
-# decoding by the uncached `llama.forward`.
+# at [layer, block, :, offset], and attention walks a WORK LIST of the
+# forward's live (row, tile) pairs — a TILE of a row's table entries
+# each, as many pairs a trip as the forward has rows — gathering the
+# pairs' pages at the pool's own dtype, once per kv head (GQA queries
+# grouped onto their kv head, nothing repeated), and merging each
+# pair's softmax sums into its row's (float32 running max, sum and
+# accumulator, as ops/attention.py). The list holds each live row's
+# tiles up to its own `valid_len` and nothing of a dead row, and the
+# walk stops with it (a traced trip count: one compiled program per
+# shape, whatever the lengths), so the math equals plain causal
+# attention over the keys inside `valid_len` and the engine stays
+# token-for-token equal to greedy decoding by the uncached
+# `llama.forward`.
 # ---------------------------------------------------------------------
 
 #: Keys of one attention tile in a single-token step: the page
-#: gathers and the two products run over this many keys of every row
-#: per trip of the walk. Measured on the v5e at the benchmark's
-#: geometry (PERF.md, PR 24): such a step is bound by the gathers'
-#: bytes, so a shorter tile wastes less past the longest row's end
-#: (128 pays more launches than it saves); a chunk's trip is bound by
-#: its launches and takes twice as many keys.
+#: gathers and the two products run over this many keys of every
+#: (row, tile) pair of a trip. Measured on the v5e at the benchmark's
+#: geometry (PERF.md, PR 24 and PR 40): a trip of 16 pairs is bound by
+#: its gathers' bytes — 13.7 us a layer at 2 KV heads (the k and the v
+#: gather 4.0 us each for 2 MB, the products, the mask and the by-row
+#: merge the rest), 102 us at 16 (23.9 us each for 16.8 MB; the two
+#: products 16 us each) — so a shorter tile wastes less past a row's
+#: end (128 pays more launches than it saves); a chunk's trip is bound
+#: by its launches and takes twice as many keys.
 PAGED_TILE_KEYS = 256
 
 
@@ -119,14 +125,62 @@ def paged_tile_keys(block_len: int, table_width: int, q_len: int) -> int:
 
 
 def paged_tiles_read(valid_len, alive, tile_keys: int):
-    """Tiles of `tile_keys` keys a paged forward attends over, per
-    row: up to the end of the longest ALIVE row, whole tiles. The one
-    rule behind the program's trip count (traced arrays) and the
-    engine's `kv_keys_read` counter (numpy arrays of the same
-    lengths), so the two cannot drift. A dead row's stale length
-    holds nothing open; all rows dead reads nothing."""
-    longest = (valid_len * alive).max()
-    return (longest + tile_keys - 1) // tile_keys
+    """Trips of a paged forward's attention loop. The attention walks
+    a WORK LIST of live (row, tile) pairs — each alive row's
+    `ceil(valid_len / tile_keys)` tiles, row after row — as many pairs
+    a trip as the forward has rows (so the page gather keeps the shape
+    PR 24 tuned): a trip reads `rows x tile_keys` keys, and the trips
+    are the pairs over the rows, rounded up. The one rule behind the
+    program's trip count (traced arrays) and the engine's
+    `kv_keys_read` counter (numpy arrays of the same lengths), so the
+    two cannot drift. A dead row's stale length adds no pair; all rows
+    dead reads nothing; one row (a prefill chunk) walks its own tiles,
+    one a trip."""
+    pairs = ((valid_len * alive + tile_keys - 1) // tile_keys).sum()
+    rows = valid_len.shape[0]
+    return (pairs + rows - 1) // rows
+
+
+def _paged_work_list(
+    tables, q_pos, valid_len, tile_blocks: int, block_len: int,
+    n_blocks: int,
+):
+    """The live (row, tile) pairs of a paged forward, made once for
+    all its layers from `tables` [b, whole tiles of entries], `q_pos`
+    [b, queries] and `valid_len` [b] (0 for a dead row). Pair p is
+    the tile `p - start_of_row` of the row whose tiles span p, rows in
+    order; the list is as long as the tables have tiles, and a pair
+    past the live ones (padding) is no row's and sees no key. -> dict
+    of [pairs, ...] arrays: `ids` [tile_blocks] the pool blocks of the
+    pair's tile, `pos` [queries] its row's `q_pos`, and `at` [3]: its
+    row (b, which is none, for padding), the position of its first key
+    and its row's `valid_len` (0 for padding)."""
+    b, entries = tables.shape
+    n_pairs = b * (entries // tile_blocks)
+    tile = tile_blocks * block_len
+    tiles = (valid_len + tile - 1) // tile
+    ends = jnp.cumsum(tiles)
+    p = jnp.arange(n_pairs)
+    owner = (p[:, None] >= ends).sum(axis=1)  # b past the live pairs
+    live, row = owner < b, jnp.minimum(owner, b - 1)
+    tile_of = jnp.where(live, p - (ends - tiles)[row], 0)
+    length = jnp.where(live, valid_len[row], 0)
+    blocks = tile_of[:, None] * tile_blocks + jnp.arange(tile_blocks)
+    # Entries wholly past a row's `valid_len` (in its last tile; every
+    # entry of a padding pair) name no key the row may see, and on the
+    # host they all name the null block: gathered as they are, every
+    # pair's copies hit one address and the chip serialises them (a
+    # step with 15 dead rows took 23.5 ms against 19.5). Each is
+    # pointed at a block of its own instead; whatever it holds is
+    # masked like the null block's junk.
+    elsewhere = jnp.arange(n_pairs * tile_blocks).reshape(n_pairs, -1)
+    ids = jnp.where(
+        blocks * block_len < length[:, None],
+        tables[row[:, None], blocks],
+        elsewhere % n_blocks,
+    )
+    at = jnp.stack([owner, tile_of * tile, length], axis=1)
+    return {"ids": ids, "pos": q_pos[row], "at": at}
 
 
 def _paged_attention(
@@ -134,63 +188,68 @@ def _paged_attention(
     k_pool,  # [layers, n_blocks, kv_heads, block_len, hd]
     v_pool,
     layer_idx,  # [] which layer's pages
-    tables: jax.Array,  # [b, whole tiles of entries] physical block ids
-    q_pos: jax.Array,  # [b, t]
-    valid_len: jax.Array,  # [b]
-    n_tiles,  # [] traced trip count (paged_tiles_read)
-    tile_blocks: int,
+    work,  # the forward's live (row, tile) pairs (_paged_work_list)
+    n_trips,  # [] traced trip count (paged_tiles_read)
 ) -> jax.Array:
-    """Attention of `q` over the pages `tables` names, read where they
-    lie: -> [b, heads, t, hd] float32. Keys past a query's position or
-    the row's `valid_len` are masked per tile; tiles past `n_tiles`
-    are never read."""
+    """Attention of `q` over the pages the work list names, read where
+    they lie: -> [b, heads, t, hd] float32. A trip takes b pairs off
+    the list (`paged_tiles_read`): their pages in one gather, each
+    pair's scores over its own tile against its own row's queries
+    (keys past a query's position or the row's `valid_len` masked),
+    and the pairs' softmax sums merged into their rows' running ones.
+    Pairs past `n_trips` trips are never read."""
     b, n_heads, t, hd = q.shape
-    n_blocks, kv_heads, bl = k_pool.shape[1:4]
+    kv_heads, bl = k_pool.shape[2:4]
+    tile_blocks = work["ids"].shape[1]
     groups = n_heads // kv_heads
     tile = tile_blocks * bl
     # The queries of one kv head side by side: row g * t + i of the
     # grouped axis is head (kv, g) at chunk position i, so both
     # products are plain matmuls against that head's keys.
     qg = q.reshape(b, kv_heads, groups * t, hd)
-    pos_g = jnp.tile(q_pos, (1, groups))[:, None, :, None]
-    len_g = valid_len[:, None, None, None]
     scale = 1.0 / jnp.sqrt(jnp.float32(hd))
     key_offsets = jnp.arange(tile)
-    # Entries wholly past a row's `valid_len` (every entry of a dead
-    # row) name no key the row may see, and on the host they all name
-    # the null block: gathered as they are, every row's copies hit one
-    # address and the chip serialises them (a step with 15 dead rows
-    # took 23.5 ms against 19.5). Each is pointed at a block of its
-    # own instead; whatever it holds is masked like the null block's
-    # junk.
-    elsewhere = jnp.arange(b * tile_blocks).reshape(b, -1) % n_blocks
+    rows = jnp.arange(b)
 
-    def one_tile(j, carry):
+    def one_trip(j, carry):
         m, l, acc = carry
+        pair = {
+            name: jax.lax.dynamic_slice_in_dim(of_all, j * b, b)
+            for name, of_all in work.items()
+        }
+        ids, (row, first_key, length) = pair["ids"], pair["at"].T
         with jax.named_scope("paged/gather_kv"):
-            ids = jax.lax.dynamic_slice_in_dim(
-                tables, j * tile_blocks, tile_blocks, axis=1
-            )
-            starts = j * tile + jnp.arange(tile_blocks) * bl
-            ids = jnp.where(
-                starts < valid_len[:, None], ids, elsewhere
-            )
             # The layer rides inside the gather's indices: one gather
             # of [kv_heads, block_len, hd] pages, no per-layer slice
             # of the pool materialised.
             kt = k_pool[layer_idx, ids]  # [b, tile_blocks, kvH, bl, hd]
             vt = v_pool[layer_idx, ids]
         with jax.named_scope("paged/attention"):
+            # (a padding pair's row, b, is clamped to the last by the
+            # gather: any will do, it sees no key)
             s = jnp.einsum(
-                "bhqd,bnhkd->bhqnk", qg, kt,
+                "bhqd,bnhkd->bhqnk", qg[row], kt,
                 preferred_element_type=jnp.float32,
             ).reshape(b, kv_heads, groups * t, tile) * scale
-            k_pos = j * tile + key_offsets
-            s = jnp.where((k_pos <= pos_g) & (k_pos < len_g), s, -1e30)
-            m_new = jnp.maximum(m, s.max(axis=-1))
+            k_pos = (first_key[:, None] + key_offsets)[:, None, None]
+            seen = (k_pos <= pair["pos"][:, None, :, None]) & (
+                k_pos < length[:, None, None, None]
+            )
+            s = jnp.where(seen, s, -1e30)
+        with jax.named_scope("paged/merge"):
+            # The split-key softmax merge, by row: a row's new max is
+            # its old one or that of its pairs of this trip; every
+            # pair's weights are taken against its own row's new max
+            # (as a lone tile's are against the running max), and
+            # their sums are added up by row. A padding pair is no
+            # row's: its junk is added to nothing.
+            mine = (row[:, None] == rows)[..., None, None]  # [pair, row, 1, 1]
+            m_new = jnp.maximum(
+                m, jnp.where(mine, s.max(axis=-1)[:, None], -1e30).max(axis=0)
+            )
+            m_row = jnp.where(mine, m_new, -1e30).max(axis=1)
             alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new[..., None])
-            l = l * alpha + p.sum(axis=-1)
+            p = jnp.exp(s - m_row[..., None])
             pv = jnp.einsum(
                 "bhqnk,bnhkd->bhqd",
                 p.astype(vt.dtype).reshape(
@@ -199,21 +258,27 @@ def _paged_attention(
                 vt,
                 preferred_element_type=jnp.float32,
             )
-            return m_new, l, acc * alpha[..., None] + pv
+            l = l * alpha + jnp.where(
+                mine, p.sum(axis=-1)[:, None], 0.0
+            ).sum(axis=0)
+            acc = acc * alpha[..., None] + jnp.where(
+                mine[..., None], pv[:, None], 0.0
+            ).sum(axis=0)
+            return m_new, l, acc
 
-    rows = (b, kv_heads, groups * t)
+    per_row = (b, kv_heads, groups * t)
     _, l, acc = jax.lax.fori_loop(
         0,
-        n_tiles,
-        one_tile,
+        n_trips,
+        one_trip,
         (
-            jnp.full(rows, -1e30, jnp.float32),
-            jnp.zeros(rows, jnp.float32),
-            jnp.zeros(rows + (hd,), jnp.float32),
+            jnp.full(per_row, -1e30, jnp.float32),
+            jnp.zeros(per_row, jnp.float32),
+            jnp.zeros(per_row + (hd,), jnp.float32),
         ),
     )
-    # A row no tile was read for (every row dead) has l == 0: its
-    # output is junk nobody reads, but keep it finite.
+    # A row no pair was read for (a dead row) has l == 0: its output
+    # is junk nobody reads, but keep it finite.
     out = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
     return out.reshape(b, n_heads, t, hd)
 
@@ -279,9 +344,8 @@ def _paged_layer(
     v_pool,
     tables: jax.Array,  # [b, whole tiles of entries] physical block ids
     q_pos: jax.Array,  # [b, t] absolute positions of x's tokens
-    valid_len: jax.Array,  # [b] valid cache length incl. x
-    n_tiles,  # [] attention's trip count
-    tile_blocks: int,
+    work,  # attention's live (row, tile) pairs
+    n_trips,  # [] attention's trip count
     live=None,  # [b] rows that are real (None: all)
 ):
     """-> (x, k_pool, v_pool, counts): counts is the layer's picks
@@ -297,10 +361,7 @@ def _paged_layer(
     with jax.named_scope("paged/scatter_kv"):
         k_pool = _paged_write(k_pool, layer_idx, tables, q_pos, k)
         v_pool = _paged_write(v_pool, layer_idx, tables, q_pos, v)
-    attn = _paged_attention(
-        q, k_pool, v_pool, layer_idx, tables, q_pos, valid_len,
-        n_tiles, tile_blocks,
-    )
+    attn = _paged_attention(q, k_pool, v_pool, layer_idx, work, n_trips)
     with jax.named_scope("layer/attn_out"):
         attn = attn.astype(cfg.dtype).transpose(0, 2, 1, 3).reshape(
             b, t, -1
@@ -323,9 +384,8 @@ def _paged_forward(
     along a row) -> (logits [b, t, vocab], new pool). `tables` maps
     each row's logical blocks to pool blocks and `valid_len` [b]
     bounds what attention may see.
-    `alive` [b] names the rows whose length bounds the walk over key
-    tiles (a dead row still computes, over whatever tiles the live
-    ones need, and sees none of their keys). The pool is carried
+    `alive` [b] names the rows whose key tiles attention walks (a
+    dead row still computes, and sees no key). The pool is carried
     through the layer loop and written in place. For a MoE config the
     new pool also holds this forward's picks per layer and expert,
     `moe_counts` [layers, E] int32 (overwritten, not summed: the
@@ -336,9 +396,9 @@ def _paged_forward(
     bl, width = pool["k"].shape[3], tables.shape[1]
     tile = paged_tile_keys(bl, width, t)
     tile_blocks = tile // bl
-    n_tiles = paged_tiles_read(valid_len, alive, tile)
-    # A dead row sees no key: its stale length must not keep its table
-    # entries (all the null block) in the gathers either.
+    n_trips = paged_tiles_read(valid_len, alive, tile)
+    # A dead row sees no key: its stale length adds no pair to the
+    # work list.
     valid_len = valid_len * alive
     # Whole tiles of table entries, and room for the one block past
     # its last that a chunk's write reads: the padding names the null
@@ -346,6 +406,12 @@ def _paged_forward(
     spare = (t + bl - 2) // bl
     tables = jnp.pad(
         tables, ((0, 0), (0, -(width + spare) % tile_blocks + spare))
+    )
+    # (the queries of a kv head's group lie side by side, as
+    # `_paged_attention` lays them)
+    work = _paged_work_list(
+        tables, jnp.tile(q_pos, (1, cfg.n_heads // cfg.n_kv_heads)),
+        valid_len, tile_blocks, bl, pool["k"].shape[1],
     )
     with jax.named_scope("embed"):
         x = embed_tokens(cfg, params, tokens)
@@ -369,7 +435,7 @@ def _paged_forward(
         layer, layer_idx = inputs
         *carry, counts = _paged_layer(
             cfg, x, {**layer, **experts}, layer_idx, cos, sin, k_pool,
-            v_pool, tables, q_pos, valid_len, n_tiles, tile_blocks, live,
+            v_pool, tables, q_pos, work, n_trips, live,
         )
         return tuple(carry), counts
 
@@ -479,10 +545,10 @@ def paged_decode_step(
     """Jitted single-step decode over the FULL slot batch against the
     block pool: sample one token per row from `last_logits`, scatter
     its k/v into each row's current block in place, and attend over
-    the tiles of block-table entries the longest alive row reaches.
-    Compiles once per (batch, pool, table) shape. `pool` and
-    `last_logits` are donated on accelerator backends — treat them as
-    consumed."""
+    each alive row's own tiles of block-table entries, a batch of
+    (row, tile) pairs a trip (`paged_tiles_read`). Compiles once per
+    (batch, pool, table) shape. `pool` and `last_logits` are donated
+    on accelerator backends — treat them as consumed."""
     global _paged_decode_jit
     if _paged_decode_jit is None:
         _paged_decode_jit = compile_watch.instrument(

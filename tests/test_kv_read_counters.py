@@ -1,6 +1,7 @@
-"""How many keys a paged forward attends over (ISSUE 24): the rule as
-a pure function — the one the program's trip count and the engine's
-`kv_keys_read` both call — and the two counters in `engine.stats()`."""
+"""How many keys a paged forward attends over (ISSUE 24, ISSUE 40): the
+rule as a pure function — the one the program's trip count and the
+engine's `kv_keys_read` both call — and the two counters in
+`engine.stats()`."""
 
 import jax
 import jax.numpy as jnp
@@ -12,26 +13,55 @@ from ray_tpu.models import generate as g
 TILE, MAX_LEN = 256, 4096
 
 
-@pytest.mark.parametrize("length,tiles", [
-    (0, 0), (1, 1), (TILE, 1), (TILE + 1, 2), (MAX_LEN, MAX_LEN // TILE),
+@pytest.mark.parametrize("length,tiles,trips", [
+    (0, 0, 0), (1, 1, 1), (TILE, 1, 1), (TILE + 1, 2, 1),
+    (MAX_LEN, MAX_LEN // TILE, 5),
 ])
 @pytest.mark.parametrize("xp", [np, jnp], ids=["numpy", "traced"])
-def test_tiles_read_reach_the_longest_alive_row(xp, length, tiles):
+def test_trips_read_are_the_live_pairs_over_the_rows(xp, length, tiles, trips):
     # Two short rows, the row under test, and a dead one whose stale
-    # length must hold nothing open.
+    # length must add no pair: four rows, so four pairs a trip.
     valid_len = xp.asarray([min(length, 1), length, 0, MAX_LEN], np.int32)
     alive = xp.asarray([True, True, True, False])
     read = g.paged_tiles_read
     if xp is jnp:
         read = jax.jit(read, static_argnums=2)
-    assert int(read(valid_len, alive, TILE)) == tiles
+    # The work list holds the first row's tile (if it has a key) and
+    # this row's `tiles`.
+    assert int(read(valid_len, alive, TILE)) == trips == -(
+        -(min(length, 1) + tiles) // 4
+    )
+    # One row (`b == 1`, a prefill chunk) walks its own tiles, one a
+    # trip.
+    assert int(read(valid_len[1:2], alive[1:2], TILE)) == tiles
 
 
 def test_all_rows_dead_read_nothing():
     valid_len = np.asarray([7, MAX_LEN, 300], np.int32)
     assert int(g.paged_tiles_read(valid_len, np.zeros(3, bool), TILE)) == 0
-    # A prefill chunk has no dead row: `alive` defaults to all.
-    assert int(g.paged_tiles_read(valid_len, True, TILE)) == MAX_LEN // TILE
+    # A prefill chunk has no dead row: `alive` defaults to all. 1 + 16
+    # + 2 pairs, three a trip.
+    assert int(g.paged_tiles_read(valid_len, True, TILE)) == 7
+
+
+@pytest.mark.parametrize("xp", [np, jnp], ids=["numpy", "traced"])
+def test_ragged_rows_among_dead_slots_read_little_more_than_they_hold(xp):
+    # `chat_loaded`'s shape: 4 of 16 slots alive, ragged, the dead
+    # ones with stale lengths. The walk to the longest alive row (the
+    # rule until ISSUE 40) read 16 slots x 6 tiles, 6.5 keys for each
+    # live one; the work list holds 6 + 3 + 4 + 3 pairs: one trip.
+    valid_len = np.full(16, 3000, np.int32)
+    alive = np.zeros(16, bool)
+    valid_len[[1, 6, 7, 12]] = [1500, 700, 1000, 600]
+    alive[[1, 6, 7, 12]] = True
+    live = int(valid_len[alive].sum())
+    to_the_longest = 16 * TILE * -(-1500 // TILE)
+    assert to_the_longest / live > 6
+    trips = int(
+        g.paged_tiles_read(xp.asarray(valid_len), xp.asarray(alive), TILE)
+    )
+    assert trips == 1
+    assert 16 * TILE * trips / live < 1.5
 
 
 @pytest.mark.parametrize("block_len,width,q_len,keys", [
@@ -44,10 +74,25 @@ def test_tile_is_whole_blocks_within_the_table(block_len, width, q_len, keys):
     assert g.paged_tile_keys(block_len, width, q_len) == keys
 
 
-def test_engine_counts_keys_live_and_keys_read():
+@pytest.mark.parametrize("tile_keys,read_tiles", [
+    # The table (48 keys) is shorter than a tile: every step is one
+    # trip of two pairs (one per slot) of 48 keys.
+    (g.PAGED_TILE_KEYS, 6),
+    # Tiles of 16 keys: the row's 12..17 keys are 1, 1, 1, 1, 1 and 2
+    # pairs, each step still one trip of two (the walk to the longest
+    # alive row read 7 tiles a slot).
+    (16, 6),
+    # Tiles of 8: 2, 2, 2, 2, 2 and 3 pairs: the last step takes two
+    # trips.
+    (8, 7),
+])
+def test_engine_counts_keys_live_and_keys_read(
+    monkeypatch, tile_keys, read_tiles
+):
     from ray_tpu.llm import EngineConfig, InferenceEngine
     from ray_tpu.models.llama import LlamaConfig, init_params
 
+    monkeypatch.setattr(g, "PAGED_TILE_KEYS", tile_keys)
     cfg = LlamaConfig(
         vocab_size=128, dim=64, n_layers=2, n_heads=4, n_kv_heads=2,
         intermediate=128, max_seq_len=128, dtype=jnp.float32,
@@ -71,14 +116,13 @@ def test_engine_counts_keys_live_and_keys_read():
         assert once["kv_keys_live"] == sum(
             len(prompt) + i + 1 for i in range(new)
         )
-        # The table (48 keys) is shorter than a tile, so every step
-        # walks one tile of 48 keys for each of the two slots.
+        # A program reads a tile for every slot a trip.
         tile = g.paged_tile_keys(once["kv_block_len"], 48 // once["kv_block_len"], 1)
-        assert tile == 48
-        assert once["kv_keys_read"] == new * slots * tile
-        list(engine.submit(prompt[:5], max_new_tokens=new))
+        assert tile == min(tile_keys, 48)
+        assert once["kv_keys_read"] == read_tiles * slots * tile
+        list(engine.submit(prompt, max_new_tokens=new))
         twice = engine.stats()
-        assert twice["kv_keys_live"] > once["kv_keys_live"]
+        assert twice["kv_keys_live"] == 2 * once["kv_keys_live"]
         assert twice["kv_keys_read"] == 2 * once["kv_keys_read"]
     finally:
         engine.close()
