@@ -74,6 +74,7 @@ from .placement_groups import (
     place_bundles,
 )
 from .policies import NodeView, PlacementPolicy
+from .profiling import relay_timeout_s
 from .rpc import DEFERRED, Connection, RpcClient, RpcError, RpcServer
 from .scheduler import LocalScheduler, ResourceSet
 from ray_tpu.devtools.lock_witness import make_lock
@@ -1357,12 +1358,15 @@ class NodeDaemon:
     # -- streaming-generator items (stream_runs.py) ---------------------
     def _h_stream_append(self, conn, msg):
         if not self.is_head:
+            fwd = {"first_ts": msg["first_ts"]} if "first_ts" in msg else {}
             self.head.notify(
                 "stream_append", task=msg["task"], index=msg["index"],
-                data=msg["data"],
+                data=msg["data"], **fwd,
             )
             return {}
-        self._streams.put(msg["task"], msg["index"], msg["data"])
+        self._streams.put(
+            msg["task"], msg["index"], msg["data"], msg.get("first_ts")
+        )
         return {}
 
     def _h_stream_end(self, conn, msg):
@@ -4701,9 +4705,10 @@ class NodeDaemon:
             k: msg[k] for k in self._PROFILE_PARAMS if k in msg
         }
         params.setdefault("kind", "stack")
-        timeout = float(msg.get("duration_s", 5.0)) + 30.0
-        if "start_at" in params:
-            timeout += max(0.0, float(params["start_at"]) - time.time())
+        timeout = relay_timeout_s(
+            params["kind"], msg.get("duration_s", 5.0),
+            params.get("start_at"),
+        )
         return self._profile_target(
             msg.get("node_id"), msg["pid"], timeout, **params
         )
@@ -4728,7 +4733,9 @@ class NodeDaemon:
             # to completion.
             return self.head.call(
                 "profile_gang",
-                timeout=float(msg.get("duration_s", 2.0)) + 120.0,
+                timeout=relay_timeout_s(
+                    "gang", msg.get("duration_s", 2.0)
+                ) + 90.0,
                 **fwd,
             )
         duration_s = min(
@@ -4771,7 +4778,7 @@ class NodeDaemon:
         # samples for the same duration — slices across ranks line up
         # on the shared clock instead of staggering by fan-out order.
         start_at = time.time() + 0.5
-        timeout = duration_s + 30.0 + (start_at - time.time())
+        timeout = relay_timeout_s("gang", duration_s, start_at)
 
         def capture(item):
             (node_hex, pid), rank = item

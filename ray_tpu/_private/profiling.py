@@ -244,6 +244,41 @@ def sample_timeline(
     }
 
 
+#: What the profile relay allows a capture beyond its window: the
+#: worker's direct call, the sampler's merge, the reply's way back.
+RELAY_SLACK_S = 30.0
+#: Seconds `jax.profiler.stop_trace` may take per second traced. It
+#: collects and writes every device event the window recorded, so its
+#: cost grows with the window: a serve replica whose chip never idles
+#: records some 237,000 operations a second (a 36-layer model at 117
+#: programs a second) and stopping took 27-29 s per traced second
+#: there, whatever the profiler's options (Python tracer, host tracer
+#: level, HLO protos, the TPU's trace mode) but host-only, which has
+#: no device events (PERF.md section 6, PR 41). Half as much again.
+GANG_STOP_S_PER_TRACED_S = 40.0
+
+
+def relay_timeout_s(
+    kind: str,
+    duration_s: float,
+    start_at: Optional[float] = None,
+    now: Optional[float] = None,
+) -> float:
+    """How long a relay of the profile path (state.profile_worker ->
+    daemon -> worker) waits for one capture of `duration_s`: the
+    window, the slack, for a gang capture the time stopping the
+    `jax.profiler` trace may take, and the wait for a synchronized
+    window's `start_at` (epoch seconds)."""
+    timeout = float(duration_s) + RELAY_SLACK_S
+    if kind == "gang":
+        timeout += GANG_STOP_S_PER_TRACED_S * float(duration_s)
+    if start_at is not None:
+        timeout += max(
+            0.0, float(start_at) - (time.time() if now is None else now)
+        )
+    return timeout
+
+
 def capture_gang(
     duration_s: float = 2.0,
     hz: float = 100.0,
@@ -255,8 +290,11 @@ def capture_gang(
     runs under a `jax.profiler` trace whose artifact directory rides
     back in the result: device operations, and the host phases
     `step_telemetry.phase_timer` names (the engine loop's, the input
-    path's). Alongside it the in-process timeline sampler provides
-    the chrome-trace slices the head merges. jax is only touched when
+    path's). `jax_trace_stop_s` says what stopping the trace took: on
+    a busy chip many times the window (`GANG_STOP_S_PER_TRACED_S`),
+    during which the process goes on serving. Alongside it the
+    in-process timeline sampler provides the chrome-trace slices the
+    head merges. jax is only touched when
     the process already imported it; failures degrade to sampler-only,
     never fail the capture."""
     trace_dir = None
@@ -278,6 +316,7 @@ def capture_gang(
             duration_s=duration_s, hz=hz, start_at=start_at
         )
     finally:
+        stop_t0 = time.perf_counter()
         if profiler is not None:
             try:
                 profiler.profiler.stop_trace()
@@ -285,6 +324,7 @@ def capture_gang(
                 trace_dir = None
     if trace_dir is not None:
         result["jax_trace_dir"] = trace_dir
+        result["jax_trace_stop_s"] = time.perf_counter() - stop_t0
     return result
 
 

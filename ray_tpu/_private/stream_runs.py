@@ -26,7 +26,9 @@ CLOSED_KEPT_S = 600.0
 
 
 class _Run:
-    __slots__ = ("items", "base", "end", "parked", "closed_at")
+    __slots__ = (
+        "items", "base", "end", "parked", "closed_at", "first_ts"
+    )
 
     def __init__(self):
         #: items[k] is item base + k: serialized bytes, or None for an
@@ -41,6 +43,9 @@ class _Run:
         self.end: Optional[tuple] = None
         self.parked: Optional[tuple] = None  # (conn, mid, after)
         self.closed_at: Optional[float] = None
+        #: The producer's epoch time on item 0, if it stamped one; it
+        #: rides in the answer that brings item 0 and in no other.
+        self.first_ts: Optional[float] = None
 
 
 class StreamRuns:
@@ -82,7 +87,10 @@ class StreamRuns:
         run.parked = None
         if end is not None:
             self._close(run)
-        return conn, mid, {"items": items, "end": end}
+        reply = {"items": items, "end": end}
+        if after == 0 and items and run.first_ts is not None:
+            reply["first_ts"] = run.first_ts
+        return conn, mid, reply
 
     @staticmethod
     def _send(answer: Optional[tuple]) -> None:
@@ -90,7 +98,13 @@ class StreamRuns:
             conn, mid, reply = answer
             conn.reply(mid, reply)
 
-    def put(self, task: bytes, index: int, data: Optional[bytes]) -> None:
+    def put(
+        self,
+        task: bytes,
+        index: int,
+        data: Optional[bytes],
+        first_ts: Optional[float] = None,
+    ) -> None:
         with self._lock:
             run = self._run(task)
             if run.closed_at is not None:
@@ -100,6 +114,8 @@ class StreamRuns:
                 # the same ids; the consumer may have them already.
                 return
             run.items.append(data)
+            if first_ts is not None:
+                run.first_ts = first_ts
             answer = self._answer(run)
         self._send(answer)
 

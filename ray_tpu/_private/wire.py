@@ -461,8 +461,10 @@ SCHEMAS: Dict[str, Dict[str, Any]] = {
     # Streaming-generator items (stream_runs.py): the producer
     # appends, somebody who knows reports the end, the consumer parks
     # one request per stream and says when it will ask no more.
+    # `first_ts`: the producer's epoch time on a run's FIRST item.
     "stream_append": {
         "task": bytes, "index": int, "data": (bytes, type(None)),
+        "?first_ts": float,
     },
     "stream_end": {
         "task": bytes, "?count": (int, type(None)),

@@ -2131,9 +2131,14 @@ class CoreWorker:
             for item in value:
                 oid = ObjectID.for_return(task_id, count + 2)
                 if streaming:
+                    # The run's first item carries the time it was
+                    # handed to the transport: what its consumer
+                    # counts the item's way from (serve: a stream's
+                    # first token, observability.py B6).
+                    stamp = {} if count else {"first_ts": time.time()}
                     self._client.notify(
                         "stream_append", task=task, index=count,
-                        data=self._stream_item_bytes(oid, item),
+                        data=self._stream_item_bytes(oid, item), **stamp,
                     )
                 else:
                     self.put_object(oid, item)

@@ -279,6 +279,12 @@ class TokenStream:
     def request_id(self) -> str:
         return self._req.request_id
 
+    @property
+    def first_token_ts(self) -> Optional[float]:
+        """The loop's `perf_counter` when it emitted this request's
+        first token; None until then."""
+        return self._req.first_token_ts
+
     def __iter__(self) -> Iterator[int]:
         return self
 
@@ -517,6 +523,8 @@ class InferenceEngine:
         self._loop_iterations = 0
         self._admitted = 0
         self._admit_wait_ms_total = 0.0
+        self._first_tokens = 0
+        self._prefill_ms_total = 0.0
         # What decode's attention touches, per step, from the lengths
         # the host holds: keys inside alive rows' `valid_len`, and
         # keys the step's program attends over all rows (whole tiles
@@ -606,8 +614,12 @@ class InferenceEngine:
         )
         req.bucket = bucket
         req.total_blocks = total_blocks
-        from ..serve.observability import get_request_id
+        from ..serve.observability import (
+            get_request_id,
+            observe_handler_submit,
+        )
 
+        observe_handler_submit(req.submitted_ts)
         req.trace_parent = tracing.inject_context()
         req.serve_request_id = get_request_id()  # "" outside serve
         if ec.prefix_cache:
@@ -753,6 +765,11 @@ class InferenceEngine:
                 loop_iterations=self._loop_iterations,
                 admitted=self._admitted,
                 admit_wait_ms_total=self._admit_wait_ms_total,
+                # Admission to first token, of the requests that
+                # reached one: the chunks of their prompts AND the
+                # decode steps that ran between them.
+                first_tokens=self._first_tokens,
+                prefill_ms_total=self._prefill_ms_total,
                 kv_keys_live=self._kv_keys_live,
                 kv_keys_read=self._kv_keys_read,
                 # The pipeline: chunks and steps dispatched, those
@@ -1023,6 +1040,11 @@ class InferenceEngine:
         now = time.perf_counter()
         admitted = req.admitted_ts if req.admitted_ts is not None else now
         decoding = req.decoding_ts if req.decoding_ts is not None else now
+        first = (
+            {} if req.first_token_ts is None else {"first_token_ms": round(
+                (req.first_token_ts - req.submitted_ts) * 1e3, 3
+            )}
+        )
         tracing.record_span(
             "engine.request",
             req.submitted_ns,
@@ -1036,6 +1058,7 @@ class InferenceEngine:
             decode_ms=round((now - decoding) * 1e3, 3),
             tokens=req.emitted,
             finish_reason=reason,
+            **first,
         )
 
     def _fail_all_locked(self, error: BaseException) -> None:
@@ -1507,6 +1530,10 @@ class InferenceEngine:
                 tok = int(tokens[slot])
                 if req.first_token_ts is None:
                     req.first_token_ts = now
+                    self._first_tokens += 1
+                    self._prefill_ms_total += (
+                        now - req.admitted_ts
+                    ) * 1e3
                     self._observe_ttft(
                         (now - req.submitted_ts) * 1e3
                     )

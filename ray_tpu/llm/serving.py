@@ -175,7 +175,10 @@ class LLMServer:
         max_new = None if max_new is None else int(max_new)
         eos = payload.get("eos_token")
         eos = None if eos is None else int(eos)
-        from ..serve.observability import get_request_id
+        from ..serve.observability import (
+            get_request_id,
+            observe_first_item,
+        )
 
         engine = self.get_engine(family)
         # The engine mints its own UNIQUE id (a client-controlled
@@ -200,6 +203,12 @@ class LLMServer:
         if cancelled_early:
             stream.cancel()
         try:
+            # The first token is B6 of the request's first-token
+            # stages: one reading, then the same iterator goes on.
+            for token in stream:
+                observe_first_item(stream.first_token_ts)
+                yield f"{token} ".encode()
+                break
             for token in stream:
                 yield f"{token} ".encode()
         finally:

@@ -138,6 +138,10 @@ class ObjectRefGenerator:
         #: with the producer, more where it does not).
         self.stream_items = 0
         self.stream_fetches = 0
+        #: The producer's epoch time on the stream's first item, from
+        #: the answer that brought it (None before, and where the
+        #: producer stamped none).
+        self.first_item_ts: float | None = None
 
     def _ref(self, object_id: ObjectID) -> ObjectRef:
         return ObjectRef(object_id, owner=self._owner)
@@ -183,6 +187,8 @@ class ObjectRefGenerator:
             self.stream_items += len(reply["items"])
             self._fetched.extend(reply["items"])
             self._end = reply["end"]
+            if "first_ts" in reply:
+                self.first_item_ts = reply["first_ts"]
         oid = self._item_id(self._index)
         self._index += 1
         return oid, self._fetched.popleft()

@@ -11,10 +11,51 @@ into per-deployment histograms:
   serve_stream_fetches            consumer received and its requests
   serve_queue_wait_ms             replica: send -> execution start
   serve_request_latency_ms        replica: handler execution time
+                                  (a stream's: the generator's first
+                                  advance to its exhaustion)
   serve_model_load_ms             multiplex: model swap (load) time
   serve_requests_total            replica: completions by outcome
   serve_http_requests_total       proxy: completions by status class
   serve_replica_executing         replica: currently-executing gauge
+
+A streamed request's way to its first token is cut at eight
+boundaries, each a clock reading where the work happens, one series
+per adjacent pair, observed ONCE per request and never per token:
+
+  B0 HTTP request read (proxy)          B4 admitted to a slot (engine)
+  B1 replica chosen, call sent (proxy)  B5 first token emitted (engine)
+  B2 handler starts (replica)           B6 first item handed to the
+  B3 engine.submit() (replica)             transport (replica)
+                                        B7 first body bytes written
+                                           (proxy)
+
+  serve_http_dispatch_ms          B0->B1 proxy: route, body, handle,
+                                  replica choice (serve_router_routing_ms
+                                  lies inside it), the context
+  serve_queue_wait_ms             B1->B2 (above)
+  serve_handler_submit_ms         B2->B3 replica: the handler before the
+                                  engine has the request (a replica's
+                                  first request: the model's load)
+  engine.stats() admitted,        B3->B4 engine: the wait for a slot
+  admit_wait_ms_total
+  engine.stats() first_tokens,    B4->B5 engine: admission to first
+  prefill_ms_total                token, the chunks and the decode steps
+                                  between them (B3->B5 stays
+                                  serve_engine_ttft_ms)
+  serve_first_item_handoff_ms     B5->B6 replica: loop thread's put to
+                                  the handler thread's first yield
+  serve_first_item_transit_ms     B6->B7 the first item's way through the
+                                  head daemon to the proxy's socket
+                                  (epoch clocks of two processes:
+                                  approximate across hosts, as
+                                  serve_queue_wait_ms is)
+  serve_http_first_byte_ms        B0->B7 proxy: the program's own time
+                                  to first token
+
+The same readings ride the request's three spans as attributes
+(`serve.http` first_byte_ms; `serve.handle` queue_wait_ms, submit_ms,
+first_item_ms; `engine.request` first_token_ms), each counted from its
+span's start.
 
 All ride the existing metrics pipe (util/metrics) to the head, so
 they show up in `metrics_summary()`, the Prometheus endpoint and the
@@ -56,7 +97,11 @@ __all__ = [
     "reset_request_context",
     "observe_http",
     "observe_routing",
+    "observe_http_dispatch",
+    "observe_http_first_byte",
     "observe_queue_wait",
+    "observe_handler_submit",
+    "observe_first_item",
     "observe_handler",
     "observe_model_load",
     "replica_executing",
@@ -251,6 +296,68 @@ def observe_routing(app: str, deployment: str, dur_ms: float) -> None:
         pass
 
 
+def _observe_stage(
+    name: str, description: str, app: str, deployment: str, dur_ms: float
+) -> None:
+    """One reading of a request's stage, clamped at 0 where two
+    processes' clocks meet; the callers see to `_ENABLED` and, for the
+    first-token stages, to once per streamed request."""
+    try:
+        _histogram(name, description, ("app", "deployment")).observe(
+            max(0.0, dur_ms),
+            tags={"app": app, "deployment": deployment},
+        )
+    except Exception:
+        pass
+
+
+def observe_http_dispatch(
+    app: str, deployment: str, dur_ms: float
+) -> None:
+    """Proxy, B0->B1: HTTP request read to the replica call sent
+    (route refresh and match, body read, the handle, replica choice,
+    the context). Streamed requests only."""
+    if not _ENABLED:
+        return
+    _observe_stage(
+        "serve_http_dispatch_ms",
+        "HTTP request read to replica call sent, per streamed request",
+        app, deployment, dur_ms,
+    )
+
+
+def observe_http_first_byte(
+    app: str,
+    deployment: str,
+    first_byte_ms: float,
+    first_item_ts: Optional[float],
+) -> None:
+    """Proxy, at the first body bytes of a streamed response (B7):
+    B0->B7, the program's own time to first token, onto the series
+    and the `serve.http` span; and B6->B7 from the epoch stamp the
+    producer put on the stream's first item (None where the transport
+    carried none: nothing observed)."""
+    if not _ENABLED:
+        return
+    from ..util.tracing import add_span_attributes
+
+    now = time.time()  # B7 on the epoch clock, beside the caller's reading
+    _observe_stage(
+        "serve_http_first_byte_ms",
+        "HTTP request read to first body bytes written, per streamed "
+        "request",
+        app, deployment, first_byte_ms,
+    )
+    if first_item_ts is not None:
+        _observe_stage(
+            "serve_first_item_transit_ms",
+            "First stream item handed to the transport to its bytes "
+            "written by the proxy, per streamed request",
+            app, deployment, (now - first_item_ts) * 1e3,
+        )
+    add_span_attributes(first_byte_ms=round(first_byte_ms, 3))
+
+
 def observe_stream(
     app: str, deployment: str, items: int, fetches: int
 ) -> None:
@@ -283,17 +390,58 @@ def observe_queue_wait(
     time; cross-host clock skew makes this approximate off-box)."""
     if not _ENABLED:
         return
-    try:
-        _histogram(
-            "serve_queue_wait_ms",
-            "Router-send to handler-start wait per request",
-            ("app", "deployment"),
-        ).observe(
-            max(0.0, dur_ms),
-            tags={"app": app, "deployment": deployment},
-        )
-    except Exception:
-        pass
+    _observe_stage(
+        "serve_queue_wait_ms",
+        "Router-send to handler-start wait per request",
+        app, deployment, dur_ms,
+    )
+
+
+def observe_handler_submit(submitted_ts: float) -> None:
+    """Replica, B2->B3: the streaming handler's start (the reading the
+    replica left in the request context, its `perf_counter`) to
+    `engine.submit()`. The context keeps the result, so a handler that
+    submits twice observes once; outside a streamed serve request
+    there is no reading and nothing is observed."""
+    ctx = _request_ctx.get()
+    if not _ENABLED or not ctx or "submit_ms" in ctx:
+        return
+    started = ctx.get("handler_started_ts")
+    if started is None:
+        return
+    from ..util.tracing import add_span_attributes
+
+    dur_ms = ctx["submit_ms"] = (submitted_ts - started) * 1e3
+    _observe_stage(
+        "serve_handler_submit_ms",
+        "Handler start to engine.submit(), per streamed request",
+        str(ctx.get("app", "")), str(ctx.get("deployment", "")), dur_ms,
+    )
+    add_span_attributes(submit_ms=round(dur_ms, 3))
+
+
+def observe_first_item(first_token_ts: Optional[float]) -> None:
+    """Replica handler, at its first token (B6), before the yield
+    that hands it to the transport: B5->B6 from the engine loop's
+    reading of the first token (same interpreter, same clock), and
+    the `serve.handle` span's `first_item_ms`, counted from the
+    handler's start."""
+    ctx = _request_ctx.get()
+    if not _ENABLED or not ctx or first_token_ts is None:
+        return
+    from ..util.tracing import add_span_attributes
+
+    now = time.perf_counter()
+    _observe_stage(
+        "serve_first_item_handoff_ms",
+        "Engine loop's first token to the handler's first yield, per "
+        "streamed request",
+        str(ctx.get("app", "")), str(ctx.get("deployment", "")),
+        (now - first_token_ts) * 1e3,
+    )
+    started = ctx.get("handler_started_ts")
+    if started is not None:
+        add_span_attributes(first_item_ms=round((now - started) * 1e3, 3))
 
 
 def observe_handler(

@@ -379,8 +379,9 @@ class Proxy:
         # Filled by _route_request with the route that actually
         # served the request — re-matching in the finally would both
         # rescan the table and misattribute across a mid-request
-        # route-table refresh.
-        target = {"app": "", "deployment": ""}
+        # route-table refresh. `t0` (B0) is what a streamed request's
+        # first-token stages count from.
+        target = {"app": "", "deployment": "", "t0": t0}
         status = 500
         try:
             with span(
@@ -411,6 +412,7 @@ class Proxy:
             )
 
     def _route_request(self, handler, parsed, request_id, target):
+        from .observability import observe_http_dispatch
         from .router import DeploymentHandle, DeploymentOverloaded
 
         self._refresh_routes()
@@ -458,7 +460,10 @@ class Proxy:
                     multiplexed_model_id=model_id,
                     request_id=request_id,
                 ).remote(request)
-                self._stream_response(handler, chunks)
+                observe_http_dispatch(
+                    app, ingress, (chunks.sent_ts - target["t0"]) * 1e3
+                )
+                self._stream_response(handler, chunks, target)
                 return None
             handle = handle.options(
                 multiplexed_model_id=model_id, request_id=request_id
@@ -493,10 +498,15 @@ class Proxy:
             "application/json",
         )
 
-    def _stream_response(self, handler, chunks) -> None:
+    def _stream_response(self, handler, chunks, target) -> None:
         """Chunked transfer-encoding: each replica yield goes on the
         wire immediately (reference: proxy.py streaming ASGI
-        responses for generator deployments — LLM token output)."""
+        responses for generator deployments — LLM token output). The
+        first chunk written is B7 of the request's first-token stages
+        (observability.py): one reading, then the loop goes on over
+        the same iterator with nothing per chunk."""
+        from .observability import observe_http_first_byte
+
         handler.send_response(200)
         handler.send_header("Content-Type", "text/plain; charset=utf-8")
         # Streaming clients need the id MOST (runbook: grep a slow
@@ -514,21 +524,31 @@ class Proxy:
         # aborts the socket so the client observes a truncated chunked
         # body (a detectable failure) instead of a well-formed 200 with
         # silently missing content.
+        def write(chunk) -> bool:
+            data = (
+                chunk if isinstance(chunk, bytes) else str(chunk).encode()
+            )
+            if data:
+                handler.wfile.write(
+                    f"{len(data):X}\r\n".encode() + data + b"\r\n"
+                )
+                handler.wfile.flush()
+            return bool(data)
+
         clean = False
         try:
             try:
                 for chunk in chunks:
-                    data = (
-                        chunk
-                        if isinstance(chunk, bytes)
-                        else str(chunk).encode()
-                    )
-                    if not data:
-                        continue
-                    handler.wfile.write(
-                        f"{len(data):X}\r\n".encode() + data + b"\r\n"
-                    )
-                    handler.wfile.flush()
+                    if write(chunk):
+                        observe_http_first_byte(
+                            target["app"],
+                            target["deployment"],
+                            (time.perf_counter() - target["t0"]) * 1e3,
+                            chunks.first_item_ts,
+                        )
+                        break
+                for chunk in chunks:
+                    write(chunk)
                 clean = True
             finally:
                 # Releases the router's ongoing-count slot even when

@@ -11,7 +11,7 @@ from __future__ import annotations
 import os
 import threading
 import time
-from typing import Any
+from typing import Any, Optional
 
 
 class HandleRef:
@@ -90,10 +90,11 @@ class Replica:
         self._served_lock = threading.Lock()
         self._started = time.time()
 
-    def _begin_request(self, ctx: dict) -> None:
-        """Request-entry bookkeeping: queue wait (router send -> here)
-        and the executing gauge routers/`/api/serve` subtract from
-        in-flight to derive queue depth."""
+    def _begin_request(self, ctx: dict) -> Optional[float]:
+        """Request-entry bookkeeping: queue wait (router send -> here;
+        returned in ms, None where the caller sent no time) and the
+        executing gauge routers/`/api/serve` subtract from in-flight
+        to derive queue depth."""
         from .observability import (
             observe_queue_wait,
             replica_executing,
@@ -104,11 +105,11 @@ class Replica:
             self._executing += 1
             executing = self._executing
         sent = ctx.get("sent_ts")
+        wait_ms = None
         if sent is not None:
+            wait_ms = max(0.0, (time.time() - float(sent)) * 1e3)
             observe_queue_wait(
-                self._app_name,
-                self._deployment_name,
-                (time.time() - float(sent)) * 1e3,
+                self._app_name, self._deployment_name, wait_ms
             )
         replica_executing(
             self._app_name,
@@ -116,6 +117,7 @@ class Replica:
             self.replica_id,
             executing,
         )
+        return wait_ms
 
     def _end_request(self) -> None:
         from .observability import replica_executing
@@ -198,9 +200,12 @@ class Replica:
         streaming-generator transport (reference: replica.py
         handle_request_streaming + StreamingObjectRefGenerator).
         Called with num_returns='streaming' by the router. Latency is
-        recorded over the WHOLE stream (first yield to exhaustion) —
+        recorded over the WHOLE stream (this generator's first advance,
+        when the actor's thread takes the call, to its exhaustion) —
         the number a token-streaming client experiences, and what its
-        `serve.handle` span covers."""
+        `serve.handle` span covers. The handler's start (B2 of the
+        first-token stages, observability.py) is left in the request
+        context for whoever observes the stages below it."""
         from ..util.tracing import remote_parent, span
 
         from .multiplex import _model_id_ctx, _set_request_model_id
@@ -211,7 +216,8 @@ class Replica:
         )
 
         ctx = ctx or {}
-        self._begin_request(ctx)
+        wait_ms = self._begin_request(ctx)
+        t0 = ctx["handler_started_ts"] = time.perf_counter()
         target = (
             self._instance
             if method == "__call__"
@@ -220,13 +226,16 @@ class Replica:
         token = _set_request_model_id(model_id)
         ctx_token = request_context(ctx)
         request_id = str(ctx.get("request_id", ""))
-        t0 = time.perf_counter()
         error = False
         try:
             with remote_parent(ctx.get("trace")), span(
                 "serve.handle",
                 request_id=request_id,
                 deployment=f"{self._app_name}/{self._deployment_name}",
+                **(
+                    {} if wait_ms is None
+                    else {"queue_wait_ms": round(wait_ms, 3)}
+                ),
             ):
                 yield from target(*args, **kwargs)
         except BaseException:

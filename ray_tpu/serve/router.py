@@ -219,7 +219,11 @@ class DeploymentResponseGenerator:
         actor=None,
         request_id: str = "",
         tokens: int = 0,
+        sent_ts: float = 0.0,
     ):
+        #: This process's `perf_counter` when the call was sent (B1
+        #: of the first-token stages, observability.py).
+        self.sent_ts = sent_ts
         self._gen = ref_gen
         self._router = router
         self._replica_id = replica_id
@@ -231,6 +235,13 @@ class DeploymentResponseGenerator:
 
     def __iter__(self):
         return self
+
+    @property
+    def first_item_ts(self) -> Optional[float]:
+        """The producer's epoch stamp on the stream's first item, once
+        that item has been received; None before, and where the
+        transport carried none."""
+        return self._gen.first_item_ts
 
     def __next__(self):
         if self._finished:
@@ -746,6 +757,7 @@ class DeploymentHandle:
         self._slo_admit(replica, tokens)
         ctx = self._request_ctx()
         if self._stream:
+            sent_ts = time.perf_counter()  # B1, beside ctx["sent_ts"]
             ref_gen = replica["actor"].handle_request_streaming.options(
                 num_returns="streaming"
             ).remote(self._method, args, kwargs, self._model_id, ctx)
@@ -757,6 +769,7 @@ class DeploymentHandle:
                 actor=replica["actor"],
                 request_id=str(ctx.get("request_id", "")),
                 tokens=tokens,
+                sent_ts=sent_ts,
             )
         ref = replica["actor"].handle_request.remote(
             self._method, args, kwargs, self._model_id, ctx

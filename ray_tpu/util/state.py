@@ -142,8 +142,14 @@ def profile_worker(
     }
     if node_id is not None:
         kwargs["node_id"] = bytes.fromhex(node_id)
+    from .._private.profiling import relay_timeout_s
+
+    # Ten seconds over the daemon's own wait for the worker, so that a
+    # capture that ran to its end is never thrown away here.
     return _worker().call(
-        "profile_worker", timeout=float(duration_s) + 40.0, **kwargs
+        "profile_worker",
+        timeout=relay_timeout_s(kind, duration_s) + 10.0,
+        **kwargs,
     )
 
 
@@ -170,9 +176,11 @@ def profile_gang(
     }
     if job_id is not None:
         kwargs["job"] = str(job_id)
+    from .._private.profiling import relay_timeout_s
+
     reply = _worker().call(
         "profile_gang",
-        timeout=float(duration_s) + 120.0,
+        timeout=relay_timeout_s("gang", duration_s) + 90.0,
         **kwargs,
     )
     if path is not None:

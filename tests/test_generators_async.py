@@ -277,6 +277,58 @@ def test_streaming_costs_the_head_nothing_per_item(
     assert len(rt.api._session.daemon.objects) <= objects_before + 1
 
 
+def test_a_runs_first_item_carries_its_producers_time_once(rt_session):
+    """The producer stamps a run's FIRST item with its epoch time (one
+    field on one `stream_append`); the answer that brings item 0
+    carries it to the consumer and no other answer does."""
+    import time
+
+    from ray_tpu._private.stream_runs import StreamRuns
+
+    class Conn:
+        conn_id = 1
+
+        def __init__(self):
+            self.replies = []
+
+        def reply(self, mid, reply):
+            self.replies.append(reply)
+
+    runs, conn = StreamRuns(), Conn()
+    runs.put(b"t", 0, b"a", first_ts=123.5)
+    runs.put(b"t", 1, b"b")
+    runs.fetch(conn, 1, b"t", 0)
+    runs.fetch(conn, 2, b"t", 2)
+    runs.put(b"t", 2, b"c")
+    runs.end(b"t", 3, None)
+    runs.fetch(conn, 3, b"t", 3)
+    assert [r["items"] for r in conn.replies] == [[b"a", b"b"], [b"c"], []]
+    assert [r.get("first_ts") for r in conn.replies] == [123.5, None, None]
+    assert "first_ts" not in conn.replies[1]
+    # A producer that stamps nothing (a run relayed by an older node):
+    # the answer has no such key.
+    runs.put(b"u", 0, b"a")
+    runs.fetch(conn, 4, b"u", 0)
+    assert "first_ts" not in conn.replies[-1]
+
+    rt = rt_session
+
+    @rt.remote(num_returns="streaming")
+    def slow(n):
+        for i in range(n):
+            time.sleep(0.05)
+            yield i
+
+    gen = slow.remote(4)
+    assert gen.first_item_ts is None
+    before = time.time()
+    assert gen.next_value() == 0
+    stamp = gen.first_item_ts
+    assert before <= stamp <= time.time()
+    assert [gen.next_value() for _ in range(3)] == [1, 2, 3]
+    assert gen.first_item_ts == stamp
+
+
 def test_streaming_non_generator_rejected(rt_session):
     rt = rt_session
 

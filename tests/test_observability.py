@@ -451,3 +451,238 @@ def test_event_stats_per_handler_timing(rt_session):
     # errors asserted only on a handler THIS test exercised — other
     # handlers may legitimately carry errors from session traffic.
     assert stats["register_client"]["errors"] == 0
+
+
+# -- a streamed request's first token, stage by stage (ISSUE 41) -------
+# Keep these LAST in the file: the cluster below is module-scoped, and
+# the tests above each make and shut down a session of their own.
+
+#: The series a streamed request observes once each on its way to its
+#: first token (serve/observability.py, boundaries B0 to B7).
+STAGE_SERIES = (
+    "serve_http_dispatch_ms", "serve_handler_submit_ms",
+    "serve_first_item_handoff_ms", "serve_first_item_transit_ms",
+    "serve_http_first_byte_ms",
+)
+OLD_SERIES = (
+    "serve_queue_wait_ms", "serve_engine_ttft_ms",
+    "serve_http_request_latency_ms",
+)
+ENGINE_COUNTERS = (
+    "admitted", "admit_wait_ms_total", "first_tokens", "prefill_ms_total",
+    "tokens_emitted",
+)
+TINY_LLM = {
+    "vocab_size": 128, "dim": 64, "n_layers": 2, "n_heads": 4,
+    "n_kv_heads": 2, "intermediate": 128, "max_seq_len": 128,
+    "dtype": "float32",
+}
+
+
+class FirstTokenCluster:
+    """proxy -> router -> replica -> a tiny engine at `/llm`, and a
+    handler that streams nothing at `/echo`."""
+
+    def __init__(self, rt, serve, port, actor):
+        self.rt, self.serve, self.port, self._actor = rt, serve, port, actor
+
+    def post(self, path, payload, request_id="", on_first=None):
+        import http.client
+
+        conn = http.client.HTTPConnection("127.0.0.1", self.port, timeout=120)
+        headers = {"Content-Type": "application/json"}
+        if request_id:
+            headers["x-request-id"] = request_id
+        try:
+            conn.request("POST", path, body=json.dumps(payload), headers=headers)
+            resp = conn.getresponse()
+            first = resp.read(2)
+            if on_first is not None:
+                on_first()
+            return resp.status, first + resp.read(), dict(resp.getheaders())
+        finally:
+            conn.close()
+
+    def readings(self):
+        """[sum, count] of every serve series on the head and the
+        engine's counters, once they have settled."""
+        from ray_tpu.util.metrics import metrics_summary
+
+        def read():
+            table = {
+                name: (float(row.get("sum", 0.0)), float(row.get("count", 0.0)))
+                for name, row in metrics_summary().items()
+                # (the replica's handler timer counts this read's own
+                # call for the engine's counters)
+                if name.startswith("serve_") and "count" in row
+                and name != "serve_request_latency_ms"
+            }
+            engine = self.rt.get(
+                self._actor.handle_request.remote("engine_stats", (), {}),
+                timeout=60,
+            ).get("tiny") or {}
+            return table, {k: engine.get(k, 0) for k in ENGINE_COUNTERS}
+
+        # Every process flushes each half second: three reads in a
+        # row that agree have seen the flush of each.
+        deadline = time.monotonic() + 20.0
+        reads = [read()]
+        while True:
+            time.sleep(0.7)
+            reads.append(read())
+            if reads[-3:].count(reads[-1]) == 3 or time.monotonic() > deadline:
+                return reads[-1]
+
+    def spans(self, request_id):
+        by_name = {}
+        deadline = time.monotonic() + 10.0
+        while len(by_name) < 3 and time.monotonic() < deadline:
+            for s in self.rt.api._session.worker.call(
+                "list_spans", limit=10000
+            )["spans"]:
+                if s["attributes"].get("request_id") == request_id:
+                    by_name[s["name"]] = s
+            time.sleep(0.05)
+        return by_name
+
+
+@pytest.fixture(scope="module")
+def first_token_cluster():
+    import os
+
+    import ray_tpu as rt
+    import ray_tpu.serve as serve
+    from ray_tpu.llm import build_llm_app
+    from ray_tpu.serve.controller import CONTROLLER_NAME
+
+    # The proxy's process reads the SLO threshold from its environment:
+    # with 8 tokens a request is shed while another still streams.
+    key = "RT_serve_slo_queue_threshold_tokens"
+    old = os.environ.get(key)
+    os.environ[key] = "8"
+    rt.init(num_cpus=4, ignore_reinit_error=False)
+    try:
+        serve.run(
+            build_llm_app(
+                {"tiny": {"kind": "init", "seed": 0, "config": TINY_LLM}},
+                engine={"slots": 2, "max_len": 128, "prefill_chunk": 8},
+            ),
+            name="llm", route_prefix="/llm",
+        )
+
+        @serve.deployment
+        class Echo:
+            def __call__(self, request):
+                return {"echo": request.json()}
+
+        serve.run(Echo.bind(), name="echo", route_prefix="/echo")
+        port = serve.start(http_port=0)
+        controller = rt.get_actor(CONTROLLER_NAME, namespace="serve")
+        rows = rt.get(controller.get_replicas.remote("llm", "llm"), timeout=60)
+        cluster = FirstTokenCluster(rt, serve, port, rows[0]["actor"])
+        # The replica's first request loads the model and compiles.
+        status, body, _ = cluster.post(
+            "/llm", {"prompt": [3, 1, 4, 1, 5], "max_new_tokens": 4}
+        )
+        assert status == 200 and len(body.split()) == 4
+        yield cluster
+    finally:
+        serve.shutdown()
+        rt.shutdown()
+        if old is None:
+            os.environ.pop(key, None)
+        else:
+            os.environ[key] = old
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("case", ["stream", "unary", "shed"])
+def test_first_token_stages_are_observed_once_a_request(
+    first_token_cluster, case
+):
+    cluster = first_token_cluster
+    before, engine_before = cluster.readings()
+    shed = []
+    if case == "stream":
+        status, body, headers = cluster.post(
+            "/llm", {"prompt": [2, 7, 1, 8, 2, 8], "max_new_tokens": 32},
+            request_id=f"stages-{case}",
+        )
+        assert status == 200 and len(body.split()) == 32
+    elif case == "unary":
+        status, body, _ = cluster.post("/echo", {"x": 1})
+        assert status == 200 and json.loads(body) == {"echo": {"x": 1}}
+    else:
+        # A second request while the first still holds most of its 96
+        # tokens against a threshold of 8: the proxy answers 503.
+        status, body, _ = cluster.post(
+            "/llm", {"prompt": [1, 2, 3], "max_new_tokens": 96},
+            on_first=lambda: shed.append(cluster.post(
+                "/llm", {"prompt": [4, 5, 6], "max_new_tokens": 96}
+            )),
+        )
+        assert status == 200 and len(body.split()) == 96
+        assert shed[0][0] == 503 and "Retry-After" in shed[0][2]
+    after, engine_after = cluster.readings()
+
+    def delta(name):
+        s0, n0 = before.get(name, (0.0, 0.0))
+        s1, n1 = after.get(name, (0.0, 0.0))
+        return s1 - s0, n1 - n0
+
+    streamed = 0 if case == "unary" else 1
+    # Exactly one observation a streamed request (of 32 or 96 tokens),
+    # none a token; a unary call and a 503 observe nothing new.
+    for name in STAGE_SERIES:
+        assert delta(name)[1] == streamed, (name, delta(name))
+    engine = {
+        k: engine_after[k] - engine_before[k] for k in ENGINE_COUNTERS
+    }
+    assert engine["first_tokens"] == engine["admitted"] == streamed
+    assert delta("serve_http_request_latency_ms")[1] == 1 + len(shed)
+    if case != "stream":
+        return
+    assert engine["tokens_emitted"] == 32
+    stage = {name: delta(name)[0] for name in STAGE_SERIES + OLD_SERIES}
+    assert [delta(name)[1] for name in OLD_SERIES] == [1, 1, 1]
+    assert all(v >= 0.0 for v in stage.values()), stage
+    admit, prefill = engine["admit_wait_ms_total"], engine["prefill_ms_total"]
+    assert admit >= 0.0 and prefill > 0.0
+    # The engine's own time to first token is its two stages.
+    assert stage["serve_engine_ttft_ms"] == pytest.approx(
+        admit + prefill, abs=0.01
+    )
+    # The stages sum to the program's own time to first token.
+    first_byte = stage["serve_http_first_byte_ms"]
+    parts = (
+        stage["serve_http_dispatch_ms"] + stage["serve_queue_wait_ms"]
+        + stage["serve_handler_submit_ms"] + admit + prefill
+        + stage["serve_first_item_handoff_ms"]
+        + stage["serve_first_item_transit_ms"]
+    )
+    assert abs(first_byte - parts) <= max(5.0, 0.2 * first_byte), stage
+    assert first_byte <= stage["serve_http_request_latency_ms"]
+    # One trace, three spans, the same readings as attributes.
+    spans = cluster.spans(f"stages-{case}")
+    assert set(spans) == {"serve.http", "serve.handle", "engine.request"}
+    assert len({s["trace_id"] for s in spans.values()}) == 1
+    http_attrs = spans["serve.http"]["attributes"]
+    handle = spans["serve.handle"]["attributes"]
+    request = spans["engine.request"]["attributes"]
+    assert float(http_attrs["first_byte_ms"]) == pytest.approx(
+        first_byte, abs=0.01
+    )
+    assert float(handle["queue_wait_ms"]) == pytest.approx(
+        stage["serve_queue_wait_ms"], abs=0.01
+    )
+    assert float(handle["submit_ms"]) == pytest.approx(
+        stage["serve_handler_submit_ms"], abs=0.01
+    )
+    assert float(request["first_token_ms"]) == pytest.approx(
+        admit + prefill, abs=0.01
+    )
+    assert float(handle["first_item_ms"]) == pytest.approx(
+        stage["serve_handler_submit_ms"] + admit + prefill
+        + stage["serve_first_item_handoff_ms"], abs=0.05
+    )
+    assert {"queue_ms", "prefill_ms", "decode_ms"} <= set(request)

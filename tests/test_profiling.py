@@ -10,6 +10,8 @@ live session.
 import threading
 import time
 
+import pytest
+
 from ray_tpu._private import profiling
 
 
@@ -164,3 +166,28 @@ def test_run_profile_dispatch():
         raise AssertionError("expected ValueError")
     except ValueError:
         pass
+
+
+@pytest.mark.parametrize("kind, duration_s, start_in_s, want", [
+    # the window and the slack, as before
+    ("cpu", 5.0, None, 35.0),
+    ("stack", 0.0, None, 30.0),
+    # a gang capture also stops a `jax.profiler` trace, whose cost
+    # grows with the window: 3 s of continuous device work took 81 s
+    # to stop on the chip and is given 120 where 33 were outlasted
+    ("gang", 3.0, None, 3.0 + 30.0 + 120.0),
+    ("gang", 60.0, None, 60.0 + 30.0 + 2400.0),
+    # a synchronized window's start is waited for, one that passed is not
+    ("gang", 2.0, 0.5, 2.0 + 30.0 + 80.0 + 0.5),
+    ("gang", 2.0, -4.0, 2.0 + 30.0 + 80.0),
+])
+def test_relay_timeout_grows_with_a_gang_window(
+    kind, duration_s, start_in_s, want
+):
+    now = 1_000_000.0
+    start_at = None if start_in_s is None else now + start_in_s
+    assert profiling.relay_timeout_s(
+        kind, duration_s, start_at, now=now
+    ) == pytest.approx(want)
+    # The relay outlasts the capture it waits for at any window.
+    assert profiling.relay_timeout_s(kind, duration_s) > duration_s

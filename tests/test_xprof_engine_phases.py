@@ -243,6 +243,7 @@ def test_capture_gang_traces_on_the_cpu_backend(engine):
     assert result["samples"] > 0  # the sampler still runs alongside
     names = host_events(result["jax_trace_dir"])
     assert {"engine.decode.dispatch", "engine.emit"} <= names
+    assert 0.0 <= result["jax_trace_stop_s"] < 30.0
 
 
 def test_phase_timer_needs_no_jax_and_annotates_only_the_outermost():
