@@ -13,9 +13,16 @@ loop, every iteration:
   2. admits the FIFO head of the waiting queue — gated on KV-block
      availability (not enough blocks: the head WAITS, no skip-ahead,
      no crash) — pinning any prefix-cache hit and reserving the rest
-     of its pages, then DISPATCHES its prefill's next fixed-size
-     chunk, written straight into its pages (Sarathi-style
-     interleave, starting AFTER the shared prefix);
+     of its pages, then DISPATCHES its prefill's next chunk, written
+     straight into its pages (Sarathi-style interleave, starting
+     AFTER the shared prefix). Every chunk is `prefill_chunk` tokens
+     but a prompt's last, which is the chunk, its half or its
+     quarter, the smallest that holds the tokens left
+     (kv_slots.bucket_for): a function of the prompt's length alone,
+     so a hit and a miss run the same last chunk. The loop runs
+     every such shape once before its first admission
+     (`_warm_chunk_shapes`), so no prompt length compiles anything
+     later;
   3. DISPATCHES one jitted paged decode step over the FULL slot batch
      (static shapes: full-width block tables, dead rows masked and
      parked on the null block; attention walks the tables only as far
@@ -132,9 +139,9 @@ class EngineConfig:
     #: longer a per-slot memory reservation — just the admission bound
     #: and the logical block-table width.
     max_len: int = 256
-    #: Prefill chunk length. Prompts pad up to a multiple of this
-    #: (the length-bucket set), and long prompts prefill chunk-by-
-    #: chunk interleaved with decode steps.
+    #: Prefill chunk length. Long prompts prefill chunk-by-chunk
+    #: interleaved with decode steps; a prompt's last chunk pads to
+    #: this, its half or its quarter (kv_slots.bucket_for).
     prefill_chunk: int = 32
     #: KV block (page) length in tokens; 0 = auto (largest divisor of
     #: prefill_chunk up to 16). Must divide prefill_chunk and max_len.
@@ -525,6 +532,14 @@ class InferenceEngine:
         self._admit_wait_ms_total = 0.0
         self._first_tokens = 0
         self._prefill_ms_total = 0.0
+        # How much of what the chunks compute the prompts need: chunks
+        # dispatched, those at a shape under `prefill_chunk`, the
+        # positions they span (padding too), and the positions the
+        # admitted prompts had beyond their prefix hits.
+        self._prefill_chunks = 0
+        self._prefill_short_chunks = 0
+        self._prefill_tokens_computed = 0
+        self._prefill_tokens_needed = 0
         # What decode's attention touches, per step, from the lengths
         # the host holds: keys inside alive rows' `valid_len`, and
         # keys the step's program attends over all rows (whole tiles
@@ -770,6 +785,14 @@ class InferenceEngine:
                 # decode steps that ran between them.
                 first_tokens=self._first_tokens,
                 prefill_ms_total=self._prefill_ms_total,
+                # Chunks dispatched, those shorter than
+                # `prefill_chunk`, the positions they span and the
+                # positions the admitted prompts needed (a prompt less
+                # its prefix hit): the rest is padding.
+                prefill_chunks=self._prefill_chunks,
+                prefill_short_chunks=self._prefill_short_chunks,
+                prefill_tokens_computed=self._prefill_tokens_computed,
+                prefill_tokens_needed=self._prefill_tokens_needed,
                 kv_keys_live=self._kv_keys_live,
                 kv_keys_read=self._kv_keys_read,
                 # The pipeline: chunks and steps dispatched, those
@@ -790,7 +813,10 @@ class InferenceEngine:
                 # models/generate.py registers (one wrapper a
                 # program; a second one here was credited nothing).
                 # Process-wide, like the jitted programs themselves.
-                # Steady state after warmup is a FIXED number —
+                # Each compiles once a shape: `prefill` and
+                # `finish_chunk` once for each of the last chunk's
+                # shapes, as the engine starts. Steady state after
+                # warmup is a FIXED number —
                 # movement under traffic is a recompile bug. (A
                 # mixed-generation window runs a fifth,
                 # `generate.paged_decode_step`.)
@@ -839,6 +865,9 @@ class InferenceEngine:
         `jax.profiler` trace). The buckets are drained once an
         iteration into the totals `stats()` returns as `loop_ms`."""
         try:
+            if self._kv is not None:
+                # Before the first admission, in no phase or counter.
+                self._warm_chunk_shapes()
             with phase_timer("engine.reap") as phase:
                 self._phase = phase
                 while True:
@@ -1097,8 +1126,11 @@ class InferenceEngine:
         """Prefill tokens a prefix hit lets this request skip: capped
         at len(prompt) - 1 (the LAST prompt token is always computed —
         its logits seed decoding) and rounded down to a whole prefill
-        chunk (offsets stay chunk-aligned, keeping the chunk shape
-        static)."""
+        chunk, so at most where the prompt's last chunk starts: a hit
+        runs the last chunk a miss runs, same positions, same shape,
+        same program, and their greedy tokens are equal. (A skip to
+        the block would leave a hit a shorter last chunk over other
+        positions: another program than the miss's.)"""
         bl = self._kv.block_len
         chunk = self._kv.prefill_chunk
         usable = min(hit_blocks * bl, len(req.prompt) - 1)
@@ -1137,6 +1169,7 @@ class InferenceEngine:
             shared = shared[:skip_blocks]
         req.skip = skip
         req.offset = skip
+        self._prefill_tokens_needed += len(req.prompt) - skip
         req.n_shared = skip_blocks
         req.block_ids = shared + alloc.reserve(
             req.total_blocks - skip_blocks
@@ -1201,8 +1234,6 @@ class InferenceEngine:
         pages, with `finish_chunk` behind it; neither is waited for."""
         import jax.numpy as jnp
 
-        from ..models.generate import finish_chunk, paged_prefill
-
         phase = self._phase
         phase.switch("engine.admit")
         with self._lock:
@@ -1239,23 +1270,14 @@ class InferenceEngine:
             padded = np.zeros((1, req.bucket), np.int32)
             padded[0, : len(req.prompt)] = req.prompt
             req.padded = padded
-        chunk = self.config.prefill_chunk
+        # `prefill_chunk` tokens, or what the plan leaves the prompt's
+        # last chunk (`bucket_for`: offsets are whole chunks, so only
+        # the last is ever shorter).
         start = req.offset
+        chunk = min(self.config.prefill_chunk, req.bucket - start)
         tokens = req.padded[:, start:start + chunk]
         req.offset += chunk
         last_chunk = req.offset >= req.bucket
-        phase.switch("engine.prefill.dispatch")
-        t0 = self._dispatching()
-        logits, pool = paged_prefill(
-            self._gens[req.gen]["params"],
-            self.cfg,
-            tokens,
-            self._kv.pool,
-            req.table,
-            np.int32(start),
-            np.int32(start + chunk),
-        )
-        self._kv.pool = pool
         started = cancelled = False
         if last_chunk:
             # The host's side of a row's start happens HERE, at
@@ -1295,26 +1317,20 @@ class InferenceEngine:
                         if 0 <= req.eos_token < self.cfg.vocab_size
                         else -1
                     )
+        phase.switch("engine.prefill.dispatch")
+        t0 = self._dispatching()
+        self._prefill_chunks += 1
+        self._prefill_short_chunks += int(chunk < self.config.prefill_chunk)
+        self._prefill_tokens_computed += chunk
         # Next-token logits come from the prompt's LAST REAL position
-        # (inside the last chunk by bucket construction: it covers
-        # [bucket - chunk, bucket) and len(prompt) > bucket - chunk —
-        # prefix skip never reaches the final chunk, it is capped at
-        # len(prompt) - 1). `finish_chunk` keeps that one row and the
-        # chunk's logits of every position (311 MB at qwen's chunk)
-        # are dropped here, at dispatch.
-        self._state, self._last_logits, fence = finish_chunk(
-            self._state,
-            self._last_logits,
-            logits,
-            pool.get("moe_counts"),
-            np.int32(slot),
-            np.int32(len(req.prompt) - 1 - start if last_chunk else 0),
-            np.bool_(started),
-            self._positions[slot],
-            self._budget[slot],
-            self._eos[slot],
+        # (inside the last chunk by bucket construction: it starts at
+        # the last whole chunk under len(prompt) — prefix skip never
+        # reaches the final chunk, it is capped at len(prompt) - 1).
+        fence = self._dispatch_chunk(
+            self._gens[req.gen]["params"], tokens, req.table, start,
+            slot, len(req.prompt) - 1 - start if last_chunk else 0,
+            started,
         )
-        del logits
         if started:
             self._state_patches += 1
         if cancelled:
@@ -1322,6 +1338,65 @@ class InferenceEngine:
         self._inflight.append(
             _ChunkInFlight(req if started else None, fence, t0)
         )
+
+    def _dispatch_chunk(
+        self, params, tokens, table, start: int, slot: int, local: int,
+        started: bool,
+    ):
+        """Dispatch `paged_prefill` over `tokens` [1, t], positions
+        [start, start + t) of the row whose table is `table`, and
+        `finish_chunk` behind it, which starts the row at the chunk's
+        `local` position if `started` (from the mirrors' values of
+        `slot`); neither is waited for. -> the chunk's fence."""
+        from ..models.generate import finish_chunk, paged_prefill
+
+        logits, pool = paged_prefill(
+            params,
+            self.cfg,
+            tokens,
+            self._kv.pool,
+            table,
+            np.int32(start),
+            np.int32(start + tokens.shape[1]),
+        )
+        self._kv.pool = pool
+        # `finish_chunk` keeps one row and the chunk's logits of every
+        # position (311 MB at qwen's chunk) are dropped here, at
+        # dispatch.
+        self._state, self._last_logits, fence = finish_chunk(
+            self._state,
+            self._last_logits,
+            logits,
+            pool.get("moe_counts"),
+            np.int32(slot),
+            np.int32(local),
+            np.bool_(started),
+            self._positions[slot],
+            self._budget[slot],
+            self._eos[slot],
+        )
+        return fence
+
+    def _warm_chunk_shapes(self) -> None:
+        """Run the chunk's two programs once at every shape a prompt's
+        last chunk can have (`kv_slots.chunk_shapes`), as the loop
+        starts: against the null table row, which starts no row and
+        writes only the null block, whose contents are garbage by
+        design. Which shapes the first requests happen to need then
+        compiles nothing later. Counted in no counter, timer or
+        span. (On the loop's thread, which traces every other program
+        of the engine: on a replica's handler thread a chunk program's
+        first call held the host 1.0-1.1 s, here 0.7-0.9; PERF.md,
+        PR 43.)"""
+        import jax
+
+        for shape in self._kv.chunk_shapes():
+            jax.block_until_ready(
+                self._dispatch_chunk(
+                    self.params, np.zeros((1, shape), np.int32),
+                    self._null_row, 0, 0, 0, False,
+                )
+            )
 
     # -- decode --------------------------------------------------------
     def _live_rows(self) -> List[tuple]:

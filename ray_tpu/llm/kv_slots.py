@@ -28,10 +28,12 @@ item 1c):
 
 Shapes stay STATIC: the decode step runs over the full slot batch
 with full-width [slots, max_blocks] tables (dead rows ride along
-pointing at the reserved null block 0), prompts pad to prefill-chunk
-buckets, and chunks are fixed-size — XLA compiles the paged prefill
-and decode step each ONCE per engine geometry (the per-bucket scratch
-caches of the arena design are gone).
+pointing at the reserved null block 0), and a prompt runs as whole
+prefill chunks and a last one padded to the chunk, its half or its
+quarter, whichever holds the tokens left (`bucket_for`) — XLA
+compiles the decode step ONCE per engine geometry and the paged
+prefill once a shape, three at most (the per-bucket scratch caches
+of the arena design are gone).
 
 Junk-is-masked contract (unchanged from the arenas): a freed block is
 NOT zeroed. Attention masks positions >= valid_len, and every visible
@@ -54,14 +56,34 @@ from ..models.llama import LlamaConfig
 from ..models.generate import init_block_pool
 
 
-def bucket_for(n: int, chunk: int, max_len: int) -> int:
-    """Smallest multiple of `chunk` holding `n` tokens (whole-chunk
-    prefill: the last chunk pads rather than shrinking, keeping the
-    chunk shape static). Raises when it exceeds the per-request
+def chunk_shapes(chunk: int, block_len: int) -> tuple:
+    """Token counts a prompt's LAST chunk may have, ascending: the
+    prefill chunk, its half and its quarter, each offered only where
+    it is a whole number of KV blocks (a chunk writes whole blocks).
+    Derived from the engine's geometry alone: every engine of one
+    geometry compiles, and warms, the same set."""
+    return tuple(
+        chunk // parts for parts in (4, 2, 1)
+        if chunk % (parts * block_len) == 0
+    )
+
+
+def bucket_for(n: int, chunk: int, max_len: int, block_len: int) -> int:
+    """Where the chunks of an `n`-token prompt end: whole chunks of
+    `chunk` cover [0, last), last = (ceil(n / chunk) - 1) * chunk, and
+    the last chunk starts there and is the smallest of
+    `chunk_shapes` that holds the n - last tokens left, padded to that
+    shape. A function of the prompt's length alone: not of the other
+    traffic, the slot or a prefix hit (a hit skips whole chunks and
+    never the last). Raises when it exceeds the per-request
     capacity."""
     if n < 1:
         raise ValueError("empty prompt")
-    bucket = ((n + chunk - 1) // chunk) * chunk
+    last = (n - 1) // chunk * chunk
+    bucket = last + next(
+        shape for shape in chunk_shapes(chunk, block_len)
+        if shape >= n - last
+    )
     if bucket > max_len:
         raise ValueError(
             f"prompt of {n} tokens needs a {bucket}-token bucket but "
@@ -311,7 +333,12 @@ class PagedKVCache:
 
     # -- geometry ------------------------------------------------------
     def bucket_for(self, prompt_len: int) -> int:
-        return bucket_for(prompt_len, self.prefill_chunk, self.max_len)
+        return bucket_for(
+            prompt_len, self.prefill_chunk, self.max_len, self.block_len
+        )
+
+    def chunk_shapes(self) -> tuple:
+        return chunk_shapes(self.prefill_chunk, self.block_len)
 
     def blocks_for(self, total_tokens: int) -> int:
         """Blocks a sequence of `total_tokens` positions occupies."""
