@@ -558,6 +558,21 @@ class InferenceEngine:
             ) if cfg is not None and cfg.moe_experts else (),
             0,
         )
+        # What only some configurations' forwards count, by the pool's
+        # counter leaves: the picks the router made over ALL its
+        # outputs where the experts held here are fewer (the `moe_picks`
+        # above are then the picks that met a held expert), and the
+        # (query, key) pairs a learned selection could see and kept.
+        if self._kv is not None:
+            if "moe_routed" in self._kv.pool:
+                self._moe["moe_picks_routed"] = 0
+            if "dsa_counts" in self._kv.pool:
+                # (the chunks' share apart: theirs is the attention
+                # kernel's work, ops/selected_attention.py)
+                self._moe.update(
+                    dsa_keys_visible=0, dsa_keys_selected=0,
+                    dsa_chunk_keys_visible=0, dsa_chunk_keys_selected=0,
+                )
         self._prefilling: Optional[_Request] = None
         self._by_id: Dict[str, _Request] = {}
         self._policy_pending: "deque[_PolicyRequest]" = deque()
@@ -1348,7 +1363,9 @@ class InferenceEngine:
         `finish_chunk` behind it, which starts the row at the chunk's
         `local` position if `started` (from the mirrors' values of
         `slot`); neither is waited for. -> the chunk's fence."""
-        from ..models.generate import finish_chunk, paged_prefill
+        from ..models.generate import (
+            counter_leaves, finish_chunk, paged_prefill,
+        )
 
         logits, pool = paged_prefill(
             params,
@@ -1367,7 +1384,7 @@ class InferenceEngine:
             self._state,
             self._last_logits,
             logits,
-            pool.get("moe_counts"),
+            counter_leaves(pool),
             np.int32(slot),
             np.int32(local),
             np.bool_(started),
@@ -1465,7 +1482,7 @@ class InferenceEngine:
         import jax
         import jax.numpy as jnp
 
-        from ..models.generate import paged_decode_step
+        from ..models.generate import counter_leaves, paged_decode_step
 
         phase = self._phase
         ec = self.config
@@ -1518,8 +1535,9 @@ class InferenceEngine:
             )
             if self._moe:
                 # Each group's program counts its own rows' picks.
-                moe_counts = pool["moe_counts"] + (
-                    0 if moe_counts is None else moe_counts
+                counted = counter_leaves(pool)
+                moe_counts = counted if moe_counts is None else (
+                    jax.tree.map(jnp.add, counted, moe_counts)
                 )
         self._kv.pool = pool
         self._last_logits = merged
@@ -1591,7 +1609,7 @@ class InferenceEngine:
             # The mirrors still stand where this step found the rows.
             self._count_kv_keys([[slot for slot, _ in rows]])
             if self._moe:
-                self._count_moe("decode", fetch["moe_counts"])
+                self._count_moe("decode", fetch)
         self._emit(rows, fetch["token"])
         self._observe_step(step_ms, len(rows), len(rows))
 
@@ -1643,16 +1661,35 @@ class InferenceEngine:
             self._kv_keys_live += live
             self._kv_keys_read += read
 
-    def _count_moe(self, program: str, counts: np.ndarray) -> None:
-        """Add one paged forward's picks per layer and expert,
-        `counts` [layers, E]: the picks; per layer the experts that
+    def _count_moe(self, program: str, counted: dict) -> None:
+        """Add what one paged forward counted (the pool's counter
+        leaves, fetched). `moe_counts` [expert layers, E]: the picks
+        per layer and expert; per layer the experts that
         got any token (the expert weights the forward had to read);
         and, of a chunk, per layer the fullest expert's tokens (how
-        uneven the grouped matmuls ran)."""
-        picks, layers = int(counts.sum()), counts.shape[0]
-        touched = int((counts > 0).sum())
+        uneven the grouped matmuls ran). `moe_routed` [expert layers]:
+        the picks over all the router's outputs; `dsa_counts`
+        [layers, 2]: the pairs its attention could see and kept."""
+        added: Dict[str, int] = {}
+        if "moe_routed" in counted:
+            added["moe_picks_routed"] = int(counted["moe_routed"].sum())
+        if "dsa_counts" in counted:
+            visible, kept = map(int, counted["dsa_counts"].sum(axis=0))
+            added.update(dsa_keys_visible=visible, dsa_keys_selected=kept)
+            if program == "prefill":
+                added.update(
+                    dsa_chunk_keys_visible=visible,
+                    dsa_chunk_keys_selected=kept,
+                )
+        counts = counted.get("moe_counts")
         with self._lock:
             moe = self._moe
+            for name, value in added.items():
+                moe[name] += value
+            if counts is None:
+                return
+            picks, layers = int(counts.sum()), counts.shape[0]
+            touched = int((counts > 0).sum())
             if program == "prefill":
                 moe["moe_picks_prefill"] += picks
                 moe["moe_chunk_layers"] += layers

@@ -53,7 +53,7 @@ from collections import OrderedDict
 from typing import Dict, Hashable, List, Sequence
 
 from ..models.llama import LlamaConfig
-from ..models.generate import init_block_pool
+from ..models.generate import cache_leaves, init_block_pool
 
 
 def chunk_shapes(chunk: int, block_len: int) -> tuple:
@@ -368,6 +368,8 @@ class PagedKVCache:
         return keys
 
     def nbytes(self) -> int:
-        return int(
-            self._pool["k"].nbytes + self._pool["v"].nbytes
-        )
+        """Bytes of the pool's cache leaves, whatever a configuration
+        names them (k and v; a latent and an indexer's keys)."""
+        return int(sum(
+            leaf.nbytes for leaf in cache_leaves(self._pool).values()
+        ))

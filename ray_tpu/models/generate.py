@@ -29,7 +29,10 @@ import jax
 import jax.numpy as jnp
 
 from .._private import compile_watch
-from ..ops.norms import apply_rotary, rotary_embedding
+from ..ops.norms import (
+    apply_rotary, layer_norm, rms_norm, rotary_embedding, yarn_mscale,
+)
+from ..ops.selected_attention import selected_attention
 from .llama import embed_tokens, model_norm
 from .llama import EXPERT_LEAVES, LlamaConfig, _mlp, project_qkv
 
@@ -96,7 +99,24 @@ def init_block_pool(
     """The shared pool: k/v of shape
     [layers, n_blocks, kv_heads, block_len, head_dim]; for a MoE
     config also `moe_counts` [layers, E], where each paged forward
-    leaves its picks per expert (`_paged_forward`)."""
+    leaves its picks per expert (`_paged_forward`).
+
+    A latent-attention config (`kv_lora_rank`) caches no k and v: one
+    `latent` entry a token a layer, [layers, n_blocks, block_len,
+    kv_lora_rank + rope dims] (the compressed key-value latent and the
+    one rotary key all heads share), and where an indexer selects the
+    keys (`index_topk`) its key beside it, `index_k` [..., index dim]:
+    both are functions of the token prefix alone, so they live under
+    the same block tables, allocator and prefix cache, and a shared
+    page stays shareable. (No unit axis where the kv heads stand, and
+    an entry as wide as whole lanes, `_lanes`: the TPU keeps an array
+    whose rows are not whole lanes with another axis innermost, and
+    the compiler then re-laid the whole pool around every step.) Its
+    counters (`COUNTER_LEAVES`) count expert layers only, and
+    `dsa_counts` [layers, 2] the (query, key) pairs each layer's
+    attention could see and did attend."""
+    if cfg.kv_lora_rank:
+        return _init_latent_pool(cfg, n_blocks, block_len)
     shape = (
         cfg.n_layers,
         n_blocks,
@@ -112,6 +132,57 @@ def init_block_pool(
         pool["moe_counts"] = jnp.zeros(
             (cfg.n_layers, cfg.moe_experts), jnp.int32
         )
+    return pool
+
+
+#: The pool's leaves that hold no cache: what a paged forward counted,
+#: left there for the engine to fetch (overwritten, not summed).
+COUNTER_LEAVES = ("moe_counts", "moe_routed", "dsa_counts")
+
+
+def cache_leaves(pool: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+    """The pool's cache arrays, whatever a configuration names them."""
+    return {n: a for n, a in pool.items() if n not in COUNTER_LEAVES}
+
+
+def counter_leaves(pool: Dict[str, jax.Array]):
+    """The counters a paged forward left in the pool, or None where a
+    configuration counts nothing. Whoever fetches them copies them
+    first (`+ 0`): the pool's own are donated to the next program
+    before the host reads them."""
+    return {n: pool[n] for n in COUNTER_LEAVES if n in pool} or None
+
+
+def _lanes(width: int) -> int:
+    """`width` rounded up to whole TPU lanes (128): what a row of a
+    tiled array occupies on the chip whatever its declared width. A
+    latent entry of 576 numbers is declared 640 wide, the rest zero."""
+    return -(-width // 128) * 128
+
+
+def _pad_last(x, width: int):
+    """x with zeros behind its last axis up to `width`."""
+    return jnp.pad(
+        x, ((0, 0),) * (x.ndim - 1) + ((0, width - x.shape[-1]),)
+    )
+
+
+def _init_latent_pool(cfg: LlamaConfig, n_blocks: int, block_len: int):
+    def leaf(width):
+        return jnp.zeros(
+            (cfg.n_layers, n_blocks, block_len, _lanes(width)), cfg.dtype
+        )
+
+    pool = {"latent": leaf(cfg.kv_lora_rank + cfg.qk_rope_head_dim)}
+    if cfg.index_topk:
+        pool["index_k"] = leaf(cfg.index_head_dim)
+        pool["dsa_counts"] = jnp.zeros((cfg.n_layers, 2), jnp.int32)
+    if cfg.moe_experts:
+        expert_layers = cfg.n_layers - cfg.dense_layers
+        pool["moe_counts"] = jnp.zeros(
+            (expert_layers, cfg.moe_experts), jnp.int32
+        )
+        pool["moe_routed"] = jnp.zeros((expert_layers,), jnp.int32)
     return pool
 
 
@@ -190,16 +261,21 @@ def _paged_attention(
     layer_idx,  # [] which layer's pages
     work,  # the forward's live (row, tile) pairs (_paged_work_list)
     n_trips,  # [] traced trip count (paged_tiles_read)
+    scale=None,  # the softmax scale where it is not hd ** -0.5
+    v_width=None,  # the leading dims of a page that are its value
 ) -> jax.Array:
     """Attention of `q` over the pages the work list names, read where
-    they lie: -> [b, heads, t, hd] float32. A trip takes b pairs off
-    the list (`paged_tiles_read`): their pages in one gather, each
+    they lie: -> [b, heads, t, hd] float32 ([.., v_width] where that is
+    given: a latent page is key and value in one). A trip takes b
+    pairs off the list (`paged_tiles_read`): their pages in one gather, each
     pair's scores over its own tile against its own row's queries
     (keys past a query's position or the row's `valid_len` masked),
     and the pairs' softmax sums merged into their rows' running ones.
     Pairs past `n_trips` trips are never read."""
     b, n_heads, t, hd = q.shape
-    kv_heads, bl = k_pool.shape[2:4]
+    # (a latent pool has no kv-head axis: its pages are one head's)
+    paged_heads = k_pool.ndim == 5
+    kv_heads, bl = k_pool.shape[2:4] if paged_heads else (1, k_pool.shape[2])
     tile_blocks = work["ids"].shape[1]
     groups = n_heads // kv_heads
     tile = tile_blocks * bl
@@ -207,7 +283,10 @@ def _paged_attention(
     # grouped axis is head (kv, g) at chunk position i, so both
     # products are plain matmuls against that head's keys.
     qg = q.reshape(b, kv_heads, groups * t, hd)
-    scale = 1.0 / jnp.sqrt(jnp.float32(hd))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.float32(hd))
+    if v_width is not None:
+        hd = v_width
     key_offsets = jnp.arange(tile)
     rows = jnp.arange(b)
 
@@ -224,6 +303,10 @@ def _paged_attention(
             # of the pool materialised.
             kt = k_pool[layer_idx, ids]  # [b, tile_blocks, kvH, bl, hd]
             vt = v_pool[layer_idx, ids]
+            if not paged_heads:
+                kt, vt = kt[:, :, None], vt[:, :, None]
+            if v_width is not None:
+                vt = vt[..., :v_width]
         with jax.named_scope("paged/attention"):
             # (a padding pair's row, b, is clamped to the last by the
             # gather: any will do, it sees no key)
@@ -376,6 +459,35 @@ def _paged_layer(
     return x, k_pool, v_pool, counts
 
 
+def _paged_plan(
+    tables, q_pos, valid_len, alive, n_blocks: int, bl: int, groups: int
+):
+    """What a paged forward's attention walks, made once for all its
+    layers from `tables` [b, width], `q_pos` [b, t], `valid_len` [b]
+    and `alive` -> (tables padded to whole tiles, valid_len with a dead
+    row's 0, the work list of live (row, tile) pairs with `q_pos`
+    tiled `groups` times, the trip count)."""
+    t, width = q_pos.shape[1], tables.shape[1]
+    tile = paged_tile_keys(bl, width, t)
+    tile_blocks = tile // bl
+    n_trips = paged_tiles_read(valid_len, alive, tile)
+    # A dead row sees no key: its stale length adds no pair to the
+    # work list.
+    valid_len = valid_len * alive
+    # Whole tiles of table entries, and room for the one block past
+    # its last that a chunk's write reads: the padding names the null
+    # block, at key positions no `valid_len` reaches.
+    spare = (t + bl - 2) // bl
+    tables = jnp.pad(
+        tables, ((0, 0), (0, -(width + spare) % tile_blocks + spare))
+    )
+    work = _paged_work_list(
+        tables, jnp.tile(q_pos, (1, groups)), valid_len, tile_blocks,
+        bl, n_blocks,
+    )
+    return tables, valid_len, work, n_trips
+
+
 def _paged_forward(
     params, cfg: LlamaConfig, tokens, pool, tables, q_pos, valid_len,
     alive=True,
@@ -392,26 +504,15 @@ def _paged_forward(
     engine adds them up); a dead row picks no expert."""
     q_pos = jnp.asarray(q_pos, jnp.int32)
     valid_len = jnp.asarray(valid_len, jnp.int32)
-    t = tokens.shape[1]
-    bl, width = pool["k"].shape[3], tables.shape[1]
-    tile = paged_tile_keys(bl, width, t)
-    tile_blocks = tile // bl
-    n_trips = paged_tiles_read(valid_len, alive, tile)
-    # A dead row sees no key: its stale length adds no pair to the
-    # work list.
-    valid_len = valid_len * alive
-    # Whole tiles of table entries, and room for the one block past
-    # its last that a chunk's write reads: the padding names the null
-    # block, at key positions no `valid_len` reaches.
-    spare = (t + bl - 2) // bl
-    tables = jnp.pad(
-        tables, ((0, 0), (0, -(width + spare) % tile_blocks + spare))
-    )
+    if cfg.kv_lora_rank:
+        return _latent_forward(
+            params, cfg, tokens, pool, tables, q_pos, valid_len, alive
+        )
     # (the queries of a kv head's group lie side by side, as
     # `_paged_attention` lays them)
-    work = _paged_work_list(
-        tables, jnp.tile(q_pos, (1, cfg.n_heads // cfg.n_kv_heads)),
-        valid_len, tile_blocks, bl, pool["k"].shape[1],
+    tables, valid_len, work, n_trips = _paged_plan(
+        tables, q_pos, valid_len, alive, pool["k"].shape[1],
+        pool["k"].shape[3], cfg.n_heads // cfg.n_kv_heads,
     )
     with jax.named_scope("embed"):
         x = embed_tokens(cfg, params, tokens)
@@ -452,6 +553,401 @@ def _paged_forward(
     if counts is not None:
         new_pool["moe_counts"] = counts
     return logits, new_pool
+
+
+
+# ---------------------------------------------------------------------
+# Latent attention with a learned selection of keys (DeepSeek-V3.2: MLA
+# under DSA). The cache holds one `latent` entry a token a layer (the
+# compressed key-value latent and the rotary key all heads share) and
+# the indexer's key `index_k` beside it. A layer: project q through its
+# latent and ABSORB the keys' expansion into it (q~_i = qN_i Wkv_b[i]^T,
+# so a head's score is q~_i . cKV + qR_i . kR against the cache entry
+# itself: 128 queries a latent entry, no key or value expanded); write
+# the token's entry and indexer key; the indexer scores every live key
+# over the work list's (row, tile) pairs; each query keeps its
+# `index_topk` best; attention runs over those alone; values are the
+# latents, expanded after the softmax sum (Wkv_b's value part), then
+# Wo. That is a DECODE STEP: its rows each GATHER their own selected
+# entries, a shorter list. A CHUNK's selection differs by query (2,048
+# queries x 2,048 of up to 16k keys: a gather of 4 M cache rows a
+# layer), and absorbed scores cost 3.4 times the operations of a
+# head's own (2 x (576 + 512) a pair against 2 x (192 + 128)), which
+# a step hides behind the weights it reads and a chunk does not. So a
+# chunk takes latent attention's OTHER form, the one the equations are
+# written in: the live tiles' latents are expanded to every head's
+# keys and values once a layer (`_expand_latent`), and a flash kernel
+# runs dense over them and attends where the selection allows
+# (ops/selected_attention.py).
+# ---------------------------------------------------------------------
+
+
+def _latent_write(pool, layer_idx, tables, q_pos, new):
+    """`_paged_write` for a pool leaf with no kv-head axis: `new`
+    [b, t, width], the entries of tokens at positions `q_pos` [b, t],
+    into `pool` [layers, n_blocks, block_len, width] at this layer; a
+    token or a few as rows, a chunk as whole blocks."""
+    _, n_blocks, bl, width = pool.shape
+    b, t, _ = new.shape
+    new = _pad_last(new.astype(pool.dtype), width)
+    if t < bl:
+        phys = jnp.take_along_axis(tables, q_pos // bl, axis=1)
+        rows = (layer_idx * n_blocks + phys) * bl + q_pos % bl
+        flat = pool.reshape(-1, width).at[rows.reshape(-1)].set(
+            new.reshape(-1, width)
+        )
+        return flat.reshape(pool.shape)
+    first, shift = q_pos[:, 0] // bl, q_pos[:, 0] % bl
+    span = (t + bl - 2) // bl + 1
+    phys = jnp.take_along_axis(
+        tables, first[:, None] + jnp.arange(span), axis=1
+    )
+    held = jax.vmap(
+        lambda old, rows, at: jax.lax.dynamic_update_slice(
+            old, rows, (at, 0)
+        )
+    )(pool[layer_idx, phys].reshape(b, span * bl, width), new, shift)
+    return pool.at[layer_idx, phys].set(
+        held.reshape(b, span, bl, width)
+    )
+
+
+def _sortable(x):
+    """float32 -> uint32 whose order is the floats' (-inf lowest;
+    -0.0 and 0.0 one value, as they compare)."""
+    i = jax.lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x), jnp.int32)
+    i = jnp.where(i < 0, i ^ jnp.int32(0x7FFFFFFF), i)
+    return jax.lax.bitcast_convert_type(i, jnp.uint32) ^ jnp.uint32(
+        0x80000000
+    )
+
+
+def _kth_largest(keys, k: int):
+    """keys [..., n] uint32 -> [...]: the k-th largest of each row,
+    exactly, bit by bit from the top: 32 counting passes and no sort
+    (a sort of a chunk's [queries, keys] scores is the slower by far)."""
+    def bit(i, found):
+        trial = found | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(keys >= trial[..., None], axis=-1) >= k
+        return jnp.where(enough, trial, found)
+
+    return jax.lax.fori_loop(
+        0, 32, bit, jnp.zeros(keys.shape[:-1], jnp.uint32)
+    )
+
+
+def _index_scores(qi, wi, index_pool, layer_idx, work, n_trips, keys):
+    """The indexer over the work list: qi [b, index heads, t, dim]
+    rotated, wi [b, index heads, t] float32, the pool's `index_k` ->
+    I [b, t, keys] float32: sum_j wi_j ReLU(qi_j . kI_s) at every
+    key s a query may see (inside its row's `valid_len`, not after
+    it), -inf elsewhere. A trip scores b pairs' tiles, as
+    `_paged_attention` walks them."""
+    b, _, t, dim = qi.shape
+    bl = index_pool.shape[2]
+    tile = work["ids"].shape[1] * bl
+    key_offsets = jnp.arange(tile)
+
+    def one_trip(j, scores):
+        pair = {
+            name: jax.lax.dynamic_slice_in_dim(of_all, j * b, b)
+            for name, of_all in work.items()
+        }
+        ids, (row, first_key, length) = pair["ids"], pair["at"].T
+        with jax.named_scope("paged/gather_kv"):
+            kt = index_pool[layer_idx, ids].reshape(b, tile, -1)[..., :dim]
+        s = jnp.einsum(
+            "bhtd,bkd->bhtk", qi[row], kt,
+            preferred_element_type=jnp.float32,
+        )
+        s = jnp.sum(jax.nn.relu(s) * wi[row][..., None], axis=1)
+        k_pos = (first_key[:, None] + key_offsets)[:, None]  # [b, 1, tile]
+        seen = (k_pos <= pair["pos"][..., None]) & (
+            k_pos < length[:, None, None]
+        )
+        s = jnp.where(seen, s, -jnp.inf)
+        return _write_tiles(scores, s, row, first_key, axis=2)
+
+    return jax.lax.fori_loop(
+        0, n_trips, one_trip,
+        jnp.full((b, t, keys), -jnp.inf, jnp.float32),
+    )
+
+
+def _write_tiles(into, tiles, row, first_key, axis: int):
+    """`tiles` [pairs, ...], pair i's slab of keys written into
+    `into` [rows, ...] at row `row[i]` from key `first_key[i]` on
+    (`axis` is the keys'); a padding pair (`row[i]` past the rows)
+    writes back what was there. One pair a trip (a chunk: one row)
+    walks its live tiles alone and meets no padding pair."""
+    rows, pairs = into.shape[0], tiles.shape[0]
+    for i in range(pairs):
+        at = [jnp.minimum(row[i], rows - 1)] + [0] * (into.ndim - 1)
+        at[axis] = first_key[i]
+        new = tiles[i][None].astype(into.dtype)
+        if pairs > 1:
+            old = jax.lax.dynamic_slice(into, at, new.shape)
+            new = jnp.where(row[i] < rows, new, old)
+        into = jax.lax.dynamic_update_slice(into, new, at)
+    return into
+
+
+def _expand_latent(latent_pool, layer_idx, work, n_trips, wk, wv, rows, keys):
+    """The live tiles' cache entries as every head's keys and values:
+    -> (kn [rows, keys, heads x dn], kr [rows, keys, dr], v [rows,
+    keys, heads x dv]) in the pool's dtype, the heads side by side as
+    the matmul leaves them, zeros where no live tile lies. `wk`
+    [latent, heads x dn] and `wv` [latent, heads x dv] are Wkv_b's two
+    parts (dn, dv whole lanes); dr lanes of an entry behind its latent
+    are the rotary key all heads share."""
+    latent_width = wk.shape[0]
+    bl, width = latent_pool.shape[2:]
+    dr = width - latent_width
+    tile = work["ids"].shape[1] * bl
+    dt = latent_pool.dtype
+
+    def one_trip(j, out):
+        kn, kr, v = out
+        pair = {
+            name: jax.lax.dynamic_slice_in_dim(of_all, j * rows, rows)
+            for name, of_all in work.items()
+        }
+        ids, (row, first_key, _) = pair["ids"], pair["at"].T
+        with jax.named_scope("paged/gather_kv"):
+            entries = latent_pool[layer_idx, ids].reshape(rows, tile, width)
+        latents = entries[..., :latent_width]
+        return (
+            _write_tiles(kn, latents @ wk, row, first_key, axis=1),
+            _write_tiles(
+                kr, entries[..., latent_width:], row, first_key, axis=1
+            ),
+            _write_tiles(v, latents @ wv, row, first_key, axis=1),
+        )
+
+    return jax.lax.fori_loop(0, n_trips, one_trip, (
+        jnp.zeros((rows, keys, wk.shape[1]), dt),
+        jnp.zeros((rows, keys, dr), dt),
+        jnp.zeros((rows, keys, wv.shape[1]), dt),
+    ))
+
+
+def _latent_layer(
+    cfg: LlamaConfig,
+    x: jax.Array,  # [b, t, dim]
+    layer: Dict[str, jax.Array],
+    layer_idx,  # [] this layer's index into the pool
+    stack_idx,  # [] and into its stack's expert matrices
+    cos,
+    sin,
+    cache: Dict[str, jax.Array],  # the pool's cache leaves
+    tables: jax.Array,  # [b, whole tiles of entries]
+    q_pos: jax.Array,  # [b, t]
+    valid_len: jax.Array,  # [b] (0 for a dead row)
+    work,  # the live (row, tile) pairs, `pos` a row's q_pos
+    n_trips,
+    live=None,
+):
+    """-> (x, cache, counts): counts holds the layer's `moe_counts`
+    [E held] and `moe_routed` [] (an expert layer) and `dsa_counts` [2]
+    (visible pairs, attended pairs; with an indexer)."""
+    b, t, _ = x.shape
+    heads, dt = cfg.n_heads, cfg.dtype
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    kvr, vd = cfg.kv_lora_rank, cfg.v_head_dim
+    scale = (nope + rope) ** -0.5
+    if cfg.rope_scaling is not None and cfg.rope_scaling[0] == "yarn":
+        scale *= yarn_mscale(cfg.rope_scaling[1]) ** 2
+    step = t == 1  # absorbed queries against cache entries, else heads
+
+    def rotated(v):  # rotary on the rope dims, which lead
+        return jnp.concatenate(
+            [apply_rotary(v[..., :rope], cos, sin), v[..., rope:]], axis=-1
+        )
+
+    h = model_norm(cfg, x, layer["attn_norm"])
+    wkv_b = layer["wkv_b"].reshape(kvr, heads, nope + vd)
+    with jax.named_scope("mla/q"):
+        cq = rms_norm(h @ layer["wq"], layer["q_norm"], eps=cfg.norm_eps)
+        q = (cq @ layer["wq_b"]).reshape(b, t, heads, nope + rope)
+        q = q.transpose(0, 2, 1, 3)
+        q_nope, q_rope = q[..., :nope], apply_rotary(q[..., nope:], cos, sin)
+        if step:
+            # absorbed: [b, heads, 1, kvr + rope] against a cache entry
+            q = _pad_last(jnp.concatenate([
+                jnp.einsum("bhtn,chn->bhtc", q_nope, wkv_b[..., :nope]),
+                q_rope,
+            ], axis=-1), cache["latent"].shape[-1])
+    with jax.named_scope("mla/kv_latent"):
+        kv = h @ layer["wkv_a"]  # [b, t, kvr + rope]
+        entry = jnp.concatenate([
+            rms_norm(kv[..., :kvr], layer["kv_norm"], eps=cfg.norm_eps),
+            apply_rotary(kv[:, None, :, kvr:], cos, sin)[:, 0],
+        ], axis=-1)
+        # Written BEFORE attention: a chunk attends to its own tokens.
+        cache = dict(cache, latent=_latent_write(
+            cache["latent"], layer_idx, tables, q_pos, entry
+        ))
+    latent = cache["latent"]
+    keys = tables.shape[1] * latent.shape[2]
+    counts = {}
+    allowed = picked = None
+    if cfg.index_topk:
+        ih, idim = cfg.index_n_heads, cfg.index_head_dim
+        with jax.named_scope("dsa/index"):
+            qi = rotated(
+                (cq @ layer["wiq"]).reshape(b, t, ih, idim)
+                .transpose(0, 2, 1, 3)
+            )
+            ki = rotated(layer_norm(
+                h @ layer["wik"], layer["ik_norm"], layer["ik_bias"]
+            )[:, None])[:, 0]
+            wi = (h @ layer["wiw"]).astype(jnp.float32).transpose(
+                0, 2, 1
+            ) * (ih ** -0.5 * idim ** -0.5)
+            cache["index_k"] = _latent_write(
+                cache["index_k"], layer_idx, tables, q_pos, ki
+            )
+            index = _index_scores(
+                qi, wi, cache["index_k"], layer_idx, work, n_trips, keys
+            )
+        with jax.named_scope("dsa/select"):
+            visible = index > -jnp.inf
+            top = min(cfg.index_topk, keys)
+            if step:
+                best, picked = jax.lax.top_k(index[:, 0], top)  # [b, top]
+                allowed = best > -jnp.inf
+            else:
+                ranks = _sortable(index)
+                allowed = visible & (
+                    ranks >= _kth_largest(ranks, top)[..., None]
+                )
+            counts["dsa_counts"] = jnp.stack(
+                [visible.sum(), allowed.sum()]
+            ).astype(jnp.int32)
+    with jax.named_scope("mla/attend"):
+        if picked is not None:
+            # A step's rows each gather their own selected entries:
+            # key s of row i lies in block tables[i, s // bl].
+            n_blocks, bl, width = latent.shape[1:]
+            rows = (
+                layer_idx * n_blocks
+                + jnp.take_along_axis(tables, picked // bl, axis=1)
+            ) * bl + picked % bl
+            with jax.named_scope("paged/gather_kv"):
+                chosen = latent.reshape(-1, width)[rows]  # [b, top, width]
+            s = jnp.einsum(
+                "bhd,bkd->bhk", q[:, :, 0], chosen,
+                preferred_element_type=jnp.float32,
+            ) * scale
+            s = jnp.where(allowed[:, None], s, -1e30)
+            p = jnp.exp(s - s.max(axis=-1, keepdims=True))
+            out = jnp.einsum(
+                "bhk,bkc->bhc", p.astype(dt), chosen[..., :kvr],
+                preferred_element_type=jnp.float32,
+            ) / p.sum(axis=-1, keepdims=True)
+            out = out[:, :, None]  # [b, heads, 1, kvr]
+        elif step:
+            out = _paged_attention(
+                q, latent, latent, layer_idx,
+                dict(work, pos=jnp.tile(work["pos"], (1, heads))),
+                n_trips, scale=scale, v_width=kvr,
+            )
+        else:
+            if allowed is None:  # no indexer: every visible key
+                k_pos = jnp.arange(keys)
+                allowed = (k_pos <= q_pos[..., None]) & (
+                    k_pos < valid_len[:, None, None]
+                )
+            kn, kr, v = _expand_latent(
+                latent, layer_idx, work, n_trips,
+                _pad_last(wkv_b[..., :nope], _lanes(nope)).reshape(kvr, -1),
+                _pad_last(wkv_b[..., nope:], _lanes(vd)).reshape(kvr, -1),
+                b, keys,
+            )
+            out = selected_attention(
+                _pad_last(q_nope, _lanes(nope)),
+                _pad_last(q_rope, kr.shape[-1]), kn, kr, v,
+                allowed.astype(jnp.int8), q_pos[:, 0], valid_len,
+                scale=scale, block_k=work["ids"].shape[1] * latent.shape[2],
+            )[..., :vd]  # [b, heads, t, vd]: the heads' own values
+    with jax.named_scope("mla/out"):
+        if step:
+            out = jnp.einsum(
+                "bhtc,chv->bhtv", out.astype(dt), wkv_b[..., nope:]
+            )
+        out = out.astype(dt).transpose(0, 2, 1, 3).reshape(b, t, heads * vd)
+        x = x + out @ layer["wo"]
+    with jax.named_scope("paged/mlp"):
+        expert_layer = "router" in layer
+        x, _, picks = _mlp(
+            cfg, x, layer, live=live,
+            layer_idx=stack_idx if expert_layer else None,
+        )
+        if expert_layer:
+            rows_live = b if live is None else live.sum()
+            counts["moe_counts"] = picks
+            counts["moe_routed"] = jnp.asarray(
+                rows_live * t * cfg.moe_top_k, jnp.int32
+            )
+    return x, cache, counts
+
+
+def _latent_forward(
+    params, cfg: LlamaConfig, tokens, pool, tables, q_pos, valid_len,
+    alive=True,
+):
+    """`_paged_forward` of a latent-attention config: the same work
+    list, trip count and page writes over the pool's `latent` and
+    `index_k`, through the model's stacks in turn (`dense_layers`,
+    where it has leading dense layers, then `layers`; the pool's layer
+    axis counts across them). The new pool holds the forward's
+    counters, each summed over nothing: `moe_counts` and `moe_routed`
+    an entry an expert layer, `dsa_counts` one a layer."""
+    cache = cache_leaves(pool)
+    n_blocks, bl = cache["latent"].shape[1:3]
+    tables, valid_len, work, n_trips = _paged_plan(
+        tables, q_pos, valid_len, alive, n_blocks, bl, 1
+    )
+    with jax.named_scope("embed"):
+        x = embed_tokens(cfg, params, tokens)
+    cos, sin = rotary_embedding(
+        q_pos, cfg.qk_rope_head_dim, cfg.rope_theta, cfg.rope_scaling
+    )
+    live = None if alive is True else alive
+    counters: Dict[str, list] = {}
+    first = 0
+    for stack in ("dense_layers", "layers"):
+        layers = params.get(stack)
+        if layers is None:
+            continue
+        experts = {n: layers[n] for n in EXPERT_LEAVES if n in layers}
+        sliced = {n: w for n, w in layers.items() if n not in experts}
+        depth = layers["attn_norm"].shape[0]
+
+        def body(carry, inputs, experts=experts, first=first):
+            x, cache = carry
+            layer, stack_idx = inputs
+            x, cache, counts = _latent_layer(
+                cfg, x, {**layer, **experts}, first + stack_idx,
+                stack_idx, cos, sin, cache, tables, q_pos, valid_len,
+                work, n_trips, live,
+            )
+            return (x, cache), counts
+
+        (x, cache), counts = jax.lax.scan(
+            body, (x, cache), (sliced, jnp.arange(depth))
+        )
+        for name, value in counts.items():
+            counters.setdefault(name, []).append(value)
+        first += depth
+    with jax.named_scope("final_norm"):
+        x = model_norm(cfg, x, params["final_norm"])
+    with jax.named_scope("lm_head"):
+        logits = (x @ params["lm_head"]).astype(jnp.float32)
+    return logits, {
+        **cache,
+        **{n: jnp.concatenate(v) for n, v in counters.items()},
+    }
 
 
 def _paged_prefill_impl(
@@ -615,9 +1111,10 @@ def _paged_engine_step_impl(
     # What the host fetches when it retires the step, in buffers of
     # their own: the pool's `moe_counts` is donated to the next program
     # before the host gets to read it.
-    fetch = {"token": token, "step": state["step"]}
-    if "moe_counts" in pool:
-        fetch["moe_counts"] = pool["moe_counts"] + 0
+    fetch = {
+        "token": token, "step": state["step"],
+        **jax.tree.map(lambda c: c + 0, counter_leaves(pool) or {}),
+    }
     return fetch, pool, last_logits, state
 
 
@@ -700,7 +1197,9 @@ def _finish_chunk_impl(
         "budget": start(state["budget"], budget),
         "eos": start(state["eos"], eos),
     }
-    fence = row[:1] if moe_counts is None else moe_counts + 0
+    fence = row[:1] if moe_counts is None else jax.tree.map(
+        lambda counted: counted + 0, moe_counts
+    )
     return state, last_logits, fence
 
 

@@ -58,6 +58,10 @@ def config_from_hf(hf_config) -> LlamaConfig:
     convert cleanly and generate subtly wrong logits)."""
     import jax.numpy as jnp
 
+    if getattr(hf_config, "kv_lora_rank", None):
+        LlamaConfig(
+            kv_lora_rank=int(hf_config.kv_lora_rank)
+        ).require_plain_attention("checkpoint conversion (hf_convert.py)")
     scaling = getattr(hf_config, "rope_scaling", None)
     rope_scaling = None
     if scaling:
@@ -230,6 +234,7 @@ def convert_hf_llama(state_dict: Dict[str, Any], cfg: LlamaConfig):
     pytree (layers stacked on axis 0 for lax.scan)."""
     import jax.numpy as jnp
 
+    cfg.require_plain_attention("checkpoint conversion (hf_convert.py)")
     L = cfg.n_layers
     consumed = set()
 

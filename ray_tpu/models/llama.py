@@ -30,7 +30,7 @@ from ..ops.attention import (
     mha_reference,
     repeat_kv,
 )
-from ..ops.moe import moe_ffn_dropless, moe_ffn_ep
+from ..ops.moe import moe_ffn_dropless, moe_ffn_ep, route_grouped_sigmoid
 from ..ops.norms import apply_rotary, rms_norm, rotary_embedding, swiglu
 from ..ops.ring_attention import ring_attention
 from ..parallel.sharding import Annotated, annotate
@@ -106,20 +106,86 @@ class LlamaConfig:
     #: heads: Qwen3; True means this) or "proj" (over the whole
     #: projection before the split into heads: OLMoE).
     qk_norm: Any = False
+    # ---- DeepSeek-family keys (the serve forwards only) ----
+    #: Latent attention (MLA): >0 is the width of the compressed
+    #: key-value latent, which with `qk_rope_head_dim` shared rotary
+    #: key dims is ALL the cache holds of a token (models/generate.py).
+    #: The heads are then `qk_nope_head_dim + qk_rope_head_dim` wide
+    #: for scores and `v_head_dim` for values, both expanded from the
+    #: latent, and q is projected through a latent of `q_lora_rank`.
+    #: Rotary acts on the rope dims alone (`rope_scaling` "yarn").
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    #: Learned sparse attention (DSA) over the latent cache: >0 is how
+    #: many keys a query attends, the largest by an indexer's score
+    #: (`index_n_heads` heads of `index_head_dim`, whose keys the
+    #: cache holds beside the latent).
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    #: With "sigmoid_groups" (`moe_router`): the router's outputs
+    #: where they are more than the `moe_experts` HELD here (one
+    #: rank's share of an expert-parallel layer: experts
+    #: `moe_first_expert` and after), its groups, the groups a token
+    #: may choose from, and the factor on the normalised gates.
+    moe_router_experts: int = 0
+    moe_first_expert: int = 0
+    moe_groups: int = 1
+    moe_top_groups: int = 1
+    moe_route_scale: float = 1.0
+    #: Width of the shared expert every token passes beside its
+    #: routed ones (0: none).
+    moe_shared_intermediate: int = 0
+    #: Leading layers, of `n_layers`, whose FFN is a dense GLU of
+    #: width `dense_intermediate` in a model whose others are expert
+    #: layers: a stack of their own, `dense_layers/*`.
+    dense_layers: int = 0
+    dense_intermediate: int = 0
 
     def __post_init__(self):
         if self.qk_norm is True:
             object.__setattr__(self, "qk_norm", "head")
         if self.qk_norm not in (False, "head", "proj"):
             raise ValueError(f"unknown qk_norm {self.qk_norm!r}")
-        if self.moe_router not in ("softmax", "softmax_renorm"):
+        if self.moe_router not in (
+            "softmax", "softmax_renorm", "sigmoid_groups"
+        ):
             raise ValueError(f"unknown moe_router {self.moe_router!r}")
+        if isinstance(self.rope_scaling, list):  # from a JSON file
+            object.__setattr__(
+                self, "rope_scaling", tuple(self.rope_scaling)
+            )
+        if self.index_topk and not self.kv_lora_rank:
+            raise ValueError("index_topk selects keys of a latent cache")
+        if self.dense_layers and not self.moe_experts:
+            raise ValueError("dense_layers lead a model of expert layers")
+
+    def require_plain_attention(self, what: str) -> None:
+        """The one refusal of a latent-attention configuration by the
+        code that has no equations for it (training, conversion from
+        a checkpoint): it would otherwise run other mathematics."""
+        if self.kv_lora_rank:
+            raise NotImplementedError(
+                f"{what} has no latent attention (kv_lora_rank="
+                f"{self.kv_lora_rank}): a DeepSeek-family configuration "
+                "runs on the serve path only (models/generate.py)"
+            )
 
     @property
     def head_dim(self) -> int:
         return self.custom_head_dim or self.dim // self.n_heads
 
     def num_params(self) -> int:
+        if self.kv_lora_rank:
+            shapes = jax.eval_shape(
+                lambda: init_params(jax.random.PRNGKey(0), self)
+            )
+            return sum(
+                math.prod(leaf.shape) for leaf in jax.tree.leaves(shapes)
+            )
         embed = self.vocab_size * self.dim
         if self.moe_experts:
             ffn = self.dim * self.moe_experts + (
@@ -237,6 +303,13 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
             * (1.0 / math.sqrt(fan_in))
         ).astype(dt)
 
+    if cfg.kv_lora_rank:
+        return {
+            "embed": norm_init(k_embed, cfg.dim, (cfg.vocab_size, cfg.dim)),
+            **_init_latent_layers(k_layers, cfg, norm_init),
+            "final_norm": jnp.ones((cfg.dim,), dt),
+            "lm_head": norm_init(k_out, cfg.dim, (cfg.dim, cfg.vocab_size)),
+        }
     keys = jax.random.split(k_layers, 8)
     L = cfg.n_layers
     layers = {
@@ -288,10 +361,92 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
     }
 
 
+def latent_layer_shapes(cfg: LlamaConfig, L: int, experts: bool) -> Dict:
+    """{leaf: (shape, fan in; 0 for a norm weight or a bias)} of a
+    stack of `L` latent-attention layers, expert layers or dense.
+
+    Attention: `wq` [d, q_lora_rank] and `q_norm` (the query's latent
+    and its RMSNorm), `wq_b` -> heads x (nope + rope); `wkv_a`
+    [d, kv_lora_rank + rope] (the latent and the one rotary key of all
+    heads) and `kv_norm` over the latent; `wkv_b` [kv_lora_rank,
+    heads x (nope + v)], a head's key part then its value part; `wo`.
+    Indexer: `wiq` [q_lora_rank, index heads x index dim], `wik`
+    [d, index dim] with LayerNorm `ik_norm`, `ik_bias`, `wiw`
+    [d, index heads]. FFN: dense `w1` `w3` `w2`, or `router`
+    [d, outputs], `router_bias` [outputs], the held experts' `w_gate`
+    `w_up` `w_down` and the shared expert's `shared_gate` `shared_up`
+    `shared_down`."""
+    d, H = cfg.dim, cfg.n_heads
+    qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    out = {
+        "attn_norm": ((L, d), 0), "mlp_norm": ((L, d), 0),
+        "wq": ((L, d, qr), d), "q_norm": ((L, qr), 0),
+        "wq_b": ((L, qr, H * (nope + rope)), qr),
+        "wkv_a": ((L, d, kvr + rope), d), "kv_norm": ((L, kvr), 0),
+        "wkv_b": ((L, kvr, H * (nope + vd)), kvr),
+        "wo": ((L, H * vd, d), H * vd),
+    }
+    if cfg.index_topk:
+        ih, idim = cfg.index_n_heads, cfg.index_head_dim
+        out.update({
+            "wiq": ((L, qr, ih * idim), qr), "wik": ((L, d, idim), d),
+            "ik_norm": ((L, idim), 0), "ik_bias": ((L, idim), 0),
+            "wiw": ((L, d, ih), d),
+        })
+    if not experts:
+        f = cfg.dense_intermediate
+        out.update({
+            "w1": ((L, d, f), d), "w3": ((L, d, f), d), "w2": ((L, f, d), f),
+        })
+        return out
+    E, f = cfg.moe_experts, cfg.intermediate
+    outputs = cfg.moe_router_experts or E
+    out.update({
+        "router": ((L, d, outputs), d), "router_bias": ((L, outputs), 0),
+        "w_gate": ((L, E, d, f), d), "w_up": ((L, E, d, f), d),
+        "w_down": ((L, E, f, d), f),
+    })
+    fs = cfg.moe_shared_intermediate
+    if fs:
+        out.update({
+            "shared_gate": ((L, d, fs), d), "shared_up": ((L, d, fs), d),
+            "shared_down": ((L, fs, d), fs),
+        })
+    return out
+
+
+def _init_latent_layers(key, cfg: LlamaConfig, norm_init) -> Dict:
+    """The two stacks of a latent-attention model: `dense_layers`
+    (where it has leading dense layers) and `layers`."""
+    out = {}
+    for name, L, experts in (
+        ("dense_layers", cfg.dense_layers, False),
+        ("layers", cfg.n_layers - cfg.dense_layers, True),
+    ):
+        if not L:
+            continue
+        stack = {}
+        for i, (leaf, (shape, fan_in)) in enumerate(
+            latent_layer_shapes(cfg, L, experts).items()
+        ):
+            if fan_in:
+                stack[leaf] = norm_init(
+                    jax.random.fold_in(key, i + 64 * experts), fan_in, shape
+                )
+            elif leaf.endswith("bias"):
+                stack[leaf] = jnp.zeros(shape, cfg.dtype)
+            else:
+                stack[leaf] = jnp.ones(shape, cfg.dtype)
+        out[name] = stack
+    return out
+
+
 def param_annotations(cfg: LlamaConfig) -> Dict[str, Any]:
     """Logical-axis annotations matching init_params' tree: GSPMD maps
     these through PARAM_RULES (fsdp shards embed dims, tp shards
     heads/mlp/vocab)."""
+    cfg.require_plain_attention("the training layout (models/llama.py)")
     layers = {
         "wq": annotate("layers", "embed", "heads"),
         "wk": annotate("layers", "embed", "kv_heads"),
@@ -411,7 +566,8 @@ def _mlp(cfg: LlamaConfig, x, layer, ep_axis=None, live=None,
     layer to use (ops/moe.py `moe_ffn_dropless` has both)."""
     b, t, _ = x.shape
     h = model_norm(cfg, x, layer["mlp_norm"])
-    if not cfg.moe_experts:
+    if not cfg.moe_experts or "router" not in layer:
+        # (a leading dense layer of an expert model has no router)
         x = x + model_glu(cfg, h @ layer["w1"], h @ layer["w3"]) @ layer["w2"]
         return x, jnp.zeros((), jnp.float32), None
     moe = dict(
@@ -420,6 +576,16 @@ def _mlp(cfg: LlamaConfig, x, layer, ep_axis=None, live=None,
         glu=partial(model_glu, cfg),
     )
     flat = h.reshape(b * t, -1)
+    if cfg.moe_router == "sigmoid_groups":
+        with jax.named_scope("moe/route"):
+            moe.update(
+                routed=route_grouped_sigmoid(
+                    flat, layer["router"], layer["router_bias"],
+                    cfg.moe_top_k, cfg.moe_groups, cfg.moe_top_groups,
+                    cfg.moe_route_scale,
+                ),
+                first_expert=cfg.moe_first_expert,
+            )
     if ep_axis is not None:
         out, aux = moe_ffn_ep(
             layer, flat, axis_name=ep_axis,
@@ -432,6 +598,11 @@ def _mlp(cfg: LlamaConfig, x, layer, ep_axis=None, live=None,
             live=None if live is None else jnp.repeat(live, t),
             layer=layer_idx, **moe,
         )
+    if "shared_gate" in layer:
+        with jax.named_scope("moe/shared"):
+            out = out + model_glu(
+                cfg, flat @ layer["shared_up"], flat @ layer["shared_gate"]
+            ) @ layer["shared_down"]
     return x + out.reshape(b, t, -1), aux, counts
 
 
@@ -456,6 +627,7 @@ def forward_and_aux(
     refuses to lower the kernel). Leave it None inside a `shard_map`,
     where the call is already per shard.
     """
+    cfg.require_plain_attention("the training forward (models/llama.py)")
     b, t = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(t), (b, t))

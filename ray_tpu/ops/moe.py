@@ -7,6 +7,9 @@ anywhere in Ray core or its libraries); built TPU-first.
   * `route`: float32 softmax over all experts, the `k` largest
     probabilities and their experts, renormalised to sum to one or
     left as they are (OLMoE's `norm_topk_prob: false`).
+  * `route_grouped_sigmoid`: DeepSeek-V3's router: sigmoid scores, a
+    correction bias that decides the choice and not the gates, the
+    choice limited to the best groups of experts.
   * `gated_experts`: `down(act(gate(x)) * up(x))` for rows already
     grouped by expert; how a row finds its expert's matrices is the
     caller's matmul.
@@ -87,6 +90,49 @@ def route(
     return gates, experts, aux_loss
 
 
+def route_grouped_sigmoid(
+    x: jax.Array,
+    router: jax.Array,
+    bias: jax.Array,
+    k: int,
+    n_groups: int,
+    top_groups: int,
+    scale: float = 1.0,
+) -> Tuple[jax.Array, jax.Array]:
+    """DeepSeek-V3's router (`scoring_func: sigmoid`, `topk_method:
+    noaux_tc`, `norm_topk_prob`): x [t, d], router [d, E], bias [E]
+    -> (gates [t, k] float32, experts [t, k]).
+
+    A score is `sigmoid(x . router_e)`, float32. WHICH experts a token
+    gets is decided on `score + bias` (the correction bias that
+    balances load without an auxiliary loss): the experts stand in
+    `n_groups` groups of neighbours, a group's mark is the sum of its
+    two largest corrected scores, the `top_groups` best groups stay
+    and the `k` largest corrected scores inside them win. The GATES
+    are the winners' plain scores, the bias left out, over their sum,
+    times `scale` (`routed_scaling_factor`)."""
+    logits = jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    )
+    scores = jax.nn.sigmoid(logits)
+    corrected = scores + bias.astype(jnp.float32)
+    t, num_experts = scores.shape
+    by_group = corrected.reshape(t, n_groups, num_experts // n_groups)
+    marks = jnp.sum(lax.top_k(by_group, 2)[0], axis=-1)  # [t, groups]
+    _, kept = lax.top_k(marks, top_groups)
+    in_kept = jnp.any(
+        kept[:, :, None] == jnp.arange(n_groups), axis=1
+    )  # [t, groups]
+    corrected = jnp.where(
+        in_kept[:, :, None], by_group, -jnp.inf
+    ).reshape(t, num_experts)
+    _, experts = lax.top_k(corrected, k)
+    gates = jnp.take_along_axis(scores, experts, axis=-1)
+    gates = gates / jnp.sum(gates, axis=-1, keepdims=True) * scale
+    return gates, experts
+
+
 def gated_experts(
     params: Dict, rows: jax.Array, matmul: Callable, glu: Callable
 ) -> jax.Array:
@@ -107,9 +153,21 @@ def moe_ffn_dropless(
     glu: Callable = swiglu,
     live: Optional[jax.Array] = None,
     layer: Optional[jax.Array] = None,
+    routed: Optional[Tuple[jax.Array, jax.Array]] = None,
+    first_expert: int = 0,
 ):
     """Every expert local, no capacity. x: [tokens, d] ->
     (out [tokens, d], aux_loss, counts [E] int32: picks per expert).
+
+    `routed`: the `(gates, experts)` of a router of the caller's
+    (`route_grouped_sigmoid`) in the place of `route`'s; the auxiliary
+    loss is then 0. With it the router may be WIDER than the experts
+    held here, which are `first_expert` and those after it, as many as
+    the expert matrices hold: one rank's share of an expert-parallel
+    layer, run without its exchange. A pick of an expert held
+    elsewhere sorts past the last group, as a dead row's does, reads
+    no weight and adds nothing; `counts` are the picks that met a held
+    expert.
 
     `layer`: the experts' matrices are whole stacks `[layers, E, ., .]`
     and this is the layer to use. A loop over layers that slices its
@@ -128,10 +186,20 @@ def moe_ffn_dropless(
     comes out zero.
     """
     t, _ = x.shape
-    num_experts = params["router"].shape[-1]
+    num_experts = params["w_gate"].shape[-3]
     with jax.named_scope("moe/route"):
-        gates, experts, aux = route(x, params["router"], k, renormalise)
-        picked = experts.reshape(-1)  # [t*k], token-major
+        if routed is None:
+            gates, experts, aux = route(
+                x, params["router"], k, renormalise
+            )
+            picked = experts.reshape(-1)  # [t*k], token-major
+        else:
+            (gates, experts), aux = routed, jnp.zeros((), jnp.float32)
+            picked = experts.reshape(-1) - first_expert
+            picked = jnp.where(
+                (picked >= 0) & (picked < num_experts), picked,
+                num_experts,
+            )
         if live is not None:
             # Past the last expert: sorted behind every group.
             picked = jnp.where(jnp.repeat(live, k), picked, num_experts)
@@ -162,6 +230,10 @@ def moe_ffn_dropless(
         if live is not None:
             # Rows behind the last group are whatever the kernel left.
             out = jnp.where(live[:, None, None], out, 0)
+        if routed is not None:
+            out = jnp.where(
+                (picked < num_experts).reshape(t, k, 1), out, 0
+            )
         out = jnp.sum(out * gates[:, :, None], axis=1)
     return out.astype(x.dtype), aux, counts
 

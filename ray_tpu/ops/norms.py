@@ -7,6 +7,8 @@ Pallas kernel in attention.py)."""
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -26,6 +28,19 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6,
     return (normed * scale).astype(dtype)
 
 
+def layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array,
+               eps: float = 1e-6) -> jax.Array:
+    """LayerNorm (mean and variance over the last axis, weight and
+    bias) in f32, cast back to the input dtype."""
+    xf = x.astype(jnp.float32)
+    mean = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
+    normed = (xf - mean) * jax.lax.rsqrt(var + eps)
+    return (
+        normed * weight.astype(jnp.float32) + bias.astype(jnp.float32)
+    ).astype(x.dtype)
+
+
 def rope_frequencies(
     head_dim: int, theta: float = 10000.0, scaling=None
 ) -> jax.Array:
@@ -36,6 +51,8 @@ def rope_frequencies(
 
     - "linear": every frequency divided by `factor` (position
       interpolation).
+    - "yarn": `(kind, factor, beta_slow, beta_fast, original_max)`,
+      blended by pair index (below).
     - "llama3": Llama-3.1's piecewise scheme (public formula; HF
       modeling_rope_utils._compute_llama3_parameters): wavelengths
       shorter than original_max/high_freq_factor keep their frequency,
@@ -62,7 +79,37 @@ def rope_frequencies(
             freqs / factor,
             jnp.where(wavelen < high_wavelen, freqs, smoothed),
         )
+    if kind == "yarn":
+        # YaRN (arXiv:2309.00071; transformers'
+        # `_compute_yarn_parameters`): `low_freq_factor` is
+        # `beta_slow` and `high_freq_factor` `beta_fast`, rotations
+        # over `original_max` positions. A pair that turns more than
+        # `beta_fast` times keeps its frequency, one that turns less
+        # than `beta_slow` times is interpolated by `factor`, and the
+        # pairs between (by index, between the two correction
+        # dimensions, the lower floored and the upper ceiled) blend
+        # linearly.
+        def correction_dim(rotations):
+            return head_dim * math.log(
+                orig_max / (rotations * 2.0 * math.pi)
+            ) / (2.0 * math.log(theta))
+
+        low = max(math.floor(correction_dim(high_ff)), 0)
+        high = min(math.ceil(correction_dim(low_ff)), head_dim - 1)
+        ramp = jnp.clip(
+            (jnp.arange(half, dtype=jnp.float32) - low)
+            / max(high - low, 0.001),
+            0.0, 1.0,
+        )
+        return freqs / factor * ramp + freqs * (1.0 - ramp)
     raise ValueError(f"unknown rope scaling kind {kind!r}")
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature `0.1 * mscale * ln(factor) + 1`
+    (1 for a factor of at most 1): DeepSeek's latent attention scales
+    its softmax by the square of it (`mscale_all_dim`)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
 def rotary_embedding(

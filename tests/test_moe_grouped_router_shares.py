@@ -1,0 +1,251 @@
+"""DeepSeek-V3's router and one chip's share of an expert layer
+(ops/moe.py `route_grouped_sigmoid`, `moe_ffn_dropless(routed=,
+first_expert=)`, `llama._mlp`), against float32 loops written from the
+equations; YaRN's frequencies against the closed form."""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models.llama import LlamaConfig, _mlp
+from ray_tpu.ops.moe import moe_ffn_dropless, route_grouped_sigmoid
+from ray_tpu.ops.norms import rope_frequencies, yarn_mscale
+
+E, GROUPS, TOP_GROUPS, K, D, F = 32, 4, 2, 4, 16, 8
+
+
+def _router(seed=0, tokens=64):
+    rng = np.random.default_rng(seed)
+    return (
+        rng.normal(size=(tokens, D)).astype(np.float32),
+        rng.normal(size=(D, E)).astype(np.float32) * D ** -0.5,
+        rng.normal(size=(E,)).astype(np.float32) * 0.1,
+    )
+
+
+def _loop_route(x, router, bias, scale):
+    """Token by token, as the equations say."""
+    gates, experts = [], []
+    per = E // GROUPS
+    for h in x:
+        s = 1.0 / (1.0 + np.exp(-(h.astype(np.float64) @ router)))
+        c = s + bias
+        marks = [
+            np.sort(c[g * per:(g + 1) * per])[-2:].sum() for g in range(GROUPS)
+        ]
+        kept = np.argsort(marks)[-TOP_GROUPS:]
+        allowed = [e for e in range(E) if e // per in kept]
+        chosen = sorted(allowed, key=lambda e: -c[e])[:K]
+        total = sum(s[e] for e in chosen)
+        experts.append(chosen)
+        gates.append([scale * s[e] / total for e in chosen])
+    return np.array(gates), np.array(experts)
+
+
+def test_the_router_chooses_inside_the_best_groups_by_corrected_scores():
+    x, router, bias = _router()
+    gates, experts = route_grouped_sigmoid(
+        jnp.asarray(x), jnp.asarray(router), jnp.asarray(bias),
+        K, GROUPS, TOP_GROUPS, 2.5,
+    )
+    want_gates, want_experts = _loop_route(x, router, bias, 2.5)
+    assert np.array_equal(np.asarray(experts), want_experts)
+    np.testing.assert_allclose(np.asarray(gates), want_gates, rtol=1e-5)
+    # at most TOP_GROUPS groups a token, and the gates sum to the scale
+    groups = np.asarray(experts) // (E // GROUPS)
+    assert max(len(set(row)) for row in groups) <= TOP_GROUPS
+    np.testing.assert_allclose(np.asarray(gates).sum(axis=1), 2.5, rtol=1e-5)
+
+
+def test_the_bias_moves_the_choice_and_never_the_gates():
+    x, router, bias = _router(1)
+    args = (K, GROUPS, TOP_GROUPS, 1.0)
+    plain_g, plain_e = route_grouped_sigmoid(
+        jnp.asarray(x), jnp.asarray(router), jnp.zeros(E), *args
+    )
+    # a bias that lifts group 3's experts over everything: every token
+    # chooses there, and its gates are still its plain scores' shares
+    lifted = np.zeros(E, np.float32)
+    lifted[24:] = 10.0
+    gates, experts = route_grouped_sigmoid(
+        jnp.asarray(x), jnp.asarray(router), jnp.asarray(lifted), *args
+    )
+    assert not np.array_equal(np.asarray(experts), np.asarray(plain_e))
+    assert (np.asarray(experts) >= 24).all()
+    scores = 1.0 / (1.0 + np.exp(-(x @ router)))
+    picked = np.take_along_axis(scores, np.asarray(experts), axis=1)
+    np.testing.assert_allclose(
+        np.asarray(gates), picked / picked.sum(axis=1, keepdims=True), rtol=1e-5
+    )
+    assert np.asarray(gates).max() <= 1.0  # no 10 got into a gate
+    del plain_g
+
+
+def _layer(seed, held_first=0, held=E):
+    """An expert layer's leaves: router over E, experts `held_first`..."""
+    rng = np.random.default_rng(seed)
+
+    def w(*shape, fan):
+        return jnp.asarray(rng.normal(size=shape).astype(np.float32) * fan ** -0.5)
+
+    full = {
+        "mlp_norm": jnp.asarray(1 + 0.1 * rng.normal(size=D).astype(np.float32)),
+        "router": w(D, E, fan=D),
+        "router_bias": jnp.asarray(0.1 * rng.normal(size=E).astype(np.float32)),
+        "w_gate": w(E, D, F, fan=D), "w_up": w(E, D, F, fan=D),
+        "w_down": w(E, F, D, fan=F),
+        "shared_gate": w(D, F, fan=D), "shared_up": w(D, F, fan=D),
+        "shared_down": w(F, D, fan=F),
+    }
+    share = dict(full)
+    for name in ("w_gate", "w_up", "w_down"):
+        share[name] = full[name][held_first:held_first + held]
+    return full, share
+
+
+def _cfg(held, first=0, shared=True):
+    return LlamaConfig(
+        vocab_size=8, dim=D, n_layers=2, n_heads=2, n_kv_heads=2,
+        intermediate=F, dtype=jnp.float32, kv_lora_rank=4,
+        moe_experts=held, moe_top_k=K, moe_router="sigmoid_groups",
+        moe_router_experts=E, moe_first_expert=first, moe_groups=GROUPS,
+        moe_top_groups=TOP_GROUPS, moe_route_scale=2.5,
+        moe_shared_intermediate=F if shared else 0,
+    )
+
+
+def _ffn_only(cfg, x, layer):
+    """What `_mlp` adds to the residual stream."""
+    out, _, counts = _mlp(cfg, x, layer)
+    return np.asarray(out - x), counts
+
+
+def test_sixteen_shares_and_the_shared_expert_once_are_the_uncut_layer():
+    """The guide's share test: the routed parts that all the shares
+    give, with what every chip computes alike (the shared expert)
+    counted once, add up to the uncut layer."""
+    full, _ = _layer(3)
+    x = jnp.asarray(
+        np.random.default_rng(4).normal(size=(1, 48, D)).astype(np.float32)
+    )
+    whole, whole_counts = _ffn_only(_cfg(E), x, full)
+    no_shared = {k: v for k, v in full.items() if not k.startswith("shared")}
+    shared_part = whole - _ffn_only(_cfg(E, shared=False), x, no_shared)[0]
+    assert np.abs(shared_part).max() > 0.01  # it is not nothing
+    shares, per, picks = 16, E // 16, 0
+    total = np.zeros_like(whole)
+    for rank in range(shares):
+        _, share = _layer(3, rank * per, per)
+        share = {k: v for k, v in share.items() if not k.startswith("shared")}
+        part, counts = _ffn_only(
+            _cfg(per, rank * per, shared=False), x, share
+        )
+        assert counts.shape == (per,)
+        assert np.array_equal(
+            np.asarray(counts), np.asarray(whole_counts)[rank * per:(rank + 1) * per]
+        )
+        picks += int(counts.sum())
+        total += part
+    assert picks == 48 * K  # every pick met exactly one share
+    np.testing.assert_allclose(total + shared_part, whole, atol=2e-5)
+
+
+def test_a_share_is_the_loop_over_its_held_experts():
+    """One share against a float32 loop: gates normalised over ALL the
+    chosen, the held experts' parts alone, the shared expert added."""
+    first, held = 8, 8
+    full, share = _layer(5, first, held)
+    x = np.random.default_rng(6).normal(size=(40, D)).astype(np.float32)
+    got, counts = _ffn_only(_cfg(held, first), jnp.asarray(x)[None], share)
+    norm = np.asarray(full["mlp_norm"])
+    h = x / np.sqrt((x * x).mean(axis=1, keepdims=True) + 1e-6) * norm
+    gates, experts = _loop_route(
+        h, np.asarray(full["router"]), np.asarray(full["router_bias"]), 2.5
+    )
+
+    def silu(v):
+        return v / (1.0 + np.exp(-v))
+
+    def expert(v, gate, up, down):
+        return (silu(v @ np.asarray(gate)) * (v @ np.asarray(up))) @ np.asarray(down)
+
+    want = np.zeros_like(x)
+    met = np.zeros(held, int)
+    for t in range(len(x)):
+        for gate, e in zip(gates[t], experts[t]):
+            if first <= e < first + held:
+                met[e - first] += 1
+                want[t] += gate * expert(
+                    h[t], full["w_gate"][e], full["w_up"][e], full["w_down"][e]
+                )
+        want[t] += expert(
+            h[t], full["shared_gate"], full["shared_up"], full["shared_down"]
+        )
+    np.testing.assert_allclose(got[0], want, atol=2e-5)
+    assert np.array_equal(np.asarray(counts), met)
+
+
+def test_a_dead_rows_picks_meet_no_held_expert():
+    full, _ = _layer(7)
+    x = jnp.asarray(
+        np.random.default_rng(8).normal(size=(6, D)).astype(np.float32)
+    )
+    routed = route_grouped_sigmoid(
+        x, full["router"], full["router_bias"], K, GROUPS, TOP_GROUPS, 2.5
+    )
+    live = jnp.asarray([True, False, True, True, False, True])
+    out, _, counts = moe_ffn_dropless(full, x, k=K, routed=routed, live=live)
+    alone, _, _ = moe_ffn_dropless(full, x[live], k=K, routed=(
+        routed[0][live], routed[1][live]
+    ))
+    assert int(counts.sum()) == 4 * K
+    assert np.abs(np.asarray(out)[~np.asarray(live)]).max() == 0.0
+    np.testing.assert_allclose(
+        np.asarray(out)[np.asarray(live)], np.asarray(alone), atol=1e-5
+    )
+
+
+@pytest.mark.parametrize("dim, theta, factor, beta_fast, beta_slow, original", [
+    (64, 10000.0, 40.0, 32.0, 1.0, 4096),   # DeepSeek-V3.2's rope dims
+    (32, 50000.0, 8.0, 16.0, 2.0, 2048),
+])
+def test_yarn_frequencies_are_the_closed_form(
+    dim, theta, factor, beta_fast, beta_slow, original
+):
+    got = np.asarray(rope_frequencies(
+        dim, theta, ("yarn", factor, beta_slow, beta_fast, original)
+    ))
+
+    def correction_dim(rotations):
+        return dim * math.log(original / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta)
+        )
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    assert 0 < low < high < dim // 2
+    for j in range(dim // 2):
+        plain = theta ** (-2.0 * j / dim)
+        if j <= low:
+            want = plain  # turns often enough inside the trained span
+        elif j >= high:
+            want = plain / factor  # interpolated whole
+        else:
+            blend = (j - low) / (high - low)
+            want = plain / factor * blend + plain * (1 - blend)
+        assert got[j] == pytest.approx(want, rel=1e-5), j
+    # DeepSeek's temperature: 0.1 ln(factor) + 1, squared into the scale
+    assert yarn_mscale(40.0) == pytest.approx(0.1 * math.log(40.0) + 1.0)
+    assert yarn_mscale(1.0) == 1.0
+
+
+def test_a_list_from_a_json_file_is_a_hashable_scaling():
+    cfg = LlamaConfig(rope_scaling=["yarn", 40, 1, 32, 4096])
+    assert cfg.rope_scaling == ("yarn", 40, 1, 32, 4096) and hash(cfg)
+    with pytest.raises(ValueError, match="index_topk"):
+        LlamaConfig(index_topk=4)
+    with pytest.raises(ValueError, match="dense_layers"):
+        LlamaConfig(dense_layers=1)

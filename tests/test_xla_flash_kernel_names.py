@@ -230,3 +230,160 @@ def test_expert_matmuls_are_named_ragged_dot_and_scoped(one_chip):
     # that: the compiler's own count of the program's operations.
     flops = compiled.cost_analysis()["flops"]
     assert flops < 1.5 * tokens * k * 3 * 2 * dim * width, flops
+
+
+@pytest.mark.parametrize("program", ["paged_decode_step", "paged_prefill"])
+def test_latent_programs_are_scoped_and_update_the_pool_in_place(
+    one_chip, program
+):
+    """The serve forwards of a latent-attention configuration with an
+    indexer (DeepSeek-V3.2's mechanisms at a shallow, narrow size, the
+    benchmark's block length and cache widths), compiled for the
+    described chip: every stage stands under the scope the trace is
+    read by (`mla/*`, `dsa/*`, `moe/shared` beside `moe/route`,
+    `moe/experts`, `moe/combine`), the kernels are the experts'
+    `ragged-dot` and, in a chunk alone, `selected_attn` (what
+    `benchmark/layer_metrics/selected_attn_kernel_share.py` sums by;
+    a step gathers its selected entries in plain XLA), and the donated
+    pool is updated in place. The last is not a given: a latent entry of 576
+    numbers, kept 576 wide, made the TPU lay the pool out with its
+    blocks innermost and re-lay all of it around every step (a copy
+    of 1.9 GB at the benchmark's size), so the entry is declared in
+    whole lanes (`generate._lanes`)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from ray_tpu.models import generate
+
+    cfg = llama.LlamaConfig(
+        vocab_size=512, dim=1024, n_layers=3, n_heads=16, n_kv_heads=16,
+        intermediate=256, max_seq_len=4096, dtype=jnp.bfloat16,
+        rope_scaling=("yarn", 40, 1, 32, 4096),
+        kv_lora_rank=512, q_lora_rank=256, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128,
+        index_topk=512, index_n_heads=8, index_head_dim=128,
+        moe_experts=4, moe_top_k=4, moe_router="sigmoid_groups",
+        moe_router_experts=16, moe_groups=4, moe_top_groups=2,
+        moe_route_scale=2.5, moe_shared_intermediate=256,
+        dense_layers=1, dense_intermediate=512,
+    )
+    slots, block, chunk = 16, 16, 256
+    width = cfg.max_seq_len // block
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda s: spec(s.shape, s.dtype),
+        jax.eval_shape(
+            lambda k: llama.init_params(k, cfg), jax.random.PRNGKey(0)
+        ),
+    )
+    pool = jax.tree.map(
+        lambda s: spec(s.shape, s.dtype),
+        jax.eval_shape(
+            lambda: generate.init_block_pool(cfg, slots * width + 1, block)
+        ),
+    )
+    assert pool["latent"].shape[-1] == 640 and pool["index_k"].shape[-1] == 128
+    # the kernel asks jax.default_backend() whether to run interpreted:
+    # steered here, as the flash kernels' compile above is
+    patch = pytest.MonkeyPatch()
+    patch.setattr(jax, "default_backend", lambda: "tpu")
+    if program == "paged_decode_step":
+        lowered = jax.jit(
+            generate._paged_decode_step_impl,
+            static_argnames=("temperature", "top_k", "cfg"),
+            donate_argnums=(2, 4),
+        ).lower(
+            params, cfg, pool, spec((slots, width), jnp.int32),
+            spec((slots, cfg.vocab_size), jnp.float32),
+            spec((slots,), jnp.int32), spec((slots,), jnp.bool_),
+            spec((2,), jnp.uint32), temperature=0.0, top_k=0,
+        )
+    else:
+        lowered = jax.jit(
+            generate._paged_prefill_impl, static_argnames=("cfg",),
+            donate_argnums=(3,),
+        ).lower(
+            params, cfg, spec((1, chunk), jnp.int32), pool,
+            spec((1, width), jnp.int32), spec((), jnp.int32),
+            spec((), jnp.int32),
+        )
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+        patch.undo()
+    text = compiled.as_text()
+    for scope in (
+        "mla/q", "mla/kv_latent", "dsa/index", "dsa/select", "mla/attend",
+        "mla/out", "moe/shared", "moe/route", "moe/experts", "moe/combine",
+    ):
+        assert scope in text, scope
+    kernels = {
+        re.sub(r"[.\d]+$", "", line.strip().split(" = ")[0]
+               .removeprefix("ROOT ").lstrip("%"))
+        for line in text.splitlines()
+        if "tpu_custom_call" in line and " = " in line
+    }
+    families = {n for n in kernels if not n.startswith("ragged-dot")}
+    assert any(n.startswith("ragged-dot") for n in kernels), kernels
+    assert families == (
+        set() if program == "paged_decode_step" else {"selected_attn"}
+    ), kernels
+    memory = compiled.memory_analysis()
+    latent = pool["latent"]
+    one_leaf = 2 * int(jnp.prod(jnp.asarray(latent.shape)))
+    assert memory.alias_size_in_bytes >= one_leaf  # donated, reused
+    assert memory.temp_size_in_bytes < one_leaf, memory
+
+
+@pytest.mark.parametrize("queries", [2048, 512], ids=["chunk", "quarter"])
+def test_selected_attention_compiles_at_the_benchmarks_widths(one_chip, queries):
+    """The chunk's attention kernel at `deepseek-v3.2-l5-ep16`'s sizes
+    (128 heads, 128 + 64 key dims in whole lanes, 128 value dims, a
+    table of 18,432 keys, tiles of 512), compiled by Mosaic for the
+    described chip: one `selected_attn` call whose temporaries are a
+    block's, not the scores'."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from ray_tpu.ops import selected_attention as sa
+
+    heads, keys, lanes = 128, 18432, 128
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    patch = pytest.MonkeyPatch()
+    patch.setattr(jax, "default_backend", lambda: "tpu")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(
+            lambda *a: sa.selected_attention(*a, scale=0.135)
+        ).lower(
+            spec((1, heads, queries, lanes)), spec((1, heads, queries, lanes)),
+            spec((1, keys, heads * lanes)), spec((1, keys, lanes)),
+            spec((1, keys, heads * lanes)), spec((1, queries, keys), jnp.int8),
+            spec((1,), jnp.int32), spec((1,), jnp.int32),
+        ).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+        patch.undo()
+    calls = [
+        line.strip().split(" = ")[0].removeprefix("ROOT ").lstrip("%")
+        for line in compiled.as_text().splitlines()
+        if "tpu_custom_call" in line and " = " in line
+    ]
+    assert len(calls) == 1 and re.fullmatch(r"selected_attn(\.\d+)?", calls[0])
+    # the two scaled copies of q; [heads, queries, keys] float32
+    # scores would be 19 GB at a chunk
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * (
+        heads * queries * lanes * 2
+    )
