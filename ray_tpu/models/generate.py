@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from .._private import compile_watch
+from ..ops.latent_expand import latent_expand
 from ..ops.norms import (
     apply_rotary, layer_norm, rms_norm, rotary_embedding, yarn_mscale,
 )
@@ -666,7 +667,7 @@ def _index_scores(qi, wi, index_pool, layer_idx, work, n_trips, keys):
             k_pos < length[:, None, None]
         )
         s = jnp.where(seen, s, -jnp.inf)
-        return _write_tiles(scores, s, row, first_key, axis=2)
+        return _write_tiles(scores, s, row, first_key)
 
     return jax.lax.fori_loop(
         0, n_trips, one_trip,
@@ -674,16 +675,16 @@ def _index_scores(qi, wi, index_pool, layer_idx, work, n_trips, keys):
     )
 
 
-def _write_tiles(into, tiles, row, first_key, axis: int):
-    """`tiles` [pairs, ...], pair i's slab of keys written into
-    `into` [rows, ...] at row `row[i]` from key `first_key[i]` on
-    (`axis` is the keys'); a padding pair (`row[i]` past the rows)
-    writes back what was there. One pair a trip (a chunk: one row)
-    walks its live tiles alone and meets no padding pair."""
+def _write_tiles(into, tiles, row, first_key):
+    """`tiles` [pairs, ..., tile], pair i's slab of keys written into
+    `into` [rows, ..., keys] at row `row[i]` from key `first_key[i]`
+    on; a padding pair (`row[i]` past the rows) writes back what was
+    there. One pair a trip (a chunk: one row) walks its live tiles
+    alone and meets no padding pair."""
     rows, pairs = into.shape[0], tiles.shape[0]
     for i in range(pairs):
         at = [jnp.minimum(row[i], rows - 1)] + [0] * (into.ndim - 1)
-        at[axis] = first_key[i]
+        at[-1] = first_key[i]
         new = tiles[i][None].astype(into.dtype)
         if pairs > 1:
             old = jax.lax.dynamic_slice(into, at, new.shape)
@@ -692,43 +693,30 @@ def _write_tiles(into, tiles, row, first_key, axis: int):
     return into
 
 
-def _expand_latent(latent_pool, layer_idx, work, n_trips, wk, wv, rows, keys):
-    """The live tiles' cache entries as every head's keys and values:
-    -> (kn [rows, keys, heads x dn], kr [rows, keys, dr], v [rows,
-    keys, heads x dv]) in the pool's dtype, the heads side by side as
-    the matmul leaves them, zeros where no live tile lies. `wk`
-    [latent, heads x dn] and `wv` [latent, heads x dv] are Wkv_b's two
-    parts (dn, dv whole lanes); dr lanes of an entry behind its latent
-    are the rotary key all heads share."""
-    latent_width = wk.shape[0]
-    bl, width = latent_pool.shape[2:]
-    dr = width - latent_width
-    tile = work["ids"].shape[1] * bl
-    dt = latent_pool.dtype
-
-    def one_trip(j, out):
-        kn, kr, v = out
-        pair = {
-            name: jax.lax.dynamic_slice_in_dim(of_all, j * rows, rows)
-            for name, of_all in work.items()
-        }
-        ids, (row, first_key, _) = pair["ids"], pair["at"].T
-        with jax.named_scope("paged/gather_kv"):
-            entries = latent_pool[layer_idx, ids].reshape(rows, tile, width)
-        latents = entries[..., :latent_width]
-        return (
-            _write_tiles(kn, latents @ wk, row, first_key, axis=1),
-            _write_tiles(
-                kr, entries[..., latent_width:], row, first_key, axis=1
-            ),
-            _write_tiles(v, latents @ wv, row, first_key, axis=1),
-        )
-
-    return jax.lax.fori_loop(0, n_trips, one_trip, (
-        jnp.zeros((rows, keys, wk.shape[1]), dt),
-        jnp.zeros((rows, keys, dr), dt),
-        jnp.zeros((rows, keys, wv.shape[1]), dt),
-    ))
+def _expand_latent(latent_pool, layer_idx, tables, valid_len, wk, wv, tile):
+    """A row's cache entries as every head's keys and values: -> (kn
+    [rows, keys, heads x dn], kr [rows, keys, dr], v [rows, keys, heads
+    x dv]) in the pool's dtype, the heads side by side as the matmul
+    leaves them. `wk` [latent, heads x dn] and `wv` [latent, heads x
+    dv] are Wkv_b's two parts (dn, dv whole lanes); dr lanes of an
+    entry behind its latent are the rotary key all heads share. ONE
+    gather of the row's table gives the entries (the null block's
+    where the table is padding: finite), and the kernel writes each of
+    the row's live tiles of `tile` keys once, where `selected_attention`
+    reads it; kn and v past them are never written and hold anything
+    (ops/latent_expand.py)."""
+    rows = tables.shape[0]
+    latent_width, width = wk.shape[0], latent_pool.shape[-1]
+    with jax.named_scope("paged/gather_kv"):
+        entries = latent_pool[layer_idx, tables].reshape(rows, -1, width)
+    # (a latent narrower than its lanes: what else they hold meets
+    # rows of zeros)
+    rest = ((0, _lanes(latent_width) - latent_width), (0, 0))
+    kn, v = latent_expand(
+        entries, jnp.pad(wk, rest), jnp.pad(wv, rest),
+        -(-valid_len // tile), block_k=tile,
+    )
+    return kn, entries[..., latent_width:], v
 
 
 def _latent_layer(
@@ -858,17 +846,18 @@ def _latent_layer(
                 allowed = (k_pos <= q_pos[..., None]) & (
                     k_pos < valid_len[:, None, None]
                 )
+            tile = work["ids"].shape[1] * latent.shape[2]
             kn, kr, v = _expand_latent(
-                latent, layer_idx, work, n_trips,
+                latent, layer_idx, tables, valid_len,
                 _pad_last(wkv_b[..., :nope], _lanes(nope)).reshape(kvr, -1),
                 _pad_last(wkv_b[..., nope:], _lanes(vd)).reshape(kvr, -1),
-                b, keys,
+                tile,
             )
             out = selected_attention(
                 _pad_last(q_nope, _lanes(nope)),
                 _pad_last(q_rope, kr.shape[-1]), kn, kr, v,
                 allowed.astype(jnp.int8), q_pos[:, 0], valid_len,
-                scale=scale, block_k=work["ids"].shape[1] * latent.shape[2],
+                scale=scale, block_k=tile,
             )[..., :vd]  # [b, heads, t, vd]: the heads' own values
     with jax.named_scope("mla/out"):
         if step:

@@ -232,6 +232,68 @@ def test_expert_matmuls_are_named_ragged_dot_and_scoped(one_chip):
     assert flops < 1.5 * tokens * k * 3 * 2 * dim * width, flops
 
 
+def _build_latent_program(
+    one_chip, cfg, program, n_blocks, block, width, chunk, slots=16
+):
+    """-> (compiled, the pool's specs): `paged_decode_step` over `slots`
+    rows or `paged_prefill` of one `chunk`, of a latent-attention
+    configuration with a pool of `n_blocks` blocks of `block` and
+    tables `width` wide, compiled for the described chip with the
+    compile cache off."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from ray_tpu.models import generate
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda s: spec(s.shape, s.dtype),
+        jax.eval_shape(
+            lambda k: llama.init_params(k, cfg), jax.random.PRNGKey(0)
+        ),
+    )
+    pool = jax.tree.map(
+        lambda s: spec(s.shape, s.dtype),
+        jax.eval_shape(
+            lambda: generate.init_block_pool(cfg, n_blocks, block)
+        ),
+    )
+    # the kernels ask jax.default_backend() whether to run interpreted:
+    # steered here, as the flash kernels' compile above is
+    patch = pytest.MonkeyPatch()
+    patch.setattr(jax, "default_backend", lambda: "tpu")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        if program == "paged_decode_step":
+            lowered = jax.jit(
+                generate._paged_decode_step_impl,
+                static_argnames=("temperature", "top_k", "cfg"),
+                donate_argnums=(2, 4),
+            ).lower(
+                params, cfg, pool, spec((slots, width), jnp.int32),
+                spec((slots, cfg.vocab_size), jnp.float32),
+                spec((slots,), jnp.int32), spec((slots,), jnp.bool_),
+                spec((2,), jnp.uint32), temperature=0.0, top_k=0,
+            )
+        else:
+            lowered = jax.jit(
+                generate._paged_prefill_impl, static_argnames=("cfg",),
+                donate_argnums=(3,),
+            ).lower(
+                params, cfg, spec((1, chunk), jnp.int32), pool,
+                spec((1, width), jnp.int32), spec((), jnp.int32),
+                spec((), jnp.int32),
+            )
+        return lowered.compile(), pool
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+        patch.undo()
+
+
 @pytest.mark.parametrize("program", ["paged_decode_step", "paged_prefill"])
 def test_latent_programs_are_scoped_and_update_the_pool_in_place(
     one_chip, program
@@ -244,16 +306,14 @@ def test_latent_programs_are_scoped_and_update_the_pool_in_place(
     `moe/experts`, `moe/combine`), the kernels are the experts'
     `ragged-dot` and, in a chunk alone, `selected_attn` (what
     `benchmark/layer_metrics/selected_attn_kernel_share.py` sums by;
-    a step gathers its selected entries in plain XLA), and the donated
+    a step gathers its selected entries in plain XLA) with
+    `latent_expand`, which writes the keys and values it reads
+    (ISSUE 45: no fill, no copy a tile), and the donated
     pool is updated in place. The last is not a given: a latent entry of 576
     numbers, kept 576 wide, made the TPU lay the pool out with its
     blocks innermost and re-lay all of it around every step (a copy
     of 1.9 GB at the benchmark's size), so the entry is declared in
     whole lanes (`generate._lanes`)."""
-    from jax.experimental.compilation_cache import compilation_cache
-
-    from ray_tpu.models import generate
-
     cfg = llama.LlamaConfig(
         vocab_size=512, dim=1024, n_layers=3, n_heads=16, n_kv_heads=16,
         intermediate=256, max_seq_len=4096, dtype=jnp.bfloat16,
@@ -268,56 +328,11 @@ def test_latent_programs_are_scoped_and_update_the_pool_in_place(
     )
     slots, block, chunk = 16, 16, 256
     width = cfg.max_seq_len // block
-
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    params = jax.tree.map(
-        lambda s: spec(s.shape, s.dtype),
-        jax.eval_shape(
-            lambda k: llama.init_params(k, cfg), jax.random.PRNGKey(0)
-        ),
-    )
-    pool = jax.tree.map(
-        lambda s: spec(s.shape, s.dtype),
-        jax.eval_shape(
-            lambda: generate.init_block_pool(cfg, slots * width + 1, block)
-        ),
+    compiled, pool = _build_latent_program(
+        one_chip, cfg, program, slots * width + 1, block, width, chunk,
+        slots,
     )
     assert pool["latent"].shape[-1] == 640 and pool["index_k"].shape[-1] == 128
-    # the kernel asks jax.default_backend() whether to run interpreted:
-    # steered here, as the flash kernels' compile above is
-    patch = pytest.MonkeyPatch()
-    patch.setattr(jax, "default_backend", lambda: "tpu")
-    if program == "paged_decode_step":
-        lowered = jax.jit(
-            generate._paged_decode_step_impl,
-            static_argnames=("temperature", "top_k", "cfg"),
-            donate_argnums=(2, 4),
-        ).lower(
-            params, cfg, pool, spec((slots, width), jnp.int32),
-            spec((slots, cfg.vocab_size), jnp.float32),
-            spec((slots,), jnp.int32), spec((slots,), jnp.bool_),
-            spec((2,), jnp.uint32), temperature=0.0, top_k=0,
-        )
-    else:
-        lowered = jax.jit(
-            generate._paged_prefill_impl, static_argnames=("cfg",),
-            donate_argnums=(3,),
-        ).lower(
-            params, cfg, spec((1, chunk), jnp.int32), pool,
-            spec((1, width), jnp.int32), spec((), jnp.int32),
-            spec((), jnp.int32),
-        )
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        compiled = lowered.compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-        compilation_cache.reset_cache()
-        patch.undo()
     text = compiled.as_text()
     for scope in (
         "mla/q", "mla/kv_latent", "dsa/index", "dsa/select", "mla/attend",
@@ -333,13 +348,75 @@ def test_latent_programs_are_scoped_and_update_the_pool_in_place(
     families = {n for n in kernels if not n.startswith("ragged-dot")}
     assert any(n.startswith("ragged-dot") for n in kernels), kernels
     assert families == (
-        set() if program == "paged_decode_step" else {"selected_attn"}
+        set() if program == "paged_decode_step"
+        else {"selected_attn", "latent_expand"}
     ), kernels
     memory = compiled.memory_analysis()
     latent = pool["latent"]
     one_leaf = 2 * int(jnp.prod(jnp.asarray(latent.shape)))
     assert memory.alias_size_in_bytes >= one_leaf  # donated, reused
     assert memory.temp_size_in_bytes < one_leaf, memory
+    if program == "paged_prefill":
+        assert not _fills_and_copies_of_expanded_buffers(
+            text, cfg.n_heads * 128
+        )
+        # what the program held before ISSUE 45, at these sizes
+        assert memory.temp_size_in_bytes <= 4709376, memory
+
+
+def _fills_and_copies_of_expanded_buffers(text: str, columns: int):
+    """The `broadcast` and `dynamic-update-slice` instructions of a
+    compiled chunk whose result is a whole buffer of expanded keys or
+    values, `bf16[rows, keys of a whole table, heads x lanes]`: what
+    `_expand_latent` cost before its kernel wrote each tile once
+    (ISSUE 45: a fill of 554 MB and a copy a tile, twice a layer)."""
+    found = []
+    for line in text.splitlines():
+        made = re.match(
+            r"\s*(?:ROOT )?(%\S+) = bf16\[\d+,(\d+),(\d+)\]\S* "
+            r"(broadcast|dynamic-update-slice)\(", line,
+        )
+        if made and int(made.group(3)) == columns and int(made.group(2)) >= 4096:
+            found.append(made.group(1))
+    return found
+
+
+def test_latent_chunk_at_the_benchmarks_sizes_writes_its_expansion_once(
+    one_chip
+):
+    """`paged_prefill` of `deepseek-v3.2-l5-ep16` as the cell runs it
+    (chunk 512, a table of 1,024 blocks of 16, the pool's 20,480
+    blocks), compiled for the described chip: the expansion is the
+    `latent_expand` kernel, one call a stack of layers, no buffer of
+    16,896 keys x 16,384 columns is filled or copied into, and the
+    program's temporaries are no more than they were with the fills
+    (1,279,400,960 bytes)."""
+    import json
+    import pathlib
+
+    config = json.loads((
+        pathlib.Path(__file__).parent.parent
+        / "benchmark/configs/deepseek-v3.2-l5-ep16.json"
+    ).read_text())
+    engine, model = config["engine"], dict(config["model"])
+    model["rope_scaling"] = tuple(model["rope_scaling"])
+    cfg = llama.LlamaConfig(**model, dtype=jnp.dtype(config["dtype"]))
+    block = engine["kv_block_len"]
+    compiled, _ = _build_latent_program(
+        one_chip, cfg, "paged_prefill", engine["kv_blocks"], block,
+        engine["max_len"] // block, engine["prefill_chunk"],
+    )
+    text = compiled.as_text()
+    calls = [
+        line.strip().split(" = ")[0].removeprefix("ROOT ").lstrip("%")
+        for line in text.splitlines()
+        if "tpu_custom_call" in line and " = " in line
+    ]
+    expansions = [n for n in calls if n.startswith("latent_expand")]
+    assert len(expansions) == 2, calls  # the dense stack's and the experts'
+    assert f"bf16[1,16896,{cfg.n_heads * 128}]" in text  # the buffers exist
+    assert not _fills_and_copies_of_expanded_buffers(text, cfg.n_heads * 128)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1279400960
 
 
 @pytest.mark.parametrize("queries", [2048, 512], ids=["chunk", "quarter"])
