@@ -112,7 +112,7 @@ import numpy as np
 from .._private import compile_watch
 from .._private.step_telemetry import phase_timer, take_phases
 from ..util import tracing
-from .kv_slots import NULL_BLOCK, PagedKVCache, default_block_len
+from .kv_slots import NULL_BLOCK, PagedKVCache
 from .scheduler import EngineDead, EngineOverloaded, SlotScheduler
 
 __all__ = [
@@ -185,6 +185,7 @@ class _Request:
         "prefix_keys", "total_blocks", "block_ids", "n_shared",
         "skip", "gen", "submitted_ns", "admitted_ts", "decoding_ts",
         "trace_parent", "serve_request_id", "table", "dispatched",
+        "window_copy",
     )
 
     def __init__(
@@ -225,6 +226,9 @@ class _Request:
         self.prefix_keys: List[tuple] = []
         self.total_blocks = 0
         self.block_ids: List[int] = []
+        #: (from pages, to pages) of a prefix hit's window tail, until
+        #: it is dispatched (models with window layers)
+        self.window_copy = None
         self.n_shared = 0
         self.skip = 0
         #: The request's table row on the device, [1, width], uploaded
@@ -461,14 +465,10 @@ class InferenceEngine:
         self._gen_latest = 0
         self._weight_version = 0
         if cfg is not None:
-            block_len = ec.kv_block_len or default_block_len(
-                ec.prefill_chunk
-            )
-            n_blocks = ec.kv_blocks or (
-                ec.slots * (ec.max_len // block_len) + 1
-            )
-            self._kv = PagedKVCache(
-                cfg, n_blocks, block_len, ec.max_len, ec.prefill_chunk
+            self._kv = PagedKVCache.for_engine(
+                cfg, slots=ec.slots, max_len=ec.max_len,
+                prefill_chunk=ec.prefill_chunk,
+                kv_block_len=ec.kv_block_len, kv_blocks=ec.kv_blocks,
             )
             self._sched = SlotScheduler(ec.slots, ec.max_waiting)
             # Keys of one attention tile in a decode step (for the
@@ -476,7 +476,7 @@ class InferenceEngine:
             from ..models.generate import paged_tile_keys
 
             self._kv_tile_keys = paged_tile_keys(
-                block_len, self._kv.max_blocks, q_len=1
+                self._kv.block_len, self._kv.max_blocks, q_len=1
             )
         else:
             self._kv = None
@@ -493,15 +493,14 @@ class InferenceEngine:
             self._alive = np.zeros(ec.slots, bool)
             self._eos = np.full(ec.slots, -1, np.int32)
             self._budget = np.zeros(ec.slots, np.int32)
-            self._tables = np.full(
-                (ec.slots, self._kv.max_blocks), NULL_BLOCK, np.int32
-            )
+            # (every slot's table rows as the cache lays them out:
+            # `tables`, and a window pool's `window_rings`)
+            self._mirror = self._kv.host_rows([None] * ec.slots)
+            self._tables = self._mirror["tables"]
             self._last_logits = jnp.zeros(
                 (ec.slots, cfg.vocab_size), jnp.float32
             )
-            self._null_row = jnp.full(
-                (1, self._kv.max_blocks), NULL_BLOCK, jnp.int32
-            )
+            self._null_row = self._kv.row_table(0, None)
             self._push_state()
         # Programs dispatched and not retired, oldest first, and the
         # clock at the last retirement (where the next program's
@@ -546,6 +545,22 @@ class InferenceEngine:
         # to the longest alive row, the rule the program itself runs).
         self._kv_keys_live = 0
         self._kv_keys_read = 0
+        # Where the model has window layers (`LlamaConfig.layer_kinds`):
+        # the keys they walked, in steps and chunks, and what they
+        # would have walked over the whole row (what the full layers
+        # did walk); ring pages overwritten in place; each pool's peak
+        # of pinned pages; and what the full pool alone would have let
+        # prefix hits skip (beside `prefix_tokens_saved`, what was
+        # skipped: the rest went with an evicted window tail).
+        self._window: Dict[str, int] = dict.fromkeys(
+            (
+                "swa_keys_read", "swa_keys_unwindowed",
+                "window_pages_recycled", "window_pool_used",
+                "full_pool_used", "prefix_tokens_full_hit",
+            ) if self._kv is not None and self._kv.window is not None
+            else (),
+            0,
+        )
         # What the expert layers did, from the picks per layer and
         # expert each paged forward leaves in the pool (MoE configs
         # only; a dense engine has none of these keys in stats()).
@@ -629,12 +644,12 @@ class InferenceEngine:
         total_blocks = self._kv.blocks_for(
             max(bucket, len(prompt) + max_new)
         )
-        if total_blocks > self._kv.alloc.capacity():
+        if total_blocks > self._kv.full.capacity():
             # OOM is a SHED, not a crash or an unserviceable queue
             # entry: this request could never be admitted.
             raise EngineOverloaded(
                 f"request needs {total_blocks} KV blocks but the pool "
-                f"holds {self._kv.alloc.capacity()}; shed"
+                f"holds {self._kv.full.capacity()}; shed"
             )
         req = _Request(
             request_id or uuid.uuid4().hex[:16],
@@ -820,6 +835,7 @@ class InferenceEngine:
                 state_patches=self._state_patches,
                 pipeline_drains=self._pipeline_drains,
                 **self._moe,
+                **self._window,
                 **self._device,
             )
             if self._kv is not None:
@@ -849,8 +865,10 @@ class InferenceEngine:
                 out.update(
                     kv_bytes=self._kv.nbytes(),
                     kv_block_len=self._kv.block_len,
-                    **self._kv.alloc.stats(),
+                    **self._kv.full.stats(),
                 )
+                if self._kv.window is not None:
+                    out.update(self._kv.window.stats())
         return out
 
     def close(self) -> None:
@@ -1041,8 +1059,17 @@ class InferenceEngine:
         cancellation the caller patches in (`_patch_slot`)."""
         self._sched.release(slot)
         self._alive[slot] = False
-        self._tables[slot, :] = NULL_BLOCK
+        for rows in self._mirror.values():
+            rows[slot, :] = NULL_BLOCK
         if req.block_ids:
+            if self._kv.window is not None:
+                # Blocks the row wrote beyond its ring's pages each
+                # overwrote one in place.
+                written = max(req.offset, int(self._positions[slot]))
+                self._window["window_pages_recycled"] += max(
+                    0, self._kv.blocks_for(written)
+                    - len(req.block_ids["window"]),
+                )
             # Unpin: full prompt blocks stay in the prefix cache
             # (refcount 0, LRU-evictable); private blocks go back to
             # the free list. block_ids cleared so no path can double-
@@ -1151,6 +1178,17 @@ class InferenceEngine:
         usable = min(hit_blocks * bl, len(req.prompt) - 1)
         return (usable // chunk) * chunk
 
+    def _window_skip(self, req: _Request, skip: int) -> int:
+        """What of `_skip_for`'s tokens the window layers can skip too:
+        the longest whole-chunk boundary whose tail, the window's worth
+        of keys before it, the window pool still holds
+        (llm/kv_window.py); all of it for a model without such
+        layers."""
+        window = self._kv.window
+        if window is None or not skip:
+            return skip
+        return window.usable_skip(req.prefix_keys, skip)
+
     def _gate_locked(self, req: _Request) -> bool:
         """Admission gate: can the FIFO head get its blocks NOW? The
         reservation needs `total - skip` fresh blocks, and pinning the
@@ -1159,11 +1197,19 @@ class InferenceEngine:
         `available()` when pinned; hits already pinned by a live
         request are free to share. A gated admission can therefore
         never fail its reservation one line later, and sharing a
-        LIVE request's prefix genuinely relaxes admission."""
-        alloc = self._kv.alloc
+        LIVE request's prefix genuinely relaxes admission. With a
+        window pool the gate covers both: the row's ring of window
+        pages is reserved here and never grows (kv_window.py), and a
+        hit reaches only as far as both pools can serve it."""
+        alloc = self._kv.full
         hits = alloc.peek_prefix(req.prefix_keys)
-        skip_blocks = self._skip_for(req, hits) // self._kv.block_len
+        skip = self._window_skip(req, self._skip_for(req, hits))
+        skip_blocks = skip // self._kv.block_len
         cached = alloc.peek_cached(req.prefix_keys, skip_blocks)
+        if self._kv.window is not None and not self._kv.window.gate(
+            req.prefix_keys, skip, req.total_blocks
+        ):
+            return False
         return (
             alloc.available() - cached
             >= req.total_blocks - skip_blocks
@@ -1173,9 +1219,10 @@ class InferenceEngine:
         """Pin the request's prefix-cache hit (if any) and reserve the
         rest of its pages; build its table row. Runs under the lock in
         the same critical section as the gate."""
-        alloc = self._kv.alloc
+        alloc = self._kv.full
         shared = alloc.match_prefix(req.prefix_keys)
-        skip = self._skip_for(req, len(shared))
+        full_skip = self._skip_for(req, len(shared))
+        skip = self._window_skip(req, full_skip)
         skip_blocks = skip // self._kv.block_len
         if len(shared) > skip_blocks:
             # Hit blocks beyond the chunk-aligned usable window: unpin
@@ -1189,9 +1236,20 @@ class InferenceEngine:
         req.block_ids = shared + alloc.reserve(
             req.total_blocks - skip_blocks
         )
-        row = self._tables[req.slot]
-        row[:] = NULL_BLOCK
-        row[: len(req.block_ids)] = req.block_ids
+        window = self._kv.window
+        if window is not None:
+            ring, req.window_copy = window.admit(
+                req.prefix_keys, skip, req.total_blocks
+            )
+            req.block_ids = {"full": req.block_ids, "window": ring}
+            counted = self._window
+            counted["prefix_tokens_full_hit"] += full_skip
+            for pool, pages in (("full", alloc), ("window", window.alloc)):
+                counted[f"{pool}_pool_used"] = max(
+                    counted[f"{pool}_pool_used"], pages.used()
+                )
+        for name, rows in self._kv.host_rows([req.block_ids]).items():
+            self._mirror[name][req.slot] = rows[0]
         if skip:
             self._prefix_hits += 1
             self._prefix_tokens_saved += skip
@@ -1203,16 +1261,14 @@ class InferenceEngine:
     def _push_state(self) -> None:
         """Make the device's step state from the mirrors, whole: at
         start, and after a mixed-generation window's serial steps."""
-        import jax.numpy as jnp
-
-        self._state = {
-            "tables": jnp.asarray(self._tables),
-            "positions": jnp.asarray(self._positions),
-            "alive": jnp.asarray(self._alive),
-            "eos": jnp.asarray(self._eos),
-            "budget": jnp.asarray(self._budget),
-            "step": jnp.asarray(np.int32(self._steps)),
-        }
+        blocks = [None] * self.config.slots
+        with self._lock:
+            for slot, req in self._sched.running.items():
+                blocks[slot] = req.block_ids
+        self._state = self._kv.step_state(
+            blocks, self._positions, self._alive, self._eos,
+            self._budget, self._steps,
+        )
 
     def _patch_slot(self, slot: int, table_row) -> None:
         """The device's row of `slot`: this table row, and dead."""
@@ -1278,8 +1334,13 @@ class InferenceEngine:
                 self._prefilling = req
         slot = req.slot
         if admitting:
-            req.table = jnp.asarray(self._tables[slot:slot + 1])
+            req.table = self._kv.row_table(slot, req.block_ids)
             self._patch_slot(slot, req.table)
+            if req.window_copy is not None:
+                # A hit's window tail, from the prefix cache's pages
+                # into the row's ring, before its first chunk.
+                self._copy_window_pages(*req.window_copy)
+                req.window_copy = None
         phase.switch("engine.prefill.prepare")
         if req.padded is None:
             padded = np.zeros((1, req.bucket), np.int32)
@@ -1316,12 +1377,9 @@ class InferenceEngine:
                         # The chunk that fills the last of them is
                         # only queued: a request that hits them reads
                         # them in a program dispatched after it.
-                        for i in range(
-                            req.n_shared, len(req.prefix_keys)
-                        ):
-                            self._kv.alloc.register(
-                                req.block_ids[i], req.prefix_keys[i]
-                            )
+                        self._kv.publish(
+                            req.block_ids, req.n_shared, req.prefix_keys
+                        )
                     self._positions[slot] = len(req.prompt)
                     self._alive[slot] = True
                     self._budget[slot] = req.max_new_tokens
@@ -1348,11 +1406,60 @@ class InferenceEngine:
         )
         if started:
             self._state_patches += 1
+        if self._kv.window is not None and not cancelled:
+            self._after_window_chunk(req, start, chunk)
         if cancelled:
             self._patch_slot(slot, self._null_row)
         self._inflight.append(
             _ChunkInFlight(req if started else None, fence, t0)
         )
+
+    def _copy_window_pages(self, src: List[int], dst: List[int]) -> None:
+        """Dispatch `copy_window_pages` behind what is queued."""
+        from ..models.generate import copy_window_pages
+
+        self._kv.pool = copy_window_pages(
+            self._kv.pool, np.asarray(src, np.int32),
+            np.asarray(dst, np.int32),
+        )
+
+    def _after_window_chunk(self, req: _Request, start: int, chunk: int) -> None:
+        """A chunk over [start, start + chunk) of `req` was dispatched:
+        count what its window layers walked, and where it ends on a
+        whole-chunk boundary of the prompt, keep that boundary's tail
+        for later hits (kv_window.py `keep_tail`)."""
+        from ..models.generate import (
+            paged_tile_keys, paged_tiles_read, window_first_key,
+            window_view_blocks,
+        )
+
+        window, bl = self._kv.window, self._kv.block_len
+        end = start + chunk
+        first = window_first_key(np.int32(start), window.window, bl)
+        counted = {}
+        for name, length, width in (
+            ("swa_keys_read", end - int(first),
+             window_view_blocks(window.window, bl, chunk)),
+            ("swa_keys_unwindowed", end, self._kv.max_blocks),
+        ):
+            tile = paged_tile_keys(bl, width, chunk)
+            counted[name] = tile * int(paged_tiles_read(
+                np.asarray([length]), True, tile
+            ))
+        copy = None
+        with self._lock:
+            for name, keys in counted.items():
+                self._window[name] += keys
+            if (
+                self.config.prefix_cache
+                and end % self._kv.prefill_chunk == 0
+                and end <= len(req.prompt) and req.block_ids
+            ):
+                copy = window.keep_tail(
+                    req.prefix_keys, req.block_ids["window"], end
+                )
+        if copy is not None:
+            self._copy_window_pages(*copy)
 
     def _dispatch_chunk(
         self, params, tokens, table, start: int, slot: int, local: int,
@@ -1414,6 +1521,9 @@ class InferenceEngine:
                     self._null_row, 0, 0, 0, False,
                 )
             )
+        if self._kv.window is not None:
+            null = [NULL_BLOCK] * self._kv.window.tail_blocks
+            self._copy_window_pages(null, null)
 
     # -- decode --------------------------------------------------------
     def _live_rows(self) -> List[tuple]:
@@ -1494,6 +1604,12 @@ class InferenceEngine:
         self._count_kv_keys(by_gen.values())
         key = jax.random.fold_in(self._base_key, self._steps)
         tables = jnp.asarray(self._tables)
+        if self._kv.window is not None:
+            from ..models.generate import KindTables
+
+            tables = KindTables(
+                tables, jnp.asarray(self._mirror["window_rings"])
+            )
         positions = jnp.asarray(self._positions)
         # paged_decode_step donates last_logits on accelerator
         # backends, so each group gets a PRIVATE copy of the pre-step
@@ -1657,9 +1773,51 @@ class InferenceEngine:
             read += self.config.slots * tile * int(
                 paged_tiles_read(valid_len, mask, tile)
             )
+        if self._kv.window is not None:
+            live, read = self._count_window_keys(groups, live, read)
         with self._lock:
             self._kv_keys_live += live
             self._kv_keys_read += read
+
+    def _count_window_keys(self, groups, live: int, read: int) -> tuple:
+        """A step of a model with window layers. Adds `swa_keys_read`
+        (what a window layer walked: every row's view of its last
+        `window` keys, `generate._window_view`) and
+        `swa_keys_unwindowed` (what it would have walked over the whole
+        row: `read`, what a full layer did), and -> (live, read) summed
+        over the kinds of layer, each kind as many times as the model
+        has layers of it: a window layer's live keys are a row's last
+        `window`, so `kv_read_amplification` keeps its meaning."""
+        from ..models.generate import (
+            paged_tile_keys, paged_tiles_read, window_first_key,
+            window_view_blocks,
+        )
+
+        window, bl = self._kv.window.window, self._kv.block_len
+        tile = paged_tile_keys(bl, window_view_blocks(window, bl, 1), 1)
+        seen = self._positions + 1 - window_first_key(
+            self._positions, window, bl
+        )
+        w_live = w_read = 0
+        for slots in groups:
+            mask = np.zeros_like(self._alive)
+            mask[slots] = True
+            w_live += int(np.minimum(self._positions[mask] + 1, window).sum())
+            w_read += self.config.slots * tile * int(
+                paged_tiles_read(seen, mask, tile)
+            )
+        layers = {
+            kind.cache: len(ls)
+            for kind, ls in self.cfg.attn_kinds().values()
+        }
+        with self._lock:
+            self._window["swa_keys_read"] += w_read
+            self._window["swa_keys_unwindowed"] += read
+        full = layers.get("full", 0)
+        return (
+            full * live + layers["window"] * w_live,
+            full * read + layers["window"] * w_read,
+        )
 
     def _count_moe(self, program: str, counted: dict) -> None:
         """Add what one paged forward counted (the pool's counter
@@ -1708,7 +1866,7 @@ class InferenceEngine:
     def _block_stats(self) -> Dict[str, int]:
         if self._kv is None:
             return {"kv_used": 0, "kv_total": 0}
-        alloc = self._kv.alloc
+        alloc = self._kv.full
         return {
             "kv_used": alloc.used(),
             "kv_total": alloc.capacity(),

@@ -282,9 +282,49 @@ class BlockAllocator:
         }
 
 
+class _TwoPools:
+    """`PagedKVCache.alloc` of a model that keeps two kinds of cache:
+    a row's blocks are {"full": ids, "window": ids}, reserved and
+    released together; whoever carries them treats them as opaque."""
+
+    def __init__(self, full: BlockAllocator, window):
+        self.full, self.window = full, window
+
+    def reserve(self, n: int) -> Dict[str, List[int]]:
+        """A row of `n` blocks: its full pages and its ring of window
+        pages (llm/kv_window.py), both or neither."""
+        ring = self.window.ring_for(n)
+        if ring > self.window.alloc.available():
+            raise BlocksExhausted(
+                f"need {ring} window pages, only "
+                f"{self.window.alloc.available()} available"
+            )
+        return {
+            "full": self.full.reserve(n),
+            "window": self.window.alloc.reserve(ring),
+        }
+
+    def release(self, blocks: Dict[str, List[int]]) -> None:
+        self.full.release(blocks["full"])
+        self.window.alloc.release(blocks["window"])
+
+
 class PagedKVCache:
     """The engine's shared block pool plus its geometry: block length,
-    per-request logical-table width, and prompt-length buckets."""
+    per-request logical-table width, and prompt-length buckets.
+
+    What a row's cache IS belongs to this class alone: the engine, the
+    benchmark's probe and its compile rehearsal get the cache from
+    `for_engine`, a row's `table` argument from `row_table` and a
+    step's `state` from `step_state`, and carry a row's blocks (what
+    `alloc.reserve(blocks_for(tokens))` returned) as they come. For a
+    model whose layers are all of one kind that is one pool, one
+    allocator (`alloc` is `full`), a list of block ids a row and the
+    six-leaf state. A model with `layer_kinds` (window and full
+    attention mixed) keeps a second pool for its window layers
+    (`window`, llm/kv_window.py): a row's blocks are then {"full",
+    "window"}, its table a table in each pool and the state has one
+    more leaf, `window_rings`."""
 
     def __init__(
         self,
@@ -293,6 +333,7 @@ class PagedKVCache:
         block_len: int,
         max_len: int,
         prefill_chunk: int,
+        slots: int = 0,
     ):
         if prefill_chunk < 1 or prefill_chunk > max_len:
             raise ValueError(
@@ -315,8 +356,101 @@ class PagedKVCache:
         #: Logical table width: the block count a max_len sequence
         #: needs; every request's table pads to it (static shapes).
         self.max_blocks = self.max_len // self.block_len
-        self.alloc = BlockAllocator(n_blocks, reserved=1)
-        self._pool = init_block_pool(cfg, int(n_blocks), self.block_len)
+        #: The allocator of the pages every kind of model has: a row's
+        #: whole length, the prefix cache's blocks.
+        self.full = BlockAllocator(n_blocks, reserved=1)
+        self.alloc = self.full
+        #: The window layers' pages, where the model has such layers.
+        self.window = None
+        pool_blocks = int(n_blocks)
+        windows = {k.window for k in cfg.layer_kinds if k.window}
+        if windows:
+            from .kv_window import WindowPages
+
+            self.window = WindowPages(
+                windows.pop(), self.block_len, self.prefill_chunk,
+                slots, int(n_blocks),
+            )
+            self.alloc = _TwoPools(self.full, self.window)
+            pool_blocks = {
+                "full": pool_blocks, "window": self.window.alloc.n_blocks
+            }
+        self._pool = init_block_pool(cfg, pool_blocks, self.block_len)
+
+    @classmethod
+    def for_engine(
+        cls, cfg: LlamaConfig, *, slots: int, max_len: int,
+        prefill_chunk: int, kv_block_len: int = 0, kv_blocks: int = 0,
+    ) -> "PagedKVCache":
+        """The cache of an engine's settings (`EngineConfig`'s keys of
+        the same names; 0 is auto: the largest block up to 16 that
+        divides the chunk, `slots x max_len` worth of blocks). A
+        model's window pool is sized from these (kv_window.py)."""
+        block_len = kv_block_len or default_block_len(prefill_chunk)
+        n_blocks = kv_blocks or slots * (max_len // block_len) + 1
+        return cls(cfg, n_blocks, block_len, max_len, prefill_chunk, slots)
+
+    # -- what a row's cache is, for the programs -----------------------
+    def host_rows(self, blocks: Sequence) -> Dict[str, "object"]:
+        """Every slot's block ids as numpy tables, `blocks[i]` what
+        `alloc.reserve` gave the row in slot i (None or empty: no row):
+        `tables` [n, max_blocks], the null block past a row's end and
+        in a slot that holds none; with a window pool `window_rings`
+        [n, ring] beside it, logical block j at entry j mod ring."""
+        import numpy as np
+
+        rows = {"tables": (self.max_blocks, "full")}
+        if self.window is not None:
+            rows["window_rings"] = (self.window.ring, "window")
+        out = {}
+        for name, (width, part) in rows.items():
+            table = np.full((len(blocks), width), NULL_BLOCK, np.int32)
+            for slot, ids in enumerate(blocks):
+                if ids:
+                    ids = ids if self.window is None else ids[part]
+                    table[slot, :len(ids)] = ids
+            out[name] = table
+        return out
+
+    def row_table(self, slot: int, blocks):
+        """What `paged_prefill` takes as `table` and `patch_step_slot`
+        as `table_row` for the row in `slot` (one program serves every
+        slot, so it is not read) whose blocks are `blocks`: [1,
+        max_blocks], or with a window pool `generate.KindTables`."""
+        import jax.numpy as jnp
+
+        rows = self.host_rows([blocks])
+        if self.window is None:
+            return jnp.asarray(rows["tables"])
+        from ..models.generate import KindTables
+
+        return KindTables(
+            jnp.asarray(rows["tables"]), jnp.asarray(rows["window_rings"])
+        )
+
+    def step_state(self, blocks: Sequence, positions, alive, eos, budget, step):
+        """What `paged_engine_step` takes as `state` (layout in
+        models/generate.py), from every slot's blocks and the per-slot
+        arrays."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        return {
+            **{n: jnp.asarray(t) for n, t in self.host_rows(blocks).items()},
+            "positions": jnp.asarray(positions, jnp.int32),
+            "alive": jnp.asarray(alive, bool),
+            "eos": jnp.asarray(eos, jnp.int32),
+            "budget": jnp.asarray(budget, jnp.int32),
+            "step": jnp.asarray(np.int32(step)),
+        }
+
+    def publish(self, blocks, n_shared: int, prefix_keys: Sequence) -> None:
+        """Register a row's full prompt blocks past the `n_shared` it
+        took from the cache itself, for later prefix hits; first writer
+        wins on races."""
+        ids = blocks if self.window is None else blocks["full"]
+        for i in range(n_shared, len(prefix_keys)):
+            self.full.register(ids[i], prefix_keys[i])
 
     # -- pool ----------------------------------------------------------
     @property
@@ -324,7 +458,8 @@ class PagedKVCache:
         """The {"k", "v"} block pool the jitted paged kernels consume
         and (on accelerator backends, via donation) update in place;
         for a MoE config it also carries `moe_counts`, the last
-        forward's picks per layer and expert."""
+        forward's picks per layer and expert. ONE pytree whatever the
+        model caches: a second pool's leaves lie in it too."""
         return self._pool
 
     @pool.setter

@@ -23,7 +23,7 @@ owns the allocator and the refcounts; this module owns the compute).
 from __future__ import annotations
 
 from functools import partial
-from typing import Dict
+from typing import Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -35,7 +35,7 @@ from ..ops.norms import (
 )
 from ..ops.selected_attention import selected_attention
 from .llama import embed_tokens, model_norm
-from .llama import EXPERT_LEAVES, LlamaConfig, _mlp, project_qkv
+from .llama import EXPERT_LEAVES, AttnKind, LlamaConfig, _mlp, project_qkv
 
 
 def _sample(logits, key, temperature: float, top_k: int):
@@ -118,6 +118,8 @@ def init_block_pool(
     attention could see and did attend."""
     if cfg.kv_lora_rank:
         return _init_latent_pool(cfg, n_blocks, block_len)
+    if cfg.layer_kinds:
+        return _init_kinds_pool(cfg, n_blocks, block_len)
     shape = (
         cfg.n_layers,
         n_blocks,
@@ -185,6 +187,91 @@ def _init_latent_pool(cfg: LlamaConfig, n_blocks: int, block_len: int):
         )
         pool["moe_routed"] = jnp.zeros((expert_layers,), jnp.int32)
     return pool
+
+
+class KindTables(NamedTuple):
+    """A row's (or every slot's) block table in each kind's pool of a
+    `layer_kinds` model: what `paged_prefill` and `patch_step_slot`
+    take as the row's table. In the step's state the second lies under
+    `window_rings`, beside `tables`."""
+
+    full: jax.Array  # [b, width]
+    window: jax.Array  # [b, ring]: logical block j at entry j mod ring
+
+
+#: The pool's k and v leaves of each cache a `layer_kinds` model keeps
+#: (`AttnKind.cache`): the full-attention layers' under the names every
+#: plain model has, the window layers' beside them.
+KIND_LEAVES = {"full": ("k", "v"), "window": ("window_k", "window_v")}
+
+
+def _init_kinds_pool(cfg: LlamaConfig, n_blocks, block_len: int):
+    """The pool of a `layer_kinds` model: k and v of each kind of
+    layer under `KIND_LEAVES`' names, [the kind's layers, the kind's
+    own `n_blocks[cache]`, its kv heads, block_len, width]: a pool a
+    kind, since a window layer keeps a row's last keys and a full
+    layer all of them (llm/kv_window.py has the bookkeeping). A plain
+    number of blocks gives both kinds that many: a caller that keeps
+    ONE id space and hands every kind a row's one full-width table. Keys and
+    values are as wide as whole lanes (`_lanes`: a head_dim of 192 is
+    kept 256 wide, the rest zero, which is what the chip lays out
+    anyway). Counters as a latent pool's: an entry an expert layer."""
+    pool = {}
+    if not isinstance(n_blocks, dict):
+        n_blocks = dict.fromkeys(KIND_LEAVES, n_blocks)
+    for kind, layers in cfg.attn_kinds().values():
+        for name, width in zip(
+            KIND_LEAVES[kind.cache],
+            (cfg.head_dim, cfg.v_head_dim or cfg.head_dim),
+        ):
+            pool[name] = jnp.zeros(
+                (len(layers), n_blocks[kind.cache], kind.kv_heads,
+                 block_len, _lanes(width)), cfg.dtype,
+            )
+    if cfg.moe_experts:
+        expert_layers = cfg.n_layers - cfg.dense_layers
+        pool["moe_counts"] = jnp.zeros(
+            (expert_layers, cfg.moe_experts), jnp.int32
+        )
+        pool["moe_routed"] = jnp.zeros((expert_layers,), jnp.int32)
+    return pool
+
+
+def window_view_blocks(window: int, block_len: int, q_len: int) -> int:
+    """Table entries of a window layer's VIEW of a row in a forward of
+    `q_len` consecutive queries: the blocks that hold the `window - 1`
+    keys before the first query, the queries' own, and the one more a
+    chunk's write reads (`_paged_write`). What a row's ring of window
+    pages must at least hold (llm/kv_window.py)."""
+    return (window - 1 + q_len - 1) // block_len + 2
+
+
+def window_first_key(q_first, window: int, block_len: int):
+    """Position of the first key of a window layer's view: the start
+    of the block that holds the earliest key the query at `q_first`
+    (a forward's first, [b]) may see. numpy or traced alike: the one
+    rule behind the program's view and the engine's `swa_keys_read`."""
+    return (q_first - (window - 1)).clip(0) // block_len * block_len
+
+
+def _window_view(tables, q_first, window: int, block_len: int, q_len: int):
+    """`tables` [b, R], a row's RING of window pages (logical block j
+    at entry j mod R: a page is overwritten in place once its last key
+    has left every later query's window), seen as a plain table of the
+    blocks a forward's queries can see -> (view [b, entries], the
+    position of the view's first key [b]). Positions less that first
+    key, the view is a row's table like any other: the write, the work
+    list and the attention walk it as they walk a full layer's."""
+    ring = tables.shape[1]
+    entries = window_view_blocks(window, block_len, q_len)
+    if ring < entries:
+        raise ValueError(
+            f"a ring of {ring} window pages a row is narrower than the "
+            f"{entries} blocks a forward of {q_len} queries sees"
+        )
+    first_key = window_first_key(q_first, window, block_len)
+    blocks = first_key[:, None] // block_len + jnp.arange(entries)
+    return jnp.take_along_axis(tables, blocks % ring, axis=1), first_key
 
 
 def paged_tile_keys(block_len: int, table_width: int, q_len: int) -> int:
@@ -264,6 +351,8 @@ def _paged_attention(
     n_trips,  # [] traced trip count (paged_tiles_read)
     scale=None,  # the softmax scale where it is not hd ** -0.5
     v_width=None,  # the leading dims of a page that are its value
+    window: int = 0,  # keys a query sees, itself the last (0: all before)
+    sink=None,  # [heads] a logit a head in the softmax's denominator
 ) -> jax.Array:
     """Attention of `q` over the pages the work list names, read where
     they lie: -> [b, heads, t, hd] float32 ([.., v_width] where that is
@@ -286,8 +375,8 @@ def _paged_attention(
     qg = q.reshape(b, kv_heads, groups * t, hd)
     if scale is None:
         scale = 1.0 / jnp.sqrt(jnp.float32(hd))
-    if v_width is not None:
-        hd = v_width
+    # (values may be narrower than keys: the accumulator is theirs)
+    hd = v_pool.shape[-1] if v_width is None else v_width
     key_offsets = jnp.arange(tile)
     rows = jnp.arange(b)
 
@@ -319,6 +408,8 @@ def _paged_attention(
             seen = (k_pos <= pair["pos"][:, None, :, None]) & (
                 k_pos < length[:, None, None, None]
             )
+            if window:
+                seen &= k_pos > pair["pos"][:, None, :, None] - window
             s = jnp.where(seen, s, -1e30)
         with jax.named_scope("paged/merge"):
             # The split-key softmax merge, by row: a row's new max is
@@ -351,15 +442,24 @@ def _paged_attention(
             return m_new, l, acc
 
     per_row = (b, kv_heads, groups * t)
+    if sink is None:
+        m0 = jnp.full(per_row, -1e30, jnp.float32)
+        l0 = jnp.zeros(per_row, jnp.float32)
+    else:
+        # The sink is one more term of every query's softmax sum that
+        # carries no value: the walk starts from it, (m, l, acc) =
+        # (sink, 1, 0), laid out as the grouped queries are.
+        m0 = jnp.broadcast_to(
+            jnp.repeat(
+                sink.astype(jnp.float32).reshape(kv_heads, groups), t, axis=1
+            ), per_row,
+        )
+        l0 = jnp.ones(per_row, jnp.float32)
     _, l, acc = jax.lax.fori_loop(
         0,
         n_trips,
         one_trip,
-        (
-            jnp.full(per_row, -1e30, jnp.float32),
-            jnp.zeros(per_row, jnp.float32),
-            jnp.zeros(per_row + (hd,), jnp.float32),
-        ),
+        (m0, l0, jnp.zeros(per_row + (hd,), jnp.float32)),
     )
     # A row no pair was read for (a dead row) has l == 0: its output
     # is junk nobody reads, but keep it finite.
@@ -431,21 +531,37 @@ def _paged_layer(
     work,  # attention's live (row, tile) pairs
     n_trips,  # [] attention's trip count
     live=None,  # [b] rows that are real (None: all)
+    kind: AttnKind = None,  # this layer's, of a `layer_kinds` model
+    ffn_idx=None,  # and its index into its FFN's stack
 ):
     """-> (x, k_pool, v_pool, counts): counts is the layer's picks
-    per expert [E] for a MoE config, None for a dense one."""
+    per expert [E] for a MoE config, None for a dense one. A layer of
+    `kind` gets that kind's pool, table, positions and work list; a
+    window layer's are its VIEW of the row (`_window_view`)."""
     b, t, _ = x.shape
     with jax.named_scope("layer/attn_qkv"):
         h = model_norm(cfg, x, layer["attn_norm"])
-        q, k, v = project_qkv(cfg, h, layer)
+        q, k, v = project_qkv(cfg, h, layer, kind)
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
     # Write BEFORE attention so the chunk attends to its own tokens
     # (prefill self-attention).
     with jax.named_scope("paged/scatter_kv"):
+        if kind is not None:  # whole lanes, as the pool keeps them
+            q, k = (_pad_last(a, k_pool.shape[-1]) for a in (q, k))
+            v_width, v = v.shape[-1], _pad_last(v, v_pool.shape[-1])
         k_pool = _paged_write(k_pool, layer_idx, tables, q_pos, k)
         v_pool = _paged_write(v_pool, layer_idx, tables, q_pos, v)
-    attn = _paged_attention(q, k_pool, v_pool, layer_idx, work, n_trips)
+    if kind is None:
+        attn = _paged_attention(q, k_pool, v_pool, layer_idx, work, n_trips)
+    else:
+        with jax.named_scope(f"attn/{kind.cache}"):
+            attn = _paged_attention(
+                q, k_pool, v_pool, layer_idx, work, n_trips,
+                scale=cfg.head_dim ** -0.5, window=kind.window,
+                sink=layer.get("sink"),
+            )[..., :v_width]
+        layer_idx = ffn_idx
     with jax.named_scope("layer/attn_out"):
         attn = attn.astype(cfg.dtype).transpose(0, 2, 1, 3).reshape(
             b, t, -1
@@ -489,6 +605,44 @@ def _paged_plan(
     return tables, valid_len, work, n_trips
 
 
+def _kind_plans(cfg: LlamaConfig, pool, tables, q_pos, valid_len, alive):
+    """What each KIND of layer of a `layer_kinds` model walks in one
+    paged forward, made once for all its layers: -> {cache: (plan, the
+    kind's layers)}, a plan holding the kind's `tables`, `q_pos`,
+    `work`, `n_trips` (`_paged_plan`) and rotary `cos`, `sin` at the
+    kind's own base. `tables` holds a row's table in each kind's pool
+    (`KindTables`), or is one [b, width] table for both. A window layer's plan is
+    made from its VIEW of the row (`_window_view`), so it walks the
+    tiles that hold a key some query of this forward can see and no
+    other: for a step the last block or two of tiles, for a chunk its
+    own and the window before it."""
+    plans = {}
+    for kind, layers in cfg.attn_kinds().values():
+        n_blocks, kv_heads, bl = pool[KIND_LEAVES[kind.cache][0]].shape[1:4]
+        # (a plain table serves both kinds: a ring as wide as the row)
+        table = tables
+        if isinstance(tables, KindTables):
+            table = getattr(tables, kind.cache)
+        pos, valid = q_pos, valid_len
+        if kind.window:
+            table, first_key = _window_view(
+                table, q_pos[:, 0], kind.window, bl, q_pos.shape[1]
+            )
+            pos, valid = q_pos - first_key[:, None], valid_len - first_key
+        table, _, work, n_trips = _paged_plan(
+            table, pos, valid, alive, n_blocks, bl, cfg.n_heads // kv_heads
+        )
+        cos, sin = rotary_embedding(
+            q_pos, cfg.rotary_dim or cfg.head_dim, kind.rope_theta,
+            cfg.rope_scaling,
+        )
+        plans[kind.cache] = (dict(
+            tables=table, q_pos=pos, work=work, n_trips=n_trips,
+            cos=cos, sin=sin,
+        ), layers)
+    return plans
+
+
 def _paged_forward(
     params, cfg: LlamaConfig, tokens, pool, tables, q_pos, valid_len,
     alive=True,
@@ -509,6 +663,50 @@ def _paged_forward(
         return _latent_forward(
             params, cfg, tokens, pool, tables, q_pos, valid_len, alive
         )
+    live = None if alive is True else alive
+    if cfg.layer_kinds:
+        # Layers of more than one KIND: every layer is `_paged_layer`
+        # with its kind's plan, its place in its kind's stack and its
+        # place in its FFN's (`llama.kinds_layer_shapes`); unrolled.
+        # Counters as a latent model's.
+        plans = _kind_plans(cfg, pool, tables, q_pos, valid_len, alive)
+        with jax.named_scope("embed"):
+            x = embed_tokens(cfg, params, tokens)
+        cache = cache_leaves(pool)
+        counters: Dict[str, list] = {"moe_counts": [], "moe_routed": []}
+        rows_live = tokens.shape[0] if live is None else live.sum()
+        routed = jnp.asarray(
+            rows_live * tokens.shape[1] * cfg.moe_top_k, jnp.int32
+        )
+        for layer_idx, kind in enumerate(cfg.layer_kinds):
+            plan, layers = plans[kind.cache]
+            at = layers.index(layer_idx)
+            dense = layer_idx < cfg.dense_layers
+            ffn = params["dense_layers" if dense else "layers"]
+            ffn_idx = layer_idx - (0 if dense else cfg.dense_layers)
+            weights = {
+                **{n: w[at] for n, w in params[f"attn_{kind.cache}"].items()},
+                # (a layer's experts stay whole stacks: ops/moe.py)
+                **{n: w if n in EXPERT_LEAVES else w[ffn_idx]
+                   for n, w in ffn.items()},
+            }
+            k_name, v_name = KIND_LEAVES[kind.cache]
+            x, cache[k_name], cache[v_name], picks = _paged_layer(
+                cfg, x, weights, at, plan["cos"], plan["sin"],
+                cache[k_name], cache[v_name], plan["tables"], plan["q_pos"],
+                plan["work"], plan["n_trips"], live, kind=kind,
+                ffn_idx=ffn_idx,
+            )
+            if picks is not None:
+                counters["moe_counts"].append(picks)
+                counters["moe_routed"].append(routed)
+        with jax.named_scope("final_norm"):
+            x = model_norm(cfg, x, params["final_norm"])
+        with jax.named_scope("lm_head"):
+            logits = (x @ params["lm_head"]).astype(jnp.float32)
+        return logits, {
+            **cache, **{n: jnp.stack(v) for n, v in counters.items() if v},
+        }
     # (the queries of a kv head's group lie side by side, as
     # `_paged_attention` lays them)
     tables, valid_len, work, n_trips = _paged_plan(
@@ -521,7 +719,6 @@ def _paged_forward(
         q_pos, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
     )
 
-    live = None if alive is True else alive
     # The loop slices each layer's weights out of their stacks, all
     # but a MoE layer's experts: a slice of those would be copied
     # before the grouped-matmul kernel (805 MB a layer at OLMoE's
@@ -748,9 +945,7 @@ def _latent_layer(
     step = t == 1  # absorbed queries against cache entries, else heads
 
     def rotated(v):  # rotary on the rope dims, which lead
-        return jnp.concatenate(
-            [apply_rotary(v[..., :rope], cos, sin), v[..., rope:]], axis=-1
-        )
+        return apply_rotary(v, cos, sin)
 
     h = model_norm(cfg, x, layer["attn_norm"])
     wkv_b = layer["wkv_b"].reshape(kvr, heads, nope + vd)
@@ -1003,7 +1198,9 @@ def _paged_decode_step_impl(
     # into the new request's — possibly shared prefix-cache — pages.
     # Masking to the null block here makes the guarantee kernel-level,
     # independent of host bookkeeping order.
-    tables = jnp.where(alive[:, None], tables, 0)
+    tables = jax.tree.map(
+        lambda table: jnp.where(alive[:, None], table, 0), tables
+    )
     logits, pool = _paged_forward(
         params, cfg, token[:, None], pool, tables,
         positions[:, None], positions + 1, alive,
@@ -1081,8 +1278,11 @@ def _paged_engine_step_impl(
     top_k: int,
 ):
     alive = state["alive"]
+    tables = state["tables"]
+    if "window_rings" in state:  # a row's table in each kind's pool
+        tables = KindTables(tables, state["window_rings"])
     token, pool, last_logits = _paged_decode_step_impl(
-        params, cfg, pool, state["tables"], last_logits,
+        params, cfg, pool, tables, last_logits,
         state["positions"], alive,
         jax.random.fold_in(base_key, state["step"]),
         temperature, top_k,
@@ -1146,9 +1346,12 @@ def paged_engine_step(
 
 
 def _patch_step_slot_impl(state, slot, table_row):
+    rows = {"tables": table_row}
+    if isinstance(table_row, KindTables):
+        rows = {"tables": table_row.full, "window_rings": table_row.window}
     return {
         **state,
-        "tables": state["tables"].at[slot].set(table_row[0]),
+        **{n: state[n].at[slot].set(row[0]) for n, row in rows.items()},
         "alive": state["alive"].at[slot].set(False),
     }
 
@@ -1165,6 +1368,37 @@ def patch_step_slot(state, slot, table_row):
     cannot know of (a cancellation: the null row). `slot` is traced:
     one program for every slot."""
     return _patch_step_slot_jit(state, slot, table_row)
+
+
+def _copy_window_pages_impl(pool, src, dst):
+    return {
+        **pool,
+        **{
+            name: pool[name].at[:, dst].set(pool[name][:, src])
+            for name in KIND_LEAVES["window"]
+        },
+    }
+
+
+_copy_window_pages_jit = None
+
+
+def copy_window_pages(pool, src, dst):
+    """-> the pool with the window pages `src` [n] copied over the
+    pages `dst` [n], every window layer's k and v: how the keys at a
+    chunk boundary outlive the ring that overwrites them (to pages of
+    the prefix cache), and how a prefix hit finds them again (into its
+    row's ring). llm/kv_window.py says when. `pool` is donated on
+    accelerator backends."""
+    global _copy_window_pages_jit
+    if _copy_window_pages_jit is None:
+        _copy_window_pages_jit = compile_watch.instrument(
+            "generate.copy_window_pages",
+            jax.jit(
+                _copy_window_pages_impl, donate_argnums=accel_donate(0)
+            ),
+        )
+    return _copy_window_pages_jit(pool, src, dst)
 
 
 def _finish_chunk_impl(

@@ -19,7 +19,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from functools import partial
-from typing import Any, Dict, Optional
+from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +34,25 @@ from ..ops.moe import moe_ffn_dropless, moe_ffn_ep, route_grouped_sigmoid
 from ..ops.norms import apply_rotary, rms_norm, rotary_embedding, swiglu
 from ..ops.ring_attention import ring_attention
 from ..parallel.sharding import Annotated, annotate
+
+
+class AttnKind(NamedTuple):
+    """What one KIND of attention layer has of its own in a model that
+    mixes kinds (`LlamaConfig.layer_kinds`): how many keys a query
+    sees, itself the last of them (0: every key up to itself), its kv
+    heads, its rotary base, and whether each head has a learned SINK
+    logit, which joins the softmax's denominator and carries no
+    value."""
+
+    window: int = 0
+    kv_heads: int = 0
+    rope_theta: float = 10000.0
+    sink: bool = False
+
+    @property
+    def cache(self) -> str:
+        """Which of a row's two caches this kind's layers keep."""
+        return "window" if self.window else "full"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -144,6 +163,22 @@ class LlamaConfig:
     #: layers: a stack of their own, `dense_layers/*`.
     dense_layers: int = 0
     dense_intermediate: int = 0
+    # ---- layers of more than one kind (the serve forwards only) ----
+    #: The model's attention layers in order, an `AttnKind` each (from
+    #: a JSON file `[window, kv_heads, rope_theta, sink]`), where they
+    #: are not all alike: window and full attention mixed (MiMo-V2's
+    #: `hybrid_layer_pattern`, with each kind's own `num_key_value_heads`,
+    #: `rope_theta` and sink). At most one kind with a window and one
+    #: without: each has its page pool, a row's table and the work list
+    #: in a paged forward (models/generate.py, llm/kv_window.py), and
+    #: what a kind changes of the attention leaves a stack of its own
+    #: (`kinds_layer_shapes`).
+    #: `n_kv_heads` and `rope_theta` are then not read.
+    layer_kinds: tuple = ()
+    #: The leading dims of a head that rotary turns (0: all of it).
+    rotary_dim: int = 0
+    #: A factor on the values (MiMo-V2's `attention_value_scale`).
+    value_scale: float = 1.0
 
     def __post_init__(self):
         if self.qk_norm is True:
@@ -162,11 +197,40 @@ class LlamaConfig:
             raise ValueError("index_topk selects keys of a latent cache")
         if self.dense_layers and not self.moe_experts:
             raise ValueError("dense_layers lead a model of expert layers")
+        if self.layer_kinds:
+            kinds = tuple(AttnKind(*kind) for kind in self.layer_kinds)
+            object.__setattr__(self, "layer_kinds", kinds)
+            if len(kinds) != self.n_layers:
+                raise ValueError(
+                    f"layer_kinds names {len(kinds)} layers of {self.n_layers}"
+                )
+            if len({k.cache for k in set(kinds)}) != len(set(kinds)):
+                raise ValueError(
+                    "layer_kinds: at most one kind with a window and one "
+                    "without (each has one page pool)"
+                )
+            if self.kv_lora_rank:
+                raise ValueError("layer_kinds are kinds of plain attention")
+
+    def attn_kinds(self) -> Dict[str, tuple]:
+        """{cache: (its `AttnKind`, the layers of that kind)} of a
+        model with `layer_kinds`, in the order the kinds first occur."""
+        out: Dict[str, tuple] = {}
+        for layer, kind in enumerate(self.layer_kinds):
+            out.setdefault(kind.cache, (kind, []))[1].append(layer)
+        return out
 
     def require_plain_attention(self, what: str) -> None:
         """The one refusal of a latent-attention configuration by the
         code that has no equations for it (training, conversion from
         a checkpoint): it would otherwise run other mathematics."""
+        if self.layer_kinds:
+            raise NotImplementedError(
+                f"{what} has no layers of more than one kind "
+                "(layer_kinds: window and full attention mixed): such a "
+                "configuration runs on the serve path only "
+                "(models/generate.py)"
+            )
         if self.kv_lora_rank:
             raise NotImplementedError(
                 f"{what} has no latent attention (kv_lora_rank="
@@ -179,7 +243,7 @@ class LlamaConfig:
         return self.custom_head_dim or self.dim // self.n_heads
 
     def num_params(self) -> int:
-        if self.kv_lora_rank:
+        if self.kv_lora_rank or self.layer_kinds:
             shapes = jax.eval_shape(
                 lambda: init_params(jax.random.PRNGKey(0), self)
             )
@@ -303,10 +367,10 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
             * (1.0 / math.sqrt(fan_in))
         ).astype(dt)
 
-    if cfg.kv_lora_rank:
+    if cfg.kv_lora_rank or cfg.layer_kinds:
         return {
             "embed": norm_init(k_embed, cfg.dim, (cfg.vocab_size, cfg.dim)),
-            **_init_latent_layers(k_layers, cfg, norm_init),
+            **_init_stacks(k_layers, cfg, norm_init),
             "final_norm": jnp.ones((cfg.dim,), dt),
             "lm_head": norm_init(k_out, cfg.dim, (cfg.dim, cfg.vocab_size)),
         }
@@ -416,25 +480,73 @@ def latent_layer_shapes(cfg: LlamaConfig, L: int, experts: bool) -> Dict:
     return out
 
 
-def _init_latent_layers(key, cfg: LlamaConfig, norm_init) -> Dict:
-    """The two stacks of a latent-attention model: `dense_layers`
-    (where it has leading dense layers) and `layers`."""
-    out = {}
-    for name, L, experts in (
-        ("dense_layers", cfg.dense_layers, False),
-        ("layers", cfg.n_layers - cfg.dense_layers, True),
+def kinds_layer_shapes(cfg: LlamaConfig) -> Dict[str, Dict]:
+    """{stack: {leaf: (shape, fan in; 0 for a norm weight, a bias or a
+    sink)}} of a model with `layer_kinds`. `dense_layers/*` (the
+    leading dense layers) and `layers/*` (the others), stacked as a
+    latent model's: the attention leaves whose shape no kind changes
+    (`attn_norm`, `wq` -> heads x head_dim, `wo`) beside the layer's
+    FFN (`mlp_norm`; `w1` `w3` `w2` of `dense_intermediate`, or
+    `router` [d, outputs], `router_bias` and the held experts' `w_gate`
+    `w_up` `w_down`). What a kind changes is stacked by kind,
+    `attn_full/*` and `attn_window/*` over that kind's layers in order:
+    `wk` -> the kind's kv heads x head_dim, `wv` -> kv heads x
+    `v_head_dim`, and where the kind has one `sink` [heads]."""
+    d, H, hd = cfg.dim, cfg.n_heads, cfg.head_dim
+    vd = cfg.v_head_dim or hd
+    E, f = cfg.moe_experts, cfg.intermediate
+    outputs = cfg.moe_router_experts or E
+    out: Dict[str, Dict] = {}
+    for name, L, ffn in (
+        ("dense_layers", cfg.dense_layers, lambda L, f: {
+            "w1": ((L, d, f), d), "w3": ((L, d, f), d), "w2": ((L, f, d), f),
+        }),
+        ("layers", cfg.n_layers - cfg.dense_layers, lambda L, f: {
+            "router": ((L, d, outputs), d), "router_bias": ((L, outputs), 0),
+            "w_gate": ((L, E, d, f), d), "w_up": ((L, E, d, f), d),
+            "w_down": ((L, E, f, d), f),
+        }),
     ):
-        if not L:
-            continue
+        if L:
+            out[name] = {
+                "attn_norm": ((L, d), 0), "wq": ((L, d, H * hd), d),
+                "wo": ((L, H * vd, d), H * vd), "mlp_norm": ((L, d), 0),
+                **ffn(L, cfg.dense_intermediate if name == "dense_layers" else f),
+            }
+    for kind, layers in cfg.attn_kinds().values():
+        L, kv = len(layers), kind.kv_heads
+        out[f"attn_{kind.cache}"] = {
+            "wk": ((L, d, kv * hd), d), "wv": ((L, d, kv * vd), d),
+            **({"sink": ((L, H), 0)} if kind.sink else {}),
+        }
+    return out
+
+
+def _init_stacks(key, cfg: LlamaConfig, norm_init) -> Dict:
+    """The stacks of a model whose layers are not all alike. Latent
+    attention: `dense_layers` (where it has leading dense layers) and
+    `layers`; `layer_kinds`: `kinds_layer_shapes`."""
+    if cfg.layer_kinds:
+        plans = kinds_layer_shapes(cfg)
+    else:
+        plans = {
+            name: latent_layer_shapes(cfg, L, experts)
+            for name, L, experts in (
+                ("dense_layers", cfg.dense_layers, False),
+                ("layers", cfg.n_layers - cfg.dense_layers, True),
+            ) if L
+        }
+    out: Dict[str, Any] = {}
+    for number, (name, plan) in enumerate(plans.items()):
+        # (the key of a latent model's leaf is what it has been)
+        base = 64 * (name == "layers") + 64 * number * name.startswith("attn_")
         stack = {}
-        for i, (leaf, (shape, fan_in)) in enumerate(
-            latent_layer_shapes(cfg, L, experts).items()
-        ):
+        for i, (leaf, (shape, fan_in)) in enumerate(plan.items()):
             if fan_in:
                 stack[leaf] = norm_init(
-                    jax.random.fold_in(key, i + 64 * experts), fan_in, shape
+                    jax.random.fold_in(key, i + base), fan_in, shape
                 )
-            elif leaf.endswith("bias"):
+            elif leaf.endswith("bias") or leaf == "sink":
                 stack[leaf] = jnp.zeros(shape, cfg.dtype)
             else:
                 stack[leaf] = jnp.ones(shape, cfg.dtype)
@@ -487,14 +599,26 @@ def param_annotations(cfg: LlamaConfig) -> Dict[str, Any]:
     }
 
 
-def project_qkv(cfg: LlamaConfig, h, layer):
+def project_qkv(cfg: LlamaConfig, h, layer, kind: Optional[AttnKind] = None):
     """Shared QKV projection (+ Qwen2-family biases) and head split —
     the training layer and the KV-cache serving layer must use the
     SAME projection or their logits silently diverge.
-    h: [b, t, dim] -> each of q/k/v: [b, heads, t, head_dim]."""
+    h: [b, t, dim] -> each of q/k/v: [b, heads, t, head_dim]. For a
+    layer of `kind` (`layer_kinds`): that kind's kv heads, and values
+    `v_head_dim` wide, times `value_scale`."""
     b, t, _ = h.shape
     hd = cfg.head_dim
     q, k, v = h @ layer["wq"], h @ layer["wk"], h @ layer["wv"]
+    if kind is not None:
+        def heads(x, n):
+            return x.reshape(b, t, n, -1).transpose(0, 2, 1, 3)
+
+        if cfg.value_scale != 1.0:
+            v = v * jnp.asarray(cfg.value_scale, v.dtype)
+        return (
+            heads(q, cfg.n_heads), heads(k, kind.kv_heads),
+            heads(v, kind.kv_heads),
+        )
     if cfg.attn_bias:
         q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
     if cfg.qk_norm == "proj":

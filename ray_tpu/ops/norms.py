@@ -129,7 +129,16 @@ def apply_rotary(
     x: jax.Array, cos: jax.Array, sin: jax.Array
 ) -> jax.Array:
     """Apply RoPE to [batch, heads, seq, head_dim] given per-position
-    (cos, sin) of shape [batch, seq, head_dim//2] (or broadcastable)."""
+    (cos, sin) of shape [batch, seq, head_dim//2] (or broadcastable).
+    Tables narrower than half the head turn its LEADING dims alone,
+    twice their width, and the rest pass as they are (a partial rotary
+    factor; the rope dims of a latent-attention head)."""
+    turned = 2 * cos.shape[-1]
+    if turned < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rotary(x[..., :turned], cos, sin), x[..., turned:]],
+            axis=-1,
+        )
     dtype = x.dtype
     xf = x.astype(jnp.float32)
     half = x.shape[-1] // 2

@@ -334,3 +334,116 @@ def test_engine_block_accounting_in_stats(tiny_model):
         assert stats["kv_blocks_cached"] == 0
     finally:
         eng.close()
+
+
+# ---------------------------------------------------------------------
+# the window layers' pages (llm/kv_window.py): a second pool, a ring a
+# row, the tails the prefix cache keeps
+# ---------------------------------------------------------------------
+
+def test_window_pool_geometry_and_the_stated_bound():
+    from ray_tpu.llm.kv_window import WindowPages
+
+    window, bl, chunk = 128, 16, 1024
+    pages = WindowPages(window, bl, chunk, slots=32, full_blocks=24576)
+    # never more than `window - 1 + chunk` tokens need, plus one
+    assert pages.ring == -(-(window - 1 + chunk) // bl) + 1 == 73
+    assert pages.tail_blocks == 8  # the 127 keys before a boundary
+    assert pages.ring_for(5) == 5 and pages.ring_for(10 ** 6) == 73
+    # every slot's ring, a tail for every whole-chunk boundary the full
+    # pool can hold, and the null block
+    assert pages.alloc.n_blocks == 32 * 73 + (24576 * bl // chunk) * 8 + 1
+    with pytest.raises(ValueError, match="shorter than the window"):
+        WindowPages(window, bl, 64, slots=1, full_blocks=8)
+
+
+def test_a_rows_ring_holds_its_pages_over_ten_chunks_and_300_steps():
+    """The bookkeeping of one row's whole life: 10 chunks of prompt
+    (every boundary's tail kept, or dropped where the pool is tight),
+    then 300 steps. The row's pinned pages never pass the ring, which
+    is the stated bound; the kept tails are refcount 0."""
+    from ray_tpu.llm.kv_window import WindowPages
+
+    window, bl, chunk = 8, 4, 16
+    pages = WindowPages(window, bl, chunk, slots=2, full_blocks=64)
+    bound = -(-(window - 1 + chunk) // bl) + 1
+    keys = [("doc", i) for i in range(10 * chunk // bl)]
+    total_blocks = -(-(10 * chunk + 300) // bl)
+    assert pages.gate(keys, 0, total_blocks)
+    ring, copy = pages.admit(keys, 0, total_blocks)
+    assert copy is None and len(ring) == pages.ring <= bound
+    for boundary in range(chunk, 10 * chunk + 1, chunk):
+        kept = pages.keep_tail(keys, ring, boundary)
+        assert kept is not None
+        src, dst = kept
+        assert set(src) <= set(ring) and not set(dst) & set(ring)
+        assert len(src) == len(dst) == pages.tail_blocks == 2
+        assert pages.alloc.used() == len(ring)  # the tails are not pinned
+    assert pages.alloc.cached() == 10 * pages.tail_blocks
+    # the same boundary again: already held, nothing to copy
+    assert pages.keep_tail(keys, ring, chunk) is None
+    # a hit finds the longest boundary whose tail is whole
+    assert pages.usable_skip(keys, 9 * chunk) == 9 * chunk
+    other = [("other", i) for i in range(len(keys))]
+    assert pages.usable_skip(other, 9 * chunk) == 0
+    pages.alloc.release(ring)
+    assert pages.alloc.used() == 0
+
+
+def test_a_hit_copies_its_tail_into_the_ring_and_a_tight_pool_keeps_no_tail():
+    from ray_tpu.llm.kv_slots import BlockAllocator
+    from ray_tpu.llm.kv_window import WindowPages
+
+    window, bl, chunk = 8, 4, 16
+    pages = WindowPages(window, bl, chunk, slots=1, full_blocks=64)
+    keys = [("doc", i) for i in range(12)]
+    first, _ = pages.admit(keys, 0, 40)
+    src, kept = pages.keep_tail(keys, first, 2 * chunk)
+    pages.alloc.release(first)
+    # the hit: boundary 32 is logical blocks 6 and 7 of a ring of 7
+    ring, (tail, into) = pages.admit(keys, 2 * chunk, 40)
+    assert tail == kept and into == [ring[6], ring[7 % pages.ring]]
+    assert pages.alloc.used() == len(ring)  # the tail is unpinned again
+    # every page pinned by rings: keeping a tail is skipped, not an error
+    pages.alloc = BlockAllocator(len(ring) + 1)
+    ring = pages.alloc.reserve(len(ring))
+    assert pages.keep_tail([("x", i) for i in range(12)], ring, chunk) is None
+    assert not pages.gate(keys, 0, 40)  # and no second ring fits
+
+
+def test_a_one_kind_model_gets_todays_tables_and_state_bit_for_bit(tiny_model):
+    """`for_engine`, `row_table` and `step_state` of a model whose
+    layers are all alike: the values the engine's own lines gave
+    before they moved behind the cache (what the benchmark's probe
+    pins: `tests/benchmark/test_benchmark_probe.py`)."""
+    cfg, _ = tiny_model
+    cache = PagedKVCache.for_engine(
+        cfg, slots=3, max_len=48, prefill_chunk=8, kv_block_len=0,
+        kv_blocks=0,
+    )
+    assert cache.block_len == default_block_len(8) == 8
+    assert cache.full.n_blocks == 3 * (48 // 8) + 1
+    assert cache.alloc is cache.full and cache.window is None
+    assert sorted(cache.pool) == ["k", "v"]
+    blocks = [cache.alloc.reserve(cache.blocks_for(20)), None, [7, 9]]
+    assert blocks[0] == [1, 2, 3]
+    table = cache.row_table(0, blocks[0])
+    assert table.dtype == jnp.int32
+    assert np.array_equal(np.asarray(table), [[1, 2, 3, 0, 0, 0]])
+    state = cache.step_state(
+        blocks, np.array([20, 0, 3]), np.array([True, False, True]),
+        np.full(3, -1), np.array([5, 0, 2]), 4,
+    )
+    assert sorted(state) == [
+        "alive", "budget", "eos", "positions", "step", "tables",
+    ]
+    assert np.array_equal(
+        np.asarray(state["tables"]),
+        [[1, 2, 3, 0, 0, 0], [0] * 6, [7, 9, 0, 0, 0, 0]],
+    )
+    for name, dtype in (
+        ("tables", jnp.int32), ("positions", jnp.int32), ("alive", bool),
+        ("eos", jnp.int32), ("budget", jnp.int32), ("step", jnp.int32),
+    ):
+        assert state[name].dtype == dtype, name
+    assert int(state["step"]) == 4 and state["step"].shape == ()

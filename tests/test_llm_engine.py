@@ -875,3 +875,91 @@ def test_replica_death_fails_inflight_stream(rt_session):
         assert outcome not in (None, "clean_stop"), outcome
     finally:
         serve.shutdown()
+
+
+# ---------------------------------------------------------------------
+# a model with window layers: two page pools under one admission gate
+# ---------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def window_model():
+    from ray_tpu.models.llama import LlamaConfig, init_params
+
+    cfg = LlamaConfig(
+        vocab_size=128, dim=64, n_layers=3, n_heads=4, n_kv_heads=2,
+        custom_head_dim=16, intermediate=32, max_seq_len=512,
+        dtype=jnp.float32, moe_experts=4, moe_top_k=2,
+        moe_router="sigmoid_groups",
+        layer_kinds=[[0, 2, 1e6, False], [8, 4, 1e4, True], [8, 4, 1e4, True]],
+    )
+    return cfg, init_params(jax.random.PRNGKey(0), cfg)
+
+
+WINDOW_KW = dict(slots=2, max_len=512, prefill_chunk=16, kv_block_len=4)
+
+
+def test_a_rows_window_pages_stay_inside_the_stated_bound(window_model):
+    """A 10-chunk prompt and 300 steps: the window pool never pins
+    more than the row's ring, `window - 1 + chunk` tokens' pages plus
+    one, however long the row grows; the full pool holds its length."""
+    from ray_tpu.llm import EngineConfig, InferenceEngine
+
+    cfg, params = window_model
+    eng = InferenceEngine(
+        params, cfg, EngineConfig(max_new_tokens=300, **WINDOW_KW),
+    )
+    bound = -(-(8 - 1 + 16) // 4) + 1
+    try:
+        stream = eng.submit(list(range(1, 128)) + list(range(1, 34)))
+        seen, most = 0, 0
+        for _ in stream:
+            seen += 1
+            if seen % 25 == 0:
+                most = max(most, eng.stats()["window_blocks_used"])
+        stats = eng.stats()
+    finally:
+        eng.close()
+    assert seen == 300
+    assert 0 < most <= bound and stats["window_pool_used"] <= bound
+    assert stats["full_pool_used"] == -(-(160 + 300) // 4)
+    assert stats["window_pages_recycled"] == -(-(160 + 299) // 4) - bound
+    assert stats["window_blocks_used"] == stats["kv_blocks_used"] == 0
+
+
+def test_the_gate_covers_the_window_pool(window_model):
+    """Slots and full pages are free but the window pool holds one
+    ring: the second request WAITS behind the first and is served
+    after it releases, with the tokens it gets when alone."""
+    from ray_tpu.llm import EngineConfig, InferenceEngine
+    from ray_tpu.llm.kv_slots import BlockAllocator
+
+    cfg, params = window_model
+    prompts = [list(range(1, 40)), list(range(50, 95))]
+    alone = InferenceEngine(
+        params, cfg,
+        EngineConfig(max_new_tokens=24, prefix_cache=False, **WINDOW_KW),
+    )
+    try:
+        want = [list(alone.submit(p)) for p in prompts]
+    finally:
+        alone.close()
+    eng = InferenceEngine(
+        params, cfg,
+        EngineConfig(max_new_tokens=24, prefix_cache=False, **WINDOW_KW),
+    )
+    try:
+        ring = eng._kv.window.ring
+        eng._kv.window.alloc = BlockAllocator(ring + 3)  # one ring and a bit
+        first, second = (eng.submit(p) for p in prompts)
+        waited = False
+        got_first = []
+        for token in first:
+            got_first.append(token)
+            stats = eng.stats()
+            waited |= stats["waiting"] == 1 and stats["slots_used"] == 1
+        assert waited  # a slot was free all along; the ring was not
+        assert got_first == want[0] and list(second) == want[1]
+        stats = eng.stats()
+        assert stats["dead"] is False and stats["window_blocks_used"] == 0
+    finally:
+        eng.close()

@@ -249,3 +249,65 @@ def test_a_list_from_a_json_file_is_a_hashable_scaling():
         LlamaConfig(index_topk=4)
     with pytest.raises(ValueError, match="dense_layers"):
         LlamaConfig(dense_layers=1)
+
+
+def test_sixteen_shares_of_a_one_group_router_are_the_uncut_layer():
+    """MiMo-V2's layer (`n_group` 1, no shared expert,
+    `routed_scaling_factor` null -> 1.0): the 16 shares of EP16, each
+    the router whole and its own two of the 32 experts, add up to the
+    uncut layer, and every pick meets exactly one share; the uncut
+    layer is the float32 loop with the 4 largest of score + bias out
+    of ALL the outputs."""
+    def cfg(held, first=0):
+        return LlamaConfig(
+            vocab_size=8, dim=D, n_layers=2, n_heads=2, n_kv_heads=2,
+            intermediate=F, dtype=jnp.float32, moe_experts=held,
+            moe_top_k=K, moe_router="sigmoid_groups", moe_router_experts=E,
+            moe_first_expert=first, moe_groups=1, moe_top_groups=1,
+            moe_route_scale=1.0,
+        )
+
+    full, _ = _layer(9)
+    full = {k: v for k, v in full.items() if not k.startswith("shared")}
+    x = jnp.asarray(
+        np.random.default_rng(10).normal(size=(1, 48, D)).astype(np.float32)
+    )
+    whole, whole_counts = _ffn_only(cfg(E), x, full)
+    shares, per, picks = 16, E // 16, 0
+    total = np.zeros_like(whole)
+    for rank in range(shares):
+        share = dict(full)
+        for name in ("w_gate", "w_up", "w_down"):
+            share[name] = full[name][rank * per:(rank + 1) * per]
+        part, counts = _ffn_only(cfg(per, rank * per), x, share)
+        assert np.array_equal(
+            np.asarray(counts),
+            np.asarray(whole_counts)[rank * per:(rank + 1) * per],
+        )
+        picks += int(counts.sum())
+        total += part
+    assert picks == 48 * K  # every pick met exactly one share
+    np.testing.assert_allclose(total, whole, atol=2e-5)
+    # and the uncut layer is the equations': no group is left out
+    h = np.asarray(x[0])
+    h = h / np.sqrt((h * h).mean(axis=1, keepdims=True) + 1e-6) * np.asarray(
+        full["mlp_norm"]
+    )
+    scores = 1.0 / (1.0 + np.exp(-(h @ np.asarray(full["router"]))))
+    chosen = np.argsort(-(scores + np.asarray(full["router_bias"])), axis=1)[:, :K]
+    assert np.array_equal(
+        np.bincount(chosen.ravel(), minlength=E), np.asarray(whole_counts)
+    )
+    gates = np.take_along_axis(scores, chosen, axis=1)
+    gates = gates / gates.sum(axis=1, keepdims=True)
+
+    def expert(v, e):
+        gate, up, down = (np.asarray(full[n][e]) for n in ("w_gate", "w_up", "w_down"))
+        g = v @ gate
+        return ((g / (1.0 + np.exp(-g))) * (v @ up)) @ down
+
+    want = np.stack([
+        sum(gates[t, j] * expert(h[t], chosen[t, j]) for j in range(K))
+        for t in range(len(h))
+    ])
+    np.testing.assert_allclose(whole[0], want, atol=2e-5)
