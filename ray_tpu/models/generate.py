@@ -35,7 +35,7 @@ from ..ops.norms import (
 )
 from ..ops.selected_attention import selected_attention
 from .llama import embed_tokens, model_norm
-from .llama import EXPERT_LEAVES, AttnKind, LlamaConfig, _mlp, project_qkv
+from .llama import EXPERT_LEAVES, AttnKind, LlamaConfig, _mlp
 
 
 def _sample(logits, key, temperature: float, top_k: int):
@@ -517,6 +517,68 @@ def _paged_write(pool, layer_idx, tables, q_pos, new):
     )
 
 
+def _qkv_flat(cfg: LlamaConfig, h, layer, kind: AttnKind = None):
+    """The first half of `llama.project_qkv`, the train layer's
+    one-piece form of the same arithmetic: the three products (+
+    Qwen2-family biases, + OLMoE's norm over the whole projection; a
+    layer of `kind`: values times `value_scale`), heads not yet split.
+    h: [b, t, dim] -> each of q/k/v: [b, t, heads * head_dim]. The
+    training layer and the serve layer must use the SAME projection
+    or their logits silently diverge; the two share arithmetic and
+    not code while an edit of models/llama.py would cost the train
+    cells their byte-identical programs, and
+    tests/test_serve_projection_pin.py holds `_split_heads(_qkv_flat)`
+    equal to `project_qkv` bit for bit in every family."""
+    q, k, v = h @ layer["wq"], h @ layer["wk"], h @ layer["wv"]
+    if kind is not None:
+        if cfg.value_scale != 1.0:
+            v = v * jnp.asarray(cfg.value_scale, v.dtype)
+        return q, k, v
+    if cfg.attn_bias:
+        q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
+    if cfg.qk_norm == "proj":
+        q = rms_norm(q, layer["q_norm"], eps=cfg.norm_eps)
+        k = rms_norm(k, layer["k_norm"], eps=cfg.norm_eps)
+    return q, k, v
+
+
+def _row_major(h, flat):
+    """`flat`, the projections of `h` [b, t, dim] about to be split
+    into heads, held as the matmul leaves them. Left free, the
+    compiler folds the head split's transposition into the WEIGHT: it
+    slices `wq` / `wk` (a chunk's `wv` too) out of their stacks and
+    copies them heads-major before the product, a layer a forward (8
+    MB of `wq` at qwen2.5-3b's widths, 100 MB at MiMo's), where the
+    activation it would otherwise re-lay has 16 to 512 rows. Held, the
+    product reads its weight in the stack, as `wo`'s and the FFN's do,
+    and the transposition falls on the activation. Where the
+    activation has as many rows as the weight (OLMoE's chunk of 2,048)
+    it is no longer the smaller side, and the compiler keeps its
+    choice."""
+    b, t, dim = h.shape
+    if b * t >= dim:
+        return flat
+    return jax.lax.optimization_barrier(flat)
+
+
+def _split_heads(cfg: LlamaConfig, q, k, v, layer, kind: AttnKind = None):
+    """The second half of `llama.project_qkv`: each of q/k/v [b, t,
+    heads * head_dim] -> [b, heads, t, head_dim] (+ Qwen3's norm a
+    head, BEFORE RoPE). A layer of `kind`: that kind's kv heads, and
+    values `v_head_dim` wide."""
+    b, t, _ = q.shape
+    kv_heads = cfg.n_kv_heads if kind is None else kind.kv_heads
+
+    def heads(x, n):
+        return x.reshape(b, t, n, -1).transpose(0, 2, 1, 3)
+
+    q, k, v = heads(q, cfg.n_heads), heads(k, kv_heads), heads(v, kv_heads)
+    if kind is None and cfg.qk_norm == "head":
+        q = rms_norm(q, layer["q_norm"], eps=cfg.norm_eps)
+        k = rms_norm(k, layer["k_norm"], eps=cfg.norm_eps)
+    return q, k, v
+
+
 def _paged_layer(
     cfg: LlamaConfig,
     x: jax.Array,  # [b, t, dim]
@@ -541,7 +603,8 @@ def _paged_layer(
     b, t, _ = x.shape
     with jax.named_scope("layer/attn_qkv"):
         h = model_norm(cfg, x, layer["attn_norm"])
-        q, k, v = project_qkv(cfg, h, layer, kind)
+        flat = _row_major(h, _qkv_flat(cfg, h, layer, kind))
+        q, k, v = _split_heads(cfg, *flat, layer, kind)
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
     # Write BEFORE attention so the chunk attends to its own tokens
@@ -724,7 +787,11 @@ def _paged_forward(
     # before the grouped-matmul kernel (805 MB a layer at OLMoE's
     # widths), so they stay whole and the expert layer finds its own
     # in them (ops/moe.py). A dense model has none: its loop is as it
-    # was.
+    # was. The other slices are read inside their matmuls, where the
+    # stack holds them, as long as the product comes out row-major:
+    # the projections that feed a head split are held so
+    # (`_row_major`), or the compiler would fold the split's
+    # transposition into `wq` / `wk` and copy them out a layer.
     layers = params["layers"]
     experts = {n: layers[n] for n in EXPERT_LEAVES if n in layers}
     sliced = {n: w for n, w in layers.items() if n not in experts}
