@@ -53,6 +53,7 @@ import os
 import sys
 import threading
 import time
+import weakref
 from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -104,6 +105,8 @@ _lock = threading.Lock()  # rt: noqa[RT004] — held for dict ops only, never ac
 #: — the same structure the head daemon folds wire records into
 #: (`fold_record`), so `detect_storms` serves both sides.
 _programs: Dict[str, dict] = {}
+#: Every live `WatchedFunction`: `reset` empties their seen-sets.
+_watched: "weakref.WeakSet" = weakref.WeakSet()
 _tl = threading.local()
 #: Process-global mirror of the per-thread frame stacks: jax's
 #: monitoring listener can fire from a different thread than the
@@ -151,10 +154,36 @@ def storm_threshold() -> int:
     return _storm_threshold
 
 
+def _is_compile_record(record: tuple) -> bool:
+    """A record `record_compile` pushed into the metrics buffer."""
+    return record[0] == "compile" or str(record[1]).startswith(
+        "rt_jax_compile"
+    )
+
+
 def reset() -> None:
-    """Drop all recorded programs (tests)."""
+    """Forget every compile this process has seen (tests): the
+    registry, and with it all that would let an earlier compile show
+    in, or hide from, the next record — the instrumented wrappers'
+    seen-sets, the compile records the metrics buffer has not
+    delivered (pushed while no session was up, they would reach the
+    NEXT session's head and read there as its storm), and the
+    executables jax itself keeps (a wrapper that misses its seen-set
+    but finds XLA's cache warm records nothing: the program's count
+    would stay at zero however often it ran)."""
     with _lock:
         _programs.clear()
+        watched = list(_watched)
+    for fn in watched:
+        with fn._seen_lock:
+            fn._seen.clear()
+    from ..util.metrics import _Buffer
+
+    _Buffer.discard(_is_compile_record)
+    if "jax" in sys.modules:
+        import jax
+
+        jax.clear_caches()
 
 
 # ---------------------------------------------------------------------
@@ -620,13 +649,15 @@ class WatchedFunction:
     (old jax, or a cache hit we mistook for a miss — recorded
     honestly as near-zero)."""
 
-    __slots__ = ("name", "_fn", "_seen", "_seen_lock")
+    __slots__ = ("name", "_fn", "_seen", "_seen_lock", "__weakref__")
 
     def __init__(self, name: str, fn: Callable):
         self.name = str(name)
         self._fn = fn
         self._seen: set = set()
         self._seen_lock = threading.Lock()
+        with _lock:
+            _watched.add(self)
         _install_monitoring()
 
     def __call__(self, *args, **kwargs):

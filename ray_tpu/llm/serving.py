@@ -99,19 +99,9 @@ class LLMServer:
         families: Dict[str, Dict[str, Any]],
         default_family: Optional[str] = None,
         engine: Optional[Dict[str, Any]] = None,
-        engine_enabled: bool = True,
     ):
         if not families:
             raise ValueError("families must name at least one model")
-        if not engine_enabled:
-            # The keyword outlives its switch only because
-            # benchmark/drivers/serve.py still binds
-            # `engine_enabled=True`; it goes with that line.
-            raise ValueError(
-                "engine_enabled=False: the per-request fallback it "
-                "selected was deleted in PR 29; the engine is the "
-                "only serve path"
-            )
         self._families = dict(families)
         self._default = default_family or next(iter(self._families))
         self._engine_cfg = EngineConfig(**(engine or {}))

@@ -23,7 +23,7 @@ owns the allocator and the refcounts; this module owns the compute).
 from __future__ import annotations
 
 from functools import partial
-from typing import Dict, NamedTuple
+from typing import Callable, Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -94,50 +94,6 @@ def accel_donate(*argnums: int):
 PAGED_TILE_KEYS = 256
 
 
-def init_block_pool(
-    cfg: LlamaConfig, n_blocks: int, block_len: int
-) -> Dict[str, jax.Array]:
-    """The shared pool: k/v of shape
-    [layers, n_blocks, kv_heads, block_len, head_dim]; for a MoE
-    config also `moe_counts` [layers, E], where each paged forward
-    leaves its picks per expert (`_paged_forward`).
-
-    A latent-attention config (`kv_lora_rank`) caches no k and v: one
-    `latent` entry a token a layer, [layers, n_blocks, block_len,
-    kv_lora_rank + rope dims] (the compressed key-value latent and the
-    one rotary key all heads share), and where an indexer selects the
-    keys (`index_topk`) its key beside it, `index_k` [..., index dim]:
-    both are functions of the token prefix alone, so they live under
-    the same block tables, allocator and prefix cache, and a shared
-    page stays shareable. (No unit axis where the kv heads stand, and
-    an entry as wide as whole lanes, `_lanes`: the TPU keeps an array
-    whose rows are not whole lanes with another axis innermost, and
-    the compiler then re-laid the whole pool around every step.) Its
-    counters (`COUNTER_LEAVES`) count expert layers only, and
-    `dsa_counts` [layers, 2] the (query, key) pairs each layer's
-    attention could see and did attend."""
-    if cfg.kv_lora_rank:
-        return _init_latent_pool(cfg, n_blocks, block_len)
-    if cfg.layer_kinds:
-        return _init_kinds_pool(cfg, n_blocks, block_len)
-    shape = (
-        cfg.n_layers,
-        n_blocks,
-        cfg.n_kv_heads,
-        block_len,
-        cfg.head_dim,
-    )
-    pool = {
-        "k": jnp.zeros(shape, cfg.dtype),
-        "v": jnp.zeros(shape, cfg.dtype),
-    }
-    if cfg.moe_experts:
-        pool["moe_counts"] = jnp.zeros(
-            (cfg.n_layers, cfg.moe_experts), jnp.int32
-        )
-    return pool
-
-
 #: The pool's leaves that hold no cache: what a paged forward counted,
 #: left there for the engine to fetch (overwritten, not summed).
 COUNTER_LEAVES = ("moe_counts", "moe_routed", "dsa_counts")
@@ -170,25 +126,6 @@ def _pad_last(x, width: int):
     )
 
 
-def _init_latent_pool(cfg: LlamaConfig, n_blocks: int, block_len: int):
-    def leaf(width):
-        return jnp.zeros(
-            (cfg.n_layers, n_blocks, block_len, _lanes(width)), cfg.dtype
-        )
-
-    pool = {"latent": leaf(cfg.kv_lora_rank + cfg.qk_rope_head_dim)}
-    if cfg.index_topk:
-        pool["index_k"] = leaf(cfg.index_head_dim)
-        pool["dsa_counts"] = jnp.zeros((cfg.n_layers, 2), jnp.int32)
-    if cfg.moe_experts:
-        expert_layers = cfg.n_layers - cfg.dense_layers
-        pool["moe_counts"] = jnp.zeros(
-            (expert_layers, cfg.moe_experts), jnp.int32
-        )
-        pool["moe_routed"] = jnp.zeros((expert_layers,), jnp.int32)
-    return pool
-
-
 class KindTables(NamedTuple):
     """A row's (or every slot's) block table in each kind's pool of a
     `layer_kinds` model: what `paged_prefill` and `patch_step_slot`
@@ -199,41 +136,125 @@ class KindTables(NamedTuple):
     window: jax.Array  # [b, ring]: logical block j at entry j mod ring
 
 
-#: The pool's k and v leaves of each cache a `layer_kinds` model keeps
+#: The pool's k and v leaves of each cache of plain attention
 #: (`AttnKind.cache`): the full-attention layers' under the names every
-#: plain model has, the window layers' beside them.
+#: model of one kind has, a `layer_kinds` model's window layers' beside
+#: them.
 KIND_LEAVES = {"full": ("k", "v"), "window": ("window_k", "window_v")}
 
 
-def _init_kinds_pool(cfg: LlamaConfig, n_blocks, block_len: int):
-    """The pool of a `layer_kinds` model: k and v of each kind of
-    layer under `KIND_LEAVES`' names, [the kind's layers, the kind's
-    own `n_blocks[cache]`, its kv heads, block_len, width]: a pool a
-    kind, since a window layer keeps a row's last keys and a full
-    layer all of them (llm/kv_window.py has the bookkeeping). A plain
-    number of blocks gives both kinds that many: a caller that keeps
-    ONE id space and hands every kind a row's one full-width table. Keys and
-    values are as wide as whole lanes (`_lanes`: a head_dim of 192 is
-    kept 256 wide, the rest zero, which is what the chip lays out
-    anyway). Counters as a latent pool's: an entry an expert layer."""
-    pool = {}
-    if not isinstance(n_blocks, dict):
-        n_blocks = dict.fromkeys(KIND_LEAVES, n_blocks)
-    for kind, layers in cfg.attn_kinds().values():
-        for name, width in zip(
-            KIND_LEAVES[kind.cache],
-            (cfg.head_dim, cfg.v_head_dim or cfg.head_dim),
-        ):
-            pool[name] = jnp.zeros(
-                (len(layers), n_blocks[kind.cache], kind.kv_heads,
-                 block_len, _lanes(width)), cfg.dtype,
+class _Cache(NamedTuple):
+    """One cache a model keeps and the attention that reads it: what
+    `_pool_plan` reads off a `LlamaConfig`, and all that the pool, a
+    forward's plans and its walk over the layers know of a family."""
+
+    layers: tuple  # the model's layers that keep it, in order
+    #: pool leaf -> (a page's axes before block_len, an entry's width)
+    leaves: Dict[str, tuple]
+    attend: Callable  # its layers' attention half
+    groups: int  # query heads a cached head (a work list tiles q_pos so)
+    rotary: tuple  # (dims, base) of the rotary embedding its layers apply
+    out_scope: str  # what a trace names its layers' residual through `wo`
+    window: int = 0  # keys a query sees (0: all before it)
+
+
+def _pool_plan(cfg: LlamaConfig):
+    """THE reading of a `LlamaConfig` for the serve path -> ({cache:
+    `_Cache`}, {counter leaf: shape}): which caches the pool holds
+    (`init_block_pool` says why each is laid out as it is), which
+    attention half reads each, and what a forward counts. The pool and
+    `_paged_forward` both follow it and ask the configuration nothing
+    else of its family: latent attention (`kv_lora_rank`) is one cache
+    of entries without a kv-head axis; `layer_kinds` a cache a kind;
+    any other model is the one-kind case, every layer a full-attention
+    layer of `n_kv_heads` at `rope_theta` (a head as wide as it is,
+    not whole lanes: the pool such a model has had).
+
+    `moe_counts` has an entry an expert layer; a model whose layers
+    lie in stacks of their own (the first two families) holds a share
+    of the experts it routes over and counts `moe_routed` beside it."""
+    every = tuple(range(cfg.n_layers))
+    counters = {}
+    if cfg.kv_lora_rank:
+        leaves = {
+            "latent": ((), _lanes(cfg.kv_lora_rank + cfg.qk_rope_head_dim))
+        }
+        if cfg.index_topk:
+            leaves["index_k"] = ((), _lanes(cfg.index_head_dim))
+            counters["dsa_counts"] = (cfg.n_layers, 2)
+        caches = {"latent": _Cache(
+            every, leaves, _latent_attend, 1,
+            (cfg.qk_rope_head_dim, cfg.rope_theta), "mla/out",
+        )}
+    else:
+        kinds = cfg.attn_kinds() if cfg.layer_kinds else {
+            "full": (AttnKind(0, cfg.n_kv_heads, cfg.rope_theta), every)
+        }
+        lanes = _lanes if cfg.layer_kinds else int
+        caches = {
+            name: _Cache(
+                tuple(layers),
+                {leaf: ((kind.kv_heads,), lanes(width)) for leaf, width in zip(
+                    KIND_LEAVES[name],
+                    (cfg.head_dim, cfg.v_head_dim or cfg.head_dim),
+                )},
+                partial(_paged_attend, kind=kind),
+                cfg.n_heads // kind.kv_heads,
+                (cfg.rotary_dim or cfg.head_dim, kind.rope_theta),
+                "layer/attn_out", kind.window,
             )
+            for name, (kind, layers) in kinds.items()
+        }
     if cfg.moe_experts:
         expert_layers = cfg.n_layers - cfg.dense_layers
-        pool["moe_counts"] = jnp.zeros(
-            (expert_layers, cfg.moe_experts), jnp.int32
-        )
-        pool["moe_routed"] = jnp.zeros((expert_layers,), jnp.int32)
+        counters["moe_counts"] = (expert_layers, cfg.moe_experts)
+        if cfg.kv_lora_rank or cfg.layer_kinds:
+            counters["moe_routed"] = (expert_layers,)
+    return caches, counters
+
+
+def init_block_pool(
+    cfg: LlamaConfig, n_blocks, block_len: int
+) -> Dict[str, jax.Array]:
+    """The shared pool: k/v of shape
+    [layers, n_blocks, kv_heads, block_len, head_dim]; for a MoE
+    config also `moe_counts` [layers, E], where each paged forward
+    leaves its picks per expert (`_paged_forward`).
+
+    A latent-attention config (`kv_lora_rank`) caches no k and v: one
+    `latent` entry a token a layer, [layers, n_blocks, block_len,
+    kv_lora_rank + rope dims] (the compressed key-value latent and the
+    one rotary key all heads share), and where an indexer selects the
+    keys (`index_topk`) its key beside it, `index_k` [..., index dim]:
+    both are functions of the token prefix alone, so they live under
+    the same block tables, allocator and prefix cache, and a shared
+    page stays shareable. (No unit axis where the kv heads stand, and
+    an entry as wide as whole lanes, `_lanes`: the TPU keeps an array
+    whose rows are not whole lanes with another axis innermost, and
+    the compiler then re-laid the whole pool around every step.) Its
+    counters (`COUNTER_LEAVES`) count expert layers only, and
+    `dsa_counts` [layers, 2] the (query, key) pairs each layer's
+    attention could see and did attend.
+
+    A `layer_kinds` model: k and v of each kind of layer under
+    `KIND_LEAVES`' names, [the kind's layers, the kind's own
+    `n_blocks[cache]`, its kv heads, block_len, width]: a pool a kind,
+    since a window layer keeps a row's last keys and a full layer all
+    of them (llm/kv_window.py has the bookkeeping). A plain number of
+    blocks gives both kinds that many: a caller that keeps ONE id
+    space and hands every kind a row's one full-width table. Counters
+    as a latent pool's: an entry an expert layer."""
+    caches, counters = _pool_plan(cfg)
+    pool = {}
+    for name, cache in caches.items():
+        blocks = n_blocks[name] if isinstance(n_blocks, dict) else n_blocks
+        for leaf, (heads, width) in cache.leaves.items():
+            pool[leaf] = jnp.zeros(
+                (len(cache.layers), blocks, *heads, block_len, width),
+                cfg.dtype,
+            )
+    for name, shape in counters.items():
+        pool[name] = jnp.zeros(shape, jnp.int32)
     return pool
 
 
@@ -517,28 +538,29 @@ def _paged_write(pool, layer_idx, tables, q_pos, new):
     )
 
 
-def _qkv_flat(cfg: LlamaConfig, h, layer, kind: AttnKind = None):
+def _qkv_flat(cfg: LlamaConfig, h, layer, kind: AttnKind):
     """The first half of `llama.project_qkv`, the train layer's
-    one-piece form of the same arithmetic: the three products (+
-    Qwen2-family biases, + OLMoE's norm over the whole projection; a
-    layer of `kind`: values times `value_scale`), heads not yet split.
-    h: [b, t, dim] -> each of q/k/v: [b, t, heads * head_dim]. The
-    training layer and the serve layer must use the SAME projection
-    or their logits silently diverge; the two share arithmetic and
-    not code while an edit of models/llama.py would cost the train
-    cells their byte-identical programs, and
+    one-piece form of the same arithmetic: the three products and what
+    the configuration has that acts on a whole projection (Qwen2-family
+    biases, OLMoE's norm, a `value_scale` on the values), heads not
+    yet split. h: [b, t, dim] -> each of q/k/v: [b, t, heads *
+    head_dim]. The training layer and the serve layer must use the
+    SAME projection or their logits silently diverge; the two share
+    arithmetic and not code while an edit of models/llama.py would
+    cost the train cells their byte-identical programs, and
     tests/test_serve_projection_pin.py holds `_split_heads(_qkv_flat)`
-    equal to `project_qkv` bit for bit in every family."""
+    equal to `project_qkv` bit for bit in every family. (`kind`
+    stands where `project_qkv` has it and is not read: what a kind
+    changes of a projection, its kv heads and its values' width, is
+    in the weights' own shapes.)"""
     q, k, v = h @ layer["wq"], h @ layer["wk"], h @ layer["wv"]
-    if kind is not None:
-        if cfg.value_scale != 1.0:
-            v = v * jnp.asarray(cfg.value_scale, v.dtype)
-        return q, k, v
     if cfg.attn_bias:
         q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
     if cfg.qk_norm == "proj":
         q = rms_norm(q, layer["q_norm"], eps=cfg.norm_eps)
         k = rms_norm(k, layer["k_norm"], eps=cfg.norm_eps)
+    if cfg.value_scale != 1.0:
+        v = v * jnp.asarray(cfg.value_scale, v.dtype)
     return q, k, v
 
 
@@ -561,82 +583,57 @@ def _row_major(h, flat):
     return jax.lax.optimization_barrier(flat)
 
 
-def _split_heads(cfg: LlamaConfig, q, k, v, layer, kind: AttnKind = None):
+def _split_heads(cfg: LlamaConfig, q, k, v, layer, kind: AttnKind):
     """The second half of `llama.project_qkv`: each of q/k/v [b, t,
     heads * head_dim] -> [b, heads, t, head_dim] (+ Qwen3's norm a
-    head, BEFORE RoPE). A layer of `kind`: that kind's kv heads, and
-    values `v_head_dim` wide."""
+    head, BEFORE RoPE): as many kv heads as the key projection holds
+    heads of `head_dim`, values as wide as theirs leaves them."""
     b, t, _ = q.shape
-    kv_heads = cfg.n_kv_heads if kind is None else kind.kv_heads
+    kv_heads = k.shape[-1] // cfg.head_dim
 
     def heads(x, n):
         return x.reshape(b, t, n, -1).transpose(0, 2, 1, 3)
 
     q, k, v = heads(q, cfg.n_heads), heads(k, kv_heads), heads(v, kv_heads)
-    if kind is None and cfg.qk_norm == "head":
+    if cfg.qk_norm == "head":
         q = rms_norm(q, layer["q_norm"], eps=cfg.norm_eps)
         k = rms_norm(k, layer["k_norm"], eps=cfg.norm_eps)
     return q, k, v
 
 
-def _paged_layer(
-    cfg: LlamaConfig,
-    x: jax.Array,  # [b, t, dim]
-    layer: Dict[str, jax.Array],
-    layer_idx,  # [] this layer's index into the pool
-    cos,
-    sin,
-    k_pool,  # [layers, n_blocks, kv_heads, block_len, hd]: the pool
-    v_pool,
-    tables: jax.Array,  # [b, whole tiles of entries] physical block ids
-    q_pos: jax.Array,  # [b, t] absolute positions of x's tokens
-    work,  # attention's live (row, tile) pairs
-    n_trips,  # [] attention's trip count
-    live=None,  # [b] rows that are real (None: all)
-    kind: AttnKind = None,  # this layer's, of a `layer_kinds` model
-    ffn_idx=None,  # and its index into its FFN's stack
-):
-    """-> (x, k_pool, v_pool, counts): counts is the layer's picks
-    per expert [E] for a MoE config, None for a dense one. A layer of
-    `kind` gets that kind's pool, table, positions and work list; a
-    window layer's are its VIEW of the row (`_window_view`)."""
-    b, t, _ = x.shape
+def _paged_attend(cfg: LlamaConfig, h, layer, cache, plan, at, *, kind):
+    """The attention half of a layer of plain attention, of `kind`:
+    h [b, t, dim] the normed activation, `layer` its weights, `cache`
+    the pool's cache leaves, `plan` its kind's (`_plans`: pool, table,
+    positions and work list; a window layer's are its VIEW of the
+    row, `_window_view`), `at` its index into its kind's leaves ->
+    (the attention's output [b, heads, t, value width] before `wo`,
+    the cache, no counts of its own)."""
+    k_name, v_name = KIND_LEAVES[kind.cache]
+    k_pool, v_pool = cache[k_name], cache[v_name]
     with jax.named_scope("layer/attn_qkv"):
-        h = model_norm(cfg, x, layer["attn_norm"])
         flat = _row_major(h, _qkv_flat(cfg, h, layer, kind))
         q, k, v = _split_heads(cfg, *flat, layer, kind)
-        q = apply_rotary(q, cos, sin)
-        k = apply_rotary(k, cos, sin)
+        q = apply_rotary(q, plan["cos"], plan["sin"])
+        k = apply_rotary(k, plan["cos"], plan["sin"])
     # Write BEFORE attention so the chunk attends to its own tokens
     # (prefill self-attention).
     with jax.named_scope("paged/scatter_kv"):
-        if kind is not None:  # whole lanes, as the pool keeps them
+        v_width, scale = v.shape[-1], None
+        if k.shape[-1] != k_pool.shape[-1]:
+            # Whole lanes, as the pool keeps them; the softmax scale
+            # stays that of the head as it is.
             q, k = (_pad_last(a, k_pool.shape[-1]) for a in (q, k))
-            v_width, v = v.shape[-1], _pad_last(v, v_pool.shape[-1])
-        k_pool = _paged_write(k_pool, layer_idx, tables, q_pos, k)
-        v_pool = _paged_write(v_pool, layer_idx, tables, q_pos, v)
-    if kind is None:
-        attn = _paged_attention(q, k_pool, v_pool, layer_idx, work, n_trips)
-    else:
-        with jax.named_scope(f"attn/{kind.cache}"):
-            attn = _paged_attention(
-                q, k_pool, v_pool, layer_idx, work, n_trips,
-                scale=cfg.head_dim ** -0.5, window=kind.window,
-                sink=layer.get("sink"),
-            )[..., :v_width]
-        layer_idx = ffn_idx
-    with jax.named_scope("layer/attn_out"):
-        attn = attn.astype(cfg.dtype).transpose(0, 2, 1, 3).reshape(
-            b, t, -1
-        )
-        x = x + attn @ layer["wo"]
-    with jax.named_scope("paged/mlp"):
-        # A MoE layer's experts arrive as whole stacks (below).
-        x, _, counts = _mlp(
-            cfg, x, layer, live=live,
-            layer_idx=layer_idx if cfg.moe_experts else None,
-        )
-    return x, k_pool, v_pool, counts
+            v = _pad_last(v, v_pool.shape[-1])
+            scale = cfg.head_dim ** -0.5
+        k_pool = _paged_write(k_pool, at, plan["tables"], plan["q_pos"], k)
+        v_pool = _paged_write(v_pool, at, plan["tables"], plan["q_pos"], v)
+    with jax.named_scope(f"attn/{kind.cache}"):
+        out = _paged_attention(
+            q, k_pool, v_pool, at, plan["work"], plan["n_trips"],
+            scale=scale, window=kind.window, sink=layer.get("sink"),
+        )[..., :v_width]
+    return out, {**cache, k_name: k_pool, v_name: v_pool}, {}
 
 
 def _paged_plan(
@@ -666,159 +663,6 @@ def _paged_plan(
         bl, n_blocks,
     )
     return tables, valid_len, work, n_trips
-
-
-def _kind_plans(cfg: LlamaConfig, pool, tables, q_pos, valid_len, alive):
-    """What each KIND of layer of a `layer_kinds` model walks in one
-    paged forward, made once for all its layers: -> {cache: (plan, the
-    kind's layers)}, a plan holding the kind's `tables`, `q_pos`,
-    `work`, `n_trips` (`_paged_plan`) and rotary `cos`, `sin` at the
-    kind's own base. `tables` holds a row's table in each kind's pool
-    (`KindTables`), or is one [b, width] table for both. A window layer's plan is
-    made from its VIEW of the row (`_window_view`), so it walks the
-    tiles that hold a key some query of this forward can see and no
-    other: for a step the last block or two of tiles, for a chunk its
-    own and the window before it."""
-    plans = {}
-    for kind, layers in cfg.attn_kinds().values():
-        n_blocks, kv_heads, bl = pool[KIND_LEAVES[kind.cache][0]].shape[1:4]
-        # (a plain table serves both kinds: a ring as wide as the row)
-        table = tables
-        if isinstance(tables, KindTables):
-            table = getattr(tables, kind.cache)
-        pos, valid = q_pos, valid_len
-        if kind.window:
-            table, first_key = _window_view(
-                table, q_pos[:, 0], kind.window, bl, q_pos.shape[1]
-            )
-            pos, valid = q_pos - first_key[:, None], valid_len - first_key
-        table, _, work, n_trips = _paged_plan(
-            table, pos, valid, alive, n_blocks, bl, cfg.n_heads // kv_heads
-        )
-        cos, sin = rotary_embedding(
-            q_pos, cfg.rotary_dim or cfg.head_dim, kind.rope_theta,
-            cfg.rope_scaling,
-        )
-        plans[kind.cache] = (dict(
-            tables=table, q_pos=pos, work=work, n_trips=n_trips,
-            cos=cos, sin=sin,
-        ), layers)
-    return plans
-
-
-def _paged_forward(
-    params, cfg: LlamaConfig, tokens, pool, tables, q_pos, valid_len,
-    alive=True,
-):
-    """tokens [b, t] at absolute positions q_pos [b, t] (consecutive
-    along a row) -> (logits [b, t, vocab], new pool). `tables` maps
-    each row's logical blocks to pool blocks and `valid_len` [b]
-    bounds what attention may see.
-    `alive` [b] names the rows whose key tiles attention walks (a
-    dead row still computes, and sees no key). The pool is carried
-    through the layer loop and written in place. For a MoE config the
-    new pool also holds this forward's picks per layer and expert,
-    `moe_counts` [layers, E] int32 (overwritten, not summed: the
-    engine adds them up); a dead row picks no expert."""
-    q_pos = jnp.asarray(q_pos, jnp.int32)
-    valid_len = jnp.asarray(valid_len, jnp.int32)
-    if cfg.kv_lora_rank:
-        return _latent_forward(
-            params, cfg, tokens, pool, tables, q_pos, valid_len, alive
-        )
-    live = None if alive is True else alive
-    if cfg.layer_kinds:
-        # Layers of more than one KIND: every layer is `_paged_layer`
-        # with its kind's plan, its place in its kind's stack and its
-        # place in its FFN's (`llama.kinds_layer_shapes`); unrolled.
-        # Counters as a latent model's.
-        plans = _kind_plans(cfg, pool, tables, q_pos, valid_len, alive)
-        with jax.named_scope("embed"):
-            x = embed_tokens(cfg, params, tokens)
-        cache = cache_leaves(pool)
-        counters: Dict[str, list] = {"moe_counts": [], "moe_routed": []}
-        rows_live = tokens.shape[0] if live is None else live.sum()
-        routed = jnp.asarray(
-            rows_live * tokens.shape[1] * cfg.moe_top_k, jnp.int32
-        )
-        for layer_idx, kind in enumerate(cfg.layer_kinds):
-            plan, layers = plans[kind.cache]
-            at = layers.index(layer_idx)
-            dense = layer_idx < cfg.dense_layers
-            ffn = params["dense_layers" if dense else "layers"]
-            ffn_idx = layer_idx - (0 if dense else cfg.dense_layers)
-            weights = {
-                **{n: w[at] for n, w in params[f"attn_{kind.cache}"].items()},
-                # (a layer's experts stay whole stacks: ops/moe.py)
-                **{n: w if n in EXPERT_LEAVES else w[ffn_idx]
-                   for n, w in ffn.items()},
-            }
-            k_name, v_name = KIND_LEAVES[kind.cache]
-            x, cache[k_name], cache[v_name], picks = _paged_layer(
-                cfg, x, weights, at, plan["cos"], plan["sin"],
-                cache[k_name], cache[v_name], plan["tables"], plan["q_pos"],
-                plan["work"], plan["n_trips"], live, kind=kind,
-                ffn_idx=ffn_idx,
-            )
-            if picks is not None:
-                counters["moe_counts"].append(picks)
-                counters["moe_routed"].append(routed)
-        with jax.named_scope("final_norm"):
-            x = model_norm(cfg, x, params["final_norm"])
-        with jax.named_scope("lm_head"):
-            logits = (x @ params["lm_head"]).astype(jnp.float32)
-        return logits, {
-            **cache, **{n: jnp.stack(v) for n, v in counters.items() if v},
-        }
-    # (the queries of a kv head's group lie side by side, as
-    # `_paged_attention` lays them)
-    tables, valid_len, work, n_trips = _paged_plan(
-        tables, q_pos, valid_len, alive, pool["k"].shape[1],
-        pool["k"].shape[3], cfg.n_heads // cfg.n_kv_heads,
-    )
-    with jax.named_scope("embed"):
-        x = embed_tokens(cfg, params, tokens)
-    cos, sin = rotary_embedding(
-        q_pos, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
-    )
-
-    # The loop slices each layer's weights out of their stacks, all
-    # but a MoE layer's experts: a slice of those would be copied
-    # before the grouped-matmul kernel (805 MB a layer at OLMoE's
-    # widths), so they stay whole and the expert layer finds its own
-    # in them (ops/moe.py). A dense model has none: its loop is as it
-    # was. The other slices are read inside their matmuls, where the
-    # stack holds them, as long as the product comes out row-major:
-    # the projections that feed a head split are held so
-    # (`_row_major`), or the compiler would fold the split's
-    # transposition into `wq` / `wk` and copy them out a layer.
-    layers = params["layers"]
-    experts = {n: layers[n] for n in EXPERT_LEAVES if n in layers}
-    sliced = {n: w for n, w in layers.items() if n not in experts}
-
-    def body(carry, inputs):
-        x, k_pool, v_pool = carry
-        layer, layer_idx = inputs
-        *carry, counts = _paged_layer(
-            cfg, x, {**layer, **experts}, layer_idx, cos, sin, k_pool,
-            v_pool, tables, q_pos, work, n_trips, live,
-        )
-        return tuple(carry), counts
-
-    (x, new_k, new_v), counts = jax.lax.scan(
-        body,
-        (x, pool["k"], pool["v"]),
-        (sliced, jnp.arange(cfg.n_layers)),
-    )
-    with jax.named_scope("final_norm"):
-        x = model_norm(cfg, x, params["final_norm"])
-    with jax.named_scope("lm_head"):
-        logits = (x @ params["lm_head"]).astype(jnp.float32)
-    new_pool = {"k": new_k, "v": new_v}
-    if counts is not None:
-        new_pool["moe_counts"] = counts
-    return logits, new_pool
-
 
 
 # ---------------------------------------------------------------------
@@ -983,26 +827,20 @@ def _expand_latent(latent_pool, layer_idx, tables, valid_len, wk, wv, tile):
     return kn, entries[..., latent_width:], v
 
 
-def _latent_layer(
-    cfg: LlamaConfig,
-    x: jax.Array,  # [b, t, dim]
-    layer: Dict[str, jax.Array],
-    layer_idx,  # [] this layer's index into the pool
-    stack_idx,  # [] and into its stack's expert matrices
-    cos,
-    sin,
-    cache: Dict[str, jax.Array],  # the pool's cache leaves
-    tables: jax.Array,  # [b, whole tiles of entries]
-    q_pos: jax.Array,  # [b, t]
-    valid_len: jax.Array,  # [b] (0 for a dead row)
-    work,  # the live (row, tile) pairs, `pos` a row's q_pos
-    n_trips,
-    live=None,
-):
-    """-> (x, cache, counts): counts holds the layer's `moe_counts`
-    [E held] and `moe_routed` [] (an expert layer) and `dsa_counts` [2]
-    (visible pairs, attended pairs; with an indexer)."""
-    b, t, _ = x.shape
+def _latent_attend(cfg: LlamaConfig, h, layer, cache, plan, layer_idx):
+    """The attention half of a latent-attention layer: h [b, t, dim]
+    the normed activation, `layer` its weights, `cache` the pool's
+    cache leaves, `plan` the forward's (`_plans`; its work list's
+    `pos` a row's q_pos), `layer_idx` its index into the pool -> (the
+    attention's output [b, heads, t, v_head_dim] before `wo`, the
+    cache, the layer's `dsa_counts` [2] where it has an indexer:
+    visible pairs, attended pairs)."""
+    b, t, _ = h.shape
+    cos, sin, tables, q_pos, valid_len, work, n_trips = (
+        plan[n] for n in (
+            "cos", "sin", "tables", "q_pos", "valid_len", "work", "n_trips"
+        )
+    )
     heads, dt = cfg.n_heads, cfg.dtype
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     kvr, vd = cfg.kv_lora_rank, cfg.v_head_dim
@@ -1014,7 +852,6 @@ def _latent_layer(
     def rotated(v):  # rotary on the rope dims, which lead
         return apply_rotary(v, cos, sin)
 
-    h = model_norm(cfg, x, layer["attn_norm"])
     wkv_b = layer["wkv_b"].reshape(kvr, heads, nope + vd)
     with jax.named_scope("mla/q"):
         cq = rms_norm(h @ layer["wq"], layer["q_norm"], eps=cfg.norm_eps)
@@ -1121,83 +958,201 @@ def _latent_layer(
                 allowed.astype(jnp.int8), q_pos[:, 0], valid_len,
                 scale=scale, block_k=tile,
             )[..., :vd]  # [b, heads, t, vd]: the heads' own values
-    with jax.named_scope("mla/out"):
-        if step:
+    if step:
+        with jax.named_scope("mla/out"):
             out = jnp.einsum(
                 "bhtc,chv->bhtv", out.astype(dt), wkv_b[..., nope:]
             )
-        out = out.astype(dt).transpose(0, 2, 1, 3).reshape(b, t, heads * vd)
+    return out, cache, counts
+
+
+def _serve_block(
+    cfg: LlamaConfig,
+    x: jax.Array,  # [b, t, dim]
+    layer: Dict[str, jax.Array],  # its weights; experts as whole stacks
+    cache: Dict[str, jax.Array],  # the pool's cache leaves
+    spec: _Cache,  # its cache's: which attention half it runs
+    plan,  # what its cache's layers walk in this forward (`_plans`)
+    at,  # [] its index into its cache's leaves
+    ffn_idx,  # [] and into its FFN's stack
+    live,  # [b] rows that are real (None: all)
+    counted,  # the counters the pool has leaves for
+):
+    """THE layer of a paged forward, whatever the family: norm, an
+    attention half (which writes the layer's cache and returns the
+    attention's output before `wo`), the residual through `wo`, the
+    FFN -> (x, cache, counts): of `counted`, the layer's `moe_counts`
+    [E held] and `moe_routed` [] (an expert layer: its picks per
+    expert, and the picks over all the router's outputs; a dead row
+    picks none) and what its attention counted (`dsa_counts`)."""
+    b, t, _ = x.shape
+    with jax.named_scope("layer/attn_qkv"):
+        h = model_norm(cfg, x, layer["attn_norm"])
+    out, cache, counts = spec.attend(cfg, h, layer, cache, plan, at)
+    with jax.named_scope(spec.out_scope):
+        out = out.astype(cfg.dtype).transpose(0, 2, 1, 3).reshape(b, t, -1)
         x = x + out @ layer["wo"]
     with jax.named_scope("paged/mlp"):
-        expert_layer = "router" in layer
+        # (a leading dense layer of an expert model has no router)
         x, _, picks = _mlp(
             cfg, x, layer, live=live,
-            layer_idx=stack_idx if expert_layer else None,
+            layer_idx=ffn_idx if "router" in layer else None,
         )
-        if expert_layer:
-            rows_live = b if live is None else live.sum()
+        if picks is not None:
             counts["moe_counts"] = picks
-            counts["moe_routed"] = jnp.asarray(
-                rows_live * t * cfg.moe_top_k, jnp.int32
-            )
+            if "moe_routed" in counted:
+                rows_live = b if live is None else live.sum()
+                counts["moe_routed"] = jnp.asarray(
+                    rows_live * t * cfg.moe_top_k, jnp.int32
+                )
     return x, cache, counts
 
 
-def _latent_forward(
+def _plans(caches, pool, tables, q_pos, valid_len, alive):
+    """What the layers of each of a model's caches (`_pool_plan`) walk
+    in one paged forward, made once for all of them: -> {cache: plan},
+    a plan holding the cache's `tables`, `q_pos`, `valid_len`, `work`
+    and `n_trips` (`_paged_plan`). `tables` holds a row's table in
+    each kind's pool (`KindTables`), or is one [b, width] table for
+    every cache. A window layer's plan is made from its VIEW of the
+    row (`_window_view`), so it walks the tiles that hold a key some
+    query of this forward can see and no other: for a step the last
+    block or two of tiles, for a chunk its own and the window before
+    it."""
+    plans = {}
+    for name, cache in caches.items():
+        shape = pool[next(iter(cache.leaves))].shape
+        n_blocks, bl = shape[1], shape[-2]
+        # (a plain table serves every kind: a ring as wide as the row)
+        table = tables
+        if isinstance(tables, KindTables):
+            table = getattr(tables, name)
+        pos, valid = q_pos, valid_len
+        if cache.window:
+            table, first_key = _window_view(
+                table, q_pos[:, 0], cache.window, bl, q_pos.shape[1]
+            )
+            pos, valid = q_pos - first_key[:, None], valid_len - first_key
+        # (the queries of a kv head's group lie side by side, as
+        # `_paged_attention` lays them)
+        table, valid, work, n_trips = _paged_plan(
+            table, pos, valid, alive, n_blocks, bl, cache.groups
+        )
+        plans[name] = dict(
+            tables=table, q_pos=pos, valid_len=valid, work=work,
+            n_trips=n_trips,
+        )
+    return plans
+
+
+def _paged_forward(
     params, cfg: LlamaConfig, tokens, pool, tables, q_pos, valid_len,
     alive=True,
 ):
-    """`_paged_forward` of a latent-attention config: the same work
-    list, trip count and page writes over the pool's `latent` and
-    `index_k`, through the model's stacks in turn (`dense_layers`,
-    where it has leading dense layers, then `layers`; the pool's layer
-    axis counts across them). The new pool holds the forward's
-    counters, each summed over nothing: `moe_counts` and `moe_routed`
-    an entry an expert layer, `dsa_counts` one a layer."""
-    cache = cache_leaves(pool)
-    n_blocks, bl = cache["latent"].shape[1:3]
-    tables, valid_len, work, n_trips = _paged_plan(
-        tables, q_pos, valid_len, alive, n_blocks, bl, 1
-    )
+    """tokens [b, t] at absolute positions q_pos [b, t] (consecutive
+    along a row) -> (logits [b, t, vocab], new pool). `tables` maps
+    each row's logical blocks to pool blocks and `valid_len` [b]
+    bounds what attention may see.
+    `alive` [b] names the rows whose key tiles attention walks (a
+    dead row still computes, and sees no key). The pool is carried
+    through the walk over the layers and written in place. The new
+    pool also holds what this forward counted, in the leaves the pool
+    has for it (`COUNTER_LEAVES`; overwritten, not summed: the engine
+    adds them up): `moe_counts` and `moe_routed` an entry an expert
+    layer, `dsa_counts` one a layer.
+
+    ONE walk for every family. What differs between them is what
+    `_pool_plan` read off the configuration: which caches there are,
+    which attention half reads each, and which stacks hold the layers
+    (`dense_layers`, where the model has leading dense layers, then
+    `layers`; what a kind changes of the attention in a stack of its
+    own, `attn_full` / `attn_window`, beside them)."""
+    q_pos = jnp.asarray(q_pos, jnp.int32)
+    valid_len = jnp.asarray(valid_len, jnp.int32)
+    live = None if alive is True else alive
+    caches, counters = _pool_plan(cfg)
+    plans = _plans(caches, pool, tables, q_pos, valid_len, alive)
     with jax.named_scope("embed"):
         x = embed_tokens(cfg, params, tokens)
-    cos, sin = rotary_embedding(
-        q_pos, cfg.qk_rope_head_dim, cfg.rope_theta, cfg.rope_scaling
-    )
-    live = None if alive is True else alive
-    counters: Dict[str, list] = {}
-    first = 0
-    for stack in ("dense_layers", "layers"):
-        layers = params.get(stack)
-        if layers is None:
-            continue
+    for name, spec in caches.items():
+        plans[name]["cos"], plans[name]["sin"] = rotary_embedding(
+            q_pos, *spec.rotary, cfg.rope_scaling
+        )
+    #: model layer -> (its cache, its index into that cache's leaves)
+    where = {
+        layer: (name, at) for name, spec in caches.items()
+        for at, layer in enumerate(spec.layers)
+    }
+
+    def block(x, cache, layer, name, at, ffn_idx):
+        return _serve_block(
+            cfg, x, layer, cache, caches[name], plans[name], at, ffn_idx,
+            live, tuple(counters),
+        )
+
+    cache = cache_leaves(pool)
+    counted: Dict[str, list] = {name: [] for name in counters}
+    #: what a kind changes of the attention, where it lies in a stack
+    #: of its own over that kind's layers (`llama.kinds_layer_shapes`)
+    apart = {n: params[f"attn_{n}"] for n in caches if f"attn_{n}" in params}
+    stacks = [params[n] for n in ("dense_layers", "layers") if n in params]
+    first = 0  # the stack's first layer, of the model's
+    for layers in stacks:
+        depth = layers["attn_norm"].shape[0]
+        # A layer's weights are sliced out of their stacks, all but a
+        # MoE layer's experts: a slice of those would be copied
+        # before the grouped-matmul kernel (805 MB a layer at OLMoE's
+        # widths), so they stay whole and the expert layer finds its
+        # own in them (ops/moe.py). A dense model has none. The other
+        # slices are read inside their matmuls, where the stack holds
+        # them, as long as the product comes out row-major: the
+        # projections that feed a head split are held so
+        # (`_row_major`), or the compiler would fold the split's
+        # transposition into `wq` / `wk` and copy them out a layer.
         experts = {n: layers[n] for n in EXPERT_LEAVES if n in layers}
         sliced = {n: w for n, w in layers.items() if n not in experts}
-        depth = layers["attn_norm"].shape[0]
+        if not apart:
+            # The stack's layers are alike: one body, scanned.
+            name, _ = where[first]
 
-        def body(carry, inputs, experts=experts, first=first):
-            x, cache = carry
-            layer, stack_idx = inputs
-            x, cache, counts = _latent_layer(
-                cfg, x, {**layer, **experts}, first + stack_idx,
-                stack_idx, cos, sin, cache, tables, q_pos, valid_len,
-                work, n_trips, live,
+            def body(carry, inputs):
+                layer, i = inputs
+                # (a model of one stack counts its layers as the scan
+                # does: no `0 +` in the program it has had)
+                *carry, counts = block(
+                    *carry, {**layer, **experts}, name,
+                    first + i if len(stacks) > 1 else i, i,
+                )
+                return tuple(carry), counts
+
+            (x, cache), counts = jax.lax.scan(
+                body, (x, cache), (sliced, jnp.arange(depth))
             )
-            return (x, cache), counts
-
-        (x, cache), counts = jax.lax.scan(
-            body, (x, cache), (sliced, jnp.arange(depth))
-        )
+        else:
+            # They alternate in kind: unrolled, each layer with its
+            # kind's plan and its place in its kind's stacks.
+            per_layer = []
+            for i in range(depth):
+                name, at = where[first + i]
+                layer = {
+                    **{n: w[at] for n, w in apart[name].items()},
+                    **{n: w[i] for n, w in sliced.items()},
+                    **experts,
+                }
+                x, cache, counts = block(x, cache, layer, name, at, i)
+                per_layer.append(counts)
+            counts = {
+                n: jnp.stack([c[n] for c in per_layer]) for n in per_layer[0]
+            }
         for name, value in counts.items():
-            counters.setdefault(name, []).append(value)
+            counted[name].append(value)
         first += depth
     with jax.named_scope("final_norm"):
         x = model_norm(cfg, x, params["final_norm"])
     with jax.named_scope("lm_head"):
         logits = (x @ params["lm_head"]).astype(jnp.float32)
     return logits, {
-        **cache,
-        **{n: jnp.concatenate(v) for n, v in counters.items()},
+        **cache, **{n: jnp.concatenate(v) for n, v in counted.items()},
     }
 
 

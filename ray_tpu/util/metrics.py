@@ -102,6 +102,21 @@ class _Buffer:
         with self.records_lock:
             self.records.append(record)
 
+    @classmethod
+    def discard(cls, unwanted) -> None:
+        """Drop the records no flush has delivered yet for which
+        `unwanted(record)` holds (`compile_watch.reset`)."""
+        with cls._lock:
+            buf = cls._instance
+        if buf is None:
+            return
+        with buf.records_lock:
+            buf.records = [r for r in buf.records if not unwanted(r)]
+            buf._sealed = [
+                (seq, [r for r in batch if not unwanted(r)])
+                for seq, batch in buf._sealed
+            ]
+
     def add_drain_hook(self, hook) -> None:
         """Register a callable run before each flush seals a batch
         (idempotent per hook object). Hooks push records via push();

@@ -410,18 +410,6 @@ def test_engine_death_fails_inflight_not_hangs(tiny_model):
     eng.close()
 
 
-def test_llm_server_refuses_engine_enabled_false():
-    """The keyword outlived its switch (the benchmark's driver still
-    binds `engine_enabled=True`): True or absent is the engine, False
-    names a path that no longer exists."""
-    from ray_tpu.llm.serving import LLMServer
-
-    families = {"tiny": {"kind": "init", "config": {}}}
-    LLMServer(families, engine_enabled=True)
-    with pytest.raises(ValueError, match="PR 29"):
-        LLMServer(families, engine_enabled=False)
-
-
 # ---------------------------------------------------------------------
 # dispatch ahead, retire behind (ISSUE 27): the pipelined loop streams
 # what a plain serial loop would, token for token

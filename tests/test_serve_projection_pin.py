@@ -1,5 +1,5 @@
 """A projection that feeds a head split, on the serve path: the paged
-layer computes it in two halves (`generate._qkv_flat`, the products
+attention half (`generate._paged_attend`) computes it in two halves (`generate._qkv_flat`, the products
 and what acts on a whole projection; `generate._split_heads`, the
 split and what acts on a head) and HOLDS the flat products row-major
 between them (`generate._row_major`: the compiler then reads a layer's
@@ -14,8 +14,10 @@ bit for bit in every family.
 Four families of plain attention at the sizes of their configuration
 files' `rehearsal` groups: dense with biases, an expert model that
 norms the whole projection, a norm a head, a model of two KINDS of
-layer. Latent attention under an indexer (`_latent_layer`) is NOT
+layer. Latent attention under an indexer (`_latent_attend`) is NOT
 held: its programs are the ones they were (PERF.md section 6, PR 50).
+The five families' two programs also return the pool they were given,
+leaf for leaf (the last test).
 Nothing here compiles for a described chip, and nothing lowers a
 full-size train step."""
 
@@ -40,7 +42,7 @@ FAMILIES = {
     "layer_kinds": ("mimo-v2-flash-l7-ep16", {}),
     "latent": ("deepseek-v3.2-l5-ep16", {}),
 }
-#: The families `_paged_layer` serves.
+#: The families `_paged_attend` serves.
 PAGED = ("dense_bias", "moe_proj_norm", "head_norm", "layer_kinds")
 #: The families the training layer serves.
 TRAINED = ("dense_bias", "moe_proj_norm", "head_norm")
@@ -276,8 +278,8 @@ def test_the_hold_changes_no_logit(family, monkeypatch):
 @pytest.mark.parametrize("program", PROGRAMS)
 @pytest.mark.parametrize("family", FAMILIES)
 def test_serve_programs_hold_every_head_split_projection(family, program):
-    """(c) One hold at every head-split projection of `_paged_layer`;
-    (d) none in `_latent_layer`'s programs."""
+    """(c) One hold at every head-split projection of `_paged_attend`;
+    (d) none in `_latent_attend`'s programs."""
     cfg, programs = _serve_programs(family)
     call, shapes = programs[program]
     rows, t = shapes[1].shape if program == "paged_prefill" else (
@@ -319,3 +321,46 @@ def test_training_layer_holds_nothing(family):
     names = {eqn.primitive.name for eqn in _equations(traced.jaxpr)}
     assert "dot_general" in names
     assert _hold_primitive() not in names
+
+
+#: family -> the counters its forwards leave in the pool
+COUNTERS = {
+    "dense_bias": set(),
+    "moe_proj_norm": {"moe_counts"},
+    "head_norm": set(),
+    "layer_kinds": {"moe_counts", "moe_routed"},
+    "latent": {"moe_counts", "moe_routed", "dsa_counts"},
+}
+
+
+def _leaves(tree):
+    return {name: (a.shape, a.dtype) for name, a in tree.items()}
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+@pytest.mark.parametrize("family", FAMILIES)
+def test_serve_programs_return_the_pool_they_were_given(family, program):
+    """(e) A chunk and a step give back `init_block_pool`'s tree, leaf
+    for leaf, counters included: what the pool's donation rests on (a
+    leaf of another shape is a second pool on the chip) and what the
+    engine fetches by name (`counter_leaves`). No compute."""
+    cfg, programs = _serve_programs(family)
+    call, shapes = programs[program]
+    given = shapes[2 if program == "paged_prefill" else 1]
+    first = next(iter(generate.cache_leaves(given).values()))
+    n_blocks = first.shape[1]
+    if cfg.layer_kinds:  # a pool a kind, each of its own size
+        n_blocks = {
+            kind: given[k].shape[1]
+            for kind, (k, _) in generate.KIND_LEAVES.items()
+        }
+    made = _leaves(jax.eval_shape(
+        lambda: generate.init_block_pool(cfg, n_blocks, first.shape[-2])
+    ))
+    assert set(made) & set(generate.COUNTER_LEAVES) == COUNTERS[family]
+    assert _leaves(given) == made
+    out = jax.eval_shape(call, *shapes)
+    assert _leaves(out[1]) == made
+    if program == "paged_engine_step":  # the step's fetch: copies of them
+        counted = {n: a for n, a in out[0].items() if n in COUNTERS[family]}
+        assert _leaves(counted) == {n: made[n] for n in COUNTERS[family]}
