@@ -185,7 +185,7 @@ class _Request:
         "prefix_keys", "total_blocks", "block_ids", "n_shared",
         "skip", "gen", "submitted_ns", "admitted_ts", "decoding_ts",
         "trace_parent", "serve_request_id", "table", "dispatched",
-        "window_copy",
+        "window_copy", "state_read",
     )
 
     def __init__(
@@ -229,6 +229,10 @@ class _Request:
         #: (from pages, to pages) of a prefix hit's window tail, until
         #: it is dispatched (models with window layers)
         self.window_copy = None
+        #: The snapshot's state slot a prefix hit's first chunk starts
+        #: from, until that chunk is dispatched (models with conv
+        #: layers, llm/kv_state.py)
+        self.state_read = None
         self.n_shared = 0
         self.skip = 0
         #: The request's table row on the device, [1, width], uploaded
@@ -561,6 +565,13 @@ class InferenceEngine:
             else (),
             0,
         )
+        # Where the model has conv layers (a state slot a row,
+        # llm/kv_state.py, whose `stats()` holds the snapshots'
+        # counters): what the pages alone would have let prefix hits
+        # skip, counted as beside a window pool (the rest went with an
+        # evicted snapshot).
+        if self._kv is not None and self._kv.state is not None:
+            self._window["prefix_tokens_full_hit"] = 0
         # What the expert layers did, from the picks per layer and
         # expert each paged forward leaves in the pool (MoE configs
         # only; a dense engine has none of these keys in stats()).
@@ -869,6 +880,15 @@ class InferenceEngine:
                 )
                 if self._kv.window is not None:
                     out.update(self._kv.window.stats())
+                if self._kv.state is not None:
+                    # (and the pages' bytes beside the states', held
+                    # by rows or by the prefix cache)
+                    pages = self._kv.full
+                    out.update(
+                        self._kv.state.stats(),
+                        kv_bytes_in_use=self._kv.block_bytes
+                        * (pages.used() + pages.cached()),
+                    )
         return out
 
     def close(self) -> None:
@@ -1184,10 +1204,14 @@ class InferenceEngine:
         of keys before it, the window pool still holds
         (llm/kv_window.py); all of it for a model without such
         layers."""
-        window = self._kv.window
-        if window is None or not skip:
-            return skip
-        return window.usable_skip(req.prefix_keys, skip)
+        window, state = self._kv.window, self._kv.state
+        if window is not None and skip:
+            skip = window.usable_skip(req.prefix_keys, skip)
+        if state is not None and skip:
+            # (and the conv layers: as far as a snapshot of their
+            # state is still held, llm/kv_state.py)
+            skip = state.usable_skip(req.prefix_keys, skip)
+        return skip
 
     def _gate_locked(self, req: _Request) -> bool:
         """Admission gate: can the FIFO head get its blocks NOW? The
@@ -1208,6 +1232,10 @@ class InferenceEngine:
         cached = alloc.peek_cached(req.prefix_keys, skip_blocks)
         if self._kv.window is not None and not self._kv.window.gate(
             req.prefix_keys, skip, req.total_blocks
+        ):
+            return False
+        if self._kv.state is not None and not self._kv.state.gate(
+            req.prefix_keys, skip
         ):
             return False
         return (
@@ -1243,11 +1271,17 @@ class InferenceEngine:
             )
             req.block_ids = {"full": req.block_ids, "window": ring}
             counted = self._window
-            counted["prefix_tokens_full_hit"] += full_skip
             for pool, pages in (("full", alloc), ("window", window.alloc)):
                 counted[f"{pool}_pool_used"] = max(
                     counted[f"{pool}_pool_used"], pages.used()
                 )
+        state = self._kv.state
+        if state is not None:
+            own, req.state_read = state.admit(req.prefix_keys, skip)
+            req.block_ids = {"full": req.block_ids, "state": own}
+        if "prefix_tokens_full_hit" in self._window:
+            # (what the pages alone would have let this hit skip)
+            self._window["prefix_tokens_full_hit"] += full_skip
         for name, rows in self._kv.host_rows([req.block_ids]).items():
             self._mirror[name][req.slot] = rows[0]
         if skip:
@@ -1352,6 +1386,11 @@ class InferenceEngine:
         start = req.offset
         chunk = min(self.config.prefill_chunk, req.bucket - start)
         tokens = req.padded[:, start:start + chunk]
+        # (read before a cancelled last chunk's release unpins them)
+        params = self._gens[req.gen]["params"]
+        table = req.table
+        if self._kv.state is not None:
+            table = self._state_table(req, start, chunk)
         req.offset += chunk
         last_chunk = req.offset >= req.bucket
         started = cancelled = False
@@ -1400,7 +1439,7 @@ class InferenceEngine:
         # the last whole chunk under len(prompt) — prefix skip never
         # reaches the final chunk, it is capped at len(prompt) - 1).
         fence = self._dispatch_chunk(
-            self._gens[req.gen]["params"], tokens, req.table, start,
+            params, tokens, table, start,
             slot, len(req.prompt) - 1 - start if last_chunk else 0,
             started,
         )
@@ -1412,6 +1451,28 @@ class InferenceEngine:
             self._patch_slot(slot, self._null_row)
         self._inflight.append(
             _ChunkInFlight(req if started else None, fence, t0)
+        )
+
+    def _state_table(self, req: _Request, start: int, chunk: int):
+        """The `table` of the chunk over [start, start + chunk) of
+        `req`, a model with conv layers: the row's pages and, of its
+        state, the slot the chunk starts from (a hit's first chunk: its
+        snapshot's; otherwise the row's own), the slot a copy goes to
+        where the chunk ends on a whole-chunk boundary of the prompt
+        (kept for later hits, kv_state.py `keep`), and how far the
+        prompt reaches into a padded last chunk."""
+        end, snapshot = start + chunk, NULL_BLOCK
+        with self._lock:
+            if (
+                self.config.prefix_cache
+                and end % self._kv.prefill_chunk == 0
+                and end <= len(req.prompt) and req.block_ids
+            ):
+                snapshot = self._kv.state.keep(req.prefix_keys, end)
+        read, req.state_read = req.state_read, None
+        return self._kv.row_table(
+            req.slot, req.block_ids, read=read, snapshot=snapshot,
+            length=min(end, len(req.prompt)), pages=req.table.full,
         )
 
     def _copy_window_pages(self, src: List[int], dst: List[int]) -> None:
@@ -1609,6 +1670,12 @@ class InferenceEngine:
 
             tables = KindTables(
                 tables, jnp.asarray(self._mirror["window_rings"])
+            )
+        if self._kv.state is not None:
+            from ..models.generate import step_state_tables
+
+            tables = step_state_tables(
+                tables, jnp.asarray(self._mirror["state_slots"])
             )
         positions = jnp.asarray(self._positions)
         # paged_decode_step donates last_logits on accelerator

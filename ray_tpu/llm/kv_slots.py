@@ -53,7 +53,7 @@ from collections import OrderedDict
 from typing import Dict, Hashable, List, Sequence
 
 from ..models.llama import LlamaConfig
-from ..models.generate import cache_leaves, init_block_pool
+from ..models.generate import STATE_LEAF, cache_leaves, init_block_pool
 
 
 def chunk_shapes(chunk: int, block_len: int) -> tuple:
@@ -309,6 +309,29 @@ class _TwoPools:
         self.window.alloc.release(blocks["window"])
 
 
+class _PagesAndState:
+    """`PagedKVCache.alloc` of a model with conv layers: a row's blocks
+    are {"full": its pages, "state": [its state slot]}, reserved and
+    released together; whoever carries them treats them as opaque."""
+
+    def __init__(self, full: BlockAllocator, state):
+        self.full, self.state = full, state
+
+    def reserve(self, n: int) -> Dict[str, List[int]]:
+        """A row of `n` blocks and its one state slot
+        (llm/kv_state.py), both or neither."""
+        if self.state.alloc.available() < 1:
+            raise BlocksExhausted("no state slot available")
+        return {
+            "full": self.full.reserve(n),
+            "state": self.state.alloc.reserve(1),
+        }
+
+    def release(self, blocks: Dict[str, List[int]]) -> None:
+        self.full.release(blocks["full"])
+        self.state.alloc.release(blocks["state"])
+
+
 class PagedKVCache:
     """The engine's shared block pool plus its geometry: block length,
     per-request logical-table width, and prompt-length buckets.
@@ -324,7 +347,10 @@ class PagedKVCache:
     attention mixed) keeps a second pool for its window layers
     (`window`, llm/kv_window.py): a row's blocks are then {"full",
     "window"}, its table a table in each pool and the state has one
-    more leaf, `window_rings`."""
+    more leaf, `window_rings`. A model with conv layers keeps a STATE
+    slot a row beside its pages (`state`, llm/kv_state.py): a row's
+    blocks are {"full", "state"}, its table `generate.StateTables`
+    and the state's one more leaf is `state_slots`."""
 
     def __init__(
         self,
@@ -375,7 +401,37 @@ class PagedKVCache:
             pool_blocks = {
                 "full": pool_blocks, "window": self.window.alloc.n_blocks
             }
+        #: The conv layers' state slots, where the model has such layers.
+        self.state = None
+        if any(k.conv for k in cfg.layer_kinds):
+            from .kv_state import StateSlots
+
+            if self.window is not None:
+                raise ValueError(
+                    "window layers and conv layers in one model: no cache "
+                    "holds both a ring and a state slot a row"
+                )
+            self.state = StateSlots(
+                self.block_len, self.prefill_chunk, slots, int(n_blocks)
+            )
+            self.alloc = _PagesAndState(self.full, self.state)
+            # (the leaf's slot axis in whole 16-row tiles, so that its
+            # rows are the leaf as it lies: `init_block_pool`)
+            pool_blocks = {
+                "full": pool_blocks,
+                "conv": -(-self.state.alloc.n_blocks // 16) * 16,
+            }
         self._pool = init_block_pool(cfg, pool_blocks, self.block_len)
+        if self.state is not None:
+            states = self._pool[STATE_LEAF]
+            self.state.slot_bytes = states.nbytes // states.shape[2]
+        #: Bytes of one block of pages over every leaf that holds pages
+        #: (for the engine's `kv_bytes_in_use`).
+        self.block_bytes = sum(
+            leaf.nbytes // leaf.shape[1]
+            for name, leaf in cache_leaves(self._pool).items()
+            if name != STATE_LEAF
+        )
 
     @classmethod
     def for_engine(
@@ -396,30 +452,56 @@ class PagedKVCache:
         `alloc.reserve` gave the row in slot i (None or empty: no row):
         `tables` [n, max_blocks], the null block past a row's end and
         in a slot that holds none; with a window pool `window_rings`
-        [n, ring] beside it, logical block j at entry j mod ring."""
+        [n, ring] beside it, logical block j at entry j mod ring; with
+        state slots `state_slots` [n, 1], a row's own."""
         import numpy as np
 
         rows = {"tables": (self.max_blocks, "full")}
         if self.window is not None:
             rows["window_rings"] = (self.window.ring, "window")
+        if self.state is not None:
+            rows["state_slots"] = (1, "state")
         out = {}
         for name, (width, part) in rows.items():
             table = np.full((len(blocks), width), NULL_BLOCK, np.int32)
             for slot, ids in enumerate(blocks):
                 if ids:
-                    ids = ids if self.window is None else ids[part]
+                    ids = ids[part] if isinstance(ids, dict) else ids
                     table[slot, :len(ids)] = ids
             out[name] = table
         return out
 
-    def row_table(self, slot: int, blocks):
+    def row_table(
+        self, slot: int, blocks, read=None, snapshot: int = NULL_BLOCK,
+        length: int = -1, pages=None,
+    ):
         """What `paged_prefill` takes as `table` and `patch_step_slot`
         as `table_row` for the row in `slot` (one program serves every
         slot, so it is not read) whose blocks are `blocks`: [1,
-        max_blocks], or with a window pool `generate.KindTables`."""
+        max_blocks], or with a window pool `generate.KindTables`. With
+        state slots `generate.StateTables`: the row starts from its own
+        slot or the one given as `read` (a snapshot's), leaves its state
+        in its own and a copy in `snapshot`, and `length` is its own
+        length where the chunk may be padded (-1: not given); `pages`
+        is the row's table of pages where the device has it already
+        (an earlier `row_table(...).full`: a table a chunk then uploads
+        four numbers)."""
         import jax.numpy as jnp
 
         rows = self.host_rows([blocks])
+        if self.state is not None:
+            import numpy as np
+
+            from ..models.generate import StateTables
+
+            own = int(rows["state_slots"][0, 0])
+            return StateTables(
+                jnp.asarray(rows["tables"]) if pages is None else pages,
+                jnp.asarray(np.asarray(
+                    [[own if read is None else read, own, snapshot, length]],
+                    np.int32,
+                )),
+            )
         if self.window is None:
             return jnp.asarray(rows["tables"])
         from ..models.generate import KindTables
@@ -448,7 +530,7 @@ class PagedKVCache:
         """Register a row's full prompt blocks past the `n_shared` it
         took from the cache itself, for later prefix hits; first writer
         wins on races."""
-        ids = blocks if self.window is None else blocks["full"]
+        ids = blocks["full"] if isinstance(blocks, dict) else blocks
         for i in range(n_shared, len(prefix_keys)):
             self.full.register(ids[i], prefix_keys[i])
 

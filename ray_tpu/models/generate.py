@@ -18,6 +18,9 @@ once per shape:
 The pool is [layers, blocks, kv_heads, block_len, head_dim]; a row's
 block table maps its logical blocks to pool blocks (llm/kv_slots.py
 owns the allocator and the refcounts; this module owns the compute).
+A layer that is no attention (a gated short convolution) keeps a STATE
+that belongs to a row and not to a page, in slots of a leaf of the same
+pool (`_conv_mix`, llm/kv_state.py).
 """
 
 from __future__ import annotations
@@ -136,11 +139,33 @@ class KindTables(NamedTuple):
     window: jax.Array  # [b, ring]: logical block j at entry j mod ring
 
 
+class StateTables(NamedTuple):
+    """A row's (or every slot's) table of pages and its STATE slots,
+    of a model with conv layers (`AttnKind.conv`): what `paged_prefill`
+    and `patch_step_slot` take as the row's table. The state of a conv
+    layer is a row's, not a page's: a slot of the pool's `conv_state`
+    holds the columns before the row's next position, and the forward
+    is told which slot it starts from, which it leaves the state in,
+    and which one more takes a copy (a SNAPSHOT at a whole-chunk
+    boundary, for the prefix cache: llm/kv_state.py). In the step's
+    state a row's own slot lies under `state_slots`, beside `tables`."""
+
+    full: jax.Array  # [b, width]
+    #: [b, 4] int32: the slot read, the slot written, the slot a
+    #: snapshot goes to (0, the null slot: none), and the row's length
+    #: (-1: not given; the chunk's tokens then end at its last nonzero
+    #: token, `_state_plan`)
+    conv: jax.Array
+
+
 #: The pool's k and v leaves of each cache of plain attention
 #: (`AttnKind.cache`): the full-attention layers' under the names every
 #: model of one kind has, a `layer_kinds` model's window layers' beside
-#: them.
+#: them. Where a head's key and value fit ONE row of lanes together
+#: (`_joint_kv`) they are one leaf, the first name with a `v` behind.
 KIND_LEAVES = {"full": ("k", "v"), "window": ("window_k", "window_v")}
+#: The pool's leaf of the conv layers' states.
+STATE_LEAF = "conv_state"
 
 
 class _Cache(NamedTuple):
@@ -156,6 +181,9 @@ class _Cache(NamedTuple):
     rotary: tuple  # (dims, base) of the rotary embedding its layers apply
     out_scope: str  # what a trace names its layers' residual through `wo`
     window: int = 0  # keys a query sees (0: all before it)
+    #: >0: no pages at all. Its leaves are [layers, state, slots, width],
+    #: this many columns a slot (a conv layer's `taps - 1`).
+    state: int = 0
 
 
 def _pool_plan(cfg: LlamaConfig):
@@ -191,26 +219,48 @@ def _pool_plan(cfg: LlamaConfig):
             "full": (AttnKind(0, cfg.n_kv_heads, cfg.rope_theta), every)
         }
         lanes = _lanes if cfg.layer_kinds else int
-        caches = {
-            name: _Cache(
+        widths = (cfg.head_dim, cfg.v_head_dim or cfg.head_dim)
+        caches = {}
+        for name, (kind, layers) in kinds.items():
+            if kind.conv:
+                caches[name] = _Cache(
+                    tuple(layers), {STATE_LEAF: ((), cfg.dim)},
+                    partial(_conv_mix, kind=kind), 1, (0, 0.0),
+                    "layer/conv_out", state=kind.conv - 1,
+                )
+                continue
+            leaves = dict(zip(KIND_LEAVES[name], map(lanes, widths)))
+            if _joint_kv(cfg):
+                leaves = {KIND_LEAVES[name][0] + "v": _lanes(sum(widths))}
+            caches[name] = _Cache(
                 tuple(layers),
-                {leaf: ((kind.kv_heads,), lanes(width)) for leaf, width in zip(
-                    KIND_LEAVES[name],
-                    (cfg.head_dim, cfg.v_head_dim or cfg.head_dim),
-                )},
+                {leaf: ((kind.kv_heads,), width)
+                 for leaf, width in leaves.items()},
                 partial(_paged_attend, kind=kind),
                 cfg.n_heads // kind.kv_heads,
                 (cfg.rotary_dim or cfg.head_dim, kind.rope_theta),
                 "layer/attn_out", kind.window,
             )
-            for name, (kind, layers) in kinds.items()
-        }
     if cfg.moe_experts:
         expert_layers = cfg.n_layers - cfg.dense_layers
         counters["moe_counts"] = (expert_layers, cfg.moe_experts)
         if cfg.kv_lora_rank or cfg.layer_kinds:
             counters["moe_routed"] = (expert_layers,)
     return caches, counters
+
+
+def _joint_kv(cfg: LlamaConfig) -> bool:
+    """Whether a head's key and value lie side by side in ONE pool
+    entry, `[v | k]`: a `layer_kinds` model whose keys are as wide as
+    its values and fit one row of lanes with them (heads of 64). Each
+    padded to lanes of its own they would take twice the bytes a token;
+    together they take what they are, the queries meet the entry behind
+    zeros where the value lies, and the values are the entry's leading
+    dims (`_paged_attention`'s `v_width`, as a latent entry is key and
+    value in one). A model whose values are narrower than its keys
+    (MiMo-V2) keeps a leaf each, as it has."""
+    alike = cfg.v_head_dim in (0, cfg.head_dim)
+    return bool(cfg.layer_kinds) and alike and 2 * cfg.head_dim <= 128
 
 
 def init_block_pool(
@@ -243,16 +293,30 @@ def init_block_pool(
     of them (llm/kv_window.py has the bookkeeping). A plain number of
     blocks gives both kinds that many: a caller that keeps ONE id
     space and hands every kind a row's one full-width table. Counters
-    as a latent pool's: an entry an expert layer."""
+    as a latent pool's: an entry an expert layer. Heads of 64 keep
+    key and value in one entry, leaf `kv` (`_joint_kv`).
+
+    Conv layers (`AttnKind.conv`) keep no pages: `conv_state` [conv
+    layers, taps - 1, `n_blocks["conv"]` state slots, dim], a row's
+    columns before its next position in a slot of its own and the
+    prefix cache's snapshots in further slots (llm/kv_state.py); slot
+    0 is the null slot. (The slots are the rows of a plane a column:
+    with a whole number of 16-row tiles of them, `PagedKVCache` sees
+    to that, the leaf seen as rows of `dim` is the leaf as it lies; as
+    [.., slots, 2, dim] the chip pads the 2 to a tile and re-lays the
+    whole leaf around every layer's write, 46 % of the device's time in
+    PR 52's first traced run.) A plain number gives
+    as many slots as blocks: the caller that keeps one id space, whose
+    rows' slots are their tables' first block ids (`_state_plan`)."""
     caches, counters = _pool_plan(cfg)
     pool = {}
     for name, cache in caches.items():
         blocks = n_blocks[name] if isinstance(n_blocks, dict) else n_blocks
         for leaf, (heads, width) in cache.leaves.items():
-            pool[leaf] = jnp.zeros(
-                (len(cache.layers), blocks, *heads, block_len, width),
-                cfg.dtype,
-            )
+            shape = (blocks, *heads, block_len, width)
+            if cache.state:
+                shape = (cache.state, blocks, width)
+            pool[leaf] = jnp.zeros((len(cache.layers), *shape), cfg.dtype)
     for name, shape in counters.items():
         pool[name] = jnp.zeros(shape, jnp.int32)
     return pool
@@ -610,12 +674,31 @@ def _paged_attend(cfg: LlamaConfig, h, layer, cache, plan, at, *, kind):
     (the attention's output [b, heads, t, value width] before `wo`,
     the cache, no counts of its own)."""
     k_name, v_name = KIND_LEAVES[kind.cache]
-    k_pool, v_pool = cache[k_name], cache[v_name]
     with jax.named_scope("layer/attn_qkv"):
         flat = _row_major(h, _qkv_flat(cfg, h, layer, kind))
         q, k, v = _split_heads(cfg, *flat, layer, kind)
         q = apply_rotary(q, plan["cos"], plan["sin"])
         k = apply_rotary(k, plan["cos"], plan["sin"])
+    if k_name + "v" in cache:
+        # Key and value in one entry, `[v | k]` (`_joint_kv`): one
+        # write, and the queries behind zeros where the value lies.
+        pool = cache[k_name + "v"]
+        v_width = v.shape[-1]
+        with jax.named_scope("paged/scatter_kv"):
+            entry = _pad_last(jnp.concatenate([v, k], -1), pool.shape[-1])
+            pool = _paged_write(pool, at, plan["tables"], plan["q_pos"], entry)
+            q = _pad_last(
+                jnp.concatenate([jnp.zeros_like(q[..., :v_width]), q], -1),
+                pool.shape[-1],
+            )
+        with jax.named_scope(f"attn/{kind.cache}"):
+            out = _paged_attention(
+                q, pool, pool, at, plan["work"], plan["n_trips"],
+                scale=cfg.head_dim ** -0.5, v_width=v_width,
+                window=kind.window, sink=layer.get("sink"),
+            )
+        return out, {**cache, k_name + "v": pool}, {}
+    k_pool, v_pool = cache[k_name], cache[v_name]
     # Write BEFORE attention so the chunk attends to its own tokens
     # (prefill self-attention).
     with jax.named_scope("paged/scatter_kv"):
@@ -966,6 +1049,104 @@ def _latent_attend(cfg: LlamaConfig, h, layer, cache, plan, layer_idx):
     return out, cache, counts
 
 
+# ---------------------------------------------------------------------
+# Gated short convolutions among attention layers (LFM2: `layer_types`
+# "conv"). Such a layer has no keys. With n the normed activation:
+# [B | C | u] = n W_in, z = B * u, c_t = sum_j w_j z_(t - (taps-1) + j)
+# (a depthwise causal convolution over positions, `taps` of them, z zero
+# before the row's first token), and the mixer's output is (C * c) W_out.
+# All a later position needs of a row is z at its last `taps - 1`
+# positions: a STATE of [taps - 1, dim] numbers a layer, whatever the
+# row's length, kept in a slot of the pool's `conv_state` that belongs
+# to the row (llm/kv_state.py has the bookkeeping). A forward reads the
+# columns where it starts, convolves its chunk or its one position, and
+# leaves the columns before the row's next position: at the row's last
+# VALID position, not at a padded chunk's end.
+# ---------------------------------------------------------------------
+
+
+def _state_plan(table, tokens, q_pos):
+    """What the conv layers of a paged forward are told, made once for
+    all of them: -> dict of `read` [b] (the slot a row's columns are
+    read from), `write` [b, 2] (the slots they are left in: the row's
+    own and a snapshot's, the null slot 0 for none), `fresh` [b] (a row
+    that starts at position 0 starts from zeros) and `n_valid` [b] (how
+    many of the forward's positions are the row's).
+
+    `table` is a `StateTables`, or a PLAIN table of pages [b, width]:
+    the caller that keeps one id space for every cache
+    (`init_block_pool` with a plain number), whose row's slot is its
+    table's first block id; it keeps no snapshot and gives no length.
+    A step's one position is always the row's (a dead row's slots are
+    the null slot). Of a chunk, where no length is given, the row's
+    tokens end at the last that is not 0: whoever hands a padded chunk
+    over without its length (the benchmark's probe) pads with 0 and
+    draws no 0, and the engine, whose prompts may hold any id, gives
+    the length."""
+    b, t = tokens.shape
+    if isinstance(table, StateTables):
+        read, own, snapshot, length = table.conv.T
+    else:
+        read = own = table[:, 0]
+        snapshot, length = jnp.zeros_like(own), jnp.full_like(own, -1)
+    if t == 1:
+        n_valid = jnp.ones((b,), jnp.int32)
+    else:
+        by_tokens = jnp.max(
+            jnp.where(tokens != 0, jnp.arange(1, t + 1), 0), axis=1
+        )
+        n_valid = jnp.clip(
+            jnp.where(length >= 0, length - q_pos[:, 0], by_tokens), 0, t
+        ).astype(jnp.int32)
+    return dict(
+        read=read, write=jnp.stack([own, snapshot], axis=1),
+        fresh=q_pos[:, 0] == 0, n_valid=n_valid,
+    )
+
+
+def _conv_mix(cfg: LlamaConfig, h, layer, cache, plan, at, *, kind):
+    """The mixer of a conv layer, where an attention layer has its
+    attention half: h [b, t, dim] the normed activation, `layer` its
+    weights (`wq` `wc` `wu` the thirds B, C, u of W_in, `taps`), `cache`
+    the pool's cache leaves, `plan` the forward's (`_state_plan`), `at`
+    its index into `conv_state` -> (C * c as [b, 1, t, dim], one head
+    as wide as the model before `wo` = W_out, the cache, no counts)."""
+    b, t, dim = h.shape
+    held = kind.conv - 1
+    state = cache[STATE_LEAF]  # [conv layers, held, slots, dim]
+    slots = state.shape[2]
+    # A slot's column j is row (at * held + j) * slots + slot of the
+    # state seen as rows of `dim`: read and written as rows, the form
+    # `_paged_write` scatters a token in.
+    flat, columns = state.reshape(-1, dim), at * held + jnp.arange(held)
+    f32 = jnp.float32
+    with jax.named_scope("layer/conv_in"):
+        gate = h @ layer["wc"]
+        z = (h @ layer["wq"]) * (h @ layer["wu"])
+    with jax.named_scope("layer/conv"):
+        before = jnp.where(
+            plan["fresh"][:, None, None], 0,
+            flat[columns * slots + plan["read"][:, None]],
+        ).astype(z.dtype)
+        zz = jnp.concatenate([before, z], axis=1)  # [b, held + t, dim]
+        taps = layer["taps"].astype(f32)
+        c = sum(
+            taps[j] * zz[:, j:j + t].astype(f32) for j in range(kind.conv)
+        )
+        out = gate.astype(f32) * c
+        # What the row's next position needs: the `held` columns up to
+        # its last valid one (column i of `zz` is position start - held
+        # + i), into the row's slot and the snapshot's.
+        keep = jax.vmap(
+            lambda row, n: jax.lax.dynamic_slice_in_dim(row, n, held, axis=0)
+        )(zz, plan["n_valid"]).astype(state.dtype)
+        rows = columns * slots + plan["write"][..., None]  # [b, 2, held]
+        state = flat.at[rows.reshape(-1)].set(
+            jnp.broadcast_to(keep[:, None], (b, 2, held, dim)).reshape(-1, dim)
+        ).reshape(state.shape)
+    return out[:, None], {**cache, STATE_LEAF: state}, {}
+
+
 def _serve_block(
     cfg: LlamaConfig,
     x: jax.Array,  # [b, t, dim]
@@ -1008,7 +1189,7 @@ def _serve_block(
     return x, cache, counts
 
 
-def _plans(caches, pool, tables, q_pos, valid_len, alive):
+def _plans(caches, pool, tables, q_pos, valid_len, alive, tokens):
     """What the layers of each of a model's caches (`_pool_plan`) walk
     in one paged forward, made once for all of them: -> {cache: plan},
     a plan holding the cache's `tables`, `q_pos`, `valid_len`, `work`
@@ -1018,14 +1199,19 @@ def _plans(caches, pool, tables, q_pos, valid_len, alive):
     row (`_window_view`), so it walks the tiles that hold a key some
     query of this forward can see and no other: for a step the last
     block or two of tiles, for a chunk its own and the window before
-    it."""
+    it. A cache of STATE (conv layers) has no pages and no work list:
+    its plan is the rows' slots and how many of the forward's `tokens`
+    are each row's (`_state_plan`)."""
     plans = {}
     for name, cache in caches.items():
+        if cache.state:
+            plans[name] = _state_plan(tables, tokens, q_pos)
+            continue
         shape = pool[next(iter(cache.leaves))].shape
         n_blocks, bl = shape[1], shape[-2]
         # (a plain table serves every kind: a ring as wide as the row)
         table = tables
-        if isinstance(tables, KindTables):
+        if isinstance(tables, (KindTables, StateTables)):
             table = getattr(tables, name)
         pos, valid = q_pos, valid_len
         if cache.window:
@@ -1066,15 +1252,17 @@ def _paged_forward(
     which attention half reads each, and which stacks hold the layers
     (`dense_layers`, where the model has leading dense layers, then
     `layers`; what a kind changes of the attention in a stack of its
-    own, `attn_full` / `attn_window`, beside them)."""
+    own, `attn_full` / `attn_window` / `attn_conv`, beside them)."""
     q_pos = jnp.asarray(q_pos, jnp.int32)
     valid_len = jnp.asarray(valid_len, jnp.int32)
     live = None if alive is True else alive
     caches, counters = _pool_plan(cfg)
-    plans = _plans(caches, pool, tables, q_pos, valid_len, alive)
+    plans = _plans(caches, pool, tables, q_pos, valid_len, alive, tokens)
     with jax.named_scope("embed"):
         x = embed_tokens(cfg, params, tokens)
     for name, spec in caches.items():
+        if spec.state:
+            continue  # (a conv layer turns nothing)
         plans[name]["cos"], plans[name]["sin"] = rotary_embedding(
             q_pos, *spec.rotary, cfg.rope_scaling
         )
@@ -1128,24 +1316,66 @@ def _paged_forward(
             (x, cache), counts = jax.lax.scan(
                 body, (x, cache), (sliced, jnp.arange(depth))
             )
+            parts = [counts]
         else:
-            # They alternate in kind: unrolled, each layer with its
-            # kind's plan and its place in its kind's stacks.
-            per_layer = []
-            for i in range(depth):
+            # They alternate in kind, each layer with its kind's plan
+            # and its place in its kind's stacks. Where the stack
+            # holds two or more whole PERIODS of the pattern (LFM2's
+            # conv, conv, attention, conv: ten of them) they are ONE
+            # body, a period long, scanned; what is left behind them,
+            # and a stack of under two periods (MiMo's six layers),
+            # is unrolled. A period of one layer is the scan above.
+            names = [where[first + i][0] for i in range(depth)]
+            period = next(
+                p for p in range(1, depth + 1)
+                if all(names[i] == names[i + p] for i in range(depth - p))
+            )
+            whole = depth // period if depth >= 2 * period else 0
+            #: layers of a kind in a period: how far a kind's index
+            #: into its stacks moves from one period to the next
+            per = {n: names[:period].count(n) for n in apart}
+
+            def one(carry, i, trip=0):
+                """The stack's layer `i`, `trip` periods on."""
                 name, at = where[first + i]
+                at, i = at + trip * per[name], i + trip * period
                 layer = {
                     **{n: w[at] for n, w in apart[name].items()},
                     **{n: w[i] for n, w in sliced.items()},
                     **experts,
                 }
-                x, cache, counts = block(x, cache, layer, name, at, i)
-                per_layer.append(counts)
-            counts = {
-                n: jnp.stack([c[n] for c in per_layer]) for n in per_layer[0]
-            }
-        for name, value in counts.items():
-            counted[name].append(value)
+                return block(*carry, layer, name, at, i)
+
+            def stacked(per_layer):
+                return {
+                    n: jnp.stack([c[n] for c in per_layer])
+                    for n in per_layer[0]
+                }
+
+            def trip_body(carry, trip):
+                per_layer = []
+                for i in range(period):
+                    *carry, layer_counts = one(carry, i, trip)
+                    per_layer.append(layer_counts)
+                return tuple(carry), stacked(per_layer)
+
+            parts = []
+            if whole:
+                (x, cache), scanned = jax.lax.scan(
+                    trip_body, (x, cache), jnp.arange(whole)
+                )
+                parts.append({
+                    n: v.reshape(-1, *v.shape[2:]) for n, v in scanned.items()
+                })
+            per_layer = []
+            for i in range(whole * period, depth):
+                x, cache, layer_counts = one((x, cache), i)
+                per_layer.append(layer_counts)
+            if per_layer:
+                parts.append(stacked(per_layer))
+        for part in parts:
+            for name, value in part.items():
+                counted[name].append(value)
         first += depth
     with jax.named_scope("final_norm"):
         x = model_norm(cfg, x, params["final_norm"])
@@ -1303,6 +1533,8 @@ def _paged_engine_step_impl(
     tables = state["tables"]
     if "window_rings" in state:  # a row's table in each kind's pool
         tables = KindTables(tables, state["window_rings"])
+    if "state_slots" in state:
+        tables = step_state_tables(tables, state["state_slots"])
     token, pool, last_logits = _paged_decode_step_impl(
         params, cfg, pool, tables, last_logits,
         state["positions"], alive,
@@ -1327,6 +1559,16 @@ def _paged_engine_step_impl(
         **jax.tree.map(lambda c: c + 0, counter_leaves(pool) or {}),
     }
     return fetch, pool, last_logits, state
+
+
+def step_state_tables(tables, state_slots):
+    """Every slot's pages and its own state slot (`state_slots` [slots,
+    1]) as a step takes them: a row reads and leaves its columns in its
+    own slot, keeps no snapshot, and its one position is its own."""
+    own = state_slots.astype(jnp.int32)
+    return StateTables(tables, jnp.concatenate(
+        [own, own, jnp.zeros_like(own), jnp.full_like(own, -1)], axis=1
+    ))
 
 
 _paged_engine_step_jit = None
@@ -1371,6 +1613,8 @@ def _patch_step_slot_impl(state, slot, table_row):
     rows = {"tables": table_row}
     if isinstance(table_row, KindTables):
         rows = {"tables": table_row.full, "window_rings": table_row.window}
+    if isinstance(table_row, StateTables):  # (the slot the row writes)
+        rows = {"tables": table_row.full, "state_slots": table_row.conv[:, 1:2]}
     return {
         **state,
         **{n: state[n].at[slot].set(row[0]) for n, row in rows.items()},
@@ -1395,9 +1639,9 @@ def patch_step_slot(state, slot, table_row):
 def _copy_window_pages_impl(pool, src, dst):
     return {
         **pool,
-        **{
+        **{  # (k and v, or the one leaf that holds both: `_joint_kv`)
             name: pool[name].at[:, dst].set(pool[name][:, src])
-            for name in KIND_LEAVES["window"]
+            for name in pool if name.startswith("window_")
         },
     }
 

@@ -42,16 +42,23 @@ class AttnKind(NamedTuple):
     sees, itself the last of them (0: every key up to itself), its kv
     heads, its rotary base, and whether each head has a learned SINK
     logit, which joins the softmax's denominator and carries no
-    value."""
+    value. A kind with `conv` taps is no attention at all: its mixer
+    is a gated short convolution over positions (LFM2's `conv` layers,
+    `conv_L_cache` taps), it has no keys, and what it keeps of a row
+    is the `conv - 1` columns before the next position: a STATE that
+    belongs to the row, not pages (llm/kv_state.py)."""
 
     window: int = 0
     kv_heads: int = 0
     rope_theta: float = 10000.0
     sink: bool = False
+    conv: int = 0
 
     @property
     def cache(self) -> str:
-        """Which of a row's two caches this kind's layers keep."""
+        """Which of a row's caches this kind's layers keep."""
+        if self.conv:
+            return "conv"
         return "window" if self.window else "full"
 
 
@@ -164,14 +171,17 @@ class LlamaConfig:
     dense_layers: int = 0
     dense_intermediate: int = 0
     # ---- layers of more than one kind (the serve forwards only) ----
-    #: The model's attention layers in order, an `AttnKind` each (from
-    #: a JSON file `[window, kv_heads, rope_theta, sink]`), where they
+    #: The model's layers in order, an `AttnKind` each (from a JSON
+    #: file `[window, kv_heads, rope_theta, sink]`, with the taps as a
+    #: fifth entry for a conv layer: `[0, 0, 0, false, 3]`), where they
     #: are not all alike: window and full attention mixed (MiMo-V2's
     #: `hybrid_layer_pattern`, with each kind's own `num_key_value_heads`,
-    #: `rope_theta` and sink). At most one kind with a window and one
-    #: without: each has its page pool, a row's table and the work list
-    #: in a paged forward (models/generate.py, llm/kv_window.py), and
-    #: what a kind changes of the attention leaves a stack of its own
+    #: `rope_theta` and sink), or gated short convolutions among
+    #: attention layers (LFM2's `layer_types`). At most one kind with a
+    #: window, one without and one of convolutions: each has its cache,
+    #: a row's table and the work list in a paged forward
+    #: (models/generate.py, llm/kv_window.py, llm/kv_state.py), and
+    #: what a kind changes of the mixer leaves a stack of its own
     #: (`kinds_layer_shapes`).
     #: `n_kv_heads` and `rope_theta` are then not read.
     layer_kinds: tuple = ()
@@ -206,11 +216,15 @@ class LlamaConfig:
                 )
             if len({k.cache for k in set(kinds)}) != len(set(kinds)):
                 raise ValueError(
-                    "layer_kinds: at most one kind with a window and one "
-                    "without (each has one page pool)"
+                    "layer_kinds: at most one kind with a window, one "
+                    "without and one of convolutions (each has one cache)"
                 )
             if self.kv_lora_rank:
                 raise ValueError("layer_kinds are kinds of plain attention")
+            if self.qk_norm == "proj":
+                raise ValueError(
+                    "layer_kinds: a q/k norm is a head's (qk_norm='head')"
+                )
 
     def attn_kinds(self) -> Dict[str, tuple]:
         """{cache: (its `AttnKind`, the layers of that kind)} of a
@@ -227,7 +241,8 @@ class LlamaConfig:
         if self.layer_kinds:
             raise NotImplementedError(
                 f"{what} has no layers of more than one kind "
-                "(layer_kinds: window and full attention mixed): such a "
+                "(layer_kinds: window and full attention mixed, or gated "
+                "short convolutions among attention layers): such a "
                 "configuration runs on the serve path only "
                 "(models/generate.py)"
             )
@@ -491,7 +506,18 @@ def kinds_layer_shapes(cfg: LlamaConfig) -> Dict[str, Dict]:
     `w_up` `w_down`). What a kind changes is stacked by kind,
     `attn_full/*` and `attn_window/*` over that kind's layers in order:
     `wk` -> the kind's kv heads x head_dim, `wv` -> kv heads x
-    `v_head_dim`, and where the kind has one `sink` [heads]."""
+    `v_head_dim`, where the kind has one `sink` [heads], and with
+    `qk_norm` the two norms over a head, `q_norm` `k_norm`.
+
+    A kind with `conv` taps (a gated short convolution, no attention)
+    keeps the names: its input projection `[B | C | u] = n W_in` lies
+    as its three `dim x dim` thirds, the `B` third under `layers/wq`
+    (so every layer of a stack has a first input projection there,
+    whatever its kind), `W_out` under `layers/wo`, the operator norm
+    under `layers/attn_norm`; `attn_conv/*` holds what the kind has of
+    its own: the `C` and `u` thirds `wc` `wu` and the `taps` [conv,
+    dim] (tap `conv - 1` meets the current position). Such a model's
+    heads x head_dim and heads x `v_head_dim` are both `dim`."""
     d, H, hd = cfg.dim, cfg.n_heads, cfg.head_dim
     vd = cfg.v_head_dim or hd
     E, f = cfg.moe_experts, cfg.intermediate
@@ -515,9 +541,22 @@ def kinds_layer_shapes(cfg: LlamaConfig) -> Dict[str, Dict]:
             }
     for kind, layers in cfg.attn_kinds().values():
         L, kv = len(layers), kind.kv_heads
+        if kind.conv:
+            if H * hd != d or H * vd != d:
+                raise ValueError(
+                    "a conv layer's thirds are dim x dim: heads x head_dim "
+                    f"is {H * hd}, dim {d}"
+                )
+            out["attn_conv"] = {
+                "wc": ((L, d, d), d), "wu": ((L, d, d), d),
+                "taps": ((L, kind.conv, d), kind.conv),
+            }
+            continue
         out[f"attn_{kind.cache}"] = {
             "wk": ((L, d, kv * hd), d), "wv": ((L, d, kv * vd), d),
             **({"sink": ((L, H), 0)} if kind.sink else {}),
+            **({"q_norm": ((L, hd), 0), "k_norm": ((L, hd), 0)}
+               if cfg.qk_norm else {}),
         }
     return out
 
@@ -615,10 +654,11 @@ def project_qkv(cfg: LlamaConfig, h, layer, kind: Optional[AttnKind] = None):
 
         if cfg.value_scale != 1.0:
             v = v * jnp.asarray(cfg.value_scale, v.dtype)
-        return (
-            heads(q, cfg.n_heads), heads(k, kind.kv_heads),
-            heads(v, kind.kv_heads),
-        )
+        q, k = heads(q, cfg.n_heads), heads(k, kind.kv_heads)
+        if cfg.qk_norm:  # a head's, BEFORE RoPE (as below)
+            q = rms_norm(q, layer["q_norm"], eps=cfg.norm_eps)
+            k = rms_norm(k, layer["k_norm"], eps=cfg.norm_eps)
+        return q, k, heads(v, kind.kv_heads)
     if cfg.attn_bias:
         q, k, v = q + layer["bq"], k + layer["bk"], v + layer["bv"]
     if cfg.qk_norm == "proj":

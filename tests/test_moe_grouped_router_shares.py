@@ -251,29 +251,47 @@ def test_a_list_from_a_json_file_is_a_hashable_scaling():
         LlamaConfig(dense_layers=1)
 
 
-def test_sixteen_shares_of_a_one_group_router_are_the_uncut_layer():
-    """MiMo-V2's layer (`n_group` 1, no shared expert,
-    `routed_scaling_factor` null -> 1.0): the 16 shares of EP16, each
-    the router whole and its own two of the 32 experts, add up to the
+@pytest.mark.parametrize("outputs, shares, reference", [
+    (32, 16, None), (64, 8, "lfm2_moe_ref"),
+], ids=["mimo_v2_ep16_of_32", "lfm2_ep8_4_of_64"])
+def test_the_shares_of_a_one_group_router_are_the_uncut_layer(
+    outputs, shares, reference
+):
+    """MiMo-V2's and LFM2's layer (one group, no shared expert, a
+    route scale of 1.0, top-4): the shares of EP16 / EP8, each the
+    router whole and its own `outputs / shares` experts, add up to the
     uncut layer, and every pick meets exactly one share; the uncut
     layer is the float32 loop with the 4 largest of score + bias out
-    of ALL the outputs."""
+    of ALL the outputs, and where the configuration's plain reference
+    is named, that reference's layer with every expert held."""
     def cfg(held, first=0):
         return LlamaConfig(
             vocab_size=8, dim=D, n_layers=2, n_heads=2, n_kv_heads=2,
             intermediate=F, dtype=jnp.float32, moe_experts=held,
-            moe_top_k=K, moe_router="sigmoid_groups", moe_router_experts=E,
-            moe_first_expert=first, moe_groups=1, moe_top_groups=1,
-            moe_route_scale=1.0,
+            moe_top_k=K, moe_router="sigmoid_groups",
+            moe_router_experts=outputs, moe_first_expert=first, moe_groups=1,
+            moe_top_groups=1, moe_route_scale=1.0,
         )
 
-    full, _ = _layer(9)
-    full = {k: v for k, v in full.items() if not k.startswith("shared")}
+    rng = np.random.default_rng(9 + outputs)
+
+    def w(*shape, fan):
+        return jnp.asarray(rng.normal(size=shape).astype(np.float32) * fan ** -0.5)
+
+    full = {
+        "mlp_norm": jnp.asarray(1 + 0.1 * rng.normal(size=D).astype(np.float32)),
+        "router": w(D, outputs, fan=D),
+        "router_bias": jnp.asarray(
+            0.1 * rng.normal(size=outputs).astype(np.float32)
+        ),
+        "w_gate": w(outputs, D, F, fan=D), "w_up": w(outputs, D, F, fan=D),
+        "w_down": w(outputs, F, D, fan=F),
+    }
     x = jnp.asarray(
         np.random.default_rng(10).normal(size=(1, 48, D)).astype(np.float32)
     )
-    whole, whole_counts = _ffn_only(cfg(E), x, full)
-    shares, per, picks = 16, E // 16, 0
+    whole, whole_counts = _ffn_only(cfg(outputs), x, full)
+    per, picks = outputs // shares, 0
     total = np.zeros_like(whole)
     for rank in range(shares):
         share = dict(full)
@@ -296,7 +314,7 @@ def test_sixteen_shares_of_a_one_group_router_are_the_uncut_layer():
     scores = 1.0 / (1.0 + np.exp(-(h @ np.asarray(full["router"]))))
     chosen = np.argsort(-(scores + np.asarray(full["router_bias"])), axis=1)[:, :K]
     assert np.array_equal(
-        np.bincount(chosen.ravel(), minlength=E), np.asarray(whole_counts)
+        np.bincount(chosen.ravel(), minlength=outputs), np.asarray(whole_counts)
     )
     gates = np.take_along_axis(scores, chosen, axis=1)
     gates = gates / gates.sum(axis=1, keepdims=True)
@@ -311,3 +329,21 @@ def test_sixteen_shares_of_a_one_group_router_are_the_uncut_layer():
         for t in range(len(h))
     ])
     np.testing.assert_allclose(whole[0], want, atol=2e-5)
+    if reference is None:
+        return
+    # the eight shares against the benchmark's own reference, uncut
+    import importlib
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    ref = importlib.import_module(f"benchmark.reference.{reference}")
+    numbers = ref._numbers(dict(
+        dim=D, n_heads=2, norm_eps=1e-6, moe_top_k=K, moe_route_scale=1.0,
+        moe_first_expert=0,
+    ))
+    with jax.default_matmul_precision("highest"):
+        uncut = np.asarray(ref._ffn(x[0], full, m=numbers) - x[0])
+    np.testing.assert_allclose(total[0], uncut, atol=2e-5)

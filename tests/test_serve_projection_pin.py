@@ -11,12 +11,14 @@ so THIS FILE is what keeps "the training layer and the serving layer
 use the same projection" true: the halves composed equal `project_qkv`
 bit for bit in every family.
 
-Four families of plain attention at the sizes of their configuration
+Five families of plain attention at the sizes of their configuration
 files' `rehearsal` groups: dense with biases, an expert model that
 norms the whole projection, a norm a head, a model of two KINDS of
-layer. Latent attention under an indexer (`_latent_attend`) is NOT
+attention layer, and one whose attention layers (a norm a head, under
+`layer_kinds`) stand among gated short convolutions, which have no
+head split and hold nothing. Latent attention under an indexer (`_latent_attend`) is NOT
 held: its programs are the ones they were (PERF.md section 6, PR 50).
-The five families' two programs also return the pool they were given,
+The six families' two programs also return the pool they were given,
 leaf for leaf (the last test).
 Nothing here compiles for a described chip, and nothing lowers a
 full-size train step."""
@@ -41,9 +43,12 @@ FAMILIES = {
     "head_norm": ("qwen2.5-3b", {"qk_norm": "head"}),
     "layer_kinds": ("mimo-v2-flash-l7-ep16", {}),
     "latent": ("deepseek-v3.2-l5-ep16", {}),
+    "conv_kinds": ("lfm2-24b-a2b-ep8", {}),
 }
 #: The families `_paged_attend` serves.
-PAGED = ("dense_bias", "moe_proj_norm", "head_norm", "layer_kinds")
+PAGED = (
+    "dense_bias", "moe_proj_norm", "head_norm", "layer_kinds", "conv_kinds"
+)
 #: The families the training layer serves.
 TRAINED = ("dense_bias", "moe_proj_norm", "head_norm")
 PROGRAMS = ("paged_engine_step", "paged_prefill")
@@ -137,13 +142,35 @@ def _expected_holds(cfg, rows, t):
         return sorted(
             [(rows, t, cfg.n_heads * cfg.head_dim),
              (rows, t, kind.kv_heads * cfg.head_dim),
-             (rows, t, kind.kv_heads * cfg.v_head_dim)]
-            for kind in cfg.layer_kinds
+             (rows, t, kind.kv_heads * (cfg.v_head_dim or cfg.head_dim))]
+            for kind in _traced_bodies(cfg) if not kind.conv
         )
     return [[
         (rows, t, heads * cfg.head_dim)
         for heads in (cfg.n_heads, cfg.n_kv_heads, cfg.n_kv_heads)
     ]]
+
+
+def _traced_bodies(cfg):
+    """The kinds of the layer bodies a forward of a `layer_kinds` model
+    traces, by `_paged_forward`'s rule: in each stack (the leading
+    dense layers, the others) two or more whole periods of the pattern
+    are ONE body a period long, scanned, and what is left behind them,
+    or a stack of under two periods, a body a layer."""
+    bodies, first = [], 0
+    for depth in (cfg.dense_layers, cfg.n_layers - cfg.dense_layers):
+        kinds = cfg.layer_kinds[first:first + depth]
+        first += depth
+        if not depth:
+            continue
+        period = next(
+            p for p in range(1, depth + 1)
+            if all(kinds[i].cache == kinds[i + p].cache for i in range(depth - p))
+        )
+        whole = depth // period if depth >= 2 * period else 0
+        bodies += list(kinds[:period] if whole else ())
+        bodies += list(kinds[whole * period:])
+    return bodies
 
 
 def _drawn(tree, seed):
@@ -161,7 +188,10 @@ def _drawn(tree, seed):
 def _one_layer(cfg):
     """-> (h [2, 8, dim], a layer's weights, its kind or None)."""
     params = _drawn(init_params(jax.random.PRNGKey(0), cfg), 1)
-    kind = cfg.layer_kinds[1] if cfg.layer_kinds else None
+    # (the first attention kind past layer 0: MiMo's window kind)
+    kind = next(
+        (k for k in cfg.layer_kinds[1:] if not k.conv), None
+    ) if cfg.layer_kinds else None
     # (a kind's layer: `wq` lies beside its FFN, `wk` / `wv` by kind)
     stack = {
         **params["layers"],
@@ -330,6 +360,7 @@ COUNTERS = {
     "head_norm": set(),
     "layer_kinds": {"moe_counts", "moe_routed"},
     "latent": {"moe_counts", "moe_routed", "dsa_counts"},
+    "conv_kinds": {"moe_counts", "moe_routed"},
 }
 
 
@@ -347,12 +378,15 @@ def test_serve_programs_return_the_pool_they_were_given(family, program):
     cfg, programs = _serve_programs(family)
     call, shapes = programs[program]
     given = shapes[2 if program == "paged_prefill" else 1]
-    first = next(iter(generate.cache_leaves(given).values()))
+    first = next(
+        leaf for name, leaf in generate.cache_leaves(given).items()
+        if name != generate.STATE_LEAF  # (a page's: block_len its axis -2)
+    )
     n_blocks = first.shape[1]
     if cfg.layer_kinds:  # a pool a kind, each of its own size
-        n_blocks = {
-            kind: given[k].shape[1]
-            for kind, (k, _) in generate.KIND_LEAVES.items()
+        n_blocks = {  # (a state leaf is [layers, columns, slots, dim])
+            kind: given[next(iter(cache.leaves))].shape[2 if cache.state else 1]
+            for kind, cache in generate._pool_plan(cfg)[0].items()
         }
     made = _leaves(jax.eval_shape(
         lambda: generate.init_block_pool(cfg, n_blocks, first.shape[-2])
