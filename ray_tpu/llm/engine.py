@@ -855,9 +855,10 @@ class InferenceEngine:
                 # models/generate.py registers (one wrapper a
                 # program; a second one here was credited nothing).
                 # Process-wide, like the jitted programs themselves.
-                # Each compiles once a shape: `prefill` and
-                # `finish_chunk` once for each of the last chunk's
-                # shapes, as the engine starts. Steady state after
+                # Each compiles once a shape: `prefill` once for
+                # each of the last chunk's shapes, as the engine
+                # starts, and `finish_chunk` once for them all (a
+                # chunk hands it one row). Steady state after
                 # warmup is a FIXED number —
                 # movement under traffic is a recompile bug. (A
                 # mixed-generation window runs a fifth,
@@ -1527,10 +1528,11 @@ class InferenceEngine:
         started: bool,
     ):
         """Dispatch `paged_prefill` over `tokens` [1, t], positions
-        [start, start + t) of the row whose table is `table`, and
-        `finish_chunk` behind it, which starts the row at the chunk's
-        `local` position if `started` (from the mirrors' values of
-        `slot`); neither is waited for. -> the chunk's fence."""
+        [start, start + t) of the row whose table is `table`, with its
+        head over the chunk's `local` position alone, and `finish_chunk`
+        behind it, which starts the row from those logits if `started`
+        (from the mirrors' values of `slot`); neither is waited for.
+        -> the chunk's fence."""
         from ..models.generate import (
             counter_leaves, finish_chunk, paged_prefill,
         )
@@ -1543,18 +1545,17 @@ class InferenceEngine:
             table,
             np.int32(start),
             np.int32(start + tokens.shape[1]),
+            row=np.int32(local),
         )
         self._kv.pool = pool
-        # `finish_chunk` keeps one row and the chunk's logits of every
-        # position (311 MB at qwen's chunk) are dropped here, at
-        # dispatch.
+        # `logits` is the one row `finish_chunk` may keep, [1, vocab]:
+        # the chunk ran its final norm and head for no other position.
         self._state, self._last_logits, fence = finish_chunk(
             self._state,
             self._last_logits,
             logits,
             counter_leaves(pool),
             np.int32(slot),
-            np.int32(local),
             np.bool_(started),
             self._positions[slot],
             self._budget[slot],

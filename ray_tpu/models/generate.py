@@ -1233,12 +1233,17 @@ def _plans(caches, pool, tables, q_pos, valid_len, alive, tokens):
 
 def _paged_forward(
     params, cfg: LlamaConfig, tokens, pool, tables, q_pos, valid_len,
-    alive=True,
+    alive=True, row=None,
 ):
     """tokens [b, t] at absolute positions q_pos [b, t] (consecutive
     along a row) -> (logits [b, t, vocab], new pool). `tables` maps
     each row's logical blocks to pool blocks and `valid_len` [b]
     bounds what attention may see.
+    Which positions the head is run for is the caller's to say: with
+    `row` [b] (traced, a position of the forward a row) the final norm
+    and the head see that one position of each row and the logits are
+    [b, vocab]; the walk over the layers, the pool and the counters
+    are the same.
     `alive` [b] names the rows whose key tiles attention walks (a
     dead row still computes, and sees no key). The pool is carried
     through the walk over the layers and written in place. The new
@@ -1377,6 +1382,10 @@ def _paged_forward(
             for name, value in part.items():
                 counted[name].append(value)
         first += depth
+    if row is not None:
+        x = jax.vmap(
+            lambda h, at: jax.lax.dynamic_index_in_dim(h, at, keepdims=False)
+        )(x, row)
     with jax.named_scope("final_norm"):
         x = model_norm(cfg, x, params["final_norm"])
     with jax.named_scope("lm_head"):
@@ -1387,16 +1396,21 @@ def _paged_forward(
 
 
 def _paged_prefill_impl(
-    params, cfg: LlamaConfig, tokens, pool, table, offset, valid_len
+    params, cfg: LlamaConfig, tokens, pool, table, offset, valid_len,
+    row=None,
 ):
     b, t = tokens.shape
     q_pos = (
         jnp.asarray(offset, jnp.int32)
         + jnp.broadcast_to(jnp.arange(t), (b, t))
     )
+
+    def a_row(value):
+        return jnp.broadcast_to(jnp.asarray(value, jnp.int32), (b,))
+
     return _paged_forward(
-        params, cfg, tokens, pool, table,
-        q_pos, jnp.broadcast_to(jnp.asarray(valid_len, jnp.int32), (b,)),
+        params, cfg, tokens, pool, table, q_pos, a_row(valid_len),
+        row=None if row is None else a_row(row),
     )
 
 
@@ -1404,7 +1418,8 @@ _paged_prefill_jit = None
 
 
 def paged_prefill(
-    params, cfg: LlamaConfig, tokens, pool, table, offset, valid_len
+    params, cfg: LlamaConfig, tokens, pool, table, offset, valid_len,
+    row=None,
 ):
     """Jitted chunked prefill straight into the block pool: one
     forward over `tokens` [1, chunk] at positions [offset, offset +
@@ -1413,6 +1428,10 @@ def paged_prefill(
     `offset` is traced, this compiles ONCE per (chunk, nb, model) —
     not once per prompt bucket — and a prefix-cache hit simply starts
     at a later offset with the shared blocks already in the pool.
+    -> (logits [1, chunk, vocab], pool), or with `row` (traced: a
+    position of the chunk, 0 its first) the logits [1, vocab] of that
+    one position, the only ones such a program computes: what a caller
+    that reads one row asks for (the engine: a prompt's last token).
     `pool` is donated on accelerator backends."""
     global _paged_prefill_jit
     if _paged_prefill_jit is None:
@@ -1425,7 +1444,7 @@ def paged_prefill(
             )(_paged_prefill_impl),
         )
     return _paged_prefill_jit(
-        params, cfg, tokens, pool, table, offset, valid_len
+        params, cfg, tokens, pool, table, offset, valid_len, row
     )
 
 
@@ -1668,10 +1687,10 @@ def copy_window_pages(pool, src, dst):
 
 
 def _finish_chunk_impl(
-    state, last_logits, logits, moe_counts, slot, local, last,
-    position, budget, eos,
+    state, last_logits, logits, moe_counts, slot, last, position, budget,
+    eos,
 ):
-    row = logits[0, local]
+    row = logits[0]
     last_logits = last_logits.at[slot].set(
         jnp.where(last, row, last_logits[slot])
     )
@@ -1696,19 +1715,19 @@ _finish_chunk_jit = None
 
 
 def finish_chunk(
-    state, last_logits, logits, moe_counts, slot, local, last,
-    position, budget, eos,
+    state, last_logits, logits, moe_counts, slot, last, position, budget,
+    eos,
 ):
     """What follows every `paged_prefill` chunk of the engine, in one
     small program whose scalars are all traced. If the chunk was the
-    prompt's `last`, the row starts: `last_logits[slot]` becomes the
-    chunk's logits at its `local` position (the prompt's last token)
-    and the state's row gets its `position`, `budget` and `eos` and is
-    alive. Otherwise nothing changes. -> (state, last_logits, fence):
-    the fence is ready when the chunk is, and small enough to keep
-    while the chunk's logits of every position are dropped; for a MoE
-    config it is a copy of the chunk's `moe_counts` (the pool's own is
-    donated to the next program). `last_logits` is donated on
+    prompt's `last`, the row starts: `last_logits[slot]` becomes
+    `logits` [1, vocab], the one position the chunk ran its head for
+    (`paged_prefill`'s `row`: the prompt's last token), and the
+    state's row gets its `position`, `budget` and `eos` and is alive.
+    Otherwise nothing changes. -> (state, last_logits, fence): the
+    fence is ready when the chunk is; for a MoE config it is a copy of
+    the chunk's `moe_counts` (the pool's own is donated to the next
+    program), else the row's first logit. `last_logits` is donated on
     accelerator backends."""
     global _finish_chunk_jit
     if _finish_chunk_jit is None:
@@ -1719,6 +1738,6 @@ def finish_chunk(
             ),
         )
     return _finish_chunk_jit(
-        state, last_logits, logits, moe_counts, slot, local, last,
-        position, budget, eos,
+        state, last_logits, logits, moe_counts, slot, last, position,
+        budget, eos,
     )

@@ -201,9 +201,9 @@ def test_a_started_engine_compiles_for_no_prompt_length(tiny_model):
         chunk_programs = {
             k: dict(first["compiles"][k]) for k in ("prefill", "finish_chunk")
         }
-        assert all(
-            row["distinct_shapes"] >= 3 for row in chunk_programs.values()
-        )
+        assert chunk_programs["prefill"]["distinct_shapes"] >= 3
+        # (a chunk of any shape hands `finish_chunk` one row: ISSUE 53)
+        assert chunk_programs["finish_chunk"]["distinct_shapes"] >= 1
 
         def counts():
             return {
