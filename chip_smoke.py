@@ -8,7 +8,9 @@ weights from a seed:
   device  platform, device kind and count as JAX reports them; a
           platform other than `tpu` is a failure, not a downgrade.
   kernel  `flash_attention` forward and grad against `mha_reference`,
-          compiled by Mosaic (never interpreted on a chip).
+          and the serve step's `paged_attn` over ragged rows of a block
+          pool against dense attention, at one GQA and one MHA
+          geometry, compiled by Mosaic (never interpreted on a chip).
   train   `JaxTrainer.fit` -> `make_train_step`, batch 8 x seq 2048,
           mesh over every chip; loss finite and falling, zero
           steady-state compiles. On several chips: `fsdp=n`, then
@@ -224,8 +226,82 @@ def device_and_kernel_phase(rehearse: bool) -> dict:
         )
         require(fwd_err < 0.05, f"{row['shape']}: fwd err {fwd_err}")
         require(grad_err < 0.01, f"{row['shape']}: grad err {grad_err}")
+    out["paged"] = [
+        paged_attn_row(heads, kv_heads, rehearse, device)
+        for heads, kv_heads in ((16, 2), (8, 8))
+    ]
     out["cache_hits"] = hits[0]
     return out
+
+
+def paged_attn_row(heads: int, kv_heads: int, rehearse: bool, device) -> dict:
+    """The serve step's attention kernel (ops/paged_attention.py) over
+    ragged rows of a block pool, one dead, against dense float32
+    attention over each row's own keys."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models import generate as g
+
+    rows, block, lanes = (4, 16, 128)
+    width = 8 if rehearse else 64
+    lengths = np.linspace(1, width * block, rows).astype(np.int32)
+    alive = np.arange(rows) != 1
+    n_blocks = rows * width + 1
+    rng = np.random.default_rng(heads)
+    tables = 1 + rng.permutation(rows * width).astype(np.int32).reshape(
+        rows, width
+    )
+    keys = jax.random.split(jax.random.PRNGKey(heads), 3)
+    q = 0.5 * jax.random.normal(keys[0], (rows, heads, 1, lanes), jnp.bfloat16)
+    k_pool, v_pool = (
+        0.5 * jax.random.normal(
+            key, (2, n_blocks, kv_heads, block, lanes), jnp.bfloat16
+        ) for key in keys[1:]
+    )
+
+    def kernel(q, k_pool, v_pool):
+        plan = g._paged_plan(
+            jnp.asarray(tables), jnp.asarray(lengths)[:, None] - 1,
+            jnp.asarray(lengths), jnp.asarray(alive), n_blocks, block,
+            heads // kv_heads, in_place=True,
+        )
+        return g._attend_pages(q, k_pool, v_pool, 1, plan)
+
+    t0 = time.perf_counter()
+    forward = jax.jit(kernel)
+    mosaic = "tpu_custom_call" in forward.lower(q, k_pool, v_pool).as_text()
+    got = np.asarray(forward(q, k_pool, v_pool))
+    err = 0.0
+
+    def f32(a):
+        return np.asarray(a, np.float32)
+
+    for row in np.flatnonzero(alive):
+        n = int(lengths[row])
+        k, v = (
+            f32(pool[1])[tables[row]].transpose(1, 0, 2, 3)
+            .reshape(kv_heads, -1, lanes)[:, :n] for pool in (k_pool, v_pool)
+        )
+        kv_of = np.arange(heads) // (heads // kv_heads)
+        s = np.einsum("hd,hkd->hk", f32(q)[row, :, 0], k[kv_of]) / lanes ** 0.5
+        p = np.exp(s - s.max(axis=-1, keepdims=True))
+        want = np.einsum("hk,hkd->hd", p / p.sum(-1, keepdims=True), v[kv_of])
+        err = max(err, float(np.abs(got[row, :, 0] - want).max()))
+    shape = f"{heads}/{kv_heads} heads x {width * block} keys"
+    require(
+        mosaic or device["platform"] != "tpu",
+        f"paged_attn {shape}: not lowered to a Mosaic custom call on a TPU",
+    )
+    require(err < 0.02, f"paged_attn {shape}: err {err}")
+    require(
+        bool((got[~alive] == 0).all()), f"paged_attn {shape}: a dead row's output"
+    )
+    return {
+        "shape": shape, "mosaic": mosaic, "err": err,
+        "wall_s": round(time.perf_counter() - t0, 2),
+    }
 
 
 def run_kernel_child(rehearse: bool) -> dict:
@@ -730,6 +806,12 @@ def main() -> int:
             f"flash {row['shape']}: fwd err {row['fwd_err']:.2e} grad "
             f"err {row['grad_err']:.2e} mosaic={row['mosaic']} "
             f"({row['wall_s']} s with compiles)",
+        )
+    for row in probe["paged"]:
+        say(
+            "kernel",
+            f"paged_attn {row['shape']}: err {row['err']:.2e} "
+            f"mosaic={row['mosaic']} ({row['wall_s']} s with compiles)",
         )
 
     import ray_tpu as rt

@@ -475,12 +475,15 @@ class InferenceEngine:
                 kv_block_len=ec.kv_block_len, kv_blocks=ec.kv_blocks,
             )
             self._sched = SlotScheduler(ec.slots, ec.max_waiting)
-            # Keys of one attention tile in a decode step (for the
-            # `kv_keys_read` counter).
-            from ..models.generate import paged_tile_keys
+            # Keys of one attention tile in a decode step, and whether
+            # the step's kernel reads each cache's pool where it lies
+            # (for the `kv_keys_read` counter).
+            from ..models.generate import paged_tile_keys, step_reads_in_place
 
+            self._kv_in_place = step_reads_in_place(cfg, self._kv.pool)
             self._kv_tile_keys = paged_tile_keys(
-                self._kv.block_len, self._kv.max_blocks, q_len=1
+                self._kv.block_len, self._kv.max_blocks, 1,
+                self._kv_in_place.get("full", False),
             )
         else:
             self._kv = None
@@ -1827,9 +1830,11 @@ class InferenceEngine:
     def _count_kv_keys(self, groups) -> None:
         """Add a step's `kv_keys_live` / `kv_keys_read`: one program
         per group of slots (one, or one a weight generation), each
-        over the work list of its own rows' (row, tile) pairs, a
-        tile for every slot a trip."""
-        from ..models.generate import paged_tiles_read
+        over its own rows' tiles: each alive row's whole tiles where
+        the step's kernel reads the pool in place, else the work
+        list's trips, a tile for every slot a trip
+        (`generate.paged_keys_read`)."""
+        from ..models.generate import paged_keys_read
 
         tile = self._kv_tile_keys
         valid_len = self._positions + 1
@@ -1838,9 +1843,9 @@ class InferenceEngine:
             mask = np.zeros_like(self._alive)
             mask[slots] = True
             live += int(valid_len[mask].sum())
-            read += self.config.slots * tile * int(
-                paged_tiles_read(valid_len, mask, tile)
-            )
+            read += int(paged_keys_read(
+                valid_len, mask, tile, self._kv_in_place.get("full", False)
+            ))
         if self._kv.window is not None:
             live, read = self._count_window_keys(groups, live, read)
         with self._lock:
@@ -1857,12 +1862,15 @@ class InferenceEngine:
         has layers of it: a window layer's live keys are a row's last
         `window`, so `kv_read_amplification` keeps its meaning."""
         from ..models.generate import (
-            paged_tile_keys, paged_tiles_read, window_first_key,
+            paged_keys_read, paged_tile_keys, window_first_key,
             window_view_blocks,
         )
 
         window, bl = self._kv.window.window, self._kv.block_len
-        tile = paged_tile_keys(bl, window_view_blocks(window, bl, 1), 1)
+        tile = paged_tile_keys(
+            bl, window_view_blocks(window, bl, 1), 1,
+            self._kv_in_place["window"],
+        )
         seen = self._positions + 1 - window_first_key(
             self._positions, window, bl
         )
@@ -1871,9 +1879,9 @@ class InferenceEngine:
             mask = np.zeros_like(self._alive)
             mask[slots] = True
             w_live += int(np.minimum(self._positions[mask] + 1, window).sum())
-            w_read += self.config.slots * tile * int(
-                paged_tiles_read(seen, mask, tile)
-            )
+            w_read += int(paged_keys_read(
+                seen, mask, tile, self._kv_in_place["window"]
+            ))
         layers = {
             kind.cache: len(ls)
             for kind, ls in self.cfg.attn_kinds().values()

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from .._private import compile_watch
 from ..ops.latent_expand import latent_expand
+from ..ops.paged_attention import paged_attention
 from ..ops.norms import (
     apply_rotary, layer_norm, rms_norm, rotary_embedding, yarn_mscale,
 )
@@ -69,7 +70,13 @@ def accel_donate(*argnums: int):
 # TABLE maps logical block j -> physical block id. A forward touches
 # the pool IN PLACE: the layer loop carries both arrays (all layers),
 # each layer scatters its new k/v
-# at [layer, block, :, offset], and attention walks a WORK LIST of the
+# at [layer, block, :, offset], and attention reads each alive row's
+# own TILES of table entries up to its `valid_len`, nothing of a dead
+# row. A single-token step over a pool with a kv-head axis and entries
+# of whole lanes is one Pallas kernel a layer, which reads the pages
+# where they lie, a row at a time (ops/paged_attention.py). A chunk, a
+# latent pool and a pool whose entries are not whole lanes (a DMA
+# cannot slice those) walk a WORK LIST of the
 # forward's live (row, tile) pairs — a TILE of a row's table entries
 # each, as many pairs a trip as the forward has rows — gathering the
 # pairs' pages at the pool's own dtype, once per kv head (GQA queries
@@ -359,30 +366,78 @@ def _window_view(tables, q_first, window: int, block_len: int, q_len: int):
     return jnp.take_along_axis(tables, blocks % ring, axis=1), first_key
 
 
-def paged_tile_keys(block_len: int, table_width: int, q_len: int) -> int:
+def paged_tile_keys(
+    block_len: int, table_width: int, q_len: int, in_place: bool = False
+) -> int:
     """Keys in one attention tile for a pool geometry and `q_len`
     query tokens a row: whole blocks, `PAGED_TILE_KEYS` for a
-    single-token step and twice that for a chunk, never more than a
-    row's table holds."""
-    keys = PAGED_TILE_KEYS if q_len == 1 else 2 * PAGED_TILE_KEYS
+    single-token step that walks the work list and twice that for a
+    chunk and for a step whose kernel reads the pool `in_place` (a tile
+    there is a trip of a row's loop, 0.7 us of fixed cost whatever it
+    holds: 0.66 ms a layer at 256 keys against 0.57 at 512, 64 rows of
+    2.6k keys x 8 heads), never more than a row's table holds."""
+    one = q_len == 1 and not in_place
+    keys = PAGED_TILE_KEYS if one else 2 * PAGED_TILE_KEYS
     return max(1, min(keys // block_len, table_width)) * block_len
 
 
+def paged_row_tiles(valid_len, alive, tile_keys: int):
+    """Tiles of `tile_keys` keys that a paged forward's attention reads
+    of each row [b]: an alive row's `ceil(valid_len / tile_keys)`, none
+    of a dead one whatever its stale length. THE rule of what the
+    attention walks, for traced arrays (the program: the kernel's trips
+    a row, the work list's trip count) and numpy ones of the same
+    lengths (the engine's `kv_keys_read`), so the two cannot drift."""
+    return (valid_len * alive + tile_keys - 1) // tile_keys
+
+
 def paged_tiles_read(valid_len, alive, tile_keys: int):
-    """Trips of a paged forward's attention loop. The attention walks
-    a WORK LIST of live (row, tile) pairs — each alive row's
-    `ceil(valid_len / tile_keys)` tiles, row after row — as many pairs
-    a trip as the forward has rows (so the page gather keeps the shape
-    PR 24 tuned): a trip reads `rows x tile_keys` keys, and the trips
-    are the pairs over the rows, rounded up. The one rule behind the
-    program's trip count (traced arrays) and the engine's
-    `kv_keys_read` counter (numpy arrays of the same lengths), so the
-    two cannot drift. A dead row's stale length adds no pair; all rows
-    dead reads nothing; one row (a prefill chunk) walks its own tiles,
-    one a trip."""
-    pairs = ((valid_len * alive + tile_keys - 1) // tile_keys).sum()
+    """Trips of the WORK LIST's walk (`_paged_attention`): the list
+    holds the live (row, tile) pairs (`paged_row_tiles`), row after
+    row, and a trip takes as many pairs as the forward has rows (so
+    the page gather keeps the shape PR 24 tuned): a trip reads `rows x
+    tile_keys` keys, and the trips are the pairs over the rows, rounded
+    up. All rows dead reads nothing; one row (a prefill chunk) walks
+    its own tiles, one a trip."""
+    pairs = paged_row_tiles(valid_len, alive, tile_keys).sum()
     rows = valid_len.shape[0]
     return (pairs + rows - 1) // rows
+
+
+def _reads_in_place(cache: _Cache, pool) -> bool:
+    """Whether a single-token step's attention over `cache`'s pages is
+    the kernel's (ops/paged_attention.py), which reads each alive row's
+    tiles where they lie: a pool with the kv-head axis whose entries
+    are whole lanes. What the code can see of a pool and nothing else.
+    A latent pool has no such axis (its step gathers a selection, or
+    walks the list with absorbed queries); an entry that is not whole
+    lanes (a one-kind model's head of 64) is padded to them in the
+    chip's memory, and a DMA cannot slice it."""
+    return all(
+        pool[leaf].ndim == 5 and pool[leaf].shape[-1] % 128 == 0
+        for leaf in cache.leaves
+    )
+
+
+def step_reads_in_place(cfg: LlamaConfig, pool) -> Dict[str, bool]:
+    """Cache of pages (`_pool_plan`) -> `_reads_in_place`: what the
+    engine's `kv_keys_read` asks, as the program's plans do."""
+    return {
+        name: _reads_in_place(cache, pool)
+        for name, cache in _pool_plan(cfg)[0].items() if not cache.state
+    }
+
+
+def paged_keys_read(valid_len, alive, tile_keys: int, in_place: bool):
+    """Keys a paged forward's attention reads of the pool, a layer:
+    each alive row's whole tiles where the kernel walks them
+    (`in_place`), a tile for every row of the forward a trip where the
+    work list is walked."""
+    if in_place:
+        return tile_keys * paged_row_tiles(valid_len, alive, tile_keys).sum()
+    return valid_len.shape[0] * tile_keys * paged_tiles_read(
+        valid_len, alive, tile_keys
+    )
 
 
 def _paged_work_list(
@@ -665,6 +720,30 @@ def _split_heads(cfg: LlamaConfig, q, k, v, layer, kind: AttnKind):
     return q, k, v
 
 
+def _attend_pages(
+    q, k_pool, v_pool, layer_idx, plan, *, scale=None, v_width=None,
+    window: int = 0, sink=None,
+):
+    """Attention of `q` over its rows' pages as `plan` says they are
+    walked (`_paged_plan`), `_paged_attention`'s arguments and result:
+    a plan with a work list walks it; one without (a step over a pool
+    the kernel reads in place, `_reads_in_place`) is the kernel's, a row at
+    a time over `plan["tiles"]` tiles of its table."""
+    if "work" in plan:
+        return _paged_attention(
+            q, k_pool, v_pool, layer_idx, plan["work"], plan["n_trips"],
+            scale=scale, v_width=v_width, window=window, sink=sink,
+        )
+    out = paged_attention(
+        q, k_pool, None if v_pool is k_pool else v_pool, layer_idx,
+        plan["tables"], plan["tiles"], plan["q_pos"][:, 0],
+        plan["valid_len"], tile_blocks=plan["tile_blocks"],
+        scale=q.shape[-1] ** -0.5 if scale is None else scale,
+        window=window, sink=sink,
+    )
+    return out if v_width is None else out[..., :v_width]
+
+
 def _paged_attend(cfg: LlamaConfig, h, layer, cache, plan, at, *, kind):
     """The attention half of a layer of plain attention, of `kind`:
     h [b, t, dim] the normed activation, `layer` its weights, `cache`
@@ -692,8 +771,8 @@ def _paged_attend(cfg: LlamaConfig, h, layer, cache, plan, at, *, kind):
                 pool.shape[-1],
             )
         with jax.named_scope(f"attn/{kind.cache}"):
-            out = _paged_attention(
-                q, pool, pool, at, plan["work"], plan["n_trips"],
+            out = _attend_pages(
+                q, pool, pool, at, plan,
                 scale=cfg.head_dim ** -0.5, v_width=v_width,
                 window=kind.window, sink=layer.get("sink"),
             )
@@ -712,25 +791,36 @@ def _paged_attend(cfg: LlamaConfig, h, layer, cache, plan, at, *, kind):
         k_pool = _paged_write(k_pool, at, plan["tables"], plan["q_pos"], k)
         v_pool = _paged_write(v_pool, at, plan["tables"], plan["q_pos"], v)
     with jax.named_scope(f"attn/{kind.cache}"):
-        out = _paged_attention(
-            q, k_pool, v_pool, at, plan["work"], plan["n_trips"],
+        out = _attend_pages(
+            q, k_pool, v_pool, at, plan,
             scale=scale, window=kind.window, sink=layer.get("sink"),
         )[..., :v_width]
     return out, {**cache, k_name: k_pool, v_name: v_pool}, {}
 
 
 def _paged_plan(
-    tables, q_pos, valid_len, alive, n_blocks: int, bl: int, groups: int
+    tables, q_pos, valid_len, alive, n_blocks: int, bl: int, groups: int,
+    in_place: bool = False,
 ):
     """What a paged forward's attention walks, made once for all its
     layers from `tables` [b, width], `q_pos` [b, t], `valid_len` [b]
-    and `alive` -> (tables padded to whole tiles, valid_len with a dead
-    row's 0, the work list of live (row, tile) pairs with `q_pos`
-    tiled `groups` times, the trip count)."""
+    and `alive` -> the plan: `tables` padded to whole tiles,
+    `valid_len` with a dead row's 0, `q_pos`, and either the work list
+    of live (row, tile) pairs with `q_pos` tiled `groups` times and its
+    trip count (`work`, `n_trips`), or, where a step's kernel reads the
+    pool `in_place`, each row's `tiles` of `tile_blocks` entries."""
     t, width = q_pos.shape[1], tables.shape[1]
-    tile = paged_tile_keys(bl, width, t)
+    tile = paged_tile_keys(bl, width, t, in_place)
     tile_blocks = tile // bl
-    n_trips = paged_tiles_read(valid_len, alive, tile)
+    # (the trip count before the rest, where the work list's programs
+    # have had it: they lower to the text they had)
+    if in_place:
+        walk = dict(
+            tiles=paged_row_tiles(valid_len, alive, tile),
+            tile_blocks=tile_blocks,
+        )
+    else:
+        n_trips = paged_tiles_read(valid_len, alive, tile)
     # A dead row sees no key: its stale length adds no pair to the
     # work list.
     valid_len = valid_len * alive
@@ -741,11 +831,12 @@ def _paged_plan(
     tables = jnp.pad(
         tables, ((0, 0), (0, -(width + spare) % tile_blocks + spare))
     )
-    work = _paged_work_list(
-        tables, jnp.tile(q_pos, (1, groups)), valid_len, tile_blocks,
-        bl, n_blocks,
-    )
-    return tables, valid_len, work, n_trips
+    if not in_place:
+        walk = dict(n_trips=n_trips, work=_paged_work_list(
+            tables, jnp.tile(q_pos, (1, groups)), valid_len, tile_blocks,
+            bl, n_blocks,
+        ))
+    return dict(tables=tables, q_pos=q_pos, valid_len=valid_len, **walk)
 
 
 # ---------------------------------------------------------------------
@@ -1192,8 +1283,9 @@ def _serve_block(
 def _plans(caches, pool, tables, q_pos, valid_len, alive, tokens):
     """What the layers of each of a model's caches (`_pool_plan`) walk
     in one paged forward, made once for all of them: -> {cache: plan},
-    a plan holding the cache's `tables`, `q_pos`, `valid_len`, `work`
-    and `n_trips` (`_paged_plan`). `tables` holds a row's table in
+    a plan holding the cache's `tables`, `q_pos`, `valid_len` and what
+    its attention walks (`_paged_plan`: the work list, or for a step's
+    kernel each row's tiles). `tables` holds a row's table in
     each kind's pool (`KindTables`), or is one [b, width] table for
     every cache. A window layer's plan is made from its VIEW of the
     row (`_window_view`), so it walks the tiles that hold a key some
@@ -1221,12 +1313,9 @@ def _plans(caches, pool, tables, q_pos, valid_len, alive, tokens):
             pos, valid = q_pos - first_key[:, None], valid_len - first_key
         # (the queries of a kv head's group lie side by side, as
         # `_paged_attention` lays them)
-        table, valid, work, n_trips = _paged_plan(
-            table, pos, valid, alive, n_blocks, bl, cache.groups
-        )
-        plans[name] = dict(
-            tables=table, q_pos=pos, valid_len=valid, work=work,
-            n_trips=n_trips,
+        plans[name] = _paged_plan(
+            table, pos, valid, alive, n_blocks, bl, cache.groups,
+            in_place=q_pos.shape[1] == 1 and _reads_in_place(cache, pool),
         )
     return plans
 

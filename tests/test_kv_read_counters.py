@@ -1,7 +1,8 @@
-"""How many keys a paged forward attends over (ISSUE 24, ISSUE 40): the
-rule as a pure function — the one the program's trip count and the
-engine's `kv_keys_read` both call — and the two counters in
-`engine.stats()`."""
+"""How many keys a paged forward attends over (ISSUE 24, ISSUE 40,
+ISSUE 54): the rule as a pure function — `paged_row_tiles`, the one
+the program's walk (the work list's trip count, the kernel's tiles a
+row) and the engine's `kv_keys_read` both call — and the two counters
+in `engine.stats()`."""
 
 import jax
 import jax.numpy as jnp
@@ -124,6 +125,90 @@ def test_engine_counts_keys_live_and_keys_read(
         twice = engine.stats()
         assert twice["kv_keys_live"] == 2 * once["kv_keys_live"]
         assert twice["kv_keys_read"] == 2 * once["kv_keys_read"]
+    finally:
+        engine.close()
+
+
+@pytest.mark.parametrize("slots", [16, 64])
+@pytest.mark.parametrize("in_place", [True, False], ids=["kernel", "work_list"])
+def test_the_programs_walk_and_the_engines_count_are_one_rule(
+    monkeypatch, slots, in_place
+):
+    """What the program walks (its plan, traced) and what the engine
+    counts (numpy arrays of the same lengths) both go through
+    `paged_row_tiles`, and agree: each alive row's whole tiles where
+    the kernel reads the pool in place, a tile for every row a trip of
+    the work list's."""
+    rng = np.random.default_rng(slots)
+    valid_len = rng.integers(1, MAX_LEN + 1, slots).astype(np.int32)
+    alive = rng.random(slots) < 0.8
+    calls = []
+    rule = g.paged_row_tiles
+
+    def counted(valid_len, alive, tile_keys):
+        calls.append(type(valid_len))
+        return rule(valid_len, alive, tile_keys)
+
+    monkeypatch.setattr(g, "paged_row_tiles", counted)
+    block, width = 16, MAX_LEN // 16
+    tile = g.paged_tile_keys(block, width, 1, in_place)
+    assert tile == (2 * TILE if in_place else TILE)
+    plan = jax.jit(
+        lambda valid_len, alive: {
+            name: value for name, value in g._paged_plan(
+                jnp.zeros((slots, width), jnp.int32), valid_len[:, None] - 1,
+                valid_len, alive, slots * width + 1, block, 1,
+                in_place=in_place,
+            ).items() if name in ("tiles", "n_trips")
+        }
+    )(valid_len, alive)
+    traced = len(calls)
+    assert traced == 1 and calls[0] is not np.ndarray
+    if in_place:
+        walked = tile * int(plan["tiles"].sum())
+        assert (np.asarray(plan["tiles"])[~alive] == 0).all()
+    else:
+        walked = slots * tile * int(plan["n_trips"])
+    assert int(g.paged_keys_read(valid_len, alive, tile, in_place)) == walked
+    assert len(calls) == traced + 1 and calls[-1] is np.ndarray
+    live = int(valid_len[alive].sum())
+    assert live <= walked < live + (alive.sum() + slots * (not in_place)) * tile
+
+
+def test_engine_counts_each_alive_rows_own_tiles_where_the_kernel_reads(
+    monkeypatch,
+):
+    """A pool with the kv-head axis and entries of whole lanes: the
+    step's attention is the kernel's, a row at a time, and the engine
+    counts an alive row's tiles and nothing for the other slot. Tiles
+    of 16 keys: the row's 12..17 keys are 1, 1, 1, 1, 1 and 2."""
+    from decode_oracle import greedy_uncached
+    from ray_tpu.llm import EngineConfig, InferenceEngine
+    from ray_tpu.models.llama import LlamaConfig, init_params
+
+    monkeypatch.setattr(g, "PAGED_TILE_KEYS", 8)
+    cfg = LlamaConfig(
+        vocab_size=128, dim=256, n_layers=2, n_heads=2, n_kv_heads=1,
+        intermediate=128, max_seq_len=128, dtype=jnp.float32,
+        attention="reference",
+    )
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    new = 6
+    engine = InferenceEngine(
+        params, cfg,
+        EngineConfig(slots=2, max_len=48, prefill_chunk=8, max_new_tokens=new),
+        family="tiny",
+    )
+    try:
+        assert g.step_reads_in_place(cfg, engine._kv.pool) == {"full": True}
+        prompt = list(range(1, 12))
+        got = list(engine.submit(prompt, max_new_tokens=new))
+        assert got == greedy_uncached(params, cfg, prompt, new)
+        stats = engine.stats()
+        assert stats["kv_keys_live"] == sum(
+            len(prompt) + i + 1 for i in range(new)
+        )
+        assert stats["kv_keys_read"] == 7 * 16
     finally:
         engine.close()
 

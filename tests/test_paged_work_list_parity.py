@@ -1,13 +1,17 @@
-"""The paged attention's work list (ISSUE 40) against a dense float32
-oracle: `_paged_attention` walks live (row, tile) pairs, as many a
-trip as the forward has rows, and merges each pair's softmax sums
-into its row's; the oracle gathers every row's whole table and takes
-one masked softmax. One parametrised test, GQA grouping x scenario,
-and one that the two programs the engine drives compile once however
-the live pairs change.
+"""The paged attention's two walks against a dense float64 oracle:
+`_paged_attention` walks a work list of live (row, tile) pairs (ISSUE
+40), as many a trip as the forward has rows, and merges each pair's
+softmax sums into its row's; a step over a pool the kernel reads in
+place walks each row's own tiles (ISSUE 54, ops/paged_attention.py,
+interpreted here); the oracle gathers every row's whole table and
+takes one masked softmax. One parametrised test, walk x GQA grouping x
+scenario (the kernel's cases also: key wider than value, key and value
+in one entry, a window, a sink), one that a row's output from the
+kernel is bit-equal whatever the other rows hold, and one that the two
+programs the engine drives compile once however the live pairs change.
 
 Geometry: blocks of 8 keys, rows to 128 keys (16 table entries), a
-decode tile of 32 keys (4 a row) and a chunk tile of 64."""
+decode tile of 32 keys (4 a row), and 64 for a chunk and the kernel."""
 
 import jax
 import jax.numpy as jnp
@@ -31,29 +35,45 @@ def small_tiles(monkeypatch):
     monkeypatch.setattr(g, "PAGED_TILE_KEYS", TILE)
 
 
-def dense_oracle(q, k_pool, v_pool, layer, tables, q_pos, valid_len):
+def dense_oracle(
+    q, k_pool, v_pool, layer, tables, q_pos, valid_len, *, scale=None,
+    v_width=None, window=0, sink=None,
+):
     """Plain attention, a row at a time over its whole table: float64
-    scores, one masked softmax; a row that sees no key gives zeros."""
+    scores, one masked softmax; a row that sees no key gives zeros.
+    `v_width`: the values are the entries' leading dims; `window`: a
+    query sees that many keys, itself the last; `sink` [heads]: a logit
+    more in the softmax's sum, with no value."""
     b, heads, t, hd = q.shape
     kv_heads = k_pool.shape[2]
-    out = np.zeros((b, heads, t, hd))
+    v_width = v_width or v_pool.shape[-1]
+    scale = scale or 1 / np.sqrt(hd)
+    out = np.zeros((b, heads, t, v_width))
     k_pos = np.arange(tables.shape[1] * BL)
     for row in range(b):
         # [entries, kvH, bl, hd] -> [kvH, keys, hd]
         k, v = (
             np.asarray(pool, np.float64)[layer, tables[row]]
-            .transpose(1, 0, 2, 3).reshape(kv_heads, -1, hd)
+            .transpose(1, 0, 2, 3).reshape(kv_heads, -1, pool.shape[-1])
             for pool in (k_pool, v_pool)
         )
         for head in range(heads):
             kv = head // (heads // kv_heads)
-            s = np.asarray(q, np.float64)[row, head] @ k[kv].T / np.sqrt(hd)
+            s = np.asarray(q, np.float64)[row, head] @ k[kv].T * scale
             seen = (k_pos <= q_pos[row][:, None]) & (k_pos < valid_len[row])
+            if window:
+                seen &= k_pos > q_pos[row][:, None] - window
             if not seen.any():
                 continue
             s = np.where(seen, s, -np.inf)
-            p = np.exp(s - s.max(axis=-1, keepdims=True))
-            out[row, head] = (p / p.sum(axis=-1, keepdims=True)) @ v[kv]
+            top = s.max(axis=-1, keepdims=True)
+            if sink is not None:
+                top = np.maximum(top, sink[head])
+            p = np.exp(s - top)
+            total = p.sum(axis=-1, keepdims=True)
+            if sink is not None:
+                total = total + np.exp(sink[head] - top)
+            out[row, head] = (p / total) @ v[kv][:, :v_width]
     return out
 
 
@@ -87,48 +107,137 @@ SCENARIOS = {
 }
 
 
-@pytest.mark.parametrize("scenario", sorted(SCENARIOS))
-@pytest.mark.parametrize(
-    "heads,kv_heads", [(8, 1), (4, 4)], ids=["gqa8", "mha"]
+#: A slot admitted again: rows that held long sequences hold short
+#: ones, their tables still naming the pages behind them.
+SCENARIOS["step_readmitted_slots"] = (
+    np.asarray([3, 128, 1, 40, 9, 2, 128, 17] * 2, np.int32)[:, None] - 1,
+    np.asarray([3, 128, 1, 40, 9, 2, 128, 17] * 2, np.int32),
+    np.asarray([True, True, False, True] * 4),
 )
-def test_work_list_attention_matches_the_dense_oracle(
-    heads, kv_heads, scenario
-):
-    q_pos, valid_len, alive = SCENARIOS[scenario]
+STEPS = sorted(name for name in SCENARIOS if name.startswith("step"))
+GROUPINGS = {"gqa8": (8, 1), "gqa4": (8, 2), "mha": (4, 4)}
+#: What else a pool the kernel reads can be, at 4 queries a kv head:
+#: -> (key width, value width or None for ONE `[v | k]` entry, window,
+#: whether every head has a sink).
+KINDS = {
+    "plain": (HD, HD, 0, False),
+    "key_wider_than_value": (2 * HD, HD, 0, False),
+    "key_and_value_in_one_entry": (2 * HD, None, 0, False),
+    "window": (HD, HD, 40, False),
+    "window_and_sink": (HD, HD, 40, True),
+    "sink": (HD, HD, 0, True),
+}
+CASES = [
+    # (the work list's, as they were)
+    *[("list", grouping, scenario, "plain")
+      for grouping in ("gqa8", "mha") for scenario in sorted(SCENARIOS)
+      if scenario != "step_readmitted_slots"],
+    *[("kernel", grouping, scenario, "plain")
+      for grouping in GROUPINGS for scenario in STEPS],
+    *[("kernel", "gqa4", "step_dead_rows_between_live_ones", kind)
+      for kind in KINDS if kind != "plain"],
+]
+
+
+def _inputs(grouping, scenario, kind, seed=0):
+    """-> (q, k_pool, v_pool or None, tables, sink or None)."""
+    heads, kv_heads = GROUPINGS[grouping]
+    q_pos, _, _ = SCENARIOS[scenario]
     b, t = q_pos.shape
-    rng = np.random.default_rng(b + heads)
+    k_width, v_width, _, has_sink = KINDS[kind]
+    rng = np.random.default_rng(b + heads + seed)
     n_blocks = b * WIDTH + 1
     tables = (
         1 + rng.permutation(b * WIDTH).astype(np.int32)
     ).reshape(b, WIDTH)
-    q = rng.standard_normal((b, heads, t, HD)).astype(np.float32)
+    q = rng.standard_normal((b, heads, t, k_width)).astype(np.float32)
+    if v_width is None:  # the queries behind zeros where the value lies
+        q[..., :HD] = 0
     k_pool, v_pool = (
-        rng.standard_normal((LAYERS, n_blocks, kv_heads, BL, HD)).astype(
-            np.float32
-        )
-        for _ in range(2)
+        rng.standard_normal(
+            (LAYERS, n_blocks, kv_heads, BL, width)
+        ).astype(np.float32) if width else None
+        for width in (k_width, v_width)
     )
-    tile = g.paged_tile_keys(BL, WIDTH, t)
+    sink = rng.standard_normal(heads).astype(np.float32) if has_sink else None
+    return q, k_pool, v_pool, tables, sink
+
+
+def _attend(how, grouping, rows, kind, q, k_pool, v_pool, tables, sink):
+    """The walk `how` names over `rows` (a scenario's positions,
+    lengths and alive rows), as `_paged_forward` makes its plan and
+    `_paged_attend` calls it."""
+    heads, kv_heads = GROUPINGS[grouping]
+    q_pos, valid_len, alive = rows
+    _, v_width, window, _ = KINDS[kind]
 
     @jax.jit
     def attend(q, k_pool, v_pool, tables, q_pos, valid_len, alive):
-        # As `_paged_forward` calls it: the trip count from the stale
-        # lengths and `alive`, the list from the masked lengths.
-        n_trips = g.paged_tiles_read(valid_len, alive, tile)
-        work = g._paged_work_list(
-            tables, jnp.tile(q_pos, (1, heads // kv_heads)),
-            valid_len * alive, tile // BL, BL, n_blocks,
+        plan = g._paged_plan(
+            tables, q_pos, valid_len, alive, k_pool.shape[1], BL,
+            heads // kv_heads, in_place=how == "kernel",
         )
-        return g._paged_attention(q, k_pool, v_pool, 1, work, n_trips)
+        assert ("work" in plan) == (how == "list")
+        return g._attend_pages(
+            q, k_pool, k_pool if v_pool is None else v_pool, 1, plan,
+            scale=HD ** -0.5, v_width=HD if v_pool is None else None,
+            window=window, sink=sink,
+        )
 
-    got = np.asarray(
+    return np.asarray(
         attend(q, k_pool, v_pool, tables, q_pos, valid_len, alive)
     )
-    assert got.shape == q.shape and np.isfinite(got).all()
-    want = dense_oracle(q, k_pool, v_pool, 1, tables, q_pos, valid_len)
+
+
+@pytest.mark.parametrize(
+    "how,grouping,scenario,kind", CASES, ids=["-".join(c) for c in CASES]
+)
+def test_work_list_attention_matches_the_dense_oracle(
+    how, grouping, scenario, kind
+):
+    q_pos, valid_len, alive = SCENARIOS[scenario]
+    q, k_pool, v_pool, tables, sink = _inputs(grouping, scenario, kind)
+    got = _attend(
+        how, grouping, SCENARIOS[scenario], kind, q, k_pool, v_pool, tables,
+        sink,
+    )
+    assert got.shape == (*q.shape[:3], HD) and np.isfinite(got).all()
+    want = dense_oracle(
+        q, k_pool, k_pool if v_pool is None else v_pool, 1, tables, q_pos,
+        valid_len, scale=HD ** -0.5, v_width=HD, window=KINDS[kind][2],
+        sink=sink,
+    )
     np.testing.assert_allclose(got[alive], want[alive], rtol=2e-5, atol=2e-5)
     # A dead row sees no key, whatever its stale length says.
     assert (got[~alive] == 0).all()
+
+
+def test_a_rows_output_from_the_kernel_is_its_own_whatever_the_others_hold():
+    """The work list puts a row's tiles into trips by what the rows
+    before it hold, so its sums are merged in another order when they
+    change; the kernel walks a row's tiles alone and in order: bit for
+    bit the same output beside other rows, other lengths, dead rows."""
+    grouping, scenario, other = "gqa4", "step_ragged_16_alive", (
+        "step_readmitted_slots"
+    )
+    q, k_pool, v_pool, tables, sink = _inputs(grouping, scenario, "plain")
+    among = _attend(
+        "kernel", grouping, SCENARIOS[scenario], "plain", q, k_pool, v_pool,
+        tables, sink,
+    )
+    row = 9  # 127 keys: two tiles
+    q2, _, _, tables2, _ = _inputs(grouping, other, "plain", seed=1)
+    q2[row], tables2[row] = q[row], tables[row]
+    lengths = SCENARIOS[other][1].copy()
+    lengths[row] = SCENARIOS[scenario][1][row]
+    alive = np.zeros(16, bool)
+    alive[[0, row, 15]] = True
+    alone = _attend(
+        "kernel", grouping, (lengths[:, None] - 1, lengths, alive), "plain",
+        q2, k_pool, v_pool, tables2, sink,
+    )
+    assert np.abs(among[row]).max() > 0
+    assert (alone[row] == among[row]).all()
 
 
 def test_the_engines_programs_compile_once_whatever_the_live_pairs():

@@ -6,7 +6,9 @@ the model puts around attention.
 
 With them, because this is the one file that may describe a topology,
 the serve forwards compiled for the same described chip: the paged
-decode step and prefill chunk hold ONE block pool (ISSUE 24).
+decode step and prefill chunk hold ONE block pool (ISSUE 24), and a
+step's attention is one `paged_attn` kernel a layer body at the
+benchmark's own sizes (ISSUE 54).
 
 The topology is described inside a module-scoped fixture of this file
 and nowhere else (on-chip-measurement guide, section 2): only one
@@ -17,6 +19,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -139,6 +142,10 @@ def test_paged_programs_update_the_pool_in_place(one_chip, program):
     pool = {
         "k": spec(pool_shape, cfg.dtype), "v": spec(pool_shape, cfg.dtype)
     }
+    # (the step's attention kernel asks jax.default_backend() whether
+    # to run interpreted: steered here, as the other compiles are)
+    patch = pytest.MonkeyPatch()
+    patch.setattr(jax, "default_backend", lambda: "tpu")
     if program == "paged_decode_step":
         lowered = jax.jit(
             generate._paged_decode_step_impl,
@@ -167,9 +174,90 @@ def test_paged_programs_update_the_pool_in_place(one_chip, program):
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
         compilation_cache.reset_cache()
+        patch.undo()
     one_array = 2 * int(jnp.prod(jnp.asarray(pool_shape)))
     assert memory.alias_size_in_bytes >= 2 * one_array  # donated, reused
     assert memory.temp_size_in_bytes < one_array, memory
+
+
+#: configuration -> the `paged_attn` calls its step's program holds: one
+#: a body of attention layers over a pool the kernel reads in place
+#: (`generate.step_reads_in_place`). LFM2's 38 expert layers are nine
+#: whole periods of four, scanned as one body, and an attention and a
+#: conv layer behind them; a latent pool's step has no such kernel.
+PAGED_ATTN_CALLS = {
+    "lfm2-24b-a2b-ep8": 2, "olmoe-1b-7b-l8": 1, "qwen2.5-3b": 1,
+    "deepseek-v3.2-l5-ep16": 0,
+}
+
+
+@pytest.mark.parametrize("config", sorted(PAGED_ATTN_CALLS))
+def test_the_steps_attention_is_one_kernel_a_body_that_reads_the_pool_in_place(
+    one_chip, config
+):
+    """`paged_engine_step` at a benchmark configuration's own sizes,
+    compiled for the described chip (ISSUE 54): a step's attention is
+    one Mosaic call named `paged_attn` a layer body (what
+    `breakdown.device_ops` prints it by), the donated pool is updated
+    in place around it (the kernel reads the pool the layer's write
+    returned: no copy of a leaf), and nothing has the shape of the
+    walk's page gather, `[pairs, tile_blocks, kv_heads, block_len,
+    lanes]`, which the step's program copied a trip."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from benchmark import compile_rehearsal, harness
+    from ray_tpu.models import generate
+
+    settings = harness.load_config(harness.load_manifest(), config)
+    cfg, a = compile_rehearsal.serve_arguments(settings, one_chip)
+
+    def step(params, pool, last_logits, state, key):
+        return generate._paged_engine_step_impl(
+            params, cfg, pool, last_logits, state, key, 0.0, 0
+        )
+
+    patch = pytest.MonkeyPatch()
+    patch.setattr(jax, "default_backend", lambda: "tpu")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+            *(a[n] for n in ("params", "pool", "last_logits", "state", "key"))
+        ).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+        patch.undo()
+    text = compiled.as_text()
+    calls = [
+        line.strip().split(" = ")[0].removeprefix("ROOT ").lstrip("%")
+        for line in text.splitlines()
+        if "tpu_custom_call" in line and " = " in line
+    ]
+    kernels = [c for c in calls if re.fullmatch(r"paged_attn(\.\d+)?", c)]
+    assert len(kernels) == PAGED_ATTN_CALLS[config], calls
+    in_place = generate.step_reads_in_place(cfg, a["pool"])
+    assert any(in_place.values()) == bool(kernels), in_place
+    leaves = generate.cache_leaves(a["pool"])
+    pool_bytes = sum(
+        leaf.dtype.itemsize * int(np.prod(leaf.shape))
+        for leaf in leaves.values()
+    )
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes  # donated, reused
+    smallest = min(
+        leaf.dtype.itemsize * int(np.prod(leaf.shape))
+        for leaf in leaves.values()
+    )
+    assert memory.temp_size_in_bytes < smallest, memory
+    slots, block = settings["engine"]["slots"], settings["engine"]["kv_block_len"]
+    for name, leaf in leaves.items():
+        if leaf.ndim != 5 or not kernels:
+            continue
+        _, _, kv_heads, _, lanes = leaf.shape
+        gathered = rf"\[{slots},\d+,{kv_heads},{block},{lanes}\]"
+        assert not re.search(gathered, text), (name, gathered)
 
 
 def test_expert_matmuls_are_named_ragged_dot_and_scoped(one_chip):
