@@ -8,7 +8,7 @@ weights from a seed:
   device  platform, device kind and count as JAX reports them; a
           platform other than `tpu` is a failure, not a downgrade.
   kernel  `flash_attention` forward and grad against `mha_reference`,
-          and the serve step's `paged_attn` over ragged rows of a block
+          plain and under a window, and the serve step's `paged_attn` over ragged rows of a block
           pool against dense attention, at one GQA and one MHA
           geometry, compiled by Mosaic (never interpreted on a chip).
   train   `JaxTrainer.fit` -> `make_train_step`, batch 8 x seq 2048,
@@ -55,7 +55,13 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 # the same code in seconds (four virtual devices stand in for a
 # four-chip host so the sharded paths are rehearsed too).
 REAL = {
-    "kernel_shapes": [(2048, 2048, True), (512, 2048, False)],
+    # (queries, keys, causal, window): the last two under a window, one
+    # equal to a forward block and one that cuts blocks of a sequence
+    # that is no block multiple
+    "kernel_shapes": [
+        (2048, 2048, True, 0), (512, 2048, False, 0),
+        (4096, 4096, True, 1024), (2304, 2304, True, 600),
+    ],
     "model": dict(
         vocab_size=32000, dim=1024, n_layers=24, n_heads=8,
         n_kv_heads=8, intermediate=2816, max_seq_len=2048,
@@ -68,7 +74,9 @@ REAL = {
     "new_tokens": 32,
 }
 REHEARSAL = {
-    "kernel_shapes": [(256, 256, True), (128, 256, False)],
+    "kernel_shapes": [
+        (256, 256, True, 0), (128, 256, False, 0), (256, 256, True, 96),
+    ],
     "model": dict(
         vocab_size=512, dim=64, n_layers=2, n_heads=4, n_kv_heads=4,
         intermediate=128, max_seq_len=64,
@@ -179,7 +187,7 @@ def device_and_kernel_phase(rehearse: bool) -> dict:
         )))
 
     sizes = REHEARSAL if rehearse else REAL
-    for t_q, t_k, causal in sizes["kernel_shapes"]:
+    for t_q, t_k, causal, window in sizes["kernel_shapes"]:
         q = 0.5 * jax.random.normal(
             jax.random.PRNGKey(1), (1, 2, t_q, 128), jnp.bfloat16
         )
@@ -191,11 +199,11 @@ def device_and_kernel_phase(rehearse: bool) -> dict:
             # force_pallas: the rehearsal runs the same kernel in the
             # interpreter; on a chip the flag changes nothing.
             return flash_attention(
-                a, b, b, causal=causal, force_pallas=True
+                a, b, b, causal=causal, force_pallas=True, window=window
             )
 
         def reference(a, b):
-            return mha_reference(a, b, b, causal=causal)
+            return mha_reference(a, b, b, causal=causal, window=window)
 
         def loss_of(fn):
             return lambda a, b: jnp.sum(
@@ -212,7 +220,9 @@ def device_and_kernel_phase(rehearse: bool) -> dict:
         )(q, kv)
         grad_err = max(diff(a, b) for a, b in zip(grads, ref_grads))
         row = {
-            "shape": f"{t_q}/{t_k} causal={causal}",
+            "shape": f"{t_q}/{t_k} causal={causal}" + (
+                f" window={window}" if window else ""
+            ),
             "mosaic": mosaic,
             "fwd_err": fwd_err,
             "grad_err": grad_err,

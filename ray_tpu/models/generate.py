@@ -208,6 +208,14 @@ def _pool_plan(cfg: LlamaConfig):
     `moe_counts` has an entry an expert layer; a model whose layers
     lie in stacks of their own (the first two families) holds a share
     of the experts it routes over and counts `moe_routed` beside it."""
+    for key in ("attn_gate", "post_norms"):
+        if getattr(cfg, key):
+            raise NotImplementedError(
+                f"the paged forwards (models/generate.py) have no {key}: "
+                "their block has no gate on the attention output and no "
+                "norm on a half's output; such a configuration runs on "
+                "the train path only (models/llama.py)"
+            )
     every = tuple(range(cfg.n_layers))
     counters = {}
     if cfg.kv_lora_rank:

@@ -170,7 +170,7 @@ class LlamaConfig:
     #: layers: a stack of their own, `dense_layers/*`.
     dense_layers: int = 0
     dense_intermediate: int = 0
-    # ---- layers of more than one kind (the serve forwards only) ----
+    # ---- layers of more than one kind ----
     #: The model's layers in order, an `AttnKind` each (from a JSON
     #: file `[window, kv_heads, rope_theta, sink]`, with the taps as a
     #: fifth entry for a conv layer: `[0, 0, 0, false, 3]`), where they
@@ -189,6 +189,16 @@ class LlamaConfig:
     rotary_dim: int = 0
     #: A factor on the values (MiMo-V2's `attention_value_scale`).
     value_scale: float = 1.0
+    # ---- Trinity-family keys (`layer_kinds` models; the training
+    # forward only: the paged forwards refuse them by name) ----
+    #: A sigmoid gate on the attention output, before `wo`:
+    #: `o * sigmoid(a Wg)` of the block's normed input `a` (leaf `wg`
+    #: [dim, heads x head_dim] beside `wq`).
+    attn_gate: bool = False
+    #: An RMSNorm on each half's OUTPUT before it joins the residual
+    #: (leaves `attn_post_norm`, `mlp_post_norm`), beside the norms on
+    #: the halves' inputs.
+    post_norms: bool = False
 
     def __post_init__(self):
         if self.qk_norm is True:
@@ -225,6 +235,10 @@ class LlamaConfig:
                 raise ValueError(
                     "layer_kinds: a q/k norm is a head's (qk_norm='head')"
                 )
+        elif self.attn_gate or self.post_norms:
+            raise ValueError(
+                "attn_gate and post_norms are a layer_kinds model's"
+            )
 
     def attn_kinds(self) -> Dict[str, tuple]:
         """{cache: (its `AttnKind`, the layers of that kind)} of a
@@ -235,22 +249,31 @@ class LlamaConfig:
         return out
 
     def require_plain_attention(self, what: str) -> None:
-        """The one refusal of a latent-attention configuration by the
-        code that has no equations for it (training, conversion from
-        a checkpoint): it would otherwise run other mathematics."""
-        if self.layer_kinds:
-            raise NotImplementedError(
-                f"{what} has no layers of more than one kind "
-                "(layer_kinds: window and full attention mixed, or gated "
-                "short convolutions among attention layers): such a "
-                "configuration runs on the serve path only "
-                "(models/generate.py)"
-            )
+        """The one refusal of a configuration by the code that has no
+        equations for it (training, conversion from a checkpoint): it
+        would otherwise run other mathematics. Latent attention, and
+        of a model with `layer_kinds` a kind with a sink, a kind of
+        gated short convolutions, and values narrower than the keys."""
         if self.kv_lora_rank:
             raise NotImplementedError(
                 f"{what} has no latent attention (kv_lora_rank="
                 f"{self.kv_lora_rank}): a DeepSeek-family configuration "
                 "runs on the serve path only (models/generate.py)"
+            )
+        for kind in set(self.layer_kinds):
+            if kind.sink or kind.conv:
+                raise NotImplementedError(
+                    f"{what} has window and full attention mixed "
+                    "(layer_kinds), and of those neither a kind with a "
+                    "sink logit nor one of gated short convolutions "
+                    f"(conv taps): {tuple(kind)} runs on the serve path "
+                    "only (models/generate.py)"
+                )
+        if self.layer_kinds and self.v_head_dim not in (0, self.head_dim):
+            raise NotImplementedError(
+                f"{what} has no values narrower than the keys in a "
+                f"model with layer_kinds (v_head_dim={self.v_head_dim}): "
+                "the serve path only (models/generate.py)"
             )
 
     @property
@@ -503,7 +526,11 @@ def kinds_layer_shapes(cfg: LlamaConfig) -> Dict[str, Dict]:
     (`attn_norm`, `wq` -> heads x head_dim, `wo`) beside the layer's
     FFN (`mlp_norm`; `w1` `w3` `w2` of `dense_intermediate`, or
     `router` [d, outputs], `router_bias` and the held experts' `w_gate`
-    `w_up` `w_down`). What a kind changes is stacked by kind,
+    `w_up` `w_down`, with `moe_shared_intermediate` the shared expert's
+    `shared_gate` `shared_up` `shared_down`), with `attn_gate` the
+    gate's `wg` [d, heads x head_dim] and with `post_norms` the norms
+    on the halves' outputs, `attn_post_norm` `mlp_post_norm`. What a
+    kind changes is stacked by kind,
     `attn_full/*` and `attn_window/*` over that kind's layers in order:
     `wk` -> the kind's kv heads x head_dim, `wv` -> kv heads x
     `v_head_dim`, where the kind has one `sink` [heads], and with
@@ -539,6 +566,22 @@ def kinds_layer_shapes(cfg: LlamaConfig) -> Dict[str, Dict]:
                 "wo": ((L, H * vd, d), H * vd), "mlp_norm": ((L, d), 0),
                 **ffn(L, cfg.dense_intermediate if name == "dense_layers" else f),
             }
+            # (what later families brought stands behind: a leaf's key
+            # is its place in its stack, `_init_stacks`)
+            fs = cfg.moe_shared_intermediate
+            if fs and name == "layers":
+                out[name].update({
+                    "shared_gate": ((L, d, fs), d),
+                    "shared_up": ((L, d, fs), d),
+                    "shared_down": ((L, fs, d), fs),
+                })
+            if cfg.attn_gate:
+                out[name]["wg"] = ((L, d, H * vd), d)
+            if cfg.post_norms:
+                out[name].update({
+                    "attn_post_norm": ((L, d), 0),
+                    "mlp_post_norm": ((L, d), 0),
+                })
     for kind, layers in cfg.attn_kinds().values():
         L, kv = len(layers), kind.kv_heads
         if kind.conv:
@@ -593,48 +636,62 @@ def _init_stacks(key, cfg: LlamaConfig, norm_init) -> Dict:
     return out
 
 
+#: Logical axes of every leaf a layer stack may hold, behind its
+#: leading `layers` axis (`param_annotations`).
+_LEAF_AXES = {
+    "wq": ("embed", "heads"), "wk": ("embed", "kv_heads"),
+    "wv": ("embed", "kv_heads"), "wo": ("heads", "embed"),
+    "wg": ("embed", "heads"),
+    "bq": ("heads",), "bk": ("kv_heads",), "bv": ("kv_heads",),
+    "router": ("embed", None),
+    "w_gate": ("expert", "embed", "mlp"), "w_up": ("expert", "embed", "mlp"),
+    "w_down": ("expert", "mlp", "embed"),
+    "w1": ("embed", "mlp"), "w3": ("embed", "mlp"), "w2": ("mlp", "embed"),
+    "shared_gate": ("embed", "mlp"), "shared_up": ("embed", "mlp"),
+    "shared_down": ("mlp", "embed"),
+}
+
+
 def param_annotations(cfg: LlamaConfig) -> Dict[str, Any]:
     """Logical-axis annotations matching init_params' tree: GSPMD maps
     these through PARAM_RULES (fsdp shards embed dims, tp shards
     heads/mlp/vocab)."""
     cfg.require_plain_attention("the training layout (models/llama.py)")
-    layers = {
-        "wq": annotate("layers", "embed", "heads"),
-        "wk": annotate("layers", "embed", "kv_heads"),
-        "wv": annotate("layers", "embed", "kv_heads"),
-        "wo": annotate("layers", "heads", "embed"),
-        "attn_norm": annotate("layers", None),
-        "mlp_norm": annotate("layers", None),
-    }
-    if cfg.attn_bias:
-        layers.update({
-            "bq": annotate("layers", "heads"),
-            "bk": annotate("layers", "kv_heads"),
-            "bv": annotate("layers", "kv_heads"),
-        })
-    if cfg.qk_norm:
-        layers.update({
-            "q_norm": annotate("layers", None),
-            "k_norm": annotate("layers", None),
-        })
-    if cfg.moe_experts:
-        layers.update({
-            "router": annotate("layers", "embed", None),
-            "w_gate": annotate("layers", "expert", "embed", "mlp"),
-            "w_up": annotate("layers", "expert", "embed", "mlp"),
-            "w_down": annotate("layers", "expert", "mlp", "embed"),
-        })
-    else:
-        layers.update({
-            "w1": annotate("layers", "embed", "mlp"),
-            "w3": annotate("layers", "embed", "mlp"),
-            "w2": annotate("layers", "mlp", "embed"),
-        })
-    return {
+    top = {
         "embed": annotate("vocab", "embed"),
-        "layers": layers,
         "final_norm": annotate(None),
         "lm_head": annotate("embed", "vocab"),
+    }
+    if cfg.layer_kinds:
+        # The tree `kinds_layer_shapes` plans: a norm weight or a bias
+        # (no fan in) is whole on every device.
+        return {
+            **top,
+            **{
+                stack: {
+                    leaf: annotate(
+                        "layers", *_LEAF_AXES.get(leaf, (None,) * (len(shape) - 1))
+                    )
+                    for leaf, (shape, _) in plan.items()
+                }
+                for stack, plan in kinds_layer_shapes(cfg).items()
+            },
+        }
+    leaves = ["wq", "wk", "wv", "wo", "attn_norm", "mlp_norm"]
+    if cfg.attn_bias:
+        leaves += ["bq", "bk", "bv"]
+    if cfg.qk_norm:
+        leaves += ["q_norm", "k_norm"]
+    leaves += (
+        ["router", "w_gate", "w_up", "w_down"] if cfg.moe_experts
+        else ["w1", "w3", "w2"]
+    )
+    return {
+        **top,
+        "layers": {
+            leaf: annotate("layers", *_LEAF_AXES.get(leaf, (None,)))
+            for leaf in leaves
+        },
     }
 
 
@@ -680,16 +737,70 @@ def project_qkv(cfg: LlamaConfig, h, layer, kind: Optional[AttnKind] = None):
 
 @jax.named_scope("layer/attention")
 def _attention(cfg: LlamaConfig, q, k, v, sp_axis: Optional[str],
-               mesh=None):
-    k = repeat_kv(k, cfg.n_heads // cfg.n_kv_heads)
-    v = repeat_kv(v, cfg.n_heads // cfg.n_kv_heads)
+               mesh=None, kind: Optional[AttnKind] = None):
+    """Causal attention of one layer; of a layer of `kind`
+    (`layer_kinds`) over that kind's kv heads and inside its window."""
+    kv_heads, window = (
+        (cfg.n_kv_heads, 0) if kind is None else (kind.kv_heads, kind.window)
+    )
+    k = repeat_kv(k, cfg.n_heads // kv_heads)
+    v = repeat_kv(v, cfg.n_heads // kv_heads)
     if cfg.attention == "ring" and sp_axis is not None:
+        if window:
+            raise NotImplementedError(
+                "ring attention has no window: a model with window "
+                "layers trains with attention='flash' or 'reference'"
+            )
         return ring_attention(q, k, v, sp_axis, causal=True)
     if cfg.attention == "flash":
         if mesh is not None and mesh.size > 1:
-            return flash_attention_sharded(q, k, v, mesh, causal=True)
-        return flash_attention(q, k, v, causal=True)
-    return mha_reference(q, k, v, causal=True)
+            return flash_attention_sharded(
+                q, k, v, mesh, causal=True, window=window
+            )
+        return flash_attention(q, k, v, causal=True, window=window)
+    return mha_reference(q, k, v, causal=True, window=window)
+
+
+def _kind_attention(cfg: LlamaConfig, h, layer, kind: AttnKind, rotary,
+                    sp_axis=None, mesh=None):
+    """The attention of a layer of `kind` on its normed input h
+    [b, t, dim] -> [b, heads, t, head_dim]: `layer` holds `wq` and the
+    kind's own leaves; `rotary` is the kind's (cos, sin), None for a
+    kind that turns nothing (`rope_theta` 0)."""
+    with jax.named_scope("layer/attn_qkv"):
+        q, k, v = project_qkv(cfg, h, layer, kind)
+        if rotary is not None:
+            q = apply_rotary(q, *rotary)
+            k = apply_rotary(k, *rotary)
+    return _attention(cfg, q, k, v, sp_axis, mesh, kind)
+
+
+def _kind_layer(cfg: LlamaConfig, x, layer, attend, ep_axis=None):
+    """One decoder block of a model with `layer_kinds`, a dense layer
+    or an expert layer alike (`_mlp` tells them apart by the leaves).
+    `attend(h)` is the layer's attention by its kind
+    (`_kind_attention`). -> (x, aux) as `_layer`."""
+    b, t, _ = x.shape
+    with jax.named_scope("layer/attn_qkv"):
+        h = model_norm(cfg, x, layer["attn_norm"])
+    attn = attend(h).transpose(0, 2, 1, 3).reshape(b, t, -1)
+    if cfg.attn_gate:
+        with jax.named_scope("layer/attn_gate"):
+            attn = attn * jax.nn.sigmoid(h @ layer["wg"])
+    with jax.named_scope("layer/attn_out"):
+        x = _residual(cfg, x, attn @ layer["wo"], layer, "attn_post_norm")
+    with jax.named_scope("layer/mlp"):
+        x, aux, _ = _mlp(cfg, x, layer, ep_axis)
+    return x, aux
+
+
+def _residual(cfg: LlamaConfig, x, out, layer, post_norm: str):
+    """x + out, or where the layer norms a half's OUTPUT
+    (`post_norms`) x + norm(out)."""
+    if post_norm in layer:
+        with jax.named_scope("layer/post_norm"):
+            out = model_norm(cfg, out, layer[post_norm])
+    return x + out
 
 
 def _layer(cfg: LlamaConfig, x, layer, cos, sin, sp_axis=None,
@@ -732,7 +843,8 @@ def _mlp(cfg: LlamaConfig, x, layer, ep_axis=None, live=None,
     h = model_norm(cfg, x, layer["mlp_norm"])
     if not cfg.moe_experts or "router" not in layer:
         # (a leading dense layer of an expert model has no router)
-        x = x + model_glu(cfg, h @ layer["w1"], h @ layer["w3"]) @ layer["w2"]
+        out = model_glu(cfg, h @ layer["w1"], h @ layer["w3"]) @ layer["w2"]
+        x = _residual(cfg, x, out, layer, "mlp_post_norm")
         return x, jnp.zeros((), jnp.float32), None
     moe = dict(
         k=cfg.moe_top_k,
@@ -767,7 +879,8 @@ def _mlp(cfg: LlamaConfig, x, layer, ep_axis=None, live=None,
             out = out + model_glu(
                 cfg, flat @ layer["shared_up"], flat @ layer["shared_gate"]
             ) @ layer["shared_down"]
-    return x + out.reshape(b, t, -1), aux, counts
+    x = _residual(cfg, x, out.reshape(b, t, -1), layer, "mlp_post_norm")
+    return x, aux, counts
 
 
 def forward_and_aux(
@@ -797,39 +910,143 @@ def forward_and_aux(
         positions = jnp.broadcast_to(jnp.arange(t), (b, t))
     with jax.named_scope("embed"):
         x = embed_tokens(cfg, params, tokens)
-    cos, sin = rotary_embedding(
-        positions, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
-    )
+    if cfg.layer_kinds:
+        x, aux = _kinds_layers(
+            params, x, cfg, positions, sp_axis, ep_axis, mesh
+        )
+    else:
+        cos, sin = rotary_embedding(
+            positions, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
+        )
 
-    def body(x, layer):
-        return _layer(cfg, x, layer, cos, sin, sp_axis, ep_axis, mesh)
+        def body(x, layer):
+            return _layer(cfg, x, layer, cos, sin, sp_axis, ep_axis, mesh)
 
-    if cfg.remat:
-        if cfg.remat_policy == "dots":
-            body = jax.checkpoint(
-                body,
-                policy=jax.checkpoint_policies
-                .dots_with_no_batch_dims_saveable,
-            )
-        elif cfg.remat_policy == "dots_flash":
-            body = jax.checkpoint(
-                body,
-                policy=jax.checkpoint_policies.save_from_both_policies(
-                    jax.checkpoint_policies
-                    .dots_with_no_batch_dims_saveable,
-                    jax.checkpoint_policies.save_only_these_names(
-                        "flash_out", "flash_lse"
-                    ),
-                ),
-            )
-        else:
-            body = jax.checkpoint(body)
-    x, auxs = jax.lax.scan(body, x, params["layers"])
+        x, aux = jax.lax.scan(_remat(cfg, body), x, params["layers"])
     with jax.named_scope("final_norm"):
         x = model_norm(cfg, x, params["final_norm"])
     with jax.named_scope("lm_head"):
         logits = (x @ params["lm_head"]).astype(jnp.float32)
-    return logits, jnp.sum(auxs)
+    return logits, jnp.sum(aux)
+
+
+def _remat(cfg: LlamaConfig, body):
+    """`body` under the configuration's rematerialization policy."""
+    if not cfg.remat:
+        return body
+    if cfg.remat_policy == "dots":
+        return jax.checkpoint(
+            body,
+            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+        )
+    if cfg.remat_policy == "dots_flash":
+        return jax.checkpoint(
+            body,
+            policy=jax.checkpoint_policies.save_from_both_policies(
+                jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+                jax.checkpoint_policies.save_only_these_names(
+                    "flash_out", "flash_lse"
+                ),
+            ),
+        )
+    return jax.checkpoint(body)
+
+
+def _kinds_layers(params, x, cfg: LlamaConfig, positions, sp_axis,
+                  ep_axis, mesh):
+    """The layers of a model with `layer_kinds` on x [b, t, dim] ->
+    (x, the summed auxiliary loss).
+
+    The leading dense stack, then the expert stack, each ONE scan over
+    its layers, as the all-alike path's: the stack's leaves
+    (`dense_layers/*`, `layers/*`) are the scan's `xs`, so a layer's
+    weights reach its block as the scan hands them out and their
+    gradients come back stacked, and the body is `_kind_layer` under
+    the remat policy. What a layer's KIND changes is its attention
+    alone, and that alone is chosen inside the body: a `lax.switch`
+    over the kinds the stack holds around `_kind_attention`, each
+    branch with its kind's window, kv heads, rotary tables (none for a
+    kind that turns nothing) and its own leaves, read from the kind's
+    small stacks (`attn_window/*`, `attn_full/*`: `wk`, `wv`, the q/k
+    norms) at the layer's index into its kind. One trace of the block
+    and one of each kind's attention, whatever the depth and the
+    pattern.
+
+    Why not as `generate._paged_forward` walks this tree, a scan over
+    whole PERIODS whose body slices the period's layers out of its
+    `xs`, and why the switch is no wider than the attention: by the
+    compiler's own count of the step's memory at Trinity-Mini's nine
+    layers (`benchmark/compile_rehearsal.py`, a described v5e, PR 55)
+    the periods' scan takes 20.3 GB, a switch around the whole block
+    19.1 GB and this loop 15.6 GB (the count runs some 5 GB over what
+    the chip then reads, 10.9 GB for this loop, so the first two would
+    not have fitted 16). A slice is a value the body computes, so
+    `jax.checkpoint` keeps it and the scan stacks it; and the backward
+    pass of a `switch` keeps the residuals of EVERY branch, zeros for
+    the ones not taken: around a whole expert layer that is the
+    layer's rows twice."""
+    kinds = cfg.attn_kinds()
+    names = list(kinds)
+    of_kind = {name: params[f"attn_{name}"] for name in names}
+    rotary = {
+        name: rotary_embedding(
+            positions, cfg.rotary_dim or cfg.head_dim, kind.rope_theta,
+            cfg.rope_scaling,
+        ) if kind.rope_theta else None
+        for name, (kind, _) in kinds.items()
+    }
+    #: layer -> (its kind's place in `names`, its index into that kind)
+    where = {
+        layer: (which, at)
+        for which, (_, layers) in enumerate(kinds.values())
+        for at, layer in enumerate(layers)
+    }
+
+    def attention_of(name):
+        def attend(h, wq, of_kind, at):
+            own = {
+                leaf: jax.lax.dynamic_index_in_dim(w, at, keepdims=False)
+                for leaf, w in of_kind[name].items()
+            }
+            return _kind_attention(
+                cfg, h, {"wq": wq, **own}, kinds[name][0], rotary[name],
+                sp_axis, mesh,
+            )
+
+        return attend
+
+    auxs = []
+    first = 0
+    for stack in (params.get("dense_layers"), params.get("layers")):
+        if stack is None:
+            continue
+        depth = stack["attn_norm"].shape[0]
+        which, at = zip(*(where[first + i] for i in range(depth)))
+        present = sorted(set(which))
+        branches = [attention_of(names[k]) for k in present]
+
+        @partial(_remat, cfg)
+        def block(x, layer, of_kind, which, at, branches=branches):
+            def attend(h):
+                if len(branches) == 1:
+                    return branches[0](h, layer["wq"], of_kind, at)
+                return jax.lax.switch(
+                    which, branches, h, layer["wq"], of_kind, at
+                )
+
+            return _kind_layer(cfg, x, layer, attend, ep_axis)
+
+        def body(x, xs, block=block):
+            return block(x, xs[0], of_kind, *xs[1:])
+
+        x, aux = jax.lax.scan(body, x, (
+            stack,
+            jnp.asarray([present.index(k) for k in which], jnp.int32),
+            jnp.asarray(at, jnp.int32),
+        ))
+        auxs.append(jnp.sum(aux))
+        first += depth
+    return x, sum(auxs)
 
 
 def forward(

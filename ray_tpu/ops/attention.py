@@ -46,9 +46,10 @@ def _out_struct(shape, dtype, like) -> jax.ShapeDtypeStruct:
     return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
-def _mask_logits(s, qi, ki, block_q, block_k, causal, kv_len):
+def _mask_logits(s, qi, ki, block_q, block_k, causal, kv_len, window=0):
     """Mask out-of-range KV columns (sequence padded to block
-    multiples) and, when causal, future positions."""
+    multiples) and, when causal, future positions; with a window the
+    keys that lie `window` or more before the query."""
     rows = jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0
     ) + qi * block_q
@@ -58,6 +59,8 @@ def _mask_logits(s, qi, ki, block_q, block_k, causal, kv_len):
     valid = cols < kv_len
     if causal:
         valid = jnp.logical_and(valid, rows >= cols)
+    if window:
+        valid = jnp.logical_and(valid, rows - cols < window)
     return jnp.where(valid, s, DEFAULT_MASK_VALUE)
 
 
@@ -94,7 +97,26 @@ def _init_bias_tile(bias_ref, first_step) -> None:
         ).astype(bias_ref.dtype)
 
 
-def _block_needs_mask(qi, ki, block_q, block_k, causal, kv_len):
+def _block_runs(qi, ki, block_q, block_k, causal, window=0):
+    """Traced predicate (True where nothing is ever skipped): does
+    the (qi, ki) tile hold a pair the mask lets through? Causal: its
+    last query row is not before its first key; with a window also
+    its first query row sees its last key."""
+    run = True
+    if causal:
+        run = qi * block_q + block_q - 1 >= ki * block_k
+    if window:
+        run &= qi * block_q - (ki * block_k + block_k - 1) < window
+    return run
+
+
+def _first_key_block(qi, block_q, block_k, window):
+    """The first key block query block `qi` sees under `window`:
+    floor((qi * block_q - window + 1) / block_k), not under 0."""
+    return jnp.maximum(qi * block_q - window + 1, 0) // block_k
+
+
+def _block_needs_mask(qi, ki, block_q, block_k, causal, kv_len, window=0):
     """Traced predicate: does this (qi, ki) tile need logit masking?
     Returns None when masking is statically never needed, so callers
     can skip the branch entirely. Interior tiles (strictly below the
@@ -104,6 +126,10 @@ def _block_needs_mask(qi, ki, block_q, block_k, causal, kv_len):
     may_pad = kv_len % block_k != 0  # static
     if causal:
         on_diag = qi * block_q < ki * block_k + block_k - 1
+        if window:
+            # the window's edge cuts it: its last query row does not
+            # see its first key
+            on_diag |= qi * block_q + block_q - 1 - ki * block_k >= window
         if may_pad:
             return on_diag | (ki * block_k + block_k > kv_len)
         return on_diag
@@ -119,9 +145,12 @@ def mha_reference(
     *,
     causal: bool = True,
     scale: Optional[float] = None,
+    window: int = 0,
 ) -> jax.Array:
     """Readable O(T^2)-memory attention; the numerical ground truth
-    for the kernels and the CPU-test fallback."""
+    for the kernels and the CPU-test fallback. `window` (causal
+    only): a query sees the last `window` keys up to itself, its own
+    position counted (0: every key up to itself)."""
     *_, t_q, d = q.shape
     t_k = k.shape[-2]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
@@ -130,7 +159,13 @@ def mha_reference(
     ) * scale
     if causal:
         mask = jnp.tril(jnp.ones((t_q, t_k), dtype=bool), k=t_k - t_q)
+        if window:
+            mask &= ~jnp.tril(
+                jnp.ones((t_q, t_k), dtype=bool), k=t_k - t_q - window
+            )
         logits = jnp.where(mask, logits, DEFAULT_MASK_VALUE)
+    elif window:
+        raise ValueError("a window is a causal mask's")
     weights = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum(
         "bhqk,bhkd->bhqd", weights.astype(v.dtype), v
@@ -145,7 +180,7 @@ def _fwd_kernel(
     q_ref, k_ref, v_ref, out_ref, lse_ref,
     acc_ref, m_ref, l_ref, bias_ref,
     *, causal: bool, block_q: int, block_k: int,
-    kv_len: int, fast_mask: bool,
+    kv_len: int, fast_mask: bool, window: int = 0,
 ):
     """Online-softmax flash forward in the log2 domain.
 
@@ -172,10 +207,9 @@ def _fwd_kernel(
         m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # Causal: skip fully-masked KV blocks (q rows all before kv cols).
-    run = True
-    if causal:
-        run = qi * block_q + block_q - 1 >= ki * block_k
+    # Causal: skip fully-masked KV blocks (q rows all before kv cols,
+    # or with a window all `window` or more behind them).
+    run = _block_runs(qi, ki, block_q, block_k, causal, window)
 
     def _update(s, v):
         m_prev = m_ref[:, :1]  # [bq, 1]
@@ -205,7 +239,7 @@ def _fwd_kernel(
         )  # [bq, bk] f32, log2-domain logits
 
         needs_mask = _block_needs_mask(
-            qi, ki, block_q, block_k, causal, kv_len
+            qi, ki, block_q, block_k, causal, kv_len, window
         )
         if needs_mask is None:
             _update(s, v)
@@ -224,7 +258,8 @@ def _fwd_kernel(
             def _masked():
                 _update(
                     _mask_logits(
-                        s, qi, ki, block_q, block_k, causal, kv_len
+                        s, qi, ki, block_q, block_k, causal, kv_len,
+                        window,
                     ),
                     v,
                 )
@@ -249,13 +284,19 @@ def _fwd_kernel(
 _LOG2E = math.log2(math.e)
 
 
-def _flash_forward(q, k, v, scale, causal, block_q, block_k, kv_len):
+def _flash_forward(
+    q, k, v, scale, causal, block_q, block_k, kv_len, window=0
+):
     bh, t, d = q.shape
     tk = k.shape[1]
     nq = pl.cdiv(t, block_q)
     nk = pl.cdiv(tk, block_k)
     grid = (bh, nq, nk)
-    fast_mask = _bias_fast_path(causal, block_q, block_k, kv_len, t)
+    # (the one resident bias tile is the diagonal's: the tile a
+    # window's edge cuts has another pattern and takes the iota mask)
+    fast_mask = not window and _bias_fast_path(
+        causal, block_q, block_k, kv_len, t
+    )
     kernel = functools.partial(
         _fwd_kernel,
         causal=causal,
@@ -263,7 +304,18 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, kv_len):
         block_k=block_k,
         kv_len=kv_len,
         fast_mask=fast_mask,
+        window=window,
     )
+    kv_index = lambda b, i, j: (b, j, 0)  # noqa: E731
+    if window:
+        # A step the kernel skips names the nearest key block it
+        # runs, which the pipeline then has already: no block outside
+        # a query block's window is copied in.
+        def kv_index(b, i, j):
+            first = _first_key_block(i, block_q, block_k, window)
+            last = (i * block_q + block_q - 1) // block_k
+            return (b, jnp.clip(j, first, last), 0)
+
     # XLA fuses this multiply into q's producer; inside the kernel it
     # would cost a pass per (qi, ki) tile instead of one per qi block.
     # f32 multiply then cast: the effective logit scale stays exact
@@ -275,8 +327,8 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, kv_len):
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), kv_index),
+            pl.BlockSpec((1, block_k, d), kv_index),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -316,6 +368,7 @@ def _bwd_fused_kernel(
     dk_acc_ref, dv_acc_ref, bias_ref, dq_all_ref,
     *, scale: float, causal: bool, block_q: int, block_k: int,
     kv_len: int, q_len: int, fast_mask: bool, interp: bool,
+    window: int = 0,
 ):
     """Single-pass backward: dq, dk, dv from ONE s/p computation per
     tile. Split dq + dkv kernels would each recompute s = q2 @ k^T and
@@ -362,9 +415,7 @@ def _bwd_fused_kernel(
         def _init_dq_all():
             dq_all_ref[:] = jnp.zeros_like(dq_all_ref)
 
-    run = True
-    if causal:
-        run = qi * block_q + block_q - 1 >= ki * block_k
+    run = _block_runs(qi, ki, block_q, block_k, causal, window)
 
     @pl.when(run)
     def _compute():
@@ -419,7 +470,7 @@ def _bwd_fused_kernel(
             return jnp.where(row_ids < q_len, p, 0.0)
 
         needs_mask = _block_needs_mask(
-            qi, ki, block_q, block_k, causal, kv_len
+            qi, ki, block_q, block_k, causal, kv_len, window
         )
         q_may_pad = q_len % block_q != 0  # static
         if q_may_pad:
@@ -441,7 +492,7 @@ def _bwd_fused_kernel(
             @pl.when(needs_mask)
             def _masked():
                 p = jnp.exp2(_mask_logits(
-                    s, qi, ki, block_q, block_k, causal, kv_len
+                    s, qi, ki, block_q, block_k, causal, kv_len, window
                 ) - lse)
                 _update(_row_masked(p) if q_may_pad else p)
 
@@ -466,7 +517,8 @@ def _bwd_fused_kernel(
 
 
 def _flash_backward_fused(
-    q, k, v, out, lse, do, scale, causal, block_q, block_k, kv_len, q_len
+    q, k, v, out, lse, do, scale, causal, block_q, block_k, kv_len, q_len,
+    window=0,
 ):
     bh, t, d = q.shape
     tk = k.shape[1]
@@ -477,7 +529,9 @@ def _flash_backward_fused(
     block_k = min(block_k, 512)
     nq = pl.cdiv(t, block_q)
     nk = pl.cdiv(tk, block_k)
-    fast_mask = _bias_fast_path(causal, block_q, block_k, kv_len, q_len)
+    fast_mask = not window and _bias_fast_path(
+        causal, block_q, block_k, kv_len, q_len
+    )
     q2 = (q.astype(jnp.float32) * (scale * _LOG2E)).astype(q.dtype)
     delta = jnp.sum(
         out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1
@@ -497,22 +551,37 @@ def _flash_backward_fused(
     dq_seed = jnp.zeros_like(q, jnp.float32)
 
     interp = _interpret()
+    q_index = lambda b, j, i: (b, i, 0)  # noqa: E731
+    row_index = lambda b, j, i: (b, 0, i)  # noqa: E731
+    if window:
+        # As the forward's keys: a step the kernel skips names the
+        # nearest query block it runs, so q, do, lse and delta of a
+        # block outside a key block's reach are not copied in. (dq's
+        # blocks stay where they are: a skipped step carries its
+        # partial sum through.)
+        def _near(j, i):
+            first = (j * block_k) // block_q
+            last = (j * block_k + block_k - 1 + window - 1) // block_q
+            return jnp.clip(i, first, jnp.minimum(last, nq - 1))
+
+        q_index = lambda b, j, i: (b, _near(j, i), 0)  # noqa: E731
+        row_index = lambda b, j, i: (b, 0, _near(j, i))  # noqa: E731
     dq, dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_fused_kernel,
             scale=scale, causal=causal,
             block_q=block_q, block_k=block_k,
             kv_len=kv_len, q_len=q_len, fast_mask=fast_mask,
-            interp=interp,
+            interp=interp, window=window,
         ),
         grid=(bh, nk, nq),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d), q_index),
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, 8, block_q), lambda b, j, i: (b, 0, i)),
-            pl.BlockSpec((1, 8, block_q), lambda b, j, i: (b, 0, i)),
+            pl.BlockSpec((1, block_q, d), q_index),
+            pl.BlockSpec((1, 8, block_q), row_index),
+            pl.BlockSpec((1, 8, block_q), row_index),
             pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
         ],
         out_specs=[
@@ -547,18 +616,22 @@ def _flash_backward_fused(
 # ---------------------------------------------------------------------------
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9)
 )
 def _flash_attention_bhsd(
-    q, k, v, scale, causal, block_q, block_k, kv_len, q_len
+    q, k, v, scale, causal, block_q, block_k, kv_len, q_len, window=0
 ):
-    out, _ = _flash_forward(q, k, v, scale, causal, block_q, block_k, kv_len)
+    out, _ = _flash_forward(
+        q, k, v, scale, causal, block_q, block_k, kv_len, window
+    )
     return out
 
 
-def _flash_fwd_rule(q, k, v, scale, causal, block_q, block_k, kv_len, q_len):
+def _flash_fwd_rule(
+    q, k, v, scale, causal, block_q, block_k, kv_len, q_len, window
+):
     out, lse = _flash_forward(
-        q, k, v, scale, causal, block_q, block_k, kv_len
+        q, k, v, scale, causal, block_q, block_k, kv_len, window
     )
     # Residuals carry checkpoint names so a remat policy that saves
     # them (models.llama remat_policy="dots_flash") turns the backward
@@ -572,12 +645,12 @@ def _flash_fwd_rule(q, k, v, scale, causal, block_q, block_k, kv_len, q_len):
 
 
 def _flash_bwd_rule(
-    scale, causal, block_q, block_k, kv_len, q_len, residuals, do
+    scale, causal, block_q, block_k, kv_len, q_len, window, residuals, do
 ):
     q, k, v, out, lse = residuals
     dq, dk, dv = _flash_backward_fused(
         q, k, v, out, lse, do, scale, causal, block_q, block_k,
-        kv_len, q_len,
+        kv_len, q_len, window,
     )
     return dq, dk, dv
 
@@ -595,6 +668,7 @@ def flash_attention(
     block_q: int = 1024,
     block_k: int = 1024,
     force_pallas: bool = False,
+    window: int = 0,
 ) -> jax.Array:
     """Fused attention. On a TPU this is always the Pallas kernel,
     compiled by Mosaic: a shape the compiler refuses raises, it never
@@ -605,12 +679,28 @@ def flash_attention(
 
     q/k/v: [batch, heads, seq, head_dim]. head_dim should be a
     multiple of 128 for MXU efficiency (callers pad).
+
+    `window` (causal self-attention only): a query sees the last
+    `window` keys up to itself, its own position counted. The forward
+    and the fused backward skip the key blocks that lie wholly before
+    a query block's window, as they skip those behind the diagonal,
+    and mask the one the window's edge cuts. A static argument: 0, or
+    a window the whole sequence fits, is the plain causal kernel.
     """
     b, h, t, d = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    if jax.default_backend() != "tpu" and not force_pallas:
-        return mha_reference(q, k, v, causal=causal, scale=scale)
     tk = k.shape[2]
+    if window and not (causal and t == tk):
+        raise ValueError(
+            "a window is causal self-attention's: "
+            f"causal={causal}, {t} queries, {tk} keys"
+        )
+    if window >= tk:
+        window = 0
+    if jax.default_backend() != "tpu" and not force_pallas:
+        return mha_reference(
+            q, k, v, causal=causal, scale=scale, window=window
+        )
     block_q = min(block_q, t)
     block_k = min(block_k, tk)
     qf = q.reshape(b * h, t, d)
@@ -627,7 +717,7 @@ def flash_attention(
         kf = jnp.pad(kf, ((0, 0), (0, tk_pad), (0, 0)))
         vf = jnp.pad(vf, ((0, 0), (0, tk_pad), (0, 0)))
     out = _flash_attention_bhsd(
-        qf, kf, vf, scale, causal, block_q, block_k, tk, t
+        qf, kf, vf, scale, causal, block_q, block_k, tk, t, window
     )
     return out[:, :t, :].reshape(b, h, t, d)
 

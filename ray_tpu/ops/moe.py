@@ -133,6 +133,46 @@ def route_grouped_sigmoid(
     return gates, experts
 
 
+@jax.custom_vjp
+def grouped_matmul(
+    rows: jax.Array, weights: jax.Array, groups: jax.Array
+) -> jax.Array:
+    """`lax.ragged_dot(rows [n, d], weights [G, d, f], groups [G])`
+    whose gradient is the mathematics' wherever the rows lie: a row
+    behind the last group is in no product, so its cotangent is ZERO.
+
+    XLA's grouped-matmul kernel on the TPU leaves the rows behind the
+    last group as they were in memory, in its transposes as in the
+    forward (the CPU's writes zeros there and shows nothing). The
+    forward's callers select those rows of the OUTPUT away; the
+    cotangent to `rows` came back with the same garbage, and the
+    gather's transpose added it into real tokens' gradients (PR 55, on
+    the chip, one rank's share with seven picks of eight held
+    elsewhere: a third of the runs reached NaN inside 48 s and the
+    others trained on noise). The select lives here, in the backward
+    pass of the product itself, so it holds for every caller that
+    differentiates (a layer's slice or a whole stack, dead rows or
+    picks held elsewhere), and a forward that is never differentiated
+    lowers to `lax.ragged_dot` alone, as before."""
+    return lax.ragged_dot(rows, weights, groups)
+
+
+def _grouped_matmul_fwd(rows, weights, groups):
+    return lax.ragged_dot(rows, weights, groups), (rows, weights, groups)
+
+
+def _grouped_matmul_bwd(saved, cotangent):
+    rows, weights, groups = saved
+    d_rows, d_weights = jax.vjp(
+        lambda r, w: lax.ragged_dot(r, w, groups), rows, weights
+    )[1](cotangent)
+    grouped = jnp.arange(rows.shape[0]) < jnp.sum(groups)
+    return jnp.where(grouped[:, None], d_rows, 0), d_weights, None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
 def gated_experts(
     params: Dict, rows: jax.Array, matmul: Callable, glu: Callable
 ) -> jax.Array:
@@ -217,7 +257,7 @@ def moe_ffn_dropless(
             params,
             x[order // k],
             # (a stack's two leading axes merge for free)
-            lambda rows, w: lax.ragged_dot(
+            lambda rows, w: grouped_matmul(
                 rows, w.reshape((-1,) + w.shape[-2:]), groups
             ),
             glu,

@@ -347,3 +347,191 @@ def test_the_shares_of_a_one_group_router_are_the_uncut_layer(
     with jax.default_matmul_precision("highest"):
         uncut = np.asarray(ref._ffn(x[0], full, m=numbers) - x[0])
     np.testing.assert_allclose(total[0], uncut, atol=2e-5)
+
+
+# ---- the share tied to the model through the TRAINING layer (ISSUE 55):
+# Trinity-Mini's expert layer, one group, a shared expert, a norm on the
+# layer's output. The 8 shares of a small layer through `llama._mlp`
+# (what `loss_fn` differentiates), the shared expert counted once, add
+# up to what the uncut reference gives for the whole layer, and so do
+# their gradients with respect to the layer's input.
+
+SHARES = 8
+
+
+def _trinity_cfg(held, first=0, shared=True):
+    return LlamaConfig(
+        vocab_size=8, dim=D, n_layers=2, n_heads=2, n_kv_heads=2,
+        intermediate=F, dtype=jnp.float32, norm_eps=1e-5,
+        layer_kinds=[[4, 2, 10000.0, False], [0, 2, 0, False]],
+        attn_gate=True, post_norms=True, dense_layers=0,
+        moe_experts=held, moe_top_k=K, moe_router="sigmoid_groups",
+        moe_router_experts=E, moe_first_expert=first, moe_groups=1,
+        moe_top_groups=1, moe_route_scale=2.826,
+        moe_shared_intermediate=F if shared else 0,
+    )
+
+
+def _summed_shares(x, full):
+    """sum over the 8 shares of what `_mlp` adds for its held experts,
+    and the shared expert's part once (the first share carries it)."""
+    per = E // SHARES
+    total, picks = jnp.zeros_like(x), []
+    for rank in range(SHARES):
+        layer = {
+            k: v for k, v in full.items()
+            if rank == 0 or not k.startswith("shared")
+        }
+        for name in ("w_gate", "w_up", "w_down"):
+            layer[name] = full[name][rank * per:(rank + 1) * per]
+        out, aux, counts = _mlp(
+            _trinity_cfg(per, rank * per, shared=rank == 0), x, layer
+        )
+        total = total + (out - x)
+        picks.append(counts)
+    return total, jnp.concatenate(picks)
+
+
+def _uncut_reference(x, full, post_norm):
+    """x [t, d] -> the whole layer of `trinity_ref`: x + N_post(f)."""
+    from benchmark.reference import trinity_ref
+
+    numbers = trinity_ref._Numbers(
+        eps=1e-5, moe_top_k=K, route_scale=2.826, first_expert=0
+    )
+    with jax.default_matmul_precision("highest"):
+        return trinity_ref._ffn(
+            x, dict(full, mlp_post_norm=post_norm), m=numbers
+        )
+
+
+@pytest.mark.parametrize("seed", [11, 12])
+def test_eight_shares_through_the_training_layer_are_the_uncut_reference(seed):
+    from benchmark.reference.llama_ref import _rms_norm
+
+    full, _ = _layer(seed)
+    rng = np.random.default_rng(seed + 100)
+    x = jnp.asarray(rng.normal(size=(40, D)).astype(np.float32))
+    post_norm = jnp.asarray(1 + 0.1 * rng.normal(size=D).astype(np.float32))
+    summed, picks = _summed_shares(x[None], full)
+    assert int(picks.sum()) == 40 * K  # every pick met exactly one share
+    assert picks.shape == (E,)
+    # the shared expert is there, and once: twice would be far off
+    shared = _mlp(
+        _trinity_cfg(E), x[None], full
+    )[0] - _mlp(
+        _trinity_cfg(E, shared=False), x[None],
+        {k: v for k, v in full.items() if not k.startswith("shared")},
+    )[0]
+    assert float(jnp.abs(shared).max()) > 0.01
+    want = _uncut_reference(x, full, post_norm) - x
+    got = _rms_norm(summed[0], post_norm, 1e-5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5)
+    twice = _rms_norm((summed + shared)[0], post_norm, 1e-5)
+    assert float(jnp.abs(twice - want).max()) > 1e-2
+    # and the whole layer through `_mlp` itself, post-norm and all
+    whole = _mlp(
+        _trinity_cfg(E), x[None], dict(full, mlp_post_norm=post_norm)
+    )[0][0]
+    np.testing.assert_allclose(
+        np.asarray(whole - x), np.asarray(want), atol=3e-5
+    )
+
+
+@pytest.mark.parametrize("seed", [11, 12])
+def test_the_shares_gradients_to_the_layers_input_add_up_too(seed):
+    from benchmark.reference.llama_ref import _rms_norm
+
+    full, _ = _layer(seed)
+    rng = np.random.default_rng(seed + 100)
+    x = jnp.asarray(rng.normal(size=(40, D)).astype(np.float32))
+    post_norm = jnp.asarray(1 + 0.1 * rng.normal(size=D).astype(np.float32))
+    weight = jnp.asarray(rng.normal(size=(40, D)).astype(np.float32))
+
+    def through_the_shares(x):
+        summed, _ = _summed_shares(x[None], full)
+        return jnp.sum(weight * _rms_norm(summed[0], post_norm, 1e-5))
+
+    def through_the_reference(x):
+        return jnp.sum(weight * (_uncut_reference(x, full, post_norm) - x))
+
+    got = jax.grad(through_the_shares)(x)
+    want = jax.grad(through_the_reference)(x)
+    assert float(jnp.abs(want).max()) > 0.1
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
+
+
+def test_a_shares_gradient_takes_nothing_from_the_rows_behind_the_last_group(
+    monkeypatch,
+):
+    """On the chip `lax.ragged_dot` leaves the rows behind the last
+    group as they were in memory, forward AND backward (the CPU's writes
+    zeros, which hides it). Simulated here: a grouped matmul whose
+    output and whose cotangent to its rows are NaN behind the groups.
+    The gradient of one rank's share to the layer's input and to its
+    weights is the true one all the same (ISSUE 55: the picks held
+    elsewhere, seven of eight, must add nothing to a real token)."""
+    from jax import lax
+
+    import ray_tpu.ops.moe as moe
+
+    real = lax.ragged_dot
+
+    def behind(rows, groups):
+        return (jnp.arange(rows.shape[0]) >= jnp.sum(groups))[:, None]
+
+    @jax.custom_vjp
+    def garbage_dot(rows, w, groups):
+        return jnp.where(behind(rows, groups), jnp.nan, real(rows, w, groups))
+
+    def fwd(rows, w, groups):
+        return garbage_dot(rows, w, groups), (rows, w, groups)
+
+    def bwd(saved, cotangent):
+        rows, w, groups = saved
+        clean = jnp.where(behind(rows, groups), 0, cotangent)
+        d_rows, d_w = jax.vjp(
+            lambda r, w: real(r, w, groups), rows, w
+        )[1](clean)
+        return jnp.where(behind(rows, groups), jnp.nan, d_rows), d_w, None
+
+    garbage_dot.defvjp(fwd, bwd)
+    full, share = _layer(21, 8, 4)
+    x = jnp.asarray(
+        np.random.default_rng(22).normal(size=(1, 24, D)).astype(np.float32)
+    )
+    cfg = _trinity_cfg(4, 8)
+
+    def through_the_layer(x, layer):
+        out, _, _ = _mlp(cfg, x, layer)
+        return jnp.sum(out * out)
+
+    # The same for a caller that hands the grouped matmul a whole STACK
+    # and a layer's index, dead rows among the live ones: the contract
+    # is the product's own, not one caller's (REVIEW 55).
+    live = jnp.arange(24) % 5 != 0
+    stack = {
+        k: jnp.stack([v, v + 1.0]) for k, v in share.items()
+        if k in ("w_gate", "w_up", "w_down")
+    }
+
+    def through_a_stack(x, stack):
+        routed = moe.route_grouped_sigmoid(
+            x[0], share["router"], share["router_bias"], K,
+            n_groups=1, top_groups=1, scale=2.826,
+        )
+        out, _, _ = moe.moe_ffn_dropless(
+            stack, x[0], k=K, live=live, layer=jnp.int32(1),
+            routed=routed, first_expert=8,
+        )
+        return jnp.sum(out * out)
+
+    for loss, weights in ((through_the_layer, share), (through_a_stack, stack)):
+        want = jax.grad(loss, (0, 1))(x, weights)
+        with monkeypatch.context() as patched:
+            patched.setattr(moe.lax, "ragged_dot", garbage_dot)
+            got = jax.grad(loss, (0, 1))(x, weights)
+        assert float(jnp.abs(want[0]).max()) > 0.01
+        assert all(bool(jnp.isfinite(g).all()) for g in jax.tree.leaves(got))
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)

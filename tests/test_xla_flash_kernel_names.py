@@ -43,27 +43,27 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(scope="module")
-def kernel_calls(one_chip):
+def _attention_kernel_calls(one_chip, kv_heads, kind=None):
     """{instruction name: op_name} of the Mosaic calls in the compiled
-    gradient of the model's attention block."""
+    gradient of the model's attention block, of a layer of `kind`
+    (`layer_kinds`) where one is given."""
     from jax.experimental.compilation_cache import compilation_cache
 
     cfg = llama.LlamaConfig(
         vocab_size=32768, dim=HEADS * HEAD_DIM, n_layers=1,
-        n_heads=HEADS, n_kv_heads=KV_HEADS, intermediate=14336,
+        n_heads=HEADS, n_kv_heads=kv_heads, intermediate=14336,
         max_seq_len=SEQ, dtype=jnp.bfloat16, attention="flash",
     )
 
     def loss(q, k, v):
-        out = llama._attention(cfg, q, k, v, None)
+        out = llama._attention(cfg, q, k, v, None, kind=kind)
         return out.astype(jnp.float32).sum()
 
     q = jax.ShapeDtypeStruct(
         (1, HEADS, SEQ, HEAD_DIM), jnp.bfloat16, sharding=one_chip
     )
     kv = jax.ShapeDtypeStruct(
-        (1, KV_HEADS, SEQ, HEAD_DIM), jnp.bfloat16, sharding=one_chip
+        (1, kv_heads, SEQ, HEAD_DIM), jnp.bfloat16, sharding=one_chip
     )
     # `flash_attention` asks jax.default_backend(), which is the CPU
     # here, and would take its reference branch: steered in the test,
@@ -93,6 +93,21 @@ def kernel_calls(one_chip):
     return calls
 
 
+@pytest.fixture(scope="module")
+def kernel_calls(one_chip):
+    """The plain causal kernels at `mistral-7b-v0.3-l4`'s widths."""
+    return _attention_kernel_calls(one_chip, KV_HEADS)
+
+
+@pytest.fixture(scope="module")
+def windowed_kernel_calls(one_chip):
+    """The kernels under a window at `trinity-mini-ep8`'s widths: 32
+    heads / 4 kv heads x 128, 8,192 tokens under a window of 2,048."""
+    return _attention_kernel_calls(
+        one_chip, 4, llama.AttnKind(window=2048, kv_heads=4)
+    )
+
+
 def test_two_mosaic_kernels_and_nothing_unnamed(kernel_calls):
     families = sorted(re.sub(r"[.\d]+$", "", n) for n in kernel_calls)
     assert families == ["flash_bwd", "flash_fwd"], kernel_calls
@@ -106,6 +121,25 @@ def test_kernel_is_named_and_scoped(kernel_calls, kernel):
     assert re.fullmatch(rf"{kernel}(\.\d+)?", name)
     assert "layer/attention" in op_name
     assert f"/{kernel}/" in op_name
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd"])
+def test_the_windowed_call_keeps_the_kernels_names(
+    windowed_kernel_calls, kernel
+):
+    """A window is an argument of the SAME two Pallas calls (ISSUE 55):
+    Mosaic compiles them at the benchmark's widths (the clamped index
+    maps, the second inequality of the mask), and the device trace
+    finds them by the names `flash_kernel_share` sums by."""
+    families = sorted(
+        re.sub(r"[.\d]+$", "", n) for n in windowed_kernel_calls
+    )
+    assert families == ["flash_bwd", "flash_fwd"], windowed_kernel_calls
+    (name, op_name), = [
+        kv for kv in windowed_kernel_calls.items() if kv[0].startswith(kernel)
+    ]
+    assert re.fullmatch(rf"{kernel}(\.\d+)?", name)
+    assert "layer/attention" in op_name and f"/{kernel}/" in op_name
 
 
 @pytest.mark.parametrize("program", ["paged_decode_step", "paged_prefill"])
