@@ -10,7 +10,10 @@ weights from a seed:
   kernel  `flash_attention` forward and grad against `mha_reference`,
           plain and under a window, and the serve step's `paged_attn` over ragged rows of a block
           pool against dense attention, at one GQA and one MHA
-          geometry, compiled by Mosaic (never interpreted on a chip).
+          geometry, compiled by Mosaic (never interpreted on a chip);
+          one rank's share of an expert layer at `trinity-mini-ep8`'s
+          widths, its budget of rows against every pick, output and
+          input-gradient, at the even load and at one over the budget.
   train   `JaxTrainer.fit` -> `make_train_step`, batch 8 x seq 2048,
           mesh over every chip; loss finite and falling, zero
           steady-state compiles. On several chips: `fsdp=n`, then
@@ -90,7 +93,7 @@ REHEARSAL = {
 }
 REHEARSAL_DEVICES = 4
 
-PHASE_BUDGET_S = {"kernel": 300, "train": 420, "serve": 480}
+PHASE_BUDGET_S = {"kernel": 480, "train": 420, "serve": 480}
 _LABEL = "platform=? "
 
 
@@ -240,8 +243,87 @@ def device_and_kernel_phase(rehearse: bool) -> dict:
         paged_attn_row(heads, kv_heads, rehearse, device)
         for heads, kv_heads in ((16, 2), (8, 8))
     ]
+    out["experts"] = expert_layer_rows(rehearse)
     out["cache_hits"] = hits[0]
     return out
+
+
+def expert_layer_rows(rehearse: bool) -> list:
+    """One rank's share of an expert layer (ops/moe.py
+    `moe_ffn_dropless` under a router wider than the experts held) at
+    `trinity-mini-ep8`'s widths: the layer that computes its budget of
+    rows against the one that computes every pick, output and gradient
+    to the input, at the even load and at a load over the budget
+    (where the layer takes every row). The rows behind the last group
+    are garbage on the chip and zeros on the CPU, so this is where a
+    read of one shows."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.moe import held_row_budget, moe_ffn_dropless
+
+    t, k, d, f, held, over = (
+        (192, 4, 32, 64, 4, 16) if rehearse else (8192, 8, 2048, 1024, 16, 128)
+    )
+    budget = held_row_budget(t * k, held, over)
+    require(budget < t * k, f"expert layer: no budget under {t * k} picks")
+    keys = jax.random.split(jax.random.PRNGKey(57), 5)
+    params = {
+        name: (jax.random.normal(key, shape) / shape[1] ** 0.5).astype(
+            jnp.bfloat16
+        )
+        for name, key, shape in (
+            ("w_gate", keys[0], (held, d, f)), ("w_up", keys[1], (held, d, f)),
+            ("w_down", keys[2], (held, f, d)),
+        )
+    }
+    x = jax.random.normal(keys[3], (t, d), jnp.bfloat16)
+    scores = jax.random.normal(keys[4], (t, over))
+
+    def both(routed_over):
+        def loss(x, gates, experts):
+            out, _, counts = moe_ffn_dropless(
+                params, x, k=k, routed=(gates, experts),
+                routed_over=routed_over,
+            )
+            return jnp.sum(out.astype(jnp.float32) * 0.01), (out, counts)
+
+        return jax.jit(jax.value_and_grad(loss, has_aux=True))
+
+    compacted, all_rows = both(over), both(0)
+
+    def far(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+
+    rows = []
+    for load, lift in (("even", 0.0), ("over_budget", 2.5)):
+        t0 = time.perf_counter()
+        lifted = scores.at[:, :held].add(lift)
+        gates, experts = jax.lax.top_k(jax.nn.sigmoid(lifted), k)
+        (_, (got, counts)), got_dx = compacted(x, gates, experts)
+        (_, (want, _)), want_dx = all_rows(x, gates, experts)
+        picks = int(counts.sum())
+        row = {
+            "load": load, "held_picks": picks, "budget": budget,
+            "picks": t * k, "out_err": far(got, want),
+            "dx_err": far(got_dx, want_dx),
+            "finite": bool(
+                jnp.isfinite(got.astype(jnp.float32)).all()
+                & jnp.isfinite(got_dx.astype(jnp.float32)).all()
+            ),
+            "wall_s": round(time.perf_counter() - t0, 2),
+        }
+        rows.append(row)
+        require(
+            (picks > budget) == (load == "over_budget") and picks > 0,
+            f"expert layer {load}: {picks} held picks, budget {budget}",
+        )
+        require(row["finite"], f"expert layer {load}: not finite")
+        # bf16 rounds to 2^-8; the two sum in float32 in another order
+        require(row["out_err"] < 0.02, f"expert layer {load}: {row}")
+        require(row["dx_err"] < 0.03, f"expert layer {load}: {row}")
+    return rows
 
 
 def paged_attn_row(heads: int, kv_heads: int, rehearse: bool, device) -> dict:
@@ -822,6 +904,14 @@ def main() -> int:
             "kernel",
             f"paged_attn {row['shape']}: err {row['err']:.2e} "
             f"mosaic={row['mosaic']} ({row['wall_s']} s with compiles)",
+        )
+    for row in probe["experts"]:
+        say(
+            "kernel",
+            f"expert layer, {row['load']} load ({row['held_picks']} held "
+            f"picks of {row['picks']}, budget {row['budget']}): out err "
+            f"{row['out_err']:.2e} dx err {row['dx_err']:.2e} against every "
+            f"pick computed ({row['wall_s']} s with compiles)",
         )
 
     import ray_tpu as rt

@@ -590,11 +590,15 @@ class InferenceEngine:
         # What only some configurations' forwards count, by the pool's
         # counter leaves: the picks the router made over ALL its
         # outputs where the experts held here are fewer (the `moe_picks`
-        # above are then the picks that met a held expert), and the
+        # above are then the picks that met a held expert), the expert
+        # layers' forwards whose held picks passed the row budget and
+        # took every row (ops/moe.py `held_row_budget`), and the
         # (query, key) pairs a learned selection could see and kept.
         if self._kv is not None:
             if "moe_routed" in self._kv.pool:
                 self._moe["moe_picks_routed"] = 0
+            if "moe_spilled" in self._kv.pool:
+                self._moe["moe_forwards_spilled"] = 0
             if "dsa_counts" in self._kv.pool:
                 # (the chunks' share apart: theirs is the attention
                 # kernel's work, ops/selected_attention.py)
@@ -1902,11 +1906,15 @@ class InferenceEngine:
         got any token (the expert weights the forward had to read);
         and, of a chunk, per layer the fullest expert's tokens (how
         uneven the grouped matmuls ran). `moe_routed` [expert layers]:
-        the picks over all the router's outputs; `dsa_counts`
-        [layers, 2]: the pairs its attention could see and kept."""
+        the picks over all the router's outputs; `moe_spilled` [expert
+        layers]: 1 where a layer's held picks passed its row budget;
+        `dsa_counts` [layers, 2]: the pairs its attention could see
+        and kept."""
         added: Dict[str, int] = {}
         if "moe_routed" in counted:
             added["moe_picks_routed"] = int(counted["moe_routed"].sum())
+        if "moe_spilled" in counted:
+            added["moe_forwards_spilled"] = int(counted["moe_spilled"].sum())
         if "dsa_counts" in counted:
             visible, kept = map(int, counted["dsa_counts"].sum(axis=0))
             added.update(dsa_keys_visible=visible, dsa_keys_selected=kept)

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from .._private import compile_watch
 from ..ops.latent_expand import latent_expand
+from ..ops.moe import held_row_budget
 from ..ops.paged_attention import paged_attention
 from ..ops.norms import (
     apply_rotary, layer_norm, rms_norm, rotary_embedding, yarn_mscale,
@@ -106,7 +107,7 @@ PAGED_TILE_KEYS = 256
 
 #: The pool's leaves that hold no cache: what a paged forward counted,
 #: left there for the engine to fetch (overwritten, not summed).
-COUNTER_LEAVES = ("moe_counts", "moe_routed", "dsa_counts")
+COUNTER_LEAVES = ("moe_counts", "moe_routed", "moe_spilled", "dsa_counts")
 
 
 def cache_leaves(pool: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
@@ -207,7 +208,8 @@ def _pool_plan(cfg: LlamaConfig):
 
     `moe_counts` has an entry an expert layer; a model whose layers
     lie in stacks of their own (the first two families) holds a share
-    of the experts it routes over and counts `moe_routed` beside it."""
+    of the experts it routes over and counts `moe_routed` and
+    `moe_spilled` beside it."""
     for key in ("attn_gate", "post_norms"):
         if getattr(cfg, key):
             raise NotImplementedError(
@@ -261,6 +263,7 @@ def _pool_plan(cfg: LlamaConfig):
         counters["moe_counts"] = (expert_layers, cfg.moe_experts)
         if cfg.kv_lora_rank or cfg.layer_kinds:
             counters["moe_routed"] = (expert_layers,)
+            counters["moe_spilled"] = (expert_layers,)
     return caches, counters
 
 
@@ -1262,9 +1265,11 @@ def _serve_block(
     attention half (which writes the layer's cache and returns the
     attention's output before `wo`), the residual through `wo`, the
     FFN -> (x, cache, counts): of `counted`, the layer's `moe_counts`
-    [E held] and `moe_routed` [] (an expert layer: its picks per
-    expert, and the picks over all the router's outputs; a dead row
-    picks none) and what its attention counted (`dsa_counts`)."""
+    [E held], `moe_routed` [] and `moe_spilled` [] (an expert layer:
+    its picks per expert, the picks over all the router's outputs, a
+    dead row picking none, and 1 where the held picks passed
+    `ops/moe.py`'s `held_row_budget`, so the layer took every row)
+    and what its attention counted (`dsa_counts`)."""
     b, t, _ = x.shape
     with jax.named_scope("layer/attn_qkv"):
         h = model_norm(cfg, x, layer["attn_norm"])
@@ -1285,6 +1290,10 @@ def _serve_block(
                 counts["moe_routed"] = jnp.asarray(
                     rows_live * t * cfg.moe_top_k, jnp.int32
                 )
+                counts["moe_spilled"] = (picks.sum() > held_row_budget(
+                    b * t * cfg.moe_top_k, cfg.moe_experts,
+                    layer["router"].shape[-1],
+                )).astype(jnp.int32)
     return x, cache, counts
 
 
@@ -1346,8 +1355,8 @@ def _paged_forward(
     through the walk over the layers and written in place. The new
     pool also holds what this forward counted, in the leaves the pool
     has for it (`COUNTER_LEAVES`; overwritten, not summed: the engine
-    adds them up): `moe_counts` and `moe_routed` an entry an expert
-    layer, `dsa_counts` one a layer.
+    adds them up): `moe_counts`, `moe_routed` and `moe_spilled` an
+    entry an expert layer, `dsa_counts` one a layer.
 
     ONE walk for every family. What differs between them is what
     `_pool_plan` read off the configuration: which caches there are,

@@ -861,6 +861,7 @@ def _mlp(cfg: LlamaConfig, x, layer, ep_axis=None, live=None,
                     cfg.moe_route_scale,
                 ),
                 first_expert=cfg.moe_first_expert,
+                routed_over=layer["router"].shape[-1],
             )
     if ep_axis is not None:
         out, aux = moe_ffn_ep(

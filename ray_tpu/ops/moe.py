@@ -19,7 +19,12 @@ anywhere in Ray core or its libraries); built TPU-first.
     sorted rows (`lax.ragged_dot`, which XLA compiles to its own
     grouped-matmul kernel on the TPU), so each token meets only its
     `k` experts and NOTHING is dropped at any skew: all tokens to one
-    expert is one group of `t` rows.
+    expert is one group of `t` rows. Where the device holds a share
+    of the experts its router deals over (one rank of an
+    expert-parallel layer), the picks that meet a held expert are the
+    first of the sorted picks and only `held_row_budget` rows are
+    gathered, multiplied, summed and differentiated; a load over the
+    budget takes every row, so the layer is exact at every load.
   * `moe_ffn_ep` — experts sharded over the `ep` mesh axis inside a
     `shard_map`: dispatch/return are `lax.all_to_all` hops over ICI
     with GShard/Switch fixed-capacity buffers (static shapes for the
@@ -31,6 +36,7 @@ anywhere in Ray core or its libraries); built TPU-first.
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
@@ -184,6 +190,199 @@ def gated_experts(
     return matmul(hidden, params["w_down"])
 
 
+def held_row_budget(picks: int, held: int, routed_over: int) -> int:
+    """The rows an expert layer computes of its `picks` = tokens x k
+    sorted picks, where it holds `held` of the `routed_over` experts
+    its router deals over: twice the held experts' even share of the
+    picks, in whole 512s, and never more than the picks. A layer that
+    holds every expert it routes over, or so few picks that the round
+    number reaches them (a decode step's rows), computes all of them:
+    the budget IS the picks, and `moe_ffn_dropless` is the program it
+    was. A shape's rule, with nothing to set: the layer is exact at
+    every load (`moe_ffn_dropless`), the budget only says which of
+    its two programs a load takes."""
+    if routed_over <= held:
+        return picks
+    share = -(-2 * picks * held // routed_over)
+    return min(picks, -(-share // 512) * 512)
+
+
+def _flat(w):
+    """A stack's two leading axes merged (free): `[layers x E, ., .]`."""
+    return w.reshape((-1,) + w.shape[-2:])
+
+
+def _all_rows(params, x, gates, picks, k, glu, masked):
+    """Every one of the `t * k` sorted picks through the experts and
+    back to its token, the ones behind the last group selected away
+    (`masked`: there are picks of experts held elsewhere). `picks` are
+    `(picked, order, groups, live)`."""
+    picked, order, groups, live = picks
+    t = x.shape[0]
+    num_experts = params["w_gate"].shape[-3]
+    with jax.named_scope("moe/experts"):
+        out = gated_experts(
+            params,
+            x[order // k],
+            lambda rows, w: grouped_matmul(rows, _flat(w), groups),
+            glu,
+        )
+    with jax.named_scope("moe/combine"):
+        # Back to token order by the inverse permutation (a gather; a
+        # scatter-add of t*k rows serialises on the TPU), then the
+        # weighted sum of each token's k rows in float32.
+        out = out[jnp.argsort(order)].reshape(t, k, -1)
+        if live is not None:
+            # Rows behind the last group are whatever the kernel left.
+            out = jnp.where(live[:, None, None], out, 0)
+        if masked:
+            out = jnp.where(
+                (picked < num_experts).reshape(t, k, 1), out, 0
+            )
+        out = jnp.sum(out * gates[:, :, None], axis=1)
+    return out
+
+
+def _held_picks(picks, rows, k):
+    """Of the first `rows` sorted picks: -> (their index into the
+    `t * k` picks, their tokens, [rows, 1] whether a row is a held
+    pick or filler behind the last group)."""
+    _, order, groups, _ = picks
+    first = order[:rows]
+    return first, first // k, (jnp.arange(rows) < jnp.sum(groups))[:, None]
+
+
+def _held_rows(params, x, gates, picks, rows, k, glu):
+    """The first `rows` sorted picks alone: the picks that met a held
+    expert, in expert order, then filler behind the last group ->
+    (out [t, d] float32, what `_held_rows_bwd` needs of it). Each pass
+    of the layer (the gather of the tokens' rows, the three grouped
+    matmuls, the GLU, the gates, the sum back into the tokens) runs
+    over `rows` rows and not `t * k`."""
+    groups = picks[2]
+    first, token, filled = _held_picks(picks, rows, k)
+    with jax.named_scope("moe/experts"):
+        xs = x[token]
+        up = lax.ragged_dot(xs, _flat(params["w_up"]), groups)
+        gate = lax.ragged_dot(xs, _flat(params["w_gate"]), groups)
+        out = lax.ragged_dot(glu(up, gate), _flat(params["w_down"]), groups)
+    with jax.named_scope("moe/combine"):
+        # The filler is whatever the kernel left (garbage on the chip):
+        # selected away BEFORE the product with the gate. A filler
+        # row's token is a real one and takes a zero. The sum is a
+        # scatter-add of `rows` rows in float32: at a quarter of the
+        # picks it costs less than the gather of `t x k` rows and the
+        # float32 product over `[t, k, d]` it replaces (PERF.md
+        # section 6, PR 57).
+        y = jnp.where(filled, out, 0) * gates.reshape(-1)[first][:, None]
+        y = jnp.zeros(x.shape, y.dtype).at[token].add(y)
+    return y, (xs, up, gate, out)
+
+
+def _held_rows_bwd(saved, params, x, gates, picks, rows, k, glu, dy):
+    """`_held_rows`' cotangents to (the expert matrices, x, gates)
+    from what its forward saved: every pass over `rows` rows. Written
+    out because the residuals have to cross a `lax.cond` by hand
+    (`_budgeted_rows`); the parametrised test holds it to `jax.grad`
+    of the all-rows program and of a plain loop. A row behind the last
+    group is garbage in every saved array and in the kernel's
+    cotangents to rows (`_grouped_matmul_bwd` selects those away), and
+    its own cotangent is zero."""
+    xs, up, gate, out = saved
+    groups = picks[2]
+    first, token, filled = _held_picks(picks, rows, k)
+    with jax.named_scope("moe/combine"):
+        d_y = dy[token]
+        d_gates = jnp.zeros(gates.size, gates.dtype).at[first].add(
+            jnp.sum(jnp.where(filled, out, 0) * d_y, axis=-1)
+        ).reshape(gates.shape)
+        weight = gates.reshape(-1)[first][:, None]
+        d_out = jnp.where(filled, d_y * weight, 0).astype(out.dtype)
+    with jax.named_scope("moe/experts"):
+        hidden, glu_bwd = jax.vjp(glu, up, gate)
+        d_hidden, d_down, _ = _grouped_matmul_bwd(
+            (hidden, _flat(params["w_down"]), groups), d_out
+        )
+        d_up, d_gate = glu_bwd(d_hidden)
+        d_xs_up, d_w_up, _ = _grouped_matmul_bwd(
+            (xs, _flat(params["w_up"]), groups), d_up
+        )
+        d_xs_gate, d_w_gate, _ = _grouped_matmul_bwd(
+            (xs, _flat(params["w_gate"]), groups), d_gate
+        )
+        d_x = jnp.zeros(x.shape, x.dtype).at[token].add(d_xs_up + d_xs_gate)
+    d_params = {
+        "w_gate": d_w_gate.reshape(params["w_gate"].shape),
+        "w_up": d_w_up.reshape(params["w_up"].shape),
+        "w_down": d_down.reshape(params["w_down"].shape),
+    }
+    return d_params, d_x, d_gates
+
+
+def _fits(picks, rows):
+    """Whether the picks that met a held expert fit `rows` rows."""
+    return jnp.sum(picks[2]) <= rows
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _budgeted_rows(rows, k, glu, masked, params, x, gates, picks):
+    """The expert part of a layer whose router is wider than the
+    experts held, exact at every load: `_held_rows` where the held
+    picks fit `rows`, `_all_rows` where they do not. `picks` are
+    `(picked, order, groups, live)`.
+
+    Why a `custom_vjp` and not the `lax.cond` alone: the backward of
+    a `cond` keeps the residuals of EVERY branch (zeros for the one
+    not taken), so the all-rows branch's `t x k` rows would be written
+    and held a layer a step although it never runs; at
+    `trinity-mini-ep8` the compiler counts that step at 16.44 of
+    15.75 GB (PERF.md section 6, PR 57). Here the forward hands the
+    backward `_held_rows`' residuals alone (zeros of that size where
+    the load spilled), and the backward's own `cond` either uses them
+    or, over the budget, differentiates `_all_rows` from the inputs."""
+    return lax.cond(
+        _fits(picks, rows),
+        lambda *inputs: _held_rows(*inputs, rows, k, glu)[0],
+        lambda *inputs: _all_rows(*inputs, k, glu, masked),
+        params, x, gates, picks,
+    )
+
+
+def _budgeted_rows_fwd(rows, k, glu, masked, params, x, gates, picks):
+    def held(*inputs):
+        return _held_rows(*inputs, rows, k, glu)
+
+    inputs = (params, x, gates, picks)
+    _, saved = jax.eval_shape(held, *inputs)
+
+    def spilled(*inputs):
+        zeros = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), saved)
+        return _all_rows(*inputs, k, glu, masked), zeros
+
+    out, saved = lax.cond(_fits(picks, rows), held, spilled, *inputs)
+    return out, (saved, *inputs)
+
+
+def _budgeted_rows_bwd(rows, k, glu, masked, residuals, dy):
+    def held(saved, *inputs):
+        return _held_rows_bwd(saved, *inputs, rows, k, glu, dy)
+
+    def spilled(saved, params, x, gates, picks):
+        return jax.vjp(
+            lambda params, x, gates: _all_rows(
+                params, x, gates, picks, k, glu, masked
+            ),
+            params, x, gates,
+        )[1](dy)
+
+    picks = residuals[-1]
+    cotangents = lax.cond(_fits(picks, rows), held, spilled, *residuals)
+    return (*cotangents, None)
+
+
+_budgeted_rows.defvjp(_budgeted_rows_fwd, _budgeted_rows_bwd)
+
+
 def moe_ffn_dropless(
     params: Dict,
     x: jax.Array,
@@ -195,6 +394,7 @@ def moe_ffn_dropless(
     layer: Optional[jax.Array] = None,
     routed: Optional[Tuple[jax.Array, jax.Array]] = None,
     first_expert: int = 0,
+    routed_over: int = 0,
 ):
     """Every expert local, no capacity. x: [tokens, d] ->
     (out [tokens, d], aux_loss, counts [E] int32: picks per expert).
@@ -203,11 +403,21 @@ def moe_ffn_dropless(
     (`route_grouped_sigmoid`) in the place of `route`'s; the auxiliary
     loss is then 0. With it the router may be WIDER than the experts
     held here, which are `first_expert` and those after it, as many as
-    the expert matrices hold: one rank's share of an expert-parallel
-    layer, run without its exchange. A pick of an expert held
-    elsewhere sorts past the last group, as a dead row's does, reads
-    no weight and adds nothing; `counts` are the picks that met a held
-    expert.
+    the expert matrices hold, of the `routed_over` the router deals
+    over: one rank's share of an expert-parallel layer, run without
+    its exchange. A pick of an expert held elsewhere sorts past the
+    last group, as a dead row's does, reads no weight and adds
+    nothing; `counts` are the picks that met a held expert. The held
+    picks are the first of the sorted picks, and where the router is
+    wider than the experts held only `held_row_budget` of the `t * k`
+    sorted rows are gathered, multiplied, gated, summed and
+    differentiated (`_held_rows`): an eighth of the router's experts
+    meet about an eighth of the picks, and the budget is twice that
+    share. How many picks ARE held is data, so the layer holds both
+    programs (`_budgeted_rows`): a load over the budget takes every
+    row (`_all_rows`), as a layer that holds all its experts does.
+    Nothing is dropped, clipped or approximated at any load; a load
+    only chooses how many dead rows ride along.
 
     `layer`: the experts' matrices are whole stacks `[layers, E, ., .]`
     and this is the layer to use. A loop over layers that slices its
@@ -252,29 +462,14 @@ def moe_ffn_dropless(
                 jnp.zeros(n_layers * num_experts, jnp.int32),
                 counts, (layer * num_experts,),
             )
-    with jax.named_scope("moe/experts"):
-        out = gated_experts(
-            params,
-            x[order // k],
-            # (a stack's two leading axes merge for free)
-            lambda rows, w: grouped_matmul(
-                rows, w.reshape((-1,) + w.shape[-2:]), groups
-            ),
-            glu,
-        )
-    with jax.named_scope("moe/combine"):
-        # Back to token order by the inverse permutation (a gather; a
-        # scatter-add of t*k rows serialises on the TPU), then the
-        # weighted sum of each token's k rows in float32.
-        out = out[jnp.argsort(order)].reshape(t, k, -1)
-        if live is not None:
-            # Rows behind the last group are whatever the kernel left.
-            out = jnp.where(live[:, None, None], out, 0)
-        if routed is not None:
-            out = jnp.where(
-                (picked < num_experts).reshape(t, k, 1), out, 0
-            )
-        out = jnp.sum(out * gates[:, :, None], axis=1)
+    weights = {name: params[name] for name in ("w_gate", "w_up", "w_down")}
+    masked = routed is not None
+    rows = held_row_budget(t * k, num_experts, routed_over if masked else 0)
+    picks = (picked, order, groups, live)
+    if rows == t * k:
+        out = _all_rows(weights, x, gates, picks, k, glu, masked)
+    else:
+        out = _budgeted_rows(rows, k, glu, masked, weights, x, gates, picks)
     return out.astype(x.dtype), aux, counts
 
 

@@ -328,7 +328,7 @@ def test_latent_attention_without_an_indexer_attends_every_visible_key(sound):
     plain, _, _ = _build(index_topk=0, index_n_heads=0, index_head_dim=0)
     every, _, _ = _build(index_topk=256)
     pool = g.init_block_pool(plain, 64, BL)
-    assert set(pool) == {"latent", "moe_counts", "moe_routed"}
+    assert set(pool) == {"latent", "moe_counts", "moe_routed", "moe_spilled"}
     a = _run(plain, sound["params"], sound["prompt"], steps=3)
     b = _run(every, sound["params"], sound["prompt"], steps=3)
     assert a[2] == b[2]  # the same greedy tokens

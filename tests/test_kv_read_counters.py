@@ -274,3 +274,55 @@ def test_a_dense_engine_reports_no_expert_counter():
         assert "moe_counts" not in engine._kv.pool
     finally:
         engine.close()
+
+
+@pytest.mark.parametrize("router", ["even", "skewed"])
+def test_engine_counts_the_expert_forwards_that_passed_their_row_budget(router):
+    """One rank's share of an expert layer computes `held_row_budget`
+    rows of a forward's picks and, where more picks than that meet a
+    held expert, every row (ops/moe.py): `moe_forwards_spilled` counts
+    the expert layers' forwards that did. A chunk of 256 tokens x 4
+    picks with 4 of 16 experts held has a budget of 512 rows of 1,024:
+    a drawn router deals the held experts a quarter of the picks; a
+    bias on the held experts deals them every pick."""
+    from ray_tpu.llm import EngineConfig, InferenceEngine
+    from ray_tpu.models.llama import LlamaConfig, init_params
+    from ray_tpu.ops.moe import held_row_budget
+
+    full = [0, 2, 1e4, False]
+    cfg = LlamaConfig(
+        vocab_size=128, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
+        custom_head_dim=16, intermediate=16, max_seq_len=512,
+        dtype=jnp.float32, layer_kinds=[full, full], moe_experts=4,
+        moe_top_k=4, moe_router="sigmoid_groups", moe_router_experts=16,
+        moe_first_expert=4,
+    )
+    chunk, new = 256, 3
+    assert held_row_budget(chunk * 4, 4, 16) == 512
+    # (a step's picks are fewer than the round number: no budget)
+    assert held_row_budget(2 * 4, 4, 16) == 2 * 4
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    if router == "skewed":
+        bias = jnp.zeros((2, 16)).at[:, 4:8].set(10.0)
+        params["layers"] = dict(params["layers"], router_bias=bias)
+    engine = InferenceEngine(
+        params, cfg,
+        EngineConfig(slots=2, max_len=512, prefill_chunk=chunk,
+                     max_new_tokens=new),
+        family="tiny",
+    )
+    try:
+        assert engine.stats()["moe_forwards_spilled"] == 0
+        prompt = np.random.default_rng(3).integers(1, 128, size=chunk).tolist()
+        assert len(list(engine.submit(prompt, max_new_tokens=new))) == new
+        stats = engine.stats()
+    finally:
+        engine.close()
+    assert stats["moe_chunk_layers"] == 2  # one chunk, two expert layers
+    assert stats["moe_picks_routed"] == 2 * 4 * (chunk + new)
+    if router == "skewed":
+        assert stats["moe_picks_prefill"] == 2 * 4 * chunk  # every pick held
+        assert stats["moe_forwards_spilled"] == 2  # the chunk's two layers
+    else:
+        assert stats["moe_picks_prefill"] < 2 * 512
+        assert stats["moe_forwards_spilled"] == 0

@@ -358,9 +358,9 @@ COUNTERS = {
     "dense_bias": set(),
     "moe_proj_norm": {"moe_counts"},
     "head_norm": set(),
-    "layer_kinds": {"moe_counts", "moe_routed"},
-    "latent": {"moe_counts", "moe_routed", "dsa_counts"},
-    "conv_kinds": {"moe_counts", "moe_routed"},
+    "layer_kinds": {"moe_counts", "moe_routed", "moe_spilled"},
+    "latent": {"moe_counts", "moe_routed", "moe_spilled", "dsa_counts"},
+    "conv_kinds": {"moe_counts", "moe_routed", "moe_spilled"},
 }
 
 

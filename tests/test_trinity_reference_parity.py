@@ -131,6 +131,60 @@ def test_every_leafs_gradient_matches_the_references(case, leaf):
     assert float(jnp.abs(got - want).max()) < 2e-5 * max(scale, 1.0), leaf
 
 
+def test_gradients_through_the_compacted_layer_match_over_all_positions():
+    """ISSUE 57. A sequence long enough that the rank's expert layers
+    compute their budget of rows and not every pick (400 tokens x 3
+    picks = 1,200 rows, 2 of 8 experts held: `held_row_budget` 1,024):
+    the gradient of the real `loss_fn` to an expert leaf and to the
+    EMBEDDED INPUT, position by position, against `jax.grad` of the
+    reference's loss. Every token id occurs once, so a row of the
+    embedding's gradient IS one position's gradient to the embedded
+    input, and the error is pooled over all 400 of them: the cell's own
+    `correct` reads one position's logits and holds no precision
+    (ROADMAP.md 1 g), so a wrong row would pass it."""
+    from ray_tpu.ops.moe import held_row_budget
+
+    seq = 400
+    model = _model(vocab_size=512, max_seq_len=512)
+    budget = held_row_budget(
+        seq * model["moe_top_k"], model["moe_experts"],
+        model["moe_router_experts"],
+    )
+    assert budget == 1024 < seq * model["moe_top_k"]
+    cfg = llama.LlamaConfig(**model, dtype=jnp.float32, attention="reference")
+    params = weights.make(model, "float32", 11, trinity_ref)
+    tokens = jax.random.permutation(jax.random.PRNGKey(12), 512)[:seq + 1]
+    with jax.default_matmul_precision("highest"):
+        loss, grads = jax.jit(jax.value_and_grad(
+            lambda p: llama.loss_fn(p, tokens[None, :-1], tokens[None, 1:], cfg)
+        ))(params)
+    want_loss, want_grads = jax.jit(jax.value_and_grad(
+        lambda p: trinity_ref.loss(p, tokens[:-1], tokens[1:], model, q_block=8)
+    ))(params)
+    assert abs(float(loss) - float(want_loss)) < 1e-5
+    # (a target's row takes no gradient through the embedding: untied)
+    got = {
+        "input": grads["embed"][tokens[:-1]],
+        "experts": grads["layers"]["w_up"],
+        "gates": grads["layers"]["router"],
+    }
+    want = {
+        "input": want_grads["embed"][tokens[:-1]],
+        "experts": want_grads["layers"]["w_up"],
+        "gates": want_grads["layers"]["router"],
+    }
+    for name in got:
+        scale = float(jnp.sqrt(jnp.mean(want[name] ** 2)))
+        assert scale > 1e-6, name
+        pooled = float(jnp.sqrt(jnp.mean((got[name] - want[name]) ** 2)))
+        assert pooled < 1e-4 * scale, (name, pooled / scale)
+    # every position's own row, not the pool alone
+    rows = jnp.sqrt(jnp.mean((got["input"] - want["input"]) ** 2, axis=-1))
+    assert float(rows.max()) < 1e-3 * float(
+        jnp.sqrt(jnp.mean(want["input"] ** 2))
+    )
+
+
 #: what the program would compute with a term of the block left out:
 #: the reference's own equations with that term dropped must NOT agree
 def _no_attn_post_norm(params):
