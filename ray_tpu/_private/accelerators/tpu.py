@@ -25,12 +25,21 @@ from __future__ import annotations
 import glob
 import os
 import re
+import sys
+import time
 from functools import lru_cache
 from typing import Dict, Iterable, Optional, Sequence, Tuple
 
+from ..worker_forkserver import _proc_stat
 from .base import AcceleratorManager
 
 TPU_VISIBLE_CHIPS_ENV = "TPU_VISIBLE_CHIPS"
+
+#: How long anything waits for the previous holder of a chip to be
+#: gone: a TPU spawn for a worker of its own daemon, a daemon's
+#: shutdown for the workers it killed, a TPU worker for another
+#: session's leftover.
+CHIP_WAIT_S = 30.0
 
 # Generation -> chips per host (a v5e host has 4 or 8 chips; 4 is the
 # common pod-slice shape; overridable via RT_TPU_CHIPS_PER_HOST).
@@ -86,6 +95,145 @@ def chip_scope_env(
         "TPU_CHIPS_PER_HOST_BOUNDS": bounds,
         "TPU_HOST_BOUNDS": "1,1,1",
     }
+
+
+def chip_device_files(chips: Sequence[int], dev: str = "/dev") -> set:
+    """Device files of `chips`: `/dev/accel<i>`, or the i-th numbered
+    group under `/dev/vfio` (a group's number is the IOMMU's, not the
+    chip's). Empty on a host without them."""
+    accel = {f"{dev}/accel{c}" for c in chips}
+    files = {f for f in accel if os.path.exists(f)}
+    try:
+        groups = sorted(
+            (e for e in os.listdir(f"{dev}/vfio") if e.isdigit()), key=int
+        )
+    except OSError:
+        groups = []
+    files.update(
+        f"{dev}/vfio/{groups[c]}" for c in chips if c < len(groups)
+    )
+    return files
+
+
+def chip_holders(
+    chips: Sequence[int],
+    skip: Iterable[int] = (),
+    proc: str = "/proc",
+    dev: str = "/dev",
+) -> list:
+    """Processes, other than `skip` and this one, that still hold
+    `chips`, as (pid, state, command): libtpu gives a chip to one
+    process at a time, and a worker scoped to a chip that its previous
+    holder has not let go fails at its first JAX call. A holder is
+
+    - a process with one of the chips' device files open
+      (`/proc/<pid>/fd`), or
+    - a thread group whose leader is a zombie and which still counts
+      other threads (`ps`: `Zl`): it has closed its files, so nothing
+      says WHAT it held, and the kernel's release of a device file
+      runs in exactly that state, for seconds after a four-chip
+      program's kill (PERF.md section 7). Counted only on a host that
+      has the device files, and only to be waited for a bounded time.
+
+    None on a host without the device files (CPU hosts, fake chips)."""
+    files = chip_device_files(chips, dev)
+    if not files:
+        return []
+    skip = set(skip) | {os.getpid()}
+    holders = []
+    try:
+        pids = [int(e) for e in os.listdir(proc) if e.isdigit()]
+    except OSError:
+        return []
+    for pid in pids:
+        stat = None if pid in skip else _proc_stat(pid, proc)
+        if stat is None:
+            continue
+        state, _, threads = stat
+        if state == "Z":
+            held = threads > 1
+        else:
+            held = False
+            try:
+                for fd in os.listdir(f"{proc}/{pid}/fd"):
+                    if os.readlink(f"{proc}/{pid}/fd/{fd}") in files:
+                        held = True
+                        break
+            except OSError:
+                pass
+        if held:
+            holders.append((
+                pid, state + ("l" if threads > 1 else ""),
+                # (a zombie has no command line left, only its name)
+                _proc_text(f"{proc}/{pid}/cmdline").replace("\0", " ").strip()
+                or f"[{_proc_text(f'{proc}/{pid}/comm').strip()}]",
+            ))
+    return holders
+
+
+def await_chip_holders(
+    chips: Sequence[int], timeout: float = CHIP_WAIT_S, **where
+) -> float:
+    """Wait, at most `timeout` seconds, until no other process holds
+    `chips` (`chip_holders`) -> the seconds waited. One line on
+    standard error when that was over a second, or when the chips are
+    still held and the caller goes on to find out for itself: how
+    long, for which pids, in which state."""
+    start = time.monotonic()
+    seen = holders = chip_holders(chips, **where)
+    while holders and time.monotonic() - start < timeout:
+        time.sleep(0.1)
+        seen, holders = holders, chip_holders(chips, **where)
+    waited = time.monotonic() - start
+    if holders or waited > 1.0:
+        print(
+            f"ray_tpu: waited {waited:.1f} s for chips {list(chips)}, "
+            "held by another session's "
+            + "; ".join(
+                f"pid {pid} ({state}) {cmd}" for pid, state, cmd in seen
+            )
+            + (": still held, going on" if holders else ""),
+            file=sys.stderr, flush=True,
+        )
+    return waited
+
+
+def wait_for_chips_at_tpu_init(chips: Sequence[int]) -> None:
+    """Called once by a worker scoped to `chips`, before it runs
+    anything: what another session left on the chips (its daemon gone,
+    its killed worker's threads 14-20 s over a four-chip device's
+    teardown) is waited for when THIS process's JAX initialises its
+    TPU backend, the last moment that is early enough. The worker's
+    own start (its imports, its first task's set-up: 9 s of a train
+    run's) then runs beside the leftover's teardown; waiting before
+    the spawn instead put all of the teardown into a run's set-up
+    (PERF.md section 6, PR 59). Where this JAX has no such seam the
+    wait is made here and now; on a host without the chips' device
+    files (fake chips) there is nobody to wait for."""
+    if not chip_device_files(chips):
+        return
+    try:
+        from jax._src import xla_bridge
+
+        init = xla_bridge._init_backend
+    except Exception:
+        await_chip_holders(chips)
+        return
+
+    def init_after_wait(platform):
+        if platform == "tpu":
+            await_chip_holders(chips)
+        return init(platform)
+
+    xla_bridge._init_backend = init_after_wait
+
+
+def _proc_text(path: str) -> str:
+    try:
+        with open(path, "rb") as f:
+            return f.read().decode("utf-8", "replace")
+    except OSError:
+        return ""
 
 
 def _env(*names: str) -> Optional[str]:

@@ -43,7 +43,7 @@ from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
-from .accelerators.tpu import chip_scope_env, pick_chips
+from .accelerators.tpu import CHIP_WAIT_S, chip_scope_env, pick_chips
 from .config import Config
 from .gcs import (
     ACTOR_ALIVE,
@@ -66,7 +66,7 @@ from .ids import (
 )
 from .object_store import ObjectStoreFullError, make_store
 from .spilling import FileSpillStorage
-from .stream_runs import StreamRuns
+from .stream_runs import STREAM_END_NOTE, StreamRuns
 from .placement_groups import (
     PGEntry,
     STRATEGIES,
@@ -1370,13 +1370,16 @@ class NodeDaemon:
         return {}
 
     def _h_stream_end(self, conn, msg):
+        note = {k: msg[k] for k in STREAM_END_NOTE if k in msg}
         if not self.is_head:
             self.head.notify(
                 "stream_end", task=msg["task"], count=msg.get("count"),
-                error=msg.get("error"),
+                error=msg.get("error"), **note,
             )
             return {}
-        self._streams.end(msg["task"], msg.get("count"), msg.get("error"))
+        self._streams.end(
+            msg["task"], msg.get("count"), msg.get("error"), note=note
+        )
         return {}
 
     def _h_stream_fetch(self, conn, msg):
@@ -4299,7 +4302,7 @@ class NodeDaemon:
 
     #: How long a TPU spawn waits for the previous holder of its
     #: chips to be gone before it counts as a failed spawn.
-    _CHIP_WAIT_S = 30.0
+    _CHIP_WAIT_S = CHIP_WAIT_S
 
     def _spawn_loop(self) -> None:
         # TPU spawns whose chips another process still holds, as
@@ -4344,8 +4347,12 @@ class NodeDaemon:
         when no live process spawned here is scoped to it — process
         liveness is the truth libtpu itself enforces, so there is no
         free list to keep in step with crashes, kills and
-        half-finished exits. Idle pooled TPU workers of another shape
-        are asked to exit to make room."""
+        half-finished exits (a killed worker whose threads are still
+        tearing the device down is alive: `ForkedProc.state`). What
+        ANOTHER session left behind on the chips is the worker's to
+        wait for, at the last moment (accelerators/tpu.py
+        `wait_for_chips_at_tpu_init`). Idle pooled TPU workers of
+        another shape are asked to exit to make room."""
         total = int(self.resources.get("TPU", 0))
         self._chip_procs = [
             (p, c) for p, c in self._chip_procs if p.poll() is None
@@ -6757,11 +6764,56 @@ class NodeDaemon:
                 proc.poll()
             except Exception:
                 pass
+        self._await_killed([p for p, _ in self._chip_procs])
         if self._fork_server is not None:
             try:
                 self._fork_server.close()
             except Exception:
                 pass
+
+    def _await_killed(self, procs) -> None:
+        """Wait for killed workers to be gone, bounded in TOTAL and
+        not per process (a per-proc 2 s timeout sums to hours across a
+        7k-worker pool on a loaded box, each stale handle that looks
+        alive burning its full slice; the kill already guarantees
+        death): ten seconds for CPU workers, `_CHIP_WAIT_S` for a
+        worker scoped to chips, whose kernel threads can take seconds
+        over the device's teardown after the kill (`ForkedProc.state`:
+        `Zl`). A session that returns before that leaves chips its
+        successor cannot open. Says so on standard error when it
+        waited over a second."""
+        chip_pids = {p.pid for p, _ in self._chip_procs}
+        start = time.monotonic()
+        pending = list(procs)
+        slow: Dict[int, str] = {}
+        while pending:
+            waited = time.monotonic() - start
+            alive = []
+            for proc in pending:
+                limit = self._CHIP_WAIT_S if proc.pid in chip_pids else 10.0
+                try:
+                    if proc.poll() is not None or waited >= limit:
+                        continue
+                except Exception:
+                    continue
+                alive.append(proc)
+                if waited > 1.0:
+                    state = getattr(proc, "state", lambda: None)()
+                    slow[proc.pid] = state or "alive"
+            pending = alive
+            if pending:
+                time.sleep(0.02)
+        if slow:
+            print(
+                f"ray_tpu: shutdown waited {time.monotonic() - start:.1f} s "
+                "for killed workers to be gone: "
+                + ", ".join(
+                    f"pid {pid} ({state}"
+                    + (", held chips)" if pid in chip_pids else ")")
+                    for pid, state in slow.items()
+                ),
+                file=sys.stderr, flush=True,
+            )
 
     def shutdown(self) -> None:
         self._shutdown = True
@@ -6777,19 +6829,7 @@ class NodeDaemon:
                 proc.kill()
             except ProcessLookupError:
                 pass
-        # Bounded TOTAL wait, not per-proc: a per-proc 2s timeout sums
-        # to hours across a 7k-worker pool on a loaded box (each stale
-        # handle that looks alive burns its full slice); the kill
-        # above already guarantees death.
-        wait_deadline = time.monotonic() + 10.0
-        for proc in self._worker_procs:
-            remaining = wait_deadline - time.monotonic()
-            if remaining <= 0:
-                break
-            try:
-                proc.wait(timeout=min(2.0, remaining))
-            except subprocess.TimeoutExpired:
-                pass
+        self._await_killed(self._worker_procs)
         # Whatever the deadline cut off still gets a non-blocking reap:
         # SIGKILLed-but-unwaited Popen children of this (long-lived,
         # in-process) daemon host would otherwise sit as zombies

@@ -24,6 +24,12 @@ from ray_tpu.devtools.lock_witness import make_lock
 #: A closed run is forgotten this long after it closed.
 CLOSED_KEPT_S = 600.0
 
+#: What a producer may say of its stream's end
+#: (worker.note_stream_end): the keys ride `stream_end` and then the
+#: answer that brings the end, as `first_ts` rides with the first
+#: item. A stream whose producer noted nothing carries none of them.
+STREAM_END_NOTE = ("handler_ms", "exhausted_ts", "end_ts")
+
 
 class _Run:
     __slots__ = (
@@ -36,7 +42,8 @@ class _Run:
         #: the object store under the item's id.
         self.items: List[Optional[bytes]] = []
         self.base = 0
-        #: (count or None, error payload or None) once the producer is
+        #: (count or None, error payload or None, the producer's note
+        #: of the end: a dict, mostly empty) once the producer is
         #: known to have stopped. With a count the end is delivered
         #: only behind that many items: an error that travelled by
         #: another connection can overtake the last appends.
@@ -79,9 +86,9 @@ class StreamRuns:
         items = run.items[max(after - run.base, 0):]
         end = None
         if run.end is not None:
-            count, error = run.end
+            count, error, note = run.end
             if count is None or have >= count:
-                end = {"count": have, "error": error}
+                end = {"count": have, "error": error, **note}
         if not items and end is None:
             return None
         run.parked = None
@@ -125,13 +132,14 @@ class StreamRuns:
         count: Optional[int],
         error: Optional[bytes],
         create: bool = True,
+        note: Optional[dict] = None,
     ) -> None:
         with self._lock:
             run = self._run(task) if create else self._runs.get(task)
             if run is None or run.closed_at is not None:
                 return
             if run.end is None:
-                run.end = (count, error)
+                run.end = (count, error, note or {})
             answer = self._answer(run)
         self._send(answer)
 

@@ -466,9 +466,13 @@ SCHEMAS: Dict[str, Dict[str, Any]] = {
         "task": bytes, "index": int, "data": (bytes, type(None)),
         "?first_ts": float,
     },
+    # `handler_ms`, `exhausted_ts`, `end_ts`: what a producer that
+    # noted its stream's end (worker.note_stream_end: serve's
+    # replicas) says of it; no other stream sets them.
     "stream_end": {
         "task": bytes, "?count": (int, type(None)),
         "?error": (bytes, type(None)),
+        "?handler_ms": float, "?exhausted_ts": float, "?end_ts": float,
     },
     "stream_fetch": {"task": bytes, "after": int},
     "stream_close": {"task": bytes},

@@ -103,6 +103,27 @@ def global_worker() -> Optional["CoreWorker"]:
     return _global_worker
 
 
+#: What the generator a streaming task is running has said of its own
+#: end, on the thread that runs it (`_collect_returns` drives the
+#: generator there, and sends the note on with `stream_end`).
+_stream_end_note = threading.local()
+
+
+def note_stream_end(**note: float) -> None:
+    """Called by a streaming task's generator as it runs out (from its
+    `finally`): floats that ride the stream's end to its consumer
+    (`ObjectRefGenerator.end_note`), the keys of
+    stream_runs.STREAM_END_NOTE. Outside a streaming task the note is
+    dropped by the next one."""
+    _stream_end_note.note = note
+
+
+def _take_stream_end_note() -> Optional[dict]:
+    note = getattr(_stream_end_note, "note", None)
+    _stream_end_note.note = None
+    return note
+
+
 def set_global_worker(worker: Optional["CoreWorker"]) -> None:
     global _global_worker
     with _global_lock:
@@ -2127,6 +2148,7 @@ class CoreWorker:
         streaming = mode == "streaming"
         task = task_id.binary()
         count = 0
+        _take_stream_end_note()  # a stale one, of a task that raised
         try:
             for item in value:
                 oid = ObjectID.for_return(task_id, count + 2)
@@ -2151,7 +2173,16 @@ class CoreWorker:
             e.__rt_items_emitted__ = count
             raise
         if streaming:
-            self._client.notify("stream_end", task=task, count=count)
+            # What the generator noted as it ran out (serve: E0 of a
+            # stream's end, observability.py), with the time the end
+            # is handed to the transport (E1) beside it; nothing for
+            # a generator that noted nothing.
+            note = _take_stream_end_note()
+            if note:
+                note = dict(note, end_ts=time.time())
+            self._client.notify(
+                "stream_end", task=task, count=count, **(note or {})
+            )
             return [count]
         from ..object_ref import ObjectRefGenerator
 

@@ -78,16 +78,16 @@ def _run_child(log_path: str, env: dict) -> None:
         os._exit(0)
 
 
-def _proc_stat(pid: int):
-    """(state, start time in clock ticks since boot) of `pid` from
-    /proc/<pid>/stat, or None if the process is gone. Fields 3 and 22;
-    parse after the last ')' — the comm field may itself contain
-    spaces or parens."""
+def _proc_stat(pid: int, root: str = "/proc"):
+    """(state, start time in clock ticks since boot, threads) of `pid`
+    from /proc/<pid>/stat, or None if the process is gone. Fields 3,
+    22 and 20; parse after the last ')' — the comm field may itself
+    contain spaces or parens."""
     try:
-        with open(f"/proc/{pid}/stat", "rb") as f:
+        with open(f"{root}/{pid}/stat", "rb") as f:
             stat = f.read().decode("ascii", "replace")
         fields = stat.rsplit(")", 1)[1].split()
-        return fields[0], int(fields[19])
+        return fields[0], int(fields[19]), int(fields[17])
     except (OSError, IndexError, ValueError):
         return None
 
@@ -95,6 +95,29 @@ def _proc_stat(pid: int):
 def _proc_starttime(pid: int):
     stat = _proc_stat(pid)
     return None if stat is None else stat[1]
+
+
+def proc_state(pid: int, starttime, root: str = "/proc"):
+    """None when the process forked as `pid` at `starttime` is GONE,
+    else its state as `ps` spells it ("S", "Sl", "Zl"). Gone is: no
+    such pid, another start time (the pid was recycled), or a zombie
+    with no other thread. A zombie LEADER whose thread group still
+    counts other threads (`Zl`) is not gone: they are in the kernel,
+    tearing down what the process held, and a chip stays held until
+    the last of them is through (14-17 s after a four-chip gang
+    worker's kill, PERF.md section 7). The count is `num_threads` of
+    the leader's stat, as `ps` reads it: on the chip's hosts
+    /proc/<pid>/task lists nothing by then."""
+    stat = _proc_stat(pid, root)
+    if starttime is None or stat is None or stat[1] != starttime:
+        return None
+    state, _, threads = stat
+    if state == "Z" and threads <= 1:
+        # Exited and not yet reaped by the template (its reaper naps
+        # between children): sockets, arena pins and chips are
+        # released.
+        return None
+    return state + ("l" if threads > 1 else "")
 
 
 class ForkedProc:
@@ -106,7 +129,7 @@ class ForkedProc:
     for the whole watch window). Liveness = pid exists AND its
     /proc starttime matches the one captured at fork."""
 
-    def __init__(self, pid: int, starttime=None):
+    def __init__(self, pid: int, starttime=None, proc_root: str = "/proc"):
         self.pid = pid
         self._returncode = None
         # The template reports the starttime it read while the child
@@ -116,31 +139,25 @@ class ForkedProc:
         # already dead, and poll() reports it so without ever
         # trusting the (possibly recycled) pid.
         self._starttime = starttime
+        self._proc_root = proc_root
+
+    def state(self):
+        """`proc_state` of this child: None once it is gone."""
+        if self._returncode is not None:
+            return None
+        return proc_state(self.pid, self._starttime, self._proc_root)
 
     def poll(self):
         if self._returncode is not None:
             return self._returncode
         try:
             os.kill(self.pid, 0)
-        except ProcessLookupError:
+        except (ProcessLookupError, PermissionError):
+            # No such pid, or reused by another user's process: ours
+            # is gone.
             self._returncode = 0
             return 0
-        except PermissionError:
-            # pid reused by another user's process: ours is gone.
-            self._returncode = 0
-            return 0
-        stat = _proc_stat(self.pid)
-        if (
-            self._starttime is None
-            or stat is None
-            or stat[1] != self._starttime
-            # Exited but not yet reaped by the template (its reaper
-            # naps between children): everything the process held —
-            # sockets, arena pins, chips — is already released.
-            or stat[0] == "Z"
-        ):
-            # Otherwise: same pid, different (or vanished) start time
-            # means the pid was recycled after our child exited.
+        if self.state() is None:
             self._returncode = 0
             return 0
         return None

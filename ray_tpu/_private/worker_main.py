@@ -13,9 +13,13 @@ def main() -> None:
     if os.environ.get("RT_WORKER_CHIPS"):
         # This process owns chips: place XLA's persistent cache before
         # anything it runs can compile.
+        from .accelerators.tpu import wait_for_chips_at_tpu_init
         from .compile_cache import ensure_compile_cache
 
         ensure_compile_cache()
+        wait_for_chips_at_tpu_init(
+            [int(c) for c in os.environ["RT_WORKER_CHIPS"].split(",")]
+        )
     profile_dir = os.environ.get("RT_WORKER_PROFILE")
     prof = None
     if profile_dir:

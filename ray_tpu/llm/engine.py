@@ -56,6 +56,30 @@ saw N's tokens, and writes nothing there. The price: a finished
 row's slot and blocks come back one iteration after its last step.
 Depth is one iteration, a constant of this loop.
 
+Why the queue stands (ISSUE 59). While a request waits, the queue
+stands for exactly ONE cause, that of its FIFO head (admission is
+strict FIFO, so the head's cause is every waiter's), decided in this
+order from what the loop reads anyway: `no_slot` (the scheduler has no
+free slot: nothing else could admit the head) -> `behind_prefill` (a
+slot is free and another prompt is prefilling: the one-prompt-at-a-time
+rule ALONE holds the head, the time a second prefilling prompt would
+win back) -> `no_pages` / `no_window_pages` / `no_state_slots` (the
+pool for which the gate refused the head at the loop's last attempt;
+the gate's word stands until the gate is asked again) -> `admissible`
+(nothing is known to be in the way and the loop has not come round:
+the loop's own latency). `queue_ms` is the time each cause has stood
+and `slot_ms` the slots x time in each state (`decoding`, `prefilling`,
+`empty_queued`: empty while the queue stands, what any admission
+change can win, `empty_idle`: empty with no waiter), both advanced
+under the lock at the transitions that change them (a submission, an
+admission attempt, a release, a prompt's last chunk, a cancelled
+waiter) with one `perf_counter` reading each, the clock the loop's
+phases and their profiler annotations run on: O(1) an iteration,
+nothing per token or per waiter. A request takes the clocks' reading
+at `submit()`; what they gained by its admission is ITS wait by cause
+(`admit_wait_by_cause_ms_total`, whose values sum to
+`admit_wait_ms_total`: the same readings).
+
 Requests are host-side objects; per-request device state is the pages
 its table points at, one row of `last_logits` and one row of the step
 state. Sampling parameters stay engine-level statics (jit statics in
@@ -111,9 +135,15 @@ import numpy as np
 
 from .._private import compile_watch
 from .._private.step_telemetry import phase_timer, take_phases
+from ..serve.observability import QUEUE_CAUSES
 from ..util import tracing
 from .kv_slots import NULL_BLOCK, PagedKVCache
 from .scheduler import EngineDead, EngineOverloaded, SlotScheduler
+
+#: The causes the gate gives (module docstring: why the queue
+#: stands), and what a slot is doing.
+_POOL_CAUSES = frozenset(("no_pages", "no_window_pages", "no_state_slots"))
+SLOT_STATES = ("decoding", "prefilling", "empty_queued", "empty_idle")
 
 __all__ = [
     "EngineConfig",
@@ -185,7 +215,7 @@ class _Request:
         "prefix_keys", "total_blocks", "block_ids", "n_shared",
         "skip", "gen", "submitted_ns", "admitted_ts", "decoding_ts",
         "trace_parent", "serve_request_id", "table", "dispatched",
-        "window_copy", "state_read",
+        "window_copy", "state_read", "queue_snap", "queue_cause_ms",
     )
 
     def __init__(
@@ -215,6 +245,10 @@ class _Request:
         self.submitted_ns = time.time_ns()
         self.admitted_ts: Optional[float] = None
         self.decoding_ts: Optional[float] = None
+        #: `queue_ms` as it stood at `submitted_ts`, and from admission
+        #: what it gained since: this request's wait by cause.
+        self.queue_snap: Optional[Dict[str, float]] = None
+        self.queue_cause_ms: Dict[str, float] = {}
         self.trace_parent: Optional[dict] = None
         self.serve_request_id = ""
         # prefill progress (engine thread only)
@@ -536,6 +570,17 @@ class InferenceEngine:
         self._loop_iterations = 0
         self._admitted = 0
         self._admit_wait_ms_total = 0.0
+        # The queue by cause and slot-time by state (module
+        # docstring): the standing cause and the slots in each state
+        # as `_settle_locked` left them, and the reading the clocks
+        # stand at.
+        self._queue_cause: Optional[str] = None
+        self._gate_refusal: Optional[str] = None
+        self._queue_ms = dict.fromkeys(QUEUE_CAUSES, 0.0)
+        self._admit_wait_by_cause = dict.fromkeys(QUEUE_CAUSES, 0.0)
+        self._slot_ms = dict.fromkeys(SLOT_STATES, 0.0)
+        self._slots_by_state = (0, 0, 0, ec.slots if cfg is not None else 0)
+        self._acct_t0 = self._acct_ts = time.perf_counter()
         self._first_tokens = 0
         self._prefill_ms_total = 0.0
         # How much of what the chunks compute the prompts need: chunks
@@ -698,6 +743,16 @@ class InferenceEngine:
                 )
             self._sched.submit(req)
             self._by_id[req.request_id] = req
+            # The request's own reading serves the clocks, unless a
+            # transition has read the clock since: what lies between
+            # the two goes to the cause that stands as it queues.
+            now = max(req.submitted_ts, self._acct_ts)
+            self._tick_locked(now)
+            self._settle_locked()
+            req.queue_snap = dict(self._queue_ms)
+            req.queue_snap[self._queue_cause] -= (
+                now - req.submitted_ts
+            ) * 1e3
         self._wake.set()
         return TokenStream(self, req)
 
@@ -712,7 +767,11 @@ class InferenceEngine:
                 return False
             req.cancelled.set()
             if self._sched.remove_waiting(req):
+                # Its share of the wait goes with it; the queue's
+                # cause is its next head's.
+                self._tick_locked(time.perf_counter())
                 self._finish_locked(req, "cancelled")
+                self._settle_locked()
         self._wake.set()
         return True
 
@@ -805,6 +864,8 @@ class InferenceEngine:
                 self._sched.stats() if self._sched is not None
                 else {"slots_total": 0, "slots_used": 0, "waiting": 0}
             )
+            if self._sched is not None:
+                self._tick_locked(time.perf_counter())
             out.update(
                 family=self.family,
                 steps=self._steps,
@@ -816,7 +877,6 @@ class InferenceEngine:
                 prefix_tokens_saved=self._prefix_tokens_saved,
                 weight_version=self._weight_version,
                 weight_gens=len(self._gens),
-                policy_pending_rows=self._policy_rows_pending,
                 policy_steps=self._policy_steps,
                 policy_rows_served=self._policy_rows_served,
                 dead=self._dead is not None,
@@ -828,6 +888,13 @@ class InferenceEngine:
                 loop_iterations=self._loop_iterations,
                 admitted=self._admitted,
                 admit_wait_ms_total=self._admit_wait_ms_total,
+                # Why they waited, how long each cause has stood, and
+                # what the slots did meanwhile (module docstring).
+                admit_wait_by_cause_ms_total=dict(
+                    self._admit_wait_by_cause
+                ),
+                queue_ms=dict(self._queue_ms),
+                slot_ms=dict(self._slot_ms),
                 # Admission to first token, of the requests that
                 # reached one: the chunks of their prompts AND the
                 # decode steps that ran between them.
@@ -882,7 +949,6 @@ class InferenceEngine:
                     )
                 }
                 out.update(
-                    kv_bytes=self._kv.nbytes(),
                     kv_block_len=self._kv.block_len,
                     **self._kv.full.stats(),
                 )
@@ -1085,6 +1151,7 @@ class InferenceEngine:
         (its table row there goes stale, which a dead row's never
         matters: the step program reads the null block for it); a
         cancellation the caller patches in (`_patch_slot`)."""
+        self._tick_locked(time.perf_counter())
         self._sched.release(slot)
         self._alive[slot] = False
         for rows in self._mirror.values():
@@ -1109,6 +1176,48 @@ class InferenceEngine:
             req.block_ids = []
         self._unpin_gen_locked(req)
         self._finish_locked(req, reason)
+        self._settle_locked()
+
+    # -- the queue by cause, slot-time by state (module docstring) -----
+    def _tick_locked(self, now: float) -> None:
+        """Advance the cause and slot clocks to `now`, by the state
+        `_settle_locked` left: what stood SINCE the reading before."""
+        dt = (now - self._acct_ts) * 1e3
+        if dt <= 0.0:
+            return
+        self._acct_ts = now
+        if self._queue_cause is not None:
+            self._queue_ms[self._queue_cause] += dt
+        for state, n in zip(SLOT_STATES, self._slots_by_state):
+            if n:
+                self._slot_ms[state] += n * dt
+
+    def _settle_locked(self, refused: Optional[str] = None) -> None:
+        """After a transition: the cause the queue stands for from
+        here on, in the module docstring's order, and the slots by
+        state. `refused` is the gate's word of the attempt just made;
+        without an attempt a pool's refusal stands as it stood."""
+        sched = self._sched
+        waiting = len(sched.waiting)
+        prefilling = self._prefilling is not None
+        if not waiting:
+            cause = None
+        elif not sched.free_slots:
+            cause = "no_slot"
+        elif prefilling:
+            cause = "behind_prefill"
+        elif refused is not None:
+            cause = refused
+        elif self._queue_cause in _POOL_CAUSES:
+            cause = self._queue_cause
+        else:
+            cause = "admissible"
+        self._queue_cause = cause
+        used, empty = len(sched.running), sched.free_slots
+        self._slots_by_state = (
+            used - prefilling, int(prefilling),
+            empty if waiting else 0, 0 if waiting else empty,
+        )
 
     def _unpin_gen_locked(self, req: _Request) -> None:
         if req.gen is None:
@@ -1153,6 +1262,10 @@ class InferenceEngine:
             engine_request_id=req.request_id,
             family=self._tags["family"],
             queue_ms=round((admitted - req.submitted_ts) * 1e3, 3),
+            queue_cause_ms=",".join(
+                f"{cause}={ms:.3f}"
+                for cause, ms in req.queue_cause_ms.items()
+            ),
             prefill_ms=round((decoding - admitted) * 1e3, 3),
             decode_ms=round((now - decoding) * 1e3, 3),
             tokens=req.emitted,
@@ -1189,6 +1302,9 @@ class InferenceEngine:
             self._policy_rows_pending -= preq.n
             preq.error = error
             preq.done.set()
+        if self._sched is not None:
+            self._tick_locked(time.perf_counter())
+            self._settle_locked()
         self._observe_occupancy()
 
     # -- admission / block allocation ---------------------------------
@@ -1222,7 +1338,14 @@ class InferenceEngine:
         return skip
 
     def _gate_locked(self, req: _Request) -> bool:
-        """Admission gate: can the FIFO head get its blocks NOW? The
+        """`admit_next`'s gate; the pool that refused stays in
+        `_gate_refusal` for the cause clocks."""
+        self._gate_refusal = self._gate_cause_locked(req)
+        return self._gate_refusal is None
+
+    def _gate_cause_locked(self, req: _Request) -> Optional[str]:
+        """Admission gate: can the FIFO head get its blocks NOW (None)
+        and, if not, which pool refuses it (a queue cause)? The
         reservation needs `total - skip` fresh blocks, and pinning the
         hit additionally consumes `cached` availability — only the
         hit blocks that are currently refcount-0 (cached-free) leave
@@ -1241,15 +1364,14 @@ class InferenceEngine:
         if self._kv.window is not None and not self._kv.window.gate(
             req.prefix_keys, skip, req.total_blocks
         ):
-            return False
+            return "no_window_pages"
         if self._kv.state is not None and not self._kv.state.gate(
             req.prefix_keys, skip
         ):
-            return False
-        return (
-            alloc.available() - cached
-            >= req.total_blocks - skip_blocks
-        )
+            return "no_state_slots"
+        if alloc.available() - cached < req.total_blocks - skip_blocks:
+            return "no_pages"
+        return None
 
     def _allocate_locked(self, req: _Request) -> None:
         """Pin the request's prefix-cache hit (if any) and reserve the
@@ -1353,18 +1475,29 @@ class InferenceEngine:
             req = self._prefilling
             admitting = req is None
             if admitting:
+                self._gate_refusal = None
                 admitted = self._sched.admit_next(
                     gate=self._gate_locked
                 )
                 if admitted is None:
+                    if self._sched.waiting:
+                        # The attempt's word on why the queue stands.
+                        self._tick_locked(time.perf_counter())
+                        self._settle_locked(self._gate_refusal)
                     return
                 req, slot = admitted
                 req.slot = slot
                 req.admitted_ts = time.perf_counter()
+                self._tick_locked(req.admitted_ts)
                 self._admitted += 1
                 self._admit_wait_ms_total += (
                     req.admitted_ts - req.submitted_ts
                 ) * 1e3
+                for cause, ms in self._queue_ms.items():
+                    waited = ms - req.queue_snap[cause]
+                    if waited:
+                        req.queue_cause_ms[cause] = waited
+                        self._admit_wait_by_cause[cause] += waited
                 # Pin the weight generation at ADMISSION: everything
                 # this request computes — every prefill chunk and
                 # every decode step — uses these params, even if a
@@ -1374,6 +1507,7 @@ class InferenceEngine:
                 self._gens[req.gen]["refs"] += 1
                 self._allocate_locked(req)
                 self._prefilling = req
+                self._settle_locked()
         slot = req.slot
         if admitting:
             req.table = self._kv.row_table(slot, req.block_ids)
@@ -1409,6 +1543,7 @@ class InferenceEngine:
             # admits the next prompt.
             req.padded = None
             with self._lock:
+                self._tick_locked(time.perf_counter())
                 self._prefilling = None
                 # Cancelled during the prompt: reap now rather than
                 # decoding a dead row for one step.
@@ -1437,6 +1572,7 @@ class InferenceEngine:
                         if 0 <= req.eos_token < self.cfg.vocab_size
                         else -1
                     )
+                    self._settle_locked()
         phase.switch("engine.prefill.dispatch")
         t0 = self._dispatching()
         self._prefill_chunks += 1
@@ -1947,13 +2083,16 @@ class InferenceEngine:
     # never fail a decode (serve/observability.py owns the metric
     # definitions; the engine just reports).
 
-    def _block_stats(self) -> Dict[str, int]:
+    def _block_stats(self) -> Dict[str, Any]:
+        """What the occupancy gauges take beside the slots: the pool's
+        blocks and the cause the queue stands for."""
         if self._kv is None:
             return {"kv_used": 0, "kv_total": 0}
         alloc = self._kv.full
         return {
             "kv_used": alloc.used(),
             "kv_total": alloc.capacity(),
+            "queue_cause": self._queue_cause,
         }
 
     def _observe_step(

@@ -61,8 +61,10 @@ class SlotScheduler:
 
     def admit_next(self, gate=None) -> Optional[Tuple[Any, int]]:
         """Pop the FIFO head into a free slot; None when nothing can
-        be admitted (no waiters or no free slot). `gate(request) ->
-        bool` may veto the head — the paged engine gates on KV-block
+        be admitted: no waiters, no free slot, or the gate's veto,
+        which the caller tells apart by `waiting`, `free_slots` and
+        what its own gate said, without asking the gate again.
+        `gate(request) -> bool` may veto the head — the paged engine gates on KV-block
         availability — and a vetoed head STAYS the head: admission
         remains strict FIFO (no skip-ahead), so a big request waits
         for blocks instead of being starved by smaller ones."""
@@ -109,6 +111,10 @@ class SlotScheduler:
     @property
     def waiting(self) -> Deque[Any]:
         return self._waiting
+
+    @property
+    def free_slots(self) -> int:
+        return len(self._free)
 
     def stats(self) -> Dict[str, int]:
         return {

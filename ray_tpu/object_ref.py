@@ -130,7 +130,9 @@ class ObjectRefGenerator:
         self._primary_ref: ObjectRef | None = primary_ref
         #: Serialized items fetched and not handed out yet.
         self._fetched: deque = deque()
-        #: {"count", "error"} once the daemon has said so.
+        #: {"count", "error"} once the daemon has said so, with what
+        #: the producer noted of the end, if it noted anything
+        #: (stream_runs.STREAM_END_NOTE).
         self._end: dict | None = None
         self._closed = False
         #: What the consumer counts of its transport: items received
@@ -142,6 +144,15 @@ class ObjectRefGenerator:
         #: the answer that brought it (None before, and where the
         #: producer stamped none).
         self.first_item_ts: float | None = None
+
+    @property
+    def end_note(self) -> dict:
+        """What the producer noted of the stream's end ({} before the
+        end, and for a producer that noted nothing)."""
+        return {
+            k: v for k, v in (self._end or {}).items()
+            if k not in ("count", "error")
+        }
 
     def _ref(self, object_id: ObjectID) -> ObjectRef:
         return ObjectRef(object_id, owner=self._owner)

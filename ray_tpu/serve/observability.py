@@ -52,10 +52,38 @@ per adjacent pair, observed ONCE per request and never per token:
   serve_http_first_byte_ms        B0->B7 proxy: the program's own time
                                   to first token
 
+A streamed request's END continues them with three more, and what
+they are for is one identity per request: its time above the replica.
+
+  E0 the handler's generator is exhausted (replica; its perf_counter
+     for the handler's own duration B2->E0, the epoch beside it)
+  E1 the run's end is handed to the transport (the replica's worker,
+     `_collect_returns`, where `stream_end` is notified)
+  E2 the last body bytes are written and flushed (proxy)
+
+  serve_ingress_overhead_ms       (B0->E2 on the proxy's clock) less
+                                  (B2->E0 on the replica's): the SAME
+                                  request on both sides of the minus,
+                                  observed by the proxy, which the
+                                  stream's end tells the handler's
+                                  duration (`handler_ms`)
+  serve_stream_end_handoff_ms     E0->E1 the replica's interpreter
+  serve_stream_end_transit_ms     E1->E2 head daemon, parked fetch, the
+                                  proxy's last write (epoch clocks of
+                                  two processes, as
+                                  serve_first_item_transit_ms)
+
+All three are the proxy's, once per streamed request that ended
+cleanly; E0 and E1 reach it as optional keys of the stream's end
+(`_private/worker.py` note_stream_end, stream_runs.STREAM_END_NOTE),
+which no other stream sets. What the overhead holds beyond the way in
+(serve_http_dispatch_ms + serve_queue_wait_ms) and the end (handoff +
+transit) is the tokens' way out: every item's trip behind the first.
+
 The same readings ride the request's three spans as attributes
-(`serve.http` first_byte_ms; `serve.handle` queue_wait_ms, submit_ms,
-first_item_ms; `engine.request` first_token_ms), each counted from its
-span's start.
+(`serve.http` first_byte_ms, ingress_overhead_ms; `serve.handle`
+queue_wait_ms, submit_ms, first_item_ms; `engine.request`
+first_token_ms, queue_cause_ms), each counted from its span's start.
 
 All ride the existing metrics pipe (util/metrics) to the head, so
 they show up in `metrics_summary()`, the Prometheus endpoint and the
@@ -99,6 +127,7 @@ __all__ = [
     "observe_routing",
     "observe_http_dispatch",
     "observe_http_first_byte",
+    "observe_http_stream_end",
     "observe_queue_wait",
     "observe_handler_submit",
     "observe_first_item",
@@ -358,6 +387,45 @@ def observe_http_first_byte(
     add_span_attributes(first_byte_ms=round(first_byte_ms, 3))
 
 
+def observe_http_stream_end(
+    app: str, deployment: str, total_ms: float, note: Optional[dict]
+) -> None:
+    """Proxy, at the last body bytes of a streamed response (E2):
+    the request's time above the replica, `total_ms` (B0->E2, the
+    caller's reading) less the handler's own duration that the
+    stream's end carried back, onto the series and the `serve.http`
+    span; and the end's two stages from the epochs beside it. A
+    stream whose end carried no note (its producer stamped none)
+    observes nothing."""
+    if not _ENABLED or not note or "handler_ms" not in note:
+        return
+    from ..util.tracing import add_span_attributes
+
+    now = time.time()  # E2 on the epoch clock, beside the caller's reading
+    overhead_ms = total_ms - float(note["handler_ms"])
+    _observe_stage(
+        "serve_ingress_overhead_ms",
+        "A streamed request's time in the proxy less its handler's "
+        "time in the replica, per request",
+        app, deployment, overhead_ms,
+    )
+    exhausted_ts, end_ts = note.get("exhausted_ts"), note.get("end_ts")
+    if exhausted_ts is not None and end_ts is not None:
+        _observe_stage(
+            "serve_stream_end_handoff_ms",
+            "Handler generator exhausted to the stream's end handed to "
+            "the transport, per streamed request",
+            app, deployment, (end_ts - exhausted_ts) * 1e3,
+        )
+        _observe_stage(
+            "serve_stream_end_transit_ms",
+            "Stream's end handed to the transport to the proxy's last "
+            "bytes written, per streamed request",
+            app, deployment, (now - end_ts) * 1e3,
+        )
+    add_span_attributes(ingress_overhead_ms=round(overhead_ms, 3))
+
+
 def observe_stream(
     app: str, deployment: str, items: int, fetches: int
 ) -> None:
@@ -564,6 +632,15 @@ def replica_executing(
 
 ENGINE_TAGS = ("app", "deployment", "family")
 
+#: Why an engine's waiting queue stands, in the order the cause of its
+#: FIFO head is decided (llm/engine.py's module docstring; here
+#: because the gauge that carries it is folded in processes that
+#: import no engine).
+QUEUE_CAUSES = (
+    "no_slot", "behind_prefill", "no_pages", "no_window_pages",
+    "no_state_slots", "admissible",
+)
+
 #: Decode-batch-size bucket boundaries (requests per step).
 BATCH_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0)
 
@@ -584,6 +661,7 @@ def observe_engine_step(
     waiting: int,
     kv_used: Optional[int] = None,
     kv_total: Optional[int] = None,
+    queue_cause: Optional[str] = None,
 ) -> None:
     """Engine: one decode iteration over the slot batch."""
     if not _ENABLED:
@@ -606,7 +684,7 @@ def observe_engine_step(
             ).inc(float(tokens), tags=tags)
         _engine_gauges(
             tags, slots_used, slots_total, waiting,
-            kv_used, kv_total,
+            kv_used, kv_total, queue_cause,
         )
     except Exception:
         pass
@@ -695,6 +773,7 @@ def observe_engine_occupancy(
     waiting: int,
     kv_used: Optional[int] = None,
     kv_total: Optional[int] = None,
+    queue_cause: Optional[str] = None,
 ) -> None:
     """Engine: occupancy push OUTSIDE the decode step — cancellation,
     request retirement, and engine unload all free slots (and unpin
@@ -705,10 +784,14 @@ def observe_engine_occupancy(
     try:
         _engine_gauges(
             tags, slots_used, slots_total, waiting,
-            kv_used, kv_total,
+            kv_used, kv_total, queue_cause,
         )
     except Exception:
         pass
+
+
+def _queue_cause_code(cause: Optional[str]) -> int:
+    return QUEUE_CAUSES.index(cause) + 1 if cause in QUEUE_CAUSES else 0
 
 
 def _engine_gauges(
@@ -718,6 +801,7 @@ def _engine_gauges(
     waiting: int,
     kv_used: Optional[int] = None,
     kv_total: Optional[int] = None,
+    queue_cause: Optional[str] = None,
 ) -> None:
     """Slot-occupancy + KV-block gauges, throttled like
     replica_executing: zero-crossing edges always push, same-sign
@@ -745,6 +829,12 @@ def _engine_gauges(
             "serve_engine_waiting",
             "Requests queued for a free engine slot",
             waiting,
+        ),
+        (
+            "serve_engine_queue_cause",
+            "Why the engine's queue stands: 0 while nobody waits, else "
+            "1 + the cause's place in QUEUE_CAUSES",
+            _queue_cause_code(queue_cause),
         ),
     ]
     if kv_used is not None:
@@ -887,6 +977,13 @@ def _fold_engine(summary: Dict[str, dict], row, out) -> None:
             "waiting", float(s.get("value", 0.0) or 0.0)
         ),
     )
+
+    def queue_cause(target: dict, series: dict) -> None:
+        code = int(series.get("value", 0.0) or 0.0)
+        if 0 < code <= len(QUEUE_CAUSES):
+            target["queue_cause"] = QUEUE_CAUSES[code - 1]
+
+    fold("serve_engine_queue_cause", queue_cause)
     fold(
         "serve_engine_tokens_total",
         lambda t, s: t.__setitem__(

@@ -505,7 +505,10 @@ class Proxy:
         first chunk written is B7 of the request's first-token stages
         (observability.py): one reading, then the loop goes on over
         the same iterator with nothing per chunk."""
-        from .observability import observe_http_first_byte
+        from .observability import (
+            observe_http_first_byte,
+            observe_http_stream_end,
+        )
 
         handler.send_response(200)
         handler.send_header("Content-Type", "text/plain; charset=utf-8")
@@ -550,6 +553,13 @@ class Proxy:
                 for chunk in chunks:
                     write(chunk)
                 clean = True
+                # E2: the last bytes are written and flushed.
+                observe_http_stream_end(
+                    target["app"],
+                    target["deployment"],
+                    (time.perf_counter() - target["t0"]) * 1e3,
+                    getattr(chunks, "end_note", None),
+                )
             finally:
                 # Releases the router's ongoing-count slot even when
                 # the client disconnected mid-stream.

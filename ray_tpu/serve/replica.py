@@ -209,6 +209,7 @@ class Replica:
         from ..util.tracing import remote_parent, span
 
         from .multiplex import _model_id_ctx, _set_request_model_id
+        from .._private.worker import note_stream_end
         from .observability import (
             observe_handler,
             request_context,
@@ -242,11 +243,18 @@ class Replica:
             error = True
             raise
         finally:
+            # E0 of the stream's end (observability.py): the handler's
+            # own duration on this process's clock, and the epoch
+            # beside it, ride the stream's end to the proxy.
+            handler_ms = (time.perf_counter() - t0) * 1e3
+            note_stream_end(
+                handler_ms=handler_ms, exhausted_ts=time.time()
+            )
             observe_handler(
                 self._app_name,
                 self._deployment_name,
                 method,
-                (time.perf_counter() - t0) * 1e3,
+                handler_ms,
                 error,
                 request_id=request_id,
             )

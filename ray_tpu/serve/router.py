@@ -243,6 +243,13 @@ class DeploymentResponseGenerator:
         transport carried none."""
         return self._gen.first_item_ts
 
+    @property
+    def end_note(self) -> dict:
+        """What the replica noted of the stream's end (handler_ms,
+        exhausted_ts, end_ts: observability.py E0, E1), once the end
+        has been received; {} before, and where it noted nothing."""
+        return self._gen.end_note
+
     def __next__(self):
         if self._finished:
             raise StopIteration

@@ -101,6 +101,148 @@ def test_pick_chips_takes_aligned_free_blocks():
     assert pick_chips({0, 1, 2}, 4, 4) is None
 
 
+def _fake_proc(root, pid, state, threads, fds=()):
+    import os
+
+    os.makedirs(f"{root}/{pid}/fd")
+    fields = ["0"] * 50
+    fields[0], fields[17] = state, str(threads)
+    with open(f"{root}/{pid}/stat", "w") as f:
+        f.write(f"{pid} (python3) " + " ".join(fields) + "\n")
+    with open(f"{root}/{pid}/cmdline", "wb") as f:
+        f.write(b"python3\0run.py\0" if state != "Z" else b"")
+    with open(f"{root}/{pid}/comm", "w") as f:
+        f.write("python3\n")
+    for i, target in enumerate(fds):
+        os.symlink(target, f"{root}/{pid}/fd/{i}")
+
+
+@pytest.mark.parametrize(
+    "chips,expected",
+    [
+        # the open file of chip 2, and the zombie whose threads live on
+        ((2,), [(11, "Sl", "python3 run.py"), (13, "Zl", "[python3]")]),
+        ((0, 1), [(13, "Zl", "[python3]")]),
+        ((0, 1, 2, 3), [(11, "Sl", "python3 run.py"), (12, "S", "python3 run.py"),
+                        (13, "Zl", "[python3]")]),
+    ],
+)
+def test_foreign_holders_of_a_chip_are_its_open_files_and_zl(
+    tmp_path, chips, expected
+):
+    """`chip_holders` (ISSUE 59): what a TPU spawn waits for on a host
+    whose previous session left a worker behind."""
+    import os
+
+    from ray_tpu._private.accelerators.tpu import (
+        chip_device_files,
+        chip_holders,
+    )
+
+    dev, proc = str(tmp_path / "dev"), str(tmp_path / "proc")
+    os.makedirs(f"{dev}/vfio")
+    for group in ("7", "3", "12", "9", "vfio"):  # IOMMU groups, not chips
+        open(f"{dev}/vfio/{group}", "w").close()
+    assert chip_device_files((0, 3), dev) == {
+        f"{dev}/vfio/3", f"{dev}/vfio/12"
+    }
+    _fake_proc(proc, 11, "S", 40, [f"{dev}/vfio/vfio", f"{dev}/vfio/9"])
+    _fake_proc(proc, 12, "S", 1, [f"{dev}/vfio/12", "/dev/null"])
+    _fake_proc(proc, 13, "Z", 2)     # files closed, threads in the kernel
+    _fake_proc(proc, 14, "Z", 1)     # a plain zombie holds nothing
+    _fake_proc(proc, 15, "S", 8, ["/dev/null"])
+    _fake_proc(proc, 16, "S", 8, [f"{dev}/vfio/9"])  # one of ours
+    got = chip_holders(chips, skip=[16], proc=proc, dev=dev)
+    assert sorted(got) == expected
+    # a host without the device files (CPU, fake chips): nobody
+    assert chip_holders(chips, proc=proc, dev=str(tmp_path / "none")) == []
+
+
+class _Leftover:
+    """A killed worker whose threads take `lasts` seconds over the
+    device's teardown (ForkedProc's view of a `Zl`)."""
+
+    def __init__(self, pid, lasts):
+        import time
+
+        self.pid, self._gone_at = pid, time.monotonic() + lasts
+
+    def poll(self):
+        import time
+
+        return 0 if time.monotonic() >= self._gone_at else None
+
+    def state(self):
+        return None if self.poll() == 0 else "Zl"
+
+
+@pytest.mark.parametrize(
+    "scoped,lasts,waits", [(True, 1.3, True), (False, 0.0, False)]
+)
+def test_shutdown_returns_when_a_chip_workers_threads_are_gone(
+    capsys, scoped, lasts, waits
+):
+    import time
+    import types
+
+    from ray_tpu._private.daemon import NodeDaemon
+
+    proc = _Leftover(4242, lasts)
+    daemon = types.SimpleNamespace(
+        _chip_procs=[(proc, (0, 1, 2, 3))] if scoped else [],
+        _CHIP_WAIT_S=NodeDaemon._CHIP_WAIT_S,
+    )
+    t0 = time.monotonic()
+    NodeDaemon._await_killed(daemon, [proc])
+    assert (time.monotonic() - t0 >= lasts) and proc.poll() == 0
+    err = capsys.readouterr().err
+    assert ("pid 4242 (Zl, held chips)" in err) == waits
+
+
+def test_a_tpu_worker_waits_for_another_sessions_holder_at_tpu_init(
+    monkeypatch, capsys
+):
+    """A worker scoped to chips waits for what another session left
+    on them when ITS JAX initialises the TPU backend, not before (its
+    own start runs beside the leftover's teardown), for a bounded
+    time, and standard error says what was waited for."""
+    from jax._src import xla_bridge
+
+    from ray_tpu._private.accelerators import tpu
+
+    holders = [(99, "Zl", "[python3]")]
+    calls = []
+
+    def fake_holders(chips, **where):
+        calls.append("holders")
+        if calls.count("holders") >= 3:
+            holders.clear()
+        return list(holders)
+
+    monkeypatch.setattr(tpu, "chip_holders", fake_holders)
+    monkeypatch.setattr(
+        xla_bridge, "_init_backend",
+        lambda platform: calls.append(platform) or platform,
+    )
+    before = xla_bridge._init_backend
+    tpu.wait_for_chips_at_tpu_init((0, 1, 2, 3))  # a host without chips
+    assert xla_bridge._init_backend is before
+    monkeypatch.setattr(tpu, "chip_device_files", lambda chips: {"/dev/x"})
+    tpu.wait_for_chips_at_tpu_init((0, 1, 2, 3))
+    assert calls == []  # nothing waited for at the worker's start
+    assert xla_bridge._init_backend("cpu") == "cpu" and calls == ["cpu"]
+    assert xla_bridge._init_backend("tpu") == "tpu"
+    assert calls == ["cpu", "holders", "holders", "holders", "tpu"]
+    assert capsys.readouterr().err == ""  # under a second: no line
+    # still held at the deadline: the line, and JAX finds out itself
+    holders.append((99, "Zl", "[python3]"))
+    calls[:] = [None] * 3
+    monkeypatch.setattr(tpu, "chip_holders", lambda chips, **w: holders)
+    assert tpu.await_chip_holders((0,), timeout=0.25) >= 0.25
+    err = capsys.readouterr().err
+    assert "pid 99 (Zl) [python3]" in err and "still held" in err
+
+
 # -- who asks for TPU --------------------------------------------------
 
 @pytest.mark.parametrize("chips,options", [(4, {"num_tpus": 1}), (0, {})])

@@ -453,6 +453,28 @@ def test_event_stats_per_handler_timing(rt_session):
     assert stats["register_client"]["errors"] == 0
 
 
+@pytest.mark.parametrize(
+    "code,cause", [(0, None), (2, "behind_prefill"), (6, "admissible")]
+)
+def test_the_waiting_gauges_row_says_why_the_queue_stands(code, cause):
+    """`/api/serve` and `serve.status()` fold the head's table with
+    `deployment_snapshot`: the family's row that shows `waiting` gains
+    the standing cause from the gauge beside it (ISSUE 59)."""
+    from ray_tpu.serve.observability import (
+        QUEUE_CAUSES,
+        _queue_cause_code,
+        deployment_snapshot,
+    )
+
+    assert _queue_cause_code(cause) == code and len(QUEUE_CAUSES) == 6
+    tags = "app=llm|deployment=llm|family=tiny"
+    row = deployment_snapshot({
+        "serve_engine_waiting": {"by_tags": {tags: {"value": 3.0}}},
+        "serve_engine_queue_cause": {"by_tags": {tags: {"value": code}}},
+    })[("llm", "llm")]["engine"]["tiny"]
+    assert row["waiting"] == 3.0 and row.get("queue_cause") == cause
+
+
 # -- a streamed request's first token, stage by stage (ISSUE 41) -------
 # Keep these LAST in the file: the cluster below is module-scoped, and
 # the tests above each make and shut down a session of their own.
@@ -463,6 +485,11 @@ STAGE_SERIES = (
     "serve_http_dispatch_ms", "serve_handler_submit_ms",
     "serve_first_item_handoff_ms", "serve_first_item_transit_ms",
     "serve_http_first_byte_ms",
+)
+#: ... and once each at its end (boundaries E0 to E2, ISSUE 59).
+END_SERIES = (
+    "serve_ingress_overhead_ms", "serve_stream_end_handoff_ms",
+    "serve_stream_end_transit_ms",
 )
 OLD_SERIES = (
     "serve_queue_wait_ms", "serve_engine_ttft_ms",
@@ -633,7 +660,7 @@ def test_first_token_stages_are_observed_once_a_request(
     streamed = 0 if case == "unary" else 1
     # Exactly one observation a streamed request (of 32 or 96 tokens),
     # none a token; a unary call and a 503 observe nothing new.
-    for name in STAGE_SERIES:
+    for name in STAGE_SERIES + END_SERIES:
         assert delta(name)[1] == streamed, (name, delta(name))
     engine = {
         k: engine_after[k] - engine_before[k] for k in ENGINE_COUNTERS
@@ -643,7 +670,10 @@ def test_first_token_stages_are_observed_once_a_request(
     if case != "stream":
         return
     assert engine["tokens_emitted"] == 32
-    stage = {name: delta(name)[0] for name in STAGE_SERIES + OLD_SERIES}
+    stage = {
+        name: delta(name)[0]
+        for name in STAGE_SERIES + END_SERIES + OLD_SERIES
+    }
     assert [delta(name)[1] for name in OLD_SERIES] == [1, 1, 1]
     assert all(v >= 0.0 for v in stage.values()), stage
     admit, prefill = engine["admit_wait_ms_total"], engine["prefill_ms_total"]
@@ -686,3 +716,77 @@ def test_first_token_stages_are_observed_once_a_request(
         + stage["serve_first_item_handoff_ms"], abs=0.05
     )
     assert {"queue_ms", "prefill_ms", "decode_ms"} <= set(request)
+    # Its wait by cause, summing to its wait.
+    by_cause = dict(
+        part.split("=") for part in request["queue_cause_ms"].split(",")
+    )
+    assert sum(map(float, by_cause.values())) == pytest.approx(
+        float(request["queue_ms"]), abs=0.01
+    ) and set(by_cause) <= {"admissible", "behind_prefill", "no_slot"}
+    # Above the replica: the proxy's time for THIS request less its
+    # handler's (the two spans time the same two stretches, each to
+    # its own end), on the series and the `serve.http` span, and no
+    # less than the way in and the end together.
+    overhead = stage["serve_ingress_overhead_ms"]
+    span_ms = {
+        name: (s["end_ns"] - s["start_ns"]) / 1e6 for name, s in spans.items()
+    }
+    assert overhead == pytest.approx(
+        span_ms["serve.http"] - span_ms["serve.handle"], abs=5.0
+    )
+    assert float(http_attrs["ingress_overhead_ms"]) == pytest.approx(
+        overhead, abs=0.01
+    )
+    assert 0.0 < overhead <= stage["serve_http_request_latency_ms"]
+    named = (
+        stage["serve_http_dispatch_ms"] + stage["serve_queue_wait_ms"]
+        + stage["serve_stream_end_handoff_ms"]
+        + stage["serve_stream_end_transit_ms"]
+    )
+    assert named <= overhead + 1.0, stage
+
+
+def test_a_stream_that_notes_nothing_ends_as_it_always_did(
+    first_token_cluster,
+):
+    """`data/` and `train/` stream through the transport serve does:
+    the end of a stream whose producer noted nothing (every stream but
+    a serve replica's) reaches its consumer in the parent's form, key
+    for key, in the run's answer and on the wire."""
+    from ray_tpu._private import wire
+    from ray_tpu._private.stream_runs import StreamRuns
+
+    rt = first_token_cluster.rt
+
+    @rt.remote(num_returns="streaming")
+    def count(n):
+        yield from range(n)
+
+    gen = count.remote(3)
+    assert [gen.next_value() for _ in range(3)] == [0, 1, 2]
+    with pytest.raises(StopIteration):
+        gen.next_value()
+    assert gen._end == {"count": 3, "error": None} and gen.end_note == {}
+
+    class Conn:
+        conn_id, sent = 1, []
+
+        def reply(self, mid, payload):
+            self.sent.append(payload)
+
+    runs, conn = StreamRuns(), Conn()
+    for task, note in ((b"plain", {}), (b"serve", {"handler_ms": 7.5})):
+        runs.put(task, 0, b"x", first_ts=1.0)
+        runs.end(task, 1, None, note=note)
+        runs.fetch(conn, 0, task, 0)
+    assert conn.sent == [
+        {"items": [b"x"], "end": {"count": 1, "error": None},
+         "first_ts": 1.0},
+        {"items": [b"x"], "end": {"count": 1, "error": None,
+                                  "handler_ms": 7.5}, "first_ts": 1.0},
+    ]
+    assert wire.validate("stream_end", {"task": b"t", "count": 1}) is None
+    assert wire.validate(
+        "stream_end", {"task": b"t", "count": 1, "handler_ms": 1.0,
+                       "exhausted_ts": 2.0, "end_ts": 3.0},
+    ) is None

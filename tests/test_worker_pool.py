@@ -146,6 +146,45 @@ def test_forked_proc_detects_recycled_pid():
     assert gone.poll() == 0
 
 
+def _fake_stat(root, pid, state, starttime, threads):
+    """A /proc/<pid>/stat as the kernel writes it, under `root`."""
+    import os
+
+    os.makedirs(f"{root}/{pid}", exist_ok=True)
+    fields = ["0"] * 50
+    fields[0], fields[17], fields[19] = state, str(threads), str(starttime)
+    with open(f"{root}/{pid}/stat", "w") as f:
+        f.write(f"{pid} (python3 (x)) " + " ".join(fields) + "\n")
+
+
+@pytest.mark.parametrize(
+    "state,threads,forked_at,alive",
+    [
+        ("S", 9, 77, "Sl"),
+        # the leader is a zombie and its thread group is not empty:
+        # the kernel is still tearing down what it held (its chips)
+        ("Z", 2, 77, "Zl"),
+        ("Z", 1, 77, None),     # exited, the template has not reaped it
+        ("S", 9, 76, None),     # another process has the pid now
+        ("Z", 2, None, None),   # the template's reaper won the race
+    ],
+)
+def test_a_zombie_with_threads_left_is_not_gone(
+    tmp_path, state, threads, forked_at, alive
+):
+    """`ForkedProc.poll()` against a fake /proc: what `daemon.shutdown`
+    waits on and `_claim_chips` frees chips by (ISSUE 59)."""
+    import os
+
+    from ray_tpu._private.worker_forkserver import ForkedProc
+
+    me = os.getpid()  # a pid `kill(pid, 0)` finds
+    _fake_stat(tmp_path, me, state, 77, threads)
+    proc = ForkedProc(me, forked_at, proc_root=str(tmp_path))
+    assert proc.state() == alive
+    assert proc.poll() == (None if alive else 0)
+
+
 def test_default_actors_exceed_node_cpus():
     """Default actors need 1 CPU to *schedule* but hold 0 for their
     lifetime (reference: DEFAULT_ACTOR_CREATION_CPU_SIMPLE=0 — the
