@@ -14,16 +14,35 @@ loop, every iteration:
      availability (not enough blocks: the head WAITS, no skip-ahead,
      no crash) — pinning any prefix-cache hit and reserving the rest
      of its pages, then DISPATCHES its prefill's next chunk, written
-     straight into its pages (Sarathi-style interleave, starting
-     AFTER the shared prefix). Every chunk is `prefill_chunk` tokens
-     but a prompt's last, which is the chunk, its half or its
-     quarter, the smallest that holds the tokens left
-     (kv_slots.bucket_for): a function of the prompt's length alone,
-     so a hit and a miss run the same last chunk. The loop runs
-     every such shape once before its first admission
-     (`_warm_chunk_shapes`), so no prompt length compiles anything
-     later;
-  3. DISPATCHES one jitted paged decode step over the FULL slot batch
+     straight into its pages (starting AFTER the shared prefix).
+     Every chunk is `prefill_chunk` tokens but a prompt's last, which
+     is the chunk, its half or its quarter, the smallest that holds
+     the tokens left (kv_slots.bucket_for): a function of the
+     prompt's length alone, so a hit and a miss run the same last
+     chunk. The loop runs every such shape once before its first
+     admission (`_warm_chunk_shapes`), so no prompt length compiles
+     anything later. An iteration's prefill is a BUDGET of tokens,
+     the `prefill_chunk` the configuration states (ISSUE 60): it goes
+     on, admitting and dispatching, WHILE the chunks it has
+     dispatched hold fewer tokens than that. A whole chunk ends it,
+     as it always did; a prompt's short last chunk leaves room, so
+     the next prompt is admitted behind it in the same iteration and
+     its first chunk dispatched, and so on until the budget is met,
+     the queue is empty, no slot is free or the gate refuses. Still
+     ONE prompt mid-prompt at a time (only a finished prompt lets the
+     iteration go on), strict FIFO, the gate asked per admission.
+     What it bounds: an iteration dispatches under
+     2 x `prefill_chunk` prefill tokens (at most `prefill_chunk` less
+     the smallest shape, then one whole chunk), so the longest gap
+     between two tokens of a decoding row is a step and under two
+     chunks, where one chunk an iteration made it a step and one.
+     Why not otherwise: a strict budget (the next chunk only if it
+     fits) never joins a whole chunk to a remainder, which is the
+     common case; prefilling whenever a slot is free stalls every
+     decoding row for whole prompts; two prompts mid-prompt at once
+     save no device work;
+  3. DISPATCHES one jitted paged decode step over the FULL slot batch,
+     every row step 2 started among its rows
      (static shapes: full-width block tables, dead rows masked and
      parked on the null block; attention walks the tables only as far
      as the longest alive row reaches) —
@@ -61,13 +80,16 @@ stands for exactly ONE cause, that of its FIFO head (admission is
 strict FIFO, so the head's cause is every waiter's), decided in this
 order from what the loop reads anyway: `no_slot` (the scheduler has no
 free slot: nothing else could admit the head) -> `behind_prefill` (a
-slot is free and another prompt is prefilling: the one-prompt-at-a-time
-rule ALONE holds the head, the time a second prefilling prompt would
-win back) -> `no_pages` / `no_window_pages` / `no_state_slots` (the
-pool for which the gate refused the head at the loop's last attempt;
-the gate's word stands until the gate is asked again) -> `admissible`
-(nothing is known to be in the way and the loop has not come round:
-the loop's own latency). `queue_ms` is the time each cause has stood
+slot is free and another prompt is mid-prompt: the one-prompt-at-a-time
+rule and the iteration's budget hold the head, the time a second
+prefilling prompt or a larger `prefill_chunk` would win back) ->
+`no_pages` / `no_window_pages` / `no_state_slots` (the pool for which
+the gate refused the head at the loop's last attempt; the gate's word
+stands until the gate is asked again) -> `admissible` (nothing is
+known to be in the way and the loop has not asked yet: behind a
+prompt's short last chunk the moment until the same iteration asks,
+behind a whole last chunk, whose tokens met the budget, the rest of
+the iteration). `queue_ms` is the time each cause has stood
 and `slot_ms` the slots x time in each state (`decoding`, `prefilling`,
 `empty_queued`: empty while the queue stands, what any admission
 change can win, `empty_idle`: empty with no waiter), both advanced
@@ -584,11 +606,14 @@ class InferenceEngine:
         self._first_tokens = 0
         self._prefill_ms_total = 0.0
         # How much of what the chunks compute the prompts need: chunks
-        # dispatched, those at a shape under `prefill_chunk`, the
-        # positions they span (padding too), and the positions the
-        # admitted prompts had beyond their prefix hits.
+        # dispatched, those at a shape under `prefill_chunk`, those
+        # dispatched in an iteration that had dispatched one already
+        # (behind a short last chunk), the positions they span (padding
+        # too), and the positions the admitted prompts had beyond their
+        # prefix hits.
         self._prefill_chunks = 0
         self._prefill_short_chunks = 0
+        self._prefill_joined_chunks = 0
         self._prefill_tokens_computed = 0
         self._prefill_tokens_needed = 0
         # What decode's attention touches, per step, from the lengths
@@ -901,11 +926,13 @@ class InferenceEngine:
                 first_tokens=self._first_tokens,
                 prefill_ms_total=self._prefill_ms_total,
                 # Chunks dispatched, those shorter than
-                # `prefill_chunk`, the positions they span and the
+                # `prefill_chunk`, those that joined an iteration
+                # behind another, the positions they span and the
                 # positions the admitted prompts needed (a prompt less
                 # its prefix hit): the rest is padding.
                 prefill_chunks=self._prefill_chunks,
                 prefill_short_chunks=self._prefill_short_chunks,
+                prefill_joined_chunks=self._prefill_joined_chunks,
                 prefill_tokens_computed=self._prefill_tokens_computed,
                 prefill_tokens_needed=self._prefill_tokens_needed,
                 kv_keys_live=self._kv_keys_live,
@@ -1010,10 +1037,13 @@ class InferenceEngine:
                         phase.switch("engine.policy")
                         worked = self._policy_step() or worked
                     if self._sched is not None:
-                        # Prefill before decode: an admitted request
-                        # advances by ONE chunk, then the whole batch
-                        # decodes one step (Sarathi-style interleave).
-                        # Both are only dispatched; what is retired
+                        # Prefill before decode: chunks until
+                        # `prefill_chunk` tokens are dispatched (one
+                        # whole chunk, or a prompt's short last chunk
+                        # and the next prompt's first behind it), then
+                        # the whole batch decodes one step, the rows
+                        # those chunks started among them. All are
+                        # only dispatched; what is retired
                         # after them is what the iteration before
                         # dispatched, so the device has this
                         # iteration's programs queued while the host
@@ -1464,11 +1494,26 @@ class InferenceEngine:
 
     # -- prefill -------------------------------------------------------
     def _advance_prefill(self) -> None:
+        """An iteration's prefill: chunks are dispatched, each behind
+        the one before, WHILE those dispatched so far hold fewer than
+        `prefill_chunk` tokens (module docstring, step 2). A whole
+        chunk therefore ends it; a prompt's short last chunk leaves
+        room, the prompt has left `_prefilling` by then, and the next
+        call admits the FIFO head behind it."""
+        dispatched = 0
+        while dispatched < self.config.prefill_chunk:
+            chunk = self._prefill_chunk(joined=dispatched > 0)
+            if not chunk:
+                return
+            dispatched += chunk
+
+    def _prefill_chunk(self, joined: bool) -> int:
         """Admit (if no prompt is prefilling) and dispatch the current
         prefill's next chunk, written straight into the request's
-        pages, with `finish_chunk` behind it; neither is waited for."""
-        import jax.numpy as jnp
-
+        pages, with `finish_chunk` behind it; neither is waited for.
+        `joined`: this iteration has dispatched a chunk already. -> the
+        chunk's tokens, 0 when there was nothing to do (an empty
+        queue, no free slot, the gate's refusal)."""
         phase = self._phase
         phase.switch("engine.admit")
         with self._lock:
@@ -1484,7 +1529,7 @@ class InferenceEngine:
                         # The attempt's word on why the queue stands.
                         self._tick_locked(time.perf_counter())
                         self._settle_locked(self._gate_refusal)
-                    return
+                    return 0
                 req, slot = admitted
                 req.slot = slot
                 req.admitted_ts = time.perf_counter()
@@ -1577,6 +1622,7 @@ class InferenceEngine:
         t0 = self._dispatching()
         self._prefill_chunks += 1
         self._prefill_short_chunks += int(chunk < self.config.prefill_chunk)
+        self._prefill_joined_chunks += int(joined)
         self._prefill_tokens_computed += chunk
         # Next-token logits come from the prompt's LAST REAL position
         # (inside the last chunk by bucket construction: it starts at
@@ -1596,6 +1642,7 @@ class InferenceEngine:
         self._inflight.append(
             _ChunkInFlight(req if started else None, fence, t0)
         )
+        return chunk
 
     def _state_table(self, req: _Request, start: int, chunk: int):
         """The `table` of the chunk over [start, start + chunk) of

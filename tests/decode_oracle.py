@@ -8,6 +8,8 @@ on the host, by the engine's policy; `paged_greedy` is its greedy
 one-liner for a batch of prompts.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,18 +19,21 @@ from ray_tpu.models.llama import forward
 _forward = jax.jit(forward, static_argnames=("cfg",))
 
 
-def greedy_uncached(params, cfg, prompt, max_new_tokens, eos=-1):
+def greedy_uncached(params, cfg, prompt, max_new_tokens, eos=-1, forward=None):
     """Greedy continuation of `prompt` (a list of ids) -> token list,
     ending after `eos` (inclusive) or `max_new_tokens`. Every token
     costs a whole forward over a buffer of the final length: causal
     attention and per-token routing keep what lies past a position out
-    of its logits, so one shape (one compile) serves every step."""
+    of its logits, so one shape (one compile) serves every step.
+    `forward(params, tokens [1, t]) -> logits [1, t, vocab]` is another
+    plain forward, for a model the training forward does not run."""
+    forward = forward or functools.partial(_forward, cfg=cfg)
     n = len(prompt)
     tokens = np.zeros((1, n + max_new_tokens), np.int32)
     tokens[0, :n] = prompt
     out = []
     for pos in range(n, n + max_new_tokens):
-        logits = _forward(params, jnp.asarray(tokens), cfg=cfg)
+        logits = forward(params, jnp.asarray(tokens))
         token = int(jnp.argmax(logits[0, pos - 1]))
         tokens[0, pos] = token
         out.append(token)
@@ -41,9 +46,14 @@ def serial_streams(params, cfg, ec, jobs):
     """The plain reference: the engine's policy (FIFO, one prompt
     prefilling at a time, one chunk an iteration and then one decode
     step over the rows alive, `fold_in(base_key, step)` keys) run one
-    program at a time with the state on the host. `jobs` are
-    (prompt, max_new_tokens, eos), all queued at the start and no more
-    of them than slots. -> one token list a job."""
+    program at a time with the state on the host. Every chunk here is
+    a whole one, the last padded: the engine's own schedule where its
+    geometry offers a last chunk no shorter shape (`kv_block_len`
+    above half of `prefill_chunk`, as the callers' is), so that
+    no chunk leaves room in an iteration's budget for another
+    (tests/test_engine_prefill_budget.py holds that schedule). `jobs`
+    are (prompt, max_new_tokens, eos), all queued at the start and no
+    more of them than slots. -> one token list a job."""
     from ray_tpu.llm.kv_slots import default_block_len
     from ray_tpu.models.generate import (
         init_block_pool, paged_decode_step, paged_prefill,
